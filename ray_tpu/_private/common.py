@@ -48,6 +48,15 @@ def pg_resource_name(base: str, pg_id_hex: str, bundle_index: Optional[int]) -> 
     return f"{base}_group_{bundle_index}_{pg_id_hex}"
 
 
+def holds_tpu(resources: Optional[Dict[str, float]]) -> bool:
+    """Whether a demand (or a node's total) includes TPU chips, plain or as
+    a placement-group bundle's renamed resource."""
+    return any(
+        v > 0 and (k == "TPU" or k.startswith("TPU_group_"))
+        for k, v in (resources or {}).items()
+    )
+
+
 def rewrite_resources_for_pg(
     resources: Dict[str, float], pg_id_hex: str, bundle_index: Optional[int]
 ) -> Dict[str, float]:

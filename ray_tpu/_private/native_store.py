@@ -155,8 +155,15 @@ def load_library() -> Optional[ctypes.CDLL]:
                             ["make", "-C", _SRC_DIR, "-s", "-B"],
                             check=True, capture_output=True, timeout=120,
                         )
-            except Exception as e:  # no toolchain / build failure
-                logger.debug("native store build failed: %s", e)
+            except (OSError, subprocess.SubprocessError) as e:
+                # no toolchain / build failure: the object store and the
+                # scheduler run as their Python twins, and say so
+                stderr = getattr(e, "stderr", None) or b""
+                logger.warning(
+                    "native store build failed (%s: %s) %s; using the "
+                    "pure-Python object store and scheduler",
+                    type(e).__name__, e,
+                    stderr.decode(errors="replace").strip()[-500:])
         if not os.path.exists(_LIB_PATH):
             return None
         try:

@@ -6,17 +6,26 @@ ray: python/ray/_private/resource_spec.py:175-182,
 util/accelerators/accelerators.py:1-7): TPU chips are a first-class "TPU"
 resource, and ICI topology is advertised as node labels so placement-group
 STRICT_PACK can target one slice. Detection is env-driven
-(TPU_CHIP_COUNT / TPU_TOPOLOGY / TPU_WORKER_ID, as set by GKE / QR runtimes);
-probing via jax.devices() is opt-in (config flag tpu_autodetect) because
-initializing libtpu claims the chips for the probing process.
+(TPU_CHIP_COUNT / TPU_TOPOLOGY / TPU_WORKER_ID, as set by GKE / QR runtimes).
+The opt-in probe (config flag tpu_autodetect) counts the chips' device nodes:
+this runs inside the long-lived raylet, and asking jax.devices() there would
+open the device library and keep the chips from every worker.
 """
 
 from __future__ import annotations
 
+import glob
 import os
 from typing import Dict, Tuple
 
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
+
+
+def count_tpu_device_nodes() -> int:
+    """Chips on this host, by their device nodes (/dev/accel* on older
+    generations, /dev/vfio/<n> since v5e) — opens nothing."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
 def detect_resources() -> Tuple[Dict[str, float], Dict[str, str]]:
@@ -34,15 +43,7 @@ def detect_resources() -> Tuple[Dict[str, float], Dict[str, str]]:
 
     chips = os.environ.get("TPU_CHIP_COUNT")
     if chips is None and cfg.tpu_autodetect:
-        try:
-            import jax
-
-            devs = [d for d in jax.devices() if d.platform != "cpu"]
-            chips = str(len(devs)) if devs else None
-            if devs:
-                labels["tpu-device-kind"] = getattr(devs[0], "device_kind", "tpu")
-        except Exception:
-            chips = None
+        chips = str(count_tpu_device_nodes())
     if chips:
         n = float(chips)
         if n > 0:

@@ -210,8 +210,8 @@ class _PyModulesPlugin(RuntimeEnvPlugin):
 
 class _EnvVarsPlugin(RuntimeEnvPlugin):
     """env_vars apply at worker SPAWN (the raylet exports them before the
-    interpreter starts, so sitecustomize/jax see them); this plugin only
-    validates shape."""
+    interpreter starts, so JAX_PLATFORMS / XLA_FLAGS are in place when
+    jax is first imported); this plugin only validates shape."""
 
     name = "env_vars"
     priority = 5
@@ -569,7 +569,7 @@ def build_container_command(container: dict, env: Dict[str, str],
     baked in still work for same-version clusters.
 
     ``extra_env_keys``: additional env names to forward (the caller's
-    runtime_env env_vars + accelerator triggers — the prefix filter
+    runtime_env env_vars — the prefix filter
     below only covers cluster plumbing). ``cidfile``: engine writes the
     container id there so the raylet can force-remove a container whose
     client process it had to kill (SIGKILL never proxies).

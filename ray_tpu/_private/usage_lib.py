@@ -17,6 +17,7 @@ Env switches (reference names honored):
 
 from __future__ import annotations
 
+import importlib.metadata
 import json
 import os
 import platform
@@ -45,11 +46,11 @@ def collect_usage_stats(gcs_request=None) -> Dict[str, Any]:
         "os": platform.system().lower(),
         "arch": platform.machine(),
     }
+    # from package metadata, not ``import jax``: this runs in the driver,
+    # which must never initialise a backend (one process per chip)
     try:
-        import jax
-
-        payload["jax_version"] = jax.__version__
-    except Exception:
+        payload["jax_version"] = importlib.metadata.version("jax")
+    except importlib.metadata.PackageNotFoundError:
         pass
     try:
         if ray_tpu.is_initialized():

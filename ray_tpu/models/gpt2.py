@@ -20,7 +20,6 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ray_tpu.parallel.pipeline import axis_size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -355,10 +354,7 @@ def build_train_step_sp(model, tx, mesh: Mesh, *, sp_axis: str = "sp",
 
     The model must have been built with ``attention="ring"``.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     axes = (batch_axis, sp_axis)
 
@@ -555,10 +551,7 @@ def build_train_step_pp(config: GPT2Config, tx, mesh: Mesh, *,
     """
     from ray_tpu.parallel.pipeline import pipeline_apply
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     block = Block(config)
     ln_f = nn.LayerNorm(dtype=config.dtype)
@@ -597,7 +590,7 @@ def build_train_step_pp(config: GPT2Config, tx, mesh: Mesh, *,
             # Masking to the LAST pipeline rank pins the head/loss grad
             # path to one rank, so the psum over the pipeline axis below
             # completes replicated-param grads exactly once.
-            is_last = jax.lax.axis_index(axis) == axis_size(axis) - 1
+            is_last = jax.lax.axis_index(axis) == jax.lax.axis_size(axis) - 1
             numer = jax.lax.psum(
                 jnp.where(is_last, -(ll * mask).sum(), 0.0),
                 (axis, batch_axis),

@@ -79,10 +79,7 @@ def ring_self_attention(q, k, v, mesh: Mesh, *, seq_axis: str = "sp",
     """shard_map wrapper: q,k,v are GLOBAL (b, h, s, d) arrays whose s dim
     is (or will be) sharded over ``seq_axis``; returns the global output
     with the same sharding."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     spec = P(None, None, seq_axis, None)
     fn = shard_map(

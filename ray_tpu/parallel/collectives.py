@@ -13,23 +13,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def shard_map_norep(body, *, mesh, in_specs, out_specs):
-    """shard_map with the output-replication check disabled — the kwarg was
-    renamed check_rep -> check_vma across jax versions; every call site
-    shares this shim instead of hand-rolling the try/except."""
-    try:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """shard_map with the varying-axes (output replication) check off, for
+    bodies whose replicated outputs cannot be inferred statically."""
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def psum(x, axis: str):

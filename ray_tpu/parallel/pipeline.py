@@ -31,23 +31,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-
-def axis_size(axis_name: str) -> int:
-    """Size of a mapped axis inside shard_map/pmap, on any jax version
-    (``lax.axis_size`` only exists from 0.4.32; ``psum(1, axis)`` folds
-    to the same constant on older ones)."""
-    try:
-        return lax.axis_size(axis_name)
-    except AttributeError:  # jax < 0.4.32
-        return lax.psum(1, axis_name)
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
@@ -62,7 +47,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
 
     Returns (M, ...) outputs, replicated across the pipeline axis.
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     M = microbatches.shape[0]
     idx = lax.axis_index(axis_name)
     is_first = idx == 0
@@ -72,14 +57,10 @@ def pipeline_apply(stage_fn: Callable, stage_params, microbatches,
     # mark the carries as device-varying over the pipeline axis up front:
     # the loop body makes them varying (axis_index/ppermute), and scan
     # requires carry types to be loop-invariant
-    def _varying(x):
-        try:
-            return lax.pcast(x, (axis_name,), to="varying")
-        except (AttributeError, TypeError):
-            return x  # older jax: no varying-axis types
-
-    state = _varying(jnp.zeros_like(microbatches[0]))
-    outputs = _varying(jnp.zeros_like(microbatches))
+    state = lax.pcast(jnp.zeros_like(microbatches[0]), (axis_name,),
+                      to="varying")
+    outputs = lax.pcast(jnp.zeros_like(microbatches), (axis_name,),
+                        to="varying")
 
     def tick(t, carry):
         state, outputs = carry

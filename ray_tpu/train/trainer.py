@@ -50,7 +50,7 @@ class BaseTrainer:
             merged.update(config.get("train_loop_config", config) or {})
             t.train_loop_config = merged
 
-            # Relay worker reports up through the Tune session so schedulers
+            # Pass worker reports up through the Tune session so schedulers
             # see intermediate results (falls through to the Train session
             # when no Tune trial is active).
             from ray_tpu.tune import session as session_mod

@@ -944,10 +944,7 @@ def _xla_compiled(g: _Group, op: str, arr: "np.ndarray", extra=()):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     key = (op, arr.shape, str(arr.dtype), tuple(extra))
     fn = g._compiled.get(key)
@@ -987,13 +984,9 @@ def _xla_compiled(g: _Group, op: str, arr: "np.ndarray", extra=()):
         raise ValueError(op)
 
     # all_gather's replicated output can't be statically inferred; disable
-    # the rep check (kwarg renamed check_rep -> check_vma across jax versions)
-    try:
-        smapped = shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                            out_specs=out_spec, check_vma=False)
-    except TypeError:
-        smapped = shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                            out_specs=out_spec, check_rep=False)
+    # the varying-axes check
+    smapped = shard_map(body, mesh=mesh, in_specs=(in_spec,),
+                        out_specs=out_spec, check_vma=False)
     fn = jax.jit(
         smapped,
         in_shardings=NamedSharding(mesh, in_spec),

@@ -47,7 +47,7 @@ def test_replicas_stay_in_sync(ray_start_regular):
 @pytest.mark.slow
 def test_ppo_two_learners_matches_single(ray_start_regular):
     """CartPole learning with 2 DDP learners reaches the single-learner
-    bar (the VERDICT's acceptance: multi-learner matches 1-learner)."""
+    bar (the acceptance: multi-learner matches 1-learner)."""
     algo = (
         PPOConfig()
         .environment("CartPole-native")

@@ -192,3 +192,37 @@ def test_flash_pallas_cross_lengths():
     g_r = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for gp, gr in zip(g_p, g_r):
         np.testing.assert_allclose(gp, gr, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_flash_dispatch_never_hides_a_failed_kernel(monkeypatch, platform):
+    """``impl=None`` asks the platform: on "tpu" it is the Pallas kernel or
+    the kernel's own exception — never a quiet drop to scan, which at real
+    size does not even fit the chip — and on "cpu" it is scan."""
+    from ray_tpu.ops import attention
+
+    def broken_kernel(*args, **kwargs):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    taken = []
+    scan = attention._flash_scan
+    monkeypatch.setattr(attention, "_flash_pallas_diff", broken_kernel)
+    monkeypatch.setattr(
+        attention, "_flash_scan",
+        lambda *a, **kw: taken.append("scan") or scan(*a, **kw))
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    jax.clear_caches()  # flash_attention is jitted: drop earlier decisions
+    try:
+        q, k, v = _qkv(s=32)
+        if platform == "tpu":
+            with pytest.raises(RuntimeError, match="Mosaic refused"):
+                flash_attention(q, k, v, causal=True)
+            assert taken == []
+        else:
+            out = flash_attention(q, k, v, causal=True)
+            np.testing.assert_allclose(
+                out, attention_reference(q, k, v, causal=True),
+                atol=2e-5, rtol=2e-5)
+            assert taken == ["scan"]
+    finally:
+        jax.clear_caches()

@@ -60,10 +60,7 @@ def test_pipeline_grads_match_sequential():
     mb = jax.random.normal(jax.random.PRNGKey(3), (4, 8, 16))
     w = jax.random.normal(jax.random.PRNGKey(4), mb.shape)
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def pp_loss(stacked, mb):
         def local(stacked, mb):

@@ -41,13 +41,7 @@ def _dp_train_loop(config):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: pre-promotion location, and its
-        # replication checker cannot prove AD-derived psum'd grads are
-        # replicated -- disable it (values are equal across shards)
-        from jax.experimental.shard_map import shard_map as _shard_map
-        shard_map = functools.partial(_shard_map, check_rep=False)
+    from jax import shard_map
 
     from ray_tpu import train
 
@@ -145,13 +139,7 @@ def _hybrid_train_loop(config):
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: pre-promotion location, and its
-        # replication checker cannot prove AD-derived psum'd grads are
-        # replicated -- disable it (values are equal across shards)
-        from jax.experimental.shard_map import shard_map as _shard_map
-        shard_map = functools.partial(_shard_map, check_rep=False)
+    from jax import shard_map
 
     from ray_tpu import parallel, train
 
