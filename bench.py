@@ -292,94 +292,13 @@ def _log_overhead_main():
     os._exit(0)
 
 
-def _steptrace_overhead_main():
-    """BENCH_STEPTRACE_OVERHEAD=1: the step observatory's acceptance
-    numbers on a tight collective loop. (a) recorder share: records
-    written during the window x calibrated per-record cost / wall time —
-    gated <2% (calibration x count estimator, same discipline as the
-    metrics/logs lanes: this box's virtualized 10ms-quantum CPU clocks
-    make in-situ self-timing of sub-us slices read zero). (b) off
-    posture: with steptrace disabled the same loop must leave ZERO new
-    records in the ring. Emits ONE JSON line, same contract as the
-    default bench path."""
-    import ray_tpu
-    from ray_tpu._private import steptrace
-
-    # calibrate the per-record cost, uncontended
-    n_cal = 50_000
-    steptrace.set_enabled(True)
-    steptrace.reset()
-    t0 = time.perf_counter()
-    for i in range(n_cal):
-        steptrace.record_collective("cal", i, "allreduce", 0, 1,
-                                    0.0, 0.0, 64)
-    per_record = (time.perf_counter() - t0) / n_cal
-    steptrace.reset()
-
-    def collective_loop(n=300):
-        """Tight out-of-graph collective loop: a world-1 store group on
-        the driver — every allreduce is a real KV rendezvous round trip
-        (put + get through the GCS), the hot path the recorder rides."""
-        import numpy as np
-
-        from ray_tpu.util import collective as col
-
-        arr = np.ones((16,), np.float32)
-        t0 = time.perf_counter()
-        for _ in range(n):
-            col.allreduce(arr.copy(), "steptrace_bench")
-        return n, time.perf_counter() - t0
-
-    ray_tpu.init(num_cpus=2)
-    try:
-        from ray_tpu.util import collective as col
-
-        col.init_collective_group(1, 0, backend="store",
-                                  group_name="steptrace_bench")
-        collective_loop(n=30)  # warm the KV path
-        # phase 1: enabled — calibrated recorder share of the loop
-        records_before = steptrace.record_calls()
-        ops, window_s = collective_loop()
-        records = steptrace.record_calls() - records_before
-        share = records * per_record / window_s
-        # phase 2: disabled — the same loop must record NOTHING. Gate on
-        # the exact event counter (a ring-length delta saturates once the
-        # ring is full, which would make the assertion vacuous)
-        events_before = steptrace.record_calls()
-        steptrace.set_enabled(False)
-        off_ops, off_window_s = collective_loop()
-        off_records = steptrace.record_calls() - events_before
-        steptrace.set_enabled(True)
-        col.destroy_collective_group("steptrace_bench")
-    finally:
-        ray_tpu.shutdown()
-
-    ok = share < 0.02 and records >= ops and off_records == 0
-    print(json.dumps({
-        "metric": "steptrace_overhead_recorder_fraction",
-        "value": round(share, 6),
-        "unit": "fraction",
-        "vs_baseline": 1.0 if ok else 0.0,
-        "detail": {
-            "per_record_cost_us": round(per_record * 1e6, 3),
-            "records_on": records,
-            "records_off": off_records,
-            "collective_ops": ops,
-            "window_s": round(window_s, 4),
-            "ops_per_sec_on": round(ops / window_s, 1),
-            "ops_per_sec_off": round(off_ops / off_window_s, 1),
-        },
-    }), flush=True)
-    os._exit(0)
-
-
 def _memview_overhead_main():
     """BENCH_MEMVIEW_OVERHEAD=1: the memory observatory's acceptance
     numbers on the put/get hot path. (a) tracking share: creation
     records stamped during a tight store-put/get loop x calibrated
     per-record cost (callsite frame walk + dict store) / wall time —
     gated <2% (calibration x count estimator, same discipline as the
-    metrics/logs/steptrace lanes). (b) off posture: with memview
+    metrics/logs lanes). (b) off posture: with memview
     disabled the same loop must leave ZERO new records. Emits ONE JSON
     line, same contract as the default bench path."""
     import numpy as np
@@ -452,7 +371,7 @@ def _reqtrace_overhead_main():
     record count (spans+marks the cluster actually wrote) x calibrated
     per-record cost, divided by the measured proxy round trip — gated
     <2% (calibration x count estimator, same discipline as the
-    metrics/logs/steptrace/memview lanes: this box's virtualized
+    metrics/logs/memview lanes: this box's virtualized
     10ms-quantum CPU clocks make in-situ self-timing of sub-us slices
     read zero). (b) off posture: with RAY_TPU_reqtrace_enabled=0 the
     same HTTP loop must leave ZERO record attempts cluster-wide. Emits
@@ -1005,8 +924,6 @@ def main():
         _metrics_overhead_main()
     if os.environ.get("BENCH_LOG_OVERHEAD"):
         _log_overhead_main()
-    if os.environ.get("BENCH_STEPTRACE_OVERHEAD"):
-        _steptrace_overhead_main()
     if os.environ.get("BENCH_MEMVIEW_OVERHEAD"):
         _memview_overhead_main()
     if os.environ.get("BENCH_REQTRACE_OVERHEAD"):

@@ -9,22 +9,26 @@ process keeps ONE fixed-size ring of small tuples recording
   plus rank-local start/end/bytes — the (group, seq) key is what lets a
   GCS-side merge line up the SAME logical collective across ranks and
   attribute arrival skew to the rank that showed up last;
-- **step phases** (``train.session.step_phase("data"|"h2d"|"compute"|
-  "optimizer")``) and **step boundaries** (auto-delimited at
-  ``session.report()``);
-- **XLA compile events** (first-call / recompile timing per jitted fn,
-  via ``trace_jit`` cache-size sampling and, when available, a
-  ``jax.monitoring`` duration listener) so compile storms are
-  attributable in the same timeline.
+- **spans** (``span(name, n)``): the user's step phases
+  (``train.session.step_phase("data"|"h2d"|"compute"|"optimizer")``),
+  the runtime's own (``train/report``, ``data/next`` around
+  ``data/fetch``, ``ckpt/setup|snapshot|commit`` in the worker,
+  ``ckpt/persist`` in the driver), each with an optional count of rows
+  or bytes, and **step boundaries** (auto-delimited at
+  ``session.report()``). In a process that has imported jax a span is
+  also a ``jax.profiler.TraceAnnotation("ray_tpu/<name>")``: while a
+  profiler session is live it is an event on the host plane of the same
+  ``.xplane.pb`` as the device's operations, on that trace's clock;
+- **XLA compile events** (a ``jax.monitoring`` duration listener,
+  installed by ``init_session``) so compile storms are attributable in
+  the same timeline.
 
 Metrics-core discipline applies (see metrics_core.py): ``record_*`` is
 one module-global flag load + a tuple pack + a list store — no locks
 (GIL-atomic enough for telemetry; a torn write loses one record, never
 corrupts structure) — and the whole plane is flag-gated
 (``RAY_TPU_STEPTRACE_ENABLED=0`` / cfg ``steptrace_enabled``) so it
-costs nothing when off. The bench lane (BENCH_STEPTRACE_OVERHEAD=1)
-gates the calibrated recorder share of a tight collective loop <2% and
-asserts zero records when disabled.
+costs nothing when off.
 
 Timestamps are ``time.time()`` (wall): arrival-skew comparisons happen
 ACROSS processes, so the clocks must share an epoch — monotonic clocks
@@ -44,14 +48,15 @@ Chrome-trace/Perfetto JSON.
 from __future__ import annotations
 
 import os
+import sys
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
 __all__ = [
     "set_enabled", "is_enabled", "record_calls", "record_collective",
-    "record_phase", "record_compile", "step_mark", "phase",
+    "record_phase", "record_compile", "step_mark", "span", "phase",
     "set_train_context", "clear_train_context", "reset", "snapshot",
-    "process_snapshot", "trace_jit", "install_compile_listener",
+    "process_snapshot", "install_compile_listener",
     "merge_collectives", "merge_processes", "chrome_trace",
     "SkewAggregator", "SEQ_MOD",
 ]
@@ -65,8 +70,7 @@ SEQ_MOD = 1 << 32
 _enabled = os.environ.get("RAY_TPU_STEPTRACE_ENABLED", "1").lower() not in (
     "0", "false", "no")
 _explicit = False  # set_enabled() was called: runtime override wins
-# instrumentation event count (the bench lane's calibrated-cost x count
-# estimator multiplies this, same discipline as metrics_core._events)
+# records written since import (``record_calls``; stays put while off)
 _events = 0
 
 _RING_DEFAULT = 8192
@@ -115,8 +119,8 @@ def is_enabled() -> bool:
 
 
 def record_calls() -> int:
-    """Total record_* calls in this process since import (the overhead
-    lane's event count)."""
+    """Records written in this process since import (the ring's length
+    saturates once it is full; this does not)."""
     return _events
 
 
@@ -207,7 +211,10 @@ def record_chunk(group: str, seq: int, chunk: int, op: str, rank: int,
 
 
 def record_phase(name: str, start: float, end: float,
-                 step: Optional[int] = None, rank: Optional[int] = None):
+                 step: Optional[int] = None, rank: Optional[int] = None,
+                 n: Optional[int] = None):
+    """``n`` is the span's count (rows or bytes), taken at the same
+    boundary as its times."""
     global _events, _idx
     if not _enabled:
         return
@@ -217,7 +224,7 @@ def record_phase(name: str, start: float, end: float,
     _events += 1
     ring[_idx % _ring_size] = (
         "phase", _idx, _step if step is None else step, name,
-        _rank if rank is None else rank, start, end)
+        _rank if rank is None else rank, start, end, n)
     _idx += 1
 
 
@@ -294,70 +301,72 @@ def clear_train_context():
     _step_start = None
 
 
-class phase:
-    """Context manager recording one step-phase interval. Canonical
-    phases are "data", "h2d", "compute", "optimizer" (free-form strings
-    are accepted — the timeline renders whatever it gets)."""
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
 
-    __slots__ = ("name", "_t0")
 
-    def __init__(self, name: str):
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` if this process has imported jax,
+    else None. Never imports jax: the train driver records spans too and
+    must stay off it."""
+    global _annotation
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class span:
+    """Context manager recording one interval of the current step: the
+    ring record (kind ``phase``: step index, name, rank, start, end, and
+    the count ``n`` of rows or bytes, which may be set inside the block),
+    and in a process that holds jax a profiler annotation
+    ``ray_tpu/<name>`` over the same interval. The step index is what the
+    spans of one step share; nesting is by name (``data/fetch`` lies
+    inside ``data/next``). Names are free-form; the user's canonical step
+    phases are "data", "h2d", "compute", "optimizer"."""
+
+    __slots__ = ("name", "n", "_step", "_t0", "_ann")
+
+    def __init__(self, name: str, n: Optional[int] = None):
         self.name = name
-        self._t0 = 0.0
+        self.n = n
+        self._step = 0
+        self._t0 = None  # None: entered while recording was off
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = time.time()
+        if _enabled:
+            cls = _annotation or _annotation_class()
+            if cls is not None:
+                self._ann = cls("ray_tpu/" + self.name)
+                self._ann.__enter__()
+            self._step = _step  # a report inside the span moves _step on
+            self._t0 = time.time()
         return self
 
     def __exit__(self, *exc):
-        record_phase(self.name, self._t0, time.time())
+        if self._t0 is not None:
+            end = time.time()
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+            record_phase(self.name, self._t0, end, step=self._step,
+                         n=self.n)
         return False
 
 
+phase = span  # the name train.step_phase and older callers use
+
+
 # ---------------------------------------------------------------------------
-# compile-event hooks
+# compile-event hook
 # ---------------------------------------------------------------------------
-
-def trace_jit(fn, name: Optional[str] = None):
-    """Wrap a jitted callable so cache growth during a call is recorded
-    as a compile event (first call vs recompile): jax compiles lazily at
-    call time, so a call that grows ``fn._cache_size()`` spent its wall
-    time tracing+compiling. Works on any object exposing ``_cache_size``
-    (jax.jit since 0.4); silently degrades to a passthrough otherwise."""
-    import functools
-
-    label = name or getattr(fn, "__name__", None) or "jit"
-    cache_size = getattr(fn, "_cache_size", None)
-
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        if not _enabled or cache_size is None:
-            return fn(*args, **kwargs)
-        try:
-            before = cache_size()
-        except Exception:
-            return fn(*args, **kwargs)
-        t0 = time.time()
-        out = fn(*args, **kwargs)
-        try:
-            after = cache_size()
-        except Exception:
-            return out
-        if after > before:
-            record_compile(label, t0, time.time(), first=(before == 0))
-        return out
-
-    return wrapped
-
 
 _compile_listener_installed = False
 
 
 def install_compile_listener():
     """Register a ``jax.monitoring`` duration listener mirroring backend
-    compile events into the ring (global compile storms show up even for
-    jitted fns nobody wrapped in ``trace_jit``). Idempotent; a missing /
-    old jax degrades to a no-op."""
+    compile events into the ring (compile storms show up whoever jitted
+    the function). Idempotent; a missing / old jax degrades to a no-op."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return
@@ -415,7 +424,7 @@ def snapshot() -> List[dict]:
         elif kind == "phase":
             out.append({"kind": "phase", "idx": rec[1], "step": rec[2],
                         "phase": rec[3], "rank": rec[4], "start": rec[5],
-                        "end": rec[6]})
+                        "end": rec[6], "n": rec[7]})
         elif kind == "step":
             out.append({"kind": "step", "idx": rec[1], "step": rec[2],
                         "rank": rec[3], "start": rec[4], "end": rec[5]})
@@ -601,7 +610,8 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
             "ts": rec["start"] * 1e6,
             "dur": max((rec["end"] - rec["start"]) * 1e6, 1.0),
             "pid": rec["rank"], "tid": "phases",
-            "args": {"step": rec["step"]},
+            "args": ({"step": rec["step"]} if rec.get("n") is None
+                     else {"step": rec["step"], "n": rec["n"]}),
         })
     for row in merged.get("collectives", ()):
         for rank, v in sorted(row["ranks"].items()):

@@ -17,6 +17,8 @@ import tempfile
 import uuid
 from typing import Any, Dict, Optional
 
+from ray_tpu._private import steptrace
+
 _DICT_FILE = "checkpoint_dict.pkl"
 
 
@@ -88,22 +90,43 @@ class Checkpoint:
 
 
 def save_pytree(tree, directory: str, name: str = "params"):
-    """Checkpoint a JAX pytree (orbax if available, msgpack fallback)."""
-    os.makedirs(directory, exist_ok=True)
-    target = os.path.join(directory, name)
-    try:
-        import orbax.checkpoint as ocp
+    """Checkpoint a JAX pytree (orbax if available, msgpack fallback).
 
-        ckptr = ocp.StandardCheckpointer()
-        ckptr.save(os.path.abspath(target) + "_orbax", tree, force=True)
-        ckptr.wait_until_finished()
-        return
-    except Exception:
-        pass
+    Step observatory: three spans, bytes of the tree as their count.
+    ``ckpt/setup`` is what every call pays before a byte moves,
+    ``ckpt/snapshot`` the copy off the device (orbax: ``save()`` returns
+    once the tree is on the host and a thread has the write),
+    ``ckpt/commit`` the wait for the files."""
+    import jax
+
+    with steptrace.span("ckpt/setup"):
+        os.makedirs(directory, exist_ok=True)
+        target = os.path.join(directory, name)
+        nbytes = sum(getattr(leaf, "nbytes", 0)
+                     for leaf in jax.tree_util.tree_leaves(tree))
+        try:
+            import orbax.checkpoint as ocp
+
+            ckptr = ocp.StandardCheckpointer()
+        except Exception:
+            ckptr = None
+    if ckptr is not None:
+        try:
+            with steptrace.span("ckpt/snapshot", nbytes):
+                ckptr.save(os.path.abspath(target) + "_orbax", tree,
+                           force=True)
+            with steptrace.span("ckpt/commit", nbytes):
+                ckptr.wait_until_finished()
+            return
+        except Exception:
+            pass
     from flax import serialization
 
-    with open(target + ".msgpack", "wb") as f:
-        f.write(serialization.to_bytes(tree))
+    with steptrace.span("ckpt/snapshot", nbytes):
+        data = serialization.to_bytes(tree)
+    with steptrace.span("ckpt/commit", len(data)):
+        with open(target + ".msgpack", "wb") as f:
+            f.write(data)
 
 
 def load_pytree(directory: str, target, name: str = "params"):

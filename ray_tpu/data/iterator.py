@@ -14,6 +14,7 @@ from typing import Any, Iterator, List, Optional
 import numpy as np
 import pyarrow as pa
 
+from ray_tpu._private import steptrace
 from ray_tpu.data.block import BlockAccessor, VALUE_COL, concat_blocks
 
 
@@ -28,7 +29,28 @@ def iter_batches_over(bundles, *, batch_size: Optional[int],
                       shuffle_buffer_size: Optional[int] = None,
                       shuffle_seed: Optional[int] = None) -> Iterator[Any]:
     """Re-batch a stream of (ref, meta) into fixed-size batches, carrying
-    remainders across block boundaries (the reference's batcher)."""
+    remainders across block boundaries (the reference's batcher).
+
+    Step observatory: every ``next()`` of the consumer is one ``data/next``
+    span, from the call to the batch's return (not the consumer's own time
+    between calls), rows as its count; the call that finds the stream
+    drained records one too, with 0 rows. The time inside it that is spent
+    blocked on the object plane is a ``data/fetch`` span per block."""
+    batches = _rebatch(bundles, batch_size, batch_format, drop_last,
+                       shuffle_buffer_size, shuffle_seed)
+    while True:
+        with steptrace.span("data/next", 0) as sp:
+            item = next(batches, None)
+            if item is not None:
+                sp.n, batch = item
+        if item is None:
+            return
+        yield batch
+
+
+def _rebatch(bundles, batch_size, batch_format, drop_last,
+             shuffle_buffer_size, shuffle_seed) -> Iterator[tuple]:
+    """-> (rows, batch) for each batch of ``iter_batches_over``."""
     import ray_tpu
 
     rng = np.random.default_rng(shuffle_seed)
@@ -37,7 +59,9 @@ def iter_batches_over(bundles, *, batch_size: Optional[int],
 
     def blocks():
         for ref, _m in bundles:
-            b = ray_tpu.get(ref)
+            with steptrace.span("data/fetch") as sp:
+                b = ray_tpu.get(ref)
+                sp.n = b.num_rows
             if b.num_rows:
                 yield b
 
@@ -51,7 +75,7 @@ def iter_batches_over(bundles, *, batch_size: Optional[int],
 
     if batch_size is None:
         for b in source:
-            yield _emit(b, batch_format)
+            yield b.num_rows, _emit(b, batch_format)
         return
 
     for block in source:
@@ -61,11 +85,11 @@ def iter_batches_over(bundles, *, batch_size: Optional[int],
             merged = concat_blocks(carry)
             head = merged.slice(0, batch_size)
             tail = merged.slice(batch_size)
-            yield _emit(head, batch_format)
+            yield batch_size, _emit(head, batch_format)
             carry = [tail] if tail.num_rows else []
             carry_rows = tail.num_rows
     if carry_rows and not drop_last:
-        yield _emit(concat_blocks(carry), batch_format)
+        yield carry_rows, _emit(concat_blocks(carry), batch_format)
 
 
 class DataIterator:
@@ -246,9 +270,14 @@ class _SplitStream:
 
         self._epoch += 1
         while True:
-            item = ray_tpu.get(
-                self._coord.next.remote(self._idx, self._epoch)
-            )
+            # blocked on the split coordinator: the wait for its pump and
+            # one actor round trip (0 rows: the epoch is over)
+            with steptrace.span("data/fetch", 0) as sp:
+                item = ray_tpu.get(
+                    self._coord.next.remote(self._idx, self._epoch)
+                )
+                if item is not None:
+                    sp.n = item[1].num_rows
             if item is None:
                 return
             yield item

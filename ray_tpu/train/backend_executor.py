@@ -371,7 +371,10 @@ class BackendExecutor:
         ck_data = res.get("checkpoint_data")
         ck_path = res.get("checkpoint_path")
         if ck_data is not None or ck_path is not None:
-            self._ckpts.persist(ck_data, ck_path, metrics)
+            # the copy into the trial directory runs here in the driver
+            # while the worker trains on: in the timeline, in no metric
+            with steptrace.span("ckpt/persist"):
+                self._ckpts.persist(ck_data, ck_path, metrics)
         if rank == 0 and result_callback:
             result_callback(metrics, self._ckpts.latest())
 

@@ -8,7 +8,8 @@ queue polled by the BackendExecutor on the driver.
 Step observatory hooks (_private/steptrace.py): ``init_session`` stamps
 the worker's rank/world onto the process steptrace context,
 ``step_phase("data"|"h2d"|"compute"|"optimizer")`` records intra-step
-phase intervals, and every ``report()`` auto-delimits a step boundary —
+phase intervals, and every ``report()`` auto-delimits a step boundary
+(and records itself as the span ``train/report``) —
 so a multi-rank trainer gets a merged per-step timeline
 (``util.state.train_timeline()``) without any explicit instrumentation
 beyond its existing report loop.
@@ -99,10 +100,10 @@ def init_session(ctx: TrainContext, loaded_checkpoint: Optional[Checkpoint]) -> 
     with _lock:
         _session = _Session(ctx, loaded_checkpoint)
         _session.overlap_grads = _overlap_default
-    # steptrace records (phases, step boundaries, compiles) carry this
+    # steptrace records (spans, step boundaries, compiles) carry this
     # worker's rank from here on; step 0 starts now. The jax.monitoring
     # listener mirrors backend compile events into the ring so compile
-    # storms show up even for jitted fns nobody wrapped in trace_jit.
+    # storms show up in the same timeline.
     steptrace.set_train_context(ctx.get_world_rank(), ctx.get_world_size())
     steptrace.install_compile_listener()
     return _session
@@ -152,25 +153,29 @@ def report(metrics: Dict[str, Any], *, checkpoint: Optional[Checkpoint] = None):
     if s is None:
         return metrics
     # step observatory: a report IS the natural step boundary — close the
-    # current step interval and open the next (steptrace no-ops when off)
-    steptrace.step_mark()
-    s.step_count += 1
-    s.last_progress = time.monotonic()
-    payload = {"type": "report", "metrics": dict(metrics)}
-    if checkpoint is not None:
-        # Materialize to a directory so the driver (possibly another node)
-        # persists it from shared storage; in-memory dicts ride the queue.
-        payload["checkpoint_data"] = (
-            checkpoint._data if checkpoint._data is not None else None
-        )
-        payload["checkpoint_path"] = checkpoint._path
-    draining = s.drain_requested.is_set()
-    if draining:
-        # spot preemption: this report is the step boundary the drain was
-        # waiting for — tag it so the executor requeues WITHOUT burning a
-        # failure-budget slot, then exit the loop cleanly
-        payload["drain"] = True
-    s.queue.put(payload)
+    # current step interval and open the next (steptrace no-ops when off).
+    # The span covers what the train loop waits for here: step mark,
+    # payload, queue.put.
+    with steptrace.span("train/report"):
+        steptrace.step_mark()
+        s.step_count += 1
+        s.last_progress = time.monotonic()
+        payload = {"type": "report", "metrics": dict(metrics)}
+        if checkpoint is not None:
+            # Materialize to a directory so the driver (possibly another
+            # node) persists it from shared storage; in-memory dicts ride
+            # the queue.
+            payload["checkpoint_data"] = (
+                checkpoint._data if checkpoint._data is not None else None
+            )
+            payload["checkpoint_path"] = checkpoint._path
+        draining = s.drain_requested.is_set()
+        if draining:
+            # spot preemption: this report is the step boundary the drain
+            # was waiting for — tag it so the executor requeues WITHOUT
+            # burning a failure-budget slot, then exit the loop cleanly
+            payload["drain"] = True
+        s.queue.put(payload)
     if draining:
         raise SystemExit("drain requested (preemption)")
     if s.stop_requested.is_set():
@@ -192,7 +197,7 @@ def step_phase(name: str):
             params, opt_state, loss = step(params, opt_state, batch)
         train.report({"loss": float(loss)})   # step boundary
     """
-    return steptrace.phase(name)
+    return steptrace.span(name)
 
 
 def set_overlap_grads(enabled: bool) -> bool:

@@ -131,6 +131,7 @@ def _isolate_self_managed_modules(request):
     fixtures, custom env vars) must not inherit a live shared cluster —
     their init would collide with the existing driver connection."""
     import inspect
+    import re
 
     try:
         src = inspect.getsource(request.module)
@@ -138,9 +139,10 @@ def _isolate_self_managed_modules(request):
         src = ""
     overrides_fixture = ("def ray_start_regular" in src
                          or "def ray_start_cluster" in src)
-    uses_conftest_fixture = (not overrides_fixture
-                             and ("ray_start_regular" in src
-                                  or "ray_start_cluster" in src))
+    # whole names: ray_start_regular_fn tears the shared cluster down
+    # itself and says nothing about the module's other tests
+    uses_conftest_fixture = (not overrides_fixture and re.search(
+        r"\bray_start_(regular|cluster)\b", src) is not None)
     inits_itself = "ray_tpu.init(" in src or "Cluster(" in src
     if (overrides_fixture or inits_itself) and not uses_conftest_fixture:
         _teardown_shared()

@@ -6,7 +6,7 @@ multi-rank train timeline.
 Fast deterministic tests (tier-1 under the ``steptrace`` marker): ring
 bounds + disabled-zero-cost, the merge/skew math (missing ranks,
 out-of-order arrival, duplicates, seq wraparound), step_phase/report
-step delimiting, trace_jit compile attribution, SkewAggregator
+step delimiting, SkewAggregator
 idempotent folds + EWMA straggler scores, the chrome-trace renderer, the
 one-tick unattributed-line hold in the raylet tailer, and an e2e
 2-worker JaxTrainer run whose merged timeline carries both ranks' step
@@ -15,6 +15,7 @@ visible on the cluster scrape afterwards).
 """
 
 import json
+import os
 import time
 
 import numpy as np
@@ -367,24 +368,6 @@ def test_aggregator_log_survives_dead_processes():
 
 
 # ---------------------------------------------------------------------------
-# compile attribution
-# ---------------------------------------------------------------------------
-
-def test_trace_jit_records_first_call_and_recompile():
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    fn = steptrace.trace_jit(jax.jit(lambda x: x * 2), name="double")
-    fn(jnp.ones((4,)))          # first call: compile
-    fn(jnp.ones((4,)))          # cache hit: no event
-    fn(jnp.ones((8,)))          # new shape: recompile
-    recs = [r for r in steptrace.snapshot() if r["kind"] == "compile"]
-    assert [(r["name"], r["first"]) for r in recs] == [
-        ("double", True), ("double", False)]
-    assert all(r["end"] >= r["start"] for r in recs)
-
-
-# ---------------------------------------------------------------------------
 # raylet tailer: one-tick hold beats the actor-class fallback prefix
 # ---------------------------------------------------------------------------
 
@@ -537,7 +520,10 @@ def test_jax_trainer_train_timeline_e2e(ray_start_regular, tmp_path):
             g = col.allreduce(g, "obs_e2e")
             with train_mod.step_phase("optimizer"):
                 _ = g / world
-            train_mod.report({"step": step, "rank": rank})
+            train_mod.report(
+                {"step": step, "rank": rank},
+                checkpoint=train_mod.Checkpoint.from_dict({"step": step})
+                if step == 2 else None)
 
     trainer = train.JaxTrainer(
         loop,
@@ -556,7 +542,13 @@ def test_jax_trainer_train_timeline_e2e(ray_start_regular, tmp_path):
     phases = merged["phases"]
     for rank in (0, 1):
         mine = {p["phase"] for p in phases if p["rank"] == rank}
-        assert {"data", "compute", "optimizer"} <= mine, (rank, phases)
+        # the user's phases and the runtime's own span of each report
+        assert {"data", "compute", "optimizer", "train/report"} <= mine, (
+            rank, phases)
+    # the driver's copy of the reported checkpoint, from the driver's ring
+    persists = [p for p in phases if p["phase"] == "ckpt/persist"]
+    assert persists and all(p["pid"] == os.getpid() for p in persists), (
+        persists)
     steps = merged["steps"]
     assert {s["rank"] for s in steps} == {0, 1}
     assert max(s["step"] for s in steps) >= 2
