@@ -32,7 +32,11 @@ class GPT2Config:
     dropout: float = 0.0
     dtype: Any = jnp.bfloat16
     remat: bool = False
-    # "auto": jax.nn.dot_product_attention (fused flash on TPU backends);
+    # "auto": by backend and shape (``auto_attention``): the "flash" path
+    # where it was measured faster than XLA's, else the "xla" path;
+    # "xla": jax.nn.dot_product_attention — on this runtime plain XLA
+    # fusions that write the [B, H, T, T] scores to HBM, forward and saved
+    # for backward, and no kernel;
     # "flash": ray_tpu.ops Pallas/scan flash kernel;
     # "ring": sequence-parallel ring attention — the model must run inside
     # shard_map with mesh axis ``sp_axis`` sharding the sequence dim
@@ -63,6 +67,44 @@ class GPT2Config:
         return wte + wpe + self.n_layer * block + 2 * self.n_embd
 
 
+# Where "auto" takes the Pallas kernel: where it was measured faster than
+# XLA's attention on a v5e, forward plus backward at 16,384 tokens a call
+# (PERF.md section 6, PR 25). Head dimension 64: every multiple of 128 tried
+# from 512 to 2048 (512, 640, 768, 896, 1024, 1152, 1280, 1536, 2048: 2.4x
+# to 4.9x; at 256 and 384 XLA wins), where one grid step holds a whole
+# head, and 3072, 4096 and 8192 (3.3x, 5.2x, 47x), where it holds 1536 or
+# 2048 queries and keys. Past 2048 a length that 1024 does not divide can
+# leave the kernel 128-wide grid blocks (2176 = 17 x 128: 20.9 ms against
+# XLA's 17.3), so those stay with XLA. Head dimension 128: 512, 768, 1024,
+# 2048, 4096 (2.4x to 4.3x). The multiples of 128 between those lengths
+# are interpolated, lengths past 8192 extrapolated (XLA's [T, T] scores
+# take 718 ms a layer at 8192 and no longer fit at 16,384).
+_FLASH_MIN_SEQ = 512
+_FLASH_WHOLE_HEAD_SEQ = 2048  # ops.attention._MAX_RESIDENT
+_FLASH_HEAD_DIMS = (64, 128)
+
+
+def auto_attention(q) -> str:
+    """What ``attention="auto"`` runs for causal self-attention of ``q``
+    [B, T, H, d] on the default backend: "flash" or "xla". Decided from the
+    backend and from ``q``'s type alone, its shape and the mesh it is
+    traced under: a mesh axis the kernel's ``shard_map`` wrapper does not
+    map (``model`` under tensor parallelism, ``seq``, ``expert``) would
+    leave the Mosaic call to the partitioner, which refuses it, so there
+    "auto" stays on XLA's attention as it was before the kernel was chosen
+    anywhere (ROADMAP 8a). A kernel that then fails to lower raises."""
+    from ray_tpu.ops.attention import unmapped_mesh_axes
+
+    _, seq_len, _, head_dim = q.shape
+    measured = (head_dim in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
+                and seq_len % 128 == 0
+                and (seq_len <= _FLASH_WHOLE_HEAD_SEQ or seq_len % 1024 == 0))
+    if (jax.default_backend() == "tpu" and measured
+            and not unmapped_mesh_axes(q)):
+        return "flash"
+    return "xla"
+
+
 class CausalSelfAttention(nn.Module):
     config: GPT2Config
 
@@ -76,24 +118,29 @@ class CausalSelfAttention(nn.Module):
         q = q.reshape(B, T, heads, C // heads)
         k = k.reshape(B, T, heads, C // heads)
         v = v.reshape(B, T, heads, C // heads)
-        if c.attention == "ring":
+        attention = c.attention
+        if attention == "auto":
+            attention = auto_attention(q)
+        if attention == "ring":
             from ray_tpu.ops import ring_attention
 
             bhsd = lambda t: t.transpose(0, 2, 1, 3)
             y = ring_attention(
                 bhsd(q), bhsd(k), bhsd(v), axis_name=c.sp_axis, causal=True
             ).transpose(0, 2, 1, 3)
-        elif c.attention == "flash":
+        elif attention == "flash":
             from ray_tpu.ops import flash_attention
 
             bhsd = lambda t: t.transpose(0, 2, 1, 3)
             y = flash_attention(
                 bhsd(q), bhsd(k), bhsd(v), causal=True
             ).transpose(0, 2, 1, 3)
-        else:
-            # jax.nn.dot_product_attention lowers to fused (splash/flash)
-            # attention on TPU backends.
+        elif attention == "xla":
             y = jax.nn.dot_product_attention(q, k, v, is_causal=True)
+        else:
+            raise ValueError(
+                f"attention={c.attention!r}: expected auto, xla, flash or "
+                "ring")
         y = y.reshape(B, T, C)
         return nn.Dense(C, dtype=c.dtype, name="c_proj")(y)
 

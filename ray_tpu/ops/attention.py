@@ -6,13 +6,15 @@ are absent in-repo and arrive via external stacks run on Ray). Here they are
 first-class ops:
 
 - ``flash_attention``: O(seq) memory online-softmax attention. On TPU it runs
-  a Pallas kernel tiled for the MXU (q blocks x kv blocks, accumulators in
-  VMEM); elsewhere it runs a numerically identical ``lax.scan`` formulation,
-  so tests validate the same math on CPU.
+  a Pallas kernel tiled for the MXU (tiles of queries x keys, accumulators
+  in VMEM, tile sizes chosen from the sequence lengths); elsewhere it runs a
+  numerically identical ``lax.scan`` formulation, so tests validate the
+  same math on CPU.
 - ``attention_reference``: naive full-matrix attention for numerics tests.
 
 All paths are differentiable: the fallback natively, the Pallas path via
-custom VJP (recompute-based backward using the same online-softmax blocks).
+custom VJP (one backward kernel that recomputes the probabilities from
+q, k and the saved logsumexp).
 """
 
 from __future__ import annotations
@@ -29,9 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
 NEG_INF = -1e30
-# lse/delta side tensors are stored lane-broadcast (last dim = one 128-lane
-# register row) so their Pallas blocks satisfy the TPU (8, 128) tiling rule
-_LANES = 128
 
 
 def attention_reference(q, k, v, *, causal: bool = False,
@@ -132,276 +131,372 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
 # Pallas TPU kernels
 # ----------------------------------------------------------------------
 #
-# Grid-streamed K/V: the kv-block axis is the innermost ("arbitrary") grid
-# dimension, so only one (block_k, d) K/V tile is resident in VMEM at a
-# time — sequence length is bounded by HBM, not VMEM (the r1 kernel loaded
-# the full K/V per q-block, capping seq length). The forward also emits the
-# per-row logsumexp so the backward is real Pallas kernels (dq and dk/dv)
-# instead of a scan-recompute VJP.
+# Scores are computed transposed, s^T = K Q^T of shape (keys, queries): the
+# queries lie along the 128 lanes. The softmax statistics (running max, sum,
+# lse, delta) are then row vectors (1, queries) that broadcast along
+# sublanes, the accumulators are (d, queries) and fill whole registers at
+# d = 64, and every matmul is NN or NT on operands as they arrive — given
+# V^T (forward) and K^T (backward) from XLA, which also turns O^T and dQ^T
+# back. Operands go to the MXU in the inputs' dtype and accumulate in
+# float32; the statistics and accumulators are float32.
+#
+# One grid step holds up to ``_MAX_RESIDENT`` queries and as many keys (a
+# whole head at GPT-2's 1024) and computes on tiles of ``block_q`` queries
+# by ``block_k`` keys. Under a causal mask a tile row ends at the diagonal
+# and only the tiles the diagonal crosses are masked. Where one grid step
+# holds the whole sequence, which tiles are live is known when the kernel
+# is traced and the walk over them is straight-line code; where it does
+# not, the walk is a loop with bounds computed from the grid position, and
+# the index maps clamp dead grid blocks to the last live one, which the
+# pipeline does not fetch again.
+#
+# Who reaches which walk (my chip runs, PR 25; PERF.md section 6): the
+# straight-line walk is every call up to 2048 tokens, GPT-2's 1024 in both
+# benchmark cells among them; the loop is any longer sequence, which no cell
+# sends yet and ``attention="auto"`` takes at 3072, 4096 and 8192 (3.3x to
+# 47x faster than XLA's attention there, forward plus backward). With the
+# loop alone, forward plus backward at 1024 take 17% longer (2.53 against
+# 2.16 ms a layer; the forward reads the same). ``fori_loop(...,
+# unroll=True)`` on the static bounds reads as the Python loop does (2.165)
+# and would still have to tell static bounds from traced ones.
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+_MAX_RESIDENT = 2048
+# (block_q, block_k) targets: the fastest measured for each kernel alone on
+# a v5e at (192, 1024, 64) bf16 causal (PERF.md, PR 25). Both kernels sit
+# near what the MXU allows at d = 64, half of whose depth a pass fills; the
+# backward's five matmuls pay more for the dead half of a diagonal tile
+# than for the loop steps that smaller tiles add.
+_FWD_TILES, _BWD_TILES = (512, 512), (256, 256)
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scale_folds(dtype, sm_scale: float) -> bool:
+    """Whether q * sm_scale is exact enough to take the place of scaling
+    every score: always in float32, in a narrower dtype only for a power
+    of two (d = 64: 1/8)."""
+    return dtype == jnp.float32 or math.frexp(sm_scale)[0] == 0.5
+
+
+def _clamp(x, hi: int):
+    """x held to [0, hi]: a Python int stays one."""
+    if isinstance(x, int):
+        return min(max(x, 0), hi)
+    return jnp.clip(x, 0, hi)
+
+
+def _live_tiles(rel, block_q: int, block_k: int, n_tiles: int, causal: bool):
+    """(n_full, n_live): of the resident keys' ``n_tiles`` tiles, the first
+    n_full are seen whole by every query of a q tile and those up to n_live
+    by some. ``rel`` is the q tile's first row less the first resident key,
+    both in key positions (a Python int where the grid does not move it)."""
+    if not causal:
+        return n_tiles, n_tiles
+    return (_clamp((rel + 1) // block_k, n_tiles),
+            _clamp((rel + block_q - 1 + block_k) // block_k, n_tiles))
+
+
+def _tile(c, size: int, n_tiles: int):
+    """Slice of tile ``c`` among ``n_tiles`` of ``size``; static where
+    ``c`` is, and for a lone tile, whose size need not fill a hardware tile."""
+    if n_tiles == 1:
+        c = 0
+    if isinstance(c, int):
+        return pl.ds(c * size, size)
+    return pl.ds(pl.multiple_of(c * size, size), size)
+
+
+def _scaled(q, sm_scale: float, fold: bool):
+    return (q.astype(jnp.float32) * sm_scale).astype(q.dtype) if fold else q
+
+
+def _scores(k, q, c, *, sm_scale: float, fold: bool, masked: bool,
+            block_k: int, rel):
+    """s^T (block_k, block_q) of key tile ``c``, keys along sublanes: scaled
+    here unless q came scaled, and masked where the diagonal crosses."""
+    s = _dot(k, q, _NT)
+    if not fold:
+        s = s * sm_scale
+    if masked:
+        # query position less key position, within the tile and then overall
+        ahead = (lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                 - lax.broadcasted_iota(jnp.int32, s.shape, 0))
+        s = jnp.where(ahead >= c * block_k - rel, s, NEG_INF)
+    return s
+
+
+def _walk(step, carry, n_full, n_live):
+    """Fold ``step(c, carry, masked)`` over the live key tiles: unmasked up
+    to n_full, masked from there to n_live. Static bounds unroll."""
+    if isinstance(n_full, int) and isinstance(n_live, int):
+        for c in range(n_live):
+            carry = step(c, carry, c >= n_full)
+        return carry
+    carry = lax.fori_loop(0, n_full, lambda c, x: step(c, x, False), carry)
+    return lax.fori_loop(n_full, n_live, lambda c, x: step(c, x, True), carry)
+
+
+def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                q_len: int, k_len: int):
+                offset: int, static: bool):
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
-    q_offset = qi * block_q + (k_len - q_len)
-    k_offset = ki * block_k
+    res_q, res_k = q_ref.shape[0], k_ref.shape[0]
+    n_q, n_k = res_q // block_q, res_k // block_k
+    rel0 = offset if static else qi * res_q + offset - ki * res_k
+    fold = _scale_folds(q_ref.dtype, sm_scale)
+    scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
+                               block_k=block_k)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr[...], -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr[...])
-        acc_scr[...] = jnp.zeros_like(acc_scr[...])
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # a causal block is live unless every row is above the diagonal
-    live = (q_offset + block_q - 1 >= k_offset) if causal else True
+    for j in range(n_q):
+        cols = _tile(j, block_q, n_q)
+        rel = rel0 + j * block_q
+        q = _scaled(q_ref[cols, :], sm_scale, fold)
 
-    @pl.when(live)
-    def _body():
-        q = q_ref[...].astype(jnp.float32) * sm_scale
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            rows = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + q_offset >= cols + k_offset, s, NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        safe_m = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        p = jnp.exp(s - safe_m)
-        p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-        alpha = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - safe_m), 0.0)
-        m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
+        def step(c, carry, masked):
+            m, l, acc = carry
+            rows = _tile(c, block_k, n_k)
+            s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+            m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+            # a query with no live key yet keeps m at NEG_INF: exponentiate
+            # against 0 so that its masked scores give p == 0, not exp(0)
+            m_exp = (jnp.where(m_new > NEG_INF / 2, m_new, 0.0) if masked
+                     else m_new)
+            p = jnp.exp(s - m_exp)
+            alpha = jnp.exp(m - m_exp)
+            l = l * alpha + p.sum(axis=0, keepdims=True)
+            acc = acc * alpha + _dot(vt_ref[:, rows], p.astype(vt_ref.dtype),
+                                     _NN)
+            return m_new, l, acc
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        l = l_scr[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        m = m_scr[...]
-        # rows with no live columns get lse=+inf => p == 0 in the backward.
-        # lse is stored lane-broadcast as (block_q, LANES): a (block_q,)
-        # vector output would need a (1, block_q) block, which violates the
-        # TPU (8, 128) tiling rule once the batch dim is squeezed.
-        lse = jnp.where(
-            l == 0.0, jnp.inf,
-            jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe),
-        )
-        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
+        m, l, acc = _walk(
+            step, (m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]),
+            *_live_tiles(rel, block_q, block_k, n_k, causal))
+        m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = m, l, acc
+
+        @pl.when(ki == nk - 1)
+        def _finalize():
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            ot_ref[:, cols] = (acc / l_safe).astype(ot_ref.dtype)
+            # queries with no live key get lse=+inf => p == 0 in the backward
+            lse_ref[:, cols] = jnp.where(
+                l == 0.0, jnp.inf,
+                jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale: float, causal: bool, block_q: int,
-                   block_k: int, q_len: int, k_len: int):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_offset = qi * block_q + (k_len - q_len)
-    k_offset = ki * block_k
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr[...])
-
-    live = (q_offset + block_q - 1 >= k_offset) if causal else True
-
-    @pl.when(live)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
-        lse = lse_ref[...][:, 0:1]
-        delta = delta_ref[...][:, 0:1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + q_offset >= cols + k_offset, s, NEG_INF)
-        p = jnp.exp(s - lse)  # normalized probs; lse=+inf rows -> 0
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[...] += sm_scale * jnp.dot(
-            ds, k, preferred_element_type=jnp.float32
-        )
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                    causal: bool, block_q: int, block_k: int, q_len: int,
-                    k_len: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
+                dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
+                causal: bool, block_q: int, block_k: int, offset: int,
+                static: bool):
+    """dQ^T of this (resident keys, resident queries) pair, and dK, dV
+    accumulated over the queries: s and p are recomputed once for all
+    three."""
     ki, qi = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
-    q_offset = qi * block_q + (k_len - q_len)
-    k_offset = ki * block_k
+    res_q, res_k = q_ref.shape[0], k_ref.shape[0]
+    n_q, n_k = res_q // block_q, res_k // block_k
+    rel0 = offset if static else qi * res_q + offset - ki * res_k
+    fold = _scale_folds(q_ref.dtype, sm_scale)
+    scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
+                               block_k=block_k)
 
     @pl.when(qi == 0)
     def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr[...])
-        dv_scr[...] = jnp.zeros_like(dv_scr[...])
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (q_offset + block_q - 1 >= k_offset) if causal else True
+    for j in range(n_q):
+        cols = _tile(j, block_q, n_q)
+        rel = rel0 + j * block_q
+        q, do = _scaled(q_ref[cols, :], sm_scale, fold), do_ref[cols, :]
+        lse, delta = lse_ref[:, cols], delta_ref[:, cols]  # (1, block_q)
 
-    @pl.when(live)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
-        lse = lse_ref[...][:, 0:1]
-        delta = delta_ref[...][:, 0:1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + q_offset >= cols + k_offset, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_scr[...] += sm_scale * jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32
-        )
+        def step(c, dqt, masked):
+            rows = _tile(c, block_k, n_k)
+            s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+            p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
+            dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
+            dp = _dot(v_ref[rows, :], do, _NT)
+            ds = (p * (dp - delta)).astype(q.dtype)
+            dk_scr[rows, :] += _dot(ds, q, _NN)
+            return dqt + _dot(kt_ref[:, rows], ds, _NN)  # (d, block_q)
+
+        dqt = _walk(step, jnp.zeros((dqt_ref.shape[0], block_q), jnp.float32),
+                    *_live_tiles(rel, block_q, block_k, n_k, causal))
+        dqt_ref[:, cols] = (dqt * sm_scale).astype(dqt_ref.dtype)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dk = dk_scr[...]
+        dk_ref[...] = (dk if fold else dk * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _compiler_params(interpret: bool, n_arbitrary: int = 1):
+def _largest_block(n: int, target: int, align: int) -> int:
+    """The largest multiple of ``align`` up to ``target`` that divides n,
+    or n itself (a block may always span its whole dimension)."""
+    for b in range(min(target, n) // align * align, 0, -align):
+        if n % b == 0:
+            return b
+    return n
+
+
+def _block_sizes(q_len: int, k_len: int, block_q: Optional[int],
+                 block_k: Optional[int], targets):
+    """(block_q, block_k, resident queries, resident keys) for a call.
+    Queries and keys both lie along lanes somewhere, so a tile size the
+    caller does not give is a multiple of 128 up to the kernel's target,
+    or the whole length."""
+    block_q = min(block_q or _largest_block(q_len, targets[0], 128), q_len)
+    block_k = min(block_k or _largest_block(k_len, targets[1], 128), k_len)
+    assert q_len % block_q == 0, (q_len, block_q)
+    assert k_len % block_k == 0, (k_len, block_k)
+    return (block_q, block_k,
+            _largest_block(q_len, max(_MAX_RESIDENT, block_q), block_q),
+            _largest_block(k_len, max(_MAX_RESIDENT, block_k), block_k))
+
+
+def _compiler_params(interpret: bool):
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel")
-        + ("arbitrary",) * n_arbitrary
-    )
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _last_live_k(qi, res_q: int, res_k: int, offset: int, nk: int):
+    """Index of the last block of resident keys that any query of block
+    ``qi`` sees."""
+    return jnp.clip((qi * res_q + offset + res_q - 1) // res_k, 0, nk - 1)
+
+
+def _first_live_q(ki, res_q: int, res_k: int, offset: int, nq: int):
+    """Index of the first block of resident queries that sees any key of
+    block ``ki``."""
+    return jnp.clip((ki * res_k - offset) // res_q, 0, nq - 1)
 
 
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
-                  block_q: int, block_k: int, interpret: bool):
-    """q,k,v: (B, S, D) with batch*heads folded into B. -> (out, lse)."""
+                  block_q: Optional[int], block_k: Optional[int],
+                  interpret: bool):
+    """q,k,v: (B, S, D) with batch*heads folded into B. -> (out, lse) with
+    lse (B, 1, S) float32."""
     b, q_len, d = q.shape
     k_len = k.shape[1]
-    block_q = min(block_q, q_len)
-    block_k = min(block_k, k_len)
-    assert q_len % block_q == 0, (q_len, block_q)
-    assert k_len % block_k == 0, (k_len, block_k)
+    block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
+                                                  block_k, _FWD_TILES)
+    nq, nk = q_len // res_q, k_len // res_k
+    offset = k_len - q_len
 
-    grid = (b, q_len // block_q, k_len // block_k)
+    if causal and nk > 1:
+        kmap = lambda qi, ki: jnp.minimum(
+            ki, _last_live_k(qi, res_q, res_k, offset, nk))
+    else:
+        kmap = lambda qi, ki: ki
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, q_len=q_len, k_len=k_len,
-    )
-    return pl.pallas_call(
+        block_k=block_k, offset=offset, static=nq == nk == 1)
+    out_t, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, nq, nk),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bi, qi, ki: (bi, qi, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bi, qi, ki: (bi, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bi, qi, ki: (bi, ki, 0)),
+            pl.BlockSpec((None, res_q, d), lambda bi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((None, res_k, d),
+                         lambda bi, qi, ki: (bi, kmap(qi, ki), 0)),
+            pl.BlockSpec((None, d, res_k),
+                         lambda bi, qi, ki: (bi, 0, kmap(qi, ki))),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bi, qi, ki: (bi, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda bi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((None, d, res_q), lambda bi, qi, ki: (bi, 0, qi)),
+            pl.BlockSpec((None, 1, res_q), lambda bi, qi, ki: (bi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, q_len, d), q.dtype),
-            jax.ShapeDtypeStruct((b, q_len, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, d, q_len), q.dtype),
+            jax.ShapeDtypeStruct((b, 1, q_len), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((1, res_q), jnp.float32),
+            pltpu.VMEM((1, res_q), jnp.float32),
+            pltpu.VMEM((d, res_q), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(q, k, v)
+        name="flash_fwd",
+    )(q, k, jnp.swapaxes(v, 1, 2))
+    return jnp.swapaxes(out_t, 1, 2), lse
 
 
-def _flash_pallas_bwd_kernels(q, k, v, do, lse, delta, *, causal: bool,
-                              sm_scale: float, block_q: int, block_k: int,
-                              interpret: bool):
+def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
+                             sm_scale: float, block_q: Optional[int],
+                             block_k: Optional[int], interpret: bool):
     b, q_len, d = q.shape
     k_len = k.shape[1]
-    block_q = min(block_q, q_len)
-    block_k = min(block_k, k_len)
+    block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
+                                                  block_k, _BWD_TILES)
+    nq, nk = q_len // res_q, k_len // res_k
+    offset = k_len - q_len
 
-    qspec = lambda f: pl.BlockSpec((None, block_q, d), f)
-    kspec = lambda f: pl.BlockSpec((None, block_k, d), f)
-
-    dq = pl.pallas_call(
+    if causal and nk > 1:
+        qmap = lambda ki, qi: jnp.maximum(
+            qi, _first_live_q(ki, res_q, res_k, offset, nq))
+    else:
+        qmap = lambda ki, qi: qi
+    qspec = pl.BlockSpec((None, res_q, d),
+                         lambda bi, ki, qi: (bi, qmap(ki, qi), 0))
+    kspec = pl.BlockSpec((None, res_k, d), lambda bi, ki, qi: (bi, ki, 0))
+    rowspec = pl.BlockSpec((None, 1, res_q),
+                           lambda bi, ki, qi: (bi, 0, qmap(ki, qi)))
+    # one block of keys: its dQ^T is the whole of it; several: float32
+    # partials, one per block, summed below
+    dq_dtype = q.dtype if nk == 1 else jnp.float32
+    dq_t, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_len=q_len, k_len=k_len,
-        ),
-        grid=(b, q_len // block_q, k_len // block_k),
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, offset=offset, static=nq == nk == 1),
+        grid=(b, nk, nq),
         in_specs=[
-            qspec(lambda bi, qi, ki: (bi, qi, 0)),
-            kspec(lambda bi, qi, ki: (bi, ki, 0)),
-            kspec(lambda bi, qi, ki: (bi, ki, 0)),
-            qspec(lambda bi, qi, ki: (bi, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda bi, qi, ki: (bi, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda bi, qi, ki: (bi, qi, 0)),
-        ],
-        out_specs=qspec(lambda bi, qi, ki: (bi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, q_len, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_len=q_len, k_len=k_len,
-        ),
-        grid=(b, k_len // block_k, q_len // block_q),
-        in_specs=[
-            qspec(lambda bi, ki, qi: (bi, qi, 0)),
-            kspec(lambda bi, ki, qi: (bi, ki, 0)),
-            kspec(lambda bi, ki, qi: (bi, ki, 0)),
-            qspec(lambda bi, ki, qi: (bi, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda bi, ki, qi: (bi, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda bi, ki, qi: (bi, qi, 0)),
+            qspec, kspec, kspec,
+            pl.BlockSpec((None, d, res_k), lambda bi, ki, qi: (bi, 0, ki)),
+            qspec, rowspec, rowspec,
         ],
         out_specs=[
-            kspec(lambda bi, ki, qi: (bi, ki, 0)),
-            kspec(lambda bi, ki, qi: (bi, ki, 0)),
+            pl.BlockSpec((None, None, d, res_q),
+                         lambda bi, ki, qi: (ki, bi, 0, qi)),
+            kspec, kspec,
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((nk, b, d, q_len), dq_dtype),
             jax.ShapeDtypeStruct((b, k_len, d), k.dtype),
             jax.ShapeDtypeStruct((b, k_len, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((res_k, d), jnp.float32),
+            pltpu.VMEM((res_k, d), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+        name="flash_bwd",
+    )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
+    dq_t = dq_t[0] if nk == 1 else dq_t.sum(axis=0).astype(q.dtype)
+    return jnp.swapaxes(dq_t, 1, 2), dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
                        interpret):
     """Differentiable Pallas flash attention: both directions are Pallas
-    kernels (forward saves the logsumexp; backward recomputes P per block
-    from q,k,lse — O(seq) memory, no attention matrix ever materialized)."""
+    kernels (forward saves the logsumexp; one backward kernel recomputes P
+    per tile from q,k,lse — O(seq) memory, no attention matrix ever
+    materialized)."""
     out, _ = _flash_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)
@@ -419,14 +514,13 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret,
                       res, g):
     q, k, v, out, lse = res
     # delta_i = rowsum(dO_i * O_i); tiny elementwise reduce — XLA fuses it.
-    # Lane-broadcast to (b, q_len, _LANES) to match the lse layout.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
-    dq, dk, dv = _flash_pallas_bwd_kernels(
+    # A row (b, 1, q_len), like lse.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    return _flash_pallas_bwd_kernel(
         q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    return dq, dk, dv
 
 
 _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
@@ -438,7 +532,8 @@ _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 )
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     impl: Optional[str] = None) -> jax.Array:
     """Flash attention over (..., seq, head_dim) inputs.
 
@@ -448,9 +543,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
     other backend. ``impl`` forces a path:
     "pallas" | "pallas_interpret" | "scan" | "reference".
 
+    ``block_q`` queries meet ``block_k`` keys at a time; left out, the
+    kernel chooses both from the sequence lengths (``_block_sizes``) and
+    scan takes 128 keys.
+
     Under a mesh whose data-like axes split the batch (``_batch_axes``)
     the kernel runs per batch shard inside ``shard_map``: the partitioner
-    refuses Mosaic calls, and batch and heads are independent.
+    refuses Mosaic calls, and batch and heads are independent. Any other
+    mesh axis of size > 1 (``unmapped_mesh_axes``) still leaves the call
+    to the partitioner, and JAX's own error says so.
     """
     sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     if impl is None:
@@ -459,7 +560,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
     if impl == "scan":
         return _flash_scan(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_k=block_k)
+                           block_k=block_k or 128)
     interpret = impl == "pallas_interpret"
 
     def kernel(q, k, v):
@@ -481,6 +582,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                          check_vma=False)(q, k, v)
 
 
+_BATCH_AXES = ("data", "fsdp")
+
+
 def _batch_axes(x):
     """(mesh, axes): the mesh ``x`` is traced under and those of its axes
     the batch (leading) dim is split over — the repo's data-like axes
@@ -489,7 +593,7 @@ def _batch_axes(x):
     A leading dim those axes do not divide is an error, not a reason to
     leave the kernel to the partitioner, which refuses it."""
     mesh = jax.typeof(x).sharding.mesh
-    axes = tuple(a for a in ("data", "fsdp")
+    axes = tuple(a for a in _BATCH_AXES
                  if a in mesh.axis_names and mesh.shape[a] > 1
                  and a not in mesh.manual_axes)
     n = math.prod(mesh.shape[a] for a in axes)
@@ -499,3 +603,16 @@ def _batch_axes(x):
             f"the mesh's batch axes {axes} (size {n}); the Pallas kernel "
             "runs per batch shard and cannot be partitioned otherwise")
     return mesh, axes
+
+
+def unmapped_mesh_axes(x) -> tuple:
+    """Axes of size > 1 of the mesh ``x`` is traced under that neither
+    ``flash_attention`` maps the batch over nor an enclosing ``shard_map``
+    has made manual (``model`` under tensor parallelism, ``seq``,
+    ``expert``). Under any of them the kernel reaches the partitioner,
+    which refuses it ("Mosaic kernels cannot be automatically
+    partitioned"): a caller that chooses between paths asks here first."""
+    mesh = jax.typeof(x).sharding.mesh
+    return tuple(a for a in mesh.axis_names
+                 if mesh.shape[a] > 1 and a not in _BATCH_AXES
+                 and a not in mesh.manual_axes)

@@ -39,13 +39,14 @@ def test_train_phase_runs_on_cpu_and_is_refused_for_the_platform(tmp_path):
     assert "platform_is_tpu" in proc.stderr  # named among the failures
     assert checks.pop("platform_is_tpu") is False
     # the checks only a chip can pass
-    assert checks.pop("flash_step_holds_tpu_custom_call") is False
+    assert checks.pop("auto_step_holds_tpu_custom_call") is False
     assert checks.pop("only_the_trainer_holds_the_device_library") is False
     # every phase ran up to that verdict: both attention settings trained,
     # the bystander came back from a CPU worker, the driver stayed off jax
     assert checks and all(checks.values()), (checks, proc.stdout)
-    assert {"auto_losses_finite_and_falling",
-            "flash_losses_finite_and_falling", "first_losses_agree",
+    assert {"xla_losses_finite_and_falling",
+            "auto_losses_finite_and_falling", "first_losses_agree",
+            "xla_step_holds_no_custom_call",
             "bystander_did_not_hang", "driver_never_imported_jax"} <= set(checks)
     assert "bystander: returned 4.0" in proc.stdout
     assert "computed on cpu" in proc.stdout
@@ -58,8 +59,8 @@ def test_four_chip_comparison_passes_on_virtual_devices(tmp_path):
     assert '"ok": true' not in proc.stdout
     assert checks.pop("platform_is_tpu") is False
     assert checks and all(checks.values()), (checks, proc.stdout)
-    assert {"auto_sharded_losses_match_one_device",
-            "flash_sharded_losses_match_one_device",
+    assert {"xla_sharded_losses_match_one_device",
+            "auto_sharded_losses_match_one_device",
             "every_device_holds_a_batch_shard",
             "large_batch_losses_finite_and_falling",
             "device_count"} <= set(checks)

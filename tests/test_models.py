@@ -176,6 +176,112 @@ def test_flash_pallas_interpret_tiny_seq():
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("backend,seq,head_dim,path", [
+    ("tpu", 1024, 64, "flash"),   # GPT-2's geometry: measured (PERF.md)
+    ("tpu", 512, 64, "flash"),    # the shortest length the kernel won at
+    ("tpu", 1024, 128, "flash"),  # the other head dimension measured
+    ("tpu", 640, 64, "flash"),    # 128-wide tiles, a whole head a grid step
+    ("tpu", 4096, 64, "flash"),   # 2048 resident: several grid blocks
+    ("tpu", 2176, 64, "xla"),     # 17 x 128: 128-wide grid blocks lose
+    ("tpu", 2560, 128, "xla"),    # past 2048 and 1024 does not divide it
+    ("tpu", 384, 64, "xla"),      # below the crossover
+    ("tpu", 8, 64, "xla"),        # the seq-8 dummy that init traces with
+    ("tpu", 1000, 64, "xla"),     # no 128-multiple tile divides it
+    ("tpu", 1024, 80, "xla"),     # a head dimension never measured
+    ("cpu", 1024, 64, "xla"),
+    ("gpu", 1024, 64, "xla"),
+])
+def test_gpt2_auto_attention_reads_backend_and_shape(monkeypatch, backend,
+                                                     seq, head_dim, path):
+    from ray_tpu.models import gpt2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct((2, seq, 3, head_dim), jnp.bfloat16)
+    assert _auto_path(q) == path
+
+
+def _auto_path(q):
+    """What ``auto_attention`` answers for ``q`` as a jitted step sees it."""
+    from ray_tpu.models import gpt2
+
+    seen = []
+    jax.jit(lambda q: seen.append(gpt2.auto_attention(q))).lower(q)
+    return seen[0]
+
+
+@pytest.mark.parametrize("axes,shape,path", [
+    (("data",), (4,), "flash"),
+    (("data", "fsdp"), (2, 2), "flash"),
+    # the kernel's shard_map wrapper maps the batch axes alone: under any
+    # other axis the partitioner would meet the Mosaic call and refuse it
+    (("data", "model"), (2, 2), "xla"),
+    (("data", "model"), (4, 1), "flash"),
+    (("fsdp", "seq"), (2, 2), "xla"),
+    (("data", "expert"), (2, 2), "xla"),
+])
+def test_gpt2_auto_attention_reads_the_mesh(monkeypatch, axes, shape, path):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(shape), axes)
+    q = jax.ShapeDtypeStruct(
+        (4, 1024, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, PartitionSpec(axes[0])))
+    assert _auto_path(q) == path
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_gpt2_auto_attention_dispatch(monkeypatch, backend):
+    """``auto`` at GPT-2's geometry calls the flash kernel where the backend
+    reads "tpu" and ``dot_product_attention`` elsewhere; ``xla`` never calls
+    the kernel; an unknown name is refused."""
+    from ray_tpu import ops
+    from ray_tpu.models import gpt2
+
+    calls = []
+    real_flash, real_xla = ops.flash_attention, jax.nn.dot_product_attention
+    monkeypatch.setattr(
+        ops, "flash_attention",
+        lambda *a, **kw: calls.append("flash")
+        or real_flash(*a, impl="scan", **kw))
+    monkeypatch.setattr(
+        jax.nn, "dot_product_attention",
+        lambda *a, **kw: calls.append("xla") or real_xla(*a, **kw))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    def paths(attention, seq):
+        calls.clear()
+        cfg = gpt2.GPT2Config(vocab_size=64, n_positions=seq, n_embd=128,
+                              n_layer=1, n_head=2, attention=attention)
+        ids = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+        jax.eval_shape(
+            lambda i: gpt2.GPT2(cfg).init(jax.random.PRNGKey(0), i), ids)
+        return set(calls)
+
+    assert paths("auto", 1024) == {"flash" if backend == "tpu" else "xla"}
+    assert paths("auto", 128) == {"xla"}
+    assert paths("xla", 1024) == {"xla"}
+    assert paths("flash", 1024) == {"flash"}
+    with pytest.raises(ValueError, match="attention='splash'"):
+        paths("splash", 128)
+
+
+def test_gpt2_auto_is_the_xla_program_on_cpu():
+    """On the CPU backend ``auto`` lowers to the very program ``xla`` does."""
+    from ray_tpu.models import gpt2
+
+    def lowered(attention):
+        cfg = gpt2.GPT2Config.small_test(attention=attention)
+        model, params, tx, opt_state = gpt2.make_train_state(
+            cfg, jax.random.PRNGKey(0))
+        batch = gpt2.synthetic_batch(jax.random.PRNGKey(1), 2, 128,
+                                     cfg.vocab_size)
+        step = gpt2.build_train_step(model, tx, donate=False)
+        return step.lower(params, opt_state, batch).as_text()
+
+    assert lowered("auto") == lowered("xla")
+
+
 def test_llama_7b_param_count():
     cfg = llama.LlamaConfig.llama2_7b()
     n = cfg.num_params()

@@ -1,5 +1,7 @@
 """Attention kernel numerics (vs naive reference) on the virtual CPU mesh."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -31,17 +33,6 @@ def test_flash_scan_uneven_blocks():
     q, k, v = _qkv(s=48)
     ref = attention_reference(q, k, v, causal=True)
     out = flash_attention(q, k, v, causal=True, impl="scan", block_k=32)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_pallas_interpret_matches_reference(causal):
-    q, k, v = _qkv(b=1, h=2, s=32, d=8)
-    ref = attention_reference(q, k, v, causal=causal)
-    out = flash_attention(
-        q, k, v, causal=causal, impl="pallas_interpret",
-        block_q=16, block_k=16,
-    )
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
@@ -127,71 +118,126 @@ def test_gpt2_sequence_parallel_step():
     assert float(loss2) < float(loss_sp)
 
 
-def test_flash_pallas_grad_matches_reference():
-    """The Pallas path is differentiable end-to-end: forward saves the
-    logsumexp and the backward runs real Pallas dq / dkv kernels."""
-    q, k, v = _qkv(b=1, h=1, s=32, d=8)
-
-    def loss_pallas(q, k, v):
-        return flash_attention(q, k, v, causal=True, impl="pallas_interpret",
-                               block_q=16, block_k=16).sum()
-
-    def loss_ref(q, k, v):
-        return attention_reference(q, k, v, causal=True).sum()
-
-    g_p = jax.grad(loss_pallas)(q, k, v)
-    g_r = jax.grad(loss_ref)(q, k, v)
-    np.testing.assert_allclose(g_p, g_r, atol=1e-4, rtol=1e-4)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_pallas_grad_nonuniform_cotangent(causal):
-    """Non-uniform cotangents exercise the delta = rowsum(dO*O) term of the
-    flash backward — a uniform .sum() cotangent can mask a wrong delta."""
-    q, k, v = _qkv(b=1, h=2, s=64, d=8, seed=3)
-    w = jax.random.normal(jax.random.PRNGKey(9), q.shape, q.dtype)
-
-    def loss_pallas(q, k, v):
-        return (flash_attention(q, k, v, causal=causal,
-                                impl="pallas_interpret",
-                                block_q=16, block_k=16) * w).sum()
-
-    def loss_ref(q, k, v):
-        return (attention_reference(q, k, v, causal=causal) * w).sum()
-
-    g_p = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gp, gr in zip(g_p, g_r):
-        np.testing.assert_allclose(gp, gr, atol=2e-4, rtol=2e-4)
+# The Pallas kernel in interpret mode. A case is (batch x heads, q_len,
+# k_len, head dim, block_q, block_k, resident): blocks None are the rule's
+# (``_block_sizes``), ``resident`` overrides ``_MAX_RESIDENT`` so that a
+# short sequence spans several grid blocks, as a long one does on the chip.
+_PALLAS_CASES = {
+    "tiles_2x2": (2, 32, 32, 8, 16, 16, None),
+    "tiles_4x4_two_heads": (2, 64, 64, 8, 16, 16, None),
+    "one_tile": (3, 32, 32, 8, None, None, None),
+    "wide_key_tiles": (2, 64, 64, 16, 16, 32, None),
+    "wide_query_tiles": (2, 64, 64, 16, 32, 16, None),
+    "cross_lengths_16_of_64": (2, 16, 64, 8, 16, 16, None),
+    "grid_blocks_4x4": (2, 128, 128, 8, 16, 16, 32),
+    "grid_blocks_2x4_tiles_2x1": (1, 64, 128, 8, 16, 32, 32),
+    "cross_lengths_grid_blocks": (2, 32, 128, 8, 16, 16, 32),
+    "rule_384": (1, 384, 384, 16, None, None, None),
+    "rule_gpt2_1024_64": (2, 1024, 1024, 64, None, None, None),
+}
+# (forward, gradient): float32 elementwise (atol = rtol), as the tests this
+# one merged held it; bfloat16 against the largest reference entry
+_PALLAS_TOL = {jnp.float32: (2e-5, 1e-4), jnp.bfloat16: (2e-2, 3e-2)}
+# bfloat16 where the dtype changes the program: the scale folded into q
+# (head dim 16, 64) or not (8), several grid blocks, the rule's own tiles
+_PALLAS_BF16 = ("tiles_2x2", "wide_key_tiles", "cross_lengths_16_of_64",
+                "grid_blocks_4x4", "rule_gpt2_1024_64")
+_PALLAS_PARAMS = [
+    pytest.param(case, causal, dtype,
+                 id=f"{case}-{'causal' if causal else 'full'}-{dtype.__name__}")
+    for dtype, cases in ((jnp.float32, _PALLAS_CASES),
+                         (jnp.bfloat16, _PALLAS_BF16))
+    for case in cases for causal in (False, True)]
 
 
-def test_flash_pallas_cross_lengths():
-    """q_len != k_len (decode-style causal offset) with streamed KV blocks:
-    the kv axis is a grid dimension, so K/V VMEM residency is one
-    (block_k, d) tile regardless of sequence length."""
-    b, h, d = 1, 2, 8
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    q = jax.random.normal(ks[0], (b, h, 16, d))
-    k = jax.random.normal(ks[1], (b, h, 64, d))
-    v = jax.random.normal(ks[2], (b, h, 64, d))
-    for causal in (False, True):
-        ref = attention_reference(q, k, v, causal=causal)
-        out = flash_attention(q, k, v, causal=causal,
-                              impl="pallas_interpret",
-                              block_q=16, block_k=16)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+@pytest.mark.parametrize("case,causal,dtype", _PALLAS_PARAMS)
+def test_flash_pallas_matches_reference(monkeypatch, case, causal, dtype):
+    """Forward, and the gradients of q, k and v under a non-uniform
+    cotangent (a uniform .sum() can mask a wrong delta = rowsum(dO*O)),
+    against ``attention_reference`` in float32 on the same inputs. The
+    kernel's matmuls take the inputs' dtype, so the tolerance follows it:
+    float32 is held entry by entry (a wrong small entry, such as a masked
+    row's, shows), bfloat16 against the largest reference entry."""
+    from ray_tpu.ops import attention
 
-    def loss_pallas(q, k, v):
-        return flash_attention(q, k, v, causal=True, impl="pallas_interpret",
-                               block_q=16, block_k=16).sum()
+    bh, q_len, k_len, d, block_q, block_k, resident = _PALLAS_CASES[case]
+    if resident:
+        monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+        jax.clear_caches()  # flash_attention is jitted: the rule is read
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (bh, q_len, d), dtype)
+    k = jax.random.normal(ks[1], (bh, k_len, d), dtype)
+    v = jax.random.normal(ks[2], (bh, k_len, d), dtype)
+    w = jax.random.normal(ks[3], (bh, q_len, d), jnp.float32)
+    f32 = lambda x: x.astype(jnp.float32)
 
-    def loss_ref(q, k, v):
-        return attention_reference(q, k, v, causal=True).sum()
+    def pallas(q, k, v):
+        return f32(flash_attention(q, k, v, causal=causal, block_q=block_q,
+                                   block_k=block_k, impl="pallas_interpret"))
 
-    g_p = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
-    g_r = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gp, gr in zip(g_p, g_r):
-        np.testing.assert_allclose(gp, gr, atol=1e-4, rtol=1e-4)
+    def ref(q, k, v):
+        return attention_reference(q, k, v, causal=causal)
+
+    def out_and_grads(fn, *args):  # one compile a side
+        out, vjp = jax.vjp(fn, *args)
+        return out, vjp(w)
+
+    def close(got, want, tol):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+        else:
+            err = float(jnp.abs(f32(got) - want).max() / jnp.abs(want).max())
+            assert err <= tol, err
+
+    try:
+        fwd_tol, grad_tol = _PALLAS_TOL[dtype]
+        out, grads = jax.jit(functools.partial(out_and_grads, pallas))(q, k, v)
+        want, want_grads = jax.jit(functools.partial(out_and_grads, ref))(
+            f32(q), f32(k), f32(v))
+        close(out, want, fwd_tol)
+        for got, want in zip(grads, want_grads):
+            assert got.dtype == dtype
+            close(got, want, grad_tol)
+    finally:
+        if resident:
+            jax.clear_caches()
+
+
+def test_flash_block_rule():
+    """Tiles are multiples of 128 or the whole length; a grid step holds
+    the whole sequence up to ``_MAX_RESIDENT``."""
+    from ray_tpu.ops.attention import _BWD_TILES, _FWD_TILES, _block_sizes
+
+    fwd = lambda *a: _block_sizes(*a, _FWD_TILES)
+    assert fwd(1024, 1024, None, None) == (512, 512, 1024, 1024)
+    assert _block_sizes(1024, 1024, None, None, _BWD_TILES) == (
+        256, 256, 1024, 1024)
+    assert fwd(512, 512, None, None) == (512, 512, 512, 512)
+    assert fwd(384, 384, None, None) == (384, 384, 384, 384)
+    assert fwd(8, 8, None, None) == (8, 8, 8, 8)
+    assert fwd(640, 1280, None, None) == (128, 256, 640, 1280)
+    assert fwd(8192, 8192, None, None) == (512, 512, 2048, 2048)
+    assert fwd(64, 64, 16, 16) == (16, 16, 64, 64)
+    with pytest.raises(AssertionError):
+        fwd(96, 96, 64, 16)
+
+
+@pytest.mark.parametrize("impl", ["scan", "pallas_interpret"])
+def test_flash_runs_per_batch_shard_under_a_data_mesh(impl):
+    """Under a mesh whose data axis splits the batch the kernel runs inside
+    ``shard_map``, one batch shard a device, and gives what one device
+    gives."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    q, k, v = _qkv(b=8, h=2, s=32, d=8)
+    ref = attention_reference(q, k, v, causal=True)
+    fn = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl=impl, block_q=16, block_k=16))
+    by_batch = NamedSharding(mesh, PartitionSpec("data"))
+    out = fn(*(jax.device_put(x, by_batch) for x in (q, k, v)))
+    assert out.sharding.spec[0] == "data"
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("platform", ["tpu", "cpu"])

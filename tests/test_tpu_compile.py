@@ -58,7 +58,8 @@ def no_compile_cache():
 @pytest.fixture
 def on_tpu(monkeypatch):
     """Steer code that asks ``jax.default_backend()`` (the flash
-    dispatcher) onto its TPU branch: the process itself sees the CPU."""
+    dispatcher, the model's ``attention="auto"``) onto its TPU branch: the
+    process itself sees the CPU."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()
     yield
@@ -96,9 +97,14 @@ def _device_bytes(compiled):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_kernel_compiles(topo, no_compile_cache, backward):
-    """(batch 16 x 12 heads, 1024, 64) bf16 — the shape the model calls."""
-    x = jax.ShapeDtypeStruct((16 * 12, SEQ, 64), jnp.bfloat16,
+@pytest.mark.parametrize("heads,seq", [(16 * 12, SEQ), (4 * 12, 4096)],
+                         ids=["gpt2_1024", "several_grid_blocks_4096"])
+def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, backward):
+    """(batch 16 x 12 heads, 1024, 64) bf16 — the shape the model calls,
+    one grid step a head, the tile walk unrolled; and 4096, past
+    ``_MAX_RESIDENT``: 2 x 2 grid blocks a head, the walk a loop with
+    bounds from the grid position, dead blocks clamped in the index maps."""
+    x = jax.ShapeDtypeStruct((heads, seq, 64), jnp.bfloat16,
                              sharding=SingleDeviceSharding(topo.devices[0]))
 
     def fwd(q, k, v):
@@ -112,33 +118,90 @@ def test_flash_kernel_compiles(topo, no_compile_cache, backward):
     assert "tpu_custom_call" in text
 
 
-def test_train_step_auto_fits_one_chip(topo, no_compile_cache):
+def _compile_step(cache, key, step, args):
+    """(HLO text, planned bytes) of ``step`` compiled for ``args``. Two
+    attention settings that lower to one program (``auto`` and ``flash`` at
+    this shape on a TPU) are compiled once: ``cache`` is keyed by the
+    lowered text."""
+    lowered = step.lower(*args)
+    program = (key, lowered.as_text())
+    if program not in cache:
+        compiled = lowered.compile()
+        cache[program] = (compiled.as_text(), _device_bytes(compiled))
+    return cache[program]
+
+
+@pytest.fixture(scope="module")
+def compiled_steps():
+    return {}
+
+
+def _one_chip(compiled_steps, topo, attention):
     one = SingleDeviceSharding(topo.devices[0])
-    step, args = _train_step_args("auto", one, one)
-    compiled = step.lower(*args).compile()
-    assert _device_bytes(compiled) < HBM_BYTES
+    return _compile_step(compiled_steps, "one chip",
+                         *_train_step_args(attention, one, one))
 
 
-def test_train_step_flash_fits_one_chip(topo, no_compile_cache, on_tpu):
-    one = SingleDeviceSharding(topo.devices[0])
-    step, args = _train_step_args("flash", one, one)
-    compiled = step.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _device_bytes(compiled) < HBM_BYTES
+@pytest.mark.parametrize("attention", ["xla", "auto", "flash"])
+def test_train_step_fits_one_chip(topo, no_compile_cache, compiled_steps,
+                                  on_tpu, attention):
+    """``auto`` at GPT-2's geometry is the kernel's program: it holds the
+    Mosaic call and plans less HBM than ``xla``, whose saved score tensors
+    it does not have; ``xla`` holds no kernel."""
+    text, planned = _one_chip(compiled_steps, topo, attention)
+    assert ("tpu_custom_call" in text) == (attention != "xla")
+    assert planned < HBM_BYTES
+    if attention == "auto":
+        assert planned < _one_chip(compiled_steps, topo, "xla")[1]
 
 
-@pytest.mark.parametrize("attention", ["auto", "flash"])
-def test_train_step_data_parallel_4_chips(topo, no_compile_cache, on_tpu,
-                                          attention):
+@pytest.mark.parametrize("attention", ["xla", "auto", "flash"])
+def test_train_step_data_parallel_4_chips(topo, no_compile_cache,
+                                          compiled_steps, on_tpu, attention):
     """Global batch 64 over data=4, state replicated: the partitioner must
-    add the gradient all-reduce, and — for flash — must be handed the
-    Mosaic kernel already split per batch shard."""
+    add the gradient all-reduce, and — for the kernel — must be handed the
+    Mosaic call already split per batch shard."""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
-    step, args = _train_step_args(
+    text, planned = _compile_step(compiled_steps, "data=4", *_train_step_args(
         attention, NamedSharding(mesh, PartitionSpec()),
-        NamedSharding(mesh, PartitionSpec("data")), batch=4 * BATCH)
-    compiled = step.lower(*args).compile()
-    text = compiled.as_text()
+        NamedSharding(mesh, PartitionSpec("data")), batch=4 * BATCH))
     assert "all-reduce" in text
-    assert ("tpu_custom_call" in text) == (attention == "flash")
-    assert _device_bytes(compiled) < HBM_BYTES
+    assert ("tpu_custom_call" in text) == (attention != "xla")
+    assert planned < HBM_BYTES
+
+
+def _tensor_parallel_state(params, opt_state, mesh):
+    """Abstract params and optimizer state laid out as
+    ``shard_train_state_tp`` places real ones: moments like their parameter,
+    the rest replicated."""
+    p_sh = gpt2.shard_params_tp(params, mesh)
+    treedef = jax.tree.structure(params)
+    like_params = lambda node: jax.tree.structure(node) == treedef
+    place = lambda tree, sh: jax.tree.map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        tree, sh)
+    rep = NamedSharding(mesh, PartitionSpec())
+    opt_state = jax.tree.map(
+        lambda node: place(node, p_sh) if like_params(node)
+        else _with_sharding(node, rep), opt_state, is_leaf=like_params)
+    return place(params, p_sh), opt_state
+
+
+@pytest.mark.parametrize("attention", ["xla", "auto"])
+def test_train_step_tensor_parallel_2x2(topo, no_compile_cache,
+                                        compiled_steps, on_tpu, attention):
+    """data=2 x model=2, ``shard_params_tp``: ``flash_attention`` maps only
+    the batch axes, so under a ``model`` axis the Mosaic call would reach
+    the partitioner, which refuses it (ROADMAP 8a). ``auto`` sees the mesh
+    in its operand's type and stays on XLA's attention: the program it
+    compiled to before the kernel was chosen anywhere."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    step, (params, opt_state, batch) = _train_step_args(
+        attention, NamedSharding(mesh, PartitionSpec()),
+        NamedSharding(mesh, PartitionSpec("data")), batch=2 * BATCH)
+    params, opt_state = _tensor_parallel_state(params, opt_state, mesh)
+    text, planned = _compile_step(compiled_steps, "data=2 x model=2", step,
+                                  (params, opt_state, batch))
+    assert "all-reduce" in text
+    assert "tpu_custom_call" not in text
+    assert planned < HBM_BYTES
