@@ -342,8 +342,14 @@ _flag("rpc_retry_max_delay_s", float, 2.0)
 # keepalive: ping idle connections every interval; a peer silent for the
 # timeout is declared dead (black-holed peers surface in O(timeout)
 # instead of hanging a request forever). 0 disables. v3+ sessions only.
+# A pong needs the peer's interpreter: a trainer that writes GPT-2 XL's
+# step to the compile cache holds its GIL for 23 s inside XLA's
+# ``executable.serialize()`` (PERF.md section 6, PR 26), and at 20 s its
+# raylet, the GCS and the trainer itself (on waking) each declared the other
+# dead. A process that exits is seen at once by its closed connection; this
+# timeout only bounds how long a black-holed peer goes unnoticed.
 _flag("rpc_keepalive_interval_s", float, 2.0)
-_flag("rpc_keepalive_timeout_s", float, 20.0)
+_flag("rpc_keepalive_timeout_s", float, 120.0)
 # Serve (ray: serve/_private defaults)
 _flag("serve_control_loop_period_s", float, 0.25)
 _flag("serve_default_graceful_shutdown_timeout_s", float, 5.0)

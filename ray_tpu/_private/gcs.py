@@ -510,13 +510,31 @@ class GcsServer:
                 pass
 
     async def _health_loop(self):
+        tick = time.monotonic()
         while True:
             await asyncio.sleep(cfg.heartbeat_interval_s)
             now = time.monotonic()
+            self._credit_own_stall(now - tick - cfg.heartbeat_interval_s)
+            tick = now
             for node in list(self.nodes.values()):
                 if node.alive and now - node.last_heartbeat > cfg.node_death_timeout_s:
                     await self._mark_node_dead(node.node_id, "heartbeat timeout")
             await self._broadcast_view()
+
+    def _credit_own_stall(self, overslept: float):
+        """Time this loop overslept is time this process did not run, and
+        so could record no heartbeat, whoever sent one: it counts against
+        no node. A worker that opens four chips stalls every process of
+        its host for 7-14 s (PERF.md section 7), the GCS with the raylet;
+        without the credit the GCS wakes, finds the one node's heartbeat
+        older than ``node_death_timeout_s`` and kills the job it serves.
+        A node that is silent while the GCS runs gets no credit."""
+        if overslept <= cfg.heartbeat_interval_s:
+            return
+        logger.warning("the GCS did not run for %.1f s: credited to every "
+                       "node's heartbeat", overslept)
+        for node in self.nodes.values():
+            node.last_heartbeat += overslept
 
     async def _mark_node_dead(self, node_id: str, reason: str):
         node = self.nodes.get(node_id)

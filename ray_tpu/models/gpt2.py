@@ -20,6 +20,7 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.parallel.mesh_utils import on_batch_axes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,9 +116,9 @@ class CausalSelfAttention(nn.Module):
         qkv = nn.Dense(3 * C, dtype=c.dtype, name="c_attn")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         heads = c.n_head
-        q = q.reshape(B, T, heads, C // heads)
-        k = k.reshape(B, T, heads, C // heads)
-        v = v.reshape(B, T, heads, C // heads)
+        q = on_batch_axes(q.reshape(B, T, heads, C // heads))
+        k = on_batch_axes(k.reshape(B, T, heads, C // heads))
+        v = on_batch_axes(v.reshape(B, T, heads, C // heads))
         attention = c.attention
         if attention == "auto":
             attention = auto_attention(q)
@@ -141,7 +142,7 @@ class CausalSelfAttention(nn.Module):
             raise ValueError(
                 f"attention={c.attention!r}: expected auto, xla, flash or "
                 "ring")
-        y = y.reshape(B, T, C)
+        y = on_batch_axes(y.reshape(B, T, C))
         return nn.Dense(C, dtype=c.dtype, name="c_proj")(y)
 
 
@@ -152,6 +153,7 @@ class MLP(nn.Module):
     def __call__(self, x, deterministic=True):
         c = self.config
         h = nn.Dense(4 * c.n_embd, dtype=c.dtype, name="c_fc")(x)
+        h = on_batch_axes(h)
         h = nn.gelu(h, approximate=True)
         return nn.Dense(c.n_embd, dtype=c.dtype, name="c_proj")(h)
 
@@ -162,12 +164,12 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic=True):
         c = self.config
-        x = x + CausalSelfAttention(c, name="attn")(
+        x = on_batch_axes(x + CausalSelfAttention(c, name="attn")(
             nn.LayerNorm(dtype=c.dtype, name="ln_1")(x), deterministic
-        )
-        x = x + MLP(c, name="mlp")(
+        ))
+        x = on_batch_axes(x + MLP(c, name="mlp")(
             nn.LayerNorm(dtype=c.dtype, name="ln_2")(x), deterministic
-        )
+        ))
         return x
 
 
@@ -185,19 +187,23 @@ class GPT2(nn.Module):
             # under shard_map T is the LOCAL sequence chunk; offset to
             # global positions for this sequence shard
             pos = pos + jax.lax.axis_index(c.sp_axis) * T
-        x = wte(input_ids) + wpe(pos)
+        # nn.Embed's own lookup, on a table gathered whole first: looked up
+        # in its shards at rest (split by features) the rows would come
+        # back split by features and cross to the batch axes by all-to-all
+        table = on_batch_axes(wte.embedding.astype(c.dtype), batch_dim=None)
+        x = on_batch_axes(jnp.take(table, input_ids, axis=0) + wpe(pos))
         block = Block
         if c.remat:
             block = nn.remat(Block, static_argnums=(2,))
         for i in range(c.n_layer):
             x = block(c, name=f"h_{i}")(x, deterministic)
-        x = nn.LayerNorm(dtype=c.dtype, name="ln_f")(x)
+        x = on_batch_axes(nn.LayerNorm(dtype=c.dtype, name="ln_f")(x))
         if return_hidden:
             # chunked-loss path: hand back the final hidden states so the
             # loss can run the tied vocab matmul chunk by chunk
             return x
         # weight-tied LM head; bf16 matmul (MXU) — loss upcasts per-element
-        logits = wte.attend(x)
+        logits = on_batch_axes(wte.attend(x))
         return logits
 
 
@@ -239,7 +245,8 @@ def chunked_xent_tied(hidden, embedding, labels, mask=None, n_chunks=8):
     B, T, C = hidden.shape
     assert T % n_chunks == 0, (T, n_chunks)
     t = T // n_chunks
-    hid = hidden.reshape(B, n_chunks, t, C).swapaxes(0, 1)
+    hid = on_batch_axes(hidden.reshape(B, n_chunks, t, C).swapaxes(0, 1),
+                        batch_dim=1)
     lab = labels.reshape(B, n_chunks, t).swapaxes(0, 1)
     # prevent_cse=False: remat under scan doesn't need the CSE-prevention
     # barriers (jax.checkpoint docs) — they only block XLA optimizations
@@ -249,7 +256,7 @@ def chunked_xent_tied(hidden, embedding, labels, mask=None, n_chunks=8):
         # unmasked: denominator is statically B*T — don't scan a ones mask
         @ckpt
         def chunk_ll_sum(h, l):
-            logits = h @ embedding.T.astype(h.dtype)
+            logits = on_batch_axes(h @ embedding.T.astype(h.dtype))
             return token_log_likelihood(logits, l).sum()
 
         def body(numer, hl):
@@ -262,7 +269,7 @@ def chunked_xent_tied(hidden, embedding, labels, mask=None, n_chunks=8):
 
     @ckpt
     def chunk_sums(h, l, m):
-        logits = h @ embedding.T.astype(h.dtype)
+        logits = on_batch_axes(h @ embedding.T.astype(h.dtype))
         ll = token_log_likelihood(logits, l)
         m32 = m.astype(jnp.float32)
         return (ll * m32).sum(), m32.sum()
@@ -318,6 +325,41 @@ def make_train_state(config: GPT2Config, rng, learning_rate: float = 3e-4,
     return model, params, tx, tx.init(params)
 
 
+class _StepByLayout:
+    """A train step ``(params, opt_state, batch) -> (params, opt_state,
+    loss)`` that takes its layout from its arguments. Called or lowered
+    with a state that lies on one device (or abstract and unplaced) it is
+    ``jitted()``, the plain jit. With a state placed over several devices
+    (``shard_train_state``) it is ``jitted((param shardings, optimizer
+    state shardings))``: the state comes back in the shardings it went in,
+    so the second step finds the program of the first, and the gradients
+    take the parameters' shardings. One jit a layout, kept; a loop that
+    hands back what it was given pays a walk over the leaves a call."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._by_layout = []  # [(leaf shardings, jit)]: one entry as a rule
+
+    def _for(self, params, opt_state):
+        state = (params, opt_state)
+        layout = tuple(getattr(x, "sharding", None)
+                       for x in jax.tree.leaves(state))
+        for known, fn in self._by_layout:
+            if known == layout:
+                return fn
+        spread = any(s is not None and len(s.device_set) > 1 for s in layout)
+        fn = self._jitted(jax.tree.unflatten(
+            jax.tree.structure(state), layout) if spread else None)
+        self._by_layout.append((layout, fn))
+        return fn
+
+    def __call__(self, params, opt_state, batch):
+        return self._for(params, opt_state)(params, opt_state, batch)
+
+    def lower(self, params, opt_state, batch):
+        return self._for(params, opt_state).lower(params, opt_state, batch)
+
+
 def build_train_step(model, tx, donate: bool = True, *,
                      mesh: Optional[Mesh] = None,
                      batch_axis: str = "data",
@@ -329,7 +371,10 @@ def build_train_step(model, tx, donate: bool = True, *,
     ``shard_train_state`` / ``shard_batch`` first): with batch sharded over
     data axes and params replicated (DP) or fsdp-sharded (ZeRO-3), the XLA
     partitioner inserts the gradient psum / reduce-scatter on ICI — the
-    TPU-native replacement for the reference's NCCL-DDP allreduce.
+    TPU-native replacement for the reference's NCCL-DDP allreduce. The
+    state is returned in the shardings it came in (``_StepByLayout``), and
+    the model keeps its activations on the batch axes
+    (``mesh_utils.on_batch_axes``); on one device both add nothing.
 
     ``ingraph_psum`` (or the ``train_ingraph_psum`` flag, usually armed
     per-run via ``JaxConfig(ingraph_psum=...)``) swaps the partitioner-
@@ -351,13 +396,25 @@ def build_train_step(model, tx, donate: bool = True, *,
             "runs inside shard_map, which cannot be inferred from placement")
 
     if not mode:
-        def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return params, opt_state, loss
+        def jitted(state_shardings=None):
+            def step(params, opt_state, batch):
+                loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
+                if state_shardings:
+                    # a gradient leaves the backward pass laid out as its
+                    # parameter is at rest: the sum over the split batch
+                    # becomes a reduce-scatter, not an all-reduce
+                    grads = jax.lax.with_sharding_constraint(
+                        grads, state_shardings[0])
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+                return params, opt_state, loss
 
-        return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+            return jax.jit(
+                step, donate_argnums=(0, 1) if donate else (),
+                out_shardings=(*state_shardings, None)
+                if state_shardings else None)
+
+        return _StepByLayout(jitted)
 
     from ray_tpu.parallel import collectives as col
 
@@ -425,14 +482,17 @@ def build_train_step_sp(model, tx, mesh: Mesh, *, sp_axis: str = "sp",
 
 def shard_train_state(params, opt_state, mesh: Mesh, fsdp: bool = False):
     """Place params + optimizer state on the mesh (DP replicate or FSDP
-    shard); optimizer moments inherit their parameter's sharding."""
+    shard); optimizer moments inherit their parameter's sharding. Step
+    observatory: one span ``train/shard_state`` with the bytes of both
+    trees as its count (GPT-2 XL: 18.7 GB), beside ``ckpt/persist`` in
+    ``train_timeline``."""
+    from ray_tpu._private import steptrace
     from ray_tpu.parallel.mesh_utils import replicated, shard_params_fsdp
 
     if fsdp:
         p_sh = shard_params_fsdp(params, mesh)
     else:
         p_sh = jax.tree.map(lambda _: replicated(mesh), params)
-    params = jax.tree.map(jax.device_put, params, p_sh)
     p_treedef = jax.tree_util.tree_structure(params)
 
     def is_params_like(node):
@@ -446,7 +506,11 @@ def shard_train_state(params, opt_state, mesh: Mesh, fsdp: bool = False):
             return jax.tree.map(jax.device_put, node, p_sh)
         return jax.tree.map(lambda l: jax.device_put(l, replicated(mesh)), node)
 
-    opt_state = jax.tree.map(place, opt_state, is_leaf=is_params_like)
+    nbytes = sum(getattr(leaf, "nbytes", 0)
+                 for leaf in jax.tree.leaves((params, opt_state)))
+    with steptrace.span("train/shard_state", nbytes):
+        params = jax.tree.map(jax.device_put, params, p_sh)
+        opt_state = jax.tree.map(place, opt_state, is_leaf=is_params_like)
     return params, opt_state
 
 

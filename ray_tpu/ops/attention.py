@@ -30,6 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
+from ray_tpu.parallel.mesh_utils import traced_mesh_axes
+
 NEG_INF = -1e30
 
 
@@ -582,9 +584,6 @@ def flash_attention(q, k, v, *, causal: bool = False,
                          check_vma=False)(q, k, v)
 
 
-_BATCH_AXES = ("data", "fsdp")
-
-
 def _batch_axes(x):
     """(mesh, axes): the mesh ``x`` is traced under and those of its axes
     the batch (leading) dim is split over — the repo's data-like axes
@@ -592,10 +591,7 @@ def _batch_axes(x):
     ``shard_map`` has not already split. ``axes`` is empty outside a mesh.
     A leading dim those axes do not divide is an error, not a reason to
     leave the kernel to the partitioner, which refuses it."""
-    mesh = jax.typeof(x).sharding.mesh
-    axes = tuple(a for a in _BATCH_AXES
-                 if a in mesh.axis_names and mesh.shape[a] > 1
-                 and a not in mesh.manual_axes)
+    mesh, axes, _ = traced_mesh_axes(x)
     n = math.prod(mesh.shape[a] for a in axes)
     if x.shape[0] % n:
         raise ValueError(
@@ -612,7 +608,4 @@ def unmapped_mesh_axes(x) -> tuple:
     ``expert``). Under any of them the kernel reaches the partitioner,
     which refuses it ("Mosaic kernels cannot be automatically
     partitioned"): a caller that chooses between paths asks here first."""
-    mesh = jax.typeof(x).sharding.mesh
-    return tuple(a for a in mesh.axis_names
-                 if mesh.shape[a] > 1 and a not in _BATCH_AXES
-                 and a not in mesh.manual_axes)
+    return traced_mesh_axes(x)[2]

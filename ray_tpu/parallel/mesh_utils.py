@@ -38,9 +38,12 @@ def create_mesh(
 ) -> Mesh:
     """Build a Mesh with named axes from ``axes`` (e.g. {"data": 4, "model": 2}).
 
-    Uses ``jax.experimental.mesh_utils.create_device_mesh`` when the full
-    device set is used so the logical mesh is laid out along physical ICI
-    topology; falls back to a reshape for partial device sets.
+    Over the full device set the logical mesh is laid out along the
+    physical ICI topology by ``jax.experimental.mesh_utils.
+    create_device_mesh``, and what that refuses is raised: a reshape in its
+    place would run, on links the axes were not meant for. A partial
+    device set (``devices`` given, or fewer than all) is reshaped in the
+    order given: the caller chose the devices.
     """
     devices = list(devices if devices is not None else jax.devices())
     names = _ordered_axis_names(axes)
@@ -50,15 +53,12 @@ def create_mesh(
         raise ValueError(f"mesh {axes} needs {total} devices, have {len(devices)}")
     use = devices[:total]
     if len(use) == len(jax.devices()):
-        try:
-            from jax.experimental import mesh_utils as jmu
+        from jax.experimental import mesh_utils as jmu
 
-            dev_array = jmu.create_device_mesh(sizes, devices=np.array(use))
-            return Mesh(dev_array, names)
-        except Exception:
-            pass
-    dev_array = np.array(use).reshape(sizes)
-    return Mesh(dev_array, names)
+        return Mesh(jmu.create_device_mesh(
+            sizes, devices=np.array(use),
+            allow_split_physical_axes=allow_split_physical_axes), names)
+    return Mesh(np.array(use).reshape(sizes), names)
 
 
 def auto_mesh(
@@ -182,10 +182,15 @@ def create_hybrid_mesh(
     return Mesh(dev_array, tuple(dcn_names) + tuple(ici_names))
 
 
+# The data-like axes: a batch's leading dim is split over them
+# (``data_sharding``), and under them activations stay so (``on_batch_axes``).
+BATCH_AXES = ("data", "fsdp")
+
+
 def data_sharding(mesh: Mesh, *data_axes: str) -> NamedSharding:
     """Sharding for a batch: leading dim split over data-like axes; replicated
     if the mesh has no data-like axis."""
-    axes = data_axes or tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    axes = data_axes or tuple(a for a in BATCH_AXES if a in mesh.axis_names)
     if not axes:
         return NamedSharding(mesh, PartitionSpec())
     return NamedSharding(mesh, PartitionSpec(axes if len(axes) > 1 else axes[0]))
@@ -204,10 +209,59 @@ def logical_to_physical(
     return PartitionSpec(*(rules.get(a) if a else None for a in logical_axes))
 
 
+def traced_mesh_axes(x):
+    """(mesh, batch axes, other axes) of the mesh ``x`` is traced under,
+    read from its type: the axes of size > 1 that no enclosing
+    ``shard_map`` holds, the data-like ones (``BATCH_AXES``) apart from the
+    rest (``model`` under tensor parallelism, ``seq``, ``expert``). Both
+    are empty outside a mesh and on one device."""
+    mesh = jax.typeof(x).sharding.mesh
+    live = [a for a in mesh.axis_names
+            if mesh.shape[a] > 1 and a not in mesh.manual_axes]
+    return (mesh, tuple(a for a in BATCH_AXES if a in live),
+            tuple(a for a in live if a not in BATCH_AXES))
+
+
+def on_batch_axes(x, batch_dim: Optional[int] = 0):
+    """ZeRO-3's rule for an activation: ``x`` stays split over the data-like
+    axes on its batch dim and whole on every other (``batch_dim=None``: a
+    weight gathered whole for a use that is not a matmul). The mesh is the one
+    ``x`` is traced under, read from its type (the mesh of the step's
+    placed arguments), so model code calls this unconditionally: outside a
+    mesh, on one device, or where no data-like axis has more than one
+    device, ``x`` comes back as it is and nothing is added to the program.
+
+    Without it a weight that ``shard_params_fsdp`` split on its output
+    features reads to the partitioner as tensor parallelism: it gathers
+    the batch and splits the features, and where they do not divide (25
+    heads over 4 chips) reshuffles with all-to-alls. With it the only way
+    to a batch-split product is to gather the weight just before use, and
+    the weight's gradient, a sum over the split batch, leaves as a
+    reduce-scatter.
+
+    Left alone: a mesh with another axis of size > 1 (``model``, ``seq``,
+    ``expert``: those layouts split features on purpose), axes an enclosing
+    ``shard_map`` holds, and a batch dim the axes do not divide."""
+    mesh, axes, others = traced_mesh_axes(x)
+    if not axes or others:
+        return x
+    spec = [None] * x.ndim
+    if batch_dim is not None:
+        if x.shape[batch_dim] % math.prod(mesh.shape[a] for a in axes):
+            return x
+        spec[batch_dim] = axes if len(axes) > 1 else axes[0]
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, PartitionSpec(*spec)))
+
+
 def shard_params_fsdp(params, mesh: Mesh, min_size: int = 2**16):
-    """ZeRO-3-style parameter sharding: shard the largest dim of each big
-    param over the fsdp axis, replicate small ones. Native equivalent of the
+    """ZeRO-3-style parameter sharding: shard one dim of each big param
+    over the fsdp axis (which one: below), replicate small ones and those
+    no dim of which the axis divides. Native equivalent of the
     reference's FSDP pass-through (ray: train/torch/train_loop_utils.py:101).
+    This is the layout at rest only; that the step computes as ZeRO-3
+    (weights gathered for use, gradients reduce-scattered) is decided by
+    the activations staying on the batch axes (``on_batch_axes``).
     """
     if "fsdp" not in mesh.axis_names:
         return jax.tree.map(lambda _: replicated(mesh), params)
@@ -216,14 +270,21 @@ def shard_params_fsdp(params, mesh: Mesh, min_size: int = 2**16):
     def spec_for(x):
         if x.size < min_size:
             return replicated(mesh)
-        # Shard the largest divisible dimension.
-        dims = sorted(range(x.ndim), key=lambda d: -x.shape[d])
-        for d in dims:
-            if x.shape[d] % n_shard == 0:
-                spec = [None] * x.ndim
-                spec[d] = "fsdp"
-                return NamedSharding(mesh, PartitionSpec(*spec))
-        return replicated(mesh)
+        # Of the dimensions the axis divides, the first whose shard keeps
+        # whole (8, 128) tiles, else the largest. A shard that cuts a tile
+        # (GPT-2 XL's c_attn [1600, 4800] by columns: 1200 = 9.4 x 128) is
+        # padded by the TPU partitioner and pays a halo exchange at every
+        # gather and reduction.
+        dims = [d for d in range(x.ndim) if x.shape[d] % n_shard == 0]
+        if not dims:
+            return replicated(mesh)
+        whole_tiles = [d for d in dims if (x.shape[d] // n_shard)
+                       % (128 if d == x.ndim - 1 else 8) == 0]
+        d = whole_tiles[0] if whole_tiles else max(
+            dims, key=lambda d: x.shape[d])
+        spec = [None] * x.ndim
+        spec[d] = "fsdp"
+        return NamedSharding(mesh, PartitionSpec(*spec))
 
     return jax.tree.map(spec_for, params)
 

@@ -185,3 +185,48 @@ def test_fsdp_param_sharding():
     shardings = parallel.shard_params_fsdp(params, mesh)
     assert "fsdp" in str(shardings["big"].spec)
     assert shardings["small"].spec == ()
+
+
+@pytest.mark.parametrize("shape,spec", [
+    # GPT-2 XL's kernels: rows keep whole tiles (400 = 50 x 8), a quarter of
+    # the columns does not (1200, 1600 and 400 are no multiple of 128)
+    ((1600, 4800), ("fsdp", None)), ((1600, 6400), ("fsdp", None)),
+    ((6400, 1600), ("fsdp", None)), ((1600, 1600), ("fsdp", None)),
+    # 50257 rows do not divide: the columns, though 400 cuts a tile
+    ((50257, 1600), (None, "fsdp")),
+    # GPT-2 small: 192 rows a shard; where rows and columns both keep
+    # whole tiles the first wins
+    ((768, 2304), ("fsdp", None)), ((768, 3072), ("fsdp", None)),
+    # rows cut a tile (4 x 6), columns do not (4 x 128)
+    ((24, 4096), (None, "fsdp")),
+    # nothing divides: replicated
+    ((50257, 1601), ()),
+])
+def test_fsdp_splits_the_dimension_that_keeps_whole_tiles(shape, spec):
+    import jax
+
+    from ray_tpu import parallel
+
+    mesh = parallel.create_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    x = jax.ShapeDtypeStruct(shape, np.float32)
+    assert tuple(parallel.shard_params_fsdp({"w": x}, mesh)["w"].spec) == spec
+
+
+def test_create_mesh_raises_what_the_device_mesh_refuses(monkeypatch):
+    """Over every device the layout is ``create_device_mesh``'s, and what
+    it refuses is raised, not reshaped over; a partial device set is the
+    caller's own choice and is reshaped in the order given."""
+    import jax
+    from jax.experimental import mesh_utils as jmu
+
+    from ray_tpu import parallel
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("no assignment of these axes")
+
+    monkeypatch.setattr(jmu, "create_device_mesh", refuse)
+    with pytest.raises(NotImplementedError, match="no assignment"):
+        parallel.create_mesh({"data": 4, "fsdp": 2})
+    some = jax.devices()[4:]
+    mesh = parallel.create_mesh({"data": 2, "fsdp": 2}, devices=some)
+    assert list(mesh.devices.flat) == some

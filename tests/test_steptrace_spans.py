@@ -3,8 +3,9 @@ and in a process that holds jax one ``ray_tpu/<name>`` event in whatever
 profiler trace is being taken, on that trace's clock. Where each span is
 placed: ``train/report`` (session.report), ``data/next`` around
 ``data/fetch`` (the batch iterator), ``ckpt/setup|snapshot|commit``
-(save_pytree). ``ckpt/persist`` (the driver) is covered by the e2e test
-in test_steptrace.py.
+(save_pytree), ``train/shard_state`` (gpt2.shard_train_state).
+``ckpt/persist`` (the driver) is covered by the e2e test in
+test_steptrace.py.
 
 No assertion on a duration: only on what is recorded, in which order,
 with which count, and that the two clocks differ by one constant.
@@ -248,3 +249,26 @@ def test_save_pytree_records_setup_snapshot_commit(tmp_path, monkeypatch,
     monkeypatch.undo()
     back = load_pytree(str(tmp_path), tree, name="state")
     assert np.array_equal(np.asarray(back["w"]), np.asarray(tree["w"]))
+
+
+# ---------------------------------------------------------------------------
+# train/shard_state (gpt2.shard_train_state)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fsdp", [False, True], ids=["replicated", "fsdp"])
+def test_shard_train_state_records_one_span_with_the_bytes_placed(fsdp):
+    import jax
+
+    from ray_tpu import parallel
+    from ray_tpu.models import gpt2
+
+    _, params, _, opt_state = gpt2.make_train_state(
+        gpt2.GPT2Config.small_test(), jax.random.PRNGKey(0))
+    mesh = parallel.create_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    placed = gpt2.shard_train_state(params, opt_state, mesh, fsdp=fsdp)
+    span, = _spans("train/")
+    assert span["phase"] == "train/shard_state"
+    # float32 weights and both Adam moments, and optax's int32 step count
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert span["n"] == 3 * 4 * n_params + 4
+    assert span["n"] == sum(x.nbytes for x in jax.tree.leaves(placed))

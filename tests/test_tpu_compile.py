@@ -12,6 +12,11 @@ module-scoped fixture: only one process may load the TPU library, and it
 must be the xdist worker that was handed this file.
 """
 
+import collections
+import json
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -170,11 +175,10 @@ def test_train_step_data_parallel_4_chips(topo, no_compile_cache,
     assert planned < HBM_BYTES
 
 
-def _tensor_parallel_state(params, opt_state, mesh):
-    """Abstract params and optimizer state laid out as
-    ``shard_train_state_tp`` places real ones: moments like their parameter,
-    the rest replicated."""
-    p_sh = gpt2.shard_params_tp(params, mesh)
+def _placed_state(params, opt_state, p_sh, mesh):
+    """Abstract params and optimizer state laid out as the
+    ``shard_train_state*`` family places real ones: parameters by ``p_sh``,
+    moments like their parameter, the rest replicated."""
     treedef = jax.tree.structure(params)
     like_params = lambda node: jax.tree.structure(node) == treedef
     place = lambda tree, sh: jax.tree.map(
@@ -199,9 +203,152 @@ def test_train_step_tensor_parallel_2x2(topo, no_compile_cache,
     step, (params, opt_state, batch) = _train_step_args(
         attention, NamedSharding(mesh, PartitionSpec()),
         NamedSharding(mesh, PartitionSpec("data")), batch=2 * BATCH)
-    params, opt_state = _tensor_parallel_state(params, opt_state, mesh)
+    params, opt_state = _placed_state(
+        params, opt_state, gpt2.shard_params_tp(params, mesh), mesh)
     text, planned = _compile_step(compiled_steps, "data=2 x model=2", step,
                                   (params, opt_state, batch))
     assert "all-reduce" in text
     assert "tpu_custom_call" not in text
     assert planned < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# GPT-2 XL under fsdp=4: the cell gpt2-xl.step-fsdp4 as the benchmark runs it
+# ---------------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(?.*?\)?) "
+    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\(.*?channel_id=(\d+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_ASYNC_START = re.compile(
+    r"^\s*%async-collective-start[\w.\-]* = .* calls=%([\w.\-]+)")
+
+
+def _census(text):
+    """({kind: {channel: result type}}, kinds inside asynchronous pairs) of
+    the collectives in compiled TPU HLO text, one entry a channel: the
+    pieces of an asynchronous collective share theirs. The TPU compiler
+    writes a reduce-scatter as a fusion that calls a computation named
+    ``all-reduce-scatter`` (an all-reduce and a dynamic-slice inside), so
+    an all-reduce found in such a computation is a reduce-scatter; and an
+    overlapped collective as ``async-collective-start`` / ``-done``, whose
+    kind only the computation it calls says."""
+    kinds = collections.defaultdict(dict)
+    bodies, name = collections.defaultdict(list), ""
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header:
+            name = header.group(1)
+            continue
+        bodies[name].append(line)
+        m = _COLLECTIVE.match(line)
+        if m:
+            kind = m.group(2)
+            if kind == "all-reduce" and name.startswith("all-reduce-scatter"):
+                kind = "reduce-scatter"
+            kinds[kind].setdefault(int(m.group(3)), m.group(1))
+    in_async = collections.Counter()
+    for lines in list(bodies.values()):
+        for line in lines:
+            start = _ASYNC_START.match(line)
+            if start:
+                in_async.update({m.group(2) for m in map(
+                    _COLLECTIVE.match, bodies[start.group(1)]) if m})
+    return kinds, in_async
+
+
+def _xl_cell():
+    """The cell's configuration and traffic as ``perfbench/run.py`` finds
+    them by name in BENCHMARK.json."""
+    from perfbench import run
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        loaded = run.load_cell(json.load(f), "gpt2-xl.step-fsdp4")
+    return loaded["model"], loaded["traffic"]
+
+
+def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
+    """GPT-2 XL, all 48 layers, global batch 64 x 1024 over fsdp=4, state
+    placed as ``shard_train_state(..., fsdp=True)`` places it, on the mesh
+    ``create_mesh`` builds on such a host (``create_device_mesh``: the
+    chips in ring order, which is what lets the compiler walk a weight's
+    shards round the ring, each beside its part of the matmul). What the
+    compiled step must show: it fits a chip with room; its state leaves in
+    the shardings it came in (one compilation); nothing that moves between
+    chips is shaped like an activation, a score or a logit: all-gathers and
+    collective-permutes carry weights and their shards, the reduce-scatter
+    fusions and all-reduces carry gradients (the embeddings', and the
+    vectors that are replicated at rest: no weight matrix is all-reduced);
+    nothing is resharded by all-to-all; attention is the Pallas kernel per
+    batch shard, 144 calls with the recomputation; and every asynchronous
+    collective is an all-gather, which perfbench/metrics/allgather_ms.py
+    relies on."""
+    from jax.experimental import mesh_utils as jmu
+
+    from ray_tpu.parallel import mesh_utils
+
+    model_cfg, traffic = _xl_cell()
+    layers, width = model_cfg["n_layer"], model_cfg["n_embd"]
+    mesh = Mesh(jmu.create_device_mesh([4], devices=topo.devices), ("fsdp",))
+    config = gpt2.GPT2Config(
+        vocab_size=model_cfg["vocab_size"],
+        n_positions=model_cfg["n_positions"], n_embd=width, n_layer=layers,
+        n_head=model_cfg["n_head"], dtype=jnp.bfloat16,
+        remat=traffic["remat"], attention=model_cfg["train"]["attention"],
+        loss_chunks=model_cfg["train"]["loss_chunks"])
+    model, tx = gpt2.GPT2(config), gpt2.make_optimizer()
+
+    def state(rng):
+        params = gpt2.init_params(config, rng)[1]
+        return params, tx.init(params)
+
+    params, opt_state = jax.eval_shape(state, jax.random.PRNGKey(0))
+    params, opt_state = _placed_state(
+        params, opt_state, mesh_utils.shard_params_fsdp(params, mesh), mesh)
+    ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"]), jnp.int32,
+                               sharding=mesh_utils.data_sharding(mesh))
+    compiled = gpt2.build_train_step(model, tx, donate=True).lower(
+        params, opt_state, {"input_ids": ids, "labels": ids}).compile()
+
+    planned = _device_bytes(compiled)
+    assert 0.5 * 15.75 * 2**30 < planned < 15.75 * 2**30
+    out_params, out_opt_state, _ = compiled.output_shardings
+    for out, arg in zip(jax.tree.leaves((out_params, out_opt_state)),
+                        jax.tree.leaves((params, opt_state))):
+        assert out.is_equivalent_to(arg.sharding, len(arg.shape))
+
+    text = compiled.as_text()
+    kinds, in_async = _census(text)
+    assert not kinds["all-to-all"]
+    # a chip's batch or the whole one leading, then sequence, loss chunk or
+    # heads: what an activation, a score or a logit looks like
+    batch = traffic["batch"]
+    activation = re.compile(
+        rf"\[(?:{batch}|{batch // 4}),"
+        rf"(?:{traffic['seq']}|{traffic['seq'] // 8}|{model_cfg['n_head']}),")
+    for kind, found in kinds.items():
+        for shape in found.values():
+            assert not activation.search(shape), (kind, shape)
+    # each layer's four matrices are gathered, whole or shard by shard
+    assert len(kinds["all-gather"]) >= 2 * layers
+    assert len(kinds["all-gather"]) + len(kinds["collective-permute"]) \
+        >= 2 * 4 * layers
+    assert set(in_async) == {"all-gather"}
+    # gradients are scattered as they are summed: the embeddings' by the
+    # fusion, a matrix's as its shards (a quarter of the rows) go round
+    # the ring; what is all-reduced is vectors
+    assert kinds["reduce-scatter"]
+    quarter = f"bf16[{width // 4},{3 * width}]"
+    assert any(quarter in shape
+               for shape in kinds["collective-permute"].values())
+    assert 1 <= len(kinds["all-reduce"]) <= 8
+    for shape in kinds["all-reduce"].values():
+        assert not re.search(r"\[\d+,\d+", shape), shape
+    assert not re.search(r"f32\[\d+,25,1024,1024\]", text)
+    kernels = re.findall(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert collections.Counter(kernels) == {"flash_fwd": 2 * layers,
+                                            "flash_bwd": layers}
+    assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
