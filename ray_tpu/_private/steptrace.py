@@ -12,7 +12,8 @@ process keeps ONE fixed-size ring of small tuples recording
 - **spans** (``span(name, n)``): the user's step phases
   (``train.session.step_phase("data"|"h2d"|"compute"|"optimizer")``),
   the runtime's own (``train/report``, ``data/next`` around
-  ``data/fetch``, ``ckpt/setup|snapshot|commit`` in the worker,
+  ``data/fetch``, ``ckpt/setup|snapshot|commit`` in the worker and
+  ``save/commit`` from the thread that writes a save behind its steps,
   ``ckpt/persist`` in the driver), each with an optional count of rows
   or bytes, and **step boundaries** (auto-delimited at
   ``session.report()``). In a process that has imported jax a span is

@@ -338,6 +338,13 @@ class BackendExecutor:
                             "error": TrainingFailedError(
                                 f"worker {i} failed: {res['error']}\n"
                                 f"{res.get('traceback', '')}")}
+                elif kind == "checkpoint":
+                    # a save whose files became whole behind later steps
+                    # (session.report): persisted like any other, in the
+                    # order of the saves; its older metrics score it and
+                    # are nobody's result
+                    if i == 0:
+                        self._persist(res)
                 elif kind == "report":
                     watchdog.touch(i)
                     self._handle_report(i, res, result_callback)
@@ -368,15 +375,18 @@ class BackendExecutor:
         metrics = res["metrics"]
         if rank == 0:
             self._last_metrics = metrics
+        self._persist(res)
+        if rank == 0 and result_callback:
+            result_callback(metrics, self._ckpts.latest())
+
+    def _persist(self, res: dict):
         ck_data = res.get("checkpoint_data")
         ck_path = res.get("checkpoint_path")
         if ck_data is not None or ck_path is not None:
             # the copy into the trial directory runs here in the driver
             # while the worker trains on: in the timeline, in no metric
             with steptrace.span("ckpt/persist"):
-                self._ckpts.persist(ck_data, ck_path, metrics)
-        if rank == 0 and result_callback:
-            result_callback(metrics, self._ckpts.latest())
+                self._ckpts.persist(ck_data, ck_path, res["metrics"])
 
     # ------------------------------------------------------------------
     def _recover(self, old_generation: int):

@@ -23,6 +23,7 @@ import time
 from typing import Any, Dict, Optional
 
 from ray_tpu._private import steptrace
+from ray_tpu.air import checkpoint as checkpoint_mod
 from ray_tpu.air.checkpoint import Checkpoint
 
 
@@ -148,10 +149,25 @@ def health() -> Dict[str, Any]:
 def report(metrics: Dict[str, Any], *, checkpoint: Optional[Checkpoint] = None):
     """ray parity: ray.train.report — ship metrics (+ checkpoint) to the
     driver. Outside a session, a no-op with the metrics returned for
-    testability."""
+    testability.
+
+    A checkpoint counts as handed over when the driver may persist it. For
+    a directory whose files ``save_pytree`` is still writing behind the
+    loop that is when the write ends: the metrics go now, and the
+    checkpoint follows on the same queue with the metrics it came with,
+    in the order of the saves, before the loop's ``done`` and before a
+    drain report. It never replaces newer metrics in ``Result.metrics``.
+    A commit that failed raises here, in the loop."""
     s = _session
     if s is None:
         return metrics
+    commit = checkpoint_mod.commit_in_flight()
+    draining = s.drain_requested.is_set()
+    if draining and commit is not None:
+        # the executor requeues the gang at the drain report: what is to
+        # be restored from has to be on the queue before it
+        checkpoint_mod.finish_commit()
+        commit = None
     # step observatory: a report IS the natural step boundary — close the
     # current step interval and open the next (steptrace no-ops when off).
     # The span covers what the train loop waits for here: step mark,
@@ -161,21 +177,27 @@ def report(metrics: Dict[str, Any], *, checkpoint: Optional[Checkpoint] = None):
         s.step_count += 1
         s.last_progress = time.monotonic()
         payload = {"type": "report", "metrics": dict(metrics)}
+        late = None
         if checkpoint is not None:
             # Materialize to a directory so the driver (possibly another
             # node) persists it from shared storage; in-memory dicts ride
             # the queue.
-            payload["checkpoint_data"] = (
-                checkpoint._data if checkpoint._data is not None else None
-            )
-            payload["checkpoint_path"] = checkpoint._path
-        draining = s.drain_requested.is_set()
+            handed = {"checkpoint_data": checkpoint._data,
+                      "checkpoint_path": checkpoint._path}
+            if (commit is not None and checkpoint._path is not None
+                    and commit.writes(checkpoint._path)):
+                late = {"type": "checkpoint", "metrics": payload["metrics"],
+                        **handed}
+            else:
+                payload.update(handed)
         if draining:
             # spot preemption: this report is the step boundary the drain
             # was waiting for — tag it so the executor requeues WITHOUT
             # burning a failure-budget slot, then exit the loop cleanly
             payload["drain"] = True
         s.queue.put(payload)
+        if late is not None:
+            commit.then(lambda: s.queue.put(late))
     if draining:
         raise SystemExit("drain requested (preemption)")
     if s.stop_requested.is_set():

@@ -13,7 +13,7 @@ import traceback
 from typing import Any, Callable, List, Optional
 
 import ray_tpu
-from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.air.checkpoint import Checkpoint, finish_commit
 from ray_tpu.train import session as session_mod
 
 
@@ -79,12 +79,16 @@ class TrainWorker:
                 import inspect
 
                 sig = inspect.signature(train_fn)
-                if len(sig.parameters) >= 1:
-                    train_fn(config)
-                else:
-                    train_fn()
-                sess.queue.put({"type": "done"})
-            except SystemExit:
+                try:
+                    if len(sig.parameters) >= 1:
+                        train_fn(config)
+                    else:
+                        train_fn()
+                except SystemExit:  # a drain or a stop, raised by report()
+                    pass
+                # no done with a save outstanding: its checkpoint goes on
+                # the queue first, and a commit that failed is the error
+                finish_commit()
                 sess.queue.put({"type": "done"})
             except BaseException as e:  # noqa: BLE001
                 sess.queue.put({
