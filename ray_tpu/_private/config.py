@@ -65,9 +65,7 @@ class _Config:
 
 # --- flag declarations -------------------------------------------------------
 # Scheduling
-_flag("max_pending_lease_requests_per_scheduling_category", int, 10)
 _flag("scheduler_spread_threshold", float, 0.5)
-_flag("scheduler_top_k_fraction", float, 0.2)
 _flag("max_spillback_depth", int, 10)
 _flag("worker_lease_timeout_ms", int, 30_000)
 # Topology-aware gang scheduling (topology.py): nodes advertise torus
@@ -87,13 +85,10 @@ _flag("sched_repack_max_moves", int, 8)  # bundle migrations per repack
 # Workers
 _flag("num_workers_soft_limit", int, 16)
 _flag("worker_register_timeout_s", float, 60.0)
-_flag("idle_worker_killing_time_threshold_ms", int, 300_000)
 _flag("prestart_worker_first_driver", bool, False)
-_flag("worker_niceness", int, 0)
 # Objects
 _flag("max_direct_call_object_size", int, 100 * 1024)  # inline threshold (ray: 100KB)
 _flag("object_store_memory", int, 2 * 1024**3)
-_flag("object_store_eviction_fraction", float, 0.8)
 # Slab-arena object plane (slab_arena.py): leased write slabs + shared
 # index instead of one file per object. RAY_TPU_slab_arena=0 restores the
 # legacy per-object-file data path (and with it the native C++ writer).
@@ -112,7 +107,6 @@ _flag("fetch_pipeline_depth", int, 4)
 # fraction of a chunk and the concurrent window covers the rest
 _flag("fetch_head_chunk_bytes", int, 1 << 20)
 _flag("object_pull_timeout_s", float, 60.0)
-_flag("fetch_warn_timeout_s", float, 10.0)
 # Hole-punch reclamation (object_store.punch_holes): a periodic raylet
 # pass fallocate(PUNCH_HOLE|KEEP_SIZE)s the page-aligned interior of
 # dead entry ranges in sealed segments above the fragmentation
@@ -184,7 +178,6 @@ _flag("metrics_history_len", int, 120)
 _flag("metrics_scrape_timeout_s", float, 10.0)
 _flag("metrics_report_interval_s", float, 2.0)
 _flag("task_events_buffer_size", int, 10_000)
-_flag("event_stats", bool, True)
 # Worker-log streaming to drivers (ray: log_monitor.py tail cadence +
 # worker.py print_logs). log_to_driver is the master gate for the driver
 # subscription (RAY_TPU_LOG_TO_DRIVER=0 kills it cluster-wide); raylets
@@ -233,7 +226,7 @@ _flag("actor_sender_linger_s", float, 0.5)
 # surface via the owner's task_result stream + task events), "spec" =
 # legacy ack-after-scheduling (A/B lever)
 _flag("submit_ack_mode", str, "batch")
-# control-plane stage timing (BENCH_CONTROL_PLANE): per-stage histograms
+# control-plane stage timing (perf.run_control_plane_bench): per-stage histograms
 # (envelope build, id mint, result return, submit->run) on the submit
 # path; off = one attr check per call
 _flag("control_plane_stage_timing", bool, False)
@@ -388,7 +381,6 @@ _flag("reqtrace_scrape_timeout_s", float, 10.0)
 _flag("tune_experiment_snapshot_period_s", float, 10.0)
 # Train (ray: train/_internal/backend_executor timeouts)
 _flag("train_worker_start_timeout_s", float, 300.0)
-_flag("train_result_poll_timeout_s", float, 900.0)
 # Train fault tolerance (gang supervision + checkpointed recovery)
 # interval between liveness pings / health polls of the worker gang
 _flag("train_health_check_interval_s", float, 1.0)
@@ -401,15 +393,6 @@ _flag("train_recovery_enabled", bool, True)
 # SIGTERM drain: how long a worker may run past the signal to reach the
 # next step boundary and checkpoint before it hard-exits
 _flag("train_drain_grace_s", float, 30.0)
-# In-graph gradient collective mode for build_train_step: "" lets the
-# XLA partitioner insert the reduction from shardings (default,
-# byte-identical to the pre-flag path); "chunked" splits the psum into
-# train_ingraph_psum_chunks collectives for latency hiding; "quantized"
-# rides the int8 wire format (parallel/collectives.py twins). Usually
-# set per-run via JaxConfig(ingraph_psum=...), which fans it out to the
-# worker gang.
-_flag("train_ingraph_psum", str, "")
-_flag("train_ingraph_psum_chunks", int, 4)
 
 
 GLOBAL_CONFIG = _Config()

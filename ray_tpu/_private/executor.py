@@ -156,7 +156,7 @@ class TaskExecutor:
 
     # ------------------------------------------------------------------
     def _observe_submit_to_run(self, spec: TaskSpec):
-        """BENCH_CONTROL_PLANE dispatch stage: wall-clock gap between the
+        """Control-plane dispatch stage: wall-clock gap between the
         driver stamping the spec (TaskSpec.submit_time) and this worker
         starting on it — submit RPC + lease/queue wait + dispatch in one
         number (same-box clocks; the bench runs single-host)."""

@@ -128,6 +128,7 @@ class NativeLogStore:
         if lib is None or not getattr(lib, "_has_log_store", False):
             raise OSError("native library lacks the log store")
         self._lib = lib
+        self.fsync = fsync
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._h = ctypes.c_void_p(
             lib.rtpu_log_open(path.encode(), 1 if fsync else 0)
@@ -494,7 +495,8 @@ def make_store(persist_path: Optional[str],
     - ``kv://host:port``    -> RemoteKvStore (external KV server; head
       disk loss loses no metadata — kv_server.py, redis-analog)
     - plain path            -> native C++ log store when the library
-      loads, Python append-log fallback otherwise
+      loads, Python append-log fallback otherwise; either fsyncs every
+      append under the flag ``gcs_store_fsync``
 
     ``RAY_TPU_GCS_STORAGE`` overrides the configured path wholesale, so
     an operator can point an existing deployment at durable storage
@@ -510,13 +512,16 @@ def make_store(persist_path: Optional[str],
     if persist_path.startswith("kv://"):
         return RemoteKvStore(persist_path[len("kv://"):],
                              cluster_id=cluster_id)
+    from ray_tpu._private.config import GLOBAL_CONFIG
+
+    fsync = GLOBAL_CONFIG.gcs_store_fsync
     try:
         from ray_tpu._private import native_store
 
         if native_store.available():
             # Open refuses foreign formats (returns null -> OSError), so a
             # log written by the Python store falls through to it intact.
-            return NativeLogStore(persist_path)
+            return NativeLogStore(persist_path, fsync=fsync)
     except Exception:
         pass
-    return FileLogStore(persist_path)
+    return FileLogStore(persist_path, fsync=fsync)

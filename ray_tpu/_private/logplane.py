@@ -204,12 +204,12 @@ class LogDeduplicator:
         insertion order and ``first`` is never updated, so the scan stops
         at the first live window — feed() calls this per line, and a
         full scan there was O(lines x window-population), the measured
-        hot spot of the BENCH_LOG_OVERHEAD lane."""
+        hot spot of the log plane's overhead measurement."""
         now = time.monotonic() if now is None else now
         expired = []
         for line, entry in self._seen.items():  # NO dict copy: feed()
             # calls this per line, and copying the window population per
-            # line was the measured hot spot of BENCH_LOG_OVERHEAD
+            # line was that measurement's hot spot
             if not force and now - entry["first"] <= self.window_s:
                 break  # everything after was inserted later: still live
             expired.append((line, entry))

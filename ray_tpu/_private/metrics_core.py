@@ -13,9 +13,9 @@ Design constraints, in order:
    histogram observation is: one module-global load (the enable flag),
    one int multiply, one ``int.bit_length()`` (the log2 bucket index —
    no search, no branch chain), one list increment, one float add.
-   Measured ~0.3-0.6us on the bench box; the metrics-overhead lane in
-   bench.py gates the self-measured instrumentation share at <2% of the
-   sync-task hot path.
+   Measured ~0.3-0.6us on the bench box; ``util.metrics.
+   metrics_overhead_bench`` holds the self-measured instrumentation
+   share at <2% of the sync-task hot path (tests/test_metrics.py).
 2. **No locks on the record path.** CPython's GIL makes the individual
    ``list[i] += 1`` / ``float +=`` updates effectively atomic enough for
    *statistics*: a torn read-modify-write across threads can lose an

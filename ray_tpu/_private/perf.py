@@ -32,7 +32,7 @@ def _timeit(name: str, fn: Callable[[], int], warmup: int = 1,
 
 def _lat_hist():
     """Standalone log2 latency histogram (metrics_core) for per-op tail
-    tracking: the sequential benches time EACH op into it so BENCH_CORE
+    tracking: the sequential benches time EACH op into it so a row
     carries p50/p95/p99, not just the mean ops/s (tail regressions — a
     stalled dispatch pass, a GC pause per N ops — are invisible in
     means). Batched/pipelined benches keep mean-only: a per-op latency
@@ -443,7 +443,7 @@ _CP_STAGES = (
 
 
 def run_control_plane_bench(small: bool = False) -> List[dict]:
-    """Control-plane lane (``BENCH_CONTROL_PLANE=1``): run the two
+    """Control-plane lane: run the two
     sync-roundtrip microbenchmarks (the rows the fast-path levers target),
     then scrape the cluster-wide metrics snapshot and report the per-stage
     latency breakdown of one call — envelope build, id mint, submit RPC,
@@ -515,7 +515,7 @@ def run_control_plane_bench(small: bool = False) -> List[dict]:
 
 
 # ---------------------------------------------------------------------------
-# Collective backend lane (BENCH_COLLECTIVE=1)
+# Collective backend lane
 # ---------------------------------------------------------------------------
 
 

@@ -483,7 +483,7 @@ class Raylet:
                          "log_bytes_published": 0, "log_lines_truncated": 0}
         # log plane: "logs"-channel subscriber count piggybacked on the
         # heartbeat reply (-1 = unknown yet -> tail); tailer CPU seconds
-        # accumulate for the BENCH_LOG_OVERHEAD self-measured share
+        # accumulate for the log plane's self-measured share
         self._log_subscribers = -1
         self._log_tail_cpu_s = 0.0
         self._setup_metrics()
@@ -737,7 +737,7 @@ class Raylet:
             # thread_time, not perf_counter: the counter advertises CPU
             # seconds, and on a busy raylet wall time inside this loop is
             # mostly GIL/scheduler waits — it would overstate the share
-            # the BENCH_LOG_OVERHEAD lane gates by several x
+            # of the log plane by several x
             t0 = time.thread_time()
             batch = []
             for w in list(self.all_workers.values()):

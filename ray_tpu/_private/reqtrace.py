@@ -23,9 +23,8 @@ one module-global flag load + a tuple pack + a list store — no locks
 (GIL-atomic enough for telemetry; a torn write loses one record, never
 corrupts structure) — and the whole plane is flag-gated
 (``RAY_TPU_REQTRACE_ENABLED=0`` / cfg ``reqtrace_enabled``) so it costs
-nothing when off. The bench lane (BENCH_REQTRACE_OVERHEAD=1) gates the
-calibrated per-request record cost <2% of a proxy round trip and
-asserts zero ring records when disabled.
+nothing when off: the calibrated per-request record cost is to stay <2%
+of a proxy round trip, with zero ring records when disabled.
 
 Timestamps are ``time.time()`` (wall): queue-wait spans START on the
 caller's clock (the handle stamps the send time into the RPC envelope)

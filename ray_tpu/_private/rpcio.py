@@ -1265,7 +1265,7 @@ def enable_eager_tasks(loop: asyncio.AbstractEventLoop):
     until its first suspension instead of paying a full loop round-trip
     before its first byte of work. For the control plane's short RPC
     dispatch handlers this removes one scheduling hop per message — the
-    dominant per-op cost the BENCH_CORE analysis identified. Code that
+    dominant per-op cost of a sync round trip (``ray_tpu microbenchmark``). Code that
     NEEDS deferred execution must make it explicit (``_flush_writes``
     leads with ``await asyncio.sleep(0)``)."""
     factory = getattr(asyncio, "eager_task_factory", None)

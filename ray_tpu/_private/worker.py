@@ -83,7 +83,7 @@ def _tracing_ctx():
         return None
 
 
-# --- control-plane stage timing (BENCH_CONTROL_PLANE) ------------------
+# --- control-plane stage timing (perf.run_control_plane_bench) ---------
 # Gated on cfg.control_plane_stage_timing: the bench lane (and anyone
 # chasing a microsecond) gets per-stage latency histograms on the submit
 # path; the default path pays one attribute check per call. Per-stage
@@ -98,7 +98,7 @@ def _stage_record(stage: str, seconds: float):
 
         h = _STAGE_HISTS[stage] = mc.registry().histogram(
             "control_plane_stage_seconds",
-            "Per-stage control-plane latency (see BENCH_CONTROL_PLANE)",
+            "Per-stage control-plane latency (control_plane_stage_timing)",
             scale=mc.LATENCY,
         ).labels(stage=stage)
     h.record(seconds)

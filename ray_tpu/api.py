@@ -168,8 +168,8 @@ def _subscribe_worker_logs(cw):
 
     my_job = cw.job_id.hex() if cw.job_id else None
     dedup = logplane.LogDeduplicator(window_s=cfg.log_dedup_window_s)
-    # self-measurement: printed-line count + handler CPU for the
-    # BENCH_LOG_OVERHEAD lane (snapshot-time callbacks, zero hot-path
+    # self-measurement: printed-line count + handler CPU of the log
+    # plane (snapshot-time callbacks, zero hot-path
     # cost beyond the dict writes below)
     stats = {"lines": 0, "seconds": 0.0}
     reg = metrics_core.registry()

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +20,10 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ray_tpu.parallel.mesh_utils import on_batch_axes
+from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.parallel import train_step
+from ray_tpu.parallel.mesh_utils import (on_batch_axes, replicated,
+                                         shard_params_fsdp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,8 +36,8 @@ class GPT2Config:
     dropout: float = 0.0
     dtype: Any = jnp.bfloat16
     remat: bool = False
-    # "auto": by backend and shape (``auto_attention``): the "flash" path
-    # where it was measured faster than XLA's, else the "xla" path;
+    # "auto": by backend and shape (``ops.attention.auto_attention``): the
+    # "flash" path where it was measured faster than XLA's, else "xla";
     # "xla": jax.nn.dot_product_attention — on this runtime plain XLA
     # fusions that write the [B, H, T, T] scores to HBM, forward and saved
     # for backward, and no kernel;
@@ -68,44 +71,6 @@ class GPT2Config:
         return wte + wpe + self.n_layer * block + 2 * self.n_embd
 
 
-# Where "auto" takes the Pallas kernel: where it was measured faster than
-# XLA's attention on a v5e, forward plus backward at 16,384 tokens a call
-# (PERF.md section 6, PR 25). Head dimension 64: every multiple of 128 tried
-# from 512 to 2048 (512, 640, 768, 896, 1024, 1152, 1280, 1536, 2048: 2.4x
-# to 4.9x; at 256 and 384 XLA wins), where one grid step holds a whole
-# head, and 3072, 4096 and 8192 (3.3x, 5.2x, 47x), where it holds 1536 or
-# 2048 queries and keys. Past 2048 a length that 1024 does not divide can
-# leave the kernel 128-wide grid blocks (2176 = 17 x 128: 20.9 ms against
-# XLA's 17.3), so those stay with XLA. Head dimension 128: 512, 768, 1024,
-# 2048, 4096 (2.4x to 4.3x). The multiples of 128 between those lengths
-# are interpolated, lengths past 8192 extrapolated (XLA's [T, T] scores
-# take 718 ms a layer at 8192 and no longer fit at 16,384).
-_FLASH_MIN_SEQ = 512
-_FLASH_WHOLE_HEAD_SEQ = 2048  # ops.attention._MAX_RESIDENT
-_FLASH_HEAD_DIMS = (64, 128)
-
-
-def auto_attention(q) -> str:
-    """What ``attention="auto"`` runs for causal self-attention of ``q``
-    [B, T, H, d] on the default backend: "flash" or "xla". Decided from the
-    backend and from ``q``'s type alone, its shape and the mesh it is
-    traced under: a mesh axis the kernel's ``shard_map`` wrapper does not
-    map (``model`` under tensor parallelism, ``seq``, ``expert``) would
-    leave the Mosaic call to the partitioner, which refuses it, so there
-    "auto" stays on XLA's attention as it was before the kernel was chosen
-    anywhere (ROADMAP 8a). A kernel that then fails to lower raises."""
-    from ray_tpu.ops.attention import unmapped_mesh_axes
-
-    _, seq_len, _, head_dim = q.shape
-    measured = (head_dim in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
-                and seq_len % 128 == 0
-                and (seq_len <= _FLASH_WHOLE_HEAD_SEQ or seq_len % 1024 == 0))
-    if (jax.default_backend() == "tpu" and measured
-            and not unmapped_mesh_axes(q)):
-        return "flash"
-    return "xla"
-
-
 class CausalSelfAttention(nn.Module):
     config: GPT2Config
 
@@ -119,29 +84,15 @@ class CausalSelfAttention(nn.Module):
         q = on_batch_axes(q.reshape(B, T, heads, C // heads))
         k = on_batch_axes(k.reshape(B, T, heads, C // heads))
         v = on_batch_axes(v.reshape(B, T, heads, C // heads))
-        attention = c.attention
-        if attention == "auto":
-            attention = auto_attention(q)
-        if attention == "ring":
+        if c.attention == "ring":
             from ray_tpu.ops import ring_attention
 
             bhsd = lambda t: t.transpose(0, 2, 1, 3)
             y = ring_attention(
                 bhsd(q), bhsd(k), bhsd(v), axis_name=c.sp_axis, causal=True
             ).transpose(0, 2, 1, 3)
-        elif attention == "flash":
-            from ray_tpu.ops import flash_attention
-
-            bhsd = lambda t: t.transpose(0, 2, 1, 3)
-            y = flash_attention(
-                bhsd(q), bhsd(k), bhsd(v), causal=True
-            ).transpose(0, 2, 1, 3)
-        elif attention == "xla":
-            y = jax.nn.dot_product_attention(q, k, v, is_causal=True)
         else:
-            raise ValueError(
-                f"attention={c.attention!r}: expected auto, xla, flash or "
-                "ring")
+            y = causal_self_attention(q, k, v, c.attention)
         y = on_batch_axes(y.reshape(B, T, C))
         return nn.Dense(C, dtype=c.dtype, name="c_proj")(y)
 
@@ -325,129 +276,14 @@ def make_train_state(config: GPT2Config, rng, learning_rate: float = 3e-4,
     return model, params, tx, tx.init(params)
 
 
-class _StepByLayout:
-    """A train step ``(params, opt_state, batch) -> (params, opt_state,
-    loss)`` that takes its layout from its arguments. Called or lowered
-    with a state that lies on one device (or abstract and unplaced) it is
-    ``jitted()``, the plain jit. With a state placed over several devices
-    (``shard_train_state``) it is ``jitted((param shardings, optimizer
-    state shardings))``: the state comes back in the shardings it went in,
-    so the second step finds the program of the first, and the gradients
-    take the parameters' shardings. One jit a layout, kept; a loop that
-    hands back what it was given pays a walk over the leaves a call."""
-
-    def __init__(self, jitted):
-        self._jitted = jitted
-        self._by_layout = []  # [(leaf shardings, jit)]: one entry as a rule
-
-    def _for(self, params, opt_state):
-        state = (params, opt_state)
-        layout = tuple(getattr(x, "sharding", None)
-                       for x in jax.tree.leaves(state))
-        for known, fn in self._by_layout:
-            if known == layout:
-                return fn
-        spread = any(s is not None and len(s.device_set) > 1 for s in layout)
-        fn = self._jitted(jax.tree.unflatten(
-            jax.tree.structure(state), layout) if spread else None)
-        self._by_layout.append((layout, fn))
-        return fn
-
-    def __call__(self, params, opt_state, batch):
-        return self._for(params, opt_state)(params, opt_state, batch)
-
-    def lower(self, params, opt_state, batch):
-        return self._for(params, opt_state).lower(params, opt_state, batch)
-
-
-def build_train_step(model, tx, donate: bool = True, *,
-                     mesh: Optional[Mesh] = None,
-                     batch_axis: str = "data",
-                     ingraph_psum: Optional[str] = None,
-                     psum_chunks: Optional[int] = None):
-    """Jitted (params, opt_state, batch) -> (params, opt_state, loss).
-
-    Default path: sharding is inferred from the placed arguments (use
-    ``shard_train_state`` / ``shard_batch`` first): with batch sharded over
-    data axes and params replicated (DP) or fsdp-sharded (ZeRO-3), the XLA
-    partitioner inserts the gradient psum / reduce-scatter on ICI — the
-    TPU-native replacement for the reference's NCCL-DDP allreduce. The
-    state is returned in the shardings it came in (``_StepByLayout``), and
-    the model keeps its activations on the batch axes
-    (``mesh_utils.on_batch_axes``); on one device both add nothing.
-
-    ``ingraph_psum`` (or the ``train_ingraph_psum`` flag, usually armed
-    per-run via ``JaxConfig(ingraph_psum=...)``) swaps the partitioner-
-    inserted reduction for an EXPLICIT collective inside shard_map over
-    ``mesh``: "chunked" splits each gradient allreduce into
-    ``psum_chunks`` collectives XLA's latency-hiding scheduler can start
-    early (parallel/collectives.py chunked_psum); "quantized" rides the
-    int8 wire format (quantized_psum) for ~4x fewer cross-ICI bytes per
-    fp32 gradient. Both reduce to the MEAN over ``batch_axis``, matching
-    the DP semantics of the default path. Flag unset + no explicit mode
-    = the original jit, byte-identical.
-    """
-    from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
-
-    mode = _cfg.train_ingraph_psum if ingraph_psum is None else ingraph_psum
-    if mode and mesh is None:
-        raise ValueError(
-            f"ingraph_psum={mode!r} needs an explicit mesh: the collective "
-            "runs inside shard_map, which cannot be inferred from placement")
-
-    if not mode:
-        def jitted(state_shardings=None):
-            def step(params, opt_state, batch):
-                loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
-                if state_shardings:
-                    # a gradient leaves the backward pass laid out as its
-                    # parameter is at rest: the sum over the split batch
-                    # becomes a reduce-scatter, not an all-reduce
-                    grads = jax.lax.with_sharding_constraint(
-                        grads, state_shardings[0])
-                updates, opt_state = tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                return params, opt_state, loss
-
-            return jax.jit(
-                step, donate_argnums=(0, 1) if donate else (),
-                out_shardings=(*state_shardings, None)
-                if state_shardings else None)
-
-        return _StepByLayout(jitted)
-
-    from ray_tpu.parallel import collectives as col
-
-    chunks = int(psum_chunks if psum_chunks is not None
-                 else _cfg.train_ingraph_psum_chunks)
-    n = mesh.shape[batch_axis]
-    if mode == "chunked":
-        def reduce_grad(g):
-            return col.chunked_psum(g, batch_axis, chunks=chunks) / n
-    elif mode == "quantized":
-        def reduce_grad(g):
-            return col.quantized_psum(g, batch_axis, mean=True)
-    else:
-        raise ValueError(f"unknown ingraph_psum mode: {mode!r}")
-
-    def local_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
-        grads = jax.tree.map(reduce_grad, grads)
-        loss = jax.lax.pmean(loss, batch_axis)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    from ray_tpu.parallel.collectives import shard_map_norep
-
-    bspec = PartitionSpec(batch_axis)
-    fn = shard_map_norep(
-        local_step, mesh=mesh,
-        in_specs=(PartitionSpec(), PartitionSpec(),
-                  {"input_ids": bspec, "labels": bspec}),
-        out_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec()),
-    )
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+def build_train_step(model, tx, donate: bool = True):
+    """Jitted (params, opt_state, batch) -> (params, opt_state, loss):
+    ``parallel.build_train_step`` over this model's loss. The layout is
+    the placed arguments' (``shard_train_state`` / ``shard_batch`` first),
+    and the model keeps its activations on the batch axes
+    (``mesh_utils.on_batch_axes``); on one device both add nothing."""
+    return train_step.build_train_step(
+        lambda params, batch: loss_fn(params, model, batch), tx, donate)
 
 
 def build_train_step_sp(model, tx, mesh: Mesh, *, sp_axis: str = "sp",
@@ -482,36 +318,13 @@ def build_train_step_sp(model, tx, mesh: Mesh, *, sp_axis: str = "sp",
 
 def shard_train_state(params, opt_state, mesh: Mesh, fsdp: bool = False):
     """Place params + optimizer state on the mesh (DP replicate or FSDP
-    shard); optimizer moments inherit their parameter's sharding. Step
-    observatory: one span ``train/shard_state`` with the bytes of both
-    trees as its count (GPT-2 XL: 18.7 GB), beside ``ckpt/persist`` in
-    ``train_timeline``."""
-    from ray_tpu._private import steptrace
-    from ray_tpu.parallel.mesh_utils import replicated, shard_params_fsdp
-
+    shard); optimizer moments inherit their parameter's sharding
+    (``parallel.place_train_state``)."""
     if fsdp:
         p_sh = shard_params_fsdp(params, mesh)
     else:
         p_sh = jax.tree.map(lambda _: replicated(mesh), params)
-    p_treedef = jax.tree_util.tree_structure(params)
-
-    def is_params_like(node):
-        try:
-            return jax.tree_util.tree_structure(node) == p_treedef
-        except Exception:
-            return False
-
-    def place(node):
-        if is_params_like(node):
-            return jax.tree.map(jax.device_put, node, p_sh)
-        return jax.tree.map(lambda l: jax.device_put(l, replicated(mesh)), node)
-
-    nbytes = sum(getattr(leaf, "nbytes", 0)
-                 for leaf in jax.tree.leaves((params, opt_state)))
-    with steptrace.span("train/shard_state", nbytes):
-        params = jax.tree.map(jax.device_put, params, p_sh)
-        opt_state = jax.tree.map(place, opt_state, is_leaf=is_params_like)
-    return params, opt_state
+    return train_step.place_train_state(params, opt_state, p_sh)
 
 
 def shard_params_tp(params, mesh: Mesh, model_axis: str = "model"):
@@ -530,8 +343,6 @@ def shard_params_tp(params, mesh: Mesh, model_axis: str = "model"):
     GPT-2 scale the vocab matmul is cheap relative to the blocks, and a
     replicated wte keeps the fused cross-entropy local.
     """
-    from jax.sharding import NamedSharding
-
     col = PartitionSpec(None, model_axis)  # shard output features
     row = PartitionSpec(model_axis, None)  # shard input features
     colb = PartitionSpec(model_axis)       # bias of a column-sharded matmul
@@ -556,25 +367,8 @@ def shard_train_state_tp(params, opt_state, mesh: Mesh,
                          model_axis: str = "model"):
     """Place params + optimizer state with TP sharding (moments inherit
     their parameter's layout)."""
-    p_sh = shard_params_tp(params, mesh, model_axis)
-    params = jax.tree.map(jax.device_put, params, p_sh)
-    p_treedef = jax.tree_util.tree_structure(params)
-
-    def is_params_like(node):
-        try:
-            return jax.tree_util.tree_structure(node) == p_treedef
-        except Exception:
-            return False
-
-    from ray_tpu.parallel.mesh_utils import replicated
-
-    def place(node):
-        if is_params_like(node):
-            return jax.tree.map(jax.device_put, node, p_sh)
-        return jax.tree.map(lambda l: jax.device_put(l, replicated(mesh)), node)
-
-    opt_state = jax.tree.map(place, opt_state, is_leaf=is_params_like)
-    return params, opt_state
+    return train_step.place_train_state(
+        params, opt_state, shard_params_tp(params, mesh, model_axis))
 
 
 def make_pipeline_train_state(config: GPT2Config, rng, n_stages: int,
@@ -615,33 +409,12 @@ def shard_pipeline_state(pp_params, opt_state, mesh: Mesh,
                          axis: str = "pipeline"):
     """Place PP params + optimizer moments: stage leaves sharded over the
     pipeline axis (leading dim), everything else replicated."""
-    from ray_tpu.parallel.mesh_utils import replicated
-
-    def sharding_tree(tree):
-        stage_sh = NamedSharding(mesh, PartitionSpec(axis))
-        rep = replicated(mesh)
-        return {
-            "stages": jax.tree.map(lambda _: stage_sh, tree["stages"]),
-            "embed": jax.tree.map(lambda _: rep, tree["embed"]),
-        }
-
-    p_sh = sharding_tree(pp_params)
-    pp_params = jax.tree.map(jax.device_put, pp_params, p_sh)
-    p_treedef = jax.tree_util.tree_structure(pp_params)
-
-    def is_params_like(node):
-        try:
-            return jax.tree_util.tree_structure(node) == p_treedef
-        except Exception:
-            return False
-
-    def place(node):
-        if is_params_like(node):
-            return jax.tree.map(jax.device_put, node, p_sh)
-        return jax.tree.map(lambda l: jax.device_put(l, replicated(mesh)), node)
-
-    opt_state = jax.tree.map(place, opt_state, is_leaf=is_params_like)
-    return pp_params, opt_state
+    stage_sh = NamedSharding(mesh, PartitionSpec(axis))
+    p_sh = {
+        "stages": jax.tree.map(lambda _: stage_sh, pp_params["stages"]),
+        "embed": jax.tree.map(lambda _: replicated(mesh), pp_params["embed"]),
+    }
+    return train_step.place_train_state(pp_params, opt_state, p_sh)
 
 
 def build_train_step_pp(config: GPT2Config, tx, mesh: Mesh, *,

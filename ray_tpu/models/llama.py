@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.parallel import train_step
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -133,7 +135,12 @@ class LlamaAttention(nn.Module):
 
         new_cache = None
         if kv_cache is None:
-            # prefill / training: fused causal attention (flash on TPU)
+            # prefill / training: XLA's own attention, on this runtime
+            # plain fusions that write the [B, H, T, T] scores to HBM (8.491
+            # ms a layer against the Pallas kernel's 3.166 at GPT-2's
+            # geometry: PERF.md section 6, PR 25). ops.attention's kernel
+            # takes as many key-value heads as query heads, so grouped
+            # heads stay here (ROADMAP Reach 6-9)
             y = jax.nn.dot_product_attention(q, k, v, is_causal=True)
         else:
             ck, cv = kv_cache
@@ -234,16 +241,8 @@ def loss_fn(params, model, batch):
 def build_train_step(model, tx, donate: bool = True):
     """Jitted (params, opt_state, batch) -> (params, opt_state, loss);
     sharding inferred from placed args, same contract as gpt2's."""
-
-    def step(params, opt_state, batch):
-        import optax
-
-        loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    return train_step.build_train_step(
+        lambda params, batch: loss_fn(params, model, batch), tx, donate)
 
 
 def init_kv_caches(config: LlamaConfig, batch_size: int,

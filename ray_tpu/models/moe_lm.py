@@ -30,10 +30,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ray_tpu.models import gpt2
 from ray_tpu.ops import moe
+from ray_tpu.parallel import train_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,18 +179,13 @@ def make_train_state(config: MoELMConfig, rng, learning_rate: float = 3e-4):
 
 
 def build_train_step(model, tx, donate: bool = True):
-    """Single-chip / replicated step (local experts)."""
+    """Jitted (params, opt_state, batch) -> (params, opt_state, loss, lm,
+    aux): the loss and its two parts. Single-chip, replicated, or expert-
+    parallel by placement (``shard_train_state_ep``)."""
     coeff = model.config.aux_loss_coeff
-
-    def step(params, opt_state, batch):
-        (loss, (lm, aux)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(params, model, batch, coeff)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss, lm, aux
-
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    return train_step.build_train_step(
+        lambda params, batch: loss_fn(params, model, batch, coeff), tx,
+        donate, has_aux=True)
 
 
 def shard_train_state_ep(params, opt_state, mesh: Mesh, *,
@@ -205,8 +201,6 @@ def shard_train_state_ep(params, opt_state, mesh: Mesh, *,
 
     Optimizer moments inherit their parameter's sharding. Returns the
     placed (params, opt_state) plus a ``place_batch`` function."""
-    from jax.sharding import NamedSharding
-
     def spec_for(path) -> PartitionSpec:
         names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         if names and names[-1] in ("wi", "wo"):
@@ -216,21 +210,8 @@ def shard_train_state_ep(params, opt_state, mesh: Mesh, *,
     p_sharding = jax.tree_util.tree_map_with_path(
         lambda path, _leaf: NamedSharding(mesh, spec_for(path)), params
     )
-    params = jax.tree.map(jax.device_put, params, p_sharding)
-
-    p_treedef = jax.tree_util.tree_structure(params)
-
-    def place_opt(node):
-        # moments mirror params; scalar counters replicate
-        if jax.tree_util.tree_structure(node) == p_treedef:
-            return jax.tree.map(jax.device_put, node, p_sharding)
-        return jax.device_put(node, NamedSharding(mesh, PartitionSpec()))
-
-    opt_state = jax.tree.map(
-        place_opt, opt_state,
-        is_leaf=lambda n: jax.tree_util.tree_structure(n) == p_treedef
-        or not isinstance(n, (tuple, list)),
-    )
+    params, opt_state = train_step.place_train_state(
+        params, opt_state, p_sharding)
 
     bsharding = NamedSharding(mesh, PartitionSpec(data_axis))
 

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from ray_tpu.parallel import train_step
+
 
 # ---------------------------------------------------------------- ViT
 
@@ -215,19 +217,11 @@ def make_train_state(model, config, rng, learning_rate: float = 1e-3,
 def build_train_step(model, tx, donate: bool = True):
     """Jitted (params, opt_state, batch{'image','label'}) ->
     (params, opt_state, loss); DP/FSDP come from arg placement like gpt2."""
-    import optax
-
     def loss_of(params, batch):
         logits = model.apply({"params": params}, batch["image"])
         return classification_loss(logits, batch["label"])
 
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_of)(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    return train_step.build_train_step(loss_of, tx, donate)
 
 
 def synthetic_image_batch(rng, batch_size: int, image_size: int,

@@ -609,3 +609,57 @@ def unmapped_mesh_axes(x) -> tuple:
     which refuses it ("Mosaic kernels cannot be automatically
     partitioned"): a caller that chooses between paths asks here first."""
     return traced_mesh_axes(x)[2]
+
+
+# Where "auto" takes the Pallas kernel: where it was measured faster than
+# XLA's attention on a v5e, forward plus backward at 16,384 tokens a call
+# (PERF.md section 6, PR 25). Head dimension 64: every multiple of 128 tried
+# from 512 to 2048 (512, 640, 768, 896, 1024, 1152, 1280, 1536, 2048: 2.4x
+# to 4.9x; at 256 and 384 XLA wins), where one grid step holds a whole
+# head, and 3072, 4096 and 8192 (3.3x, 5.2x, 47x), where it holds 1536 or
+# 2048 queries and keys. Past ``_MAX_RESIDENT`` a length that 1024 does not
+# divide can leave the kernel 128-wide grid blocks (2176 = 17 x 128: 20.9 ms
+# against XLA's 17.3), so those stay with XLA. Head dimension 128: 512, 768,
+# 1024, 2048, 4096 (2.4x to 4.3x). The multiples of 128 between those
+# lengths are interpolated, lengths past 8192 extrapolated (XLA's [T, T]
+# scores take 718 ms a layer at 8192 and no longer fit at 16,384).
+_FLASH_MIN_SEQ = 512
+_FLASH_HEAD_DIMS = (64, 128)
+
+
+def auto_attention(q) -> str:
+    """What ``attention="auto"`` runs for causal self-attention of ``q``
+    [B, T, H, d] on the default backend: "flash" or "xla". Decided from the
+    backend and from ``q``'s type alone, its shape and the mesh it is
+    traced under: a mesh axis the kernel's ``shard_map`` wrapper does not
+    map (``model`` under tensor parallelism, ``seq``, ``expert``) would
+    leave the Mosaic call to the partitioner, which refuses it, so there
+    "auto" stays on XLA's attention as it was before the kernel was chosen
+    anywhere (ROADMAP 8a). A kernel that then fails to lower raises."""
+    _, seq_len, _, head_dim = q.shape
+    measured = (head_dim in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
+                and seq_len % 128 == 0
+                and (seq_len <= _MAX_RESIDENT or seq_len % 1024 == 0))
+    if (jax.default_backend() == "tpu" and measured
+            and not unmapped_mesh_axes(q)):
+        return "flash"
+    return "xla"
+
+
+def causal_self_attention(q, k, v, attention: str = "auto"):
+    """Causal self-attention of ``q``, ``k``, ``v`` in a model's own
+    [B, T, H, d] layout (as many key-value heads as query heads, one
+    length) by the path ``attention`` names: "flash", this module's kernel;
+    "xla", ``jax.nn.dot_product_attention``, on this runtime plain XLA
+    fusions that write the [B, H, T, T] scores to HBM; "auto", whichever
+    ``auto_attention`` finds for ``q``."""
+    if attention == "auto":
+        attention = auto_attention(q)
+    if attention == "flash":
+        bhsd = lambda t: t.transpose(0, 2, 1, 3)
+        return flash_attention(
+            bhsd(q), bhsd(k), bhsd(v), causal=True
+        ).transpose(0, 2, 1, 3)
+    if attention == "xla":
+        return jax.nn.dot_product_attention(q, k, v, is_causal=True)
+    raise ValueError(f"attention={attention!r}: expected auto, xla or flash")

@@ -1,11 +1,9 @@
 from ray_tpu.parallel.collectives import (
     all_gather,
-    chunked_psum,
     compiled_allreduce,
     pmean,
     ppermute_next,
     psum,
-    quantized_psum,
     reduce_scatter,
 )
 from ray_tpu.parallel.mesh_utils import (
@@ -18,22 +16,28 @@ from ray_tpu.parallel.mesh_utils import (
     replicated,
     shard_params_fsdp,
 )
+from ray_tpu.parallel.train_step import (
+    build_train_step,
+    place_train_state,
+    state_shardings,
+)
 
 __all__ = [
     "all_gather",
     "auto_mesh",
-    "chunked_psum",
+    "build_train_step",
     "compiled_allreduce",
-    "quantized_psum",
     "create_hybrid_mesh",
     "create_mesh",
     "data_sharding",
     "logical_to_physical",
     "mesh_from_cluster",
+    "place_train_state",
     "pmean",
     "ppermute_next",
     "psum",
     "reduce_scatter",
     "replicated",
     "shard_params_fsdp",
+    "state_shardings",
 ]

@@ -41,48 +41,6 @@ def reduce_scatter(x, axis: str, *, scatter_axis: int = 0):
                                 tiled=True)
 
 
-def chunked_psum(x, axis: str, *, chunks: int = 4):
-    """psum issued as ``chunks`` independent collectives over equal slices
-    of the flattened operand — the in-graph twin of the store backend's
-    chunked allreduce. Splitting the reduction lets XLA's latency-hiding
-    scheduler start moving chunk 0 while upstream compute producing later
-    chunks is still running, instead of waiting for one fused op's full
-    operand. For tensors smaller than ``chunks`` elements (or chunks<=1)
-    this degenerates to a plain psum."""
-    if chunks <= 1 or x.size < chunks:
-        return jax.lax.psum(x, axis_name=axis)
-    flat = x.reshape(-1)
-    pad = (-flat.size) % chunks
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    parts = jnp.split(flat, chunks)
-    out = jnp.concatenate([jax.lax.psum(p, axis_name=axis) for p in parts])
-    if pad:
-        out = out[: x.size]
-    return out.reshape(x.shape)
-
-
-def quantized_psum(x, axis: str, *, mean: bool = False):
-    """EQuARX-style int8 allreduce inside the graph: each shard block-
-    quantizes its contribution (symmetric, scale = max|x|/127), int8 wire
-    rides the all_gather, and every shard dequantizes + sums locally — so
-    the cross-ICI bytes drop ~4x for fp32 at the cost of one rounding per
-    contribution. Matches the store backend's ``quant="int8"`` semantics:
-    SUM (or MEAN with ``mean=True``) only; result is float32."""
-    xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf))
-    # zero-safe: all-zero block keeps scale 1 so dequant stays exact zeros
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
-    qs = jax.lax.all_gather(q, axis_name=axis)          # [W, ...] int8
-    scales = jax.lax.all_gather(scale, axis_name=axis)  # [W]
-    deq = qs.astype(jnp.float32) * scales.reshape((-1,) + (1,) * x.ndim)
-    out = jnp.sum(deq, axis=0)
-    if mean:
-        out = out / qs.shape[0]
-    return out
-
-
 def ppermute_next(x, axis: str, mesh: Mesh):
     """Rotate shards to the next rank on the axis ring (ring-attention step)."""
     n = mesh.shape[axis]

@@ -18,7 +18,7 @@ coroutine polls a caller-provided gauge reader (the bench lane passes a
 cluster-scrape of ``serve_replica_queue_depth``) into a
 queue-depth-over-time series.
 
-Used by ``BENCH_SERVE_LOAD=1 bench.py`` and importable for ad-hoc A/Bs:
+Importable for ad-hoc A/Bs (tests/test_reqtrace.py runs it CI-sized):
 
     from ray_tpu.serve.load_harness import run_load
     out = run_load(url, rps=200, duration_s=10, connections=1024)

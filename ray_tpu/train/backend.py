@@ -54,13 +54,6 @@ class JaxConfig(BackendConfig):
     collective spans interleave with the step's compute phase spans.
     collective_quant ("int8") makes the train_dp group's SUM/MEAN
     allreduces ride the block-quantized wire format.
-
-    ingraph_psum ("chunked" | "quantized") sets the IN-GRAPH gradient
-    collective mode on every worker (the train_ingraph_psum flag):
-    ``models.gpt2.build_train_step`` then reduces gradients with the
-    explicit chunked/int8 psum twins from parallel/collectives.py
-    instead of the partitioner-inserted fused psum. "" keeps the
-    default (byte-identical) path.
     """
 
     distributed: str = "auto"
@@ -68,8 +61,6 @@ class JaxConfig(BackendConfig):
     env_vars: Dict[str, str] = field(default_factory=dict)
     overlap_grads: bool = False
     collective_quant: str = ""
-    ingraph_psum: str = ""
-    ingraph_psum_chunks: int = 4
 
     @property
     def backend_cls(self):
@@ -95,17 +86,6 @@ def _enable_overlap():
     from ray_tpu.train import session
 
     session.set_overlap_grads(True)
-    return True
-
-
-def _set_ingraph_psum(mode: str, chunks: int):
-    """Sticky process default: every build_train_step on this worker
-    picks the mode up from the flag table (same posture as
-    set_overlap_grads — per-run config, not per-call plumbing)."""
-    from ray_tpu._private.config import GLOBAL_CONFIG
-
-    GLOBAL_CONFIG.update({"train_ingraph_psum": mode,
-                          "train_ingraph_psum_chunks": int(chunks)})
     return True
 
 
@@ -143,13 +123,6 @@ class _JaxBackend(Backend):
         if config.overlap_grads:
             ray_tpu.get(
                 [w.execute.remote(_enable_overlap) for w in worker_group.workers],
-                timeout=300,
-            )
-        if config.ingraph_psum:
-            ray_tpu.get(
-                [w.execute.remote(_set_ingraph_psum, config.ingraph_psum,
-                                  config.ingraph_psum_chunks)
-                 for w in worker_group.workers],
                 timeout=300,
             )
         # Host-level collective group for out-of-graph sync (weight

@@ -171,8 +171,7 @@ class Histogram(Metric):
 
 
 # ---------------------------------------------------------------------------
-# metrics-overhead bench (the <2% acceptance gate; see bench.py's
-# BENCH_METRICS_OVERHEAD lane and tests/test_metrics.py)
+# metrics-overhead bench (the <2% acceptance gate: tests/test_metrics.py)
 # ---------------------------------------------------------------------------
 def measure_record_cost(n: int = 200_000) -> float:
     """Seconds per histogram record() on this box — the primitive the

@@ -11,8 +11,8 @@ import os
 # Virtual 8-device CPU mesh for sharding tests. Both variables are read when
 # jax is first imported, so they are set here, before any test module loads;
 # cluster processes the fixtures spawn inherit them. Force-override: tests
-# must never take the chip (chip_smoke.py and bench.py run outside pytest
-# and do).
+# must never take the chip (chip_smoke.py and perfbench/run.py run outside
+# pytest and do).
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 ).strip()

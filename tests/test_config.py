@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from ray_tpu._private.config import GLOBAL_CONFIG
 
 
@@ -64,3 +66,37 @@ def test_flag_wiring_rpc_message_cap():
         assert rpcio._max_msg() == 123
     finally:
         GLOBAL_CONFIG.reset()
+
+
+def test_every_declared_flag_is_read_somewhere():
+    """A flag that no file under ``ray_tpu/`` but the table names does
+    nothing when it is set: delete it, or wire it up."""
+    import re
+
+    from ray_tpu._private import config
+
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(config.__file__)))
+    words = set()
+    for root, _, files in os.walk(pkg):
+        for name in files:
+            path = os.path.join(root, name)
+            if name.endswith(".py") and path != config.__file__:
+                with open(path, encoding="utf-8") as f:
+                    words.update(re.findall(r"\w+", f.read()))
+    assert sorted(set(config._FLAG_DEFS) - words) == []
+
+
+@pytest.mark.parametrize("fsync", [False, True])
+def test_gcs_store_fsync_reaches_the_log_store(tmp_path, monkeypatch, fsync):
+    """``make_store`` hands the flag to whichever log store it builds."""
+    from ray_tpu._private import gcs_store
+
+    monkeypatch.delenv("RAY_TPU_GCS_STORAGE", raising=False)
+    monkeypatch.setitem(GLOBAL_CONFIG._values, "gcs_store_fsync", fsync)
+    store = gcs_store.make_store(str(tmp_path / "gcs.log"))
+    try:
+        assert isinstance(store, (gcs_store.FileLogStore,
+                                  gcs_store.NativeLogStore))
+        assert store.fsync is fsync
+    finally:
+        store.close()

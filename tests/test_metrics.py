@@ -597,7 +597,7 @@ def test_two_node_scrape_smoke(ray_start_cluster):
 
 @pytest.mark.slow
 def test_metrics_overhead_under_2_percent(ray_start_regular_fn):
-    """The bench.py acceptance gate, as a test: self-measured
+    """The metrics plane's acceptance gate: self-measured
     instrumentation share of the sync-task hot path < 2% (paired with
     the profiler gate's posture — the end-to-end throughput delta is
     reported only, this box's A/A noise swamps it)."""

@@ -191,10 +191,8 @@ def test_flash_pallas_interpret_tiny_seq():
     ("cpu", 1024, 64, "xla"),
     ("gpu", 1024, 64, "xla"),
 ])
-def test_gpt2_auto_attention_reads_backend_and_shape(monkeypatch, backend,
-                                                     seq, head_dim, path):
-    from ray_tpu.models import gpt2
-
+def test_auto_attention_reads_backend_and_shape(monkeypatch, backend, seq,
+                                                head_dim, path):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     q = jax.ShapeDtypeStruct((2, seq, 3, head_dim), jnp.bfloat16)
     assert _auto_path(q) == path
@@ -202,10 +200,10 @@ def test_gpt2_auto_attention_reads_backend_and_shape(monkeypatch, backend,
 
 def _auto_path(q):
     """What ``auto_attention`` answers for ``q`` as a jitted step sees it."""
-    from ray_tpu.models import gpt2
+    from ray_tpu.ops import attention as A
 
     seen = []
-    jax.jit(lambda q: seen.append(gpt2.auto_attention(q))).lower(q)
+    jax.jit(lambda q: seen.append(A.auto_attention(q))).lower(q)
     return seen[0]
 
 
@@ -219,7 +217,7 @@ def _auto_path(q):
     (("fsdp", "seq"), (2, 2), "xla"),
     (("data", "expert"), (2, 2), "xla"),
 ])
-def test_gpt2_auto_attention_reads_the_mesh(monkeypatch, axes, shape, path):
+def test_auto_attention_reads_the_mesh(monkeypatch, axes, shape, path):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -231,17 +229,17 @@ def test_gpt2_auto_attention_reads_the_mesh(monkeypatch, axes, shape, path):
 
 
 @pytest.mark.parametrize("backend", ["tpu", "cpu"])
-def test_gpt2_auto_attention_dispatch(monkeypatch, backend):
+def test_auto_attention_dispatch(monkeypatch, backend):
     """``auto`` at GPT-2's geometry calls the flash kernel where the backend
     reads "tpu" and ``dot_product_attention`` elsewhere; ``xla`` never calls
     the kernel; an unknown name is refused."""
-    from ray_tpu import ops
     from ray_tpu.models import gpt2
+    from ray_tpu.ops import attention as A
 
     calls = []
-    real_flash, real_xla = ops.flash_attention, jax.nn.dot_product_attention
+    real_flash, real_xla = A.flash_attention, jax.nn.dot_product_attention
     monkeypatch.setattr(
-        ops, "flash_attention",
+        A, "flash_attention",
         lambda *a, **kw: calls.append("flash")
         or real_flash(*a, impl="scan", **kw))
     monkeypatch.setattr(

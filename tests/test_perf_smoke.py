@@ -98,7 +98,7 @@ def test_object_plane_ratio_floors(object_plane_rows):
 
 
 # ----------------------------------------------------------------------
-# control-plane stage lane (BENCH_CONTROL_PLANE): per-stage latency
+# control-plane stage lane (perf.run_control_plane_bench): per-stage latency
 # breakdown of the submit->lease->dispatch fast path
 # ----------------------------------------------------------------------
 

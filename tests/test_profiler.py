@@ -353,7 +353,7 @@ def test_profiler_overhead_under_5_percent(ray_start_regular_fn):
     ~1.3% measured here). The end-to-end throughput delta is also
     captured, but this box (2-CPU gVisor) has a ±30% throughput noise
     floor — no-profiler A/A runs vary 1.8x — so it only gets a sanity
-    bound; bench.py BENCH_PROFILER_OVERHEAD=1 reports both numbers."""
+    bound; ``profiler_overhead_bench`` reports both numbers."""
     from ray_tpu.util.profiling import profiler_overhead_bench
 
     out = profiler_overhead_bench(hz=100.0, batch=150, window_s=5.0)

@@ -457,7 +457,7 @@ def test_dashboard_and_agent_serve_endpoints(serve_cluster):
 def test_load_harness_smoke(serve_cluster):
     """The open-loop harness drives a 2-replica deployment through the
     real proxy and reports latency/TTFT percentiles + queue-depth
-    samples (CI-sized: the 1k-connection run lives in BENCH_SERVE_LOAD)."""
+    samples (CI-sized: ``run_load`` takes 1k connections as well)."""
     from ray_tpu.serve.load_harness import run_load
 
     @serve.deployment(num_replicas=2, max_ongoing_requests=256)

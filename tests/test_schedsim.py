@@ -11,7 +11,8 @@ The tier-1 gates for the gang scheduler's simulation harness:
   and the requeue bookkeeping stays consistent (all capacity returned
   once every gang departs).
 
-The full 10k-node acceptance run lives in the BENCH_SCHEDSIM bench lane.
+The full 10k-node acceptance run is ``python -m ray_tpu schedsim --nodes
+10000 --ab`` (about 9 s).
 """
 
 import time

@@ -432,84 +432,6 @@ def test_router_request_chains_from_llm_call_shapes():
 
 
 # ---------------------------------------------------------------------------
-# satellite: in-graph psum wiring parity (chunked/quantized vs plain)
-# ---------------------------------------------------------------------------
-
-_PARITY_SCRIPT = """
-import numpy as np
-import jax, jax.numpy as jnp
-from jax.sharding import Mesh
-from ray_tpu.models.gpt2 import GPT2Config, build_train_step, \
-    make_train_state
-
-cfg = GPT2Config.small_test(dtype=jnp.float32)
-model, params, tx, opt = make_train_state(cfg, jax.random.PRNGKey(0))
-mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("data",))
-ids = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0,
-                         cfg.vocab_size)
-batch = {"input_ids": ids, "labels": ids}
-
-def run(mode):
-    step = build_train_step(model, tx, donate=False, mesh=mesh,
-                            ingraph_psum=mode, psum_chunks=2)
-    p, _, l = step(jax.tree.map(jnp.copy, params),
-                   jax.tree.map(jnp.copy, opt), batch)
-    return jax.tree.leaves(jax.device_get(p)), float(l)
-
-p0, l0 = run("")           # flags-off: the original jit path
-p1, l1 = run("chunked")
-p2, l2 = run("quantized")
-d1 = max(float(np.max(np.abs(a - b))) for a, b in zip(p0, p1))
-d2 = max(float(np.max(np.abs(a - b))) for a, b in zip(p0, p2))
-assert abs(l0 - l1) < 1e-4 and d1 < 1e-4, \
-    f"chunked psum diverged from plain: dloss={l0-l1} dparam={d1}"
-assert abs(l0 - l2) < 5e-2 and d2 < 5e-2, \
-    f"quantized psum outside int8 tolerance: dparam={d2}"
-try:
-    build_train_step(model, tx, ingraph_psum="chunked")  # no mesh
-except ValueError:
-    pass
-else:
-    raise AssertionError("mode without mesh must raise")
-print("PARITY_OK", d1, d2)
-"""
-
-
-@pytest.mark.slow
-def test_build_train_step_ingraph_psum_parity():
-    """Subprocess: XLA_FLAGS must predate the jax import to get 4 host
-    devices, and other tests in this process have already imported it."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    r = subprocess.run([sys.executable, "-c", _PARITY_SCRIPT], env=env,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))),
-                       capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "PARITY_OK" in r.stdout
-
-
-def test_jax_config_carries_ingraph_psum():
-    from ray_tpu.train.backend import JaxConfig, _set_ingraph_psum
-    from ray_tpu._private.config import GLOBAL_CONFIG
-
-    cfg = JaxConfig(ingraph_psum="chunked", ingraph_psum_chunks=8)
-    assert cfg.ingraph_psum == "chunked"
-    old = (GLOBAL_CONFIG.train_ingraph_psum,
-           GLOBAL_CONFIG.train_ingraph_psum_chunks)
-    try:
-        _set_ingraph_psum("quantized", 2)  # what on_start fans out
-        assert GLOBAL_CONFIG.train_ingraph_psum == "quantized"
-        assert GLOBAL_CONFIG.train_ingraph_psum_chunks == 2
-    finally:
-        _set_ingraph_psum(*old)
-
-
-# ---------------------------------------------------------------------------
 # e2e: serve cluster
 # ---------------------------------------------------------------------------
 

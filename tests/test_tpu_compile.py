@@ -27,6 +27,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 
 from ray_tpu.models import gpt2
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.parallel import state_shardings
 
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 BATCH, SEQ = 16, 1024
@@ -175,20 +176,12 @@ def test_train_step_data_parallel_4_chips(topo, no_compile_cache,
     assert planned < HBM_BYTES
 
 
-def _placed_state(params, opt_state, p_sh, mesh):
+def _placed_state(params, opt_state, p_sh):
     """Abstract params and optimizer state laid out as the
-    ``shard_train_state*`` family places real ones: parameters by ``p_sh``,
-    moments like their parameter, the rest replicated."""
-    treedef = jax.tree.structure(params)
-    like_params = lambda node: jax.tree.structure(node) == treedef
-    place = lambda tree, sh: jax.tree.map(
+    ``shard_train_state*`` family places real ones."""
+    return jax.tree.map(
         lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
-        tree, sh)
-    rep = NamedSharding(mesh, PartitionSpec())
-    opt_state = jax.tree.map(
-        lambda node: place(node, p_sh) if like_params(node)
-        else _with_sharding(node, rep), opt_state, is_leaf=like_params)
-    return place(params, p_sh), opt_state
+        (params, opt_state), state_shardings(params, opt_state, p_sh))
 
 
 @pytest.mark.parametrize("attention", ["xla", "auto"])
@@ -204,7 +197,7 @@ def test_train_step_tensor_parallel_2x2(topo, no_compile_cache,
         attention, NamedSharding(mesh, PartitionSpec()),
         NamedSharding(mesh, PartitionSpec("data")), batch=2 * BATCH)
     params, opt_state = _placed_state(
-        params, opt_state, gpt2.shard_params_tp(params, mesh), mesh)
+        params, opt_state, gpt2.shard_params_tp(params, mesh))
     text, planned = _compile_step(compiled_steps, "data=2 x model=2", step,
                                   (params, opt_state, batch))
     assert "all-reduce" in text
@@ -306,7 +299,7 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
 
     params, opt_state = jax.eval_shape(state, jax.random.PRNGKey(0))
     params, opt_state = _placed_state(
-        params, opt_state, mesh_utils.shard_params_fsdp(params, mesh), mesh)
+        params, opt_state, mesh_utils.shard_params_fsdp(params, mesh))
     ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"]), jnp.int32,
                                sharding=mesh_utils.data_sharding(mesh))
     compiled = gpt2.build_train_step(model, tx, donate=True).lower(
