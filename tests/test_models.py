@@ -4,6 +4,8 @@ Parity model: the reference trains/serves these families through torch
 integrations (ray: release/air_tests/air_benchmarks/workloads/,
 python/ray/serve release LLM tests); here they are native flax modules."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,9 +140,55 @@ def test_resnet50_config_shapes():
     assert cfg.num_classes == 10
 
 
-def test_gpt2_chunked_loss_matches_fused():
-    """The bench's default loss path (loss_chunks>0) must agree with the
-    fused [B,T,V] loss in value AND gradients, masked and unmasked."""
+@pytest.mark.parametrize("dtype,value_tol,dh_tol,de_tol", [
+    (jnp.float32, 1e-5, 1e-6, 1e-6),
+    # bf16: the label's logit is the unrounded row dot, the fused loss
+    # picks it out of logits rounded to 8 bits of mantissa
+    (jnp.bfloat16, 5e-3, 1e-3, 3e-3),
+], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+def test_gpt2_chunked_loss_matches_fused(masked, dtype, value_tol, dh_tol,
+                                         de_tol):
+    """The bench's default loss path (loss_chunks>0) and its own
+    derivative rule must agree with the fused [B,T,V] loss and autodiff:
+    value, gradient for the hidden state and for the embedding, under a
+    cotangent that is not 1; and the undifferentiated call (no gradients
+    formed) gives the differentiated one's value."""
+    from ray_tpu.models import gpt2
+
+    B, T, C, V = 2, 64, 32, 97
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    hidden = jax.random.normal(k[0], (B, T, C), dtype)
+    embedding = 0.3 * jax.random.normal(k[1], (V, C), jnp.float32)
+    labels = jax.random.randint(k[2], (B, T), 0, V)
+    mask = ((jnp.arange(T)[None, :] < 48).astype(jnp.float32)
+            * jnp.ones((B, 1))) if masked else None
+
+    def fused(h, e):
+        return 3 * gpt2.fused_xent(h @ e.T.astype(h.dtype), labels, mask)
+
+    def chunked(h, e):
+        return 3 * gpt2.chunked_xent_tied(h, e, labels, mask, n_chunks=4)
+
+    want, (dh_want, de_want) = jax.value_and_grad(fused, (0, 1))(
+        hidden, embedding)
+    got, (dh, de) = jax.value_and_grad(chunked, (0, 1))(hidden, embedding)
+    assert abs(float(got) - float(want)) < value_tol * float(want)
+    assert float(chunked(hidden, embedding)) == pytest.approx(
+        float(got), rel=1e-6)
+    assert dh.dtype == hidden.dtype and de.dtype == embedding.dtype
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_allclose(f32(dh), f32(dh_want), atol=dh_tol)
+    np.testing.assert_allclose(f32(de), f32(de_want), atol=de_tol)
+    if masked:
+        # a token the mask leaves out moves nothing
+        assert not f32(dh)[:, 48:].any()
+
+
+def test_gpt2_chunked_loss_through_the_model_matches_fused():
+    """``loss_fn`` with ``loss_chunks`` against ``loss_fn`` without: value
+    and every parameter's gradient, the tied embedding's from the head and
+    from the lookup summed."""
     from ray_tpu.models import gpt2
 
     cfg = gpt2.GPT2Config.small_test()
@@ -148,16 +196,49 @@ def test_gpt2_chunked_loss_matches_fused():
     model, params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
     modelc = gpt2.GPT2(cfgc)
     batch = gpt2.synthetic_batch(jax.random.PRNGKey(1), 2, 64, cfg.vocab_size)
-    for mask in (None, (jnp.arange(64)[None, :] < 48).astype(jnp.float32)
-                 * jnp.ones((2, 1))):
-        b = dict(batch)
-        if mask is not None:
-            b["mask"] = mask
-        l1, g1 = jax.value_and_grad(gpt2.loss_fn)(params, model, b)
-        l2, g2 = jax.value_and_grad(gpt2.loss_fn)(params, modelc, b)
-        assert abs(float(l1) - float(l2)) < 1e-3
-        diffs = jax.tree.map(lambda a, c: float(jnp.abs(a - c).max()), g1, g2)
-        assert max(jax.tree.leaves(diffs)) < 1e-2
+    l1, g1 = jax.value_and_grad(gpt2.loss_fn)(params, model, batch)
+    l2, g2 = jax.value_and_grad(gpt2.loss_fn)(params, modelc, batch)
+    assert abs(float(l1) - float(l2)) < 1e-3
+    diffs = jax.tree.map(lambda a, c: float(jnp.abs(a - c).max()), g1, g2)
+    assert max(jax.tree.leaves(diffs)) < 1e-2
+
+
+def _vocabulary_wide(text, vocab, op):
+    """Lines of lowered StableHLO text that hold ``op`` and an array
+    dimension equal to ``vocab``."""
+    dim = re.compile(rf"tensor<(?:\d+x)*{vocab}x")
+    return [line for line in text.splitlines()
+            if f"stablehlo.{op}" in line and dim.search(line)]
+
+
+def test_gpt2_chunked_loss_is_three_vocabulary_matmuls_in_one_walk():
+    """What shows that the head's gradient is formed beside its loss: the
+    lowered ``value_and_grad`` of ``loss_fn`` holds three ``dot_general``s
+    of vocabulary width (logits, dh, dE: none recomputed) and one ``while``
+    (no second scan for a backward pass), and no gather out of the logits
+    for the label; the undifferentiated call holds one matmul."""
+    from ray_tpu.models import gpt2
+
+    vocab = 509  # equal to no other dimension of the program
+    cfg = gpt2.GPT2Config.small_test(vocab_size=vocab, loss_chunks=4,
+                                     attention="xla")
+    model, params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
+    batch = gpt2.synthetic_batch(jax.random.PRNGKey(1), 2, 64, vocab)
+
+    def loss(params):
+        return gpt2.loss_fn(params, model, batch)
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+    assert len(_vocabulary_wide(text, vocab, "dot_general")) == 3
+    assert text.count("stablehlo.while") == 1
+    # the lookups take rows of the table: no gather along the vocabulary
+    along = re.compile(rf"tensor<(?:\d+x)+{vocab}x")
+    assert not [line for line in text.splitlines()
+                if "stablehlo.gather" in line and along.search(line)]
+
+    text = jax.jit(loss).lower(params).as_text()
+    assert len(_vocabulary_wide(text, vocab, "dot_general")) == 1
+    assert text.count("stablehlo.while") == 1
 
 
 def test_flash_pallas_interpret_tiny_seq():
