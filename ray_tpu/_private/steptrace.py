@@ -229,6 +229,24 @@ def record_phase(name: str, start: float, end: float,
     _idx += 1
 
 
+def record_counters(name: str, values: Dict[str, float],
+                    step: Optional[int] = None):
+    """Numbers a step produced beside its loss (the terms of a loss, the
+    load of the experts a chip holds), under the step they belong to: one
+    record, kind ``counters``, written where the loop reports them."""
+    global _events, _idx
+    if not _enabled:
+        return
+    ring = _ring_slot()
+    if ring is None:
+        return
+    _events += 1
+    ring[_idx % _ring_size] = (
+        "counters", _idx, _step if step is None else step, name, _rank,
+        time.time(), dict(values))
+    _idx += 1
+
+
 def record_compile(name: str, start: float, end: float, first: bool):
     global _events, _idx
     if not _enabled:
@@ -437,6 +455,10 @@ def snapshot() -> List[dict]:
             out.append({"kind": "restart", "idx": rec[1], "cause": rec[2],
                         "generation": rec[3], "start": rec[4],
                         "end": rec[5]})
+        elif kind == "counters":
+            out.append({"kind": "counters", "idx": rec[1], "step": rec[2],
+                        "name": rec[3], "rank": rec[4], "start": rec[5],
+                        "end": rec[5], "values": rec[6]})
     return out
 
 
@@ -537,6 +559,7 @@ def merge_records(records: Sequence[dict]) -> Dict[str, Any]:
     compiles: List[dict] = []
     restarts: List[dict] = []
     chunks: List[dict] = []
+    counters: List[dict] = []
     for rec in records:
         kind = rec.get("kind")
         if kind == "coll":
@@ -551,11 +574,14 @@ def merge_records(records: Sequence[dict]) -> Dict[str, Any]:
             restarts.append(rec)
         elif kind == "chunk":
             chunks.append(rec)
+        elif kind == "counters":
+            counters.append(rec)
     phases.sort(key=lambda r: r["start"])
     steps.sort(key=lambda r: r["start"])
     compiles.sort(key=lambda r: r["start"])
     restarts.sort(key=lambda r: r["start"])
     chunks.sort(key=lambda r: r["start"])
+    counters.sort(key=lambda r: r["start"])
     return {
         "collectives": merge_collectives(colls),
         "phases": phases,
@@ -563,6 +589,7 @@ def merge_records(records: Sequence[dict]) -> Dict[str, Any]:
         "compiles": compiles,
         "restarts": restarts,
         "chunks": chunks,
+        "counters": counters,
     }
 
 

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.ops.xent import fused_xent
 from ray_tpu.parallel import train_step
 
 
@@ -164,18 +165,21 @@ class LlamaAttention(nn.Module):
         return out, new_cache
 
 
-class LlamaMLP(nn.Module):
-    config: LlamaConfig
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))`` of inner width ``intermediate``, no
+    biases: this family's feed-forward part, and the dense and shared parts
+    of ``models/mla_moe.py``."""
+    intermediate: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Any = nn.linear.default_kernel_init
 
     @nn.compact
     def __call__(self, x):
-        c = self.config
-        g = nn.Dense(c.intermediate, use_bias=False, dtype=c.dtype,
-                     name="gate_proj")(x)
-        u = nn.Dense(c.intermediate, use_bias=False, dtype=c.dtype,
-                     name="up_proj")(x)
-        return nn.Dense(c.n_embd, use_bias=False, dtype=c.dtype,
-                        name="down_proj")(nn.silu(g) * u)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  kernel_init=self.kernel_init)
+        g = dense(self.intermediate, name="gate_proj")(x)
+        u = dense(self.intermediate, name="up_proj")(x)
+        return dense(x.shape[-1], name="down_proj")(nn.silu(g) * u)
 
 
 class LlamaBlock(nn.Module):
@@ -189,7 +193,7 @@ class LlamaBlock(nn.Module):
             positions, kv_cache, cache_index,
         )
         x = x + h
-        x = x + LlamaMLP(c, name="mlp")(
+        x = x + SwiGLU(c.intermediate, c.dtype, name="mlp")(
             RMSNorm(c.rms_eps, c.dtype, name="post_attn_norm")(x)
         )
         return x, new_cache
@@ -232,8 +236,6 @@ def init_params(config: LlamaConfig, rng):
 
 
 def loss_fn(params, model, batch):
-    from ray_tpu.models.gpt2 import fused_xent
-
     logits, _ = model.apply({"params": params}, batch["input_ids"])
     return fused_xent(logits, batch["labels"], batch.get("mask"))
 

@@ -33,7 +33,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ray_tpu.models import gpt2
-from ray_tpu.ops import moe
+from ray_tpu.ops import moe, xent
 from ray_tpu.parallel import train_step
 
 
@@ -166,7 +166,7 @@ def loss_fn(params, model, batch, aux_coeff: float):
     logits, aux_vars = model.apply(
         {"params": params}, batch["input_ids"], mutable=["aux_loss"]
     )
-    lm = gpt2.fused_xent(logits, batch["labels"], batch.get("mask"))
+    lm = xent.fused_xent(logits, batch["labels"], batch.get("mask"))
     aux_terms = jax.tree.leaves(aux_vars.get("aux_loss", {}))
     aux = sum(aux_terms) / max(1, len(aux_terms)) if aux_terms else 0.0
     return lm + aux_coeff * aux, (lm, aux)

@@ -1,4 +1,5 @@
-"""ray_tpu.ops — TPU kernels (Pallas) and sequence-parallel attention."""
+"""ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, expert
+layers and the vocabulary's loss."""
 
 from ray_tpu.ops.attention import (
     attention_reference,
@@ -7,10 +8,11 @@ from ray_tpu.ops.attention import (
     online_block_update,
 )
 from ray_tpu.ops.ring_attention import ring_attention, ring_self_attention
-from ray_tpu.ops import moe
+from ray_tpu.ops import moe, xent
 
 __all__ = [
     "moe",
+    "xent",
     "attention_reference",
     "finalize_flash",
     "flash_attention",

@@ -95,6 +95,7 @@ def finalize_flash(m, l, acc, dtype):
 
 def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
     *lead, q_len, d = q.shape
+    d_v = v.shape[-1]
     k_len = k.shape[-2]
     block_k = min(block_k, k_len)
     nk = -(-k_len // block_k)
@@ -105,11 +106,11 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
     else:
         kp, vp = k, v
     kb = kp.reshape(*lead, nk, block_k, d)
-    vb = vp.reshape(*lead, nk, block_k, d)
+    vb = vp.reshape(*lead, nk, block_k, d_v)
 
     m0 = jnp.full((*lead, q_len), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((*lead, q_len), jnp.float32)
-    a0 = jnp.zeros((*lead, q_len, d), jnp.float32)
+    a0 = jnp.zeros((*lead, q_len, d_v), jnp.float32)
 
     def body(carry, ib):
         m, l, acc = carry
@@ -368,11 +369,16 @@ def _block_sizes(q_len: int, k_len: int, block_q: Optional[int],
             _largest_block(k_len, max(_MAX_RESIDENT, block_k), block_k))
 
 
-def _compiler_params(interpret: bool):
+def _compiler_params(interpret: bool, width: int):
+    """``width`` is the widest head dimension of the call: up to 128 lanes
+    the residents fit the compiler's own 16 MiB of VMEM; past it (keys of
+    192 are laid out as 256 lanes) the backward's residents take 16.5 MiB
+    at 2048 queries and keys, so the kernel asks for 32 of the chip's 128."""
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=32 * 2**20 if width > 128 else None)
 
 
 def _last_live_k(qi, res_q: int, res_k: int, offset: int, nk: int):
@@ -390,10 +396,10 @@ def _first_live_q(ki, res_q: int, res_k: int, offset: int, nq: int):
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
                   interpret: bool):
-    """q,k,v: (B, S, D) with batch*heads folded into B. -> (out, lse) with
-    lse (B, 1, S) float32."""
+    """q, k: (B, S, D), v: (B, S, Dv) with batch*heads folded into B.
+    -> (out (B, S, Dv), lse) with lse (B, 1, S) float32."""
     b, q_len, d = q.shape
-    k_len = k.shape[1]
+    k_len, d_v = k.shape[1], v.shape[2]
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
                                                   block_k, _FWD_TILES)
     nq, nk = q_len // res_q, k_len // res_k
@@ -414,23 +420,23 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
             pl.BlockSpec((None, res_q, d), lambda bi, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((None, res_k, d),
                          lambda bi, qi, ki: (bi, kmap(qi, ki), 0)),
-            pl.BlockSpec((None, d, res_k),
+            pl.BlockSpec((None, d_v, res_k),
                          lambda bi, qi, ki: (bi, 0, kmap(qi, ki))),
         ],
         out_specs=[
-            pl.BlockSpec((None, d, res_q), lambda bi, qi, ki: (bi, 0, qi)),
+            pl.BlockSpec((None, d_v, res_q), lambda bi, qi, ki: (bi, 0, qi)),
             pl.BlockSpec((None, 1, res_q), lambda bi, qi, ki: (bi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, d, q_len), q.dtype),
+            jax.ShapeDtypeStruct((b, d_v, q_len), q.dtype),
             jax.ShapeDtypeStruct((b, 1, q_len), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, res_q), jnp.float32),
             pltpu.VMEM((1, res_q), jnp.float32),
-            pltpu.VMEM((d, res_q), jnp.float32),
+            pltpu.VMEM((d_v, res_q), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=_compiler_params(interpret, max(d, d_v)),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, jnp.swapaxes(v, 1, 2))
@@ -441,7 +447,7 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              sm_scale: float, block_q: Optional[int],
                              block_k: Optional[int], interpret: bool):
     b, q_len, d = q.shape
-    k_len = k.shape[1]
+    k_len, d_v = k.shape[1], v.shape[2]
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
                                                   block_k, _BWD_TILES)
     nq, nk = q_len // res_q, k_len // res_k
@@ -455,6 +461,9 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     qspec = pl.BlockSpec((None, res_q, d),
                          lambda bi, ki, qi: (bi, qmap(ki, qi), 0))
     kspec = pl.BlockSpec((None, res_k, d), lambda bi, ki, qi: (bi, ki, 0))
+    vspec = pl.BlockSpec((None, res_k, d_v), lambda bi, ki, qi: (bi, ki, 0))
+    dospec = pl.BlockSpec((None, res_q, d_v),
+                          lambda bi, ki, qi: (bi, qmap(ki, qi), 0))
     rowspec = pl.BlockSpec((None, 1, res_q),
                            lambda bi, ki, qi: (bi, 0, qmap(ki, qi)))
     # one block of keys: its dQ^T is the whole of it; several: float32
@@ -466,25 +475,25 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             block_k=block_k, offset=offset, static=nq == nk == 1),
         grid=(b, nk, nq),
         in_specs=[
-            qspec, kspec, kspec,
+            qspec, kspec, vspec,
             pl.BlockSpec((None, d, res_k), lambda bi, ki, qi: (bi, 0, ki)),
-            qspec, rowspec, rowspec,
+            dospec, rowspec, rowspec,
         ],
         out_specs=[
             pl.BlockSpec((None, None, d, res_q),
                          lambda bi, ki, qi: (ki, bi, 0, qi)),
-            kspec, kspec,
+            kspec, vspec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nk, b, d, q_len), dq_dtype),
             jax.ShapeDtypeStruct((b, k_len, d), k.dtype),
-            jax.ShapeDtypeStruct((b, k_len, d), v.dtype),
+            jax.ShapeDtypeStruct((b, k_len, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((res_k, d), jnp.float32),
-            pltpu.VMEM((res_k, d), jnp.float32),
+            pltpu.VMEM((res_k, d_v), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=_compiler_params(interpret, max(d, d_v)),
         interpret=interpret,
         name="flash_bwd",
     )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
@@ -539,7 +548,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     impl: Optional[str] = None) -> jax.Array:
     """Flash attention over (..., seq, head_dim) inputs.
 
-    Accepts (b, h, s, d) or (b, s, d). With ``impl=None`` the platform
+    Accepts (b, h, s, d) or (b, s, d); ``q`` and ``k`` share a width and
+    ``v`` may have its own, which is the output's (keys of 192 against
+    values of 128: whatever lanes a width is padded to inside the kernel are
+    the kernel's business). With ``impl=None`` the platform
     decides: the Pallas kernel on "tpu" — where a kernel that fails to
     lower raises, it never falls back — and the scan formulation on any
     other backend. ``impl`` forces a path:
@@ -567,11 +579,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     def kernel(q, k, v):
         if q.ndim == 4:
-            b, h, s, d = q.shape
-            fold = lambda x: x.reshape(b * h, x.shape[-2], d)
+            b, h, s, _ = q.shape
+            fold = lambda x: x.reshape(b * h, *x.shape[-2:])
             out = _flash_pallas_diff(fold(q), fold(k), fold(v), causal,
                                      sm_scale, block_q, block_k, interpret)
-            return out.reshape(b, h, s, d)
+            return out.reshape(b, h, s, v.shape[-1])
         return _flash_pallas_diff(q, k, v, causal, sm_scale, block_q,
                                   block_k, interpret)
 
@@ -623,21 +635,36 @@ def unmapped_mesh_axes(x) -> tuple:
 # 1024, 2048, 4096 (2.4x to 4.3x). The multiples of 128 between those
 # lengths are interpolated, lengths past 8192 extrapolated (XLA's [T, T]
 # scores take 718 ms a layer at 8192 and no longer fit at 16,384).
+# Keys 192 wide and values 128 (PR 31; 32 heads, 16,384 tokens a call,
+# forward plus backward, kernel against the "xla" path written out for two
+# widths): 512: 8.10 against 9.57 ms; 1024: 9.51 / 16.75; 2048: 13.53 /
+# 30.57; 4096: 28.29 / 59.31 (1.2x to 2.3x); 8192: 50.66 ms, where XLA's
+# scores (8.6 GB) were not tried. At 128 / 128 and the same 32 heads the
+# kernel reads 6.95, 7.86, 10.66, 25.57 and 45.59 ms: the wider key costs
+# 10% to 27%, less than its 3/2 in QK^T, dK and dQ (a 192-wide operand is
+# laid out as 256 lanes and fills the MXU's depth one and a half times).
 _FLASH_MIN_SEQ = 512
-_FLASH_HEAD_DIMS = (64, 128)
+# (key width, value width) of a head the kernel was measured at. (192, 128),
+# latent attention's per-head form (128 + 64 rotary dimensions against 128):
+# PERF.md section 6, PR 31
+_FLASH_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 
-def auto_attention(q) -> str:
+def auto_attention(q, v=None) -> str:
     """What ``attention="auto"`` runs for causal self-attention of ``q``
-    [B, T, H, d] on the default backend: "flash" or "xla". Decided from the
-    backend and from ``q``'s type alone, its shape and the mesh it is
-    traced under: a mesh axis the kernel's ``shard_map`` wrapper does not
-    map (``model`` under tensor parallelism, ``seq``, ``expert``) would
-    leave the Mosaic call to the partitioner, which refuses it, so there
-    "auto" stays on XLA's attention as it was before the kernel was chosen
-    anywhere (ROADMAP 8a). A kernel that then fails to lower raises."""
+    [B, T, H, d] (and ``v`` [B, T, H, d_v], where values have a width of
+    their own) on the default backend: "flash" or "xla". Decided from the
+    backend and from the operands' types alone: the length, the pair (key
+    width, value width) of a head, which has to be one the kernel was
+    measured at (``_FLASH_HEAD_DIMS``), and the mesh ``q`` is traced under:
+    a mesh axis the kernel's ``shard_map`` wrapper does not map (``model``
+    under tensor parallelism, ``seq``, ``expert``) would leave the Mosaic
+    call to the partitioner, which refuses it, so there "auto" stays on
+    XLA's attention as it was before the kernel was chosen anywhere
+    (ROADMAP 8a). A kernel that then fails to lower raises."""
     _, seq_len, _, head_dim = q.shape
-    measured = (head_dim in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
+    widths = (head_dim, head_dim if v is None else v.shape[-1])
+    measured = (widths in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
                 and seq_len % 128 == 0
                 and (seq_len <= _MAX_RESIDENT or seq_len % 1024 == 0))
     if (jax.default_backend() == "tpu" and measured
@@ -649,17 +676,23 @@ def auto_attention(q) -> str:
 def causal_self_attention(q, k, v, attention: str = "auto"):
     """Causal self-attention of ``q``, ``k``, ``v`` in a model's own
     [B, T, H, d] layout (as many key-value heads as query heads, one
-    length) by the path ``attention`` names: "flash", this module's kernel;
-    "xla", ``jax.nn.dot_product_attention``, on this runtime plain XLA
-    fusions that write the [B, H, T, T] scores to HBM; "auto", whichever
-    ``auto_attention`` finds for ``q``."""
+    length; ``v`` may have a width of its own, the output's) by the path
+    ``attention`` names: "flash", this module's kernel; "xla",
+    ``jax.nn.dot_product_attention``, on this runtime plain XLA fusions
+    that write the [B, H, T, T] scores to HBM (it takes one width, so for
+    values of another the same program is written out:
+    ``attention_reference``); "auto", whichever ``auto_attention`` finds
+    for ``q`` and ``v``."""
     if attention == "auto":
-        attention = auto_attention(q)
+        attention = auto_attention(q, v)
+    bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
-        bhsd = lambda t: t.transpose(0, 2, 1, 3)
         return flash_attention(
             bhsd(q), bhsd(k), bhsd(v), causal=True
         ).transpose(0, 2, 1, 3)
     if attention == "xla":
+        if v.shape[-1] != q.shape[-1]:
+            return attention_reference(
+                bhsd(q), bhsd(k), bhsd(v), causal=True).transpose(0, 2, 1, 3)
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     raise ValueError(f"attention={attention!r}: expected auto, xla or flash")

@@ -1,0 +1,447 @@
+"""The latent-attention, routed-expert model (``ray_tpu.models.mla_moe``) and
+what it stands on (``ops.moe.topk_routing`` / ``held_expert_ffn``, the flash
+kernel at a key width and a value width of their own, ``ops.xent``), held to
+the plain reference ``perfbench/families/mla_moe_reference.py`` at small
+sizes on the CPU, seeded weights, no cluster."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import compare, worker
+from ray_tpu._private import steptrace
+from ray_tpu.models import gpt2, mla_moe
+from ray_tpu.ops import attention, moe, xent
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "perfbench", "tests", "configs",
+                       "tiny-mla-moe.json")) as _f:
+    TOY = json.load(_f)
+REFERENCE = worker.load_reference(ROOT, TOY)
+FAMILY = worker.load_family(ROOT, TOY)
+TRAFFIC = {"batch": 4, "seq": 64, "remat": True}
+
+
+def _tokens(seed, vocab=TOY["vocab_size"], batch=4, seq=64):
+    return np.random.default_rng(seed).integers(
+        0, vocab, (batch, seq + 1), dtype=np.int32)
+
+
+def _differences(dtype, seed=3, round_weights=False):
+    """The comparison the benchmark's worker makes, in small: the step's
+    loss and its gradient (from Adam's first moment) against the float32
+    reference -> (loss, gradient norm: relative; cosine)."""
+    model = dict(TOY, train=dict(TOY["train"], compute_dtype=dtype))
+    built = FAMILY.build(model, TRAFFIC, None)
+    params, opt_state = jax.jit(built.make_state)(jax.random.PRNGKey(seed))
+    tokens = _tokens(seed)
+    ref_loss, ref_grads = REFERENCE.over_microbatches(
+        model, params, tokens, 2, True, jnp.asarray)
+    if round_weights:
+        # the control: weights kept to 3 bits of mantissa (what fp8 e4m3
+        # holds), as perfbench/tests/test_yardstick.py does for GPT-2
+        def chop(x):
+            if x.ndim < 2:
+                return x
+            m, e = jnp.frexp(x)
+            return jnp.ldexp(jnp.round(m * 16) / 16, e)
+
+        params = jax.tree.map(chop, params)
+    batch = {"input_ids": jnp.asarray(tokens[:, :-1]),
+             "labels": jnp.asarray(tokens[:, 1:])}
+    _, opt_state, loss = built.step(params, opt_state, batch)
+    ns, nr, cos = (float(v) for v in compare.compare_gradients(
+        compare.system_gradient(opt_state, 0.9), ref_grads))
+    return (abs(float(loss) - float(ref_loss)) / float(ref_loss),
+            abs(ns - nr) / nr, cos)
+
+
+def test_float32_step_is_the_reference_to_rounding():
+    d_loss, d_norm, cos = _differences("float32")
+    assert d_loss <= 1e-5 and d_norm <= 1e-4 and cos >= 0.99999, (
+        d_loss, d_norm, cos)
+
+
+# bfloat16 against float32 at the toy size, seeds 0-7 read on the CPU: loss
+# 1.3e-7 to 1.9e-5, gradient norm 1.5e-4 to 1.1e-3, cosine 0.99989 to
+# 0.99997. The control (weights kept to 3 bits of mantissa, seeds 3 and 5):
+# loss 2.6e-5 and 1.0e-4, norm 1.0e-3 and 2.5e-3, cosine 0.9982: it is the
+# cosine that tells them apart, so its limit lies between the two readings
+# (1 - cosine: 1.1e-4 against 1.8e-3, limit 5e-4); loss and norm at 5x the
+# worst reading.
+BF16_LIMITS = {"loss": 1e-4, "norm": 5e-3, "cosine": 0.9995}
+
+
+@pytest.mark.parametrize("seed", [3, 7])
+def test_bfloat16_step_is_inside_the_toy_limits(seed):
+    d_loss, d_norm, cos = _differences("bfloat16", seed)
+    assert d_loss <= BF16_LIMITS["loss"], d_loss
+    assert d_norm <= BF16_LIMITS["norm"], d_norm
+    assert cos >= BF16_LIMITS["cosine"], cos
+
+
+def test_a_step_in_a_lower_precision_is_outside_them():
+    d_loss, d_norm, cos = _differences("bfloat16", round_weights=True)
+    assert (d_loss > BF16_LIMITS["loss"] or d_norm > BF16_LIMITS["norm"]
+            or cos < BF16_LIMITS["cosine"]), (d_loss, d_norm, cos)
+
+
+# ----------------------------------------------------------------------
+# the expert layer
+# ----------------------------------------------------------------------
+
+def _layer(seed=0, T=96, d=32, width=16, experts=8, k=3):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return {
+        "x": jax.random.normal(keys[0], (T, d)),
+        "router": jax.random.normal(keys[1], (d, experts)) * 0.3,
+        "bias": jnp.zeros((experts,)),
+        "wi": jax.random.normal(keys[2], (experts, d, 2 * width)) * 0.2,
+        "wo": jax.random.normal(keys[3], (experts, width, d)) * 0.2,
+        "k": k,
+    }
+
+
+def _held_part(layer, index, of, bias=None):
+    held = layer["wi"].shape[0] // of
+    experts, weights = moe.topk_routing(
+        layer["x"], layer["router"],
+        layer["bias"] if bias is None else bias, layer["k"], 2.5)
+    rows = slice(index * held, (index + 1) * held)
+    return moe.held_expert_ffn(layer["x"], experts, weights,
+                               layer["wi"][rows], layer["wo"][rows],
+                               index=index, of=of)
+
+
+def _reference_layer(layer, index, of, shared, bias=None):
+    """The reference's expert layer (routed part of shard ``index`` of
+    ``of`` plus the shared expert) on ``layer``'s weights."""
+    held = layer["wi"].shape[0] // of
+    rows = slice(index * held, (index + 1) * held)
+    p = {"router": layer["router"],
+         "router_bias": layer["bias"] if bias is None else bias,
+         "experts_wi": layer["wi"][rows], "experts_wo": layer["wo"][rows],
+         "shared_experts": shared}
+    m = {"expert_shard": {"index": index, "of": of},
+         "num_experts_per_tok": layer["k"], "norm_topk_prob": True,
+         "routed_scaling_factor": 2.5}
+    with jax.default_matmul_precision("highest"):
+        return REFERENCE._experts(layer["x"][None], p, m)[0]
+
+
+def _shared(d=32, width=16, seed=9):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return {"gate_proj": {"kernel": jax.random.normal(keys[0], (d, width))},
+            "up_proj": {"kernel": jax.random.normal(keys[1], (d, width))},
+            "down_proj": {"kernel": jax.random.normal(keys[2], (width, d))}}
+
+
+@pytest.mark.parametrize("of", [2, 4, 8])
+def test_the_shares_add_up_to_the_uncut_layer(of):
+    """The routed parts of all ``of`` shares, with the shared expert counted
+    once, are the uncut reference layer."""
+    layer, shared = _layer(), _shared()
+    parts = [_held_part(layer, i, of) for i in range(of)]
+    routed = sum(y for y, _ in parts)
+    with jax.default_matmul_precision("highest"):
+        once = REFERENCE._swiglu(layer["x"], shared)
+    whole = _reference_layer(layer, 0, 1, shared)
+    np.testing.assert_allclose(routed + once, whole, rtol=2e-4, atol=2e-5)
+    # every pair fell on exactly one share
+    assert sum(int(n.sum()) for _, n in parts) == 96 * layer["k"]
+    # and each share is the reference's share
+    for i in (0, of - 1):
+        np.testing.assert_allclose(
+            parts[i][0] + once, _reference_layer(layer, i, of, shared),
+            rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("forced,pairs", [("all", 96 * 3), ("none", 0)])
+def test_no_pair_of_a_held_expert_is_dropped(forced, pairs):
+    """A router forced to send every token's every pair to the held experts
+    (the row buffer full), and one that sends none: the layer computes
+    exactly the pairs that fall on it."""
+    layer, of, index = _layer(seed=2), 2, 1        # holds experts 4..7
+    push = 10.0 if forced == "all" else -10.0
+    bias = jnp.where(jnp.arange(8) >= 4, push, 0.0)
+    y, tokens = _held_part(layer, index, of, bias)
+    assert int(tokens.sum()) == pairs
+    want = _reference_layer(layer, index, of, _shared(), bias)
+    with jax.default_matmul_precision("highest"):
+        want = want - REFERENCE._swiglu(layer["x"], _shared())
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+    if forced == "none":
+        assert not np.asarray(y).any()
+
+
+def test_routing_is_the_published_rule_on_a_hand_made_case():
+    """Two tokens, four experts, two a token. The bias moves the selection
+    (token 0 takes expert 3 for expert 1) and not the weights, which are the
+    chosen experts' own scores over their sum, times the scale."""
+    logits = jnp.log(jnp.array([[0.8, 0.6, 0.3, 0.5],
+                                [0.2, 0.7, 0.9, 0.1]]) /
+                     (1 - jnp.array([[0.8, 0.6, 0.3, 0.5],
+                                     [0.2, 0.7, 0.9, 0.1]])))
+    x, router = jnp.eye(2), logits                     # x @ router = logits
+    experts, weights = moe.topk_routing(x, router, jnp.zeros(4), 2, 2.5)
+    assert np.asarray(experts).tolist() == [[0, 1], [2, 1]]
+    np.testing.assert_allclose(
+        weights, [[2.5 * 0.8 / 1.4, 2.5 * 0.6 / 1.4],
+                  [2.5 * 0.9 / 1.6, 2.5 * 0.7 / 1.6]], rtol=1e-5)
+    bias = jnp.array([0.0, 0.0, 0.0, 0.2])             # 0.5 + 0.2 > 0.6
+    experts, weights = moe.topk_routing(x, router, bias, 2, 2.5)
+    assert np.asarray(experts).tolist() == [[0, 3], [2, 1]]
+    np.testing.assert_allclose(
+        weights, [[2.5 * 0.8 / 1.3, 2.5 * 0.5 / 1.3],
+                  [2.5 * 0.9 / 1.6, 2.5 * 0.7 / 1.6]], rtol=1e-5)
+    # the bias takes no gradient, the router does
+    g_bias, g_router = jax.grad(
+        lambda b, r: moe.topk_routing(x, r, b, 2, 2.5)[1][0, 0],
+        argnums=(0, 1))(bias, router)
+    assert not np.asarray(g_bias).any() and np.asarray(g_router).any()
+
+
+def test_the_expert_layers_gradients_are_the_dense_computations():
+    layer, of, index = _layer(seed=4), 2, 0
+    shared = _shared()
+
+    def system(x, router, wi, wo):
+        y, _ = _held_part(dict(layer, x=x, router=router, wi=wi, wo=wo),
+                          index, of)
+        return (y ** 2).sum()
+
+    def plain(x, router, wi, wo):
+        part = dict(layer, x=x, router=router, wi=wi, wo=wo)
+        with jax.default_matmul_precision("highest"):
+            y = (_reference_layer(part, index, of, shared)
+                 - REFERENCE._swiglu(x, shared))
+        return (y ** 2).sum()
+
+    args = (layer["x"], layer["router"], layer["wi"], layer["wo"])
+    for got, want in zip(jax.grad(system, argnums=(0, 1, 2, 3))(*args),
+                         jax.grad(plain, argnums=(0, 1, 2, 3))(*args)):
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-4)
+
+
+# ----------------------------------------------------------------------
+# attention with keys wider than values
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["pallas_interpret", "scan"])
+@pytest.mark.parametrize("widths", [(48, 32), (24, 16), (32, 32)],
+                         ids=["48x32", "24x16", "equal"])
+def test_flash_paths_at_a_key_width_and_a_value_width(impl, widths):
+    d_qk, d_v = widths
+    keys = jax.random.split(jax.random.PRNGKey(d_qk), 4)
+    q = jax.random.normal(keys[0], (2, 256, d_qk))
+    k = jax.random.normal(keys[1], (2, 256, d_qk))
+    v = jax.random.normal(keys[2], (2, 256, d_v))
+    g = jax.random.normal(keys[3], (2, 256, d_v))
+
+    def through(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: (fn(q, k, v) * g).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    got = through(lambda q, k, v: attention.flash_attention(
+        q, k, v, causal=True, impl=impl, block_q=128, block_k=128))
+    want = through(lambda q, k, v: attention.attention_reference(
+        q, k, v, causal=True))
+    assert got[0].shape == () and got[1][2].shape == v.shape
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5)
+
+
+def test_auto_attention_decides_by_both_widths(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = lambda d: jax.ShapeDtypeStruct((2, 8192, 32, d), jnp.bfloat16)
+    assert attention.auto_attention(shape(192), shape(128)) == "flash"
+    assert attention.auto_attention(shape(128), shape(128)) == "flash"
+    assert attention.auto_attention(shape(64)) == "flash"
+    # a pair the kernel was not measured at stays with XLA's program
+    assert attention.auto_attention(shape(192), shape(64)) == "xla"
+    assert attention.auto_attention(shape(192)) == "xla"
+
+
+def test_the_xla_path_takes_values_of_another_width():
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(keys[0], (2, 64, 4, 24))
+    k = jax.random.normal(keys[1], (2, 64, 4, 24))
+    v = jax.random.normal(keys[2], (2, 64, 4, 16))
+    bhsd = lambda t: t.transpose(0, 2, 1, 3)
+    want = attention.attention_reference(bhsd(q), bhsd(k), bhsd(v),
+                                         causal=True).transpose(0, 2, 1, 3)
+    for path in ("xla", "flash", "auto"):
+        np.testing.assert_allclose(
+            attention.causal_self_attention(q, k, v, path), want,
+            rtol=1e-4, atol=1e-5)
+
+
+def test_equal_widths_ask_the_compiler_for_nothing_new():
+    """Up to 128 lanes the kernels' compiler parameters are what they were:
+    no VMEM limit of their own."""
+    assert attention._compiler_params(False, 64).vmem_limit_bytes is None
+    assert attention._compiler_params(False, 128).vmem_limit_bytes is None
+    assert attention._compiler_params(False, 192).vmem_limit_bytes == 2**25
+
+
+# ----------------------------------------------------------------------
+# the loss walk in its new home
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_walk_over_an_untied_head_is_the_fused_loss(masked):
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    h = jax.random.normal(keys[0], (2, 32, 16))
+    head = jax.random.normal(keys[1], (50, 16)) * 0.3
+    labels = jax.random.randint(keys[2], (2, 32), 0, 50)
+    mask = (jax.random.uniform(keys[3], (2, 32)) > 0.3) if masked else None
+
+    def fused(h, head):
+        return xent.fused_xent(h @ head.T, labels, mask)
+
+    def walked(h, head):
+        return xent.chunked_xent(h, head, labels, mask, n_chunks=4)
+
+    got = jax.value_and_grad(walked, argnums=(0, 1))(h, head)
+    want = jax.value_and_grad(fused, argnums=(0, 1))(h, head)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_gpt2s_loss_is_the_moved_walk_bit_for_bit():
+    """``gpt2.loss_fn`` calls ``ops.xent.chunked_xent`` with the tied
+    embedding: the names it kept are the moved functions, and its loss and
+    gradient are those of the direct call, to the bit."""
+    assert gpt2.chunked_xent is xent.chunked_xent
+    assert gpt2.fused_xent is xent.fused_xent
+    config = gpt2.GPT2Config.small_test(loss_chunks=4, dtype=jnp.float32)
+    model, params = gpt2.init_params(config, jax.random.PRNGKey(0))
+    batch = gpt2.synthetic_batch(jax.random.PRNGKey(1), 2, 32,
+                                 config.vocab_size)
+
+    def direct(params):
+        hidden = model.apply({"params": params}, batch["input_ids"],
+                             return_hidden=True)
+        return xent.chunked_xent(hidden, params["wte"]["embedding"],
+                                 batch["labels"], None, n_chunks=4)
+
+    got = jax.value_and_grad(lambda p: gpt2.loss_fn(p, model, batch))(params)
+    want = jax.value_and_grad(direct)(params)
+    assert float(got[0]) == float(want[0])
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# ----------------------------------------------------------------------
+# the model
+# ----------------------------------------------------------------------
+
+def test_the_second_terms_targets_are_the_labels_shifted_once_more():
+    labels = jnp.arange(12).reshape(2, 6) + 100
+    targets, keep = mla_moe.second_term_targets(labels)
+    assert np.asarray(targets[:, :-1]).tolist() == np.asarray(
+        labels[:, 1:]).tolist()
+    assert np.asarray(keep).tolist() == [[1, 1, 1, 1, 1, 0]] * 2
+    mask = jnp.array([[1, 1, 0, 1, 1, 1], [1, 1, 1, 1, 1, 0]])
+    _, keep = mla_moe.second_term_targets(labels, mask)
+    assert np.asarray(keep).tolist() == [[1, 0, 1, 1, 1, 0],
+                                         [1, 1, 1, 1, 0, 0]]
+
+
+def _small(**kw):
+    config = mla_moe.MLAMoEConfig.small_test(dtype=jnp.float32,
+                                             expert_shard=(1, 2), **kw)
+    model, params, tx, opt_state = mla_moe.make_train_state(
+        config, jax.random.PRNGKey(0))
+    tokens = _tokens(7, config.vocab_size, 2, 32)
+    batch = {"input_ids": jnp.asarray(tokens[:, :-1]),
+             "labels": jnp.asarray(tokens[:, 1:])}
+    return config, model, params, tx, opt_state, batch
+
+
+def test_the_loss_is_its_two_terms_and_the_last_position_does_not_count():
+    config, model, params, _, _, batch = _small()
+    loss, aux = mla_moe.loss_fn(params, model, batch)
+    np.testing.assert_allclose(
+        loss, aux["main"] + config.mtp_loss_weight * aux["mtp"], rtol=1e-6)
+    assert aux["tokens_per_expert"].shape == (3, 4)     # 2 layers + module
+    # every token's pairs on held experts, in each expert layer
+    assert (np.asarray(aux["tokens_per_expert"]).sum(axis=1) <= 64 * 3).all()
+    # the second term ignores what follows the last label: change the last
+    # label and only the terms that read it move
+    other = dict(batch, labels=batch["labels"].at[:, -1].add(1) % 256)
+    _, moved = mla_moe.loss_fn(params, model, other)
+    assert float(moved["main"]) != float(aux["main"])
+    # without the module the loss is its first term
+    plain, _, params0, _, _, _ = _small(num_nextn_predict_layers=0)
+    model0 = mla_moe.MLAMoE(plain)
+    loss0, aux0 = mla_moe.loss_fn(params0, model0, batch)
+    assert float(aux0["mtp"]) == 0.0 and float(loss0) == float(aux0["main"])
+    assert "mtp_block" not in params0
+
+
+def test_recomputation_and_the_whole_logits_change_no_value():
+    config, model, params, _, _, batch = _small()
+    base = jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, model, batch)[0])(params)
+    for change in ({"remat": True}, {"loss_chunks": 0}):
+        other = mla_moe.MLAMoE(dataclasses.replace(config, **change))
+        got = jax.value_and_grad(
+            lambda p: mla_moe.loss_fn(p, other, batch)[0])(params)
+        np.testing.assert_allclose(got[0], base[0], rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(base[1])):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7)
+
+
+def test_the_step_is_the_one_builder_and_reports_its_parts():
+    """``mla_moe.build_train_step`` is ``parallel.build_train_step`` with the
+    auxiliary output; the selection bias stays where it was initialised; a
+    loop's report carries the two terms and the experts' load, and the step
+    observatory gets them as one record."""
+    _, model, params, tx, opt_state, batch = _small()
+    step = mla_moe.build_train_step(model, tx, donate=False)
+    new_params, _, loss, main, mtp, tokens = step(params, opt_state, batch)
+    assert not np.asarray(
+        new_params["layers_1"]["moe"]["router_bias"]).any()
+    assert np.asarray(new_params["layers_1"]["moe"]["router"] !=
+                      params["layers_1"]["moe"]["router"]).any()
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        metrics = mla_moe.step_metrics(loss, main, mtp, tokens)
+        records = [r for r in steptrace.snapshot()
+                   if r["kind"] == "counters"]
+    finally:
+        steptrace.set_enabled(False)
+    assert set(metrics) == {"loss", "loss_main", "loss_mtp",
+                            "expert_tokens_max", "expert_tokens_mean"}
+    assert metrics["expert_tokens_max"] == int(np.asarray(tokens).max())
+    assert metrics["expert_tokens_mean"] == pytest.approx(
+        float(np.asarray(tokens).mean()))
+    assert len(records) == 1 and records[0]["name"] == "train/step_aux"
+    assert records[0]["values"] == metrics
+    merged = steptrace.merge_records(records)
+    assert merged["counters"][0]["values"]["loss_mtp"] == metrics["loss_mtp"]
+
+
+def test_the_familys_count_is_the_state_the_program_makes():
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "joyai-llm-flash.json")) as f:
+        model = json.load(f)
+    built = FAMILY.build(model, {"remat": True}, None)
+    params, _ = jax.eval_shape(built.make_state, jax.random.PRNGKey(0))
+    made = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert FAMILY.num_params(model) == made == 680_441_088
+    # 6 x the matmul parameters a token uses + attention at 8k: 4.91 GFLOP
+    assert FAMILY.train_flops_per_token(model, 8192) == pytest.approx(
+        4.908e9, rel=1e-3)
+    # half an expert a layer is the routed part's expectation here
+    assert FAMILY.matmul_params_per_token(model) == pytest.approx(
+        314.7e6, rel=1e-3)

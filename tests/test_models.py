@@ -168,7 +168,7 @@ def test_gpt2_chunked_loss_matches_fused(masked, dtype, value_tol, dh_tol,
         return 3 * gpt2.fused_xent(h @ e.T.astype(h.dtype), labels, mask)
 
     def chunked(h, e):
-        return 3 * gpt2.chunked_xent_tied(h, e, labels, mask, n_chunks=4)
+        return 3 * gpt2.chunked_xent(h, e, labels, mask, n_chunks=4)
 
     want, (dh_want, de_want) = jax.value_and_grad(fused, (0, 1))(
         hidden, embedding)

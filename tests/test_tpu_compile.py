@@ -345,3 +345,58 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     assert collections.Counter(kernels) == {"flash_fwd": 2 * layers,
                                             "flash_bwd": layers}
     assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
+
+
+def _cut_cell():
+    from perfbench import run, worker
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        loaded = run.load_cell(json.load(f), "joyai-llm-flash.step-8k")
+    return worker, loaded["model"], loaded["traffic"]
+
+
+def test_latent_attention_expert_step_fits_one_chip_at_8k(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``joyai-llm-flash.step-8k`` (layer
+    0, four expert layers and the prediction module at the published
+    widths, 16 of 256 experts held, an eighth of the vocabulary), its step
+    at 2 x 8192 with recomputation, as the benchmark's family builds it:
+    the plan fits one v5e chip's 15.75 GiB with the state's 7.6 GiB as
+    arguments; attention is the Pallas kernel at keys 192 and values 128
+    wide (18 calls: six layers, forward twice and backward); the routed
+    experts are the compiler's grouped-matmul kernel; and no array is shaped
+    like a [tokens, experts, capacity] dispatch or a [T, T] score matrix,
+    whole or a head's."""
+    worker, model, traffic = _cut_cell()
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    compiled = built.step.lower(
+        params, opt_state, {"input_ids": ids, "labels": ids}).compile()
+    planned = _device_bytes(compiled)
+    state_bytes = 3 * 4 * sum(
+        int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert state_bytes < planned < 15.75 * 2**30
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_fwd[\w.]* = ", text)) == 12
+    assert len(re.findall(r"%flash_bwd[\w.]* = ", text)) == 6
+    assert "bf16[64,8192,192]" in text and "bf16[64,8192,128]" in text
+    assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 40
+    assert "conditional(" not in text      # one row buffer, taken always
+    tokens, experts = batch * seq, model["n_routed_experts_published"]
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    # 32 heads x (128 + 128) is 8192 too: the keys' and values' projection
+    # [batch, T, 8192] is the one array that may look like a score matrix
+    kv_width = model["num_attention_heads"] * (
+        model["qk_nope_head_dim"] + model["v_head_dim"])
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        # scores: two dimensions of the sequence's length side by side
+        if dims == (batch, seq, kv_width):
+            continue
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+        # a dense dispatch: tokens x experts x anything
+        assert not (len(dims) >= 3 and tokens in dims
+                    and experts in dims), dims

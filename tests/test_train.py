@@ -193,6 +193,49 @@ def test_jax_trainer_mesh_in_worker(ray_start_regular):
     assert losses[2] < losses[0]  # it learns
 
 
+def test_jax_trainer_latent_attention_expert_model(ray_start_regular):
+    """The latent-attention, routed-expert model through the normal path:
+    ``JaxTrainer.fit()`` runs a loop that builds the state, places it on a
+    4-device mesh by the model's own rule, steps with the one step builder
+    and reports each step's loss, its two terms and the experts' load."""
+    from ray_tpu import train
+
+    def loop(config):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu import parallel
+        from ray_tpu.models import gpt2, mla_moe
+
+        mesh = parallel.create_mesh({"data": 4})
+        cfg = mla_moe.MLAMoEConfig.small_test(dtype=jnp.float32,
+                                              expert_shard=(0, 2), remat=True)
+        model, params, tx, opt_state = mla_moe.make_train_state(
+            cfg, jax.random.PRNGKey(0))
+        params, opt_state = mla_moe.shard_train_state(params, opt_state, mesh)
+        step_fn = mla_moe.build_train_step(model, tx, donate=False)
+        batch = gpt2.shard_batch(gpt2.synthetic_batch(
+            jax.random.PRNGKey(1), 8, 32, cfg.vocab_size), mesh)
+        for _ in range(4):
+            params, opt_state, *out = step_fn(params, opt_state, batch)
+            train.report(mla_moe.step_metrics(*out))
+
+    trainer = train.JaxTrainer(
+        loop,
+        jax_config=train.JaxConfig(env_vars=_CPU_ENV),
+        scaling_config=train.ScalingConfig(num_workers=1),
+        run_config=train.RunConfig(name="t_mla_moe",
+                                   storage_path="/tmp/rt_test_results"),
+    )
+    result = trainer.fit()
+    assert result.error is None, result.error
+    last = result.metrics
+    assert last["loss"] == pytest.approx(
+        last["loss_main"] + 0.3 * last["loss_mtp"], rel=1e-5)
+    assert last["loss"] < 7.3          # ln(256) x 1.3 at the first step
+    assert 0 < last["expert_tokens_mean"] <= last["expert_tokens_max"] <= 256
+
+
 def test_torch_trainer_gloo(ray_start_regular):
     """ray parity: TorchTrainer with a real torch.distributed gloo group."""
     from ray_tpu import train
