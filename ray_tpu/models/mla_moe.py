@@ -306,13 +306,20 @@ def shard_train_state(params, opt_state, mesh):
         params, opt_state, param_shardings(params, mesh))
 
 
-def step_metrics(loss, main, mtp, tokens_per_expert) -> dict:
+def step_metrics(loss, main, mtp, tokens_per_expert, *, pairs=None) -> dict:
     """What a loop hands ``train.report`` after a step of
-    ``build_train_step``: the loss, its two terms, and the most and the
-    mean tokens any held expert received (over the expert layers). The same
-    numbers go to the step observatory as one ``counters`` record
-    ``train/step_aux`` under the step they belong to. Reads the four
-    results back to the host: call it where the loop reads its loss."""
+    ``build_train_step``: the loss, its two terms, the most and the mean
+    tokens any held expert received (over the expert layers), and what the
+    expert layers' row buffers held: ``rows_present``, the pairs that fell
+    on held experts, ``rows_buffered``, the lengths the layers took for
+    them (``ops.moe.row_buffer_rung``, which the layer itself asks), both
+    summed over the expert layers, and their quotient ``rows_fill``.
+    ``pairs`` is the step's tokens x ``num_experts_per_tok``, the most a
+    layer can hold, which the experts' counts do not tell: a loop that does
+    not give it gets ``rows_present`` alone. The same numbers go to the
+    step observatory as one ``counters`` record ``train/step_aux`` under
+    the step they belong to. Reads the four results back to the host: call
+    it where the loop reads its loss."""
     import numpy as np
 
     metrics = {"loss": float(loss), "loss_main": float(main),
@@ -321,5 +328,12 @@ def step_metrics(loss, main, mtp, tokens_per_expert) -> dict:
     if tokens.size:
         metrics["expert_tokens_max"] = int(tokens.max())
         metrics["expert_tokens_mean"] = float(tokens.mean())
+        present = tokens.sum(axis=-1)
+        metrics["rows_present"] = int(present.sum())
+        if pairs is not None:
+            buffered = np.asarray(moe.row_buffer_rungs(pairs))[
+                moe.row_buffer_rung(present, pairs)]
+            metrics.update(rows_buffered=int(buffered.sum()),
+                           rows_fill=float(present.sum() / buffered.sum()))
     steptrace.record_counters("train/step_aux", metrics)
     return metrics

@@ -24,14 +24,14 @@ over the k) and ``held_expert_ffn``, which is told which slice of the
 experts it holds, sorts the token-expert pairs that fall on them and runs
 grouped SwiGLU matmuls over the sorted rows (``jax.lax.ragged_dot``, which
 the TPU compiler lowers to its own grouped-matmul kernel whose grid follows
-the rows present). Every pair of a held expert is computed, whatever the
-routing; what absent experts would add is left out and nothing stands in
-for them or for their exchange.
+the rows present), over as much of the row buffer as holds them, chunk by
+chunk (``row_buffer_rungs``, PR 32). Every pair of a held expert is computed,
+whatever the routing; what absent experts would add is left out and nothing
+stands in for them or for their exchange.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -192,7 +192,15 @@ def topk_routing(x, router, bias, k: int, scale: float = 1.0,
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(f32)), k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    # each chosen expert's own score, picked by comparison, one choice at a
+    # time (no array of tokens x k x experts is formed): a gather of T * k
+    # scalars, and the scatter-add that is its transpose, take a v5e four to
+    # six times as long (1.40 and 1.29 ms against 0.35 and 0.22 at 16,384 x
+    # 8 of 256: PERF.md section 6, PR 32)
+    columns = jnp.arange(scores.shape[-1])
+    weights = jnp.stack(
+        [jnp.where(experts[:, j:j + 1] == columns, scores, 0.0).sum(axis=-1)
+         for j in range(k)], axis=-1)
     if normalize:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights * scale
@@ -204,40 +212,186 @@ def _take_rows(x, index):
     return x.at[index].get(mode="promise_in_bounds")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_sorted_pairs(x, order, inverse, mine, k: int):
-    """``x[order // k]``: the token row of each sorted pair. ``order`` is a
-    permutation of the T * k pairs and ``inverse`` its inverse, so the
-    cotangent is a gather too (un-sort, then sum a token's k pairs), where
-    autodiff's transpose of a gather is a scatter-add. Only the pairs that
-    are ``mine`` (T, k) give a token anything back: the cotangent's rows of
-    the others are whatever the grouped matmul left there."""
-    return _take_rows(x, order // k)
+def row_buffer_rungs(pairs: int) -> Tuple[int, ...]:
+    """How much of its row buffer ``held_expert_ffn`` can walk in a step of
+    ``pairs`` = tokens x k token-expert pairs, ascending: one chunk of rows,
+    two, ... up to ``pairs`` itself, the most that can fall on held experts.
+    A chunk is a thirty-second of ``pairs`` in whole tiles of 8 rows."""
+    chunk = -(-pairs // 256) * 8
+    return tuple(range(chunk, pairs, chunk)) + (pairs,)
 
 
-def _rows_fwd(x, order, inverse, mine, k):
-    return _take_rows(x, order // k), (inverse, mine)
+def row_buffer_rung(present, pairs: int):
+    """Which of ``row_buffer_rungs(pairs)`` a layer walks in a step where
+    ``present`` pairs fall on held experts: the index of the first that holds
+    them. ``present`` is a number or an array (numpy's or jax's, traced or
+    not), and so is the result."""
+    return sum((present > rung for rung in row_buffer_rungs(pairs)[:-1]),
+               0 * present)
 
 
-def _rows_bwd(k, res, g):
-    inverse, mine = res
-    pairs = _take_rows(g, inverse).reshape(-1, k, g.shape[-1])
-    dx = jnp.where(mine[..., None], pairs.astype(jnp.float32), 0.0).sum(axis=1)
-    return dx.astype(g.dtype), None, None, None
+def _walk(plan, of_chunk):
+    """``of_chunk(start, pair)`` -> arrays a chunk long, over each chunk of
+    the row buffer up to the one that holds the last pair present, written
+    side by side into buffers as long as the whole chunks that hold tokens
+    x k rows (zeros beyond what was walked). ``pair`` is the chunk's pairs
+    in the order of the sort. One loop whose trip count follows the count:
+    the code stands once in the executable, the cost is the rows'."""
+    pairs = plan["order"].shape[0]
+    rungs = row_buffer_rungs(pairs)
+    chunk, length = rungs[0], len(rungs) * rungs[0]
+    order = jnp.pad(plan["order"], (0, length - pairs))
+
+    def at(i):
+        start = i * chunk
+        return of_chunk(start, jax.lax.dynamic_slice(order, (start,), (chunk,)))
+
+    def body(i, buffers):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(buffer, part, i * chunk, 0)
+            for buffer, part in zip(buffers, at(i)))
+
+    return jax.lax.fori_loop(
+        0, row_buffer_rung(plan["present"], pairs) + 1, body,
+        tuple(jnp.zeros((length,) + part.shape[1:], part.dtype)
+              for part in jax.eval_shape(at, 0)))
 
 
-_rows_of_sorted_pairs.defvjp(_rows_fwd, _rows_bwd)
+def _chunk(rows, start, like):
+    return jax.lax.dynamic_slice_in_dim(rows, start, like.shape[0])
+
+
+# A gather of rows whose source is at most this large ran at the speed of its
+# writes on a v5e, a larger one at a fifth of it, whatever part of the source
+# its indices touch (benches/moe_gather_source.py, PR 32: 131,072 rows of
+# 2,048 from 49,152 rows: 6.0 ms whole, 2.6 in two halves of the columns;
+# from 131,072 rows no split helped).
+_FAST_GATHER_SOURCE_BYTES = 96 * 2**20
+
+
+def _column_parts(rows) -> int:
+    """Into how many column blocks ``_sum_of_pairs`` cuts ``rows`` (R, d) so
+    that each block is a fast gather's source: 1, 2 or 4 blocks of whole
+    lane tiles, else 1."""
+    source = rows.size * rows.dtype.itemsize
+    for parts in (1, 2, 4):
+        if (source <= parts * _FAST_GATHER_SOURCE_BYTES
+                and rows.shape[1] % (parts * 128) == 0):
+            return parts
+    return 1
+
+
+def _sum_of_pairs(rows, plan, weights=None):
+    """``y[t] = sum over t's pairs that are mine of weight x rows[place]``
+    -> (T, d) float32, for ``rows`` (R, d) in the order of the sort and
+    ``weights`` (T, k), or None for ones. A pair that is ``mine`` lies
+    before the count and so inside ``rows``; the others' places are clamped
+    into it and what is read there, whatever a TPU's grouped matmul left,
+    counts as zero."""
+    mine = plan["mine"]
+    at = jnp.minimum(plan["inverse"], rows.shape[0] - 1)
+    sums = []
+    for block in jnp.split(rows, _column_parts(rows), axis=1):
+        pairs = _take_rows(block, at).reshape(*mine.shape, block.shape[1])
+        pairs = jnp.where(mine[..., None], pairs.astype(jnp.float32), 0.0)
+        if weights is not None:
+            # a product and a sum, not an einsum: as a matmul its float32
+            # operand [T, k, d] was written out whole, a GiB a pass
+            pairs = pairs * weights[..., None]
+        sums.append(pairs.sum(axis=1))
+    return jnp.concatenate(sums, axis=1)
+
+
+def _gather_sources(rows) -> Tuple[int, ...]:
+    """The lengths at which ``_to_tokens`` cuts ``rows`` (R, d) for its
+    gather, ascending: what two and four fast column blocks hold, then R."""
+    fast = _FAST_GATHER_SOURCE_BYTES // (rows.shape[1] * rows.dtype.itemsize)
+    return tuple(n for n in (2 * fast, 4 * fast) if n < rows.shape[0]) + (
+        rows.shape[0],)
+
+
+def _to_tokens(rows, plan, weights=None):
+    """``_sum_of_pairs`` over the first of ``_gather_sources(rows)`` that
+    holds the pairs present: a gather's cost follows its source's length,
+    not the rows it touches, and the length has to be static."""
+    sources = _gather_sources(rows)
+
+    def from_the_first(n):
+        return lambda rows, plan, weights: _sum_of_pairs(
+            rows[:n], plan, weights)
+
+    return jax.lax.switch(
+        sum(plan["present"] > n for n in sources[:-1]) + 0 * plan["present"],
+        [from_the_first(n) for n in sources], rows, plan, weights)
+
+
+def _swiglu(hidden):
+    gate, up = jnp.split(hidden, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+@jax.jit
+def _rows_forward(x, weights, wi, wo, plan):
+    k, sizes = plan["mine"].shape[1], plan["tokens"]
+    rows, = _walk(plan, lambda start, pair: (_take_rows(x, pair // k),))
+    hidden = jax.lax.ragged_dot(rows, wi.astype(x.dtype), sizes)
+    act, = _walk(plan, lambda start, pair: (
+        _swiglu(_chunk(hidden, start, pair)),))
+    out = jax.lax.ragged_dot(act, wo.astype(x.dtype), sizes)
+    return _to_tokens(out, plan, weights).astype(x.dtype)
+
+
+@jax.jit
+def _rows_backward(x, weights, wi, wo, plan, g):
+    f32, k, sizes = jnp.float32, plan["mine"].shape[1], plan["tokens"]
+    wi_x, wo_x = wi.astype(x.dtype), wo.astype(x.dtype)
+    rows, g_rows = _walk(plan, lambda start, pair: (
+        _take_rows(x, pair // k), _take_rows(g, pair // k)))
+    hidden = jax.lax.ragged_dot(rows, wi_x, sizes)
+    # with y = sum of weight x (act @ wo): d_act = weight x (g @ wo^T), and
+    # d_weight = g . (act @ wo) = (g @ wo^T) . act: no second matmul again
+    g_act = jax.lax.ragged_dot(g_rows, wo_x.swapaxes(1, 2), sizes)
+
+    def of_chunk(start, pair):
+        scale = _take_rows(weights.reshape(-1), pair)[:, None]
+        act, pull = jax.vjp(_swiglu, _chunk(hidden, start, pair))
+        g_chunk = _chunk(g_act, start, pair).astype(f32)
+        d_hidden, = pull((scale * g_chunk).astype(act.dtype))
+        return (d_hidden, (scale * act.astype(f32)).astype(act.dtype),
+                (g_chunk * act.astype(f32)).sum(axis=-1))
+
+    d_hidden, act_scaled, d_scale = _walk(plan, of_chunk)
+
+    def for_the_matrices(rows, matrices, d_out):
+        return jax.vjp(lambda m: jax.lax.ragged_dot(rows, m, sizes),
+                       matrices)[1](d_out)[0]
+
+    d_rows = jax.lax.ragged_dot(d_hidden, wi_x.swapaxes(1, 2), sizes)
+    d_weights = jnp.where(plan["mine"], _take_rows(d_scale, jnp.minimum(
+        plan["inverse"], d_scale.shape[0] - 1)).reshape(weights.shape), 0.0)
+    return (_to_tokens(d_rows, plan).astype(x.dtype),
+            d_weights.astype(weights.dtype),
+            for_the_matrices(rows, wi_x, d_hidden).astype(wi.dtype),
+            for_the_matrices(act_scaled, wo_x, g_rows).astype(wo.dtype))
 
 
 @jax.custom_vjp
-def _unsort(y, order, inverse):
-    """``y[inverse]``: sorted pairs back in the order of the tokens; its
-    cotangent is the gather ``g[order]``."""
-    return _take_rows(y, inverse)
+def _held_rows(x, weights, wi, wo, plan):
+    """``held_expert_ffn``'s row work: gather, grouped SwiGLU, back to the
+    tokens. The grouped matmuls run over whole buffers and follow the rows
+    present by themselves; what is not a matmul walks the buffer only as far
+    as the pairs present (``_walk``), or reads that far (``_to_tokens``). It
+    is differentiated by hand: the backward pass takes the same count
+    again and recomputes rows and hidden; its residuals are the arguments,
+    whose shapes the count does not change; and every cotangent of a gather
+    is a gather. Both passes are jitted so that a model's layers of one
+    shape trace their loops and branches once: traced a layer at a time
+    they cost the cell 4 to 8 s of every start."""
+    return _rows_forward(x, weights, wi, wo, plan)
 
 
-_unsort.defvjp(lambda y, order, inverse: (_unsort(y, order, inverse), order),
-               lambda order, g: (_take_rows(g, order), None, None))
+_held_rows.defvjp(lambda *a: (_rows_forward(*a), a),
+                  lambda res, g: _rows_backward(*res, g) + (None,))
 
 
 def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
@@ -252,36 +406,49 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     expert received.
 
     The T * k pairs are sorted by held expert (pairs of absent experts last,
-    under a key of their own), each sorted row gathers its token, two
-    grouped matmuls (``jax.lax.ragged_dot``) run over the rows present, and
-    the rows go back to their tokens by the inverse permutation. The row
-    buffer is T * k long, the most that can fall on held experts, so no pair
-    is dropped at any routing; the grouped matmuls' cost follows the rows
-    present, the gathers' and the elementwise passes' the buffer."""
-    T, d = x.shape
-    k, held = experts.shape[1], wi.shape[0]
+    under a key of their own), each sorted row gathers its token, grouped
+    matmuls (``jax.lax.ragged_dot``) run over the rows present, and the rows
+    go back to their tokens. The row buffer is T * k long, the most that
+    can fall on held experts, so no pair is dropped at any routing, and the
+    work follows the pairs present, ``tokens.sum()``: the matmuls' kernel by
+    itself, the gathers into the buffer and the passes over the hidden rows
+    in loops that stop after the chunk that holds the last pair
+    (``_walk``), the gathers back to the tokens by reading a source cut to
+    that length (``_to_tokens``), forward and again backward.
+
+    What that buys (``benches/moe_row_buffer.py`` on a v5e, PR 32: the
+    layer alone, forward plus backward, T = 16,384, k = 8, d = 2,048,
+    experts 768 wide, 16 held; ms; PR 31's function, every pass over the
+    whole buffer, beside this one and the rows it walks)::
+
+        pairs present    PR 31's    this function
+                8,200       27.3     12.2   (12,288)
+               16,384       29.0     13.9   (16,384)
+               25,000       31.1     16.5   (28,672)
+               45,000       35.5     21.5   (45,056)
+               65,536       40.0     28.4   (65,536)
+               90,000       45.5     34.8   (90,112)
+              131,072       54.4     46.7  (131,072)
+
+    The gathers back to the tokens step at 49,152 and 98,304 pairs (about
+    1.5 ms each way: ``_gather_sources``); the rest is the rows'."""
+    held = wi.shape[0]
     assert 0 <= index < of, (index, of)
     local = experts - index * held
     mine = (local >= 0) & (local < held)
     key = jnp.where(mine, local, held).reshape(-1)          # (T * k,)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * k, dtype=jnp.int32))
-    tokens = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-    rows = _rows_of_sorted_pairs(x, order, inverse, mine, k)  # (T * k, d)
-    hidden = jax.lax.ragged_dot(rows, wi.astype(x.dtype), tokens)
-    gate, up = jnp.split(hidden, 2, axis=-1)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, wo.astype(x.dtype),
-                             tokens)                          # (T * k, d)
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    tokens = (key[:, None] == jnp.arange(held)).sum(axis=0, dtype=jnp.int32)
     # The TPU's grouped matmul writes the tiles that hold a group's rows and
     # leaves the rest of its result as it finds it (the CPU's writes zeros),
-    # forward and in its operand's cotangent alike. Rows past the last group
-    # belong to pairs that are not ``mine``: they are set to zero where they
-    # would reach a token, here and in ``_rows_of_sorted_pairs``' cotangent,
-    # and are read nowhere else (a group's matmul reads its own rows only).
+    # forward and in its operand's cotangent alike. Rows past the count
+    # belong to no pair that is ``mine``: they are set to zero where they
+    # would reach a token (``_sum_of_pairs``, forward and backward) and are
+    # read nowhere else (a group's matmul reads its own rows only).
     # Left in, they gave a toy configuration NaN and the full one a finite
     # loss that fell a tenth as fast (PERF.md section 6, PR 31).
-    pairs = _unsort(out, order, inverse).reshape(T, k, d)
-    pairs = jnp.where(mine[..., None], pairs.astype(jnp.float32), 0.0)
-    y = jnp.einsum("tkd,tk->td", pairs, jnp.where(mine, weights, 0.0))
-    return y.astype(x.dtype), tokens
+    plan = {"order": order, "inverse": inverse, "mine": mine,
+            "tokens": tokens, "present": tokens.sum()}
+    return _held_rows(x, weights, wi, wo, plan), tokens

@@ -179,6 +179,131 @@ def test_no_pair_of_a_held_expert_is_dropped(forced, pairs):
         assert not np.asarray(y).any()
 
 
+def _each_pair_by_itself(x, experts, weights, wi, wo, *, index, of):
+    """``held_expert_ffn`` with no buffer, no sort and no loop: every pair
+    looks its expert's matrices up, and autodiff does the rest."""
+    held = wi.shape[0]
+    local = experts - index * held
+    mine = (local >= 0) & (local < held)
+    expert = jnp.clip(local, 0, held - 1)
+    with jax.default_matmul_precision("highest"):
+        gate, up = jnp.split(
+            jnp.einsum("td,tkdh->tkh", x, wi[expert]), 2, axis=-1)
+        out = jnp.einsum("tkh,tkhd->tkd", jax.nn.silu(gate) * up, wo[expert])
+    y = jnp.where(mine, weights, 0.0)[..., None] * out
+    tokens = (jnp.where(mine, local, held)[..., None]
+              == jnp.arange(held)).sum(axis=(0, 1))
+    return y.sum(axis=1), tokens
+
+
+def _unwritten_past_the_groups(plain, traced):
+    """``jax.lax.ragged_dot`` as a TPU runs it: a group's matmul reads its
+    own rows only, and the rows past the last group, of the result and of
+    the operand's cotangent, hold whatever was there: here NaN. Each trace
+    of it leaves a mark in ``traced``."""
+    def live(lhs, sizes):
+        return (jnp.arange(lhs.shape[0]) < sizes.sum())[:, None]
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, sizes):
+        traced.append(lhs.shape)
+        out = plain(jnp.where(live(lhs, sizes), lhs, 0.0), rhs, sizes)
+        return jnp.where(live(lhs, sizes), out, jnp.nan)
+
+    def backward(res, g):
+        lhs, rhs, sizes = res
+        keep = live(lhs, sizes)
+        d_lhs, d_rhs = jax.vjp(
+            lambda lhs, rhs: plain(lhs, rhs, sizes),
+            jnp.where(keep, lhs, 0.0), rhs)[1](jnp.where(keep, g, 0.0))
+        return jnp.where(keep, d_lhs, jnp.nan), d_rhs, None
+
+    ragged_dot.defvjp(lambda *a: (ragged_dot(*a), a), backward)
+    return ragged_dot
+
+
+_PAIRS = 96 * 3
+_SOURCES = (80, 160, _PAIRS)     # where the test lets a fast gather end
+_PRESENT = sorted({0, 1, _PAIRS} | {n + more for more in (0, 1) for n in (
+    _SOURCES[:-1] + moe.row_buffer_rungs(_PAIRS)[:-1][::8])})
+
+
+@pytest.mark.parametrize("present", _PRESENT)
+def test_every_rung_is_the_one_length_computation(present, monkeypatch):
+    """Pairs present from none, through the ends of some of the walk's
+    chunks and of each source the gathers back to the tokens are cut to,
+    and one more, to all tokens x k: the layer's result, its counts and
+    its four gradients are those of each pair computed by itself, and stay
+    so when the grouped matmul leaves rows past its groups unwritten."""
+    layer = _layer(seed=present)
+    width = layer["x"].shape[1] * layer["x"].dtype.itemsize
+    monkeypatch.setattr(moe, "_FAST_GATHER_SOURCE_BYTES",
+                        _SOURCES[0] // 2 * width)
+    assert moe._gather_sources(jnp.zeros((_PAIRS, width // 4))) == _SOURCES
+    T, k, held, of = 96, layer["k"], 4, 2
+    rng = np.random.default_rng(present)
+    on_held = np.zeros(_PAIRS, bool)
+    on_held[rng.permutation(_PAIRS)[:present]] = True
+    experts = jnp.asarray(np.where(
+        on_held, rng.integers(held, 2 * held, _PAIRS),
+        rng.integers(0, held, _PAIRS)).reshape(T, k).astype(np.int32))
+    weights = jax.random.uniform(jax.random.PRNGKey(present), (T, k),
+                                 minval=0.1)
+    target = jax.random.normal(jax.random.PRNGKey(1), layer["x"].shape)
+
+    def through(ffn):
+        def loss(x, weights, wi, wo):
+            y, tokens = ffn(x, experts, weights, wi, wo, index=1, of=of)
+            return (y * target).sum(), (y, tokens)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(
+            layer["x"], weights, layer["wi"][held:], layer["wo"][held:])
+
+    (_, (want_y, want_n)), want = through(_each_pair_by_itself)
+    assert int(want_n.sum()) == present
+    lengths = moe.row_buffer_rungs(_PAIRS)
+    assert lengths[int(moe.row_buffer_rung(present, _PAIRS))] >= present
+    traced = []
+    for unwritten in (False, True):
+        if unwritten:
+            jax.clear_caches()         # the layer's passes are jitted
+            monkeypatch.setattr(
+                jax.lax, "ragged_dot",
+                _unwritten_past_the_groups(jax.lax.ragged_dot, traced))
+        (_, (y, n)), got = through(moe.held_expert_ffn)
+        assert np.array_equal(n, want_n)
+        # sums in the order of the sort and a chunk at a time, not in the
+        # tokens' order: float32's last digits
+        for a, b in zip((y,) + got, (want_y,) + want):
+            assert np.isfinite(np.asarray(a)).all()
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    assert traced                      # the second pass ran the stand-in
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("pairs", [5, 64, _PAIRS, 16384 * 8])
+def test_the_rungs_rise_to_the_pairs_and_hold_what_is_present(pairs):
+    rungs = moe.row_buffer_rungs(pairs)
+    assert list(rungs) == sorted(set(rungs)) and rungs[-1] == pairs
+    # whole chunks of a thirty-second (in tiles of 8 rows), then the whole
+    chunk = -(-pairs // 256) * 8
+    assert len(rungs) <= 32 and rungs[:-1] == tuple(
+        chunk * (i + 1) for i in range(len(rungs) - 1))
+    assert rungs[-1] - chunk < pairs <= len(rungs) * chunk
+    present = np.arange(0, pairs + 1, max(1, pairs // 4096))
+    index = np.asarray(moe.row_buffer_rung(present, pairs))
+    assert (np.diff(index) >= 0).all() and index[-1] == len(rungs) - 1
+    held = np.asarray(rungs)[index]
+    assert (held >= present).all()
+    # the first rung that holds them, not a later one
+    assert ((index == 0) | (np.asarray(rungs)[index - 1] < present)).all()
+    # derived from the pairs: another size, another ladder
+    assert moe.row_buffer_rungs(2 * pairs) != rungs
+    # a traced count chooses as a number does
+    assert int(jax.jit(lambda n: moe.row_buffer_rung(n, pairs))(
+        jnp.int32(pairs // 3))) == moe.row_buffer_rung(pairs // 3, pairs)
+
+
 def test_routing_is_the_published_rule_on_a_hand_made_case():
     """Two tokens, four experts, two a token. The bias moves the selection
     (token 0 takes expert 3 for expert 1) and not the weights, which are the
@@ -405,7 +530,8 @@ def test_the_step_is_the_one_builder_and_reports_its_parts():
     auxiliary output; the selection bias stays where it was initialised; a
     loop's report carries the two terms and the experts' load, and the step
     observatory gets them as one record."""
-    _, model, params, tx, opt_state, batch = _small()
+    config, model, params, tx, opt_state, batch = _small()
+    pairs = batch["input_ids"].size * config.num_experts_per_tok
     step = mla_moe.build_train_step(model, tx, donate=False)
     new_params, _, loss, main, mtp, tokens = step(params, opt_state, batch)
     assert not np.asarray(
@@ -415,13 +541,26 @@ def test_the_step_is_the_one_builder_and_reports_its_parts():
     steptrace.set_enabled(True)
     steptrace.reset()
     try:
-        metrics = mla_moe.step_metrics(loss, main, mtp, tokens)
+        metrics = mla_moe.step_metrics(loss, main, mtp, tokens,
+                                       pairs=pairs)
         records = [r for r in steptrace.snapshot()
                    if r["kind"] == "counters"]
     finally:
         steptrace.set_enabled(False)
     assert set(metrics) == {"loss", "loss_main", "loss_mtp",
-                            "expert_tokens_max", "expert_tokens_mean"}
+                            "expert_tokens_max", "expert_tokens_mean",
+                            "rows_present", "rows_buffered", "rows_fill"}
+    present = np.asarray(tokens).sum(axis=1)            # a layer's pairs
+    assert metrics["rows_present"] == int(present.sum())
+    # a loop that does not say how many pairs a step has: the count alone
+    assert set(mla_moe.step_metrics(loss, main, mtp, tokens)) == set(
+        metrics) - {"rows_buffered", "rows_fill"}
+    rungs = moe.row_buffer_rungs(pairs)
+    assert metrics["rows_buffered"] == sum(
+        min(n for n in rungs if n >= p) for p in present)
+    assert metrics["rows_fill"] == pytest.approx(
+        metrics["rows_present"] / metrics["rows_buffered"])
+    assert 0 < metrics["rows_fill"] <= 1
     assert metrics["expert_tokens_max"] == int(np.asarray(tokens).max())
     assert metrics["expert_tokens_mean"] == pytest.approx(
         float(np.asarray(tokens).mean()))

@@ -218,7 +218,8 @@ def test_jax_trainer_latent_attention_expert_model(ray_start_regular):
             jax.random.PRNGKey(1), 8, 32, cfg.vocab_size), mesh)
         for _ in range(4):
             params, opt_state, *out = step_fn(params, opt_state, batch)
-            train.report(mla_moe.step_metrics(*out))
+            train.report(mla_moe.step_metrics(
+                *out, pairs=8 * 32 * cfg.num_experts_per_tok))
 
     trainer = train.JaxTrainer(
         loop,
@@ -234,6 +235,7 @@ def test_jax_trainer_latent_attention_expert_model(ray_start_regular):
         last["loss_main"] + 0.3 * last["loss_mtp"], rel=1e-5)
     assert last["loss"] < 7.3          # ln(256) x 1.3 at the first step
     assert 0 < last["expert_tokens_mean"] <= last["expert_tokens_max"] <= 256
+    assert 0 < last["rows_present"] <= last["rows_buffered"]
 
 
 def test_torch_trainer_gloo(ray_start_regular):
