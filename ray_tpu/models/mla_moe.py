@@ -46,7 +46,7 @@ from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import (RMSNorm, SwiGLU, apply_rope,
                                   rope_frequencies)
 from ray_tpu.ops import moe, xent
-from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.attention import causal_self_attention, remat_policy
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated
 
@@ -208,7 +208,7 @@ class MLAMoE(nn.Module):
                          embedding_init=_init(c), name="embed")
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
         positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        block = nn.remat(Block) if c.remat else Block
+        block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
         x, tokens = on_batch_axes(embed(input_ids)), []
         for i in range(c.num_hidden_layers):
             dense = i < c.first_k_dense_replace

@@ -25,7 +25,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import ad_checkpoint, lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
@@ -515,9 +515,9 @@ def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
 
 
 def _flash_pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    out, lse = _flash_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
-                             block_q=block_q, block_k=block_k,
-                             interpret=interpret)
+    out, lse = map(ad_checkpoint.checkpoint_name, _flash_pallas(
+        q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, interpret=interpret), _REMAT_NAMES)
     return out, (q, k, v, out, lse)
 
 
@@ -661,7 +661,7 @@ def auto_attention(q, v=None) -> str:
     under tensor parallelism, ``seq``, ``expert``) would leave the Mosaic
     call to the partitioner, which refuses it, so there "auto" stays on
     XLA's attention as it was before the kernel was chosen anywhere
-    (ROADMAP 8a). A kernel that then fails to lower raises."""
+    (ROADMAP Speed 9a). A kernel that then fails to lower raises."""
     _, seq_len, _, head_dim = q.shape
     widths = (head_dim, head_dim if v is None else v.shape[-1])
     measured = (widths in _FLASH_HEAD_DIMS and seq_len >= _FLASH_MIN_SEQ
@@ -696,3 +696,22 @@ def causal_self_attention(q, k, v, attention: str = "auto"):
                 bhsd(q), bhsd(k), bhsd(v), causal=True).transpose(0, 2, 1, 3)
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     raise ValueError(f"attention={attention!r}: expected auto, xla or flash")
+
+
+# What recomputation keeps of the kernel: the two residuals of the forward
+# rule that only the kernel can make (``q``, ``k`` and ``v`` come back from a
+# block's projections). ``_flash_pallas_fwd`` names them, and hands the named
+# output on, so that what a block computes from it is recomputed from the kept
+# copy. Without a policy a name is the identity and lowers to nothing.
+_REMAT_NAMES = ("flash_out", "flash_lse")
+
+
+def remat_policy():
+    """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
+    may run the kernel: keep the kernel's output and log-sum-exp (per layer
+    one [B, T, H, d_v] array in the compute dtype and B x H x T float32),
+    recompute everything else. The backward pass of such a block then
+    reruns the projections and not the forward kernel. Where the block's
+    attention is not the kernel (``xla``, the scan) no such name exists,
+    nothing is kept and the program is the one without a policy."""
+    return jax.checkpoint_policies.save_only_these_names(*_REMAT_NAMES)

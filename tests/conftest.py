@@ -68,6 +68,28 @@ def wait_for_condition(condition, timeout: float = 30.0,
         f"within {timeout}s{suffix}")
 
 
+def kernel_calls(jaxpr):
+    """How often each Pallas kernel stands in ``jaxpr`` (a ``make_jaxpr``
+    result) and in what it calls, by the ``pallas_call``'s ``name``
+    -> a ``collections.Counter``."""
+    import collections
+
+    import jax  # not at import time: this file sets jax's environment
+
+    found = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] += 1
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
 # --- shared-cluster fast lane -------------------------------------------
 # Booting GCS + raylet + workers costs ~10-13s; with ~40 modules that is
 # minutes of pure boot. ray_start_regular therefore REUSES the previous

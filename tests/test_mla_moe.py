@@ -5,6 +5,7 @@ the plain reference ``perfbench/families/mla_moe_reference.py`` at small
 sizes on the CPU, seeded weights, no cluster."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -17,6 +18,7 @@ from perfbench import compare, worker
 from ray_tpu._private import steptrace
 from ray_tpu.models import gpt2, mla_moe
 from ray_tpu.ops import attention, moe, xent
+from tests.conftest import kernel_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "perfbench", "tests", "configs",
@@ -523,6 +525,57 @@ def test_recomputation_and_the_whole_logits_change_no_value():
         np.testing.assert_allclose(got[0], base[0], rtol=1e-6)
         for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(base[1])):
             np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7)
+
+
+def _gradient(config, batch):
+    return jax.grad(
+        lambda p: mla_moe.loss_fn(p, mla_moe.MLAMoE(config), batch)[0])
+
+
+@pytest.mark.parametrize("policy,forward", [(True, 1), (False, 2)],
+                         ids=["kept", "no_policy"])
+def test_a_recomputed_block_keeps_what_only_the_kernel_makes(
+        monkeypatch, policy, forward):
+    """Under ``remat`` a block's backward pass reruns its projections and
+    not the forward kernel: ``ops.attention.remat_policy`` keeps the
+    kernel's output and log-sum-exp by name. The gradient's jaxpr holds
+    the forward kernel once a block (three layers and the prediction
+    module) and twice without the policy."""
+    config, _, params, _, _, batch = _small(remat=True, attention="flash")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if not policy:
+        monkeypatch.setattr(mla_moe, "remat_policy", lambda: None)
+    jax.clear_caches()  # flash_attention is jitted: drop earlier decisions
+    try:
+        calls = kernel_calls(jax.make_jaxpr(_gradient(config, batch))(params))
+    finally:
+        jax.clear_caches()
+    blocks = config.num_hidden_layers + config.num_nextn_predict_layers
+    assert calls == {"flash_fwd": forward * blocks, "flash_bwd": blocks}
+
+
+def test_without_the_kernel_the_policy_keeps_nothing(monkeypatch):
+    """``attention="xla"`` makes no such name: the program is the one that
+    recomputes under no policy."""
+    config, _, params, _, _, batch = _small(remat=True, attention="xla")
+    lowered = lambda: jax.jit(_gradient(config, batch)).lower(params).as_text()
+    kept = lowered()
+    monkeypatch.setattr(mla_moe, "remat_policy", lambda: None)
+    assert kept == lowered()
+
+
+def test_the_kept_output_is_the_one_the_kernel_would_write_again(monkeypatch):
+    """The kernel path (interpreted here): gradients with the blocks
+    recomputed, their kernel outputs kept, are those without recomputation
+    bit for bit."""
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        attention.flash_attention, impl="pallas_interpret"))
+    config, _, params, _, _, batch = _small(attention="flash")
+    plain = jax.jit(_gradient(config, batch))(params)
+    kept = jax.jit(_gradient(
+        dataclasses.replace(config, remat=True), batch))(params)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_the_step_is_the_one_builder_and_reports_its_parts():

@@ -1,5 +1,6 @@
 """Attention kernel numerics (vs naive reference) on the virtual CPU mesh."""
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -13,6 +14,7 @@ from ray_tpu.ops import (
     flash_attention,
     ring_self_attention,
 )
+from tests.conftest import kernel_calls
 
 
 def _qkv(b=2, h=2, s=64, d=16, seed=0, dtype=jnp.float32):
@@ -272,3 +274,30 @@ def test_flash_dispatch_never_hides_a_failed_kernel(monkeypatch, platform):
             assert taken == ["scan"]
     finally:
         jax.clear_caches()
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_kernels_names_keep_nothing_without_a_policy(monkeypatch, remat):
+    """The forward rule names the kernel's output and log-sum-exp for
+    ``ops.attention.remat_policy``. GPT-2 recomputes its blocks under no
+    policy, where a name is the identity: the gradient still runs the
+    forward kernel twice a block (once without recomputation) and the
+    backward kernel once."""
+    from ray_tpu.models import gpt2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()  # flash_attention is jitted: drop earlier decisions
+    try:
+        config = gpt2.GPT2Config.small_test(attention="flash", remat=remat)
+        _, params = gpt2.init_params(
+            dataclasses.replace(config, attention="xla"),
+            jax.random.PRNGKey(0))
+        ids = jnp.zeros((2, 128), jnp.int32)
+        grad = jax.make_jaxpr(jax.grad(functools.partial(
+            gpt2.loss_fn, model=gpt2.GPT2(config),
+            batch={"input_ids": ids, "labels": ids})))(params)
+    finally:
+        jax.clear_caches()
+    assert kernel_calls(grad) == {
+        "flash_fwd": (2 if remat else 1) * config.n_layer,
+        "flash_bwd": config.n_layer}
