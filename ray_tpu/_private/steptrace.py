@@ -20,9 +20,15 @@ process keeps ONE fixed-size ring of small tuples recording
   also a ``jax.profiler.TraceAnnotation("ray_tpu/<name>")``: while a
   profiler session is live it is an event on the host plane of the same
   ``.xplane.pb`` as the device's operations, on that trace's clock;
-- **XLA compile events** (a ``jax.monitoring`` duration listener,
-  installed by ``init_session``) so compile storms are attributable in
-  the same timeline.
+- **compilations** (``jax.monitoring`` listeners, installed by
+  ``init_session``): one record for each part jax times of every
+  function it compiles in the process (``trace``, ``lower``, and
+  ``backend``: XLA's compile, or the load of a cached executable), named
+  by the function, with jax's own start and end and, on ``backend``, the
+  persistent cache's verdict (``hit`` with its retrieval seconds,
+  ``miss``, ``uncached``). Of traces nested in one another only the
+  outermost is kept (a train step's holds thousands); which span or step
+  caused a compilation is told by time and step index.
 
 Metrics-core discipline applies (see metrics_core.py): ``record_*`` is
 one module-global flag load + a tuple pack + a list store — no locks
@@ -50,12 +56,13 @@ from __future__ import annotations
 
 import os
 import sys
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
 __all__ = [
     "set_enabled", "is_enabled", "record_calls", "record_collective",
-    "record_phase", "record_compile", "step_mark", "span", "phase",
+    "record_phase", "record_compile", "step_mark", "span",
     "set_train_context", "clear_train_context", "reset", "snapshot",
     "process_snapshot", "install_compile_listener",
     "merge_collectives", "merge_processes", "chrome_trace",
@@ -247,7 +254,12 @@ def record_counters(name: str, values: Dict[str, float],
     _idx += 1
 
 
-def record_compile(name: str, start: float, end: float, first: bool):
+def record_compile(name: str, start: float, end: float, first: bool,
+                   part: Optional[str] = None, cache: Optional[str] = None,
+                   retrieval_s: Optional[float] = None):
+    """One compilation of ``name``, or with ``part`` one part of it as jax
+    times it; ``cache`` (on ``backend``) is the persistent cache's
+    verdict, ``retrieval_s`` what a hit took to load."""
     global _events, _idx
     if not _enabled:
         return
@@ -256,7 +268,7 @@ def record_compile(name: str, start: float, end: float, first: bool):
         return
     _events += 1
     ring[_idx % _ring_size] = ("compile", _idx, name, bool(first), _rank,
-                               start, end)
+                               start, end, _step, part, cache, retrieval_s)
     _idx += 1
 
 
@@ -372,20 +384,36 @@ class span:
         return False
 
 
-phase = span  # the name train.step_phase and older callers use
-
-
 # ---------------------------------------------------------------------------
 # compile-event hook
 # ---------------------------------------------------------------------------
 
 _compile_listener_installed = False
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_PARTS = {
+    _TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend"}
+# asked and not found is a miss (jax raises ``cache_misses`` itself only
+# where it writes an entry: not for what compiles in under a second)
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def install_compile_listener():
-    """Register a ``jax.monitoring`` duration listener mirroring backend
-    compile events into the ring (compile storms show up whoever jitted
-    the function). Idempotent; a missing / old jax degrades to a no-op."""
+    """Register ``jax.monitoring`` listeners that mirror every compilation
+    into the ring, whoever jitted the function: a record for each part
+    (``_COMPILE_PARTS``) with jax's own start and end, on ``time.time()``.
+    The cache raises its verdict and a hit's retrieval time inside the
+    backend part, in the compiling thread, before that part's own event:
+    they are held per thread and stamped on the ``backend`` record that
+    follows. A function's trace holds the traces of every jitted function
+    it calls (``jnp``'s among them: 12,000 in a 48-layer step): jax sends
+    a part's start as a scalar, by which the nesting is counted, and only
+    the outermost is recorded. Idempotent; a missing / old jax degrades to
+    a no-op."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return
@@ -394,15 +422,45 @@ def install_compile_listener():
         from jax import monitoring
     except ImportError:
         return
+    # .cache, .retrieval_s of the open backend part; .depth of open traces
+    held = threading.local()
+
+    def _on_scalar(event: str, value: float, **kw):
+        if event == _TRACE_EVENT:
+            held.depth = getattr(held, "depth", 0) + 1
+
+    def _on_event(event: str, **kw):
+        if event in _CACHE_VERDICTS:
+            held.cache = _CACHE_VERDICTS[event]
 
     def _on_duration(event: str, duration: float, **kw):
-        if _enabled and "compile" in event:
-            now = time.time()
-            record_compile(event.rsplit("/", 1)[-1] or event,
-                           now - duration, now, first=False)
+        if event == _CACHE_RETRIEVAL:
+            held.retrieval_s = duration
+
+    def _on_span(event: str, start: float, end: float, fun_name: str = "",
+                 **kw):
+        part = _COMPILE_PARTS.get(event)
+        if not part:
+            return
+        if part == "trace":
+            held.depth = depth = max(getattr(held, "depth", 1) - 1, 0)
+            if depth:
+                return
+        cache = retrieval_s = None
+        if part == "backend":  # taken even while off: never left to a later one
+            cache = vars(held).pop("cache", "uncached")
+            retrieval_s = vars(held).pop("retrieval_s", None)
+        if _enabled:
+            if fun_name.endswith(")"):  # "jit(step)" after "step": one form
+                fun_name = fun_name[fun_name.find("(") + 1:-1]
+            record_compile(fun_name, start, end, False, part, cache,
+                           retrieval_s)
 
     try:
+        monitoring.register_scalar_listener(_on_scalar)
+        monitoring.register_event_listener(_on_event)
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_time_span_listener(_on_span)
     except Exception:
         pass
 
@@ -450,7 +508,8 @@ def snapshot() -> List[dict]:
         elif kind == "compile":
             out.append({"kind": "compile", "idx": rec[1], "name": rec[2],
                         "first": rec[3], "rank": rec[4], "start": rec[5],
-                        "end": rec[6]})
+                        "end": rec[6], "step": rec[7], "part": rec[8],
+                        "cache": rec[9], "retrieval_s": rec[10]})
         elif kind == "restart":
             out.append({"kind": "restart", "idx": rec[1], "cause": rec[2],
                         "generation": rec[3], "start": rec[4],
@@ -671,12 +730,16 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
         })
     for rec in merged.get("compiles", ()):
         proc_meta(rec["rank"])
+        part = rec.get("part")
+        args = {"first_call": bool(rec.get("first"))}
+        args.update((k, rec[k]) for k in ("step", "part", "cache",
+                                          "retrieval_s")
+                    if rec.get(k) is not None)
         trace.append({
-            "name": rec["name"], "cat": "compile", "ph": "X",
-            "ts": rec["start"] * 1e6,
+            "name": f"{rec['name']} [{part}]" if part else rec["name"],
+            "cat": "compile", "ph": "X", "ts": rec["start"] * 1e6,
             "dur": max((rec["end"] - rec["start"]) * 1e6, 1.0),
-            "pid": rec["rank"], "tid": "compile",
-            "args": {"first_call": bool(rec.get("first"))},
+            "pid": rec["rank"], "tid": "compile", "args": args,
         })
     restarts = merged.get("restarts", ())
     if restarts:

@@ -82,11 +82,21 @@ def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
             params = optax.apply_updates(params, updates)
             return params, opt_state, out
 
+        step.__name__ = STEP_NAME
         return jax.jit(
             step, donate_argnums=(0, 1) if donate else (),
             out_shardings=(*shardings, None) if shardings else None)
 
     return _StepByLayout(jitted)
+
+
+# The name ``_StepByLayout``'s step is jitted under, for whoever looks for its
+# compilations in the step-telemetry ring (``steptrace`` records of kind
+# ``compile``). It stands below the builder because the kernels' Mosaic
+# payload carries the line of every frame above a ``pallas_call``, the lines
+# of ``step`` and ``_StepByLayout.lower`` among them: a line added above them
+# moves every cell's compiled program and cache key.
+STEP_NAME = "step"
 
 
 def state_shardings(params, opt_state, param_shardings):

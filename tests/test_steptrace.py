@@ -73,7 +73,7 @@ def test_disabled_records_nothing():
     steptrace.step_mark()
     assert steptrace.record_calls() == before
     assert len(steptrace.snapshot()) == 1  # nothing new landed
-    with steptrace.phase("data"):
+    with steptrace.span("data"):
         pass
     assert len(steptrace.snapshot()) == 1
 
@@ -93,10 +93,10 @@ def test_step_mark_delimits_steps():
 
 def test_phase_context_manager_stamps_step_and_rank():
     steptrace.set_train_context(rank=1, world=2)
-    with steptrace.phase("data"):
+    with steptrace.span("data"):
         pass
     steptrace.step_mark()
-    with steptrace.phase("compute"):
+    with steptrace.span("compute"):
         pass
     recs = [r for r in steptrace.snapshot() if r["kind"] == "phase"]
     assert [(r["phase"], r["step"], r["rank"]) for r in recs] == [

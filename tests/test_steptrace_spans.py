@@ -50,9 +50,8 @@ def test_span_records_count_and_the_step_it_was_entered_in():
     with steptrace.span("data/next") as sp:
         steptrace.step_mark()  # a report inside a span closes step 0
         sp.n = 16
-    with steptrace.phase("compute"):  # the older name, the same class
+    with steptrace.span("compute"):
         pass
-    assert steptrace.phase is steptrace.span
     assert [(r["phase"], r["step"], r["rank"], r["n"]) for r in _spans()] == [
         ("data/fetch", 0, 2, 7), ("data/next", 0, 2, 16),
         ("compute", 1, 2, None)]
