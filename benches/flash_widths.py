@@ -1,6 +1,11 @@
-"""Forward plus backward time of causal self-attention by path, at the head
-widths and lengths given: what ``ops.attention.auto_attention`` decides
-from. Run on the chip; prints one JSON line a shape.
+"""Forward time, and forward plus backward, of causal self-attention by path,
+at the head widths and lengths given: what ``ops.attention.auto_attention``
+decides from. Run on the chip; prints one JSON line a shape, with the kinds
+of grid block a head of the kernel's call has (``grid_block_kinds``:
+``looped`` 0 where every block is walked in straight-line code) and, from a
+profiler trace of three more calls, the device time of one ``flash_fwd``
+and one ``flash_bwd`` alone (``flash_*_kernel_ms``: the wall times hold the
+transposes round the kernels and the sum of dQ's partials too).
 
     python benches/flash_widths.py --widths 192x128 --lengths 1024,8192
 
@@ -12,7 +17,9 @@ is skipped where its [B, H, T, T] scores would not fit (past 4096).
 import argparse
 import json
 import os
+import shutil
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -30,7 +37,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import causal_self_attention
+    from ray_tpu.ops.attention import causal_self_attention, grid_block_kinds
 
     def timed(fn, *xs):
         jax.block_until_ready(fn(*xs))
@@ -39,6 +46,30 @@ def main():
             out = fn(*xs)
         jax.block_until_ready(out)
         return (time.perf_counter() - start) / args.reps * 1e3
+
+    def kernel_ms(fn, *xs):
+        """{"flash_fwd_kernel_ms": ..., "flash_bwd_kernel_ms": ...}: mean
+        device time of a call of each kernel, found in a trace as the
+        benchmark's ``attn_kernel_ms`` finds them."""
+        from perfbench import xplane
+        from perfbench.metrics.attn_kernel_ms import KERNEL
+
+        trace_dir = tempfile.mkdtemp()
+        try:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(3):
+                    out = fn(*xs)
+                jax.block_until_ready(out)
+            ops = xplane.load(xplane.find_xplane(trace_dir)).ops.get(0, ())
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+        found = {}
+        for name, start, end in ops:
+            kernel = KERNEL.match(name)
+            if kernel:
+                found.setdefault(kernel.group(1), []).append(end - start)
+        return {f"flash_{k}_kernel_ms": round(sum(ns) / len(ns) / 1e6, 3)
+                for k, ns in found.items()}
 
     for width in args.widths.split(","):
         d_qk, d_v = (int(n) for n in width.split("x"))
@@ -51,7 +82,8 @@ def main():
             v = jax.random.normal(keys[2], (batch, seq, args.heads, d_v),
                                   jnp.bfloat16)
             line = {"d_qk": d_qk, "d_v": d_v, "seq": seq, "batch": batch,
-                    "heads": args.heads, "device": jax.devices()[0].device_kind}
+                    "heads": args.heads, "device": jax.devices()[0].device_kind,
+                    "grid_blocks": grid_block_kinds(seq, seq, True)}
             for path in ("flash", "xla"):
                 if path == "xla" and seq > 4096:
                     continue
@@ -60,9 +92,13 @@ def main():
                     return causal_self_attention(q, k, v, path).astype(
                         jnp.float32).sum()
 
-                fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                fns = {"_fwd_ms": jax.jit(loss),
+                       "_fwd_bwd_ms": jax.jit(jax.grad(loss, argnums=(0, 1, 2)))}
                 try:
-                    line[path + "_fwd_bwd_ms"] = round(timed(fn, q, k, v), 3)
+                    for name, fn in fns.items():
+                        line[path + name] = round(timed(fn, q, k, v), 3)
+                    if path == "flash":
+                        line.update(kernel_ms(fns["_fwd_bwd_ms"], q, k, v))
                 except Exception as e:  # a path that does not fit or lower
                     line[path + "_error"] = str(e)[:200]
             print(json.dumps(line), flush=True)

@@ -670,7 +670,8 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
     JSON events — loadable in Perfetto / chrome://tracing. One process
     row per rank; step/phase/collective/compile slices on named
     threads; collective slices carry the merged skew attribution in
-    ``args``."""
+    ``args``; ``counters`` records are counter events ("ph": "C") named
+    as the record, their values the series."""
     trace: List[dict] = []
     seen_ranks = set()
 
@@ -740,6 +741,13 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
             "cat": "compile", "ph": "X", "ts": rec["start"] * 1e6,
             "dur": max((rec["end"] - rec["start"]) * 1e6, 1.0),
             "pid": rec["rank"], "tid": "compile", "args": args,
+        })
+    for rec in merged.get("counters", ()):
+        proc_meta(rec["rank"])
+        trace.append({
+            "name": rec["name"], "cat": "counters", "ph": "C",
+            "ts": rec["start"] * 1e6, "pid": rec["rank"],
+            "args": dict(rec["values"]),
         })
     restarts = merged.get("restarts", ())
     if restarts:

@@ -19,7 +19,9 @@ q, k and the saved logsumexp).
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 import math
 from typing import Optional
 
@@ -30,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
+from ray_tpu._private import steptrace
 from ray_tpu.parallel.mesh_utils import traced_mesh_axes
 
 NEG_INF = -1e30
@@ -148,20 +151,38 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
 # by ``block_k`` keys. Under a causal mask a tile row ends at the diagonal
 # and only the tiles the diagonal crosses are masked. Where one grid step
 # holds the whole sequence, which tiles are live is known when the kernel
-# is traced and the walk over them is straight-line code; where it does
-# not, the walk is a loop with bounds computed from the grid position, and
-# the index maps clamp dead grid blocks to the last live one, which the
-# pipeline does not fetch again.
+# is traced and the walk over them is straight-line code. Where a head is
+# several grid blocks, a block's place in the grid says what the mask leaves
+# of it (``_grid_kinds``): whole, on the diagonal or dead. The kernel
+# branches on the place and walks each kind with constant bounds
+# (``_walk_by_kind``): a diagonal block as a lone block is walked, a whole
+# block's rows as one loop whose body is a row's tiles in straight-line
+# code (``_walk_rows``), a dead block not at all (it still owes the
+# scratch's start, the outputs' write and zeros for its dQ^T partial), and
+# the index maps clamp dead blocks to the last live one, which the pipeline
+# does not fetch again. Only a masked call that is no self-attention in
+# square blocks (lengths that differ, ``res_q != res_k``) keeps loops with
+# bounds computed from the grid position.
 #
-# Who reaches which walk (my chip runs, PR 25; PERF.md section 6): the
-# straight-line walk is every call up to 2048 tokens, GPT-2's 1024 in both
-# benchmark cells among them; the loop is any longer sequence, which no cell
-# sends yet and ``attention="auto"`` takes at 3072, 4096 and 8192 (3.3x to
-# 47x faster than XLA's attention there, forward plus backward). With the
-# loop alone, forward plus backward at 1024 take 17% longer (2.53 against
-# 2.16 ms a layer; the forward reads the same). ``fori_loop(...,
-# unroll=True)`` on the static bounds reads as the Python loop does (2.165)
-# and would still have to tell static bounds from traced ones.
+# Who reaches which walk, and what a loop costs (my chip runs, PRs 25 and
+# 38; PERF.md section 6). One block a head is every call up to 2048 tokens,
+# GPT-2's 1024 in four benchmark cells among them. Several blocks are any
+# longer sequence: the cell ``joyai-llm-flash.step-8k`` sends 8192 (4 x 4
+# blocks a head: 6 whole, 4 diagonal, 6 dead) and ``attention="auto"`` takes
+# 3072, 4096 and 8192 (3.3x to 47x faster than XLA's attention there,
+# forward plus backward). A loop whose trip count the compiler does not
+# know is neither unrolled nor scheduled across: with the loop alone,
+# forward plus backward at 1024 take 17% longer (2.53 against 2.16 ms a
+# layer; ``fori_loop(..., unroll=True)`` on static bounds reads as the
+# Python loop does, 2.165). At 8192, 64 heads, keys 192 and values 128 wide,
+# a call's kernel time by walk (forward, backward; needed at the MXU's peak
+# 6.98 and 18.14 ms): every block in loops with traced bounds 15.48 and
+# 28.72 ms; by kind with a whole block's rows one loop 13.22 and 23.62; two
+# or four rows a loop step 23.42 and 23.32; a row's loop over its tiles, 1 /
+# 2 / 4 tiles a step 26.76 / 25.11 / 24.32; every tile of a whole block
+# written out 13.20 and 44.73 (64 tile bodies of five matmuls on 256 lanes:
+# at keys 128 wide the same code reads 16.38 against 17.40 for the rows'
+# loop, and compiles in 10.8 s against 6.1).
 
 _MAX_RESIDENT = 2048
 # (block_q, block_k) targets: the fastest measured for each kernel alone on
@@ -243,9 +264,77 @@ def _walk(step, carry, n_full, n_live):
     return lax.fori_loop(n_full, n_live, lambda c, x: step(c, x, True), carry)
 
 
+def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
+               causal: bool, alike_loop: bool):
+    """``row(j, n_full, n_live)`` for each of a grid block's ``n_q`` q tiles,
+    the bounds from ``_live_tiles``. With ``alike_loop``, rows whose bounds
+    are constants, the same for all and leave no tile masked (a whole grid
+    block's, a dead one's) are one loop over ``j``, a row a step: the row's
+    tiles stay straight-line code and the kernel's size stays a row's."""
+    bounds = [_live_tiles(rel0 + j * block_q, block_q, block_k, n_k, causal)
+              for j in range(n_q)]
+    n_full, n_live = bounds[0]
+    if (alike_loop and isinstance(n_full, int) and n_full == n_live
+            and bounds.count(bounds[0]) == n_q > 1):
+        lax.fori_loop(0, n_q, lambda j, _: row(j, n_full, n_live), None)
+        return
+    for j, live in enumerate(bounds):
+        row(j, *live)
+
+
+_KINDS = ("whole", "diagonal", "dead", "looped")
+
+
+def _grid_kinds(nq: int, nk: int, res_q: int, res_k: int, offset: int,
+                causal: bool) -> dict:
+    """{kind: grid blocks of it a head}, every kind of ``_KINDS`` in their
+    order: what the causal mask leaves of a block whose first query stands
+    ``rel0`` key positions past its first key is "whole" (every query sees
+    every key), "dead" (none sees any) or "diagonal" (the mask's edge
+    crosses it). Kinds are told apart where the grid is one block, which
+    is no variable of the grid whatever its offset, or self-attention in
+    square blocks (equal lengths, ``res_q == res_k``: every training call),
+    where the edge runs through a block from corner to corner or not at
+    all; under a mask any other grid, which no model here sends and no
+    chip run has measured, is "looped" throughout."""
+    if causal and (offset or res_q != res_k) and not nq == nk == 1:
+        return dict.fromkeys(_KINDS, 0) | {"looped": nq * nk}
+
+    def left_by_mask(rel0):
+        if not causal or rel0 + 1 >= res_k:
+            return "whole"
+        return "dead" if rel0 + res_q - 1 < 0 else "diagonal"
+
+    found = collections.Counter(
+        left_by_mask(qi * res_q + offset - ki * res_k)
+        for qi, ki in itertools.product(range(nq), range(nk)))
+    return {kind: found[kind] for kind in _KINDS}
+
+
+def _walk_by_kind(walk, rel0, res: int, kinds):
+    """``walk(rel0)`` for this grid block, with ``rel0`` a Python integer
+    wherever the block's kind fixes which tiles are live: a whole, a
+    diagonal and a dead block each get a branch of their own whose tile
+    bounds are constants (of a whole block only ``rel0 + 1 >= res``
+    matters, of a dead one ``rel0 <= -res``); a grid that is "looped"
+    throughout gets the loops over bounds computed from the traced
+    ``rel0``. ``kinds`` are the kinds the call's grid holds
+    (``_grid_kinds``), ``res`` its resident keys: no branch is made for a
+    kind that is absent, and none at all where there is one kind."""
+    if isinstance(rel0, int) or kinds == ("looped",):
+        return walk(rel0)
+    straight = {"whole": (rel0 + 1 >= res, res - 1),
+                "diagonal": (rel0 == 0, 0),
+                "dead": (rel0 + res - 1 < 0, -res)}
+    if len(kinds) == 1:
+        return walk(straight[kinds[0]][1])
+    for here, rel in (straight[kind] for kind in kinds):
+        pl.when(here)(functools.partial(walk, rel))
+
+
 def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                offset: int, static: bool):
+                offset: int, static: bool, kinds):
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -261,46 +350,53 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    for j in range(n_q):
-        cols = _tile(j, block_q, n_q)
-        rel = rel0 + j * block_q
-        q = _scaled(q_ref[cols, :], sm_scale, fold)
+    def walk(rel0):
+        def row(j, n_full, n_live):
+            cols = _tile(j, block_q, n_q)
+            rel = rel0 + j * block_q
+            q = _scaled(q_ref[cols, :], sm_scale, fold)
 
-        def step(c, carry, masked):
-            m, l, acc = carry
-            rows = _tile(c, block_k, n_k)
-            s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
-            m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
-            # a query with no live key yet keeps m at NEG_INF: exponentiate
-            # against 0 so that its masked scores give p == 0, not exp(0)
-            m_exp = (jnp.where(m_new > NEG_INF / 2, m_new, 0.0) if masked
-                     else m_new)
-            p = jnp.exp(s - m_exp)
-            alpha = jnp.exp(m - m_exp)
-            l = l * alpha + p.sum(axis=0, keepdims=True)
-            acc = acc * alpha + _dot(vt_ref[:, rows], p.astype(vt_ref.dtype),
-                                     _NN)
-            return m_new, l, acc
+            def step(c, carry, masked):
+                m, l, acc = carry
+                rows = _tile(c, block_k, n_k)
+                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+                m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+                # a query with no live key yet keeps m at NEG_INF:
+                # exponentiate against 0 so that its masked scores give
+                # p == 0, not exp(0)
+                m_exp = (jnp.where(m_new > NEG_INF / 2, m_new, 0.0) if masked
+                         else m_new)
+                p = jnp.exp(s - m_exp)
+                alpha = jnp.exp(m - m_exp)
+                l = l * alpha + p.sum(axis=0, keepdims=True)
+                acc = acc * alpha + _dot(vt_ref[:, rows],
+                                         p.astype(vt_ref.dtype), _NN)
+                return m_new, l, acc
 
-        m, l, acc = _walk(
-            step, (m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]),
-            *_live_tiles(rel, block_q, block_k, n_k, causal))
-        m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = m, l, acc
+            m, l, acc = _walk(
+                step, (m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]),
+                n_full, n_live)
+            m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = m, l, acc
 
-        @pl.when(ki == nk - 1)
-        def _finalize():
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            ot_ref[:, cols] = (acc / l_safe).astype(ot_ref.dtype)
-            # queries with no live key get lse=+inf => p == 0 in the backward
-            lse_ref[:, cols] = jnp.where(
-                l == 0.0, jnp.inf,
-                jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
+            @pl.when(ki == nk - 1)
+            def _finalize():
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                ot_ref[:, cols] = (acc / l_safe).astype(ot_ref.dtype)
+                # queries with no live key get lse=+inf => p == 0 in the
+                # backward
+                lse_ref[:, cols] = jnp.where(
+                    l == 0.0, jnp.inf,
+                    jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
+
+        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static)
+
+    _walk_by_kind(walk, rel0, res_k, kinds)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
                 causal: bool, block_q: int, block_k: int, offset: int,
-                static: bool):
+                static: bool, kinds):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three."""
@@ -318,25 +414,31 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    for j in range(n_q):
-        cols = _tile(j, block_q, n_q)
-        rel = rel0 + j * block_q
-        q, do = _scaled(q_ref[cols, :], sm_scale, fold), do_ref[cols, :]
-        lse, delta = lse_ref[:, cols], delta_ref[:, cols]  # (1, block_q)
+    def walk(rel0):
+        def row(j, n_full, n_live):
+            cols = _tile(j, block_q, n_q)
+            rel = rel0 + j * block_q
+            q, do = _scaled(q_ref[cols, :], sm_scale, fold), do_ref[cols, :]
+            lse, delta = lse_ref[:, cols], delta_ref[:, cols]  # (1, block_q)
 
-        def step(c, dqt, masked):
-            rows = _tile(c, block_k, n_k)
-            s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
-            p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
-            dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
-            dp = _dot(v_ref[rows, :], do, _NT)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            dk_scr[rows, :] += _dot(ds, q, _NN)
-            return dqt + _dot(kt_ref[:, rows], ds, _NN)  # (d, block_q)
+            def step(c, dqt, masked):
+                rows = _tile(c, block_k, n_k)
+                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+                p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
+                dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
+                dp = _dot(v_ref[rows, :], do, _NT)
+                ds = (p * (dp - delta)).astype(q.dtype)
+                dk_scr[rows, :] += _dot(ds, q, _NN)
+                return dqt + _dot(kt_ref[:, rows], ds, _NN)  # (d, block_q)
 
-        dqt = _walk(step, jnp.zeros((dqt_ref.shape[0], block_q), jnp.float32),
-                    *_live_tiles(rel, block_q, block_k, n_k, causal))
-        dqt_ref[:, cols] = (dqt * sm_scale).astype(dqt_ref.dtype)
+            dqt = _walk(
+                step, jnp.zeros((dqt_ref.shape[0], block_q), jnp.float32),
+                n_full, n_live)
+            dqt_ref[:, cols] = (dqt * sm_scale).astype(dqt_ref.dtype)
+
+        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static)
+
+    _walk_by_kind(walk, rel0, res_k, kinds)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -393,6 +495,35 @@ def _first_live_q(ki, res_q: int, res_k: int, offset: int, nq: int):
     return jnp.clip((ki * res_k - offset) // res_q, 0, nq - 1)
 
 
+def grid_block_kinds(q_len: int, k_len: int, causal: bool,
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None, *,
+                     backward: bool = False) -> dict:
+    """{"whole": n, "diagonal": n, "dead": n, "looped": n}: the grid blocks
+    a head of a call of these lengths has, by the rule the kernels branch
+    on (``_grid_kinds``). ``looped`` blocks walk their tiles in loops with
+    traced bounds, the others with constant ones. The forward's grid
+    unless ``backward``: the two kernels' tiles differ, and at some lengths
+    what a grid step holds with them."""
+    _, _, res_q, res_k = _block_sizes(
+        q_len, k_len, block_q, block_k, _BWD_TILES if backward else _FWD_TILES)
+    return _grid_kinds(q_len // res_q, k_len // res_k, res_q, res_k,
+                       k_len - q_len, causal)
+
+
+def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
+                   causal: bool, backward: bool):
+    """The kinds of grid block a call holds, for its kernel to branch on,
+    and their counts a head written into the runtime's ring: one record a
+    traced call (none a step), so a timeline says which walk a model's
+    calls took (``looped`` 0: every block in straight-line code)."""
+    counts = _grid_kinds(nq, nk, res_q, res_k, offset, causal)
+    steptrace.record_counters("attn/grid_blocks", {
+        **counts, "queries": nq * res_q, "keys": nk * res_k,
+        "backward": int(backward)})
+    return tuple(kind for kind in _KINDS if counts[kind])
+
+
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
                   interpret: bool):
@@ -412,7 +543,8 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         kmap = lambda qi, ki: ki
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, offset=offset, static=nq == nk == 1)
+        block_k=block_k, offset=offset, static=nq == nk == 1,
+        kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, False))
     out_t, lse = pl.pallas_call(
         kernel,
         grid=(b, nq, nk),
@@ -472,7 +604,8 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     dq_t, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, offset=offset, static=nq == nk == 1),
+            block_k=block_k, offset=offset, static=nq == nk == 1,
+            kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True)),
         grid=(b, nk, nq),
         in_specs=[
             qspec, kspec, vspec,
@@ -640,9 +773,26 @@ def unmapped_mesh_axes(x) -> tuple:
 # widths): 512: 8.10 against 9.57 ms; 1024: 9.51 / 16.75; 2048: 13.53 /
 # 30.57; 4096: 28.29 / 59.31 (1.2x to 2.3x); 8192: 50.66 ms, where XLA's
 # scores (8.6 GB) were not tried. At 128 / 128 and the same 32 heads the
-# kernel reads 6.95, 7.86, 10.66, 25.57 and 45.59 ms: the wider key costs
-# 10% to 27%, less than its 3/2 in QK^T, dK and dQ (a 192-wide operand is
-# laid out as 256 lanes and fills the MXU's depth one and a half times).
+# kernel read 6.95, 7.86, 10.66, 25.57 and 45.59 ms.
+# Since PR 38 (grid blocks walked by kind; ``benches/flash_widths.py``, my
+# chip run, same 16,384 tokens and 32 heads), ``flash_fwd`` and ``flash_bwd``
+# alone in a trace, then the wall time of forward plus backward with the
+# transposes round them and the sum of dQ's partials:
+#   192 / 128   2048: 3.51 and 6.11 ms, 13.53 (one block a head: as before)
+#               4096: 6.91 and 12.14, 24.45 (was 28.27)
+#               8192: 13.22 and 23.62, 43.29 (was 50.62; the kernels alone
+#                     15.48 and 28.72)
+#   128 / 128   2048: 2.65 and 4.22, 10.65; 4096: 5.23 and 8.80, 18.75 (was
+#               25.57); 8192: 10.12 and 17.40, 32.99 (was 45.59)
+#   64 / 64     1024: 1.27 and 2.60, 5.85; 4096: 4.19 and 8.04, 14.71
+# The wider key costs 34% to 40% more kernel time at 2048 to 8192 (less
+# than its 3/2 in QK^T, dK and dQ; a 192-wide operand is laid out as 256
+# lanes and fills the MXU's depth one and a half times). By block at 192 /
+# 128 (the 8192 reading less four diagonal blocks a head at the 2048
+# reading's price): backward 23.9 us a diagonal block of 36 tiles and 45.6
+# a whole block of 64 (0.66 and 0.71 us a tile against 0.55 at the MXU's
+# peak); forward 13.7 us a diagonal block of 10 tiles and 25.3 a whole
+# block of 16 (1.37 and 1.58 us a tile against 0.85).
 _FLASH_MIN_SEQ = 512
 # (key width, value width) of a head the kernel was measured at. (192, 128),
 # latent attention's per-head form (128 + 64 rotary dimensions against 128):

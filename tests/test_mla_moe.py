@@ -621,6 +621,10 @@ def test_the_step_is_the_one_builder_and_reports_its_parts():
     assert records[0]["values"] == metrics
     merged = steptrace.merge_records(records)
     assert merged["counters"][0]["values"]["loss_mtp"] == metrics["loss_mtp"]
+    # and a timeline draws them: a counter event named as the record
+    drawn = [e for e in steptrace.chrome_trace(merged) if e["ph"] == "C"]
+    assert [(e["name"], e["args"]) for e in drawn] == [
+        ("train/step_aux", metrics)]
 
 
 def test_the_familys_count_is_the_state_the_program_makes():

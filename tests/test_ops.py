@@ -121,21 +121,26 @@ def test_gpt2_sequence_parallel_step():
 
 
 # The Pallas kernel in interpret mode. A case is (batch x heads, q_len,
-# k_len, head dim, block_q, block_k, resident): blocks None are the rule's
-# (``_block_sizes``), ``resident`` overrides ``_MAX_RESIDENT`` so that a
-# short sequence spans several grid blocks, as a long one does on the chip.
+# k_len, key width, value width, block_q, block_k, resident): blocks None
+# are the rule's (``_block_sizes``), ``resident`` overrides ``_MAX_RESIDENT``
+# so that a short sequence spans several grid blocks, as a long one does on
+# the chip. Several blocks of equal lengths are walked by their kind (whole,
+# diagonal, dead: straight-line code), of differing lengths in loops.
 _PALLAS_CASES = {
-    "tiles_2x2": (2, 32, 32, 8, 16, 16, None),
-    "tiles_4x4_two_heads": (2, 64, 64, 8, 16, 16, None),
-    "one_tile": (3, 32, 32, 8, None, None, None),
-    "wide_key_tiles": (2, 64, 64, 16, 16, 32, None),
-    "wide_query_tiles": (2, 64, 64, 16, 32, 16, None),
-    "cross_lengths_16_of_64": (2, 16, 64, 8, 16, 16, None),
-    "grid_blocks_4x4": (2, 128, 128, 8, 16, 16, 32),
-    "grid_blocks_2x4_tiles_2x1": (1, 64, 128, 8, 16, 32, 32),
-    "cross_lengths_grid_blocks": (2, 32, 128, 8, 16, 16, 32),
-    "rule_384": (1, 384, 384, 16, None, None, None),
-    "rule_gpt2_1024_64": (2, 1024, 1024, 64, None, None, None),
+    "tiles_2x2": (2, 32, 32, 8, 8, 16, 16, None),
+    "tiles_4x4_two_heads": (2, 64, 64, 8, 8, 16, 16, None),
+    "one_tile": (3, 32, 32, 8, 8, None, None, None),
+    "wide_key_tiles": (2, 64, 64, 16, 16, 16, 32, None),
+    "wide_query_tiles": (2, 64, 64, 16, 16, 32, 16, None),
+    "cross_lengths_16_of_64": (2, 16, 64, 8, 8, 16, 16, None),
+    "grid_blocks_4x4": (2, 128, 128, 8, 8, 16, 16, 32),
+    "grid_blocks_2x4_tiles_2x1": (1, 64, 128, 8, 8, 16, 32, 32),
+    "cross_lengths_grid_blocks": (2, 32, 128, 8, 8, 16, 16, 32),
+    "rule_384": (1, 384, 384, 16, 16, None, None, None),
+    "rule_gpt2_1024_64": (2, 1024, 1024, 64, 64, None, None, None),
+    "kinds_one_tile_narrow_values": (2, 128, 128, 24, 16, 32, 32, 32),
+    "kinds_tiles_2x2_narrow_values": (2, 128, 128, 24, 16, 16, 16, 32),
+    "kinds_tiles_4x2_grid_2x2": (1, 64, 64, 24, 16, 8, 16, 32),
 }
 # (forward, gradient): float32 elementwise (atol = rtol), as the tests this
 # one merged held it; bfloat16 against the largest reference entry
@@ -143,7 +148,8 @@ _PALLAS_TOL = {jnp.float32: (2e-5, 1e-4), jnp.bfloat16: (2e-2, 3e-2)}
 # bfloat16 where the dtype changes the program: the scale folded into q
 # (head dim 16, 64) or not (8), several grid blocks, the rule's own tiles
 _PALLAS_BF16 = ("tiles_2x2", "wide_key_tiles", "cross_lengths_16_of_64",
-                "grid_blocks_4x4", "rule_gpt2_1024_64")
+                "grid_blocks_4x4", "rule_gpt2_1024_64",
+                "kinds_tiles_2x2_narrow_values")
 _PALLAS_PARAMS = [
     pytest.param(case, causal, dtype,
                  id=f"{case}-{'causal' if causal else 'full'}-{dtype.__name__}")
@@ -162,15 +168,15 @@ def test_flash_pallas_matches_reference(monkeypatch, case, causal, dtype):
     row's, shows), bfloat16 against the largest reference entry."""
     from ray_tpu.ops import attention
 
-    bh, q_len, k_len, d, block_q, block_k, resident = _PALLAS_CASES[case]
+    bh, q_len, k_len, d, d_v, block_q, block_k, resident = _PALLAS_CASES[case]
     if resident:
         monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
         jax.clear_caches()  # flash_attention is jitted: the rule is read
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     q = jax.random.normal(ks[0], (bh, q_len, d), dtype)
     k = jax.random.normal(ks[1], (bh, k_len, d), dtype)
-    v = jax.random.normal(ks[2], (bh, k_len, d), dtype)
-    w = jax.random.normal(ks[3], (bh, q_len, d), jnp.float32)
+    v = jax.random.normal(ks[2], (bh, k_len, d_v), dtype)
+    w = jax.random.normal(ks[3], (bh, q_len, d_v), jnp.float32)
     f32 = lambda x: x.astype(jnp.float32)
 
     def pallas(q, k, v):
@@ -203,6 +209,116 @@ def test_flash_pallas_matches_reference(monkeypatch, case, causal, dtype):
     finally:
         if resident:
             jax.clear_caches()
+
+
+def _all_looped(nq, nk, res_q, res_k, offset, causal):
+    return {"whole": 0, "diagonal": 0, "dead": 0, "looped": nq * nk}
+
+
+@pytest.mark.parametrize("case", ["kinds_tiles_2x2_narrow_values",
+                                  "kinds_tiles_4x2_grid_2x2",
+                                  "grid_blocks_4x4"])
+def test_flash_walk_by_kind_agrees_with_the_loop_walk(monkeypatch, case):
+    """One algorithm: the grid blocks walked by their kind and the same
+    blocks walked in loops with traced bounds (the fallback, forced on the
+    same inputs) give the same forward and the same three gradients in
+    float32."""
+    from ray_tpu.ops import attention
+
+    bh, q_len, k_len, d, d_v, block_q, block_k, resident = _PALLAS_CASES[case]
+    monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (bh, q_len, d))
+    k = jax.random.normal(ks[1], (bh, k_len, d))
+    v = jax.random.normal(ks[2], (bh, k_len, d_v))
+    w = jax.random.normal(ks[3], (bh, q_len, d_v))
+
+    def out_and_grads(q, k, v):
+        out, vjp = jax.vjp(lambda *x: flash_attention(
+            *x, causal=True, block_q=block_q, block_k=block_k,
+            impl="pallas_interpret"), q, k, v)
+        return (out, *vjp(w))
+
+    walks = {}
+    try:
+        for walk in ("by_kind", "looped"):
+            if walk == "looped":
+                monkeypatch.setattr(attention, "_grid_kinds", _all_looped)
+            jax.clear_caches()  # flash_attention is jitted
+            jaxpr = jax.make_jaxpr(out_and_grads)(q, k, v)
+            assert bool(_kernel_whiles(jaxpr)) == (walk == "looped")
+            walks[walk] = jax.jit(out_and_grads)(q, k, v)
+    finally:
+        jax.clear_caches()
+    for got, want in zip(walks["by_kind"], walks["looped"]):
+        np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+
+
+def _kernel_whiles(jaxpr):
+    """``while`` equations inside the Pallas kernels of ``jaxpr``: what a
+    ``fori_loop`` with a traced bound is traced to (static bounds give a
+    ``scan``, a Python loop nothing)."""
+    found = []
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            here = in_kernel or eqn.primitive.name == "pallas_call"
+            if in_kernel and eqn.primitive.name == "while":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(jaxpr.jaxpr, False)
+    return found
+
+
+def test_flash_grid_block_kinds():
+    """The counter and the kernels follow one rule. At 8192 tokens a head
+    is 4 x 4 grid blocks, 6 whole, 4 on the diagonal, 6 dead, none walked
+    in a loop, and the traced kernels hold no loop with a traced bound;
+    lengths that differ keep the loops."""
+    from ray_tpu._private import steptrace
+    from ray_tpu.ops.attention import grid_block_kinds
+
+    kinds = lambda *n: dict(zip(("whole", "diagonal", "dead", "looped"), n))
+    for backward in (False, True):
+        assert grid_block_kinds(8192, 8192, True,
+                                backward=backward) == kinds(6, 4, 6, 0)
+    assert grid_block_kinds(4096, 4096, True) == kinds(1, 2, 1, 0)
+    assert grid_block_kinds(1024, 1024, True) == kinds(0, 1, 0, 0)
+    assert grid_block_kinds(8192, 8192, False) == kinds(16, 0, 0, 0)
+    assert grid_block_kinds(4096, 8192, False) == kinds(8, 0, 0, 0)
+    # a prefix already seen: lengths differ, so every block keeps the loops
+    assert grid_block_kinds(4096, 8192, True) == kinds(0, 0, 0, 8)
+    # one block a head is walked in straight-line code whatever its offset
+    assert grid_block_kinds(16, 64, True, 16, 16) == kinds(0, 1, 0, 0)
+
+    def grad_jaxpr(q_len, k_len):
+        q = jax.ShapeDtypeStruct((64, q_len, 192), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((64, k_len, 192), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((64, k_len, 128), jnp.bfloat16)
+        return jax.make_jaxpr(jax.grad(lambda *x: flash_attention(
+            *x, causal=True, impl="pallas").astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()  # the record is written where a call is traced
+        square = grad_jaxpr(8192, 8192)
+        records = [r for r in steptrace.snapshot()
+                   if r["kind"] == "counters"]
+    finally:
+        steptrace.set_enabled(False)
+    assert kernel_calls(square) == {"flash_fwd": 1, "flash_bwd": 1}
+    assert not _kernel_whiles(square)
+    assert {r["name"] for r in records} == {"attn/grid_blocks"}
+    assert {r["values"]["backward"] for r in records} == {0, 1}
+    for r in records:
+        assert r["values"] == {**kinds(6, 4, 6, 0), "queries": 8192,
+                               "keys": 8192,
+                               "backward": r["values"]["backward"]}
+    assert len(_kernel_whiles(grad_jaxpr(4096, 8192))) == 2 * (4 + 8)
 
 
 def test_flash_block_rule():

@@ -104,15 +104,25 @@ def _device_bytes(compiled):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("heads,seq", [(16 * 12, SEQ), (4 * 12, 4096)],
-                         ids=["gpt2_1024", "several_grid_blocks_4096"])
-def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, backward):
+@pytest.mark.parametrize(
+    "heads,seq,widths",
+    [(16 * 12, SEQ, (64, 64)), (4 * 12, 4096, (64, 64)),
+     (2 * 32, 8192, (192, 128))],
+    ids=["gpt2_1024", "several_grid_blocks_4096", "latent_8192_192_128"])
+def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, widths,
+                               backward):
     """(batch 16 x 12 heads, 1024, 64) bf16 — the shape the model calls,
-    one grid step a head, the tile walk unrolled; and 4096, past
-    ``_MAX_RESIDENT``: 2 x 2 grid blocks a head, the walk a loop with
-    bounds from the grid position, dead blocks clamped in the index maps."""
-    x = jax.ShapeDtypeStruct((heads, seq, 64), jnp.bfloat16,
-                             sharding=SingleDeviceSharding(topo.devices[0]))
+    one grid step a head, the tile walk unrolled; 4096, past
+    ``_MAX_RESIDENT``: 2 x 2 grid blocks a head, each walked by its kind
+    (one whole, two on the diagonal, one dead: a branch on the grid
+    position, inside it straight-line code as at 1024), dead blocks clamped
+    in the index maps; and the latent-attention cell's call, 2 x 32 heads of
+    8192 with keys 192 and values 128 wide: 4 x 4 grid blocks a head."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((heads, seq, widths[0]), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((heads, seq, widths[1]), jnp.bfloat16,
+                             sharding=one_chip)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, impl="pallas")
@@ -121,7 +131,7 @@ def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, backward):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
-    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    text = jax.jit(fn).lower(q, q, v).compile().as_text()
     assert "tpu_custom_call" in text
 
 
@@ -370,7 +380,11 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     routed experts are the compiler's grouped-matmul kernel over a row
     buffer of which loops walk what holds the pairs present; and no array
     is shaped like a [tokens, experts, capacity] dispatch or a [T, T] score
-    matrix, whole or a head's."""
+    matrix, whole or a head's. Each traced kernel call wrote its grid's
+    blocks by kind into the runtime's ring, where a timeline finds them:
+    4 x 4 a head, none walked in a loop with traced bounds."""
+    from ray_tpu._private import steptrace
+
     worker, model, traffic = _cut_cell()
     built = worker.load_family(ROOT, model).build(model, traffic, None)
     one = SingleDeviceSharding(topo.devices[0])
@@ -378,8 +392,22 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
         jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
     batch, seq = traffic["batch"], traffic["seq"]
     ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
-    compiled = built.step.lower(
-        params, opt_state, {"input_ids": ids, "labels": ids}).compile()
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = built.step.lower(
+            params, opt_state, {"input_ids": ids, "labels": ids})
+        drawn = [e for e in steptrace.chrome_trace(steptrace.merge_records(
+            steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    assert {e["name"] for e in drawn} == {"attn/grid_blocks"}
+    assert {e["args"]["backward"] for e in drawn} == {0, 1}
+    for e in drawn:
+        assert e["args"] == {
+            "whole": 6, "diagonal": 4, "dead": 6, "looped": 0,
+            "queries": seq, "keys": seq, "backward": e["args"]["backward"]}
+    compiled = lowered.compile()
     planned = _device_bytes(compiled)
     state_bytes = 3 * 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(params))
