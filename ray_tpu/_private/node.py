@@ -24,13 +24,16 @@ reboot).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import subprocess
 import sys
 import tempfile
 import time
 import uuid
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_SESSION_ROOT = "/dev/shm/ray_tpu" if os.path.isdir("/dev/shm") else None
 
@@ -104,6 +107,85 @@ def package_env(env: Optional[dict] = None) -> dict:
 def _spawn(cmd, log_path: str, env: dict) -> subprocess.Popen:
     out = open(log_path, "ab")
     return subprocess.Popen(cmd, stdout=out, stderr=subprocess.STDOUT, env=env)
+
+
+# How long a signalled process may take to exit before it is killed. Sized
+# from the chip: a worker that holds a v5e host's four chips is not gone for
+# up to 16 s after it calls exit (the kernel unpins its memory first; PERF.md
+# section 7), and a train worker may drain for train_drain_grace_s (30 s)
+# before that.
+EXIT_GRACE_S = 60.0
+# The raylet's own stop may wait two graces for its workers before it exits.
+RAYLET_EXIT_GRACE_S = 2 * EXIT_GRACE_S + 10.0
+EXIT_POLL_S = 0.02
+
+
+class ProcessEnd:
+    """The end of one child process, the same for a raylet's worker and for a
+    node's raylet and GCS: SIGTERM when made (SIGKILL where ``force``),
+    SIGKILL once ``grace`` seconds have passed, and gone only when the pid
+    has been waited for. What a process held, a chip above all, is free once
+    ``gone()`` says so and not before: a process inside ``exit`` has lost
+    its connections and its fd table while the kernel still releases its
+    devices. ``kill`` replaces ``proc.kill`` where more than the process
+    has to go (a worker's container)."""
+
+    def __init__(self, proc: subprocess.Popen, grace: float = EXIT_GRACE_S,
+                 force: bool = False, kill: Optional[Callable[[], None]] = None):
+        self.proc = proc
+        self.grace = grace
+        self.since = time.monotonic()
+        self._kill = kill or proc.kill
+        self._killed = False
+        if force:
+            self.kill()
+        else:
+            try:
+                proc.terminate()
+            except OSError:
+                pass
+
+    def kill(self):
+        if not self._killed:
+            self._killed = True
+            try:
+                self._kill()
+            except OSError:
+                pass
+
+    @property
+    def age(self) -> float:
+        return time.monotonic() - self.since
+
+    @property
+    def overdue(self) -> bool:
+        """Killed a whole grace ago and still there: nothing more can be
+        sent to it, the caller logs it and goes on."""
+        return self.age >= 2 * self.grace
+
+    def gone(self) -> bool:
+        """One poll, never blocking: True once the pid has been reaped."""
+        if self.proc.poll() is not None:
+            return True
+        if self.age >= self.grace:
+            self.kill()
+        return False
+
+    def wait(self) -> bool:
+        """Block until the process is reaped (True) or overdue (False)."""
+        while not self.gone():
+            if self.overdue:
+                return False
+            time.sleep(EXIT_POLL_S)
+        return True
+
+
+def _end(proc: subprocess.Popen, grace: float = EXIT_GRACE_S,
+         force: bool = False):
+    end = ProcessEnd(proc, grace, force=force)
+    if not end.wait():
+        logger.error("process pid=%s not reaped %.1fs after its signal",
+                     proc.pid, end.age)
 
 
 class NodeProcesses:
@@ -188,11 +270,7 @@ class NodeProcesses:
 
     def kill_raylet(self, graceful: bool = False):
         """Chaos hook (analog of ray: _private/test_utils.py NodeKillerActor)."""
-        if graceful:
-            self.raylet_proc.terminate()
-        else:
-            self.raylet_proc.kill()
-        self.raylet_proc.wait(timeout=10)
+        _end(self.raylet_proc, RAYLET_EXIT_GRACE_S, force=not graceful)
 
     # -- network chaos hooks (see _private/faultsim.py) -----------------
     # Every control-plane process spawned from here inherits
@@ -223,8 +301,7 @@ class NodeProcesses:
         """Chaos hook: kill the GCS process (head only). State survives in
         the persist log; ``restart_gcs`` brings it back on the same port."""
         assert self.gcs_proc is not None, "kill_gcs only valid on the head"
-        self.gcs_proc.kill()
-        self.gcs_proc.wait(timeout=10)
+        _end(self.gcs_proc, force=True)
 
     def restart_gcs(self):
         """Restart the GCS on its original port; it replays the persist log
@@ -241,23 +318,14 @@ class NodeProcesses:
         )
 
     def shutdown(self):
-        for proc in (self.raylet_proc, self.gcs_proc):
-            if proc is None:
-                continue
-            try:
-                proc.terminate()
-            except Exception:
-                pass
-        for proc in (self.raylet_proc, self.gcs_proc):
-            if proc is None:
-                continue
-            try:
-                proc.wait(timeout=5)
-            except Exception:
-                try:
-                    proc.kill()
-                except Exception:
-                    pass
+        """End this node's processes: the raylet, which ends its workers
+        and waits for each of them (``Raylet.stop``), then the GCS. When
+        this returns, every process the session started on this node has
+        been reaped, so a chip one of them held can be opened; one that
+        outlived its SIGKILL by a whole grace is logged by pid instead."""
+        _end(self.raylet_proc, RAYLET_EXIT_GRACE_S)
+        if self.gcs_proc is not None:
+            _end(self.gcs_proc)
         # release this node's share of /dev/shm: the store dir (slab
         # segments, index, .obj files) is dead weight once the raylet is
         # gone — processes still holding mappings keep their pages until

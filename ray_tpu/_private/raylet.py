@@ -45,6 +45,7 @@ from ray_tpu._private.common import (
 )
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 from ray_tpu._private.ids import NodeID, ObjectID
+from ray_tpu._private.node import EXIT_POLL_S, ProcessEnd
 from ray_tpu._private import faultsim, logplane
 from ray_tpu._private.rpcio import (Connection, Finalized, RpcError,
                                     RpcServer, call_with_retries, connect,
@@ -94,6 +95,13 @@ class _Worker:
         self.registered = asyncio.get_running_loop().create_future()
         self.started_at = time.monotonic()
         self.oom_killed = False
+        self.kill_intended = False
+        # what this worker's actor holds of the node: given back by the
+        # raylet's _reap, once the process is gone
+        self.actor_resources: Dict[str, float] = {}
+        # set by the raylet's _end_worker: the process was signalled and
+        # is not reaped yet
+        self.end: Optional[ProcessEnd] = None
         # log streaming (ray: _private/log_monitor.py): the raylet tails
         # this file and publishes new lines to drivers
         self.log_path = log_path
@@ -411,6 +419,9 @@ class Raylet:
         # Worker pool (idle queues keyed by runtime-env hash)
         self.idle_workers: Dict[str, deque] = {}
         self.all_workers: Dict[int, _Worker] = {}  # pid -> worker
+        # pid -> worker that was signalled (_end_worker) and is not reaped
+        # yet (_reap): it may have left all_workers with its connection
+        self.dying: Dict[int, _Worker] = {}
         # spawn_id -> worker: the registration key that survives pid
         # translation through container engines (see _Worker.spawn_id)
         self._workers_by_spawn: Dict[str, _Worker] = {}
@@ -872,10 +883,7 @@ class Raylet:
                 })
             except Exception:
                 pass
-            try:
-                victim.kill_process()
-            except Exception:
-                pass
+            self._end_worker(victim, force=True)
 
     def _register_payload(self) -> dict:
         """Node registration incl. a report of what this raylet is actually
@@ -927,23 +935,60 @@ class Raylet:
                 delay = min(delay * 1.5, 2.0)
 
     async def stop(self):
+        """End every worker and wait, off the event loop's back, until each
+        one and each that was dying already has been reaped: when this
+        returns no process of this raylet holds a chip. One that outlives
+        its SIGKILL by a whole grace is logged by pid and age."""
         self._stopping = True
         for t in self._tasks:
             t.cancel()
         for w in list(self.all_workers.values()):
-            try:
-                w.proc.terminate()
-            except Exception:
-                pass
+            self._end_worker(w)
         agent = getattr(self, "agent_proc", None)
+        ends = [w.end for w in self.dying.values()]
         if agent is not None:
-            try:
-                agent.kill()
-            except Exception:
-                pass
+            ends.append(ProcessEnd(agent, force=True))
+        # the server stays up meanwhile: an exiting worker flushes its
+        # task events and its log tail through it
+        while ends and not all(e.overdue for e in ends):
+            await asyncio.sleep(EXIT_POLL_S)
+            ends = [e for e in ends if not e.gone()]
+        for e in ends:
+            logger.error("raylet %s stops with pid=%s not reaped %.1fs after "
+                         "its signal", self.node_id[:8], e.proc.pid, e.age)
         await self.server.stop()
         if self.gcs:
             await self.gcs.close()
+
+    def _end_worker(self, w: _Worker, force: bool = False) -> None:
+        """The one place that signals a worker: SIGTERM (SIGKILL where
+        ``force``), SIGKILL after node.EXIT_GRACE_S, its container with it.
+        From here on the worker is dying, whatever becomes of its
+        connection, and ``_reap`` alone says when it is gone."""
+        if w.end is not None:
+            if force:
+                w.end.kill()
+            return
+        w.end = ProcessEnd(w.proc, force=force, kill=w.kill_process)
+        self.dying[w.proc.pid] = w
+        spawn(self._reap(w))
+
+    async def _reap(self, w: _Worker) -> None:
+        """The one place that decides a worker is gone: its pid has been
+        waited for. Only then is what it held given back to the scheduler:
+        the kernel takes up to 16 s to release a dead process's chips, and
+        a worker placed onto them before that dies at its device open."""
+        while not w.end.gone():
+            await asyncio.sleep(EXIT_POLL_S)
+        del self.dying[w.proc.pid]
+        held, w.actor_resources = w.actor_resources, {}
+        # a bundle returned meanwhile took its named resources with it
+        res_add(self.resources_available,
+                {k: v for k, v in held.items() if k in self.resources_total})
+        for key, b in list(self.pg_bundles.items()):
+            if b.get("returning"):
+                self._return_bundle(*key)
+        self._dispatch_event.set()
 
     def _pending_demand(self) -> List[Dict[str, float]]:
         """Resource demand of queued tasks (infeasible + ready +
@@ -1194,6 +1239,10 @@ class Raylet:
         self.all_workers.pop(w.proc.pid, None)
         if w.spawn_id:
             self._workers_by_spawn.pop(w.spawn_id, None)
+        # a worker this raylet cannot reach serves no one: whether it was
+        # signalled, crashed or only lost its socket, it stays known as
+        # dying until it is reaped
+        self._end_worker(w)
         # record the fate so lease holders can ask WHY their direct conn
         # dropped (e.g. surface the OOM kill instead of a generic loss)
         if w.oom_killed:
@@ -1236,7 +1285,7 @@ class Raylet:
             try:
                 await self.gcs.request(
                     "actor_died",
-                    {"actor_id": w.actor_id, "intended": getattr(w, "kill_intended", False),
+                    {"actor_id": w.actor_id, "intended": w.kill_intended,
                      "reason": f"actor worker exited (pid={w.proc.pid})"},
                 )
             except Exception:
@@ -1922,7 +1971,7 @@ class Raylet:
                     victim = other.popleft()
                     if victim.conn is not None and not victim.conn.closed:
                         victim.kill_intended = True
-                        victim.proc.terminate()
+                        self._end_worker(victim)
                         reclaimed = True
                         break
                 if reclaimed:
@@ -2057,7 +2106,7 @@ class Raylet:
                 "alive" if proc.poll() is None
                 else f"exited rc={proc.returncode}",
             )
-            w.kill_process()  # reaches the container too, if any
+            self._end_worker(w, force=True)
             self.all_workers.pop(proc.pid, None)
             self._workers_by_spawn.pop(spawn_id, None)
             return None
@@ -2133,11 +2182,7 @@ class Raylet:
         if w is None:
             return {}
         w.kill_intended = True
-        res_add(self.resources_available, getattr(w, "actor_resources", {}))
-        try:
-            w.proc.terminate()
-        except Exception:
-            pass
+        self._end_worker(w)
         return {}
 
     async def _route_actor_task(self, spec: TaskSpec, actor_addr: Optional[tuple]):
@@ -3318,9 +3363,16 @@ class Raylet:
         self._return_bundle(p["pg_id"], p["bundle_index"])
 
     def _return_bundle(self, pg_id: str, bundle_index: int):
-        b = self.pg_bundles.pop((pg_id, bundle_index), None)
+        b = self.pg_bundles.get((pg_id, bundle_index))
         if not b:
             return
+        if any(w.actor_resources.keys() & b["named"].keys()
+               for w in self.dying.values()):
+            # a dying worker still holds part of this bundle, its chips
+            # among it: _reap returns the bundle once the worker is gone
+            b["returning"] = True
+            return
+        del self.pg_bundles[(pg_id, bundle_index)]
         for k, v in b["named"].items():
             self.resources_total[k] = max(0.0, self.resources_total.get(k, 0.0) - v)
             self.resources_available[k] = max(
@@ -3374,6 +3426,6 @@ class Raylet:
             return {"cancelled": True}
         running = self.running.get(tid)
         if running is not None and p.get("force") and running.worker is not None:
-            running.worker.proc.terminate()
+            self._end_worker(running.worker)
             return {"cancelled": True}
         return {"cancelled": False}

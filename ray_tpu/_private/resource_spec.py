@@ -16,16 +16,16 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 
 
-def count_tpu_device_nodes() -> int:
-    """Chips on this host, by their device nodes (/dev/accel* on older
+def tpu_device_nodes() -> List[str]:
+    """This host's chips, by their device nodes (/dev/accel* on older
     generations, /dev/vfio/<n> since v5e) — opens nothing."""
-    return (len(glob.glob("/dev/accel[0-9]*"))
-            or len(glob.glob("/dev/vfio/[0-9]*")))
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def detect_resources() -> Tuple[Dict[str, float], Dict[str, str]]:
@@ -43,7 +43,7 @@ def detect_resources() -> Tuple[Dict[str, float], Dict[str, str]]:
 
     chips = os.environ.get("TPU_CHIP_COUNT")
     if chips is None and cfg.tpu_autodetect:
-        chips = str(count_tpu_device_nodes())
+        chips = str(len(tpu_device_nodes()))
     if chips:
         n = float(chips)
         if n > 0:

@@ -1066,9 +1066,13 @@ class RpcServer:
     async def stop(self):
         if self._server:
             self._server.close()
-            await self._server.wait_closed()
         for conn in list(self.connections):
             await conn.close()
+        if self._server:
+            # after the connections: since Python 3.12 this waits for every
+            # one of them, and a peer that stays connected held a stopping
+            # raylet until its node's shutdown killed it
+            await self._server.wait_closed()
 
 
 async def connect(host: str, port: int, handler=None, name: str = "client",

@@ -233,6 +233,10 @@ def _subscribe_worker_logs(cw):
 
 
 def shutdown():
+    """Disconnect this driver and end the node it started, if any. When
+    this returns, every process the session started (raylet, its workers,
+    GCS) has been reaped, so a chip one of them held can be opened by the
+    next job (``NodeProcesses.shutdown``)."""
     with _init_lock:
         cw = global_worker.core_worker
         if cw is not None:

@@ -11,10 +11,13 @@ ICI inside jitted steps.
 
 from __future__ import annotations
 
+import errno
+import glob
 import os
 import socket
+import time
 from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 
 @dataclass
@@ -67,10 +70,83 @@ class JaxConfig(BackendConfig):
         return _JaxBackend
 
 
+# How long a TPU gang's worker waits for the host's chips before it opens
+# them. The longest release seen after a four-chip job's worker exited is
+# 16 s (PERF.md section 7); a predecessor still draining may take
+# train_drain_grace_s (30 s) more.
+CHIPS_FREE_DEADLINE_S = 60.0
+_CHIPS_POLL_S = 0.1
+
+
+def _chip_is_held(node: str) -> bool:
+    """Whether another process still holds this chip node. The probe is an
+    open and a close of the node, as jax's own open will be: a VFIO group
+    admits one opener. Reading /proc/*/fd instead would open nothing and
+    see nothing: a holder inside ``exit`` has no fd table left while its
+    nodes stay busy (on the chip /proc named the holder of one of four
+    held nodes as the job returned and of none after, for the 9.4 s until
+    the last opened: PERF.md section 7). The probe runs in the process
+    that is about to open the chips and is their one rightful opener (the
+    runtime sets no chip visibility: a TPU worker's jax opens every chip of
+    the host), so it takes no chip from anyone."""
+    try:
+        os.close(os.open(node, os.O_RDWR))
+    except OSError as e:
+        # any other error is not this wait's to judge: jax's open will say
+        return e.errno == errno.EBUSY
+    return False
+
+
+def _busy_chip_node() -> Optional[str]:
+    """The first of this host's chip nodes that is still held, or None."""
+    from ray_tpu._private.resource_spec import tpu_device_nodes
+
+    return next((n for n in tpu_device_nodes() if _chip_is_held(n)), None)
+
+
+def _chip_holders(node: str) -> List[int]:
+    """Pids that /proc shows with ``node`` open. Opens nothing; empty for
+    a holder that is exiting, or another user's."""
+    pids = []
+    for fd in glob.glob("/proc/[0-9]*/fd/*"):
+        try:
+            if os.readlink(fd) == node:
+                pids.append(int(fd.split("/")[2]))
+        except OSError:
+            pass
+    return sorted(set(pids))
+
+
+def _wait_for_chips() -> float:
+    """Wait until every chip node of this host can be opened, at most
+    CHIPS_FREE_DEADLINE_S; log and return the seconds waited. The job
+    before on this host may have returned with its worker still exiting
+    (an older runtime, a killed driver or raylet), and jax's open of a
+    held chip fails once, from the user's loop, with no retry."""
+    start = time.monotonic()
+    while (node := _busy_chip_node()) is not None:
+        waited = time.monotonic() - start
+        if waited >= CHIPS_FREE_DEADLINE_S:
+            holders = _chip_holders(node)
+            raise RuntimeError(
+                f"{node} is still held after {waited:.1f} s"
+                + (f" by pid {', '.join(map(str, holders))}" if holders
+                   else " (no live holder in /proc: a process still exiting,"
+                        " or another user's)")
+                + ": another job on this host has the chips")
+        time.sleep(_CHIPS_POLL_S)
+    waited = time.monotonic() - start
+    print(f"ray_tpu: waited {waited:.1f} s for this host's chips", flush=True)
+    return waited
+
+
 def _jax_worker_setup(coordinator: Optional[str], num_processes: int,
-                      process_id: int, env_vars: Dict[str, str]):
+                      process_id: int, env_vars: Dict[str, str],
+                      use_tpu: bool):
     for k, v in env_vars.items():
         os.environ[k] = str(v)
+    if use_tpu:
+        _wait_for_chips()
     if coordinator is not None:
         import jax
 
@@ -116,7 +192,8 @@ class _JaxBackend(Backend):
         for i, w in enumerate(worker_group.workers):
             refs.append(
                 w.execute.remote(
-                    _jax_worker_setup, coordinator, n, i, dict(config.env_vars)
+                    _jax_worker_setup, coordinator, n, i,
+                    dict(config.env_vars), config.use_tpu,
                 )
             )
         ray_tpu.get(refs, timeout=300)

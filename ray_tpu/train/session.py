@@ -80,6 +80,9 @@ class _Session:
         # gang-supervision surface: progress heartbeat for the driver-side
         # watchdog (stamped at every report) and the SIGTERM drain latch
         self.drain_requested = threading.Event()
+        # set when the train loop has returned or raised: no step boundary
+        # will come, so a drain has nothing left to wait for
+        self.loop_over = False
         self.step_count = 0
         self.last_progress = time.monotonic()
         # JaxTrainer(overlap_grads=True): GradSync dispatches gradient
@@ -125,9 +128,11 @@ def request_drain() -> bool:
     """Ask the active session to drain: checkpoint at the next step boundary
     (the next ``report()``) and exit cleanly. Returns whether a session was
     there to accept — the SIGTERM handler falls back to immediate exit when
-    no training is in flight."""
+    no training is in flight: no session, or one whose loop is over (a
+    gang killed at the end of ``fit()`` would otherwise sit out the whole
+    drain grace, holding its chips)."""
     s = _session
-    if s is None:
+    if s is None or s.loop_over:
         return False
     s.drain_requested.set()
     return True

@@ -89,13 +89,16 @@ class TrainWorker:
                 # no done with a save outstanding: its checkpoint goes on
                 # the queue first, and a commit that failed is the error
                 finish_commit()
-                sess.queue.put({"type": "done"})
+                last = {"type": "done"}
             except BaseException as e:  # noqa: BLE001
-                sess.queue.put({
+                last = {
                     "type": "error",
                     "error": f"{type(e).__name__}: {e}",
                     "traceback": traceback.format_exc(),
-                })
+                }
+            # before the driver can know: it kills the gang at this message
+            sess.loop_over = True
+            sess.queue.put(last)
 
         self._thread = threading.Thread(target=_run, name="train-loop", daemon=True)
         self._thread.start()
