@@ -8,10 +8,19 @@ and one ``flash_bwd`` alone (``flash_*_kernel_ms``: the wall times hold the
 transposes round the kernels and the sum of dQ's partials too).
 
     python benches/flash_widths.py --widths 192x128 --lengths 1024,8192
+    python benches/flash_widths.py --widths 128x128 --lengths 16384 \
+        --kv-heads 4 --window 2048 --check 1
 
 Tokens a call are held at ``--tokens`` (16,384: the cells' load), heads at
-``--heads``. ``xla`` is what ``causal_self_attention(..., "xla")`` runs; it
-is skipped where its [B, H, T, T] scores would not fit (past 4096).
+``--heads``, of which ``--kv-heads`` are keys' and values' (default: as
+many), under ``--window`` keys (default: none). ``xla`` is what
+``causal_self_attention(..., "xla")`` runs; it is skipped where its
+[B, H, T, T] scores would not fit (past 4096). ``--check 1`` also holds the
+kernel's output and three gradients (bfloat16 operands) against
+``attention_reference`` on the same operands in float32 at matmul precision
+highest, one query head at a time, and prints the largest differences
+over the largest reference entry: what interpret mode cannot show of the
+pipeline's writes.
 """
 
 import argparse
@@ -32,12 +41,19 @@ def main():
     parser.add_argument("--tokens", type=int, default=16384)
     parser.add_argument("--heads", type=int, default=32)
     parser.add_argument("--reps", type=int, default=10)
+    parser.add_argument("--kv-heads", type=int, default=None)
+    parser.add_argument("--window", type=int, default=None)
+    parser.add_argument("--check", type=int, default=0)
     args = parser.parse_args()
 
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops.attention import causal_self_attention, grid_block_kinds
+    from ray_tpu.ops.attention import (attention_reference,
+                                       causal_self_attention,
+                                       grid_block_kinds)
+
+    kv_heads = args.kv_heads or args.heads
 
     def timed(fn, *xs):
         jax.block_until_ready(fn(*xs))
@@ -71,6 +87,49 @@ def main():
         return {f"flash_{k}_kernel_ms": round(sum(ns) / len(ns) / 1e6, 3)
                 for k, ns in found.items()}
 
+    def against_reference(q, k, v):
+        """Largest |kernel - reference| over the largest |reference| entry,
+        for the output and the gradients of q, k and v under a seeded
+        cotangent."""
+        w = jax.random.normal(jax.random.PRNGKey(3), (*q.shape[:3],
+                                                      v.shape[-1]))
+        f32 = lambda x: x.astype(jnp.float32)
+
+        def out_and_grads(fn, q, k, v, w):
+            out, vjp = jax.vjp(lambda *x: f32(fn(*x)), q, k, v)
+            return (out, *vjp(w))
+
+        kernel = jax.jit(lambda q, k, v, w: out_and_grads(
+            lambda *x: causal_self_attention(*x, "flash", args.window),
+            q, k, v, w))
+        bhsd = lambda t: t.transpose(0, 2, 1, 3)
+
+        @jax.jit
+        def plain(q, k, v, w):
+            with jax.default_matmul_precision("highest"):
+                return out_and_grads(
+                    lambda *x: bhsd(attention_reference(
+                        *(bhsd(t) for t in x), causal=True,
+                        window=args.window)), f32(q), f32(k), f32(v), w)
+
+        got = kernel(q, k, v, w)
+        group = args.heads // kv_heads
+        want = [[], [], [], []]
+        for head in range(args.heads):   # one query head's scores at a time
+            one, kv = slice(head, head + 1), slice(head // group,
+                                                   head // group + 1)
+            parts = plain(q[:, :, one], k[:, :, kv], v[:, :, kv],
+                          w[:, :, one])
+            for into, part in zip(want, parts):
+                into.append(part)
+        of_group = lambda parts: [sum(parts[i:i + group])
+                                  for i in range(0, args.heads, group)]
+        want = [want[0], want[1], of_group(want[2]), of_group(want[3])]
+        return {name: round(float(
+            jnp.abs(f32(a) - jnp.concatenate(b, axis=2)).max()
+            / max(jnp.abs(x).max() for x in b)), 5)
+            for name, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
+
     for width in args.widths.split(","):
         d_qk, d_v = (int(n) for n in width.split("x"))
         for seq in (int(n) for n in args.lengths.split(",")):
@@ -78,19 +137,25 @@ def main():
             keys = jax.random.split(jax.random.PRNGKey(seq), 3)
             q = jax.random.normal(keys[0], (batch, seq, args.heads, d_qk),
                                   jnp.bfloat16)
-            k = jax.random.normal(keys[1], q.shape, jnp.bfloat16)
-            v = jax.random.normal(keys[2], (batch, seq, args.heads, d_v),
+            k = jax.random.normal(keys[1], (batch, seq, kv_heads, d_qk),
+                                  jnp.bfloat16)
+            v = jax.random.normal(keys[2], (batch, seq, kv_heads, d_v),
                                   jnp.bfloat16)
             line = {"d_qk": d_qk, "d_v": d_v, "seq": seq, "batch": batch,
-                    "heads": args.heads, "device": jax.devices()[0].device_kind,
-                    "grid_blocks": grid_block_kinds(seq, seq, True)}
+                    "heads": args.heads, "kv_heads": kv_heads,
+                    "window": args.window,
+                    "device": jax.devices()[0].device_kind,
+                    "grid_blocks": grid_block_kinds(seq, seq, True,
+                                                    window=args.window),
+                    "grid_blocks_bwd": grid_block_kinds(
+                        seq, seq, True, backward=True, window=args.window)}
             for path in ("flash", "xla"):
                 if path == "xla" and seq > 4096:
                     continue
 
                 def loss(q, k, v, path=path):
-                    return causal_self_attention(q, k, v, path).astype(
-                        jnp.float32).sum()
+                    return causal_self_attention(
+                        q, k, v, path, args.window).astype(jnp.float32).sum()
 
                 fns = {"_fwd_ms": jax.jit(loss),
                        "_fwd_bwd_ms": jax.jit(jax.grad(loss, argnums=(0, 1, 2)))}
@@ -101,6 +166,8 @@ def main():
                         line.update(kernel_ms(fns["_fwd_bwd_ms"], q, k, v))
                 except Exception as e:  # a path that does not fit or lower
                     line[path + "_error"] = str(e)[:200]
+            if args.check:
+                line["against_reference"] = against_reference(q, k, v)
             print(json.dumps(line), flush=True)
 
 

@@ -4,8 +4,12 @@
 - llama: decoder with RoPE/GQA + KV-cache serving path
 - vision: ViT and ResNet
 - moe_lm: Switch-Transformer MoE LM (GSPMD expert parallelism)
+- mla_moe: latent attention, routed experts held by share, a prediction
+  module (training)
+- afmoe: window and full attention layers over grouped heads, a gated and
+  normed attention, the same routed-expert layer (training)
 """
 
-from ray_tpu.models import gpt2, llama, moe_lm, vision
+from ray_tpu.models import afmoe, gpt2, llama, mla_moe, moe_lm, vision
 
-__all__ = ["gpt2", "llama", "moe_lm", "vision"]
+__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "vision"]

@@ -144,29 +144,39 @@ class LatentAttention(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """The expert feed-forward part: router over all experts, the held
-    experts' share of the routed result, the shared experts on every token.
-    -> (y, tokens each held expert received)."""
-    config: MLAMoEConfig
+    """The expert feed-forward part: router over all ``experts`` experts,
+    the share ``expert_shard = (index, of)`` of them held here and their
+    part of the routed result, ``shared`` shared experts on every token;
+    each expert a SwiGLU of ``width``, ``per_token`` a token, weighted as
+    ``ops.moe.topk_routing`` says by ``scale`` and ``normalize``. The sizes
+    are fields and no model's config: ``models/afmoe.py`` runs the same
+    layer under its own names. -> (y, tokens each held expert received)."""
+    experts: int
+    expert_shard: Tuple[int, int]
+    width: int
+    per_token: int
+    scale: float
+    normalize: bool
+    shared: int
+    dtype: Any
+    kernel_init: Any
 
     @nn.compact
     def __call__(self, x):
-        c = self.config
         B, T, d = x.shape
-        held, width = c.experts_held, c.moe_intermediate_size
-        router = self.param("router", _init(c), (d, c.n_routed_experts))
+        index, of = self.expert_shard
+        held, width, init = self.experts // of, self.width, self.kernel_init
+        router = self.param("router", init, (d, self.experts))
         bias = self.param("router_bias", nn.initializers.zeros,
-                          (c.n_routed_experts,))
-        wi = self.param("experts_wi", _init(c), (held, d, 2 * width))
-        wo = self.param("experts_wo", _init(c), (held, width, d))
+                          (self.experts,))
+        wi = self.param("experts_wi", init, (held, d, 2 * width))
+        wo = self.param("experts_wo", init, (held, width, d))
         flat = x.reshape(B * T, d)
         experts, weights = moe.topk_routing(
-            flat, router, bias, c.num_experts_per_tok,
-            c.routed_scaling_factor, c.norm_topk_prob)
-        index, of = c.expert_shard
+            flat, router, bias, self.per_token, self.scale, self.normalize)
         y, tokens = moe.held_expert_ffn(flat, experts, weights, wi, wo,
                                         index=index, of=of)
-        shared = SwiGLU(width * c.n_shared_experts, c.dtype, _init(c),
+        shared = SwiGLU(width * self.shared, self.dtype, init,
                         name="shared_experts")(x)
         return shared + y.reshape(B, T, d), tokens
 
@@ -187,7 +197,13 @@ class Block(nn.Module):
             y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
                                name="mlp")(h), jnp.zeros((0,), jnp.int32)
         else:
-            y, tokens = RoutedExperts(c, name="moe")(h)
+            y, tokens = RoutedExperts(
+                experts=c.n_routed_experts, expert_shard=c.expert_shard,
+                width=c.moe_intermediate_size,
+                per_token=c.num_experts_per_tok,
+                scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
+                shared=c.n_shared_experts, dtype=c.dtype,
+                kernel_init=_init(c), name="moe")(h)
         return on_batch_axes(x + y), tokens
 
 
@@ -306,34 +322,43 @@ def shard_train_state(params, opt_state, mesh):
         params, opt_state, param_shardings(params, mesh))
 
 
-def step_metrics(loss, main, mtp, tokens_per_expert, *, pairs=None) -> dict:
-    """What a loop hands ``train.report`` after a step of
-    ``build_train_step``: the loss, its two terms, the most and the mean
-    tokens any held expert received (over the expert layers), and what the
-    expert layers' row buffers held: ``rows_present``, the pairs that fell
-    on held experts, ``rows_buffered``, the lengths the layers took for
-    them (``ops.moe.row_buffer_rung``, which the layer itself asks), both
-    summed over the expert layers, and their quotient ``rows_fill``.
-    ``pairs`` is the step's tokens x ``num_experts_per_tok``, the most a
-    layer can hold, which the experts' counts do not tell: a loop that does
-    not give it gets ``rows_present`` alone. The same numbers go to the
-    step observatory as one ``counters`` record ``train/step_aux`` under
-    the step they belong to. Reads the four results back to the host: call
-    it where the loop reads its loss."""
+def held_expert_load(tokens_per_expert, pairs=None) -> dict:
+    """What the expert layers' row buffers held in a step, from the tokens
+    each held expert received [expert layers, held]: the most and the mean
+    tokens of any held expert, ``rows_present``, the pairs that fell on
+    held experts, ``rows_buffered``, the lengths the layers took for them
+    (``ops.moe.row_buffer_rung``, which the layer itself asks), both summed
+    over the expert layers, and their quotient ``rows_fill``. ``pairs`` is
+    the step's tokens x experts a token, the most a layer can hold, which
+    the experts' counts do not tell: without it ``rows_present`` alone.
+    Empty for a model with no expert layer."""
     import numpy as np
 
-    metrics = {"loss": float(loss), "loss_main": float(main),
-               "loss_mtp": float(mtp)}
     tokens = np.asarray(tokens_per_expert)
-    if tokens.size:
-        metrics["expert_tokens_max"] = int(tokens.max())
-        metrics["expert_tokens_mean"] = float(tokens.mean())
-        present = tokens.sum(axis=-1)
-        metrics["rows_present"] = int(present.sum())
-        if pairs is not None:
-            buffered = np.asarray(moe.row_buffer_rungs(pairs))[
-                moe.row_buffer_rung(present, pairs)]
-            metrics.update(rows_buffered=int(buffered.sum()),
-                           rows_fill=float(present.sum() / buffered.sum()))
+    if not tokens.size:
+        return {}
+    present = tokens.sum(axis=-1)
+    load = {"expert_tokens_max": int(tokens.max()),
+            "expert_tokens_mean": float(tokens.mean()),
+            "rows_present": int(present.sum())}
+    if pairs is not None:
+        buffered = np.asarray(moe.row_buffer_rungs(pairs))[
+            moe.row_buffer_rung(present, pairs)]
+        load.update(rows_buffered=int(buffered.sum()),
+                    rows_fill=float(present.sum() / buffered.sum()))
+    return load
+
+
+def step_metrics(loss, main, mtp, tokens_per_expert, *, pairs=None) -> dict:
+    """What a loop hands ``train.report`` after a step of
+    ``build_train_step``: the loss, its two terms and the held experts'
+    load (``held_expert_load``; ``pairs`` is the step's tokens x
+    ``num_experts_per_tok``). The same numbers go to the step observatory
+    as one ``counters`` record ``train/step_aux`` under the step they
+    belong to. Reads the four results back to the host: call it where the
+    loop reads its loss."""
+    metrics = {"loss": float(loss), "loss_main": float(main),
+               "loss_mtp": float(mtp),
+               **held_expert_load(tokens_per_expert, pairs)}
     steptrace.record_counters("train/step_aux", metrics)
     return metrics

@@ -38,16 +38,39 @@ from ray_tpu.parallel.mesh_utils import traced_mesh_axes
 NEG_INF = -1e30
 
 
+def _per_query_head(q, kv):
+    """``kv`` with each key-value head repeated for the query heads that
+    read it: query head j reads head j // group, on the heads' axis, which
+    is the one before the sequence's (batch x heads where they are folded).
+    ``kv`` itself where the heads are as many."""
+    group, rest = divmod(q.shape[-3], kv.shape[-3])
+    assert group >= 1 and not rest, (q.shape, kv.shape)
+    return kv if group == 1 else jnp.repeat(kv, group, axis=-3)
+
+
+def _seen(qi, ki, window: Optional[int]):
+    """Whether, under a causal mask, the query at key position ``qi`` sees
+    the key at ``ki``: none past itself, and under a ``window`` only the
+    last ``window`` keys, its own position among them."""
+    return (qi >= ki) if window is None else (qi >= ki) & (qi - ki < window)
+
+
 def attention_reference(q, k, v, *, causal: bool = False,
-                        sm_scale: Optional[float] = None) -> jax.Array:
-    """Naive softmax(QK^T)V. Shapes: (..., s, d)."""
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
+    """Naive softmax(QK^T)V. Shapes: (..., h, s, d); ``k`` and ``v`` may
+    have fewer heads, each read by a group of query heads. Under ``window``
+    a query sees its own position and the ``window - 1`` before it."""
     sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    assert causal or window is None, "a window is a causal mask's"
+    k, v = _per_query_head(q, k), _per_query_head(q, v)
     s = jnp.einsum("...qd,...kd->...qk", q, k) * sm_scale
     if causal:
         q_len, k_len = s.shape[-2], s.shape[-1]
         qi = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
         ki = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
-        s = jnp.where(qi + (k_len - q_len) >= ki, s, NEG_INF)
+        s = jnp.where(_seen(qi + (k_len - q_len), ki, window), s,
+                      NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p, v).astype(q.dtype)
 
@@ -58,20 +81,22 @@ def attention_reference(q, k, v, *, causal: bool = False,
 
 def online_block_update(q, k, v, m, l, acc, *, sm_scale: float,
                         q_offset=0, k_offset=0, causal: bool = False,
-                        k_total: Optional[int] = None):
+                        k_total: Optional[int] = None,
+                        window: Optional[int] = None):
     """Fold one KV block into flash accumulators.
 
     q: (..., bq, d); k/v: (..., bk, d); m,l: (..., bq); acc: (..., bq, d).
     Offsets are the blocks' global sequence positions (for causal masks in
     blockwise/ring execution). ``k_total`` masks padding columns whose
-    global position is past the true sequence end.
+    global position is past the true sequence end; under ``window`` a query
+    sees the last ``window`` keys up to its own position.
     """
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * sm_scale
     bq, bk = s.shape[-2], s.shape[-1]
     qi = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + q_offset
     ki = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + k_offset
     if causal:
-        s = jnp.where(qi >= ki, s, NEG_INF)
+        s = jnp.where(_seen(qi, ki, window), s, NEG_INF)
     if k_total is not None:
         s = jnp.where(ki < k_total, s, NEG_INF)
     m_new = jnp.maximum(m, s.max(axis=-1))
@@ -96,7 +121,9 @@ def finalize_flash(m, l, acc, dtype):
 # chunked JAX fallback (CPU / any backend; differentiable)
 # ----------------------------------------------------------------------
 
-def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
+def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int,
+                window: Optional[int] = None):
+    k, v = _per_query_head(q, k), _per_query_head(q, v)
     *lead, q_len, d = q.shape
     d_v = v.shape[-1]
     k_len = k.shape[-2]
@@ -121,7 +148,7 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int):
         m2, l2, a2 = online_block_update(
             q, kk, vv, m, l, acc, sm_scale=sm_scale,
             q_offset=k_len - q_len, k_offset=i * block_k, causal=causal,
-            k_total=k_len if pad else None,
+            k_total=k_len if pad else None, window=window,
         )
         return (m2, l2, a2), None
 
@@ -224,6 +251,16 @@ def _live_tiles(rel, block_q: int, block_k: int, n_tiles: int, causal: bool):
             _clamp((rel + block_q - 1 + block_k) // block_k, n_tiles))
 
 
+def _window_tiles(rel, block_q: int, block_k: int, n_tiles: int, window: int):
+    """(n_start, n_clear) under a window of ``window`` keys, as
+    ``_live_tiles`` counts for the causal edge: the tiles before n_start lie
+    behind the window for every query of the q tile, those from n_clear on
+    inside it for every query; the window's far edge crosses the ones
+    between."""
+    return (_clamp((rel + 1 - window) // block_k, n_tiles),
+            _clamp((rel + block_q - 1 - window + block_k) // block_k, n_tiles))
+
+
 def _tile(c, size: int, n_tiles: int):
     """Slice of tile ``c`` among ``n_tiles`` of ``size``; static where
     ``c`` is, and for a lone tile, whose size need not fill a hardware tile."""
@@ -238,10 +275,18 @@ def _scaled(q, sm_scale: float, fold: bool):
     return (q.astype(jnp.float32) * sm_scale).astype(q.dtype) if fold else q
 
 
-def _scores(k, q, c, *, sm_scale: float, fold: bool, masked: bool,
-            block_k: int, rel):
+# What ``step`` is told of a tile, by (the diagonal crosses it, the window's
+# far edge crosses it): False, no mask; True, the causal edge alone, which
+# is every masked tile of a call without a window
+_EDGES = {(False, False): False, (True, False): True,
+          (False, True): "window", (True, True): "both"}
+
+
+def _scores(k, q, c, *, sm_scale: float, fold: bool, masked,
+            block_k: int, rel, window: Optional[int] = None):
     """s^T (block_k, block_q) of key tile ``c``, keys along sublanes: scaled
-    here unless q came scaled, and masked where the diagonal crosses."""
+    here unless q came scaled, and masked (``_EDGES``) where the diagonal
+    or the window's far edge crosses."""
     s = _dot(k, q, _NT)
     if not fold:
         s = s * sm_scale
@@ -249,61 +294,110 @@ def _scores(k, q, c, *, sm_scale: float, fold: bool, masked: bool,
         # query position less key position, within the tile and then overall
         ahead = (lax.broadcasted_iota(jnp.int32, s.shape, 1)
                  - lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        s = jnp.where(ahead >= c * block_k - rel, s, NEG_INF)
+        edge = c * block_k - rel
+        seen = None if masked == "window" else ahead >= edge
+        if masked in ("window", "both"):
+            near = ahead < edge + window
+            seen = near if seen is None else seen & near
+        s = jnp.where(seen, s, NEG_INF)
     return s
 
 
-def _walk(step, carry, n_full, n_live):
+def _walk(step, carry, n_full, n_live, n_start=None, n_clear=None):
     """Fold ``step(c, carry, masked)`` over the live key tiles: unmasked up
-    to n_full, masked from there to n_live. Static bounds unroll."""
-    if isinstance(n_full, int) and isinstance(n_live, int):
-        for c in range(n_live):
-            carry = step(c, carry, c >= n_full)
+    to n_full, masked from there to n_live. Static bounds unroll. Under a
+    window (``_window_tiles``) the live tiles start at n_start and those
+    before n_clear are masked by its edge too: told apart tile by tile
+    where the bounds are static, else every masked tile gets both edges."""
+    static = isinstance(n_full, int) and isinstance(n_live, int)
+    if n_start is None:
+        if static:
+            for c in range(n_live):
+                carry = step(c, carry, c >= n_full)
+            return carry
+        carry = lax.fori_loop(0, n_full, lambda c, x: step(c, x, False),
+                              carry)
+        return lax.fori_loop(n_full, n_live, lambda c, x: step(c, x, True),
+                             carry)
+    if static and isinstance(n_start, int) and isinstance(n_clear, int):
+        for c in range(n_start, n_live):
+            carry = step(c, carry, _EDGES[c >= n_full, c < n_clear])
         return carry
-    carry = lax.fori_loop(0, n_full, lambda c, x: step(c, x, False), carry)
-    return lax.fori_loop(n_full, n_live, lambda c, x: step(c, x, True), carry)
+    clear = jnp.clip(n_clear, n_start, n_live)
+    full = jnp.clip(n_full, clear, n_live)
+    edged = lambda c, x: step(c, x, "both")
+    carry = lax.fori_loop(n_start, clear, edged, carry)
+    carry = lax.fori_loop(clear, full, lambda c, x: step(c, x, False), carry)
+    return lax.fori_loop(full, n_live, edged, carry)
 
 
 def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
-               causal: bool, alike_loop: bool):
+               causal: bool, alike_loop: bool, window: Optional[int] = None):
     """``row(j, n_full, n_live)`` for each of a grid block's ``n_q`` q tiles,
-    the bounds from ``_live_tiles``. With ``alike_loop``, rows whose bounds
-    are constants, the same for all and leave no tile masked (a whole grid
-    block's, a dead one's) are one loop over ``j``, a row a step: the row's
-    tiles stay straight-line code and the kernel's size stays a row's."""
-    bounds = [_live_tiles(rel0 + j * block_q, block_q, block_k, n_k, causal)
-              for j in range(n_q)]
-    n_full, n_live = bounds[0]
+    the bounds from ``_live_tiles``, and under a window ``row(j, n_full,
+    n_live, n_start, n_clear)`` with those of ``_window_tiles``. With
+    ``alike_loop``, rows whose bounds are constants, the same for all and
+    leave no tile masked (a whole grid block's, a dead one's) are one loop
+    over ``j``, a row a step: the row's tiles stay straight-line code and
+    the kernel's size stays a row's."""
+    def bounds_of(j):
+        rel = rel0 + j * block_q
+        live = _live_tiles(rel, block_q, block_k, n_k, causal)
+        if window is None:
+            return live
+        return live + _window_tiles(rel, block_q, block_k, n_k, window)
+
+    bounds = [bounds_of(j) for j in range(n_q)]
+    n_full, n_live = bounds[0][:2]
     if (alike_loop and isinstance(n_full, int) and n_full == n_live
+            and bounds[0][2:] in ((), (0, 0))
             and bounds.count(bounds[0]) == n_q > 1):
-        lax.fori_loop(0, n_q, lambda j, _: row(j, n_full, n_live), None)
+        lax.fori_loop(0, n_q, lambda j, _: row(j, *bounds[0]), None)
         return
     for j, live in enumerate(bounds):
         row(j, *live)
 
 
-_KINDS = ("whole", "diagonal", "dead", "looped")
+_KINDS = ("whole", "diagonal", "trailing", "dead", "looped")
+
+
+def _kinds_told_apart(nq: int, nk: int, res_q: int, res_k: int, offset: int,
+                      causal: bool, window: Optional[int]) -> bool:
+    """Whether a grid block's place says what the mask leaves of it
+    (``_grid_kinds``)."""
+    if not causal or nq == nk == 1:
+        return True
+    return (not offset and res_q == res_k
+            and (window is None or window % res_k == 0))
 
 
 def _grid_kinds(nq: int, nk: int, res_q: int, res_k: int, offset: int,
-                causal: bool) -> dict:
+                causal: bool, window: Optional[int] = None) -> dict:
     """{kind: grid blocks of it a head}, every kind of ``_KINDS`` in their
-    order: what the causal mask leaves of a block whose first query stands
+    order: what the mask leaves of a block whose first query stands
     ``rel0`` key positions past its first key is "whole" (every query sees
-    every key), "dead" (none sees any) or "diagonal" (the mask's edge
-    crosses it). Kinds are told apart where the grid is one block, which
-    is no variable of the grid whatever its offset, or self-attention in
-    square blocks (equal lengths, ``res_q == res_k``: every training call),
-    where the edge runs through a block from corner to corner or not at
-    all; under a mask any other grid, which no model here sends and no
-    chip run has measured, is "looped" throughout."""
-    if causal and (offset or res_q != res_k) and not nq == nk == 1:
+    every key), "dead" (none sees any), "diagonal" (the causal edge crosses
+    it) or, under a window, "trailing" (the window's far edge crosses it:
+    the diagonal's complementary triangle). Kinds are told apart where the
+    grid is one block, which is no variable of the grid whatever its offset
+    (and is called diagonal whichever edges cross it), or self-attention in
+    square blocks (equal lengths, ``res_q == res_k``: every training call)
+    under a window of whole blocks or none, where an edge runs through a
+    block from corner to corner or not at all; under a mask any other grid,
+    which no model here sends and no chip run has measured, is "looped"
+    throughout."""
+    if not _kinds_told_apart(nq, nk, res_q, res_k, offset, causal, window):
         return dict.fromkeys(_KINDS, 0) | {"looped": nq * nk}
 
     def left_by_mask(rel0):
-        if not causal or rel0 + 1 >= res_k:
+        if not causal:
             return "whole"
-        return "dead" if rel0 + res_q - 1 < 0 else "diagonal"
+        if rel0 + res_q - 1 < 0 or (window and rel0 - (res_k - 1) >= window):
+            return "dead"
+        if rel0 + 1 < res_k:
+            return "diagonal"
+        return ("whole" if not window or rel0 + res_q - 1 < window
+                else "trailing")
 
     found = collections.Counter(
         left_by_mask(qi * res_q + offset - ki * res_k)
@@ -311,30 +405,48 @@ def _grid_kinds(nq: int, nk: int, res_q: int, res_k: int, offset: int,
     return {kind: found[kind] for kind in _KINDS}
 
 
-def _walk_by_kind(walk, rel0, res: int, kinds):
+def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
+                  window: Optional[int] = None, dead_walks: bool = True):
     """``walk(rel0)`` for this grid block, with ``rel0`` a Python integer
     wherever the block's kind fixes which tiles are live: a whole, a
-    diagonal and a dead block each get a branch of their own whose tile
-    bounds are constants (of a whole block only ``rel0 + 1 >= res``
-    matters, of a dead one ``rel0 <= -res``); a grid that is "looped"
+    diagonal, a trailing and a dead block each get a branch of their own
+    whose tile bounds are constants (of a whole block only ``rel0 + 1 >=
+    res`` matters, of a dead one ``rel0 <= -res``; under a window a whole
+    block stands between the edges, as the one at ``rel0 == res`` does, and
+    the trailing one at ``rel0 == window``); a grid that is "looped"
     throughout gets the loops over bounds computed from the traced
     ``rel0``. ``kinds`` are the kinds the call's grid holds
-    (``_grid_kinds``), ``res`` its resident keys: no branch is made for a
-    kind that is absent, and none at all where there is one kind."""
-    if isinstance(rel0, int) or kinds == ("looped",):
+    (``_grid_kinds``), ``res`` its resident keys, ``res_q`` its resident
+    queries: no branch is made for a kind that is absent, and none at all
+    where there is one kind. Without ``dead_walks`` a block that the mask
+    leaves nothing of is not walked at all."""
+    if isinstance(rel0, int) or (dead_walks and kinds == ("looped",)):
         return walk(rel0)
-    straight = {"whole": (rel0 + 1 >= res, res - 1),
-                "diagonal": (rel0 == 0, 0),
-                "dead": (rel0 + res - 1 < 0, -res)}
+    if window is None:
+        straight = {"whole": (rel0 + 1 >= res, res - 1),
+                    "diagonal": (rel0 == 0, 0),
+                    "dead": (rel0 + res - 1 < 0, -res)}
+    else:
+        straight = {"whole": ((rel0 + 1 >= res) & (rel0 + res - 1 < window),
+                              res),
+                    "diagonal": (rel0 == 0, 0),
+                    "trailing": (rel0 == window, window),
+                    "dead": ((rel0 + res_q - 1 < 0)
+                             | (rel0 - (res - 1) >= window), -res)}
+    if kinds == ("looped",):
+        return pl.when(jnp.logical_not(straight["dead"][0]))(
+            functools.partial(walk, rel0))
     if len(kinds) == 1:
         return walk(straight[kinds[0]][1])
-    for here, rel in (straight[kind] for kind in kinds):
-        pl.when(here)(functools.partial(walk, rel))
+    for kind in kinds:
+        if dead_walks or kind != "dead":
+            here, rel = straight[kind]
+            pl.when(here)(functools.partial(walk, rel))
 
 
 def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                offset: int, static: bool, kinds):
+                offset: int, static: bool, kinds, window: Optional[int]):
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -342,7 +454,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
     rel0 = offset if static else qi * res_q + offset - ki * res_k
     fold = _scale_folds(q_ref.dtype, sm_scale)
     scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
-                               block_k=block_k)
+                               block_k=block_k, window=window)
 
     @pl.when(ki == 0)
     def _init():
@@ -351,7 +463,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def walk(rel0):
-        def row(j, n_full, n_live):
+        def row(j, *bounds):
             cols = _tile(j, block_q, n_q)
             rel = rel0 + j * block_q
             q = _scaled(q_ref[cols, :], sm_scale, fold)
@@ -375,7 +487,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
 
             m, l, acc = _walk(
                 step, (m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]),
-                n_full, n_live)
+                *bounds)
             m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = m, l, acc
 
             @pl.when(ki == nk - 1)
@@ -388,34 +500,41 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
                     l == 0.0, jnp.inf,
                     jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
 
-        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static)
+        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static,
+                   window)
 
-    _walk_by_kind(walk, rel0, res_k, kinds)
+    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
                 causal: bool, block_q: int, block_k: int, offset: int,
-                static: bool, kinds):
+                static: bool, kinds, window: Optional[int], nq: int,
+                group: int, dead_walks: bool):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
-    three."""
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    three. The last grid axis walks the ``nq`` blocks of queries of each of
+    the ``group`` query heads that read this key-value head, one head after
+    another, so dK and dV gather the whole group in the scratch. Without
+    ``dead_walks`` a block the mask leaves nothing of is skipped: its dQ^T
+    partial has no place (``_flash_pallas_bwd_kernel``)."""
+    ki, step_q = pl.program_id(1), pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    qi = step_q if group == 1 else step_q % nq
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
     n_q, n_k = res_q // block_q, res_k // block_k
     rel0 = offset if static else qi * res_q + offset - ki * res_k
     fold = _scale_folds(q_ref.dtype, sm_scale)
     scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
-                               block_k=block_k)
+                               block_k=block_k, window=window)
 
-    @pl.when(qi == 0)
+    @pl.when(step_q == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def walk(rel0):
-        def row(j, n_full, n_live):
+        def row(j, *bounds):
             cols = _tile(j, block_q, n_q)
             rel = rel0 + j * block_q
             q, do = _scaled(q_ref[cols, :], sm_scale, fold), do_ref[cols, :]
@@ -433,14 +552,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
 
             dqt = _walk(
                 step, jnp.zeros((dqt_ref.shape[0], block_q), jnp.float32),
-                n_full, n_live)
+                *bounds)
             dqt_ref[:, cols] = (dqt * sm_scale).astype(dqt_ref.dtype)
 
-        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static)
+        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static,
+                   window)
 
-    _walk_by_kind(walk, rel0, res_k, kinds)
+    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, dead_walks)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step_q == n_steps - 1)
     def _finalize():
         dk = dk_scr[...]
         dk_ref[...] = (dk if fold else dk * sm_scale).astype(dk_ref.dtype)
@@ -483,77 +603,137 @@ def _compiler_params(interpret: bool, width: int):
         vmem_limit_bytes=32 * 2**20 if width > 128 else None)
 
 
+# The blocks of the other operand that the mask leaves a block anything of,
+# first and last, as indices held to the grid: a Python int for a Python int.
+
 def _last_live_k(qi, res_q: int, res_k: int, offset: int, nk: int):
     """Index of the last block of resident keys that any query of block
     ``qi`` sees."""
-    return jnp.clip((qi * res_q + offset + res_q - 1) // res_k, 0, nk - 1)
+    return _clamp((qi * res_q + offset + res_q - 1) // res_k, nk - 1)
+
+
+def _first_live_k(qi, res_q: int, res_k: int, offset: int, nk: int,
+                  window: int):
+    """Index of the first block of resident keys that the window leaves
+    any query of block ``qi``."""
+    return _clamp((qi * res_q + offset - window + 1) // res_k, nk - 1)
 
 
 def _first_live_q(ki, res_q: int, res_k: int, offset: int, nq: int):
     """Index of the first block of resident queries that sees any key of
     block ``ki``."""
-    return jnp.clip((ki * res_k - offset) // res_q, 0, nq - 1)
+    return _clamp((ki * res_k - offset) // res_q, nq - 1)
+
+
+def _last_live_q(ki, res_q: int, res_k: int, offset: int, nq: int,
+                 window: int):
+    """Index of the last block of resident queries whose window holds any
+    key of block ``ki``."""
+    return _clamp((ki * res_k + res_k - 1 + window - 1 - offset) // res_q,
+                  nq - 1)
 
 
 def grid_block_kinds(q_len: int, k_len: int, causal: bool,
                      block_q: Optional[int] = None,
                      block_k: Optional[int] = None, *,
-                     backward: bool = False) -> dict:
-    """{"whole": n, "diagonal": n, "dead": n, "looped": n}: the grid blocks
-    a head of a call of these lengths has, by the rule the kernels branch
-    on (``_grid_kinds``). ``looped`` blocks walk their tiles in loops with
-    traced bounds, the others with constant ones. The forward's grid
-    unless ``backward``: the two kernels' tiles differ, and at some lengths
-    what a grid step holds with them."""
+                     backward: bool = False,
+                     window: Optional[int] = None) -> dict:
+    """{"whole": n, "diagonal": n, "dead": n, "looped": n}, and under a
+    ``window`` "trailing" with them: the grid blocks a head of a call of
+    these lengths has, by the rule the kernels branch on (``_grid_kinds``).
+    ``looped`` blocks walk their tiles in loops with traced bounds, the
+    others with constant ones. The forward's grid unless ``backward``: the
+    two kernels' tiles differ, and at some lengths what a grid step holds
+    with them."""
     _, _, res_q, res_k = _block_sizes(
         q_len, k_len, block_q, block_k, _BWD_TILES if backward else _FWD_TILES)
-    return _grid_kinds(q_len // res_q, k_len // res_k, res_q, res_k,
-                       k_len - q_len, causal)
+    window = _window_of(window, k_len)
+    kinds = _grid_kinds(q_len // res_q, k_len // res_k, res_q, res_k,
+                        k_len - q_len, causal, window)
+    if window is None:
+        del kinds["trailing"]
+    return kinds
+
+
+def _window_of(window: Optional[int], k_len: int) -> Optional[int]:
+    """A window that holds every key is none."""
+    assert window is None or window > 0, window
+    return None if window is None or window >= k_len else window
 
 
 def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
-                   causal: bool, backward: bool):
+                   causal: bool, backward: bool, window: Optional[int],
+                   heads):
     """The kinds of grid block a call holds, for its kernel to branch on,
     and their counts a head written into the runtime's ring: one record a
     traced call (none a step), so a timeline says which walk a model's
-    calls took (``looped`` 0: every block in straight-line code)."""
-    counts = _grid_kinds(nq, nk, res_q, res_k, offset, causal)
+    calls took (``looped`` 0: every block in straight-line code), under
+    which ``window`` (0: none) and with how many heads of queries and of
+    keys and values, the batch folded into both (``heads``,
+    ``kv_heads``)."""
+    counts = _grid_kinds(nq, nk, res_q, res_k, offset, causal, window)
     steptrace.record_counters("attn/grid_blocks", {
         **counts, "queries": nq * res_q, "keys": nk * res_k,
-        "backward": int(backward)})
+        "backward": int(backward), "window": window or 0,
+        "heads": heads[0], "kv_heads": heads[1]})
     return tuple(kind for kind in _KINDS if counts[kind])
+
+
+def _kernel_name(base: str, window: Optional[int]) -> str:
+    """``flash_fwd`` / ``flash_bwd``, and of a windowed call
+    ``flash_fwd_w<window>``: the benchmark's readers find the kernels, and
+    a call's window, by these names."""
+    return base if window is None else f"{base}_w{window}"
 
 
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
-                  interpret: bool):
-    """q, k: (B, S, D), v: (B, S, Dv) with batch*heads folded into B.
+                  interpret: bool, window: Optional[int] = None):
+    """q: (B, S, D) with batch*heads folded into B; k: (B_kv, S, D) and v:
+    (B_kv, S, Dv) with B a multiple of B_kv: query head ``i`` reads
+    key-value head ``i // (B // B_kv)``, through the index maps.
     -> (out (B, S, Dv), lse) with lse (B, 1, S) float32."""
     b, q_len, d = q.shape
     k_len, d_v = k.shape[1], v.shape[2]
+    group = b // k.shape[0]
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
                                                   block_k, _FWD_TILES)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
+    if window is not None and offset:
+        # a row of key blocks that no query sees would be given a live
+        # block's dQ^T place to leave alone, and the pipeline would write
+        # it back unwritten (``_flash_pallas_bwd_kernel``)
+        raise NotImplementedError(
+            f"flash_attention: a window over lengths that differ ({q_len} "
+            f"queries, {k_len} keys) is the scan's or the reference's")
 
-    if causal and nk > 1:
+    if causal and nk > 1 and window is not None:
+        # blocks behind the window fetch the first live one, as blocks
+        # past the diagonal the last
+        kmap = lambda qi, ki: jnp.clip(
+            ki, _first_live_k(qi, res_q, res_k, offset, nk, window),
+            _last_live_k(qi, res_q, res_k, offset, nk))
+    elif causal and nk > 1:
         kmap = lambda qi, ki: jnp.minimum(
             ki, _last_live_k(qi, res_q, res_k, offset, nk))
     else:
         kmap = lambda qi, ki: ki
+    kv_head = (lambda bi: bi) if group == 1 else (lambda bi: bi // group)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, offset=offset, static=nq == nk == 1,
-        kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, False))
+        block_k=block_k, offset=offset, static=nq == nk == 1, window=window,
+        kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, False,
+                             window, (b, k.shape[0])))
     out_t, lse = pl.pallas_call(
         kernel,
         grid=(b, nq, nk),
         in_specs=[
             pl.BlockSpec((None, res_q, d), lambda bi, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((None, res_k, d),
-                         lambda bi, qi, ki: (bi, kmap(qi, ki), 0)),
+                         lambda bi, qi, ki: (kv_head(bi), kmap(qi, ki), 0)),
             pl.BlockSpec((None, d_v, res_k),
-                         lambda bi, qi, ki: (bi, 0, kmap(qi, ki))),
+                         lambda bi, qi, ki: (kv_head(bi), 0, kmap(qi, ki))),
         ],
         out_specs=[
             pl.BlockSpec((None, d_v, res_q), lambda bi, qi, ki: (bi, 0, qi)),
@@ -570,34 +750,76 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         ],
         compiler_params=_compiler_params(interpret, max(d, d_v)),
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
     )(q, k, jnp.swapaxes(v, 1, 2))
     return jnp.swapaxes(out_t, 1, 2), lse
 
 
 def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              sm_scale: float, block_q: Optional[int],
-                             block_k: Optional[int], interpret: bool):
+                             block_k: Optional[int], interpret: bool,
+                             window: Optional[int] = None):
     b, q_len, d = q.shape
-    k_len, d_v = k.shape[1], v.shape[2]
+    b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
+    group = b // b_kv
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
                                                   block_k, _BWD_TILES)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
+    # under a window a block of queries sees ``seen`` blocks of keys at the
+    # most, and only those get a dQ^T partial
+    slots = window is not None and nk > 1
 
-    if causal and nk > 1:
+    if slots:
+        qmap = lambda ki, qi: jnp.clip(
+            qi, _first_live_q(ki, res_q, res_k, offset, nq),
+            _last_live_q(ki, res_q, res_k, offset, nq, window))
+    elif causal and nk > 1:
         qmap = lambda ki, qi: jnp.maximum(
             qi, _first_live_q(ki, res_q, res_k, offset, nq))
     else:
         qmap = lambda ki, qi: qi
-    qspec = pl.BlockSpec((None, res_q, d),
-                         lambda bi, ki, qi: (bi, qmap(ki, qi), 0))
-    kspec = pl.BlockSpec((None, res_k, d), lambda bi, ki, qi: (bi, ki, 0))
-    vspec = pl.BlockSpec((None, res_k, d_v), lambda bi, ki, qi: (bi, ki, 0))
-    dospec = pl.BlockSpec((None, res_q, d_v),
-                          lambda bi, ki, qi: (bi, qmap(ki, qi), 0))
-    rowspec = pl.BlockSpec((None, 1, res_q),
-                           lambda bi, ki, qi: (bi, 0, qmap(ki, qi)))
+    # the grid: key-value heads, their blocks of keys, and for each the
+    # blocks of queries of every query head of the group
+    if group == 1:
+        head, block = (lambda bi, step: bi), (lambda step: step)
+    else:
+        head = lambda bi, step: bi * group + step // nq
+        block = lambda step: step % nq
+    qspec = pl.BlockSpec(
+        (None, res_q, d),
+        lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
+    kspec = pl.BlockSpec((None, res_k, d), lambda bi, ki, step: (bi, ki, 0))
+    vspec = pl.BlockSpec((None, res_k, d_v), lambda bi, ki, step: (bi, ki, 0))
+    dospec = pl.BlockSpec(
+        (None, res_q, d_v),
+        lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
+    rowspec = pl.BlockSpec(
+        (None, 1, res_q),
+        lambda bi, ki, step: (head(bi, step), 0, qmap(ki, block(step))))
+    if slots:
+        # A partial's place is the number of blocks its keys lie before the
+        # last that its queries see. A dead step is given the place of the
+        # nearest live one of its row, writes nothing and so leaves that
+        # one's block as it is; a place no step writes (the first blocks of
+        # queries see fewer blocks of keys than ``seen``) holds whatever
+        # the buffer held and is left out of the sum by ``live``.
+        first, last = (
+            [_first_live_k(i, res_q, res_k, offset, nk, window)
+             for i in range(nq)],
+            [_last_live_k(i, res_q, res_k, offset, nk) for i in range(nq)])
+        seen = max(l - f + 1 for f, l in zip(first, last))
+        live = jnp.repeat(jnp.asarray(
+            [[place <= l - f for f, l in zip(first, last)]
+             for place in range(seen)]), res_q, axis=1)
+
+        def dq_block(bi, ki, step):
+            qi = qmap(ki, block(step))
+            return (_last_live_k(qi, res_q, res_k, offset, nk) - ki,
+                    head(bi, step), 0, qi)
+    else:
+        seen = nk
+        dq_block = lambda bi, ki, step: (ki, head(bi, step), 0, block(step))
     # one block of keys: its dQ^T is the whole of it; several: float32
     # partials, one per block, summed below
     dq_dtype = q.dtype if nk == 1 else jnp.float32
@@ -605,22 +827,23 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, offset=offset, static=nq == nk == 1,
-            kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True)),
-        grid=(b, nk, nq),
+            window=window, nq=nq, group=group, dead_walks=not slots,
+            kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True,
+                                 window, (b, b_kv))),
+        grid=(b_kv, nk, group * nq),
         in_specs=[
             qspec, kspec, vspec,
-            pl.BlockSpec((None, d, res_k), lambda bi, ki, qi: (bi, 0, ki)),
+            pl.BlockSpec((None, d, res_k), lambda bi, ki, step: (bi, 0, ki)),
             dospec, rowspec, rowspec,
         ],
         out_specs=[
-            pl.BlockSpec((None, None, d, res_q),
-                         lambda bi, ki, qi: (ki, bi, 0, qi)),
+            pl.BlockSpec((None, None, d, res_q), dq_block),
             kspec, vspec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nk, b, d, q_len), dq_dtype),
-            jax.ShapeDtypeStruct((b, k_len, d), k.dtype),
-            jax.ShapeDtypeStruct((b, k_len, d_v), v.dtype),
+            jax.ShapeDtypeStruct((seen, b, d, q_len), dq_dtype),
+            jax.ShapeDtypeStruct((b_kv, k_len, d), k.dtype),
+            jax.ShapeDtypeStruct((b_kv, k_len, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((res_k, d), jnp.float32),
@@ -628,33 +851,36 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         ],
         compiler_params=_compiler_params(interpret, max(d, d_v)),
         interpret=interpret,
-        name="flash_bwd",
+        name=_kernel_name("flash_bwd", window),
     )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
+    if slots:
+        dq_t = jnp.where(live[:, None, None, :], dq_t, 0.0)
     dq_t = dq_t[0] if nk == 1 else dq_t.sum(axis=0).astype(q.dtype)
     return jnp.swapaxes(dq_t, 1, 2), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
-                       interpret):
+                       interpret, window=None):
     """Differentiable Pallas flash attention: both directions are Pallas
     kernels (forward saves the logsumexp; one backward kernel recomputes P
     per tile from q,k,lse — O(seq) memory, no attention matrix ever
     materialized)."""
     out, _ = _flash_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret, window=window)
     return out
 
 
-def _flash_pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                      window):
     out, lse = map(ad_checkpoint.checkpoint_name, _flash_pallas(
         q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, interpret=interpret), _REMAT_NAMES)
+        block_k=block_k, interpret=interpret, window=window), _REMAT_NAMES)
     return out, (q, k, v, out, lse)
 
 
-def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret,
+def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                       res, g):
     q, k, v, out, lse = res
     # delta_i = rowsum(dO_i * O_i); tiny elementwise reduce — XLA fuses it.
@@ -663,7 +889,7 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret,
                     axis=-1)[:, None, :]
     return _flash_pallas_bwd_kernel(
         q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window,
     )
 
 
@@ -672,19 +898,28 @@ _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "sm_scale", "block_q", "block_k", "impl"),
+    static_argnames=("causal", "sm_scale", "block_q", "block_k", "impl",
+                     "window"),
 )
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    impl: Optional[str] = None) -> jax.Array:
+                    impl: Optional[str] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention over (..., seq, head_dim) inputs.
 
     Accepts (b, h, s, d) or (b, s, d); ``q`` and ``k`` share a width and
     ``v`` may have its own, which is the output's (keys of 192 against
     values of 128: whatever lanes a width is padded to inside the kernel are
-    the kernel's business). With ``impl=None`` the platform
+    the kernel's business). ``k`` and ``v`` may have fewer heads than ``q``,
+    a number that divides its heads (with (b, s, d) inputs, of the folded
+    batch x heads): query head ``j`` reads key-value head ``j // group``,
+    in the kernel through its index maps, with no copy of ``k`` or ``v``
+    a query head, and the backward kernel sums a key-value head's dK and dV
+    over its group. Under ``window``, which needs ``causal``, query ``i``
+    sees keys ``i - window + 1 .. i``; one that holds every key is none.
+    With ``impl=None`` the platform
     decides: the Pallas kernel on "tpu" — where a kernel that fails to
     lower raises, it never falls back — and the scan formulation on any
     other backend. ``impl`` forces a path:
@@ -701,24 +936,30 @@ def flash_attention(q, k, v, *, causal: bool = False,
     to the partitioner, and JAX's own error says so.
     """
     sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    assert causal or window is None, "a window is a causal mask's"
+    window = _window_of(window, k.shape[-2])
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "scan"
     if impl == "reference":
-        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                                   window=window)
     if impl == "scan":
         return _flash_scan(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_k=block_k or 128)
+                           block_k=block_k or 128, window=window)
     interpret = impl == "pallas_interpret"
+    assert q.shape[-3] % k.shape[-3] == 0 and k.shape[:-1] == v.shape[:-1], (
+        q.shape, k.shape, v.shape)
 
     def kernel(q, k, v):
         if q.ndim == 4:
             b, h, s, _ = q.shape
-            fold = lambda x: x.reshape(b * h, *x.shape[-2:])
+            fold = lambda x: x.reshape(b * x.shape[1], *x.shape[-2:])
             out = _flash_pallas_diff(fold(q), fold(k), fold(v), causal,
-                                     sm_scale, block_q, block_k, interpret)
+                                     sm_scale, block_q, block_k, interpret,
+                                     window)
             return out.reshape(b, h, s, v.shape[-1])
         return _flash_pallas_diff(q, k, v, causal, sm_scale, block_q,
-                                  block_k, interpret)
+                                  block_k, interpret, window)
 
     mesh, axes = _batch_axes(q)
     if not axes:
@@ -793,6 +1034,26 @@ def unmapped_mesh_axes(x) -> tuple:
 # a whole block of 64 (0.66 and 0.71 us a tile against 0.55 at the MXU's
 # peak); forward 13.7 us a diagonal block of 10 tiles and 25.3 a whole
 # block of 16 (1.37 and 1.58 us a tile against 0.85).
+# Since PR 44 (grouped key-value heads, a window; same bench with
+# ``--kv-heads 4`` and ``--window``, my chip run, 128 / 128, one sequence of
+# 16,384 tokens, 32 query heads, 8 x 8 grid blocks a head), ``flash_fwd`` and
+# ``flash_bwd`` alone, then the wall time of forward plus backward:
+#   no window, 32 key-value heads (28 whole, 8 diagonal, 28 dead a head):
+#               19.47 and 34.46 ms, 60.80
+#   no window, 4 key-value heads: 19.18 and 34.15, 58.20 (the keys and values
+#               of a group are fetched once a group in the backward and leave
+#               as 4 heads' dK and dV: grouping costs the kernels nothing)
+#   window 2048, 4 key-value heads (8 diagonal, 7 trailing, 49 dead):
+#               6.15 and 8.92, 17.70; the dQ partials 2 x 32 x 128 x 16,384
+#               float32 (0.5 GiB) against 8 (2.0 GiB) without a window
+# By kind of block, from the 2048 and 8192 readings above: a diagonal block
+# 10.35 us forward and 16.5 backward, a whole block 19.45 and 34.3 (the full
+# call priced so: 20.08 and 34.96 ms, read 19.18 and 34.15); a trailing
+# block with the seven dead grid steps of its row 15.6 us forward (the dead
+# steps fetch nothing and still owe the scratch's start and the outputs'
+# write) and 21.0 backward (its dQ^T partial has a place, a dead step's
+# none). A window that is no whole number of blocks (4096 tokens under 1024
+# keys: "looped") reads 4.27 and 7.87 ms against 4.42 and 6.63 under 2048.
 _FLASH_MIN_SEQ = 512
 # (key width, value width) of a head the kernel was measured at. (192, 128),
 # latent attention's per-head form (128 + 64 rotary dimensions against 128):
@@ -802,8 +1063,10 @@ _FLASH_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
 
 def auto_attention(q, v=None) -> str:
     """What ``attention="auto"`` runs for causal self-attention of ``q``
-    [B, T, H, d] (and ``v`` [B, T, H, d_v], where values have a width of
-    their own) on the default backend: "flash" or "xla". Decided from the
+    [B, T, H, d] (and ``v`` [B, T, H_kv, d_v], where values have a width of
+    their own; how many heads of keys and values the query heads share, and
+    a window, change nothing of the choice: the kernel was measured with
+    both, PR 44) on the default backend: "flash" or "xla". Decided from the
     backend and from the operands' types alone: the length, the pair (key
     width, value width) of a head, which has to be one the kernel was
     measured at (``_FLASH_HEAD_DIMS``), and the mesh ``q`` is traced under:
@@ -823,27 +1086,31 @@ def auto_attention(q, v=None) -> str:
     return "xla"
 
 
-def causal_self_attention(q, k, v, attention: str = "auto"):
-    """Causal self-attention of ``q``, ``k``, ``v`` in a model's own
-    [B, T, H, d] layout (as many key-value heads as query heads, one
-    length; ``v`` may have a width of its own, the output's) by the path
-    ``attention`` names: "flash", this module's kernel; "xla",
+def causal_self_attention(q, k, v, attention: str = "auto",
+                          window: Optional[int] = None):
+    """Causal self-attention of ``q`` [B, T, H, d], ``k`` [B, T, H_kv, d]
+    and ``v`` [B, T, H_kv, d_v] in a model's own layout (one length; H_kv
+    divides H, query head ``j`` reading key-value head ``j // (H / H_kv)``;
+    ``v`` may have a width of its own, the output's; under ``window`` a
+    query sees its own position and the ``window - 1`` before it) by the
+    path ``attention`` names: "flash", this module's kernel; "xla",
     ``jax.nn.dot_product_attention``, on this runtime plain XLA fusions
-    that write the [B, H, T, T] scores to HBM (it takes one width, so for
-    values of another the same program is written out:
-    ``attention_reference``); "auto", whichever ``auto_attention`` finds
-    for ``q`` and ``v``."""
+    that write the [B, H, T, T] scores to HBM (it takes one width and no
+    window here, so for values of another width or under a window the same
+    program is written out: ``attention_reference``); "auto", whichever
+    ``auto_attention`` finds for ``q`` and ``v``."""
     if attention == "auto":
         attention = auto_attention(q, v)
     bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
         return flash_attention(
-            bhsd(q), bhsd(k), bhsd(v), causal=True
+            bhsd(q), bhsd(k), bhsd(v), causal=True, window=window
         ).transpose(0, 2, 1, 3)
     if attention == "xla":
-        if v.shape[-1] != q.shape[-1]:
+        if v.shape[-1] != q.shape[-1] or window is not None:
             return attention_reference(
-                bhsd(q), bhsd(k), bhsd(v), causal=True).transpose(0, 2, 1, 3)
+                bhsd(q), bhsd(k), bhsd(v), causal=True,
+                window=window).transpose(0, 2, 1, 3)
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     raise ValueError(f"attention={attention!r}: expected auto, xla or flash")
 

@@ -90,6 +90,26 @@ def kernel_calls(jaxpr):
     return found
 
 
+def kernel_whiles(jaxpr):
+    """``while`` equations inside the Pallas kernels of ``jaxpr``: what a
+    ``fori_loop`` with a traced bound is traced to (static bounds give a
+    ``scan``, a Python loop nothing)."""
+    import jax  # not at import time: this file sets jax's environment
+
+    found = []
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            here = in_kernel or eqn.primitive.name == "pallas_call"
+            if in_kernel and eqn.primitive.name == "while":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here)
+
+    walk(jaxpr.jaxpr, False)
+    return found
+
+
 # --- shared-cluster fast lane -------------------------------------------
 # Booting GCS + raylet + workers costs ~10-13s; with ~40 modules that is
 # minutes of pure boot. ray_start_regular therefore REUSES the previous

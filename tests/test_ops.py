@@ -14,7 +14,7 @@ from ray_tpu.ops import (
     flash_attention,
     ring_self_attention,
 )
-from tests.conftest import kernel_calls
+from tests.conftest import kernel_calls, kernel_whiles
 
 
 def _qkv(b=2, h=2, s=64, d=16, seed=0, dtype=jnp.float32):
@@ -211,8 +211,9 @@ def test_flash_pallas_matches_reference(monkeypatch, case, causal, dtype):
             jax.clear_caches()
 
 
-def _all_looped(nq, nk, res_q, res_k, offset, causal):
-    return {"whole": 0, "diagonal": 0, "dead": 0, "looped": nq * nk}
+def _all_looped(nq, nk, res_q, res_k, offset, causal, window=None):
+    return {"whole": 0, "diagonal": 0, "trailing": 0, "dead": 0,
+            "looped": nq * nk}
 
 
 @pytest.mark.parametrize("case", ["kinds_tiles_2x2_narrow_values",
@@ -246,30 +247,12 @@ def test_flash_walk_by_kind_agrees_with_the_loop_walk(monkeypatch, case):
                 monkeypatch.setattr(attention, "_grid_kinds", _all_looped)
             jax.clear_caches()  # flash_attention is jitted
             jaxpr = jax.make_jaxpr(out_and_grads)(q, k, v)
-            assert bool(_kernel_whiles(jaxpr)) == (walk == "looped")
+            assert bool(kernel_whiles(jaxpr)) == (walk == "looped")
             walks[walk] = jax.jit(out_and_grads)(q, k, v)
     finally:
         jax.clear_caches()
     for got, want in zip(walks["by_kind"], walks["looped"]):
         np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
-
-
-def _kernel_whiles(jaxpr):
-    """``while`` equations inside the Pallas kernels of ``jaxpr``: what a
-    ``fori_loop`` with a traced bound is traced to (static bounds give a
-    ``scan``, a Python loop nothing)."""
-    found = []
-
-    def walk(jaxpr, in_kernel):
-        for eqn in jaxpr.eqns:
-            here = in_kernel or eqn.primitive.name == "pallas_call"
-            if in_kernel and eqn.primitive.name == "while":
-                found.append(eqn)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, here)
-
-    walk(jaxpr.jaxpr, False)
-    return found
 
 
 def test_flash_grid_block_kinds():
@@ -311,14 +294,15 @@ def test_flash_grid_block_kinds():
     finally:
         steptrace.set_enabled(False)
     assert kernel_calls(square) == {"flash_fwd": 1, "flash_bwd": 1}
-    assert not _kernel_whiles(square)
+    assert not kernel_whiles(square)
     assert {r["name"] for r in records} == {"attn/grid_blocks"}
     assert {r["values"]["backward"] for r in records} == {0, 1}
     for r in records:
-        assert r["values"] == {**kinds(6, 4, 6, 0), "queries": 8192,
-                               "keys": 8192,
-                               "backward": r["values"]["backward"]}
-    assert len(_kernel_whiles(grad_jaxpr(4096, 8192))) == 2 * (4 + 8)
+        assert r["values"] == {**kinds(6, 4, 6, 0), "trailing": 0,
+                               "queries": 8192, "keys": 8192,
+                               "backward": r["values"]["backward"],
+                               "window": 0, "heads": 64, "kv_heads": 64}
+    assert len(kernel_whiles(grad_jaxpr(4096, 8192))) == 2 * (4 + 8)
 
 
 def test_flash_block_rule():

@@ -358,11 +358,11 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
 
 
-def _cut_cell():
+def _cut_cell(name="joyai-llm-flash.step-8k"):
     from perfbench import run, worker
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        loaded = run.load_cell(json.load(f), "joyai-llm-flash.step-8k")
+        loaded = run.load_cell(json.load(f), name)
     return worker, loaded["model"], loaded["traffic"]
 
 
@@ -405,8 +405,9 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     assert {e["args"]["backward"] for e in drawn} == {0, 1}
     for e in drawn:
         assert e["args"] == {
-            "whole": 6, "diagonal": 4, "dead": 6, "looped": 0,
-            "queries": seq, "keys": seq, "backward": e["args"]["backward"]}
+            "whole": 6, "diagonal": 4, "trailing": 0, "dead": 6, "looped": 0,
+            "queries": seq, "keys": seq, "backward": e["args"]["backward"],
+            "window": 0, "heads": 64, "kv_heads": 64}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     state_bytes = 3 * 4 * sum(
@@ -450,3 +451,78 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
         # a dense dispatch: tokens x experts x anything
         assert not (len(dims) >= 3 and tokens in dims
                     and experts in dims), dims
+
+
+def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``trinity-mini.step-16k`` (a dense
+    layer and four expert layers at the published widths, three window
+    layers to the one full layer among the four, 8 of 128 experts held, an
+    eighth of the vocabulary), its step at 1 x 16,384 with recomputation, as
+    the benchmark's family builds it: the plan stays under 13.75 of one v5e
+    chip's 15.75 GiB with the state's 6.05 GB as arguments; attention is
+    the Pallas kernel over 32 query heads and 4 key-value heads, whose keys
+    and values go in as they are, [4, 16384, 128]: four windowed calls
+    (``flash_*_w2048``) and one without a window, forward and backward, and
+    no forward call again (``ops.attention.remat_policy``). Each traced call
+    wrote its grid's blocks by kind into the runtime's ring: 8 x 8 a head,
+    under the window 8 diagonal, 7 trailing, 49 dead, without it 28 whole,
+    8 diagonal, 28 dead, none walked in a loop with traced bounds. The
+    backward's float32 dQ partials are two a block of queries under the
+    window and eight (2 GiB) in the full layer. No array is shaped like a
+    [T, T] score matrix."""
+    from ray_tpu._private import steptrace
+
+    worker, model, traffic = _cut_cell("trinity-mini.step-16k")
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (1, 16384)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = built.step.lower(
+            params, opt_state, {"input_ids": ids, "labels": ids})
+        drawn = [e["args"] for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"
+            and e["name"] == "attn/grid_blocks"]
+    finally:
+        steptrace.set_enabled(False)
+    by_window = {2048: (0, 8, 7, 49), 0: (28, 8, 0, 28)}
+    assert {(e["window"], e["backward"]) for e in drawn} == {
+        (w, b) for w in by_window for b in (0, 1)}
+    for e in drawn:
+        whole, diagonal, trailing, dead = by_window[e["window"]]
+        assert e == {
+            "whole": whole, "diagonal": diagonal, "trailing": trailing,
+            "dead": dead, "looped": 0, "queries": seq, "keys": seq,
+            "backward": e["backward"], "window": e["window"], "heads": 32,
+            "kv_heads": 4}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 504_147_712
+    assert 3 * 4 * n_params < planned < 13.75 * 2**30
+    assert planned > 4 * 2**30
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(flash_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_fwd_w2048": 4,
+                     "flash_bwd_w2048": 4}
+    assert "bf16[32,16384,128]" in text and "bf16[4,16384,128]" in text
+    assert "f32[2,32,128,16384]" in text and "f32[8,32,128,16384]" in text
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+    # the vocabulary's slice equals no other dimension of the program: what
+    # ``loss_head_ms`` reads as vocabulary-wide is the head and the embedding
+    others = {model[k] for k in ("hidden_size", "intermediate_size",
+                                 "moe_intermediate_size", "head_dim")}
+    others |= {seq, seq * model["num_experts_per_tok"], 32 * 128, 4 * 128,
+               model["num_experts_published"], 2 * 1024, 2 * 6144}
+    assert model["vocab_size"] not in others
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
