@@ -1,11 +1,11 @@
 """GPT-2 in Flax — the flagship benchmark model (124M config).
 
-The reference benches Ray Train with torch GPT-2 DDP
-(ray: release/air_tests/air_benchmarks/ + driver BASELINE config
-"GPT-2-124M data-parallel"). TPU-native: params in f32, compute in bf16 so
-matmuls hit the MXU; batch sharded over the data/fsdp mesh axes; gradient
-reduction is inserted by the XLA partitioner from the sharding annotations
-(no hand-written allreduce); optional remat trades FLOPs for HBM.
+The reference benches Ray Train with torch GPT-2 DDP (ray: release/air_tests/
+air_benchmarks/ + driver BASELINE config "GPT-2-124M data-parallel").
+TPU-native: params in f32, compute in bf16 so matmuls hit the MXU; batch
+sharded over the data/fsdp mesh axes; gradients reduced by the XLA partitioner
+from the sharding annotations; optional remat recomputes a block in backward,
+all of it but the flash kernel where it ran (``ops.attention.remat_policy``).
 """
 
 from __future__ import annotations
@@ -19,7 +19,7 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.attention import causal_self_attention, remat_policy
 from ray_tpu.ops.xent import (chunked_xent, fused_xent,
                               token_log_likelihood)
 from ray_tpu.parallel import train_step
@@ -146,7 +146,7 @@ class GPT2(nn.Module):
         x = on_batch_axes(jnp.take(table, input_ids, axis=0) + wpe(pos))
         block = Block
         if c.remat:
-            block = nn.remat(Block, static_argnums=(2,))
+            block = nn.remat(Block, static_argnums=(2,), policy=remat_policy())
         for i in range(c.n_layer):
             x = block(c, name=f"h_{i}")(x, deterministic)
         x = on_batch_axes(nn.LayerNorm(dtype=c.dtype, name="ln_f")(x))

@@ -1120,13 +1120,24 @@ def causal_self_attention(q, k, v, attention: str = "auto",
 # block's projections). ``_flash_pallas_fwd`` names them, and hands the named
 # output on, so that what a block computes from it is recomputed from the kept
 # copy. Without a policy a name is the identity and lowers to nothing.
+# What a layer then holds is the named [B x H, T, d_v] output as the compiler
+# lays it out, and [B x H, 1, T] float32. At a value width of 64 the kernel
+# writes [B x H, 64, T] and the named array is its swap: kept, the swap
+# becomes a copy with its 64-wide rows padded to the 128 lanes, made for
+# every layer before the backward pass begins. In the cell
+# gpt2-xl.step-fsdp4 (a chip's 400 heads of 1,024 x 64) the compiled plan
+# grows by 93 MiB a layer where the output's bytes are 50, and the backward
+# pass copies each once more into the model's [B, T, H, 64], 0.34 ms a layer
+# on the chip (PERF.md section 6, PR 45).
 _REMAT_NAMES = ("flash_out", "flash_lse")
 
 
 def remat_policy():
     """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
     may run the kernel: keep the kernel's output and log-sum-exp (per layer
-    one [B, T, H, d_v] array in the compute dtype and B x H x T float32),
+    one [B, T, H, d_v] array in the compute dtype and B x H x T float32; at
+    a value width of 64 the kept copy is a lane-padded [B x H, T, 64],
+    nearly twice those bytes: the comment above),
     recompute everything else. The backward pass of such a block then
     reruns the projections and not the forward kernel. Where the block's
     attention is not the kernel (``xla``, the scan) no such name exists,

@@ -376,28 +376,77 @@ def test_flash_dispatch_never_hides_a_failed_kernel(monkeypatch, platform):
         jax.clear_caches()
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_the_kernels_names_keep_nothing_without_a_policy(monkeypatch, remat):
-    """The forward rule names the kernel's output and log-sum-exp for
-    ``ops.attention.remat_policy``. GPT-2 recomputes its blocks under no
-    policy, where a name is the identity: the gradient still runs the
-    forward kernel twice a block (once without recomputation) and the
-    backward kernel once."""
+def _gpt2_gradient(seq=128, **kw):
+    """(the gradient of GPT-2's loss at the toy size, its parameters): the
+    parameters from the ``xla`` twin, whose tree is the same."""
     from ray_tpu.models import gpt2
 
+    config = gpt2.GPT2Config.small_test(**kw)
+    _, params = gpt2.init_params(
+        dataclasses.replace(config, attention="xla"), jax.random.PRNGKey(0))
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(1), (2, seq + 1), 0, config.vocab_size)
+    batch = {"input_ids": tokens[:, :-1], "labels": tokens[:, 1:]}
+    return jax.grad(functools.partial(
+        gpt2.loss_fn, model=gpt2.GPT2(config), batch=batch)), params
+
+
+@pytest.mark.parametrize("what,forward", [
+    ("gpt2", 1), ("gpt2_remat", 1), ("checkpoint_no_policy", 2)])
+def test_the_kernels_names_keep_nothing_without_a_policy(
+        monkeypatch, what, forward):
+    """The forward rule names the kernel's output and log-sum-exp for
+    ``ops.attention.remat_policy``. GPT-2 recomputes its blocks under that
+    policy: the gradient runs the forward kernel once a block, recomputed
+    or not, and the backward kernel once. Without a policy a name is the
+    identity: ``jax.checkpoint`` round the kernel alone runs its forward
+    twice."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()  # flash_attention is jitted: drop earlier decisions
     try:
-        config = gpt2.GPT2Config.small_test(attention="flash", remat=remat)
-        _, params = gpt2.init_params(
-            dataclasses.replace(config, attention="xla"),
-            jax.random.PRNGKey(0))
-        ids = jnp.zeros((2, 128), jnp.int32)
-        grad = jax.make_jaxpr(jax.grad(functools.partial(
-            gpt2.loss_fn, model=gpt2.GPT2(config),
-            batch={"input_ids": ids, "labels": ids})))(params)
+        if what == "checkpoint_no_policy":
+            blocks = 1
+            q, k, v = _qkv(s=128)
+            grad = jax.make_jaxpr(jax.grad(lambda q: jax.checkpoint(
+                functools.partial(flash_attention, causal=True))(
+                    q, k, v).sum()))(q)
+        else:
+            fn, params = _gpt2_gradient(
+                attention="flash", remat=what == "gpt2_remat")
+            blocks = sum(k.startswith("h_") for k in params)
+            grad = jax.make_jaxpr(fn)(params)
     finally:
         jax.clear_caches()
-    assert kernel_calls(grad) == {
-        "flash_fwd": (2 if remat else 1) * config.n_layer,
-        "flash_bwd": config.n_layer}
+    assert blocks and kernel_calls(grad) == {
+        "flash_fwd": forward * blocks, "flash_bwd": blocks}
+
+
+def test_gpt2_without_the_kernel_the_policy_keeps_nothing(monkeypatch):
+    """``attention="xla"`` makes no such name: GPT-2's recomputed gradient
+    lowers to the program that recomputes under no policy."""
+    from ray_tpu.models import gpt2
+
+    fn, params = _gpt2_gradient(attention="xla", remat=True)
+    lowered = lambda: jax.jit(fn).lower(params).as_text()
+    kept = lowered()
+    monkeypatch.setattr(gpt2, "remat_policy", lambda: None)
+    assert kept == lowered()
+
+
+def test_gpt2_keeps_the_output_the_kernel_would_write_again(monkeypatch):
+    """The kernel path (interpreted here): GPT-2's gradients with the
+    blocks recomputed, their kernel outputs kept, are those without
+    recomputation bit for bit."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        attention.flash_attention, impl="pallas_interpret"))
+    # float32, as the other family's twin: in bf16 a recomputed block's
+    # fusions round elsewhere, kernel or no kernel
+    kw = dict(seq=64, attention="flash", dtype=jnp.float32)
+    plain, params = _gpt2_gradient(**kw)
+    kept, _ = _gpt2_gradient(remat=True, **kw)
+    plain, kept = jax.jit(plain)(params), jax.jit(kept)(params)
+    assert all(np.asarray(g).any() for g in jax.tree.leaves(kept))
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b)

@@ -286,9 +286,12 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     fusions and all-reduces carry gradients (the embeddings', and the
     vectors that are replicated at rest: no weight matrix is all-reduced);
     nothing is resharded by all-to-all; attention is the Pallas kernel per
-    batch shard, 144 calls with the recomputation; and every asynchronous
-    collective is an all-gather, which perfbench/metrics/allgather_ms.py
-    relies on."""
+    batch shard, 96 calls with the recomputation (a recomputed block keeps
+    the kernel's output and log-sum-exp, ``ops.attention.remat_policy``,
+    and runs no forward call again: the kept copies are 4.4 GiB of the
+    plan, which the upper limit holds 1.5 GiB under the chip); and every
+    asynchronous collective is an all-gather, which
+    perfbench/metrics/allgather_ms.py relies on."""
     from jax.experimental import mesh_utils as jmu
 
     from ray_tpu.parallel import mesh_utils
@@ -317,7 +320,7 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
         params, opt_state, {"input_ids": ids, "labels": ids}).compile()
 
     planned = _device_bytes(compiled)
-    assert 0.5 * 15.75 * 2**30 < planned < 15.75 * 2**30
+    assert 0.5 * 15.75 * 2**30 < planned < 14.25 * 2**30
     out_params, out_opt_state, _ = compiled.output_shardings
     for out, arg in zip(jax.tree.leaves((out_params, out_opt_state)),
                         jax.tree.leaves((params, opt_state))):
@@ -353,7 +356,7 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     assert not re.search(r"f32\[\d+,25,1024,1024\]", text)
     kernels = re.findall(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
                          r'custom_call_target="tpu_custom_call"', text, re.M)
-    assert collections.Counter(kernels) == {"flash_fwd": 2 * layers,
+    assert collections.Counter(kernels) == {"flash_fwd": layers,
                                             "flash_bwd": layers}
     assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
 
