@@ -36,6 +36,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from ray_tpu._private import steptrace
+from ray_tpu.parallel.mesh_utils import traced_mesh_axes
 
 
 def switch_gating(logits: jnp.ndarray, capacity: int
@@ -230,13 +234,47 @@ def row_buffer_rung(present, pairs: int):
                0 * present)
 
 
-def _walk(plan, of_chunk):
+def _unwritten(shape, dtype):
+    """A buffer that no operation has written: the result of a TPU kernel
+    that writes nothing, left in HBM as the allocator found it (NaN, the
+    last step's rows, anything), so that no pass over it is paid for."""
+    return pl.pallas_call(
+        lambda out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), name="unwritten")()
+
+
+def _fresh_buffer(x):
+    """What ``_walk`` starts its buffers from in the layer of input ``x``:
+    ``_unwritten`` on a TPU under no mesh axis of more than one device
+    (the partitioner refuses a Mosaic call, and this one has no batch to
+    be mapped over), else ``jnp.zeros``, which the CPU's grouped matmul,
+    reading what it likes, needs. Asked when a pass of the layer is
+    traced, of the backend and ``x``'s type alone, as
+    ``ops.attention.auto_attention`` asks for its kernel."""
+    _, batch_axes, other_axes = traced_mesh_axes(x)
+    if jax.default_backend() == "tpu" and not (batch_axes or other_axes):
+        return _unwritten
+    return jnp.zeros
+
+
+def _walk(plan, fresh, of_chunk):
     """``of_chunk(start, pair)`` -> arrays a chunk long, over each chunk of
     the row buffer up to the one that holds the last pair present, written
     side by side into buffers as long as the whole chunks that hold tokens
-    x k rows (zeros beyond what was walked). ``pair`` is the chunk's pairs
-    in the order of the sort. One loop whose trip count follows the count:
-    the code stands once in the executable, the cost is the rows'."""
+    x k rows, each made by ``fresh(shape, dtype)``. ``pair`` is the chunk's
+    pairs in the order of the sort. One loop whose trip count follows the
+    count: the code stands once in the executable, the cost is the rows'.
+
+    Beyond what was walked a buffer is UNWRITTEN (``_fresh_buffer``: zeros
+    off a TPU, whatever the memory held on one). A walked chunk is written
+    whole, rows past the count inside it from the padded ``order``: real,
+    finite values. So a reader may take the walked chunks as they are and
+    nothing of the rest: a grouped matmul, which reads its groups' rows a
+    tile of 512 at a time (a chunk at the cells' sizes is 8 such tiles, so
+    a tile that holds a pair lies inside a walked chunk); another walk over
+    the same plan, a chunk at a time; a gather whose places past the count
+    are clamped and whose values there a select sets to zero
+    (``_sum_of_pairs``, ``d_weights``)."""
     pairs = plan["order"].shape[0]
     rungs = row_buffer_rungs(pairs)
     chunk, length = rungs[0], len(rungs) * rungs[0]
@@ -253,8 +291,20 @@ def _walk(plan, of_chunk):
 
     return jax.lax.fori_loop(
         0, row_buffer_rung(plan["present"], pairs) + 1, body,
-        tuple(jnp.zeros((length,) + part.shape[1:], part.dtype)
+        tuple(fresh((length,) + part.shape[1:], part.dtype)
               for part in jax.eval_shape(at, 0)))
+
+
+def _count_row_buffers(fresh, buffers, backward: bool):
+    """One ``counters`` record ``moe/row_buffers`` a traced pass of the
+    layer (none a step; a model's layers of one shape share it): how many
+    buffers ``_walk`` filled for it, their rows (tokens x k in whole
+    chunks) and bytes, and how many of them nobody initialised."""
+    steptrace.record_counters("moe/row_buffers", {
+        "buffers": len(buffers), "rows": buffers[0].shape[0],
+        "bytes": sum(b.size * b.dtype.itemsize for b in buffers),
+        "unwritten": len(buffers) * (fresh is _unwritten),
+        "backward": int(backward)})
 
 
 def _chunk(rows, start, like):
@@ -333,11 +383,14 @@ def _swiglu(hidden):
 @jax.jit
 def _rows_forward(x, weights, wi, wo, plan):
     k, sizes = plan["mine"].shape[1], plan["tokens"]
-    rows, = _walk(plan, lambda start, pair: (_take_rows(x, pair // k),))
+    fresh = _fresh_buffer(x)
+    rows, = _walk(plan, fresh, lambda start, pair: (
+        _take_rows(x, pair // k),))
     hidden = jax.lax.ragged_dot(rows, wi.astype(x.dtype), sizes)
-    act, = _walk(plan, lambda start, pair: (
+    act, = _walk(plan, fresh, lambda start, pair: (
         _swiglu(_chunk(hidden, start, pair)),))
     out = jax.lax.ragged_dot(act, wo.astype(x.dtype), sizes)
+    _count_row_buffers(fresh, (rows, act), backward=False)
     return _to_tokens(out, plan, weights).astype(x.dtype)
 
 
@@ -345,7 +398,8 @@ def _rows_forward(x, weights, wi, wo, plan):
 def _rows_backward(x, weights, wi, wo, plan, g):
     f32, k, sizes = jnp.float32, plan["mine"].shape[1], plan["tokens"]
     wi_x, wo_x = wi.astype(x.dtype), wo.astype(x.dtype)
-    rows, g_rows = _walk(plan, lambda start, pair: (
+    fresh = _fresh_buffer(x)
+    rows, g_rows = _walk(plan, fresh, lambda start, pair: (
         _take_rows(x, pair // k), _take_rows(g, pair // k)))
     hidden = jax.lax.ragged_dot(rows, wi_x, sizes)
     # with y = sum of weight x (act @ wo): d_act = weight x (g @ wo^T), and
@@ -360,7 +414,9 @@ def _rows_backward(x, weights, wi, wo, plan, g):
         return (d_hidden, (scale * act.astype(f32)).astype(act.dtype),
                 (g_chunk * act.astype(f32)).sum(axis=-1))
 
-    d_hidden, act_scaled, d_scale = _walk(plan, of_chunk)
+    d_hidden, act_scaled, d_scale = _walk(plan, fresh, of_chunk)
+    _count_row_buffers(fresh, (rows, g_rows, d_hidden, act_scaled, d_scale),
+                       backward=True)
 
     def for_the_matrices(rows, matrices, d_out):
         return jax.vjp(lambda m: jax.lax.ragged_dot(rows, m, sizes),
@@ -380,8 +436,13 @@ def _held_rows(x, weights, wi, wo, plan):
     """``held_expert_ffn``'s row work: gather, grouped SwiGLU, back to the
     tokens. The grouped matmuls run over whole buffers and follow the rows
     present by themselves; what is not a matmul walks the buffer only as far
-    as the pairs present (``_walk``), or reads that far (``_to_tokens``). It
-    is differentiated by hand: the backward pass takes the same count
+    as the pairs present (``_walk``), or reads that far (``_to_tokens``).
+    Past the last walked chunk a row buffer holds what nobody wrote, of the
+    walked ones (``rows``, ``act``; backward ``rows``, ``g_rows``,
+    ``d_hidden``, ``act_scaled``, ``d_scale``) as of the grouped matmuls'
+    results, and everything here that reads one stops before it or sets
+    what it read to zero (``_walk`` says who may read what). It is
+    differentiated by hand: the backward pass takes the same count
     again and recomputes rows and hidden; its residuals are the arguments,
     whose shapes the count does not change; and every cotangent of a gather
     is a gather. Both passes are jitted so that a model's layers of one
@@ -414,21 +475,29 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     itself, the gathers into the buffer and the passes over the hidden rows
     in loops that stop after the chunk that holds the last pair
     (``_walk``), the gathers back to the tokens by reading a source cut to
-    that length (``_to_tokens``), forward and again backward.
+    that length (``_to_tokens``), forward and again backward. On a TPU the
+    rest of every buffer is never written (PR 46: ``_walk`` starts from
+    ``_unwritten`` memory where it filled 2.25 GiB a layer a step with
+    zeros) and never read: no result and no gradient depends on it.
 
-    What that buys (``benches/moe_row_buffer.py`` on a v5e, PR 32: the
-    layer alone, forward plus backward, T = 16,384, k = 8, d = 2,048,
+    What that buys (``benches/moe_row_buffer.py`` on a v5e, PRs 32 and 46:
+    the layer alone, forward plus backward, T = 16,384, k = 8, d = 2,048,
     experts 768 wide, 16 held; ms; PR 31's function, every pass over the
-    whole buffer, beside this one and the rows it walks)::
+    whole buffer, beside PR 32's, whose loops started from zeros, and this
+    one, whose loops start from unwritten memory, and the rows it walks)::
 
-        pairs present    PR 31's    this function
-                8,200       27.3     12.2   (12,288)
-               16,384       29.0     13.9   (16,384)
-               25,000       31.1     16.5   (28,672)
-               45,000       35.5     21.5   (45,056)
-               65,536       40.0     28.4   (65,536)
-               90,000       45.5     34.8   (90,112)
-              131,072       54.4     46.7  (131,072)
+        pairs present    PR 31's    PR 32's    this function
+                8,200       27.3       12.2      9.6   (12,288)
+               16,384       29.0       13.9     11.2   (16,384)
+               25,000       31.1       16.5     14.0   (28,672)
+               45,000       35.5       21.5     19.0   (45,056)
+               65,536       40.0       28.4     26.0   (65,536)
+               90,000       45.5       34.8     32.6   (90,112)
+              131,072       54.4       46.7     44.7  (131,072)
+
+    The bench differentiates a loss that is linear in the result, so only
+    the backward pass's five buffers are in it (1.56 GiB of zeros, 1.7 ms a
+    GiB); a training step fills the forward's two as well.
 
     The gathers back to the tokens step at 49,152 and 98,304 pairs (about
     1.5 ms each way: ``_gather_sources``); the rest is the rows'."""
@@ -443,9 +512,11 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     tokens = (key[:, None] == jnp.arange(held)).sum(axis=0, dtype=jnp.int32)
     # The TPU's grouped matmul writes the tiles that hold a group's rows and
     # leaves the rest of its result as it finds it (the CPU's writes zeros),
-    # forward and in its operand's cotangent alike. Rows past the count
-    # belong to no pair that is ``mine``: they are set to zero where they
-    # would reach a token (``_sum_of_pairs``, forward and backward) and are
+    # forward and in its operand's cotangent alike; and since PR 46 the
+    # buffers the loops fill (``_walk``) are left as found past the last
+    # walked chunk too. Rows past the count belong to no pair that is
+    # ``mine``: they are set to zero where they would reach a token
+    # (``_sum_of_pairs``, forward and backward; ``d_weights``) and are
     # read nowhere else (a group's matmul reads its own rows only).
     # Left in, they gave a toy configuration NaN and the full one a finite
     # loss that fell a tenth as fast (PERF.md section 6, PR 31).

@@ -225,19 +225,27 @@ def test_recomputation_changes_no_value_and_keeps_the_kernels_output(
     kept = grad(dataclasses.replace(config, remat=True))(params)
     for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(plain)):
         np.testing.assert_array_equal(a, b)
+    wide = afmoe.AfmoeConfig.small_test(
+        head_dim=128, sliding_window=128, remat=True, attention="auto")
+    # made off the "TPU": the initialiser runs the model, and the expert
+    # layer's unwritten buffers are a TPU kernel's
+    _, wide_params = afmoe.init_params(wide, jax.random.PRNGKey(0))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()
     try:
-        wide = afmoe.AfmoeConfig.small_test(
-            head_dim=128, sliding_window=128, remat=True, attention="auto")
-        _, wide_params = afmoe.init_params(wide, jax.random.PRNGKey(0))
         ids = jnp.zeros((1, 512), jnp.int32)
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: afmoe.loss_fn(
             p, afmoe.Afmoe(wide), {"input_ids": ids, "labels": ids})[0]))(
                 wide_params)
     finally:
         jax.clear_caches()
-    assert kernel_calls(jaxpr) == {
+    calls = kernel_calls(jaxpr)
+    # an expert layer's loops start from buffers nobody filled (PR 46): two
+    # forward, two in the recomputed forward (a block's last norm reads the
+    # layer's result), five backward
+    assert calls.pop("unwritten") == (2 + 2 + 5) * (
+        wide.num_hidden_layers - wide.num_dense_layers)
+    assert calls == {
         "flash_fwd_w128": 4, "flash_bwd_w128": 4, "flash_fwd": 1,
         "flash_bwd": 1}
 

@@ -306,6 +306,112 @@ def test_the_rungs_rise_to_the_pairs_and_hold_what_is_present(pairs):
         jnp.int32(pairs // 3))) == moe.row_buffer_rung(pairs // 3, pairs)
 
 
+def _routing_of(case, T, k, held, of):
+    """(T, k) experts of a layer that holds experts 0 .. held - 1 of held x
+    of: ``case`` pairs at random places on held experts, uniformly, or
+    "uneven": a third of all pairs, nine in ten of them on one expert and
+    none on another."""
+    rng = np.random.default_rng(7)
+    present = T * k // 3 if case == "uneven" else case
+    on_held = np.zeros(T * k, bool)
+    on_held[rng.permutation(T * k)[:present]] = True
+    to = rng.integers(0, held, T * k)
+    if case == "uneven":
+        to = np.where(rng.random(T * k) < 0.9, 1, rng.integers(2, held, T * k))
+    return present, jnp.asarray(np.where(
+        on_held, to, rng.integers(held, held * of, T * k)
+    ).reshape(T, k).astype(np.int32))
+
+
+@pytest.mark.parametrize(
+    "case", [0, 1, 4096, 4097, "uneven", 16384 * 8],
+    ids=["none", "one", "a_chunks_edge", "one_past_the_edge", "uneven",
+         "all"])
+def test_nobody_reads_what_the_walk_did_not_write(case, monkeypatch):
+    """At the cells' tokens x k (131,072 pairs, chunks of 4,096 rows): the
+    layer's result, its counts and its four gradients with every walked
+    buffer started from NaN, as a TPU's unwritten memory may hold, and the
+    grouped matmul leaving rows past its groups NaN too, are those of the
+    buffers started from zeros, to the bit: a walked chunk is written
+    whole, and what lies beyond reaches no matmul's group and no token."""
+    T, k, d, width, held, of = 16384, 8, 16, 8, 4, 4
+    present, experts = _routing_of(case, T, k, held, of)
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    x, target = (jax.random.normal(key, (T, d)) for key in keys[:2])
+    weights = jax.random.uniform(keys[2], (T, k), minval=0.1)
+    wi = jax.random.normal(keys[3], (held, d, 2 * width)) * 0.2
+    wo = jax.random.normal(keys[4], (held, width, d)) * 0.2
+
+    def both_ways():
+        def loss(x, weights, wi, wo):
+            y, tokens = moe.held_expert_ffn(x, experts, weights, wi, wo,
+                                            index=0, of=of)
+            return (y * target).sum(), (y, tokens)
+        (_, aux), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True)(x, weights, wi, wo)
+        return aux + grads
+
+    assert moe._fresh_buffer(x) is jnp.zeros
+    want = both_ways()
+    assert int(want[1].sum()) == present
+    started, traced = [], []
+
+    def nan_filled(shape, dtype):
+        started.append(shape)
+        return jnp.full(shape, jnp.nan, dtype)
+
+    jax.clear_caches()                 # the layer's passes are jitted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_unwritten", nan_filled)
+    monkeypatch.setattr(
+        jax.lax, "ragged_dot",
+        _unwritten_past_the_groups(jax.lax.ragged_dot, traced))
+    got = both_ways()
+    monkeypatch.undo()
+    jax.clear_caches()
+    # two buffers forward; five backward, the float32 vector among them
+    rows = T * k
+    assert started == [(rows, d), (rows, width), (rows, d), (rows, d),
+                       (rows, 2 * width), (rows, width), (rows,)]
+    assert traced
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        assert np.array_equal(a, b)
+
+
+def test_a_traced_pass_counts_its_row_buffers_once():
+    """Two layers of one shape, forward and backward: each pass is traced
+    once and writes one ``moe/row_buffers`` record, which a timeline draws;
+    off a TPU every buffer starts from zeros (``unwritten`` 0)."""
+    layer = _layer()
+    experts, weights = moe.topk_routing(layer["x"], layer["router"],
+                                        layer["bias"], layer["k"])
+
+    def loss(x):
+        for _ in range(2):
+            x, _ = moe.held_expert_ffn(x, experts, weights, layer["wi"][:4],
+                                       layer["wo"][:4], index=0, of=2)
+        return x.sum()
+
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()  # the record is written where a pass is traced
+        jax.grad(loss)(layer["x"])
+        drawn = [e["args"] for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot()))
+            if e["ph"] == "C" and e["name"] == "moe/row_buffers"]
+    finally:
+        steptrace.set_enabled(False)
+    rows = len(moe.row_buffer_rungs(_PAIRS)) * moe.row_buffer_rungs(_PAIRS)[0]
+    d, width = 32, 16
+    assert drawn == [
+        {"buffers": 2, "rows": rows, "bytes": 4 * rows * (d + width),
+         "unwritten": 0, "backward": 0},
+        {"buffers": 5, "rows": rows, "unwritten": 0, "backward": 1,
+         "bytes": 4 * rows * (2 * d + 3 * width) + 4 * rows}]
+
+
 def test_routing_is_the_published_rule_on_a_hand_made_case():
     """Two tokens, four experts, two a token. The bias moves the selection
     (token 0 takes expert 3 for expert 1) and not the weights, which are the
@@ -551,6 +657,11 @@ def test_a_recomputed_block_keeps_what_only_the_kernel_makes(
     finally:
         jax.clear_caches()
     blocks = config.num_hidden_layers + config.num_nextn_predict_layers
+    # each expert layer's loops start from buffers nobody filled (PR 46):
+    # two forward and five backward (a recomputed forward's layer is dead:
+    # the block ends with it, and the backward rule makes its own rows)
+    expert = blocks - config.first_k_dense_replace
+    assert calls.pop("unwritten") == (2 + 5) * expert
     assert calls == {"flash_fwd": forward * blocks, "flash_bwd": blocks}
 
 

@@ -361,6 +361,32 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
 
 
+def _row_buffer_census(text, drawn, pairs, d, width, unwritten):
+    """The held experts' walked buffers in a compiled step ``text`` and in
+    the ``counters`` events ``drawn`` while it was traced: the entry
+    computation takes ``unwritten`` of them from a kernel that writes
+    nothing (``ops.moe._unwritten``), fills none with a constant and copies
+    none (a ``broadcast`` or a ``copy`` whose result is [pairs, a width of
+    the layer] bfloat16: 2.25 to 3.25 GiB a layer a step until PR 46), each
+    traced pass of the layer wrote one ``moe/row_buffers`` record: two
+    buffers forward, five backward with the float32 vector, all of them
+    unwritten."""
+    entry = text[text.index("\nENTRY "):]
+    assert not re.findall(
+        rf"= bf16\[{pairs},(?:{d}|{width}|{2 * width})\]\S* "
+        r"(?:broadcast|copy)\(", entry)
+    records = {tuple(sorted(e["args"].items())) for e in drawn
+               if e["name"] == "moe/row_buffers"}
+    assert records == {tuple(sorted(want.items())) for want in (
+        {"buffers": 2, "rows": pairs, "bytes": 2 * pairs * (d + width),
+         "unwritten": 2, "backward": 0},
+        {"buffers": 5, "rows": pairs, "unwritten": 5, "backward": 1,
+         "bytes": 2 * pairs * (2 * d + 3 * width) + 4 * pairs})}
+    assert len(re.findall(
+        rf"%unwritten[\w.]* = (?:bf16\[{pairs},\d+\]|f32\[{pairs}\])\S* "
+        r"custom-call\(\)", entry)) == unwritten
+
+
 def _cut_cell(name="joyai-llm-flash.step-8k"):
     from perfbench import run, worker
 
@@ -385,7 +411,8 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     is shaped like a [tokens, experts, capacity] dispatch or a [T, T] score
     matrix, whole or a head's. Each traced kernel call wrote its grid's
     blocks by kind into the runtime's ring, where a timeline finds them:
-    4 x 4 a head, none walked in a loop with traced bounds."""
+    4 x 4 a head, none walked in a loop with traced bounds. The loops'
+    buffers are nobody's to fill (``_row_buffer_census``)."""
     from ray_tpu._private import steptrace
 
     worker, model, traffic = _cut_cell()
@@ -404,9 +431,11 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
             steptrace.snapshot())) if e["ph"] == "C"]
     finally:
         steptrace.set_enabled(False)
-    assert {e["name"] for e in drawn} == {"attn/grid_blocks"}
-    assert {e["args"]["backward"] for e in drawn} == {0, 1}
-    for e in drawn:
+    assert {e["name"] for e in drawn} == {"attn/grid_blocks",
+                                          "moe/row_buffers"}
+    blocks = [e for e in drawn if e["name"] == "attn/grid_blocks"]
+    assert {e["args"]["backward"] for e in blocks} == {0, 1}
+    for e in blocks:
         assert e["args"] == {
             "whole": 6, "diagonal": 4, "trailing": 0, "dead": 6, "looped": 0,
             "queries": seq, "keys": seq, "backward": e["args"]["backward"],
@@ -441,6 +470,9 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
             r"%ragged-dot-none[\w.]* = \w+\[(\d+),", text))
     held = model["n_routed_experts"]
     assert grouped == {pairs: 5 * 5, held: 5 * 2}
+    # and start from buffers nobody filled: seven an expert layer
+    _row_buffer_census(text, drawn, pairs, model["hidden_size"],
+                       model["moe_intermediate_size"], unwritten=7 * 5)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     # 32 heads x (128 + 128) is 8192 too: the keys' and values' projection
     # [batch, T, 8192] is the one array that may look like a score matrix
@@ -473,7 +505,8 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     8 diagonal, 28 dead, none walked in a loop with traced bounds. The
     backward's float32 dQ partials are two a block of queries under the
     window and eight (2 GiB) in the full layer. No array is shaped like a
-    [T, T] score matrix."""
+    [T, T] score matrix. The expert layers' loops start from buffers that
+    nobody filled (``_row_buffer_census``)."""
     from ray_tpu._private import steptrace
 
     worker, model, traffic = _cut_cell("trinity-mini.step-16k")
@@ -489,9 +522,10 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     try:
         lowered = built.step.lower(
             params, opt_state, {"input_ids": ids, "labels": ids})
-        drawn = [e["args"] for e in steptrace.chrome_trace(
-            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"
-            and e["name"] == "attn/grid_blocks"]
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+        drawn = [e["args"] for e in counters
+                 if e["name"] == "attn/grid_blocks"]
     finally:
         steptrace.set_enabled(False)
     by_window = {2048: (0, 8, 7, 49), 0: (28, 8, 0, 28)}
@@ -521,6 +555,17 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+    # the four expert layers: two loops forward, two in the recomputed
+    # forward (a block's last norm reads the layer's result, so here it is
+    # not dead) and two backward, each over row buffers tokens x k long that
+    # nobody filled (2 + 2 + 5 a layer), the gathers back under a branch in
+    # each of the three passes
+    pairs = seq * model["num_experts_per_tok"]
+    assert text.count("conditional(") == 3 * 4
+    assert len([line for line in text.splitlines() if " while(" in line
+                and f"[{pairs}," in line.split(" while(")[0]]) == 6 * 4
+    _row_buffer_census(text, counters, pairs, model["hidden_size"],
+                       model["moe_intermediate_size"], unwritten=9 * 4)
     # the vocabulary's slice equals no other dimension of the program: what
     # ``loss_head_ms`` reads as vocabulary-wide is the head and the embedding
     others = {model[k] for k in ("hidden_size", "intermediate_size",
