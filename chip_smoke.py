@@ -416,7 +416,7 @@ def main(argv=None, *, size: str = "full") -> int:
     cache_dir = place_compile_cache()  # workers inherit the variable
     _say(f"compile cache: {cache_dir} ({_cache_entries(cache_dir)} entries "
          f"at start)")
-    _say(f"native store in use: {native_store.available()}; native "
+    _say(f"native library loaded: {native_store.available()}; native "
          f"scheduler in use: {native_sched.available()}")
 
     storage = tempfile.mkdtemp(prefix="chip_smoke_")

@@ -77,7 +77,6 @@ _flag("worker_lease_timeout_ms", int, 30_000)
 # against committed gangs and prefers torus-aligned contiguous slices;
 # clusters with no coords advertised take the resource-fit path
 # untouched.
-_flag("sched_topology_enabled", bool, True)
 _flag("torus_coord", str, "")  # this node's "0x1[x2]" (per-node env)
 _flag("torus_dims", str, "")  # the torus extent "4x4[x8]"
 _flag("sched_max_candidates", int, 32)  # slice windows scored per gang
@@ -85,14 +84,11 @@ _flag("sched_repack_max_moves", int, 8)  # bundle migrations per repack
 # Workers
 _flag("num_workers_soft_limit", int, 16)
 _flag("worker_register_timeout_s", float, 60.0)
-_flag("prestart_worker_first_driver", bool, False)
 # Objects
 _flag("max_direct_call_object_size", int, 100 * 1024)  # inline threshold (ray: 100KB)
 _flag("object_store_memory", int, 2 * 1024**3)
 # Slab-arena object plane (slab_arena.py): leased write slabs + shared
-# index instead of one file per object. RAY_TPU_slab_arena=0 restores the
-# legacy per-object-file data path (and with it the native C++ writer).
-_flag("slab_arena", bool, True)
+# index instead of one file per object.
 _flag("slab_size_bytes", int, 16 * 1024 * 1024)  # default lease ceiling
 _flag("slab_min_lease_bytes", int, 1024 * 1024)  # first lease of a worker
 _flag("slab_index_slots", int, 1 << 16)  # shared index capacity (~4MB)
@@ -113,7 +109,6 @@ _flag("object_pull_timeout_s", float, 60.0)
 # threshold, returning tmpfs pages without waiting for whole-segment
 # emptiness. KEEP_SIZE preserves the mapping, so live zero-copy readers
 # keep their views; flock-pinned and pooled segments are skipped.
-_flag("slab_punch_enabled", bool, True)
 _flag("slab_punch_interval_s", float, 30.0)
 _flag("slab_punch_min_fragmentation", float, 0.25)
 _flag("slab_punch_min_bytes", int, 1 << 20)
@@ -179,12 +174,10 @@ _flag("metrics_scrape_timeout_s", float, 10.0)
 _flag("metrics_report_interval_s", float, 2.0)
 _flag("task_events_buffer_size", int, 10_000)
 # Worker-log streaming to drivers (ray: log_monitor.py tail cadence +
-# worker.py print_logs). log_to_driver is the master gate for the driver
-# subscription (RAY_TPU_LOG_TO_DRIVER=0 kills it cluster-wide); raylets
-# additionally skip tailing entirely while the GCS reports zero "logs"
+# worker.py print_logs): a driver subscribes unless init(log_to_driver=
+# False); raylets skip tailing entirely while the GCS reports zero "logs"
 # subscribers, so an unwatched cluster pays nothing for the log plane.
 _flag("log_tail_interval_s", float, 0.3)
-_flag("log_to_driver", bool, True)
 # driver-side dedup: identical lines fanning in from many workers within
 # this window collapse to one line + "[repeated Nx]" summary
 _flag("log_dedup_window_s", float, 1.0)
@@ -221,11 +214,6 @@ _flag("direct_push_batch_max", int, 64)  # specs per execute_task_batch frame
 # reuses the standing sender (and its pipelined conn) instead of paying
 # a task spawn + warm-up tick per call. 0 restores exit-on-drain.
 _flag("actor_sender_linger_s", float, 0.5)
-# submit_batch ack mode: "batch" = the raylet acks frame ACCEPTANCE and
-# schedules in the background (fire-and-forget lane; per-task failures
-# surface via the owner's task_result stream + task events), "spec" =
-# legacy ack-after-scheduling (A/B lever)
-_flag("submit_ack_mode", str, "batch")
 # control-plane stage timing (perf.run_control_plane_bench): per-stage histograms
 # (envelope build, id mint, result return, submit->run) on the submit
 # path; off = one attr check per call
@@ -242,7 +230,6 @@ _flag("free_flush_interval_s", float, 0.005)
 # next burst accumulates behind it (unbounded pipelining would drain the
 # queue one spec at a time and never form a batch)
 _flag("actor_direct_max_inflight", int, 2)
-_flag("direct_actor_calls", bool, True)  # push actor calls to the worker
 # Dispatch / scheduling cadence (raylet loops)
 _flag("dispatch_retry_interval_s", float, 0.01)
 _flag("infeasible_retry_interval_s", float, 0.5)
@@ -254,8 +241,6 @@ _flag("deferred_release_wait_s", float, 0.5)
 _flag("worker_dump_stacks_timeout_s", float, 10.0)
 # GCS scheduling retry cadence (actor placement / PG)
 _flag("gcs_schedule_retry_interval_s", float, 0.2)
-# Per-node dashboard agent (ray: dashboard/agent.py)
-_flag("enable_node_agent", bool, True)
 # Step observatory (steptrace.py): per-step trainer/collective telemetry.
 # steptrace_enabled gates every record path (zero-cost off, same posture
 # as metrics_enabled); the ring holds the newest steptrace_ring_size
@@ -274,7 +259,6 @@ _flag("memview_flow_ring_size", int, 2048)  # flow events kept per process
 # per-node fan-out timeout inside memview_cluster
 _flag("memview_scrape_timeout_s", float, 10.0)
 # Collective / device plane
-_flag("collective_timeout_s", float, 120.0)
 # Chunked pipeline transport for large store-path allreduces: tensors
 # bigger than this are reduce-scattered + allgathered in fixed-size
 # chunks (each chunk its own rendezvous sub-key under the op's seq),
@@ -302,19 +286,6 @@ _flag("collective_straggler_threshold", float, 0.0)
 _flag("tpu_autodetect", bool, False)
 # RPC substrate (ray: grpc_server.h / client channel args)
 _flag("rpc_max_message_bytes", int, 1 << 31)
-# wire frame format: 3 = out-of-band buffer table + CRC32 head trailer,
-# 2 = out-of-band buffer table (zero-copy payload buffers), 1 = legacy
-# in-band pickle frames. Clients dialing high fall back one version per
-# redial when the server doesn't ack it. The v3 CRC covers the frame head
-# (count byte + buffer table + envelope): corrupted control data is
-# detected and the connection reset instead of unpickling garbage;
-# out-of-band payload buffers stay CRC-free (checksumming multi-MB tensors
-# would re-scan the memory the zero-copy path exists to avoid).
-_flag("rpc_frame_version", int, 3)
-# payload buffers at least this big ride v2 frames out-of-band; smaller
-# ones stay in the pickle envelope (a table entry + unjoined write costs
-# more than a tiny memcpy)
-_flag("rpc_oob_min_bytes", int, 512)
 _flag("rpc_auth_timeout_s", float, 10.0)
 _flag("rpc_connect_retries", int, 30)
 # connect() retry backoff: delay starts at rpc_connect_retry_delay_s,
@@ -334,7 +305,7 @@ _flag("rpc_retry_base_delay_s", float, 0.1)
 _flag("rpc_retry_max_delay_s", float, 2.0)
 # keepalive: ping idle connections every interval; a peer silent for the
 # timeout is declared dead (black-holed peers surface in O(timeout)
-# instead of hanging a request forever). 0 disables. v3+ sessions only.
+# instead of hanging a request forever). 0 disables.
 # A pong needs the peer's interpreter: a trainer that writes GPT-2 XL's
 # step to the compile cache holds its GIL for 23 s inside XLA's
 # ``executable.serialize()`` (PERF.md section 6, PR 26), and at 20 s its
@@ -352,14 +323,11 @@ _flag("serve_default_graceful_shutdown_timeout_s", float, 5.0)
 # keep steering traffic at a replica that has since filled up.
 _flag("serve_replica_report_max_age_s", float, 5.0)
 # LLM serving engine (serve/llm): continuous batching over an arena-
-# paged KV cache with prefix-affinity routing. serve_llm_enabled=0
-# disables every LLM-specific code path (handle-side prefix biasing,
-# LLMServer construction); plain deployments never touch these either
-# way. Page geometry: page_tokens tokens per page, kv_dim float32s per
-# token; kv_pages is the per-replica page budget admission control
+# paged KV cache with prefix-affinity routing; plain deployments never
+# touch these. Page geometry: page_tokens tokens per page, kv_dim float32s
+# per token; kv_pages is the per-replica page budget admission control
 # guards. prefix_digest_max caps the chain hashes a replica reports in
 # the controller load probe (wire-size bound on the affinity signal).
-_flag("serve_llm_enabled", bool, True)
 _flag("serve_llm_page_tokens", int, 16)
 _flag("serve_llm_kv_dim", int, 64)
 _flag("serve_llm_kv_pages", int, 512)
@@ -387,9 +355,6 @@ _flag("train_health_check_interval_s", float, 1.0)
 # a rank that reports no step progress for this long is declared wedged
 # (0 disables the progress watchdog; only liveness pings run)
 _flag("train_progress_timeout_s", float, 0.0)
-# master switch: tear down + re-place + restore-from-checkpoint on failure
-# (off -> legacy behavior: surface the error to the trainer retry loop)
-_flag("train_recovery_enabled", bool, True)
 # SIGTERM drain: how long a worker may run past the signal to reach the
 # next step boundary and checkpoint before it hard-exits
 _flag("train_drain_grace_s", float, 30.0)

@@ -176,8 +176,8 @@ def make_external_storage(uri: Optional[str]) -> Optional[ExternalStorage]:
 
 
 def is_local_spill_uri(uri: Optional[str]) -> bool:
-    """True when the target is plain-filesystem (native-store fast path
-    applies); non-file schemes route through the Python store + driver."""
+    """True when the target is plain-filesystem; other schemes route
+    through their storage driver."""
     if not uri:
         return True
     return urlparse(uri).scheme in ("", "file")

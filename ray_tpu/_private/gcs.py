@@ -1214,8 +1214,8 @@ class GcsServer:
         # don't interleave reservations; waiting happens outside it.
         async with self._pg_lock:
             nodes = [n for n in self.nodes.values() if n.alive]
-            topo = (topo_mod.Topology.from_nodes(nodes)
-                    if cfg.sched_topology_enabled else None)
+            # None where no torus labels are advertised
+            topo = topo_mod.Topology.from_nodes(nodes)
             committed = self._committed_rings(but=pg.pg_id, topo=topo)
             # one dispatch point for both worlds: the wrapper takes the
             # contention path when a topology is passed and the untouched

@@ -406,11 +406,7 @@ def merge_cluster(processes: Sequence[dict],
             flows.append(dict(fl, node_id=node, pid=proc.get("pid")))
         store = proc.get("store")
         if store:
-            # arena=None means the node's store has no introspection
-            # surface (native C++ store): no row — a phantom all-zero
-            # arena would read as "healthy and empty" in triage output
-            if store.get("arena") is not None:
-                arenas.append(dict(store["arena"], node_id=node))
+            arenas.append(dict(store["arena"], node_id=node))
             for row in store.get("objects", ()):
                 r = objects.get(row["object_id"])
                 if r is None:

@@ -1,14 +1,9 @@
-"""ctypes binding for the native (C++) object store.
+"""ctypes binding for the native (C++) library.
 
 Loads ``src/librtpu_store.so`` (building it with make on first use if a
-toolchain is present) and exposes the same surface as the pure-Python
-implementation in object_store.py. The runtime picks native when
-available; set ``RAY_TPU_NATIVE_STORE=0`` to force the Python path.
-
-Reference parity: this is the plasma-client boundary (ray:
-src/ray/object_manager/plasma/client.h) collapsed to a C ABI — the data
-plane stays mmap'd files in /dev/shm either way, so native and Python
-processes interoperate on one store directory.
+toolchain is present): the ``.obj`` writer and reader, the GCS's log store
+(gcs_store.py), the scheduler (native_sched.py). ``RAY_TPU_NATIVE_STORE=0``
+forces the Python paths.
 """
 
 from __future__ import annotations
@@ -34,7 +29,6 @@ _build_attempted = False
 
 
 def _configure(lib):
-    u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.rtpu_write_object.restype = ctypes.c_long
     lib.rtpu_write_object.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
@@ -52,38 +46,6 @@ def _configure(lib):
     lib.rtpu_object_exists.restype = ctypes.c_int
     lib.rtpu_object_exists.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
 
-    lib.rtpu_store_create.restype = ctypes.c_void_p
-    lib.rtpu_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
-    lib.rtpu_store_create2.restype = ctypes.c_void_p
-    lib.rtpu_store_create2.argtypes = [
-        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p
-    ]
-    lib.rtpu_store_restore.restype = ctypes.c_int
-    lib.rtpu_store_restore.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-    lib.rtpu_store_is_spilled.restype = ctypes.c_int
-    lib.rtpu_store_is_spilled.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-    lib.rtpu_store_spilled_bytes.restype = ctypes.c_uint64
-    lib.rtpu_store_spilled_bytes.argtypes = [ctypes.c_void_p]
-    lib.rtpu_store_destroy.restype = None
-    lib.rtpu_store_destroy.argtypes = [ctypes.c_void_p]
-    lib.rtpu_store_put.restype = ctypes.c_long
-    lib.rtpu_store_put.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
-        ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_uint64),
-        ctypes.c_uint64,
-    ]
-    for name in ("register_external", "touch", "pin", "unpin", "delete"):
-        fn = getattr(lib, f"rtpu_store_{name}")
-        fn.restype = None
-        fn.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-    lib.rtpu_store_used.restype = ctypes.c_uint64
-    lib.rtpu_store_used.argtypes = [ctypes.c_void_p]
-    lib.rtpu_store_count.restype = ctypes.c_uint64
-    lib.rtpu_store_count.argtypes = [ctypes.c_void_p]
-    lib.rtpu_store_list.restype = ctypes.c_uint64
-    lib.rtpu_store_list.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint64
-    ]
     # append-log KV store (GCS persistence; src/log_store.cpp). Optional:
     # a prebuilt .so without these symbols still serves the object store.
     u8pp = ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8))
@@ -258,98 +220,3 @@ def release(handle: int):
 def object_exists(store_dir: str, oid_hex: str) -> bool:
     lib = load_library()
     return bool(lib.rtpu_object_exists(store_dir.encode(), oid_hex.encode()))
-
-
-class NativeLocalObjectStore:
-    """Owner-side accounting store backed by the C++ RtpuStore."""
-
-    def __init__(self, store_dir: str, capacity_bytes: int,
-                 spill_dir: Optional[str] = None):
-        self._lib = load_library()
-        assert self._lib is not None
-        self.store_dir = store_dir
-        self.capacity = capacity_bytes
-        self.spill_dir = spill_dir
-        self._store = ctypes.c_void_p(
-            self._lib.rtpu_store_create2(
-                store_dir.encode(), capacity_bytes,
-                (spill_dir or "").encode(),
-            )
-        )
-
-    # mirror of object_store.LocalObjectStore -------------------------
-    def put(self, object_id, metadata: bytes, buffers, total_data_len: int):
-        from ray_tpu._private.object_store import ObjectStoreFullError
-
-        arr, larr, n, keep = _buffer_pointers(metadata, buffers)
-        rc = self._lib.rtpu_store_put(
-            self._store, object_id.hex().encode(), metadata, len(metadata),
-            arr, larr, n,
-        )
-        if rc == -2:
-            raise ObjectStoreFullError(
-                f"object does not fit: used={self.used_bytes()} "
-                f"capacity={self.capacity} (all remaining objects pinned)"
-            )
-        if rc < 0:
-            raise IOError(f"native store put failed for {object_id}")
-
-    def register_external(self, object_id):
-        self._lib.rtpu_store_register_external(
-            self._store, object_id.hex().encode()
-        )
-
-    def get(self, object_id):
-        from ray_tpu._private import object_store as pystore
-
-        buf = pystore.read_object(self.store_dir, object_id)
-        if buf is None and self.restore_if_spilled(object_id):
-            buf = pystore.read_object(self.store_dir, object_id)
-        if buf is not None:
-            self._lib.rtpu_store_touch(self._store, object_id.hex().encode())
-        return buf
-
-    def contains(self, object_id) -> bool:
-        return object_exists(self.store_dir, object_id.hex()) or bool(
-            self._lib.rtpu_store_is_spilled(
-                self._store, object_id.hex().encode()
-            )
-        )
-
-    def restore_if_spilled(self, object_id) -> bool:
-        return self._lib.rtpu_store_restore(
-            self._store, object_id.hex().encode()
-        ) == 1
-
-    def spilled_stats(self):
-        return {
-            "spilled_bytes_total": int(
-                self._lib.rtpu_store_spilled_bytes(self._store)
-            ),
-        }
-
-    def pin(self, object_id):
-        self._lib.rtpu_store_pin(self._store, object_id.hex().encode())
-
-    def unpin(self, object_id):
-        self._lib.rtpu_store_unpin(self._store, object_id.hex().encode())
-
-    def delete(self, object_id):
-        self._lib.rtpu_store_delete(self._store, object_id.hex().encode())
-
-    def used_bytes(self) -> int:
-        return int(self._lib.rtpu_store_used(self._store))
-
-    def object_ids(self):
-        from ray_tpu._private.ids import ObjectID
-
-        n = int(self._lib.rtpu_store_count(self._store))
-        if n == 0:
-            return []
-        buf = ctypes.create_string_buffer(65 * n)
-        got = int(self._lib.rtpu_store_list(self._store, buf, n))
-        out = []
-        for i in range(got):
-            hexid = buf.raw[i * 65 : (i + 1) * 65].split(b"\0", 1)[0].decode()
-            out.append(ObjectID(bytes.fromhex(hexid)))
-        return out

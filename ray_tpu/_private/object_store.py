@@ -12,11 +12,9 @@ read-only for zero-copy reads. The data plane here has two formats:
   per-object syscalls on either side. Accounting is batched: the raylet
   charges capacity at slab granularity and workers self-report sealed
   entries asynchronously.
-- **One file per object** (legacy + interop): ``<id>.obj`` files with
-  ``[8B magic][8B metadata_len][8B data_len][metadata][data]``. Still the
-  format for spill/restore and any process without a lease, so mixed
-  clusters and external backends keep working; ``RAY_TPU_slab_arena=0``
-  makes it the only data path again (including the native C++ writer).
+- **One file per object**: ``<id>.obj`` files with
+  ``[8B magic][8B metadata_len][8B data_len][metadata][data]``: the
+  format for spill/restore and any process without a lease.
 
 Accounting (capacity, pinning, eviction/spill) is done by the raylet
 process that owns the store directory; readers in other processes only
@@ -249,12 +247,8 @@ def read_object(store_dir: str, object_id: ObjectID) -> Optional[ObjectBuffer]:
 
     Arena first: a shared-index hit validates the in-slab sealed header
     and returns views into the process's cached segment mapping —
-    flock-free, no per-object syscalls. Legacy ``.obj`` files (spill
-    restores, fallback writes, native-store output) keep the original
-    open+flock path: readers hold a SHARED flock for the buffer's
-    lifetime because the native free path's page-recycling pool takes a
-    non-blocking EXCLUSIVE flock before rewriting pages; slab segments
-    are never rewritten, which is why the arena path needs no lock."""
+    flock-free, no per-object syscalls. ``.obj`` files (spill restores,
+    fallback writes) keep the open+flock path."""
     t0 = time.perf_counter()
     hit = slab_arena.read(store_dir, object_id.binary())
     if hit is not None:
@@ -369,36 +363,6 @@ def write_object(
     return written
 
 
-def make_local_store(store_dir: str, capacity_bytes: int,
-                     spill_dir: Optional[str] = None):
-    """Owner-side store factory. With the slab arena enabled (default)
-    the Python store owns the node's data plane — the arena layout is
-    python-first, and the native C++ writer stays gated behind
-    ``RAY_TPU_slab_arena=0`` until it learns the slab format (the
-    parity gate: both paths serve the same public store surface and the
-    same test suite). Legacy mode picks the native store
-    (src/librtpu_store.so) when loadable. ``spill_dir`` is a path OR a
-    storage URI (ray: local_object_manager.h:40 + external_storage.py):
-    file:///bare paths spill to disk; other schemes (s3://,
-    test-registered) route through the pluggable driver."""
-    if cfg.slab_arena:
-        return LocalObjectStore(store_dir, capacity_bytes, spill_dir)
-    from ray_tpu._private import native_store
-    from ray_tpu._private.external_storage import is_local_spill_uri
-
-    if native_store.available() and is_local_spill_uri(spill_dir):
-        from urllib.parse import urlparse
-
-        local = urlparse(spill_dir).path if (
-            spill_dir and spill_dir.startswith("file://")
-        ) else spill_dir
-        return native_store.NativeLocalObjectStore(
-            store_dir, capacity_bytes, local
-        )
-    return LocalObjectStore(store_dir, capacity_bytes, spill_dir,
-                            arena=False)
-
-
 class _Segment:
     """Owner-side record of one slab segment."""
 
@@ -441,8 +405,7 @@ class LocalObjectStore:
     """
 
     def __init__(self, store_dir: str, capacity_bytes: int,
-                 spill_dir: Optional[str] = None,
-                 arena: Optional[bool] = None):
+                 spill_dir: Optional[str] = None):
         self.store_dir = store_dir
         os.makedirs(store_dir, exist_ok=True)
         self.capacity = capacity_bytes
@@ -478,7 +441,6 @@ class LocalObjectStore:
         # verdicts name the cause): register_external | untracked_restore
         self.overshoot_by_cause: Dict[str, int] = {}
         # --- slab arena (owner side) ----------------------------------
-        self.arena_enabled = cfg.slab_arena if arena is None else arena
         self._segments: Dict[int, _Segment] = {}
         # oid -> (seg, off, len, created_monotonic)
         self._slab_objs: Dict[ObjectID, tuple] = {}
@@ -507,22 +469,19 @@ class LocalObjectStore:
         self._pool: "OrderedDict[str, tuple]" = OrderedDict()
         self._pool_seq = 0
         self._pool_pinned_cache: tuple = (0.0, [])  # (ts, last probe)
-        self._index = None
-        self._local_writer = None
-        if self.arena_enabled:
-            os.makedirs(os.path.join(store_dir, slab_arena.SLAB_DIR),
-                        exist_ok=True)
-            self._index = slab_arena.SharedIndex(
-                slab_arena.index_path(store_dir),
-                slots=cfg.slab_index_slots, create=True,
-            )
-            self._local_writer = slab_arena.SlabWriter(store_dir)
-            # serializes the put slow path (seal/lease/attach): two
-            # concurrent refills would detach each other's fresh
-            # "_local" segment, stranding its capacity charge
-            self._local_put_lock = threading.Lock()
-            with self._lock:
-                self._rescan_segments_locked()
+        os.makedirs(os.path.join(store_dir, slab_arena.SLAB_DIR),
+                    exist_ok=True)
+        self._index = slab_arena.SharedIndex(
+            slab_arena.index_path(store_dir),
+            slots=cfg.slab_index_slots, create=True,
+        )
+        self._local_writer = slab_arena.SlabWriter(store_dir)
+        # serializes the put slow path (seal/lease/attach): two
+        # concurrent refills would detach each other's fresh
+        # "_local" segment, stranding its capacity charge
+        self._local_put_lock = threading.Lock()
+        with self._lock:
+            self._rescan_segments_locked()
 
     # -- restart rescan ------------------------------------------------------
     def _rescan_segments_locked(self):
@@ -568,10 +527,6 @@ class LocalObjectStore:
         """Grant one pre-sized slab segment to a writer (one RPC
         amortized over many puts). ``seals`` retires the caller's
         previous slab(s) in the same round trip."""
-        if not self.arena_enabled:
-            return {"ok": False}
-        if isinstance(seals, dict):
-            seals = [seals]
         nbytes = slab_arena.align_up(max(1, nbytes))
         with self._lock:
             for seal in seals or ():
@@ -756,8 +711,6 @@ class LocalObjectStore:
         verdicts. They go straight to dead ranges (and the PUNCH_HOLE
         sweep) instead."""
         new: List[bytes] = []
-        if not self.arena_enabled:
-            return new
         kv_prefix = slab_arena.KV_PAGE_OID_PREFIX
         with self._lock:
             for seg in list(self._segments.values()):
@@ -877,9 +830,6 @@ class LocalObjectStore:
             total_data_len: int):
         """Owner-local put (pull/push receives, broadcasts): bump into the
         raylet's own slab — the raylet leases from itself, no RPC."""
-        if not self.arena_enabled:
-            return self._put_file(object_id, metadata, buffers,
-                                  total_data_len)
         with self._lock:
             if object_id in self._slab_objs or object_id in self._sizes:
                 return  # immutable: double-writes are benign
@@ -913,9 +863,7 @@ class LocalObjectStore:
         ``seal()`` flips the state word DEAD→SEALED only when every
         byte has arrived — the same atomic-seal contract as a local
         put. Returns None when the transfer should fall back to heap
-        assembly (arena off, store full, duplicate object)."""
-        if not self.arena_enabled:
-            return None
+        assembly (store full, duplicate object)."""
         with self._lock:
             if object_id in self._slab_objs or object_id in self._sizes:
                 return None  # already resident: nothing to assemble
@@ -1042,21 +990,6 @@ class LocalObjectStore:
             if not seg.live and seg.leased_to is None and not seg.reserved:
                 self._unlink_segment_locked(seg)
 
-    def _put_file(self, object_id: ObjectID, metadata: bytes, buffers,
-                  total_data_len: int):
-        size = _HEADER + len(metadata) + total_data_len
-        self._ensure_space(size)
-        written = write_object(self.store_dir, object_id, metadata, buffers,
-                               total_data_len)
-        if written:
-            with self._lock:
-                self._sizes[object_id] = written
-                self._used += written
-                self._lru[object_id] = time.monotonic()
-                # the id exists now: a previously-cached miss must not
-                # mask a later spill-restore of this object
-                self._probe_missed.pop(object_id, None)
-
     def register_external(self, object_id: ObjectID):
         """Account for a one-file object written directly by another
         process (lease-less fallback writes, restores) — capacity is
@@ -1145,10 +1078,9 @@ class LocalObjectStore:
         return buf
 
     def get(self, object_id: ObjectID) -> Optional[ObjectBuffer]:
-        if self.arena_enabled:
-            buf = self._slab_read(object_id)
-            if buf is not None:
-                return buf
+        buf = self._slab_read(object_id)
+        if buf is not None:
+            return buf
         buf = _read_object_file(self.store_dir, object_id,
                                 time.perf_counter())
         if buf is None and (object_id in self._spilled
@@ -1165,18 +1097,17 @@ class LocalObjectStore:
         return buf
 
     def contains(self, object_id: ObjectID) -> bool:
-        if self.arena_enabled:
+        with self._lock:
+            ent = self._slab_objs.get(object_id)
+        if ent is not None:
+            state = slab_arena.state_at(self.store_dir, ent[0], ent[1],
+                                        object_id.binary())
+            if state == slab_arena.STATE_SEALED:
+                return True
             with self._lock:
-                ent = self._slab_objs.get(object_id)
-            if ent is not None:
-                state = slab_arena.state_at(self.store_dir, ent[0], ent[1],
-                                            object_id.binary())
-                if state == slab_arena.STATE_SEALED:
-                    return True
-                with self._lock:
-                    self._forget_slab_obj_locked(object_id, mark_dead=False)
-            elif slab_arena.exists(self.store_dir, object_id.binary()):
-                return True  # unreported writer object via the shared index
+                self._forget_slab_obj_locked(object_id, mark_dead=False)
+        elif slab_arena.exists(self.store_dir, object_id.binary()):
+            return True  # unreported writer object via the shared index
         if os.path.exists(_obj_path(self.store_dir, object_id)) \
                 or object_id in self._spilled:
             return True
@@ -1428,14 +1359,6 @@ class LocalObjectStore:
         with self._lock:
             self._pinned[object_id] = self._pinned.get(object_id, 0) + 1
 
-    def unpin(self, object_id: ObjectID):
-        with self._lock:
-            n = self._pinned.get(object_id, 0) - 1
-            if n <= 0:
-                self._pinned.pop(object_id, None)
-            else:
-                self._pinned[object_id] = n
-
     def delete(self, object_id: ObjectID):
         with self._lock:
             self._delete_locked(object_id)
@@ -1462,26 +1385,25 @@ class LocalObjectStore:
         # never saw — the unknown-oid case must stay a few dict misses.
         size = self._sizes.pop(object_id, 0)
         known_file = size > 0
-        if self.arena_enabled:
-            if object_id in self._slab_objs:
-                self._forget_slab_obj_locked(object_id)
-            elif tombstone and not known_file \
-                    and object_id not in self._spilled:
-                # a free can race the writer's in-flight accounting
-                # report: remember it so record_slab_objects completes
-                # the delete instead of resurrecting the object. No
-                # index probe here — frees of inline objects vastly
-                # outnumber real races, and a per-free probe is raylet
-                # CPU stolen from the data path on teardown bursts.
-                self._pending_deletes[object_id] = None
-                while len(self._pending_deletes) > 10_000:
-                    self._pending_deletes.popitem(last=False)
+        if object_id in self._slab_objs:
+            self._forget_slab_obj_locked(object_id)
+        elif tombstone and not known_file \
+                and object_id not in self._spilled:
+            # a free can race the writer's in-flight accounting
+            # report: remember it so record_slab_objects completes
+            # the delete instead of resurrecting the object. No
+            # index probe here — frees of inline objects vastly
+            # outnumber real races, and a per-free probe is raylet
+            # CPU stolen from the data path on teardown bursts.
+            self._pending_deletes[object_id] = None
+            while len(self._pending_deletes) > 10_000:
+                self._pending_deletes.popitem(last=False)
         # No filesystem touch for oids the ledger doesn't know (the
         # common case: freed inline/slab objects have no .obj file, and
         # a stat costs microseconds under a sandboxed kernel). The one
         # race — a fallback .obj write whose register_put is still in
         # flight — is closed in register_external via _pending_deletes.
-        if known_file or not self.arena_enabled:
+        if known_file:
             try:
                 os.unlink(_obj_path(self.store_dir, object_id))
             except FileNotFoundError:
@@ -1634,7 +1556,7 @@ class LocalObjectStore:
         out = {"punched_ranges": 0, "punched_bytes": 0,
                "dead_bytes_retired": 0, "skipped_pinned": 0,
                "segments": 0}
-        if not self.arena_enabled or not self.punch_supported():
+        if not self.punch_supported():
             return out
         min_frag = (cfg.slab_punch_min_fragmentation
                     if min_fragmentation is None else min_fragmentation)

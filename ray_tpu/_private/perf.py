@@ -167,7 +167,7 @@ def run_transfer_plane_bench(small: bool = False) -> List[dict]:
                 assert ok, (op, name, i)
                 h.record(dt)
                 best = max(best, size / dt / 1e6)
-                del ref
+                last_ref, ref = ref, None
             row = {"benchmark": f"xfer {op} {name}", "value": round(best, 2),
                    "unit": "MB/s", "bytes": size}
             row.update(_lat_summary(h))
@@ -177,9 +177,13 @@ def run_transfer_plane_bench(small: bool = False) -> List[dict]:
     flows = state.object_summary().get("flows") or []
     rx = [f for f in flows if f.get("kind") in ("fetch", "push_rx")]
     arena_paths = bool(rx) and all(f.get("path") == "arena" for f in rx)
+    from ray_tpu._private import slab_arena
     from ray_tpu._private.worker import global_worker
 
-    slab = bool(getattr(global_worker.core_worker, "arena_enabled", False))
+    # the last bulk put (still referenced) sits in a slab entry: the shared
+    # index is the writer's synchronous publication
+    slab = slab_arena.exists(global_worker.core_worker.store_dir,
+                             last_ref.binary())
     for row in results:
         row["arena_paths"] = arena_paths
         row["slab_backed"] = slab

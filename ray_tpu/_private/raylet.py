@@ -397,7 +397,7 @@ class Raylet:
             self.spill_dir = os.path.join(
                 spill_root, f"spill_{self.node_id[:12]}"
             )
-        self.store = object_store.make_local_store(
+        self.store = object_store.LocalObjectStore(
             self.store_dir, cfg.object_store_memory, self.spill_dir
         )
         self.resources_total = dict(resources)
@@ -550,36 +550,32 @@ class Raylet:
         # memory observatory (memview.py): arena occupancy gauges on the
         # cluster scrape — dead bytes inside live segments are the
         # hole-punch reclamation candidates, and a pooled segment pinned
-        # by a reader's SHARED flock is a stuck-view leak. Guarded: the
-        # native store (slab_arena=0) has no arena ledger.
+        # by a reader's SHARED flock is a stuck-view leak.
         st = self.store
-        if hasattr(st, "arena_dead_bytes"):
-            gauge("slab_arena_dead_bytes",
-                  "Dead (hole-punch-reclaimable) bytes inside live slab "
-                  "segments", st.arena_dead_bytes)
-            gauge("slab_arena_live_bytes",
-                  "Live object bytes resident in slab segments",
-                  st.arena_live_bytes)
-            gauge("slab_arena_fragmentation_ratio",
-                  "dead / (live + dead) resident slab bytes",
-                  st.arena_fragmentation)
-        if hasattr(st, "arena_punched_bytes"):
-            # cumulative punch-pass yield: *_total counter semantics so
-            # rate() shows reclamation activity on the cluster scrape
-            reg.counter(
-                "slab_arena_punched_dead_bytes_total",
-                "Dead bytes retired from live segments by the "
-                "hole-punch reclamation pass",
-            ).labels(**tags).set_fn(st.arena_punched_bytes)
-        if hasattr(st, "pool_pinned"):
-            # TTL-cached: a flock probe per pooled file per scrape is
-            # cheap, but metrics scrapes can arrive from several pollers
-            reg.gauge(
-                "slab_segments_pinned",
-                "Recycling-pool segments kept alive only by a reader's "
-                "SHARED flock",
-            ).labels(**dict(tags, reason="reader_flock")).set_fn(
-                lambda: len(st.pool_pinned(max_age_s=5.0)))
+        gauge("slab_arena_dead_bytes",
+              "Dead (hole-punch-reclaimable) bytes inside live slab "
+              "segments", st.arena_dead_bytes)
+        gauge("slab_arena_live_bytes",
+              "Live object bytes resident in slab segments",
+              st.arena_live_bytes)
+        gauge("slab_arena_fragmentation_ratio",
+              "dead / (live + dead) resident slab bytes",
+              st.arena_fragmentation)
+        # cumulative punch-pass yield: *_total counter semantics so
+        # rate() shows reclamation activity on the cluster scrape
+        reg.counter(
+            "slab_arena_punched_dead_bytes_total",
+            "Dead bytes retired from live segments by the "
+            "hole-punch reclamation pass",
+        ).labels(**tags).set_fn(st.arena_punched_bytes)
+        # TTL-cached: a flock probe per pooled file per scrape is
+        # cheap, but metrics scrapes can arrive from several pollers
+        reg.gauge(
+            "slab_segments_pinned",
+            "Recycling-pool segments kept alive only by a reader's "
+            "SHARED flock",
+        ).labels(**dict(tags, reason="reader_flock")).set_fn(
+            lambda: len(st.pool_pinned(max_age_s=5.0)))
         # log plane self-measurement (channel-tagged: the "logs" pubsub
         # channel is the only one carrying log records today)
         ltags = dict(tags, channel="logs")
@@ -633,10 +629,8 @@ class Raylet:
         self._tasks.append(
             spawn(self._log_tailer_loop())
         )
-        if hasattr(self.store, "punch_holes"):
-            self._tasks.append(spawn(self._punch_loop()))
-        if cfg.enable_node_agent:
-            spawn(self._start_agent())
+        self._tasks.append(spawn(self._punch_loop()))
+        spawn(self._start_agent())
         if cfg.worker_prestart > 0:
             spawn(self._prestart_workers())
         logger.info("raylet %s listening on %s", self.node_id[:8], self.port)
@@ -1081,8 +1075,6 @@ class Raylet:
         loop = asyncio.get_running_loop()
         while True:
             await asyncio.sleep(cfg.slab_punch_interval_s)
-            if not cfg.slab_punch_enabled:
-                continue
             try:
                 out = await loop.run_in_executor(None,
                                                  self.store.punch_holes)
@@ -1201,10 +1193,7 @@ class Raylet:
                 if not w.registered.done():
                     w.registered.set_result(w)
         return {"node_id": self.node_id, "store_dir": self.store_dir,
-                "resources_total": self.resources_total, "labels": self.labels,
-                # clients with a lease-capable store use the slab-arena
-                # put path; others fall back to one-file writes
-                "arena": bool(getattr(self.store, "arena_enabled", False))}
+                "resources_total": self.resources_total, "labels": self.labels}
 
     def on_disconnect(self, conn: Connection):
         if conn is self.gcs:
@@ -1448,10 +1437,9 @@ class Raylet:
         and enqueue its actor tasks first, reordering a single actor's
         calls across frames.
 
-        ack="batch" (fire-and-forget lane): the reply acks frame
-        ACCEPTANCE — scheduling proceeds in the background and the
-        driver's await no longer spans per-spec placement. Failures past
-        the ack surface exactly like failures past the legacy reply: via
+        Fire-and-forget lane: the reply acks frame ACCEPTANCE —
+        scheduling proceeds in the background and the driver's await does
+        not span per-spec placement. Failures past the ack surface via
         the owner-routed task_result stream and the task-event plane."""
         rest = []
         for spec in p["specs"]:
@@ -1459,20 +1447,16 @@ class Raylet:
                 self._enqueue_actor_task(spec, None)
             else:
                 rest.append(spec)
-        if p.get("ack") == "batch":
-            if rest:
-                t = asyncio.get_running_loop().create_task(
-                    self._schedule_batch(rest)
-                )
-                self._bg_tasks.add(t)
-                t.add_done_callback(self._bg_tasks.discard)
-            return {"accepted": len(p["specs"])}
-        for spec in rest:
-            await self._schedule_or_queue(spec)
-        return {}
+        if rest:
+            t = asyncio.get_running_loop().create_task(
+                self._schedule_batch(rest)
+            )
+            self._bg_tasks.add(t)
+            t.add_done_callback(self._bg_tasks.discard)
+        return {"accepted": len(p["specs"])}
 
     async def _schedule_batch(self, specs):
-        """Background half of the batched-ack lane. The submitter already
+        """Background half of submit_batch. The submitter already
         holds its ack, so a swallowed scheduling failure would hang its
         get() forever — every per-spec error is converted into an
         owner-routed task failure instead of a reply-path exception."""
@@ -2269,25 +2253,18 @@ class Raylet:
     # -- slab arena lease + batched accounting (slab_arena.py) ---------
     async def rpc_lease_slab(self, conn: Connection, p):
         """Grant a write slab to a local client (one RPC amortized over
-        many puts); ``seal`` retires the caller's previous slab in the
-        same round trip. A denial (no arena / store full of leased
-        slabs) sends the writer to the one-file fallback path, whose
+        many puts); ``seals`` retires the caller's previous slabs in the
+        same round trip. A denial (store full of leased slabs) sends
+        the writer to the one-file fallback path, whose
         register_external accounts the overshoot honestly."""
-        lease = getattr(self.store, "lease_slab", None)
-        if lease is None:
-            return {"ok": False}
-        seals = p.get("seals") or ([p["seal"]] if p.get("seal") else [])
-        return lease(conn.meta.get("client_id") or "", int(p["bytes"]),
-                     seals)
+        return self.store.lease_slab(conn.meta.get("client_id") or "",
+                                     int(p["bytes"]), p.get("seals"))
 
     async def rpc_slab_report(self, conn: Connection, p):
         """Batched put accounting from a slab writer: adopt the entries
         into the store ledger and publish the new locations to the GCS
         in ONE frame (vs the legacy one-register_put-RPC-per-put)."""
-        record = getattr(self.store, "record_slab_objects", None)
-        if record is None:
-            return {}
-        new = record(p["objects"])
+        new = self.store.record_slab_objects(p["objects"])
         if new:
             await self._publish_locations(new)
             self._dispatch_event.set()
@@ -2297,11 +2274,10 @@ class Raylet:
         """A slab-leasing client died: adopt the sealed prefixes of its
         leased segments (torn mid-put tails are discarded by the scan)
         and publish any unreported objects it managed to seal."""
-        reclaim = getattr(self.store, "reclaim_client_slabs", None)
-        if reclaim is None or not client_id:
+        if not client_id:
             return
         try:
-            new = reclaim(client_id)
+            new = self.store.reclaim_client_slabs(client_id)
         except Exception:
             logger.exception("slab reclaim for %s failed", client_id[:8])
             return
@@ -2335,9 +2311,7 @@ class Raylet:
         oid = ObjectID(oid_bytes)
         if self.store.contains(oid):
             # May be spilled: bring it back into shm so workers can mmap it.
-            restore = getattr(self.store, "restore_if_spilled", None)
-            if restore is not None:
-                restore(oid)
+            self.store.restore_if_spilled(oid)
             return True
         fut = self._pulls_inflight.get(oid_bytes)
         if fut is not None:
@@ -2526,9 +2500,7 @@ class Raylet:
         sealed = False
         try:
             data0 = first["data"]
-            reserve = getattr(self.store, "reserve", None)
-            if reserve is not None:
-                res = reserve(oid, metadata, total)
+            res = self.store.reserve(oid, metadata, total)
             parts: Optional[dict] = None if res is not None else {}
             received = [0]
             failed = [False]
@@ -2806,9 +2778,7 @@ class Raylet:
         if p.get("metadata") is not None:
             st["meta"] = p["metadata"]
         if st["res"] is None and not st["heap"] and st["meta"] is not None:
-            reserve = getattr(self.store, "reserve", None)
-            if reserve is not None:
-                st["res"] = reserve(oid, st["meta"], st["total"])
+            st["res"] = self.store.reserve(oid, st["meta"], st["total"])
             if st["res"] is None:
                 st["heap"] = True  # fall back for the session's lifetime
             else:
@@ -2954,20 +2924,12 @@ class Raylet:
             raise
         return Finalized(out, buf.release)
 
-    def rpc_delete_object(self, conn: Connection, p):
-        self.store.delete(ObjectID(p["object_id"]))
-
     def rpc_delete_objects(self, conn: Connection, p):
         """Batched GCS free broadcast (one frame per release burst)."""
         self._delete_local(p["object_ids"])
 
     def _delete_local(self, oids):
-        many = getattr(self.store, "delete_many", None)
-        if many is not None:
-            many([ObjectID(oid) for oid in oids])
-            return
-        for oid in oids:
-            self.store.delete(ObjectID(oid))
+        self.store.delete_many([ObjectID(oid) for oid in oids])
 
     async def rpc_owner_call(self, conn: Connection, p):
         """Route a request to an owning core worker anywhere in the cluster
@@ -3003,8 +2965,7 @@ class Raylet:
         oid = p["object_id"]
         # forget, not delete: a loss is not a free — reconstruction will
         # re-put this oid and must not hit a pending-delete tombstone
-        forget = getattr(self.store, "forget", self.store.delete)
-        forget(ObjectID(oid))
+        self.store.forget(ObjectID(oid))
         try:
             await self.gcs.request(
                 "remove_object_location",
@@ -3278,16 +3239,11 @@ class Raylet:
             # ledger rows plus flock probes of the recycling pool: run
             # it on an executor thread so a full store never stalls the
             # raylet event loop (heartbeats, dispatch, pushes).
-            # getattr-guarded: the native C++ store (slab_arena=0) has
-            # no introspection surface yet — the node still reports its
-            # workers.
             own = memview.process_snapshot({"node_id": self.node_id,
                                             "role": "raylet"})
-            intro = getattr(self.store, "arena_introspect", None)
-            objs = getattr(self.store, "memview_objects", None)
             own["store"] = {
-                "arena": intro() if intro is not None else None,
-                "objects": objs(limit) if objs is not None else [],
+                "arena": self.store.arena_introspect(),
+                "objects": self.store.memview_objects(limit),
             }
             return own
 

@@ -3,15 +3,11 @@
 Plays the role of the reference's gRPC + asio layer (ray: src/ray/rpc/,
 src/ray/common/asio/): every control-plane process (GCS, raylet, core worker)
 runs one asyncio loop; peers hold persistent duplex connections over which
-either side can issue requests or one-way notifications. Two frame formats
-exist, negotiated per connection (see the auth preamble below):
+either side can issue requests or one-way notifications. One frame format:
 
-  v1: ``[4B len][pickle((msg_id, kind, method, payload))]``
-  v2: ``[4B total_len][1B nbufs][4B len x nbufs][pickle5 envelope][buf0]...``
-  v3: v2 plus a 4-byte CRC32 trailer on the frame head:
-      ``[4B total][1B nbufs][4B len x nbufs][envelope][4B crc][buf0]...``
+  ``[4B total][1B nbufs][4B len x nbufs][pickle5 envelope][4B crc][buf0]...``
 
-v2 is the zero-copy out-of-band format: the envelope is pickled with a
+It is a zero-copy out-of-band format: the envelope is pickled with a
 ``buffer_callback`` so large buffers (numpy arrays, shm chunk views,
 ``serialization.BufferList`` members) are never memcpy'd into the pickle
 stream — the flush path writes them to the socket as vectored memoryviews,
@@ -20,7 +16,7 @@ buffer. This makes the connection a data plane too: object-manager chunks
 and inline task args/results ride frames without per-hop copies, while the
 shm store stays the intra-node zero-copy path.
 
-v3 adds the control-plane hardening layer (the reference gates releases on
+The control-plane hardening layer (the reference gates releases on
 RPC-level chaos; see faultsim.py):
 
   * frame integrity: the CRC32 trailer covers the frame HEAD (count byte,
@@ -78,9 +74,12 @@ _HDR = 4
 # frames above this size are written unjoined (joining would memcpy MBs);
 # smaller parts coalesce into one socket write per tick
 _JOIN_MAX = 128 * 1024
-# v2 buffer table: 1-byte count field caps out-of-band buffers per frame;
+# buffer table: 1-byte count field caps out-of-band buffers per frame;
 # overflow buffers simply stay in-band (correct, one extra copy)
 _MAX_OOB_BUFS = 255
+# smaller payload buffers stay in the envelope: a table entry + unjoined
+# write costs more than a tiny memcpy
+OOB_MIN_BYTES = 512
 
 _HAS_EAGER_FACTORY = hasattr(asyncio, "eager_task_factory")
 
@@ -89,18 +88,6 @@ def _max_msg() -> int:
     from ray_tpu._private.config import GLOBAL_CONFIG
 
     return GLOBAL_CONFIG.rpc_max_message_bytes
-
-
-def _oob_min() -> int:
-    from ray_tpu._private.config import GLOBAL_CONFIG
-
-    return GLOBAL_CONFIG.rpc_oob_min_bytes
-
-
-def _frame_version() -> int:
-    from ray_tpu._private.config import GLOBAL_CONFIG
-
-    return GLOBAL_CONFIG.rpc_frame_version
 
 
 def _nbytes(part) -> int:
@@ -126,34 +113,21 @@ def _nbytes(part) -> int:
 # matches the reference's cluster-token posture; deployments that face
 # untrusted networks must wrap transport in TLS/VPN at a lower layer.
 #
-# Frame-version negotiation rides the preamble's magic: a client that
-# speaks the v2 out-of-band frame format opens with magic "RTPU2" (same
-# preamble length); a v2-aware server answers with a single version byte
-# 0x02 and both sides speak v2 from the first frame. A v1-only server
-# fails the digest compare on the unknown magic and closes — the client
-# detects the EOF where the version byte should be and redials with the
-# next-lower preamble, so mixed-version clusters never misparse streams.
-# A v1 client sending "RTPU1" gets a silent (byte-free) v1 session from a
-# newer server, exactly as before. v3 ("RTPU3", ack 0x03) is v2 framing
-# plus the CRC32 head trailer; the downgrade chain is 3 -> 2 -> 1.
+# The server answers a matching preamble with one ack byte; anything else
+# (another token, another checkout's magic) it closes unanswered.
 
-_AUTH_MAGIC = b"RTPU1"
-_AUTH_MAGIC_V2 = b"RTPU2"
-_AUTH_MAGIC_V3 = b"RTPU3"
+_AUTH_MAGIC = b"RTPU3"
 _AUTH_LEN = len(_AUTH_MAGIC) + 64
-_V2_ACK = b"\x02"
-_V3_ACK = b"\x03"
-_MAGICS = {1: _AUTH_MAGIC, 2: _AUTH_MAGIC_V2, 3: _AUTH_MAGIC_V3}
-_ACKS = {2: _V2_ACK, 3: _V3_ACK}
+_AUTH_ACK = b"\x03"
 
 
 def cluster_token() -> str:
     return os.environ.get("RAY_TPU_CLUSTER_TOKEN", "")
 
 
-def _auth_preamble(token: str, version: int = 1) -> bytes:
+def _auth_preamble(token: str) -> bytes:
     digest = hashlib.sha256(token.encode()).hexdigest().encode()
-    return _MAGICS[min(version, 3)] + digest
+    return _AUTH_MAGIC + digest
 
 
 class RpcError(Exception):
@@ -188,63 +162,34 @@ class Finalized:
         self.release = release
 
 
-def _decode_v2(data: bytes):
-    """Decode a v2 frame body (everything after the 4B total-length header)
-    into ``(msg_id, kind, method, payload)``. Out-of-band buffers become
-    zero-copy memoryviews over ``data`` — they stay valid (and readonly)
-    for as long as the payload holds them, independent of the connection."""
-    if len(data) < 1:
-        raise RpcError("corrupt v2 frame: empty body")
-    nbufs = data[0]
-    view = memoryview(data)
-    if nbufs == 0:  # control-plane common case: no table to parse
-        return pickle.loads(view[1:])
-    env_start = 1 + 4 * nbufs
-    if env_start > len(data):
-        raise RpcError("corrupt v2 frame: buffer table truncated")
-    lens = [
-        int.from_bytes(view[1 + 4 * i: 5 + 4 * i], "little")
-        for i in range(nbufs)
-    ]
-    env_end = len(data) - sum(lens)
-    if env_end < env_start:
-        raise RpcError("corrupt v2 frame: buffers exceed frame length")
-    bufs = []
-    pos = env_end
-    for n in lens:
-        bufs.append(view[pos: pos + n])
-        pos += n
-    return pickle.loads(view[env_start:env_end], buffers=bufs)
-
-
-def _decode_v3(data: bytes):
-    """Decode a v3 frame body: v2 layout with a 4-byte CRC32 trailer after
-    the envelope, covering every byte before it (count byte + buffer table
-    + envelope). Structural impossibilities and CRC mismatches both raise
-    FrameCorruptError — either way the stream cannot be resynced."""
+def _decode_frame(data: bytes):
+    """Decode a frame body (all after the 4B length) into ``(msg_id, kind,
+    method, payload)``; out-of-band buffers become zero-copy readonly views
+    over ``data``. A CRC mismatch or an impossible table raises
+    FrameCorruptError: either way the stream cannot be resynced."""
     if len(data) < 5:
-        raise FrameCorruptError("corrupt v3 frame: short body")
+        raise FrameCorruptError("corrupt frame: short body")
     nbufs = data[0]
     view = memoryview(data)
     if nbufs == 0:
         crc_off = len(data) - 4
         if zlib.crc32(view[:crc_off]) != int.from_bytes(
                 view[crc_off:], "little"):
-            raise FrameCorruptError("v3 frame failed CRC32 check")
+            raise FrameCorruptError("frame failed CRC32 check")
         return pickle.loads(view[1:crc_off])
     env_start = 1 + 4 * nbufs
     if env_start > len(data):
-        raise FrameCorruptError("corrupt v3 frame: buffer table truncated")
+        raise FrameCorruptError("corrupt frame: buffer table truncated")
     lens = [
         int.from_bytes(view[1 + 4 * i: 5 + 4 * i], "little")
         for i in range(nbufs)
     ]
     crc_off = len(data) - sum(lens) - 4
     if crc_off < env_start:
-        raise FrameCorruptError("corrupt v3 frame: buffers exceed frame")
+        raise FrameCorruptError("corrupt frame: buffers exceed frame")
     if zlib.crc32(view[:crc_off]) != int.from_bytes(
             view[crc_off: crc_off + 4], "little"):
-        raise FrameCorruptError("v3 frame failed CRC32 check")
+        raise FrameCorruptError("frame failed CRC32 check")
     bufs = []
     pos = crc_off + 4
     for n in lens:
@@ -345,7 +290,7 @@ class _RpcMetrics:
             "Connections reset after keepalive silence").default
         self.crc_errors = reg.counter(
             "rpc_frame_crc_errors_total",
-            "Inbound frames failing the v3 CRC32 head check").default
+            "Inbound frames failing the CRC32 head check").default
         self._lat: Dict[str, Any] = {}
         self._handled: Dict[str, Any] = {}
         self._timeouts: Dict[str, Any] = {}
@@ -409,8 +354,7 @@ class Connection:
     _ids = itertools.count(1)
 
     def __init__(self, reader, writer, handler: Optional[object] = None,
-                 name: str = "?", version: int = 1,
-                 peer_addr: Optional[str] = None):
+                 name: str = "?", peer_addr: Optional[str] = None):
         self.reader = reader
         self.writer = writer
         self.handler = handler
@@ -418,13 +362,8 @@ class Connection:
         # "host:port" of the remote end (faultsim partition matching and
         # diagnostics); server-side conns carry the peer's ephemeral addr
         self.peer_addr = peer_addr
-        # negotiated frame format (1 = in-band pickle, 2 = out-of-band
-        # buffer table, 3 = v2 + CRC head trailer); both peers agreed on
-        # it during the auth preamble
-        self.version = version
-        # flags read once per connection: the recv/send loops are hot paths
+        # flag read once per connection: the recv/send loops are hot paths
         self._max_msg = _max_msg()
-        self._oob_min = _oob_min()
         self._pending: Dict[int, asyncio.Future] = {}
         self._msg_ids = itertools.count(1)
         self._send_lock = asyncio.Lock()
@@ -450,10 +389,8 @@ class Connection:
         self._recv_task = loop.create_task(self._recv_loop())
         from ray_tpu._private.config import GLOBAL_CONFIG
 
-        # keepalive only on v3+ sessions: both ends are new enough to pong
-        # (an old peer would log "no handler" warnings and never answer,
-        # reading as dead). Gated off for interval <= 0.
-        if self.version >= 3 and GLOBAL_CONFIG.rpc_keepalive_interval_s > 0:
+        # Gated off for interval <= 0.
+        if GLOBAL_CONFIG.rpc_keepalive_interval_s > 0:
             self._keepalive_task = loop.create_task(self._keepalive_loop())
         return self._recv_task
 
@@ -510,37 +447,23 @@ class Connection:
         """Encode one frame as a tuple of bytes-like parts (written to the
         socket in order, large parts by reference — no join memcpy).
 
-        v1: one part, ``[4B len][pickle]``.
-        v2: ``[4B total][1B nbufs][4B len x nbufs][envelope]`` as the head
-        part, then each out-of-band buffer as its own part. The envelope is
-        pickled with ``buffer_callback`` so protocol-5-aware payloads
-        (numpy arrays, PickleBuffers, serialization.BufferList members)
-        never enter the pickle stream.
-        v3: v2 with a 4-byte CRC32 of the head (count byte + table +
-        envelope) appended to the head part, before the buffers.
+        ``[4B total][1B nbufs][4B len x nbufs][envelope][4B crc]`` is the
+        head part (the CRC32 covers count byte + table + envelope), then
+        each out-of-band buffer (what pickle's ``buffer_callback`` kept out
+        of the envelope) is its own part.
 
         Raises RpcError BEFORE anything is queued when the frame would
         exceed ``rpc_max_message_bytes`` — an oversized send must fail
         loudly at the caller, not opaquely kill the peer's recv loop.
         """
-        if self.version < 2:
-            data = pickle.dumps((msg_id, kind, method, payload), protocol=5)
-            total = len(data)
-            if total > self._max_msg:
-                raise RpcError(
-                    f"outgoing {method!r} message too large: {total} bytes "
-                    f"> rpc_max_message_bytes={self._max_msg}"
-                )
-            return (total.to_bytes(_HDR, "little") + data,)
         bufs: list = []
-        oob_min = self._oob_min
 
         def _cb(pb: pickle.PickleBuffer):
             try:
                 view = pb.raw()
             except Exception:
                 return True  # non-contiguous buffer: serialize in-band
-            if view.nbytes < oob_min or len(bufs) >= _MAX_OOB_BUFS \
+            if view.nbytes < OOB_MIN_BYTES or len(bufs) >= _MAX_OOB_BUFS \
                     or view.nbytes > 0xFFFFFFFF:
                 return True  # tiny / table-overflow / >4GiB: in-band
             bufs.append(view)
@@ -548,23 +471,9 @@ class Connection:
 
         env = pickle.dumps((msg_id, kind, method, payload), protocol=5,
                            buffer_callback=_cb)
-        crc_len = 4 if self.version >= 3 else 0
-        if not bufs:
-            # control-plane common case: no table, same cost as a v1 frame
-            total = 1 + len(env) + crc_len
-            if total > self._max_msg:
-                raise RpcError(
-                    f"outgoing {method!r} message too large: {total} bytes "
-                    f"> rpc_max_message_bytes={self._max_msg}"
-                )
-            if not crc_len:
-                return (total.to_bytes(_HDR, "little") + b"\x00" + env,)
-            crc = zlib.crc32(env, zlib.crc32(b"\x00"))
-            return (total.to_bytes(_HDR, "little") + b"\x00" + env
-                    + crc.to_bytes(4, "little"),)
+        # the control plane's common case is no buffer: an empty table
         table = b"".join(v.nbytes.to_bytes(4, "little") for v in bufs)
-        total = (1 + len(table) + len(env) + crc_len
-                 + sum(v.nbytes for v in bufs))
+        total = 1 + len(table) + len(env) + 4 + sum(v.nbytes for v in bufs)
         if total > self._max_msg:
             raise RpcError(
                 f"outgoing {method!r} message too large: {total} bytes "
@@ -572,13 +481,12 @@ class Connection:
                 f"> rpc_max_message_bytes={self._max_msg}"
             )
         nb = bytes((len(bufs),))
-        head_parts = [total.to_bytes(_HDR, "little"), nb, table, env]
-        if crc_len:
-            # CRC over the head only: out-of-band buffers are the zero-copy
-            # payload path and are excluded by design (see module docs)
-            crc = zlib.crc32(env, zlib.crc32(table, zlib.crc32(nb)))
-            head_parts.append(crc.to_bytes(4, "little"))
-        return (b"".join(head_parts), *bufs)
+        # CRC over the head only: out-of-band buffers are the zero-copy
+        # payload path and are excluded by design (see module docs)
+        crc = zlib.crc32(env, zlib.crc32(table, zlib.crc32(nb)))
+        head = b"".join((total.to_bytes(_HDR, "little"), nb, table, env,
+                         crc.to_bytes(4, "little")))
+        return (head, *bufs)
 
     def _fault_peer(self) -> Optional[str]:
         """Identity string partition rules match against. Combines the
@@ -718,7 +626,7 @@ class Connection:
                                 f"mid-frame"))
                             return
                         continue
-                    # a frame is a tuple of parts (v2 out-of-band buffers
+                    # a frame is a tuple of parts (out-of-band buffers
                     # ride as separate memoryview parts, by reference)
                     for part in frame if isinstance(frame, tuple) \
                             else (frame,):
@@ -812,14 +720,9 @@ class Connection:
                 data = await self.reader.readexactly(n)
                 self._last_rx = time.monotonic()
                 _mx().bytes_in.inc(n + _HDR)
-                if self.version >= 3:
-                    msg_id, kind, method, payload = _decode_v3(data)
-                elif self.version == 2:
-                    # ONE read buffer per frame; payload buffers are
-                    # zero-copy memoryviews into it (they keep it alive)
-                    msg_id, kind, method, payload = _decode_v2(data)
-                else:
-                    msg_id, kind, method, payload = pickle.loads(data)
+                # ONE read buffer per frame; payload buffers are
+                # zero-copy memoryviews into it (they keep it alive)
+                msg_id, kind, method, payload = _decode_frame(data)
                 if kind == KIND_RESP:
                     fut = self._pending.get(msg_id)
                     if fut and not fut.done():
@@ -1027,26 +930,15 @@ class RpcServer:
         except Exception:
             writer.close()
             return
-        # run ALL digest compares unconditionally (constant-time-ish); the
-        # magic picks the negotiated frame version
-        token = cluster_token()
-        is_v3 = hmac.compare_digest(preamble, _auth_preamble(token, 3))
-        is_v2 = hmac.compare_digest(preamble, _auth_preamble(token, 2))
-        is_v1 = hmac.compare_digest(preamble, _auth_preamble(token, 1))
-        if not (is_v1 or is_v2 or is_v3):
+        if not hmac.compare_digest(preamble, _auth_preamble(cluster_token())):
             logger.warning("rejecting unauthenticated peer on :%d", self.port)
             writer.close()
             return
-        version = 3 if is_v3 else (2 if is_v2 else 1)
-        if version >= 2:
-            # version byte after the preamble: confirms v2/v3 to the client
-            # (an older server would instead have closed the connection)
-            writer.write(_ACKS[version])
+        writer.write(_AUTH_ACK)
         peername = writer.get_extra_info("peername")
         peer_addr = f"{peername[0]}:{peername[1]}" if peername else None
         conn = Connection(reader, writer, self.handler,
-                          name=f"server:{self.port}", version=version,
-                          peer_addr=peer_addr)
+                          name=f"server:{self.port}", peer_addr=peer_addr)
         self.connections.add(conn)
 
         def _closed(c):
@@ -1078,16 +970,10 @@ class RpcServer:
 async def connect(host: str, port: int, handler=None, name: str = "client",
                   retries: int = None, retry_delay: float = None,
                   token: Optional[str] = None,
-                  version: Optional[int] = None,
                   total_timeout: Optional[float] = None) -> Connection:
     """``token`` overrides the ambient cluster token for THIS connection —
     the path to external services with their own credential (the remote
     KV metadata server, like Redis with requirepass).
-
-    ``version`` pins the frame format (default: the rpc_frame_version
-    flag). A v3 dial that the peer rejects — an older server closes the
-    connection at the digest compare — falls back one version per redial
-    (3 -> 2 -> 1), so mixed-version clusters interoperate for one release.
 
     Dial failures retry with EXPONENTIAL backoff + jitter: delay starts at
     ``retry_delay`` (flag: rpc_connect_retry_delay_s), doubles per attempt,
@@ -1103,7 +989,6 @@ async def connect(host: str, port: int, handler=None, name: str = "client",
         retry_delay = GLOBAL_CONFIG.rpc_connect_retry_delay_s
     cap = max(retry_delay, GLOBAL_CONFIG.rpc_connect_backoff_max_s)
     deadline = (time.monotonic() + total_timeout) if total_timeout else None
-    want = min(_frame_version() if version is None else version, 3)
     addr = f"{host}:{port}"
     last = None
     attempt = 0
@@ -1116,45 +1001,32 @@ async def connect(host: str, port: int, handler=None, name: str = "client",
                     f"fault injection: partitioned from {addr}")
             reader, writer = await asyncio.open_connection(host, port)
             tok = cluster_token() if token is None else token
-            negotiated = 1
-            if want >= 2:
-                writer.write(_auth_preamble(tok, want))
-                await writer.drain()
+            writer.write(_auth_preamble(tok))
+            await writer.drain()
+            try:
+                ack = await asyncio.wait_for(
+                    reader.readexactly(1),
+                    GLOBAL_CONFIG.rpc_auth_timeout_s,
+                )
+            except (asyncio.IncompleteReadError, asyncio.TimeoutError,
+                    ConnectionResetError, OSError) as e:
                 try:
-                    ack = await asyncio.wait_for(
-                        reader.readexactly(1),
-                        GLOBAL_CONFIG.rpc_auth_timeout_s,
-                    )
-                except (asyncio.IncompleteReadError, asyncio.TimeoutError,
-                        ConnectionResetError, OSError) as e:
-                    try:
-                        writer.close()
-                    except Exception:
-                        pass
-                    # Downgrade ONLY on a clean EOF — that is an older
-                    # server deliberately closing at the unknown magic (or
-                    # a token mismatch — v1 surfaces those on first use
-                    # too). A reset/timeout is a transient network event;
-                    # downgrading on it would silently strip CRC+keepalive
-                    # from a fully capable peer for the session's lifetime.
-                    msg = f"v{want} handshake refused: {e!r}"
-                    if isinstance(e, asyncio.IncompleteReadError):
-                        want -= 1
-                    raise ConnectionRefusedError(msg) from None
-                if ack != _ACKS[want]:
-                    try:
-                        writer.close()
-                    except Exception:
-                        pass
-                    raise ConnectionLost(
-                        f"bad version ack from {addr}: {ack!r}"
-                    )
-                negotiated = want
-            else:
-                writer.write(_auth_preamble(tok, 1))
-                await writer.drain()
+                    writer.close()
+                except Exception:
+                    pass
+                # a clean EOF is the server closing at the digest compare
+                why = ("wrong cluster token or an older ray_tpu" if
+                       isinstance(e, asyncio.IncompleteReadError) else repr(e))
+                raise ConnectionRefusedError(
+                    f"handshake refused by {addr}: {why}") from None
+            if ack != _AUTH_ACK:
+                try:
+                    writer.close()
+                except Exception:
+                    pass
+                raise ConnectionLost(f"bad handshake ack from {addr}: {ack!r}")
             conn = Connection(reader, writer, handler, name=name,
-                              version=negotiated, peer_addr=addr)
+                              peer_addr=addr)
             # Client-side conns get disconnect callbacks too (raylet/worker
             # GCS-reconnect loops key off this).
             cb = getattr(handler, "on_disconnect", None)

@@ -20,6 +20,7 @@ from typing import Any, List, Tuple
 import cloudpickle
 
 from ray_tpu._private import object_ref as _object_ref
+from ray_tpu._private.rpcio import OOB_MIN_BYTES
 
 FMT_PICKLE5 = b"P5"
 FMT_ERROR = b"ER"
@@ -63,13 +64,10 @@ class BufferList:
 
     def __reduce_ex__(self, protocol):
         if protocol >= 5:
-            # same tunable the connection's buffer_callback applies: below
+            # the threshold the connection's buffer_callback applies: below
             # it, a table entry + unjoined write costs more than the memcpy
-            from ray_tpu._private.config import GLOBAL_CONFIG
-
-            oob_min = GLOBAL_CONFIG.rpc_oob_min_bytes
             return (BufferList, ([
-                pickle.PickleBuffer(b) if _nbytes(b) >= oob_min
+                pickle.PickleBuffer(b) if _nbytes(b) >= OOB_MIN_BYTES
                 else (b if isinstance(b, bytes) else bytes(b))
                 for b in self.buffers
             ],))
@@ -125,7 +123,7 @@ def serialize(value: Any) -> SerializedValue:
     def buffer_callback(pb: pickle.PickleBuffer):
         view = pb.raw()
         # store-layout threshold (distinct from the wire's
-        # rpc_oob_min_bytes): tiny buffers stay inside the pickled stream
+        # rpcio.OOB_MIN_BYTES): tiny buffers stay inside the pickled stream
         if view.nbytes >= 512:
             oob.append(view)
             return False

@@ -540,7 +540,7 @@ def process_snapshot(extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 # Arrivals to the SAME physical collective cannot be farther apart than
-# the op's timeout (default collective_timeout_s=120) plus clock slop: a
+# the op's timeout (its ``timeout`` argument, 120 s by default) plus clock slop: a
 # wider gap means the (group, seq) key was REUSED by a later run (groups
 # reset seq to 0 on re-init, and the GCS log deliberately outlives runs).
 # The join therefore clusters arrivals by time before attributing skew —

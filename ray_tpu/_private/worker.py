@@ -234,11 +234,7 @@ class CoreWorker:
         # slab-arena write path (slab_arena.py): this client leases write
         # slabs from its raylet and bump-allocates puts/results into the
         # mmap'd segment; accounting is self-reported in batches
-        self.arena_enabled = bool(reply.get("arena"))
-        self._slab_writer = (
-            slab_arena.SlabWriter(self.store_dir) if self.arena_enabled
-            else None
-        )
+        self._slab_writer = slab_arena.SlabWriter(self.store_dir)
         self._slab_lease_lock = threading.Lock()
         self._slab_reports: List[dict] = []
         self._slab_flushing = False
@@ -472,17 +468,13 @@ class CoreWorker:
         # (ray: CoreWorkerDirectActorTaskSubmitter); in-order frames plus
         # the executor's per-caller seq gate preserve call order. Falls
         # back to raylet routing when no direct endpoint is known.
-        if (cfg.direct_actor_calls and spec.actor_id is not None
-                and not spec.actor_creation):
+        if spec.actor_id is not None and not spec.actor_creation:
             self._submit_stage[spec.task_id] = "actor_enqueued"
             self._actor_direct_enqueue(spec)
             return
         # Tick-batched submission: a burst of .remote() calls lands on the
         # io loop as one inbox drain; buffer and ship ONE submit_batch
-        # frame (same discipline as the GCS pubsub outbox). Actor tasks
-        # ride the same buffer: the buffer is FIFO and the raylet enqueues
-        # a batch's actor tasks synchronously in spec order, so per-actor
-        # call order survives.
+        # frame (same discipline as the GCS pubsub outbox).
         self._submit_stage[spec.task_id] = "batch_buffered"
         self._submit_buf.append(spec)
         if not self._submit_flushing:
@@ -549,13 +541,9 @@ class CoreWorker:
         self._submit_flushing = False
         if not batch:
             return
+        # the raylet acks frame ACCEPTANCE and schedules in the background:
+        # failures surface via the owner's task_result stream + task events
         payload = {"specs": batch}
-        if cfg.submit_ack_mode == "batch":
-            # fire-and-forget lane: the raylet acks frame ACCEPTANCE and
-            # schedules in the background; per-task failures surface via
-            # the owner's task_result stream + task events, so this await
-            # no longer spans per-spec scheduling
-            payload["ack"] = "batch"
         try:
             # retried with backoff; the idem token is keyed on the FULL
             # frame (first, last, len): a frame is identified by its exact
@@ -2224,8 +2212,6 @@ class CoreWorker:
     def _arena_put(self, oid: ObjectID,
                    sv: serialization.SerializedValue,
                    callsite: Optional[str] = None) -> bool:
-        if self._slab_writer is None:
-            return False
         if self._slab_try_put(oid, sv, callsite):
             return True
         need = slab_arena.entry_size(len(sv.metadata), sv.total_data_len)
@@ -3176,8 +3162,7 @@ class CoreWorker:
         # reader mappings + flock fds, index mmap) — a long-lived process
         # cycling init()/shutdown() must not pin dead sessions' shm pages
         try:
-            if self._slab_writer is not None:
-                self._slab_writer.close()
+            self._slab_writer.close()
             slab_arena.drop_view(self.store_dir)
         except Exception:
             pass

@@ -127,10 +127,8 @@ def init(
         )
         global_worker.core_worker = cw
         global_worker.mode = "driver"
-        # both gates must agree: the init() kwarg and the config flag
-        # (RAY_TPU_LOG_TO_DRIVER=0 kills streaming cluster-wide without
-        # touching code; with no subscribers, raylets skip tailing too)
-        if log_to_driver and cfg.log_to_driver:
+        # with no subscribers, raylets skip tailing too
+        if log_to_driver:
             _subscribe_worker_logs(cw)
         # local usage snapshot (reference: usage_lib's session report;
         # this build never phones home — see usage_lib docstring)

@@ -100,9 +100,9 @@ def _build_app():
         """Object lifecycle rows from the memory observatory (state,
         size, owner, refs, locations, creation callsite). The bare GCS
         directory is the fallback BOTH when the memview scrape fails
-        and when it has no rows — a native-store cluster
-        (slab_arena=0) reports workers but no store ledger, and an
-        empty lifecycle listing must not mask live directory entries."""
+        and when it has no rows: an empty lifecycle listing (every
+        accounting report still in flight) must not mask live directory
+        entries."""
         limit = request.query.get("limit")
         limit = int(limit) if limit else 500
 

@@ -399,15 +399,11 @@ class _RouterState:
 
     def request_chains(self, args, kwargs) -> list:
         """Prefix chain hashes for a request, when this deployment is
-        prefix-affine (replicas reported digests) and the LLM path is
-        enabled. [] means: route plain p2c."""
+        prefix-affine (replicas reported digests). [] means: route plain
+        p2c."""
         if not self.prefix_index or self.prefix_block_tokens <= 0:
             return []
         try:
-            from ray_tpu._private.config import GLOBAL_CONFIG
-
-            if not GLOBAL_CONFIG.serve_llm_enabled:
-                return []
             from ray_tpu.serve.llm import prefix as prefix_mod
 
             tokens = prefix_mod.extract_tokens(args, kwargs)

@@ -307,9 +307,6 @@ class LLMServer:
                  use_arena: bool = True):
         from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 
-        if not cfg.serve_llm_enabled:
-            raise RuntimeError(
-                "LLM serving is disabled (serve_llm_enabled=0)")
         kv_dim = int(kv_dim or cfg.serve_llm_kv_dim)
         self.pool = KVPool(
             page_tokens=int(page_tokens or cfg.serve_llm_page_tokens),

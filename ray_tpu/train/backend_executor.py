@@ -239,11 +239,6 @@ class BackendExecutor:
             cause = outcome["cause"]
             detected = outcome["detected"]
             failures.labels(cause=cause).inc()
-            if not GLOBAL_CONFIG.train_recovery_enabled:
-                return self._result(
-                    error=outcome["error"]
-                    or TrainingFailedError(f"gang failure: {cause}")
-                )
             if cause != "drain":
                 # drain (spot preemption with a clean checkpoint handoff)
                 # is free; real failures spend the budget. max_failures<0
@@ -395,7 +390,7 @@ class BackendExecutor:
         Order matters: plant the collective abort marker FIRST so
         surviving ranks blocked in a rendezvous fail over with
         ``CollectiveWorldChangedError`` within a poll interval instead of
-        sitting out collective_timeout_s while we tear down around them.
+        sitting out the op's timeout while we tear down around them.
         """
         from ray_tpu.util import collective as col
 

@@ -1,7 +1,7 @@
-"""Native (C++) object store tests: round-trips, Python interop, eviction.
-
-Analog of ray: src/ray/object_manager/plasma/test/ — exercised through the
-ctypes boundary instead of gtest.
+"""The native (C++) one-file object writer and reader, and the log store:
+round-trips and Python interop, exercised through the ctypes boundary;
+and the node's store (object_store.LocalObjectStore) held to what the
+native store's tests held: pinning against eviction, an arena-resident put.
 """
 
 import os
@@ -47,19 +47,25 @@ def test_python_write_native_read(tmp_path):
     assert native_store.object_exists(d, _oid(2).hex())
 
 
-def test_native_store_eviction_and_pinning(tmp_path):
-    d = str(tmp_path)
-    store = native_store.NativeLocalObjectStore(d, capacity_bytes=4096)
-    blob = b"x" * 1000
+def test_store_eviction_and_pinning(tmp_path):
+    """No spill target: a pinned object survives eviction; all pinned and
+    full raises ObjectStoreFullError."""
+    capacity = 1 << 20
+    store = object_store.LocalObjectStore(str(tmp_path), capacity)
+    blob = b"x" * (300 * 1024)
     for i in range(3):
         store.put(_oid(i + 1), b"", [blob], len(blob))
-    assert store.used_bytes() <= 4096
+    assert store.used_bytes() <= capacity
     store.pin(_oid(3))
     # two more puts force eviction of the oldest unpinned objects
     store.put(_oid(4), b"", [blob], len(blob))
     store.put(_oid(5), b"", [blob], len(blob))
     assert store.contains(_oid(3))  # pinned survived
-    assert store.used_bytes() <= 4096
+    assert not store.contains(_oid(1)) and not store.contains(_oid(2))
+    assert store.used_bytes() <= capacity
+    buf = store.get(_oid(3))
+    assert bytes(buf.data) == blob
+    buf.release()
     ids = {o.hex() for o in store.object_ids()}
     assert _oid(3).hex() in ids
 
@@ -67,7 +73,7 @@ def test_native_store_eviction_and_pinning(tmp_path):
     for oid in store.object_ids():
         store.pin(oid)
     with pytest.raises(object_store.ObjectStoreFullError):
-        store.put(_oid(9), b"", [b"y" * 4000], 4000)
+        store.put(_oid(9), b"", [b"y" * (900 * 1024)], 900 * 1024)
 
 
 def test_native_store_zero_copy_writable_buffer(tmp_path):
@@ -80,14 +86,21 @@ def test_native_store_zero_copy_writable_buffer(tmp_path):
     buf.release()
 
 
-def test_cluster_uses_native_store(tmp_path):
-    """End-to-end: put/get through the runtime rides the native store."""
+def test_cluster_put_is_arena_resident(tmp_path):
+    """End-to-end: a driver's put past the inline threshold lands in the
+    slab arena (no one-file object), and get reads it back from there."""
     import ray_tpu
+    from ray_tpu._private import slab_arena
+    from ray_tpu._private.worker import global_worker
 
     ray_tpu.init(num_cpus=2)
     try:
         big = np.random.default_rng(0).standard_normal(100_000)
         ref = ray_tpu.put(big)
+        store_dir = global_worker.core_worker.store_dir
+        assert slab_arena.exists(store_dir, ref.binary())
+        assert not os.path.exists(
+            object_store._obj_path(store_dir, ObjectID(ref.binary())))
         out = ray_tpu.get(ref, timeout=30)
         np.testing.assert_array_equal(out, big)
     finally:

@@ -17,23 +17,16 @@ from ray_tpu._private.ids import ObjectID
 from ray_tpu._private.object_store import LocalObjectStore
 
 
-def _mk_store(tmp_path, capacity, native=False):
+def _mk_store(tmp_path, capacity):
     store_dir = str(tmp_path / "store")
     spill_dir = str(tmp_path / "spill")
-    if native:
-        from ray_tpu._private import native_store
-
-        if not native_store.available():
-            pytest.skip("native store unavailable")
-        return native_store.NativeLocalObjectStore(store_dir, capacity, spill_dir)
     return LocalObjectStore(store_dir, capacity, spill_dir)
 
 
-@pytest.mark.parametrize("native", [False, True])
-def test_store_spills_pinned_objects_past_capacity(tmp_path, native):
+def test_store_spills_pinned_objects_past_capacity(tmp_path):
     """Filling the store to 2x capacity with PINNED objects spills instead
     of raising; spilled objects remain addressable and restore on get."""
-    store = _mk_store(tmp_path, capacity=1 << 20, native=native)
+    store = _mk_store(tmp_path, capacity=1 << 20)
     payload = b"x" * (300 * 1024)
     oids = []
     for _ in range(8):  # ~2.4MB total vs 1MB capacity
@@ -53,9 +46,8 @@ def test_store_spills_pinned_objects_past_capacity(tmp_path, native):
         buf.release()
 
 
-@pytest.mark.parametrize("native", [False, True])
-def test_store_delete_removes_spilled_file(tmp_path, native):
-    store = _mk_store(tmp_path, capacity=256 * 1024, native=native)
+def test_store_delete_removes_spilled_file(tmp_path):
+    store = _mk_store(tmp_path, capacity=256 * 1024)
     payload = b"y" * (200 * 1024)
     a, b = ObjectID.from_random(), ObjectID.from_random()
     store.put(a, b"", [payload], len(payload))

@@ -1,13 +1,14 @@
-"""v2 wire-frame codec: out-of-band buffer table round-trips, size
-enforcement (both directions), truncation rejection, v1<->v2 preamble
-negotiation, and the zero-copy send guarantee (payload buffers reach the
-transport by reference, never through the pickle stream).
+"""Wire-frame codec: out-of-band buffer table round-trips, the CRC over
+the head, size enforcement (both directions), truncation rejection, the
+handshake (one preamble, one ack, no downgrade), and the zero-copy send
+guarantee (payload buffers reach the transport by reference, never
+through the pickle stream).
 
 Pure rpcio/serialization unit tests — no cluster.
 """
 
 import asyncio
-import pickle
+import hashlib
 
 import numpy as np
 import pytest
@@ -21,7 +22,7 @@ from ray_tpu._private.rpcio import (
     Finalized,
     RpcError,
     RpcServer,
-    _decode_v2,
+    _decode_frame,
     connect,
 )
 
@@ -43,23 +44,19 @@ class FakeWriter:
         self.closed = True
 
 
-def _conn(version=2):
-    return Connection(None, FakeWriter(), name="test", version=version)
+def _conn():
+    return Connection(None, FakeWriter(), name="test")
 
 
-def _roundtrip(payload, version=2):
+def _roundtrip(payload):
     """Encode one frame, then decode it the way the recv loop would."""
-    conn = _conn(version)
+    conn = _conn()
     parts = conn._encode_frame(7, KIND_REQ, "m", payload)
     wire = b"".join(bytes(p) for p in parts)
     total = int.from_bytes(wire[:4], "little")
     body = wire[4: 4 + total]
     assert len(body) == total, "frame length header must cover the body"
-    if version >= 3:
-        return rpcio._decode_v3(body)
-    if version == 2:
-        return _decode_v2(body)
-    return pickle.loads(body)
+    return _decode_frame(body)
 
 
 # ---------------------------------------------------------------- codec --
@@ -107,21 +104,20 @@ def test_roundtrip_fuzz_mixed():
 
 
 @pytest.mark.parametrize("nbufs", [0, 1, 3, 32])
-def test_v3_crc_roundtrip(nbufs):
+def test_crc_roundtrip(nbufs):
     arrs = [np.arange(1000 * (i + 1), dtype=np.int32) for i in range(nbufs)]
-    msg_id, kind, method, payload = _roundtrip(
-        {"arrs": arrs, "tag": "t"}, version=3)
+    msg_id, kind, method, payload = _roundtrip({"arrs": arrs, "tag": "t"})
     assert (msg_id, kind, method) == (7, KIND_REQ, "m")
     assert payload["tag"] == "t"
     for got, want in zip(payload["arrs"], arrs):
         assert np.array_equal(got, want)
 
 
-def test_v3_crc_detects_head_corruption():
+def test_crc_detects_head_corruption():
     """Any flipped byte in the CRC-covered head (count byte, table,
     envelope) must raise the typed corruption error."""
-    parts = _conn(3)._encode_frame(1, KIND_NOTIFY, "m",
-                                   {"arr": np.zeros(4096, dtype=np.uint8)})
+    parts = _conn()._encode_frame(1, KIND_NOTIFY, "m",
+                                  {"arr": np.zeros(4096, dtype=np.uint8)})
     wire = b"".join(bytes(p) for p in parts)
     body = bytearray(wire[4:])
     head_len = len(bytes(parts[0])) - 4  # head part minus the 4B length
@@ -129,9 +125,9 @@ def test_v3_crc_detects_head_corruption():
         mutated = bytearray(body)
         mutated[off] ^= 0x01
         with pytest.raises(rpcio.FrameCorruptError):
-            rpcio._decode_v3(bytes(mutated))
+            _decode_frame(bytes(mutated))
     # untouched body still decodes
-    _, _, _, payload = rpcio._decode_v3(bytes(body))
+    _, _, _, payload = _decode_frame(bytes(body))
     assert payload["arr"].nbytes == 4096
 
 
@@ -153,7 +149,7 @@ def test_frame_exactly_at_max_message_passes():
                                    {"a": np.zeros(lo, dtype=np.uint8)})
         wire = b"".join(bytes(p) for p in parts)
         assert int.from_bytes(wire[:4], "little") == (1 << 20)
-        _, _, _, payload = _decode_v2(wire[4:])
+        _, _, _, payload = _decode_frame(wire[4:])
         assert payload["a"].nbytes == lo
     finally:
         GLOBAL_CONFIG.reset()
@@ -162,11 +158,10 @@ def test_frame_exactly_at_max_message_passes():
 # ----------------------------------------------------- size enforcement --
 
 
-@pytest.mark.parametrize("version", [1, 2])
-def test_send_side_oversize_raises_with_method_and_size(version):
+def test_send_side_oversize_raises_with_method_and_size():
     GLOBAL_CONFIG.update({"rpc_max_message_bytes": 10_000})
     try:
-        conn = _conn(version)
+        conn = _conn()
         with pytest.raises(RpcError) as ei:
             conn._encode_frame(1, KIND_REQ, "push_chunks",
                                {"data": np.zeros(50_000, dtype=np.uint8)})
@@ -196,33 +191,33 @@ def test_request_nowait_oversize_leaves_no_pending_entry():
 # ---------------------------------------------------------- truncation --
 
 
-def _v2_body(payload):
+def _body(payload):
     parts = _conn()._encode_frame(1, KIND_NOTIFY, "m", payload)
     return b"".join(bytes(p) for p in parts)[4:]
 
 
 def test_truncated_buffer_table_rejected():
-    body = _v2_body({"arr": np.zeros(4096, dtype=np.uint8)})
+    body = _body({"arr": np.zeros(4096, dtype=np.uint8)})
     # claim 200 table entries in a 5-byte body
     with pytest.raises(RpcError):
-        _decode_v2(bytes([200]) + body[1:5])
+        _decode_frame(bytes([200]) + body[1:5])
 
 
 def test_buffers_exceeding_frame_rejected():
-    body = bytearray(_v2_body({"arr": np.zeros(4096, dtype=np.uint8)}))
+    body = bytearray(_body({"arr": np.zeros(4096, dtype=np.uint8)}))
     assert body[0] == 1
     # inflate the recorded buffer length past the frame end
     body[1:5] = (1 << 30).to_bytes(4, "little")
     with pytest.raises(RpcError):
-        _decode_v2(bytes(body))
+        _decode_frame(bytes(body))
 
 
 def test_empty_body_rejected():
     with pytest.raises(RpcError):
-        _decode_v2(b"")
+        _decode_frame(b"")
 
 
-# ---------------------------------------------------------- negotiation --
+# ------------------------------------------------------------ handshake --
 
 
 class EchoHandler:
@@ -238,16 +233,17 @@ class EchoHandler:
         return Finalized({"ok": True}, _rel)
 
 
-def test_v3_negotiation_and_echo():
+def test_handshake_and_echo():
     async def main():
         handler = EchoHandler()
         srv = RpcServer(handler)
         port = await srv.start()
         conn = await connect("127.0.0.1", port, name="c", retries=3)
         try:
-            assert conn.version == 3  # default: v2 framing + CRC trailer
+            # the ack is read before connect() returns: the server has
+            # accepted this connection by now
             (sconn,) = srv.connections
-            assert sconn.version == 3
+            assert not sconn.closed
             arr = np.arange(65536, dtype=np.uint8)
             reply = await conn.request("echo", {"arr": arr})
             assert np.array_equal(reply["arr"], arr)
@@ -266,93 +262,88 @@ def test_v3_negotiation_and_echo():
     asyncio.run(main())
 
 
-def test_v1_client_against_v2_server():
+class _CountingServer(RpcServer):
+    """The real server, counting the dials it was offered."""
+
+    dials = 0
+
+    async def _accept(self, reader, writer):
+        self.dials += 1
+        await super()._accept(reader, writer)
+
+
+@pytest.mark.parametrize("magic", [b"RTPU1", b"RTPU2"])
+def test_stale_preamble_is_refused(magic):
+    """A process of an older checkout opens with an older magic (and this
+    cluster's token): the server closes at the preamble, every time,
+    without an ack byte and without a connection."""
+
     async def main():
-        srv = RpcServer(EchoHandler())
+        srv = _CountingServer(EchoHandler())
         port = await srv.start()
-        conn = await connect("127.0.0.1", port, name="c", retries=3,
-                             version=1)
+        digest = hashlib.sha256(
+            rpcio.cluster_token().encode()).hexdigest().encode()
+        assert len(magic + digest) == rpcio._AUTH_LEN
         try:
-            assert conn.version == 1
-            for _ in range(100):  # no ack on v1: wait for server accept
-                if srv.connections:
-                    break
-                await asyncio.sleep(0.01)
-            (sconn,) = srv.connections
-            assert sconn.version == 1
-            arr = np.arange(4096, dtype=np.uint8)
-            reply = await conn.request("echo", {"arr": arr})
-            assert np.array_equal(reply["arr"], arr)
-        finally:
-            await conn.close()
-            await srv.stop()
-
-    asyncio.run(main())
-
-
-def test_frame_version_flag_pins_v2():
-    async def main():
-        GLOBAL_CONFIG.update({"rpc_frame_version": 2})
-        try:
-            srv = RpcServer(EchoHandler())
-            port = await srv.start()
-            conn = await connect("127.0.0.1", port, name="c", retries=3)
-            assert conn.version == 2
-            arr = np.arange(65536, dtype=np.uint8)
-            reply = await conn.request("echo", {"arr": arr})
-            assert np.array_equal(reply["arr"], arr)
-            await conn.close()
-            await srv.stop()
-        finally:
-            GLOBAL_CONFIG.reset()
-
-    asyncio.run(main())
-
-
-def test_frame_version_flag_pins_v1():
-    async def main():
-        GLOBAL_CONFIG.update({"rpc_frame_version": 1})
-        try:
-            srv = RpcServer(EchoHandler())
-            port = await srv.start()
-            conn = await connect("127.0.0.1", port, name="c", retries=3)
-            assert conn.version == 1
-            reply = await conn.request("echo", {"x": 1})
-            assert reply == {"x": 1}
-            await conn.close()
-            await srv.stop()
-        finally:
-            GLOBAL_CONFIG.reset()
-
-    asyncio.run(main())
-
-
-def test_fallback_to_v1_against_legacy_server():
-    """A pre-v2 server closes an RTPU2 preamble at the digest compare; the
-    client must redial with the v1 preamble and interoperate."""
-
-    async def main():
-        handler = EchoHandler()
-        legacy_expected = rpcio._auth_preamble(rpcio.cluster_token(), 1)
-
-        async def legacy_accept(reader, writer):
-            preamble = await reader.readexactly(rpcio._AUTH_LEN)
-            if preamble != legacy_expected:  # unknown magic: close, no ack
+            for _ in range(3):
+                reader, writer = await asyncio.open_connection(
+                    "127.0.0.1", port)
+                writer.write(magic + digest)
+                await writer.drain()
+                got = await asyncio.wait_for(reader.read(), 10)
+                assert got == b"", f"server answered a stale magic: {got!r}"
                 writer.close()
-                return
-            Connection(reader, writer, handler, name="legacy",
-                       version=1).start()
-
-        server = await asyncio.start_server(legacy_accept, "127.0.0.1", 0)
-        port = server.sockets[0].getsockname()[1]
-        conn = await connect("127.0.0.1", port, name="c", retries=5,
-                             retry_delay=0.05)
-        try:
-            assert conn.version == 1
-            reply = await conn.request("echo", {"x": 42})
-            assert reply == {"x": 42}
-        finally:
+            assert srv.dials == 3 and not srv.connections
+            # the server is unharmed: today's preamble is accepted
+            conn = await connect("127.0.0.1", port, name="c", retries=3)
+            assert await conn.request("echo", {"x": 1}) == {"x": 1}
             await conn.close()
+        finally:
+            await srv.stop()
+
+    asyncio.run(main())
+
+
+def test_wrong_token_is_refused_at_every_dial():
+    async def main():
+        srv = _CountingServer(EchoHandler())
+        port = await srv.start()
+        try:
+            with pytest.raises(rpcio.ConnectionLost) as ei:
+                await connect("127.0.0.1", port, name="c", retries=3,
+                              retry_delay=0.01, token="not-this-cluster")
+            assert "handshake refused" in str(ei.value)
+            assert "wrong cluster token" in str(ei.value)
+            assert srv.dials == 3 and not srv.connections
+        finally:
+            await srv.stop()
+
+    asyncio.run(main())
+
+
+def test_refused_handshake_does_not_downgrade():
+    """A server that closes at the preamble (another checkout's, or another
+    token's): the client sends the one preamble at every dial and raises,
+    after its retries, an error that names the handshake."""
+
+    async def main():
+        seen = []
+
+        async def refuse(reader, writer):
+            seen.append(await reader.readexactly(rpcio._AUTH_LEN))
+            writer.close()
+
+        server = await asyncio.start_server(refuse, "127.0.0.1", 0)
+        port = server.sockets[0].getsockname()[1]
+        try:
+            with pytest.raises(rpcio.ConnectionLost) as ei:
+                await connect("127.0.0.1", port, name="c", retries=4,
+                              retry_delay=0.01)
+            assert "handshake refused" in str(ei.value)
+            assert "older ray_tpu" in str(ei.value)
+            assert seen == [rpcio._auth_preamble(rpcio.cluster_token())] * 4
+            assert seen[0].startswith(b"RTPU3")  # the bytes every process opens with
+        finally:
             server.close()
             await server.wait_closed()
 
@@ -398,7 +389,7 @@ def test_1mb_numpy_send_is_zero_copy():
 
 def test_serialized_value_slot_is_zero_copy_on_send():
     """The worker inline-arg shape: ('v', metadata, sv.to_wire()) must ship
-    the value's array buffer by reference through a v2 connection."""
+    the value's array buffer by reference through a connection."""
 
     async def main():
         arr = np.arange(1 << 20, dtype=np.uint8)
@@ -420,18 +411,16 @@ def test_serialized_value_slot_is_zero_copy_on_send():
     asyncio.run(main())
 
 
-def test_bufferlist_roundtrip_v2_and_v1():
+def test_bufferlist_roundtrip():
     arr = np.arange(100_000, dtype=np.float32)
     sv = serialization.serialize({"x": arr, "y": "small"})
-    for version in (2, 1):
-        _, _, _, payload = _roundtrip(
-            {"slot": ("v", sv.metadata, sv.to_wire())}, version=version)
-        kind, meta, data = payload["slot"]
-        assert kind == "v"
-        assert isinstance(data, serialization.BufferList)
-        value = serialization.deserialize(meta, data)
-        assert value["y"] == "small"
-        assert np.array_equal(value["x"], arr)
+    _, _, _, payload = _roundtrip({"slot": ("v", sv.metadata, sv.to_wire())})
+    kind, meta, data = payload["slot"]
+    assert kind == "v"
+    assert isinstance(data, serialization.BufferList)
+    value = serialization.deserialize(meta, data)
+    assert value["y"] == "small"
+    assert np.array_equal(value["x"], arr)
 
 
 def test_bufferlist_concat_matches_to_bytes():
