@@ -8,8 +8,13 @@
   module (training)
 - afmoe: window and full attention layers over grouped heads, a gated and
   normed attention, the same routed-expert layer (training)
+- phi4flash: blocks of five kinds that hand state down the stack:
+  state-space layers (``ops.ssm.selective_scan``), window, full and cross
+  differential attention, a gated memory unit (training)
 """
 
-from ray_tpu.models import afmoe, gpt2, llama, mla_moe, moe_lm, vision
+from ray_tpu.models import (afmoe, gpt2, llama, mla_moe, moe_lm, phi4flash,
+                            vision)
 
-__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "vision"]
+__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "phi4flash",
+           "vision"]

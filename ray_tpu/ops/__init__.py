@@ -1,5 +1,6 @@
-"""ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, expert
-layers and the vocabulary's loss."""
+"""ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, the
+selective scan of state-space layers, expert layers and the vocabulary's
+loss."""
 
 from ray_tpu.ops.attention import (
     attention_reference,
@@ -8,10 +9,12 @@ from ray_tpu.ops.attention import (
     online_block_update,
 )
 from ray_tpu.ops.ring_attention import ring_attention, ring_self_attention
-from ray_tpu.ops import moe, xent
+from ray_tpu.ops.ssm import selective_scan
+from ray_tpu.ops import moe, ssm, xent
 
 __all__ = [
     "moe",
+    "ssm",
     "xent",
     "attention_reference",
     "finalize_flash",
@@ -19,4 +22,5 @@ __all__ = [
     "online_block_update",
     "ring_attention",
     "ring_self_attention",
+    "selective_scan",
 ]

@@ -1055,10 +1055,10 @@ def unmapped_mesh_axes(x) -> tuple:
 # none). A window that is no whole number of blocks (4096 tokens under 1024
 # keys: "looped") reads 4.27 and 7.87 ms against 4.42 and 6.63 under 2048.
 _FLASH_MIN_SEQ = 512
-# (key width, value width) of a head the kernel was measured at. (192, 128),
-# latent attention's per-head form (128 + 64 rotary dimensions against 128):
-# PERF.md section 6, PR 31
-_FLASH_HEAD_DIMS = ((64, 64), (128, 128), (192, 128))
+# (key width, value width) of a head the kernel was measured at. (192, 128):
+# latent attention's per-head form, PERF.md section 6, PR 31. (64, 128): a
+# map of a differential layer on its pair's values, PR 48 (this file's end)
+_FLASH_HEAD_DIMS = ((64, 64), (64, 128), (128, 128), (192, 128))
 
 
 def auto_attention(q, v=None) -> str:
@@ -1130,11 +1130,17 @@ def causal_self_attention(q, k, v, attention: str = "auto",
 # pass copies each once more into the model's [B, T, H, 64], 0.34 ms a layer
 # on the chip (PERF.md section 6, PR 45).
 _REMAT_NAMES = ("flash_out", "flash_lse")
+# and of the selective scan (``ops/ssm.py`` names them in its forward rule):
+# its output [B, T, channels] in the compute dtype and the state each chunk
+# starts from, [B, T / chunk, states, channels] float32. A block without a
+# scan has no such name, and its program is the one it was.
+SCAN_REMAT_NAMES = ("ssm_scan_out", "ssm_scan_bounds")
 
 
 def remat_policy():
     """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
-    may run the kernel: keep the kernel's output and log-sum-exp (per layer
+    may run a kernel of ``ray_tpu/ops``: keep the selective scan's output and
+    boundary states, and the flash kernel's output and log-sum-exp (per layer
     one [B, T, H, d_v] array in the compute dtype and B x H x T float32; at
     a value width of 64 the kept copy is a lane-padded [B x H, T, 64],
     nearly twice those bytes: the comment above),
@@ -1142,4 +1148,32 @@ def remat_policy():
     reruns the projections and not the forward kernel. Where the block's
     attention is not the kernel (``xla``, the scan) no such name exists,
     nothing is kept and the program is the one without a policy."""
-    return jax.checkpoint_policies.save_only_these_names(*_REMAT_NAMES)
+    return jax.checkpoint_policies.save_only_these_names(
+        *_REMAT_NAMES, *SCAN_REMAT_NAMES)
+
+
+# Keys 64 and values 128 wide (PR 48; ``benches/flash_widths.py --widths
+# 64x128 --lengths 16384 --heads 20 --kv-heads 10 --check 1``, my chip run:
+# one sequence of 16,384 tokens, 20 query heads on 10 key-value heads, a map
+# of a differential attention layer), ``flash_fwd`` and ``flash_bwd`` alone,
+# then the wall time of forward plus backward; beside it (64, 64) at the same
+# heads, of which such a layer would need four calls where it needs two of
+# these:
+#   no window:   (64, 128) 11.65 and 19.28 ms, 32.84; (64, 64) 9.58 and
+#                19.28, 30.65: the wider value costs the forward 22% and the
+#                backward nothing that these readings show. Why not is not
+#                known: two of its five matmuls (dP, dV) carry the values'
+#                width. A guess that fits, untested: the 64-wide keys'
+#                passes set its time. (64, 256) and (128, 128) at the same
+#                heads would tell; neither was run
+#   window 512:  (64, 128) 3.10 and 3.73 ms, 8.01; (64, 64) 1.99 and 3.72,
+#                6.80. 512 keys are a quarter of a 2,048-wide grid block, so
+#                all 64 blocks a head are "looped" (``grid_block_kinds``):
+#                every diagonal block walks its tiles in loops with traced
+#                bounds, and the needed pairs (8.26M a head) are 10% of the
+#                peak forward and 20% backward. Left as it is: 14 ms of a
+#                785 ms step in the one cell that has such a window.
+# Output and the three gradients against ``attention_reference`` in float32
+# there: within 0.0029 to 0.0055 of the largest entry, with and without the
+# window (bfloat16 operands). XLA's scores at these lengths are [20, 16384,
+# 16384] a map and were not tried.

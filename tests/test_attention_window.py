@@ -64,9 +64,18 @@ _CASES = {
     "window_narrower_than_a_tile": (4, 2, 128, 8, 8, 16, 16, 32, 5),
     "window_wide_keys_tiles_2x1": (4, 1, 128, 24, 16, 16, 32, 32, 32),
     "window_one_tile_a_block": (4, 1, 128, 24, 16, 32, 32, 32, 32),
+    # differential attention's calls (PR 48): values twice as wide as the
+    # keys, pairs of query heads on one pair of key-value heads; and its
+    # window layer's, a quarter of a grid block wide (512 keys of 2,048)
+    "values_twice_the_keys_one_block": (4, 2, 64, 8, 16, 16, 16, None, None),
+    "values_twice_the_keys_blocks_4x4": (4, 2, 128, 8, 16, 16, 16, 32, None),
+    "window_a_quarter_of_a_block": (4, 2, 128, 8, 16, 16, 16, 32, 8),
+    "window_a_quarter_of_a_block_tiles_2x2": (4, 2, 128, 8, 16, 16, 16, 64,
+                                              16),
 }
 _BF16 = ("grouped_blocks_4x4", "window_of_one_block",
-         "window_of_two_blocks_grouped", "window_wide_keys_tiles_2x1")
+         "window_of_two_blocks_grouped", "window_wide_keys_tiles_2x1",
+         "values_twice_the_keys_blocks_4x4", "window_a_quarter_of_a_block")
 _PARAMS = [
     pytest.param(case, impl, dtype, id=f"{case}-{impl}-{dtype.__name__}")
     for dtype, impls, cases in (
@@ -145,6 +154,24 @@ def test_grid_blocks_by_kind_under_a_window():
         0, 0, 0, 0, 4)
     pairs = 2048 * 16384 - 2048 * 2047 // 2
     assert pairs == 31_458_304 and 16384 * 16385 // 2 == 134_225_920
+    # Phi-4-mini-flash's window layer at 16,384 (PR 48): 512 keys are a
+    # quarter of a block, so every block of the 8 x 8 is walked in loops
+    for backward in (False, True):
+        assert grid_block_kinds(16384, 16384, True, backward=backward,
+                                window=512) == kinds(0, 0, 0, 0, 64)
+
+
+def test_auto_takes_the_kernel_at_keys_64_and_values_128(monkeypatch):
+    """A differential layer's maps (PR 48): keys 64 wide, the pair's values
+    128. On a TPU ``auto`` is the kernel there, as at the widths measured
+    before; a width nobody measured stays with XLA."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((1, 16384, 20, 64), jnp.bfloat16)
+    wide = lambda d: jnp.zeros((1, 16384, 10, d), jnp.bfloat16)
+    assert attention.auto_attention(q, wide(128)) == "flash"
+    assert attention.auto_attention(q, wide(64)) == "flash"
+    assert attention.auto_attention(q, wide(256)) == "xla"
+    assert (64, 128) in attention._FLASH_HEAD_DIMS
 
 
 def test_a_windowed_grouped_call_is_named_and_recorded():

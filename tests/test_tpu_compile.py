@@ -574,3 +574,92 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                model["num_experts_published"], 2 * 1024, 2 * 6144}
     assert model["vocab_size"] not in others
     print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
+
+
+def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``phi-4-mini-flash.step-one-seq``
+    (layers 0, 1, 16, 17, 18 and 19 of the published 32 at the published
+    widths, an eighth of the vocabulary), its step at 1 x 16,384 with
+    recomputation, as the benchmark's family builds it: the plan stays under
+    the 14.5 GiB that ISSUE 48 set for this length (11.55 read) with the
+    state's 8.37 GB as arguments. The two state-space layers' scans are the
+    Pallas kernels, once forward and once backward each
+    (``ops.attention.remat_policy`` keeps the output and the boundary
+    states); each of the three differential layers is two flash calls
+    forward and two backward, 20 query heads on 10 key-value heads with keys
+    64 and values 128 wide, the window layer's named after its 512 keys.
+    Each traced call wrote its record into the runtime's ring: the scan's
+    geometry, the 8 x 8 grid blocks a head (under the window all walked in
+    loops: 512 keys are a quarter of a block), and the kinds of layer the
+    model built. No array is shaped like a [T, T] score matrix or like every
+    state of the recurrence ([T, channels, states])."""
+    from ray_tpu._private import steptrace
+
+    worker, model, traffic = _cut_cell("phi-4-mini-flash.step-one-seq")
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (1, 16384)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = built.step.lower(
+            params, opt_state, {"input_ids": ids, "labels": ids})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {"attn/grid_blocks", "ssm/scan",
+                            "model/layer_kinds"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "ssm": 2, "window": 1, "full": 1, "gmu": 1, "cross": 1, "layers": 6,
+        "published_layers": 32, "hands_memory": 16, "hands_keys_values": 17}
+    assert {e["backward"] for e in by_name["ssm/scan"]} == {0, 1}
+    for e in by_name["ssm/scan"]:
+        assert e == {"channels": 5120, "states": 16, "tokens": seq,
+                     "chunk": 128, "chunks": 128, "backward": e["backward"],
+                     "boundary_bytes": 128 * 16 * 5120 * 4}
+    by_window = {512: (0, 0, 0, 0, 64), 0: (28, 8, 0, 28, 0)}
+    assert {(e["window"], e["backward"])
+            for e in by_name["attn/grid_blocks"]} == {
+        (w, b) for w in by_window for b in (0, 1)}
+    for e in by_name["attn/grid_blocks"]:
+        whole, diagonal, trailing, dead, looped = by_window[e["window"]]
+        assert e == {
+            "whole": whole, "diagonal": diagonal, "trailing": trailing,
+            "dead": dead, "looped": looped, "queries": seq, "keys": seq,
+            "backward": e["backward"], "window": e["window"], "heads": 20,
+            "kv_heads": 10}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 697_094_272
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?((?:flash|ssm_scan)_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd": 4, "flash_bwd": 4, "flash_fwd_w512": 2,
+                     "flash_bwd_w512": 2, "ssm_scan_fwd": 2,
+                     "ssm_scan_bwd": 2}
+    assert "bf16[20,16384,64]" in text and "bf16[10,16384,128]" in text
+    assert "f32[1,128,16,5120]" in text          # the boundary states
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+        assert not (seq in dims and 5120 in dims and 16 in dims), dims
+    # the vocabulary's slice equals no other dimension of the program: what
+    # ``loss_head_ms`` reads as vocabulary-wide is the head and the embedding
+    others = {model[k] for k in ("hidden_size", "intermediate_size",
+                                 "sliding_window", "dt_rank")}
+    others |= {seq, seq * 16, 5120, 2 * 5120, 2 * 10240, 1280, 160 + 32,
+               20 * 128, 2048}
+    assert model["vocab_size"] == 25008 and 25008 not in others
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
