@@ -4,8 +4,13 @@ decides from. Run on the chip; prints one JSON line a shape, with the kinds
 of grid block a head of the kernel's call has (``grid_block_kinds``:
 ``looped`` 0 where every block is walked in straight-line code) and, from a
 profiler trace of three more calls, the device time of one ``flash_fwd``
-and one ``flash_bwd`` alone (``flash_*_kernel_ms``: the wall times hold the
-transposes round the kernels and the sum of dQ's partials too).
+and one ``flash_bwd`` alone (``flash_*_kernel_ms``), what the wall time of
+forward plus backward holds besides them (``round_kernels_ms``: the V^T, K^T,
+O^T and dQ^T transposes, ``delta`` and dQ's rounding; until PR 50 the sum of
+dQ's float32 partials too) and the bytes of dQ that the backward call writes
+(``dq_written_bytes``: its first output, as the traced call declares it; since
+PR 50 one float32 [B x H, d, T] sum where a head has several blocks of keys,
+where there was one such array a block of keys).
 
     python benches/flash_widths.py --widths 192x128 --lengths 1024,8192
     python benches/flash_widths.py --widths 128x128 --lengths 16384 \
@@ -87,6 +92,23 @@ def main():
         return {f"flash_{k}_kernel_ms": round(sum(ns) / len(ns) / 1e6, 3)
                 for k, ns in found.items()}
 
+    def dq_written_bytes(fn, *xs):
+        """Bytes of the first output of the traced ``flash_bwd`` call; None
+        where the path runs no kernel (off the chip)."""
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if (eqn.primitive.name == "pallas_call"
+                        and eqn.params["name"].startswith("flash_bwd")):
+                    dq = eqn.outvars[0].aval
+                    found.append(dq.size * dq.dtype.itemsize)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*xs).jaxpr)
+        return found[0] if found else None
+
     def against_reference(q, k, v):
         """Largest |kernel - reference| over the largest |reference| entry,
         for the output and the gradients of q, k and v under a seeded
@@ -163,7 +185,13 @@ def main():
                     for name, fn in fns.items():
                         line[path + name] = round(timed(fn, q, k, v), 3)
                     if path == "flash":
-                        line.update(kernel_ms(fns["_fwd_bwd_ms"], q, k, v))
+                        kernels = kernel_ms(fns["_fwd_bwd_ms"], q, k, v)
+                        line.update(kernels, dq_written_bytes=dq_written_bytes(
+                            fns["_fwd_bwd_ms"], q, k, v))
+                        if kernels:
+                            line["round_kernels_ms"] = round(
+                                line["flash_fwd_bwd_ms"]
+                                - sum(kernels.values()), 3)
                 except Exception as e:  # a path that does not fit or lower
                     line[path + "_error"] = str(e)[:200]
             if args.check:

@@ -184,12 +184,14 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int,
 # branches on the place and walks each kind with constant bounds
 # (``_walk_by_kind``): a diagonal block as a lone block is walked, a whole
 # block's rows as one loop whose body is a row's tiles in straight-line
-# code (``_walk_rows``), a dead block not at all (it still owes the
-# scratch's start, the outputs' write and zeros for its dQ^T partial), and
-# the index maps clamp dead blocks to the last live one, which the pipeline
-# does not fetch again. Only a masked call that is no self-attention in
-# square blocks (lengths that differ, ``res_q != res_k``) keeps loops with
-# bounds computed from the grid position.
+# code (``_walk_rows``), a dead block not at all (in the forward it still
+# owes the scratch's start and the outputs' write; in the backward nothing:
+# dQ^T is summed over a head's blocks of keys by the kernel itself, in HBM,
+# by the live steps alone, ``_bwd_kernel``), and the index maps clamp dead
+# blocks to the last live one, which the pipeline does not fetch again. Only
+# a masked call that is no self-attention in square blocks (lengths that
+# differ, ``res_q != res_k``) keeps loops with bounds computed from the grid
+# position.
 #
 # Who reaches which walk, and what a loop costs (my chip runs, PRs 25 and
 # 38; PERF.md section 6). One block a head is every call up to 2048 tokens,
@@ -218,6 +220,19 @@ _MAX_RESIDENT = 2048
 # backward's five matmuls pay more for the dead half of a diagonal tile
 # than for the loop steps that smaller tiles add.
 _FWD_TILES, _BWD_TILES = (512, 512), (256, 256)
+# The most bytes of a block of queries' float32 dQ^T sum that one DMA moves,
+# in a backward step over several blocks of keys (``_bwd_kernel``: a copy is
+# whole rows of tiles, at least one). Read on the chip (PERF.md section 6,
+# PR 50; ``flash_bwd`` alone at the three cells' shapes, ms, by rows of 256
+# queries a copy: 1 / 2 / 4 / all 8; the parent's, which wrote partials,
+# last): keys 192 wide at 8,192 tokens (a row 192 KiB) 23.49 / 23.48 / 23.71
+# / 24.48, parent 23.62; 128 wide at 16,384 under a window of 2,048 (a row
+# 128 KiB) 9.65 / 9.23 / 9.10 / 9.27, parent 8.92, and with no window 33.91
+# / 33.59 / 33.51 / 34.20, parent 34.15; 64 wide (a row 64 KiB) 19.51 /
+# 19.32 / 19.26 / 19.23, parent 19.28. A copy costs its start and its wait
+# (about 30 ns each: 32 of them a live step are 1 us), and a large one
+# stands in the way of the pipeline's own.
+_COPY_BYTES = 512 * 1024
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
@@ -331,15 +346,18 @@ def _walk(step, carry, n_full, n_live, n_start=None, n_clear=None):
     return lax.fori_loop(full, n_live, edged, carry)
 
 
-def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
-               causal: bool, alike_loop: bool, window: Optional[int] = None):
-    """``row(j, n_full, n_live)`` for each of a grid block's ``n_q`` q tiles,
-    the bounds from ``_live_tiles``, and under a window ``row(j, n_full,
-    n_live, n_start, n_clear)`` with those of ``_window_tiles``. With
-    ``alike_loop``, rows whose bounds are constants, the same for all and
-    leave no tile masked (a whole grid block's, a dead one's) are one loop
-    over ``j``, a row a step: the row's tiles stay straight-line code and
-    the kernel's size stays a row's."""
+def _rows(rel0, n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
+          alike_loop: bool, window: Optional[int] = None,
+          longest_first: bool = False):
+    """(bounds, order) of a grid block's ``n_q`` rows of tiles: row ``j``'s
+    bounds from ``_live_tiles``, under a window with those of
+    ``_window_tiles`` after them, and the order in which ``_walk_rows``
+    walks the rows: None for one loop over ``j``, which with ``alike_loop``
+    rows are whose bounds are constants, the same for all and leave no tile
+    masked (a whole grid block's, a dead one's); else their indices, with
+    ``longest_first`` from the last row up where bounds are constants and
+    leave the last row more live tiles than the first (a diagonal
+    block's)."""
     def bounds_of(j):
         rel = rel0 + j * block_q
         live = _live_tiles(rel, block_q, block_k, n_k, causal)
@@ -352,10 +370,30 @@ def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
     if (alike_loop and isinstance(n_full, int) and n_full == n_live
             and bounds[0][2:] in ((), (0, 0))
             and bounds.count(bounds[0]) == n_q > 1):
-        lax.fori_loop(0, n_q, lambda j, _: row(j, *bounds[0]), None)
+        return bounds, None
+    # live tiles of a row: n_live, less n_start under a window
+    tiles = lambda b: b[1] - sum(b[2:3])
+    if (longest_first and all(isinstance(n, int) for b in bounds for n in b)
+            and tiles(bounds[-1]) > tiles(bounds[0])):
+        return bounds, range(n_q - 1, -1, -1)
+    return bounds, range(n_q)
+
+
+def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
+               causal: bool, alike_loop: bool, window: Optional[int] = None,
+               longest_first: bool = False):
+    """``row(j, n_full, n_live)`` for each of a grid block's ``n_q`` rows of
+    tiles, under a window ``row(j, n_full, n_live, n_start, n_clear)``, in
+    the order ``_rows`` gives: rows alike as one loop over ``j``, a row a
+    step, so that the row's tiles stay straight-line code and the kernel's
+    size stays a row's."""
+    bounds, order = _rows(rel0, n_q, n_k, block_q, block_k, causal,
+                          alike_loop, window, longest_first)
+    if order is None:
+        lax.fori_loop(0, len(bounds), lambda j, _: row(j, *bounds[0]), None)
         return
-    for j, live in enumerate(bounds):
-        row(j, *live)
+    for j in order:
+        row(j, *bounds[j])
 
 
 _KINDS = ("whole", "diagonal", "trailing", "dead", "looped")
@@ -406,7 +444,7 @@ def _grid_kinds(nq: int, nk: int, res_q: int, res_k: int, offset: int,
 
 
 def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
-                  window: Optional[int] = None, dead_walks: bool = True):
+                  window: Optional[int] = None, live=None):
     """``walk(rel0)`` for this grid block, with ``rel0`` a Python integer
     wherever the block's kind fixes which tiles are live: a whole, a
     diagonal, a trailing and a dead block each get a branch of their own
@@ -418,10 +456,16 @@ def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
     ``rel0``. ``kinds`` are the kinds the call's grid holds
     (``_grid_kinds``), ``res`` its resident keys, ``res_q`` its resident
     queries: no branch is made for a kind that is absent, and none at all
-    where there is one kind. Without ``dead_walks`` a block that the mask
-    leaves nothing of is not walked at all."""
-    if isinstance(rel0, int) or (dead_walks and kinds == ("looped",)):
+    where there is one kind. Given ``live``, whether the mask leaves this
+    block anything (the backward's), a block it leaves nothing of is not
+    walked at all; without it (the forward's) such a block is walked as a
+    dead one."""
+    if isinstance(rel0, int):
         return walk(rel0)
+    if kinds == ("looped",):
+        if live is None:
+            return walk(rel0)
+        return pl.when(live)(functools.partial(walk, rel0))
     if window is None:
         straight = {"whole": (rel0 + 1 >= res, res - 1),
                     "diagonal": (rel0 == 0, 0),
@@ -433,13 +477,10 @@ def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
                     "trailing": (rel0 == window, window),
                     "dead": ((rel0 + res_q - 1 < 0)
                              | (rel0 - (res - 1) >= window), -res)}
-    if kinds == ("looped",):
-        return pl.when(jnp.logical_not(straight["dead"][0]))(
-            functools.partial(walk, rel0))
     if len(kinds) == 1:
         return walk(straight[kinds[0]][1])
     for kind in kinds:
-        if dead_walks or kind != "dead":
+        if live is None or kind != "dead":
             here, rel = straight[kind]
             pl.when(here)(functools.partial(walk, rel))
 
@@ -507,17 +548,35 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
-                dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                causal: bool, block_q: int, block_k: int, offset: int,
-                static: bool, kinds, window: Optional[int], nq: int,
-                group: int, dead_walks: bool):
+                dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *sums,
+                sm_scale: float, causal: bool, block_q: int, block_k: int,
+                offset: int, static: bool, kinds, window: Optional[int],
+                nq: int, group: int):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three. The last grid axis walks the ``nq`` blocks of queries of each of
     the ``group`` query heads that read this key-value head, one head after
-    another, so dK and dV gather the whole group in the scratch. Without
-    ``dead_walks`` a block the mask leaves nothing of is skipped: its dQ^T
-    partial has no place (``_flash_pallas_bwd_kernel``)."""
+    another, so dK and dV gather the whole group in the scratch.
+
+    One block of keys a head: ``dqt_ref`` is this block of queries' place
+    in VMEM and a row of tiles writes its dQ^T there. Several: a block of
+    queries comes back once for every block of keys, never on consecutive
+    steps, so its float32 sum lives in HBM (``dqt_ref`` is the whole array)
+    and ``sums`` are two (d, resident queries) buffers cut into the pieces
+    that one copy moves (rows of tiles up to ``_COPY_BYTES``), two rows of
+    DMA semaphores and a flag. A live step starts the fetch of what the
+    blocks of keys before it left, piece by piece in the order in which it
+    walks its rows (the longest first); the first row of a piece waits for
+    it when its own tiles are computed, every row stores its dQ^T added to
+    what was fetched, and the last row of a piece starts the piece's way
+    back: the copies trickle through the step, and a row's has had every
+    row's time before it. The first block of keys that a block of queries
+    sees fetches nothing and assigns, so nothing is filled with zeros
+    first; a step the mask leaves nothing of does none of this. A piece's
+    way back is waited for where its buffer is next filled, a live step
+    later, or at the last step of this block of keys (``pending`` says
+    whether one is under way): no two steps of one block of keys touch the
+    same block of queries, so nothing reads a sum before it has landed."""
     ki, step_q = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
     qi = step_q if group == 1 else step_q % nq
@@ -527,11 +586,47 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
     fold = _scale_folds(q_ref.dtype, sm_scale)
     scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
                                block_k=block_k, window=window)
+    live = None
+    if sums:
+        nk = pl.num_programs(1)
+        sum_scr, had_scr, sems, pending = sums
+        first_k = (0 if window is None else
+                   _first_live_k(qi, res_q, res_k, offset, nk, window))
+        last_k = (_last_live_k(qi, res_q, res_k, offset, nk) if causal
+                  else nk - 1)
+        live, adds = (ki >= first_k) & (ki <= last_k), ki != first_k
+        head = pl.program_id(0) * group + step_q // nq
+        copies, _, wide = sum_scr.shape
+        rows_a_copy = wide // block_q
+
+        def place(c):
+            return dqt_ref.at[head, :,
+                              _tile(qi * copies + c, wide, nq * copies)]
+
+        def fetch(c):
+            """What the blocks of keys before left of piece ``c``."""
+            return pltpu.make_async_copy(place(c), had_scr.at[c],
+                                         sems.at[0, c])
+
+        def write(c):
+            """Piece ``c``'s sum, back to its place (waited for by any
+            step: only the semaphore and the bytes are the copy's)."""
+            return pltpu.make_async_copy(sum_scr.at[c], place(c),
+                                         sems.at[1, c])
+
+        def where(here, do_this):
+            """``do_this`` now, or in a loop where its counter says."""
+            if not isinstance(here, bool):
+                pl.when(here)(do_this)
+            elif here:
+                do_this()
 
     @pl.when(step_q == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        if sums:
+            pending[0] = 0
 
     def walk(rel0):
         def row(j, *bounds):
@@ -551,20 +646,52 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 return dqt + _dot(kt_ref[:, rows], ds, _NN)  # (d, block_q)
 
             dqt = _walk(
-                step, jnp.zeros((dqt_ref.shape[0], block_q), jnp.float32),
-                *bounds)
-            dqt_ref[:, cols] = (dqt * sm_scale).astype(dqt_ref.dtype)
+                step, jnp.zeros((kt_ref.shape[0], block_q), jnp.float32),
+                *bounds) * sm_scale
+            if not sums:
+                dqt_ref[:, cols] = dqt.astype(dqt_ref.dtype)
+                return
+            # the row's piece, its place in it as a column and as the
+            # walk comes to it: rows are walked up or down
+            c = j // rows_a_copy if copies > 1 else 0
+            at = j % rows_a_copy if rows_a_copy > 1 else 0
+            nth = at if order[0] == 0 else rows_a_copy - 1 - at
+            mine = _tile(at, block_q, rows_a_copy)
 
-        _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static,
-                   window)
+            def arrived():
+                pl.when(pending[0] == 1)(write(c).wait)
+                pl.when(adds)(fetch(c).wait)
 
-    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, dead_walks)
+            where(nth == 0, arrived)
+            sum_scr[c, :, mine] = dqt + jnp.where(adds, had_scr[c, :, mine],
+                                                  0.0)
+            where(nth == rows_a_copy - 1, lambda: write(c).start())
+
+        the_rows = (rel0, n_q, n_k, block_q, block_k, causal, not static,
+                    window, bool(sums))
+        order = _rows(*the_rows)[1] or range(n_q)
+        if sums:
+            @pl.when(adds)
+            def _fetch():
+                for c in sorted(range(copies), reverse=order[0] != 0):
+                    fetch(c).start()
+
+        _walk_rows(row, *the_rows)
+        if sums:
+            pending[0] = 1
+
+    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, live)
 
     @pl.when(step_q == n_steps - 1)
     def _finalize():
         dk = dk_scr[...]
         dk_ref[...] = (dk if fold else dk * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        if sums:
+            @pl.when(pending[0] == 1)
+            def _land():
+                for c in range(copies):
+                    write(c).wait()
 
 
 def _largest_block(n: int, target: int, align: int) -> int:
@@ -591,15 +718,20 @@ def _block_sizes(q_len: int, k_len: int, block_q: Optional[int],
             _largest_block(k_len, max(_MAX_RESIDENT, block_k), block_k))
 
 
-def _compiler_params(interpret: bool, width: int):
+def _compiler_params(interpret: bool, width: int, keys_add: bool = False):
     """``width`` is the widest head dimension of the call: up to 128 lanes
     the residents fit the compiler's own 16 MiB of VMEM; past it (keys of
     192 are laid out as 256 lanes) the backward's residents take 16.5 MiB
-    at 2048 queries and keys, so the kernel asks for 32 of the chip's 128."""
+    at 2048 queries and keys, so the kernel asks for 32 of the chip's 128.
+    With ``keys_add`` (the backward over several blocks of keys) the steps
+    along the grid's second axis add to one sum in HBM, one after another:
+    that axis is no core's to split."""
     if interpret:
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",
+                             "arbitrary" if keys_add else "parallel",
+                             "arbitrary"),
         vmem_limit_bytes=32 * 2**20 if width > 128 else None)
 
 
@@ -668,14 +800,18 @@ def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
     and their counts a head written into the runtime's ring: one record a
     traced call (none a step), so a timeline says which walk a model's
     calls took (``looped`` 0: every block in straight-line code), under
-    which ``window`` (0: none) and with how many heads of queries and of
-    keys and values, the batch folded into both (``heads``,
-    ``kv_heads``)."""
+    which ``window`` (0: none), with how many heads of queries and of keys
+    and values, the batch folded into both (``heads``, ``kv_heads``), and
+    how many dQ arrays a backward call leaves for XLA to sum
+    (``dq_partials``: 0 for every shape since PR 50, the kernel sums them
+    itself; until then one a block of keys that a block of queries
+    sees)."""
     counts = _grid_kinds(nq, nk, res_q, res_k, offset, causal, window)
     steptrace.record_counters("attn/grid_blocks", {
         **counts, "queries": nq * res_q, "keys": nk * res_k,
         "backward": int(backward), "window": window or 0,
-        "heads": heads[0], "kv_heads": heads[1]})
+        "heads": heads[0], "kv_heads": heads[1],
+        "dq_partials": 0})
     return tuple(kind for kind in _KINDS if counts[kind])
 
 
@@ -701,9 +837,9 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
     if window is not None and offset:
-        # a row of key blocks that no query sees would be given a live
-        # block's dQ^T place to leave alone, and the pipeline would write
-        # it back unwritten (``_flash_pallas_bwd_kernel``)
+        # no model sends one, and the blocks' kinds, the index maps' clamps
+        # and the backward's first and last live blocks (``_first_live_k``,
+        # ``_last_live_q``) were never held to a reference at such lengths
         raise NotImplementedError(
             f"flash_attention: a window over lengths that differ ({q_len} "
             f"queries, {k_len} keys) is the scan's or the reference's")
@@ -766,11 +902,7 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                                                   block_k, _BWD_TILES)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
-    # under a window a block of queries sees ``seen`` blocks of keys at the
-    # most, and only those get a dQ^T partial
-    slots = window is not None and nk > 1
-
-    if slots:
+    if causal and nk > 1 and window is not None:
         qmap = lambda ki, qi: jnp.clip(
             qi, _first_live_q(ki, res_q, res_k, offset, nq),
             _last_live_q(ki, res_q, res_k, offset, nq, window))
@@ -797,37 +929,35 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     rowspec = pl.BlockSpec(
         (None, 1, res_q),
         lambda bi, ki, step: (head(bi, step), 0, qmap(ki, block(step))))
-    if slots:
-        # A partial's place is the number of blocks its keys lie before the
-        # last that its queries see. A dead step is given the place of the
-        # nearest live one of its row, writes nothing and so leaves that
-        # one's block as it is; a place no step writes (the first blocks of
-        # queries see fewer blocks of keys than ``seen``) holds whatever
-        # the buffer held and is left out of the sum by ``live``.
-        first, last = (
-            [_first_live_k(i, res_q, res_k, offset, nk, window)
-             for i in range(nq)],
-            [_last_live_k(i, res_q, res_k, offset, nk) for i in range(nq)])
-        seen = max(l - f + 1 for f, l in zip(first, last))
-        live = jnp.repeat(jnp.asarray(
-            [[place <= l - f for f, l in zip(first, last)]
-             for place in range(seen)]), res_q, axis=1)
-
-        def dq_block(bi, ki, step):
-            qi = qmap(ki, block(step))
-            return (_last_live_k(qi, res_q, res_k, offset, nk) - ki,
-                    head(bi, step), 0, qi)
+    if nk == 1:
+        # one block of keys: a block of queries' dQ^T is the whole of it,
+        # written where the pipeline takes it from
+        dq_shape, sums = jax.ShapeDtypeStruct((b, d, q_len), q.dtype), []
+        dqspec = pl.BlockSpec(
+            (None, d, res_q),
+            lambda bi, ki, step: (head(bi, step), 0, block(step)))
     else:
-        seen = nk
-        dq_block = lambda bi, ki, step: (ki, head(bi, step), 0, block(step))
-    # one block of keys: its dQ^T is the whole of it; several: float32
-    # partials, one per block, summed below
-    dq_dtype = q.dtype if nk == 1 else jnp.float32
+        # several: the kernel sums them in float32 in HBM, where it fetches
+        # and writes a block of queries' sum itself (``_bwd_kernel``), and
+        # the swap below rounds once. Whatever the lengths, the group, the
+        # widths and the window: the sum takes two (d, res_q) float32
+        # buffers of VMEM, as a partial's block took
+        dq_shape = jax.ShapeDtypeStruct((b, d, q_len), jnp.float32)
+        dqspec = pl.BlockSpec(memory_space=pl.ANY)
+        n_q = res_q // block_q
+        rows_a_copy = max(
+            rows for rows in range(1, n_q + 1) if n_q % rows == 0
+            and (rows == 1 or 4 * d * block_q * rows <= _COPY_BYTES))
+        pieces = (n_q // rows_a_copy, d, rows_a_copy * block_q)
+        sums = [pltpu.VMEM(pieces, jnp.float32),
+                pltpu.VMEM(pieces, jnp.float32),
+                pltpu.SemaphoreType.DMA((2, pieces[0])),
+                pltpu.SMEM((1,), jnp.int32)]
     dq_t, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, offset=offset, static=nq == nk == 1,
-            window=window, nq=nq, group=group, dead_walks=not slots,
+            window=window, nq=nq, group=group,
             kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True,
                                  window, (b, b_kv))),
         grid=(b_kv, nk, group * nq),
@@ -836,27 +966,23 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             pl.BlockSpec((None, d, res_k), lambda bi, ki, step: (bi, 0, ki)),
             dospec, rowspec, rowspec,
         ],
-        out_specs=[
-            pl.BlockSpec((None, None, d, res_q), dq_block),
-            kspec, vspec,
-        ],
+        out_specs=[dqspec, kspec, vspec],
         out_shape=[
-            jax.ShapeDtypeStruct((seen, b, d, q_len), dq_dtype),
+            dq_shape,
             jax.ShapeDtypeStruct((b_kv, k_len, d), k.dtype),
             jax.ShapeDtypeStruct((b_kv, k_len, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((res_k, d), jnp.float32),
             pltpu.VMEM((res_k, d_v), jnp.float32),
+            *sums,
         ],
-        compiler_params=_compiler_params(interpret, max(d, d_v)),
+        compiler_params=_compiler_params(interpret, max(d, d_v),
+                                         keys_add=nk > 1),
         interpret=interpret,
         name=_kernel_name("flash_bwd", window),
     )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
-    if slots:
-        dq_t = jnp.where(live[:, None, None, :], dq_t, 0.0)
-    dq_t = dq_t[0] if nk == 1 else dq_t.sum(axis=0).astype(q.dtype)
-    return jnp.swapaxes(dq_t, 1, 2), dk, dv
+    return jnp.swapaxes(dq_t.astype(q.dtype), 1, 2), dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -1018,7 +1144,8 @@ def unmapped_mesh_axes(x) -> tuple:
 # Since PR 38 (grid blocks walked by kind; ``benches/flash_widths.py``, my
 # chip run, same 16,384 tokens and 32 heads), ``flash_fwd`` and ``flash_bwd``
 # alone in a trace, then the wall time of forward plus backward with the
-# transposes round them and the sum of dQ's partials:
+# transposes round them and, until PR 50, XLA's sum of dQ's partials (past
+# 2048 tokens; what PR 50 reads at the cells' shapes stands below):
 #   192 / 128   2048: 3.51 and 6.11 ms, 13.53 (one block a head: as before)
 #               4096: 6.91 and 12.14, 24.45 (was 28.27)
 #               8192: 13.22 and 23.62, 43.29 (was 50.62; the kernels alone
@@ -1046,14 +1173,37 @@ def unmapped_mesh_axes(x) -> tuple:
 #   window 2048, 4 key-value heads (8 diagonal, 7 trailing, 49 dead):
 #               6.15 and 8.92, 17.70; the dQ partials 2 x 32 x 128 x 16,384
 #               float32 (0.5 GiB) against 8 (2.0 GiB) without a window
+#               (until PR 50: below)
 # By kind of block, from the 2048 and 8192 readings above: a diagonal block
 # 10.35 us forward and 16.5 backward, a whole block 19.45 and 34.3 (the full
 # call priced so: 20.08 and 34.96 ms, read 19.18 and 34.15); a trailing
 # block with the seven dead grid steps of its row 15.6 us forward (the dead
 # steps fetch nothing and still owe the scratch's start and the outputs'
-# write) and 21.0 backward (its dQ^T partial has a place, a dead step's
-# none). A window that is no whole number of blocks (4096 tokens under 1024
-# keys: "looped") reads 4.27 and 7.87 ms against 4.42 and 6.63 under 2048.
+# write) and 21.0 backward. A window that is no whole number of blocks (4096
+# tokens under 1024 keys: "looped") reads 4.27 and 7.87 ms against 4.42 and
+# 6.63 under 2048.
+# Since PR 50 (the backward sums dQ^T over a head's blocks of keys itself,
+# ``_bwd_kernel``; same bench, my chip runs, the parent beside the change in
+# one call, at the three cells' shapes): ``flash_bwd`` alone, what the wall
+# time of forward plus backward holds besides the two kernels
+# (``round_kernels_ms``: the V^T, K^T, O^T and dQ^T swaps, ``delta``, dQ's
+# rounding; before, XLA's sum of the partials too), that wall time, and the
+# bytes of dQ the call writes for XLA; ``flash_fwd`` unmoved throughout:
+#   192 / 128 at 8,192, 64 heads:   23.62 -> 23.48 ms, 6.51 -> 4.83, 43.35 ->
+#               41.52; 1,610.6 -> 402.7 MB
+#   128 / 128 at 16,384, 32 on 4:   34.15 -> 33.51, 4.88 -> 2.22, 58.20 ->
+#               54.91; 2,147.5 -> 268.4 MB
+#     under a window of 2,048:      8.92 -> 9.10, 2.64 -> 2.22, 17.71 -> 17.48;
+#               536.9 -> 268.4 MB (no dead step wrote zeros here before, so
+#               the kernel pays for its copies and gains nothing back)
+#   64 / 128 at 16,384, 20 on 10:   19.28 -> 19.28, 1.91 -> 1.12, 32.84 ->
+#               32.05; 671.1 -> 83.9 MB
+#     under a window of 512:        3.73 -> 3.77, 1.19 -> 1.07, 8.02 -> 7.94
+#   64 / 64 at 1,024 (one block of keys a head: the kernel's body is the
+#               parent's): 0.975 -> 0.975, 2.09 -> 2.09
+# Output and the three gradients against ``attention_reference`` in float32
+# on the chip: the same five digits as the parent at every shape (dQ within
+# 0.00285 to 0.00392 of the largest entry).
 _FLASH_MIN_SEQ = 512
 # (key width, value width) of a head the kernel was measured at. (192, 128):
 # latent attention's per-head form, PERF.md section 6, PR 31. (64, 128): a

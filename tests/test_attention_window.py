@@ -1,7 +1,9 @@
 """Grouped key-value heads and a window in the attention ops (PR 44): the
 naive reference against a mask written out by hand, the Pallas kernel in
 interpret mode and the scan against the reference, the grid's blocks by
-kind, and that a call with neither lowers to what it lowered to before."""
+kind, and that a call with neither lowers to the jaxpr held here; since PR
+50 the backward's dQ sum over several blocks of keys, which the kernel makes
+itself: one array a call, in copies of whole rows of tiles."""
 
 import functools
 import hashlib
@@ -72,6 +74,26 @@ _CASES = {
     "window_a_quarter_of_a_block": (4, 2, 128, 8, 16, 16, 16, 32, 8),
     "window_a_quarter_of_a_block_tiles_2x2": (4, 2, 128, 8, 16, 16, 16, 64,
                                               16),
+    # the backward's dQ sum over several blocks of keys (PR 50), at the
+    # three cells' groups and key widths: no window (a block of queries is
+    # assigned at block 0 and added to up to its diagonal), a window of
+    # whole blocks (assigned at its trailing block), one that is none
+    # (looped: assigned at the first block the window leaves it)
+    "dq_sum_group_1_keys_192": (2, 2, 128, 192, 128, 16, 16, 32, None),
+    "dq_sum_group_2_keys_64": (4, 2, 128, 64, 128, 16, 16, 32, None),
+    "dq_sum_group_8_keys_128": (8, 1, 128, 128, 128, 16, 16, 32, None),
+    "dq_sum_group_1_keys_192_window_of_two_blocks": (2, 2, 128, 192, 128, 16,
+                                                     16, 32, 64),
+    "dq_sum_group_2_keys_64_window_of_one_block": (4, 2, 128, 64, 128, 16,
+                                                   16, 32, 32),
+    "dq_sum_group_8_keys_128_window_of_one_block": (8, 1, 128, 128, 128, 16,
+                                                    16, 32, 32),
+    "dq_sum_group_1_keys_192_window_looped": (2, 2, 128, 192, 128, 16, 16,
+                                              32, 40),
+    "dq_sum_group_2_keys_64_window_looped": (4, 2, 128, 64, 128, 16, 16, 32,
+                                             8),
+    "dq_sum_group_8_keys_128_window_looped": (8, 1, 128, 128, 128, 16, 32,
+                                              32, 24),
 }
 _BF16 = ("grouped_blocks_4x4", "window_of_one_block",
          "window_of_two_blocks_grouped", "window_wide_keys_tiles_2x1",
@@ -128,6 +150,48 @@ def test_window_and_grouped_heads_match_reference(monkeypatch, case, impl,
     for got, wanted, like in zip(grads, want_grads, (q, k, v)):
         assert got.shape == like.shape and got.dtype == dtype
         close(got, wanted, grad_tol)
+
+
+# (query heads, key-value heads, key width, window, rows of tiles a copy):
+# 256 tokens in 4 x 4 grid blocks of 4 rows of tiles each
+_PIECES = {
+    "a_row_a_copy_group_1": (2, 2, 192, None, 1),
+    "two_rows_a_copy_group_8": (8, 1, 128, None, 2),
+    "two_rows_a_copy_group_2_window_of_a_block": (4, 2, 64, 64, 2),
+    "a_row_a_copy_group_2_window_looped": (4, 2, 64, 40, 1),
+    "two_rows_a_copy_group_1_window_of_two_blocks": (2, 2, 192, 128, 2),
+    "the_block_a_copy_group_8_window_looped": (8, 1, 128, 24, 4),
+}
+
+
+@pytest.mark.parametrize("case", _PIECES)
+def test_the_dq_sum_moves_in_pieces(monkeypatch, case):
+    """The backward's float32 dQ^T sum goes to HBM and back in copies of
+    whole rows of tiles up to ``_COPY_BYTES`` (PR 50): a row, two or the
+    whole block of queries a copy, rows walked up (whole and trailing
+    blocks, loops) and down (diagonal ones), give dQ, dK and dV as the
+    reference does."""
+    heads, kv, d, window, rows = _PIECES[case]
+    monkeypatch.setattr(attention, "_MAX_RESIDENT", 64)
+    monkeypatch.setattr(attention, "_COPY_BYTES", 4 * d * 16 * rows)
+    jax.clear_caches()
+    q, k, v, w = _operands(heads, kv, 256, d, 128)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, impl="pallas_interpret",
+        block_q=16, block_k=16)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=True,
+                                              window=window)
+    try:
+        jaxpr = jax.make_jaxpr(lambda *x: jax.vjp(flash, *x)[1](w))(q, k, v)
+        grads = jax.jit(lambda *x: jax.vjp(flash, *x)[1](w))(q, k, v)
+        want = jax.jit(lambda *x: jax.vjp(ref, *x)[1](w))(q, k, v)
+    finally:
+        jax.clear_caches()
+    # the sum's two buffers in VMEM: pieces x d x (rows x 16 queries)
+    name = "flash_bwd" if window is None else f"flash_bwd_w{window}"
+    assert _kernel_scratch(jaxpr)[name][2] == (4 // rows, d, 16 * rows)
+    for got, wanted in zip(grads, want):
+        np.testing.assert_allclose(got, wanted, atol=1e-4, rtol=1e-4)
 
 
 def test_grid_blocks_by_kind_under_a_window():
@@ -204,20 +268,98 @@ def test_a_windowed_grouped_call_is_named_and_recorded():
         assert r == {"whole": 0, "diagonal": 8, "trailing": 7, "dead": 49,
                      "looped": 0, "queries": 16384, "keys": 16384,
                      "backward": r["backward"], "window": 2048, "heads": 32,
-                     "kv_heads": 4}
-    # dQ's float32 partials: two a block of queries, not one a block of keys
-    written = []
+                     "kv_heads": 4, "dq_partials": 0}
+    # dQ^T leaves the backward call as one float32 sum a query head
+    assert _kernel_outputs(jaxpr)["flash_bwd_w2048"][0] == (
+        (32, 128, 16384), jnp.float32)
+
+
+def _kernel_eqns(jaxpr):
+    """{kernel's name: its ``pallas_call`` equation} of ``jaxpr``."""
+    found = {}
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                written.extend(v.aval.shape for v in eqn.outvars)
+                found[eqn.params["name"]] = eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jaxpr.jaxpr)
-    assert (2, 32, 128, 16384) in written
-    assert (8, 32, 128, 16384) not in written
+    return found
+
+
+def _kernel_outputs(jaxpr):
+    """{kernel's name: [(shape, dtype) of each output]}."""
+    return {name: [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
+            for name, eqn in _kernel_eqns(jaxpr).items()}
+
+
+def _kernel_scratch(jaxpr):
+    """{kernel's name: [shape of each scratch operand]}."""
+    return {name: [v.aval.shape for v in eqn.params["jaxpr"].invars[
+        -eqn.params["grid_mapping"].num_scratch_operands:]]
+        for name, eqn in _kernel_eqns(jaxpr).items()}
+
+
+def _reductions(jaxpr):
+    """Operand shapes of every reduction in ``jaxpr`` outside the kernels."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                continue
+            if eqn.primitive.name.startswith("reduce"):
+                found.extend(v.aval.shape for v in eqn.invars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+# (q, k, v shapes, window): the three cells' calls, and lengths that differ
+_BACKWARD_CALLS = {
+    "joyai_8k_keys_192": ((64, 8192, 192), (64, 8192, 192), (64, 8192, 128),
+                          None),
+    "trinity_16k_group_8": ((32, 16384, 128), (4, 16384, 128),
+                            (4, 16384, 128), None),
+    "trinity_16k_group_8_window_2048": ((32, 16384, 128), (4, 16384, 128),
+                                        (4, 16384, 128), 2048),
+    "phi4_16k_group_2_keys_64": ((20, 16384, 64), (10, 16384, 64),
+                                 (10, 16384, 128), None),
+    "phi4_16k_group_2_keys_64_window_512": ((20, 16384, 64), (10, 16384, 64),
+                                            (10, 16384, 128), 512),
+    "cross_4096_of_8192": ((8, 4096, 128), (8, 8192, 128), (8, 8192, 128),
+                           None),
+    "gpt2_one_block": ((192, 1024, 64),) * 3 + (None,),
+}
+
+
+@pytest.mark.parametrize("case", _BACKWARD_CALLS)
+def test_the_backward_call_hands_back_one_dq(case):
+    """Whatever the blocks of keys a head, the group, the widths and the
+    window (PR 50): the backward call's first output is ONE dQ^T array
+    [B x H, d, T], float32 where the kernel summed it over several blocks
+    of keys and the operands' dtype at one; no output has a leading axis of
+    partials, and XLA reduces nothing with one after it: what it reduces is
+    ``delta``'s [B x H, T, d_v] alone."""
+    *shapes, window = _BACKWARD_CALLS[case]
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *x: flash_attention(
+        *x, causal=True, window=window, impl="pallas").astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    b, t, d = q.shape
+    name = "flash_bwd" if window is None else f"flash_bwd_w{window}"
+    several = k.shape[1] > attention._MAX_RESIDENT
+    dq, dk, dv = _kernel_outputs(jaxpr)[name]
+    assert dq == ((b, d, t), jnp.float32 if several else jnp.bfloat16)
+    assert dk == (k.shape, jnp.bfloat16) and dv == (v.shape, jnp.bfloat16)
+    assert all(len(shape) == 3 for outs in _kernel_outputs(jaxpr).values()
+               for shape, _ in outs)
+    reduced = [s for s in _reductions(jaxpr) if len(s) >= 3]
+    assert reduced and set(reduced) == {(b, t, v.shape[2])}, reduced
 
 
 def test_a_window_over_lengths_that_differ_is_refused():
@@ -257,31 +399,35 @@ def test_causal_self_attention_takes_both_in_a_models_layout(monkeypatch,
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-# sha256 (first 16 digits) of the jaxpr's text at the parent of PR 44
-# (commit b403d28), forward and gradient: a call without a window and with
-# as many key-value heads lowers to what the parent lowered, the kernels'
-# names included. (q, k, v shapes, causal.)
+# sha256 (first 16 digits) of the jaxpr's text, forward and gradient, of a
+# call without a window and with as many key-value heads, the kernels' names
+# included. (q, k, v shapes, causal.) The forward's are those of the parent
+# of PR 44 (commit b403d28). The gradient's are PR 50's, whose backward sums
+# dQ^T itself over several blocks of keys; at one block a head
+# (``gpt2_1024_64``, ``batch_and_heads_1024``) they moved by the call's first
+# output alone, [B x H, d, T] where it was [1, B x H, d, T] and a slice: the
+# kernel's body there is the parent's equation for equation.
 _PARENT = {
     "gpt2_1024_64": (((192, 1024, 64),) * 3, True,
-                     "5f3323b1f1ffc1ef", "3890c4a154dfc8c0"),
+                     "5f3323b1f1ffc1ef", "17b0a9d77b23fd90"),
     "several_blocks_4096_64": (((48, 4096, 64),) * 3, True,
-                               "0e0c8415dffe7b78", "321e8b47cb4677fb"),
+                               "0e0c8415dffe7b78", "4e729f280b87332e"),
     "latent_8192_192_128": (((64, 8192, 192), (64, 8192, 192),
                              (64, 8192, 128)), True,
-                            "1c74b3b506b9de82", "a9ecd4230e6d3d94"),
+                            "1c74b3b506b9de82", "01fd634a60681405"),
     "cross_4096_8192_looped": (((8, 4096, 128), (8, 8192, 128),
                                 (8, 8192, 128)), True,
-                               "3492ea75658517fb", "8237c180cb040deb"),
+                               "3492ea75658517fb", "1e74eceabd605ac9"),
     "no_mask_4096": (((8, 4096, 128),) * 3, False,
-                     "47ba714f8f7f46f9", "9d0553e84f35987f"),
+                     "47ba714f8f7f46f9", "c6c96bfda3a1c7fb"),
     "batch_and_heads_1024": (((2, 12, 1024, 64),) * 3, True,
-                             "6118297c0a2f298f", "508cd278a20b9913"),
+                             "6118297c0a2f298f", "bbf997deeeae1289"),
 }
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "grad"])
 @pytest.mark.parametrize("case", _PARENT)
-def test_a_call_without_either_lowers_to_the_parents_jaxpr(case, backward):
+def test_a_call_without_either_lowers_to_the_held_jaxpr(case, backward):
     shapes, causal, fwd, grad = _PARENT[case]
     q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
     fn = lambda q, k, v: flash_attention(q, k, v, causal=causal,

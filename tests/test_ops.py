@@ -301,7 +301,8 @@ def test_flash_grid_block_kinds():
         assert r["values"] == {**kinds(6, 4, 6, 0), "trailing": 0,
                                "queries": 8192, "keys": 8192,
                                "backward": r["values"]["backward"],
-                               "window": 0, "heads": 64, "kv_heads": 64}
+                               "window": 0, "heads": 64, "kv_heads": 64,
+                               "dq_partials": 0}
     assert len(kernel_whiles(grad_jaxpr(4096, 8192))) == 2 * (4 + 8)
 
 

@@ -395,6 +395,16 @@ def _cut_cell(name="joyai-llm-flash.step-8k"):
     return worker, loaded["model"], loaded["traffic"]
 
 
+def _dq_census(text, heads, d, seq):
+    """Since PR 50 the backward kernel sums dQ^T over a head's blocks of
+    keys itself: each ``flash_bwd*`` call of the compiled step hands back
+    one float32 [B x H, d, T] array, and no array of the program has an
+    axis of partials before those three (until then ``f32[n, B x H, d,
+    T]``, one a block of keys, which a reduction after the call summed)."""
+    assert f"f32[{heads},{d},{seq}]" in text
+    assert not re.search(rf"f32\[\d+,{heads},{d},{seq}\]", text)
+
+
 def test_latent_attention_expert_step_fits_one_chip_at_8k(
         topo, no_compile_cache, on_tpu):
     """The cut configuration of the cell ``joyai-llm-flash.step-8k`` (layer
@@ -439,16 +449,18 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
         assert e["args"] == {
             "whole": 6, "diagonal": 4, "trailing": 0, "dead": 6, "looped": 0,
             "queries": seq, "keys": seq, "backward": e["args"]["backward"],
-            "window": 0, "heads": 64, "kv_heads": 64}
+            "window": 0, "heads": 64, "kv_heads": 64, "dq_partials": 0}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     state_bytes = 3 * 4 * sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(params))
     assert state_bytes < planned < 13.75 * 2**30
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
     text = compiled.as_text()
     assert len(re.findall(r"%flash_fwd[\w.]* = ", text)) == 6
     assert len(re.findall(r"%flash_bwd[\w.]* = ", text)) == 6
     assert "bf16[64,8192,192]" in text and "bf16[64,8192,128]" in text
+    _dq_census(text, 64, 192, seq)
     tokens, experts = batch * seq, model["n_routed_experts_published"]
     # Each of the five expert layers runs seven grouped matmuls over whole
     # row buffers tokens x k long (no pair is dropped): two forward; hidden
@@ -502,9 +514,10 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     no forward call again (``ops.attention.remat_policy``). Each traced call
     wrote its grid's blocks by kind into the runtime's ring: 8 x 8 a head,
     under the window 8 diagonal, 7 trailing, 49 dead, without it 28 whole,
-    8 diagonal, 28 dead, none walked in a loop with traced bounds. The
-    backward's float32 dQ partials are two a block of queries under the
-    window and eight (2 GiB) in the full layer. No array is shaped like a
+    8 diagonal, 28 dead, none walked in a loop with traced bounds. Each
+    backward call hands back one float32 dQ^T sum (``_dq_census``: until PR
+    50 two partials a block of queries under the window and eight, 2 GiB,
+    in the full layer). No array is shaped like a
     [T, T] score matrix. The expert layers' loops start from buffers that
     nobody filled (``_row_buffer_census``)."""
     from ray_tpu._private import steptrace
@@ -537,7 +550,7 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
             "whole": whole, "diagonal": diagonal, "trailing": trailing,
             "dead": dead, "looped": 0, "queries": seq, "keys": seq,
             "backward": e["backward"], "window": e["window"], "heads": 32,
-            "kv_heads": 4}
+            "kv_heads": 4, "dq_partials": 0}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -551,7 +564,7 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_fwd_w2048": 4,
                      "flash_bwd_w2048": 4}
     assert "bf16[32,16384,128]" in text and "bf16[4,16384,128]" in text
-    assert "f32[2,32,128,16384]" in text and "f32[8,32,128,16384]" in text
+    _dq_census(text, 32, 128, seq)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
@@ -636,7 +649,7 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
             "whole": whole, "diagonal": diagonal, "trailing": trailing,
             "dead": dead, "looped": looped, "queries": seq, "keys": seq,
             "backward": e["backward"], "window": e["window"], "heads": 20,
-            "kv_heads": 10}
+            "kv_heads": 10, "dq_partials": 0}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -650,6 +663,7 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
                      "flash_bwd_w512": 2, "ssm_scan_fwd": 2,
                      "ssm_scan_bwd": 2}
     assert "bf16[20,16384,64]" in text and "bf16[10,16384,128]" in text
+    _dq_census(text, 20, 64, seq)
     assert "f32[1,128,16,5120]" in text          # the boundary states
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
