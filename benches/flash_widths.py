@@ -5,9 +5,10 @@ of grid block a head of the kernel's call has (``grid_block_kinds``:
 ``looped`` 0 where every block is walked in straight-line code) and, from a
 profiler trace of three more calls, the device time of one ``flash_fwd``
 and one ``flash_bwd`` alone (``flash_*_kernel_ms``), what the wall time of
-forward plus backward holds besides them (``round_kernels_ms``: the V^T, K^T,
-O^T and dQ^T transposes, ``delta`` and dQ's rounding; until PR 50 the sum of
-dQ's float32 partials too) and the bytes of dQ that the backward call writes
+forward plus backward holds besides them (``round_kernels_ms``: on the
+``xla_copies`` boundary the V^T, K^T, O^T and dQ^T transposes, ``delta`` and
+dQ's rounding, until PR 50 the sum of dQ's float32 partials too; on either
+the bench's own sum of the output and its cotangent) and the bytes of dQ that the backward call writes
 (``dq_written_bytes``: its first output, as the traced call declares it; since
 PR 50 one float32 [B x H, d, T] sum where a head has several blocks of keys,
 where there was one such array a block of keys).
@@ -56,7 +57,7 @@ def main():
 
     from ray_tpu.ops.attention import (attention_reference,
                                        causal_self_attention,
-                                       grid_block_kinds)
+                                       grid_block_kinds, heads_a_lane_tile)
 
     kv_heads = args.kv_heads or args.heads
 
@@ -156,16 +157,21 @@ def main():
         d_qk, d_v = (int(n) for n in width.split("x"))
         for seq in (int(n) for n in args.lengths.split(",")):
             batch = max(1, args.tokens // seq)
+            # operands as a model's projection writes them, [B, T, H x d]
+            # dense, and shaped [B, T, H, d] inside the timed function: an
+            # argument [B, T, H, 64] would itself be padded to the lanes
             keys = jax.random.split(jax.random.PRNGKey(seq), 3)
-            q = jax.random.normal(keys[0], (batch, seq, args.heads, d_qk),
-                                  jnp.bfloat16)
-            k = jax.random.normal(keys[1], (batch, seq, kv_heads, d_qk),
-                                  jnp.bfloat16)
-            v = jax.random.normal(keys[2], (batch, seq, kv_heads, d_v),
-                                  jnp.bfloat16)
+            q, k, v = (
+                jax.random.normal(key, (batch, seq, n * d), jnp.bfloat16)
+                for key, n, d in zip(keys, (args.heads, kv_heads, kv_heads),
+                                     (d_qk, d_qk, d_v)))
+            shaped = lambda x, n: x.reshape(batch, seq, n, -1)
             line = {"d_qk": d_qk, "d_v": d_v, "seq": seq, "batch": batch,
                     "heads": args.heads, "kv_heads": kv_heads,
                     "window": args.window,
+                    "boundary": ("model_arrays" if heads_a_lane_tile(
+                        seq, args.heads, kv_heads, d_qk, d_v)
+                        else "xla_copies"),
                     "device": jax.devices()[0].device_kind,
                     "grid_blocks": grid_block_kinds(seq, seq, True,
                                                     window=args.window),
@@ -177,7 +183,9 @@ def main():
 
                 def loss(q, k, v, path=path):
                     return causal_self_attention(
-                        q, k, v, path, args.window).astype(jnp.float32).sum()
+                        shaped(q, args.heads), shaped(k, kv_heads),
+                        shaped(v, kv_heads), path,
+                        args.window).astype(jnp.float32).sum()
 
                 fns = {"_fwd_ms": jax.jit(loss),
                        "_fwd_bwd_ms": jax.jit(jax.grad(loss, argnums=(0, 1, 2)))}
@@ -195,7 +203,9 @@ def main():
                 except Exception as e:  # a path that does not fit or lower
                     line[path + "_error"] = str(e)[:200]
             if args.check:
-                line["against_reference"] = against_reference(q, k, v)
+                line["against_reference"] = against_reference(
+                    shaped(q, args.heads), shaped(k, kv_heads),
+                    shaped(v, kv_heads))
             print(json.dumps(line), flush=True)
 
 

@@ -169,9 +169,25 @@ def _flash_scan(q, k, v, *, causal: bool, sm_scale: float, block_k: int,
 # lse, delta) are then row vectors (1, queries) that broadcast along
 # sublanes, the accumulators are (d, queries) and fill whole registers at
 # d = 64, and every matmul is NN or NT on operands as they arrive — given
-# V^T (forward) and K^T (backward) from XLA, which also turns O^T and dQ^T
-# back. Operands go to the MXU in the inputs' dtype and accumulate in
-# float32; the statistics and accumulators are float32.
+# V^T (forward) and K^T (backward), and with O^T and dQ^T turned back.
+# Operands go to the MXU in the inputs' dtype and accumulate in float32; the
+# statistics and accumulators are float32.
+#
+# Who turns them is the call's boundary, chosen at trace time from its shapes
+# (``heads_a_lane_tile``; one ``attention/boundary`` record a traced call of
+# ``causal_self_attention`` says which). Where a head is one block of keys
+# and its width divides the 128 lanes (every GPT-2 call: 1024 tokens, heads
+# of 64), the kernels take (T, 128) blocks of the model's own [B, T, H x d]
+# arrays, two 64-wide heads a grid step, and turn V, K, dO and O once a step
+# in VMEM, O^T and dQ^T once back (``_fwd_kernel_lanes``,
+# ``_bwd_kernel_lanes``): XLA copies nothing round them. Elsewhere (several
+# blocks of keys a head, grouped key-value heads, keys and values of two
+# widths, ``flash_attention``'s (b, h, s, d) entry) the operands are
+# [B x H, T, d] and XLA makes V^T and K^T and turns O^T and dQ^T back, in
+# HBM, through arrays whose 64-wide rows are padded to the lanes: in GPT-2's
+# step that was 84 copies, 12 asynchronous copies and 48 asynchronous slices
+# of head-shaped arrays, 0.64 ms a layer beside the kernels' 1.45 (PERF.md
+# section 6, PR 51).
 #
 # One grid step holds up to ``_MAX_RESIDENT`` queries and as many keys (a
 # whole head at GPT-2's 1024) and computes on tiles of ``block_q`` queries
@@ -485,6 +501,24 @@ def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
             pl.when(here)(functools.partial(walk, rel))
 
 
+def _fold_tile(s, carry, masked, values_t):
+    """One key tile's scores ``s`` (block_k, block_q) folded into a q
+    tile's online softmax ``carry`` (running max and sum as rows (1,
+    block_q), accumulator (d_v, block_q)); ``values_t()`` loads the tile's
+    V^T (d_v, block_k)."""
+    m, l, acc = carry
+    m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+    # a query with no live key yet keeps m at NEG_INF: exponentiate against
+    # 0 so that its masked scores give p == 0, not exp(0)
+    m_exp = jnp.where(m_new > NEG_INF / 2, m_new, 0.0) if masked else m_new
+    p = jnp.exp(s - m_exp)
+    alpha = jnp.exp(m - m_exp)
+    l = l * alpha + p.sum(axis=0, keepdims=True)
+    acc = acc * alpha
+    vt = values_t()
+    return m_new, l, acc + _dot(vt, p.astype(vt.dtype), _NN)
+
+
 def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int]):
@@ -510,21 +544,9 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
             q = _scaled(q_ref[cols, :], sm_scale, fold)
 
             def step(c, carry, masked):
-                m, l, acc = carry
                 rows = _tile(c, block_k, n_k)
                 s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
-                m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
-                # a query with no live key yet keeps m at NEG_INF:
-                # exponentiate against 0 so that its masked scores give
-                # p == 0, not exp(0)
-                m_exp = (jnp.where(m_new > NEG_INF / 2, m_new, 0.0) if masked
-                         else m_new)
-                p = jnp.exp(s - m_exp)
-                alpha = jnp.exp(m - m_exp)
-                l = l * alpha + p.sum(axis=0, keepdims=True)
-                acc = acc * alpha + _dot(vt_ref[:, rows],
-                                         p.astype(vt_ref.dtype), _NN)
-                return m_new, l, acc
+                return _fold_tile(s, carry, masked, lambda: vt_ref[:, rows])
 
             m, l, acc = _walk(
                 step, (m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols]),
@@ -692,6 +714,145 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
             def _land():
                 for c in range(copies):
                     write(c).wait()
+
+
+def _own_lanes(x, h: int, width: int):
+    """``x`` (rows, 128) with every lane but head ``h``'s ``width`` zeroed:
+    selected, so that whatever lies in the other lanes (a neighbour's
+    numbers, or past the array's edge anything at all) adds nothing to a
+    contraction over the tile. All of ``x`` where the head is the tile."""
+    if width == x.shape[1]:
+        return x
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * width) & (lane < (h + 1) * width), x,
+                     jnp.zeros_like(x))
+
+
+def _each_head(head, width: int, phantom: bool):
+    """``head(h)`` for the heads of this grid step's lane tile. With
+    ``phantom`` (an odd number of 64-wide heads) the last tile's second
+    half lies past the arrays' edge: nothing is computed for it, and what
+    its rows and lanes of the scratch hold is dropped with the block's
+    out-of-bounds part."""
+    for h in range(128 // width):
+        if phantom and h:
+            pl.when(pl.program_id(1) < pl.num_programs(1) - 1)(
+                functools.partial(head, h))
+        else:
+            head(h)
+
+
+def _fwd_kernel_lanes(q_ref, k_ref, v_ref, o_ref, lse_ref, vt_scr, ot_scr, *,
+                      sm_scale: float, block_q: int, block_k: int,
+                      window: Optional[int], width: int, phantom: bool):
+    """The forward of one lane tile of heads (two 64 wide, one 128 wide),
+    each one block of keys, on blocks (T, 128) of the model's own
+    [B, T, H x d] arrays. The arithmetic is ``_fwd_kernel``'s, transposed
+    scores and all: V^T is made here, once a step, in VMEM; a head's
+    scores contract over the tile's 128 lanes with the other head's
+    selected to zero (the depth of an MXU pass, which a 64-wide head half
+    fills either way); its (width, queries) accumulator lands in its rows
+    of ``ot_scr``, which is turned once and leaves as the (T, 128) block
+    of O. ``lse_ref`` is (heads a tile, T): a row a head."""
+    res = q_ref.shape[0]
+    n_q, n_k = res // block_q, res // block_k
+    fold = _scale_folds(q_ref.dtype, sm_scale)
+    scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
+                               block_k=block_k, window=window)
+    vt_scr[...] = v_ref[...].T
+
+    def head(h):
+        ours = pl.ds(h * width, width)
+        own = functools.partial(_own_lanes, h=h, width=width)
+        edge = own if phantom else (lambda x: x)
+
+        def row(j, *bounds):
+            cols = _tile(j, block_q, n_q)
+            q = _scaled(own(q_ref[cols, :]), sm_scale, fold)
+
+            def step(c, carry, masked):
+                rows = _tile(c, block_k, n_k)
+                s = scores(edge(k_ref[rows, :]), q, c, masked=masked,
+                           rel=j * block_q)
+                return _fold_tile(s, carry, masked,
+                                  lambda: vt_scr[ours, rows])
+
+            m, l, acc = _walk(
+                step, (jnp.full((1, block_q), NEG_INF, jnp.float32),
+                       jnp.zeros((1, block_q), jnp.float32),
+                       jnp.zeros((width, block_q), jnp.float32)), *bounds)
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            ot_scr[ours, cols] = acc / l_safe
+            lse_ref[h:h + 1, cols] = jnp.where(
+                l == 0.0, jnp.inf,
+                jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
+
+        _walk_rows(row, 0, n_q, n_k, block_q, block_k, True, False, window)
+
+    _each_head(head, width, phantom)
+    o_ref[...] = ot_scr[...].T.astype(o_ref.dtype)
+
+
+def _bwd_kernel_lanes(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, kt_scr, dqt_scr, dk_scr, dv_scr,
+                      *, sm_scale: float, block_q: int, block_k: int,
+                      window: Optional[int], width: int, phantom: bool):
+    """The backward of one lane tile of heads on blocks (T, 128) of the
+    model's own arrays, as ``_fwd_kernel_lanes`` is the forward:
+    ``_bwd_kernel``'s arithmetic with K^T made here, once a step. A head's
+    q and dO rows have the other head's lanes selected to zero, so dV = P
+    dO and dK = dS Q add to the head's own lanes of the tile's (T, 128)
+    sums and to nothing else; its dQ^T lands in its rows of ``dqt_scr``,
+    turned once at the end. ``delta``, a query's sum of dO x O over its
+    head's lanes, is made here as well, from dO^T and O^T, as the row that
+    the transposed scores need: XLA made it by way of a float32 copy of
+    the whole product into a layout it could reduce."""
+    res = q_ref.shape[0]
+    n_q, n_k = res // block_q, res // block_k
+    fold = _scale_folds(q_ref.dtype, sm_scale)
+    scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
+                               block_k=block_k, window=window)
+    kt_scr[...] = k_ref[...].T
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
+    # (128, T) float32, until each head's dQ^T takes its rows' place
+    dqt_scr[...] = (do_ref[...].T.astype(jnp.float32)
+                    * o_ref[...].T.astype(jnp.float32))
+
+    def head(h):
+        ours = pl.ds(h * width, width)
+        own = functools.partial(_own_lanes, h=h, width=width)
+        edge = own if phantom else (lambda x: x)
+
+        def row(j, *bounds):
+            cols = _tile(j, block_q, n_q)
+            q = _scaled(own(q_ref[cols, :]), sm_scale, fold)
+            do = own(do_ref[cols, :])
+            lse = lse_ref[h:h + 1, cols]
+            delta = dqt_scr[ours, cols].sum(axis=0, keepdims=True)
+
+            def step(c, dqt, masked):
+                rows = _tile(c, block_k, n_k)
+                s = scores(edge(k_ref[rows, :]), q, c, masked=masked,
+                           rel=j * block_q)
+                p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
+                dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
+                dp = _dot(edge(v_ref[rows, :]), do, _NT)
+                ds = (p * (dp - delta)).astype(q.dtype)
+                dk_scr[rows, :] += _dot(ds, q, _NN)
+                return dqt + _dot(kt_scr[ours, rows], ds, _NN)
+
+            dqt_scr[ours, cols] = _walk(
+                step, jnp.zeros((width, block_q), jnp.float32),
+                *bounds) * sm_scale
+
+        _walk_rows(row, 0, n_q, n_k, block_q, block_k, True, False, window)
+
+    _each_head(head, width, phantom)
+    dq_ref[...] = dqt_scr[...].T.astype(dq_ref.dtype)
+    dk = dk_scr[...]
+    dk_ref[...] = (dk if fold else dk * sm_scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _largest_block(n: int, target: int, align: int) -> int:
@@ -985,38 +1146,160 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     return jnp.swapaxes(dq_t.astype(q.dtype), 1, 2), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def heads_a_lane_tile(seq_len: int, heads: int, kv_heads: int, d: int,
+                      d_v: int) -> int:
+    """How many heads one 128-lane tile of a model's [B, T, H x d] arrays
+    holds where the kernels address those arrays themselves
+    (``_flash_pallas_lanes``), and 0 where XLA turns them into the kernels'
+    own [B x H, T, d] (``_flash_pallas``). Read from the call's shapes alone:
+    a head is one block of keys (every length up to ``_MAX_RESIDENT``: the
+    kernels past it sum over blocks and walk them by kind, on the boundary
+    they were measured with), in whole tiles of queries; keys and values
+    share a width that divides the lanes, 64 (two heads a tile, an odd head
+    out in half a tile past the arrays' edge) or 128 (192 / 128 and 64 /
+    128 would need two addresses a head); every query head has its own keys
+    and values (a group's would lie in another tile's half); and the heads
+    fill a tile."""
+    one_block = seq_len <= _MAX_RESIDENT and seq_len % 128 == 0
+    if (one_block and heads == kv_heads and d == d_v and d in (64, 128)
+            and heads * d >= 128):
+        return 128 // d
+    return 0
+
+
+def _lanes_params(interpret: bool, dtype):
+    """Batch rows x lane tiles of heads: no step adds to another's. In
+    bfloat16 the backward's residents at 2,048 tokens take 10.5 MiB of the
+    compiler's own 16; a four-byte type takes twice the blocks' share."""
+    if interpret:
+        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=32 * 2**20 if dtype.itemsize > 2 else None)
+
+
+def _lane_tiles(q, heads: int, window: Optional[int], tiles, backward: bool):
+    """What both calls on a model's own arrays share, for ``q`` [B, T,
+    heads x width] and ``tiles`` (block_q, block_k, the kernel's targets):
+    (a (T, 128) block's spec, the spec of a lane tile's rows (heads a tile,
+    T), the rows' array [B, lane tiles, heads a tile, T] whose leading two
+    are the grid, the kernel's static arguments). Writes the call's
+    ``attn/grid_blocks`` record, as ``_kinds_present`` does for the other
+    boundary: one block a head, on the diagonal."""
+    b, seq, lanes = q.shape
+    width = lanes // heads
+    a_tile = 128 // width
+    n_tiles = -(-heads // a_tile)
+    block_q, block_k, res_q, res_k = _block_sizes(seq, seq, *tiles)
+    assert res_q == res_k == seq, (seq, res_q, res_k)
+    _kinds_present(1, 1, seq, seq, 0, True, backward, window,
+                   (b * heads, b * heads))
+    return (pl.BlockSpec((None, seq, 128), lambda bi, ti: (bi, 0, ti)),
+            pl.BlockSpec((None, None, a_tile, seq),
+                         lambda bi, ti: (bi, ti, 0, 0)),
+            jax.ShapeDtypeStruct((b, n_tiles, a_tile, seq), jnp.float32),
+            dict(block_q=block_q, block_k=block_k, window=window, width=width,
+                 phantom=heads % a_tile != 0))
+
+
+def _flash_pallas_lanes(q, k, v, *, heads: int, sm_scale: float,
+                        block_q: Optional[int], block_k: Optional[int],
+                        interpret: bool, window: Optional[int] = None):
+    """q, k, v: [B, T, heads x d], a model's own arrays (what its
+    projection wrote, reshaped), where ``heads_a_lane_tile`` admits the
+    call. -> (out [B, T, heads x d], lse [B, lane tiles, heads a tile, T]
+    float32): one grid step a batch row and lane tile
+    (``_fwd_kernel_lanes``)."""
+    seq = q.shape[1]
+    spec, rowspec, rows, static = _lane_tiles(
+        q, heads, window, (block_q, block_k, _FWD_TILES), False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_lanes, sm_scale=sm_scale, **static),
+        grid=rows.shape[:2],
+        in_specs=[spec, spec, spec],
+        out_specs=[spec, rowspec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), rows],
+        scratch_shapes=[pltpu.VMEM((128, seq), v.dtype),
+                        pltpu.VMEM((128, seq), jnp.float32)],
+        compiler_params=_lanes_params(interpret, q.dtype),
+        interpret=interpret,
+        name=_kernel_name("flash_fwd", window),
+    )(q, k, v)
+
+
+def _flash_pallas_lanes_bwd(q, k, v, do, out, lse, *, heads: int,
+                            sm_scale: float, block_q: Optional[int],
+                            block_k: Optional[int], interpret: bool,
+                            window: Optional[int] = None):
+    """-> (dq, dk, dv) [B, T, heads x d] of ``_flash_pallas_lanes``'s call,
+    from its output and its ``lse`` in the rows' form it hands out."""
+    seq = q.shape[1]
+    spec, rowspec, rows, static = _lane_tiles(
+        q, heads, window, (block_q, block_k, _BWD_TILES), True)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel_lanes, sm_scale=sm_scale, **static),
+        grid=rows.shape[:2],
+        in_specs=[spec, spec, spec, spec, spec, rowspec],
+        out_specs=[spec, spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((128, seq), k.dtype),
+                        pltpu.VMEM((128, seq), jnp.float32),
+                        pltpu.VMEM((seq, 128), jnp.float32),
+                        pltpu.VMEM((seq, 128), jnp.float32)],
+        compiler_params=_lanes_params(interpret, q.dtype),
+        interpret=interpret,
+        name=_kernel_name("flash_bwd", window),
+    )(q, k, v, do, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
-                       interpret, window=None):
+                       interpret, window=None, heads=None):
     """Differentiable Pallas flash attention: both directions are Pallas
     kernels (forward saves the logsumexp; one backward kernel recomputes P
     per tile from q,k,lse — O(seq) memory, no attention matrix ever
-    materialized)."""
-    out, _ = _flash_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_q=block_q, block_k=block_k,
-                           interpret=interpret, window=window)
-    return out
+    materialized). q, k, v are the kernels' own [B x H, T, d] or, given
+    ``heads``, a model's [B, T, heads x d] (``heads_a_lane_tile``)."""
+    return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
+                          interpret, window, heads)[0]
+
+
+def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window, heads):
+    """(out, lse) by the boundary the operands are in."""
+    if heads is None:
+        return _flash_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
+                             block_q=block_q, block_k=block_k,
+                             interpret=interpret, window=window)
+    assert causal, "a model's own arrays are causal self-attention's"
+    return _flash_pallas_lanes(q, k, v, heads=heads, sm_scale=sm_scale,
+                               block_q=block_q, block_k=block_k,
+                               interpret=interpret, window=window)
 
 
 def _flash_pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                      window):
-    out, lse = map(ad_checkpoint.checkpoint_name, _flash_pallas(
-        q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window), _REMAT_NAMES)
+                      window, heads):
+    out, lse = map(ad_checkpoint.checkpoint_name, _flash_forward(
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
+        heads), _REMAT_NAMES)
     return out, (q, k, v, out, lse)
 
 
 def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
-                      res, g):
+                      heads, res, g):
     q, k, v, out, lse = res
     # delta_i = rowsum(dO_i * O_i); tiny elementwise reduce — XLA fuses it.
     # A row (b, 1, q_len), like lse.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-    return _flash_pallas_bwd_kernel(
-        q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, interpret=interpret, window=window,
-    )
+    if heads is None:
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)[:, None, :]
+        return _flash_pallas_bwd_kernel(
+            q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            window=window)
+    return _flash_pallas_lanes_bwd(
+        q, k, v, g, out, lse, heads=heads, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window)
 
 
 _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
@@ -1025,14 +1308,15 @@ _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "sm_scale", "block_q", "block_k", "impl",
-                     "window"),
+                     "window", "heads"),
 )
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     impl: Optional[str] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    heads: Optional[int] = None) -> jax.Array:
     """Flash attention over (..., seq, head_dim) inputs.
 
     Accepts (b, h, s, d) or (b, s, d); ``q`` and ``k`` share a width and
@@ -1051,6 +1335,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     other backend. ``impl`` forces a path:
     "pallas" | "pallas_interpret" | "scan" | "reference".
 
+    Given ``heads``, q, k and v are a model's own (b, s, heads x d), every
+    head's width side by side along the last axis, and so is the output:
+    causal self-attention of a shape ``heads_a_lane_tile`` admits, whose
+    kernels address those arrays themselves (two 64-wide heads a lane
+    tile); the other paths are handed the heads as an axis.
+
     ``block_q`` queries meet ``block_k`` keys at a time; left out, the
     kernel chooses both from the sequence lengths (``_block_sizes``) and
     scan takes 128 keys.
@@ -1061,22 +1351,40 @@ def flash_attention(q, k, v, *, causal: bool = False,
     mesh axis of size > 1 (``unmapped_mesh_axes``) still leaves the call
     to the partitioner, and JAX's own error says so.
     """
-    sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     assert causal or window is None, "a window is a causal mask's"
     window = _window_of(window, k.shape[-2])
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "scan"
-    if impl == "reference":
-        return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                                   window=window)
-    if impl == "scan":
-        return _flash_scan(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_k=block_k or 128, window=window)
+    if sm_scale is None:
+        sm_scale = (q.shape[-1] // (heads or 1)) ** -0.5
+    if impl in ("reference", "scan"):
+        if heads is not None:   # these take the heads as an axis
+            q, k, v = (t.reshape(*t.shape[:2], heads, -1).transpose(
+                0, 2, 1, 3) for t in (q, k, v))
+        if impl == "reference":
+            out = attention_reference(q, k, v, causal=causal,
+                                      sm_scale=sm_scale, window=window)
+        else:
+            out = _flash_scan(q, k, v, causal=causal, sm_scale=sm_scale,
+                              block_k=block_k or 128, window=window)
+        if heads is not None:
+            b, _, seq, _ = out.shape
+            out = out.transpose(0, 2, 1, 3).reshape(b, seq, -1)
+        return out
     interpret = impl == "pallas_interpret"
-    assert q.shape[-3] % k.shape[-3] == 0 and k.shape[:-1] == v.shape[:-1], (
-        q.shape, k.shape, v.shape)
+    if heads is None:
+        assert (q.shape[-3] % k.shape[-3] == 0
+                and k.shape[:-1] == v.shape[:-1]), (q.shape, k.shape, v.shape)
+    else:
+        d = q.shape[2] // heads
+        assert q.shape == k.shape == v.shape and heads_a_lane_tile(
+            q.shape[1], heads, heads, d, d), (q.shape, k.shape, v.shape,
+                                              heads)
 
     def kernel(q, k, v):
+        if heads is not None:
+            return _flash_pallas_diff(q, k, v, causal, sm_scale, block_q,
+                                      block_k, interpret, window, heads)
         if q.ndim == 4:
             b, h, s, _ = q.shape
             fold = lambda x: x.reshape(b * x.shape[1], *x.shape[-2:])
@@ -1253,6 +1561,17 @@ def causal_self_attention(q, k, v, attention: str = "auto",
         attention = auto_attention(q, v)
     bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
+        (b, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
+        a_tile = heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
+        steptrace.record_counters("attention/boundary", {
+            "tokens": seq, "heads": heads, "kv_heads": kv_heads,
+            "d_qk": d, "d_v": d_v, "window": window or 0,
+            "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0)})
+        if a_tile:
+            lanes = lambda t: t.reshape(b, seq, -1)
+            return flash_attention(
+                lanes(q), lanes(k), lanes(v), causal=True, window=window,
+                heads=heads).reshape(b, seq, heads, d_v)
         return flash_attention(
             bhsd(q), bhsd(k), bhsd(v), causal=True, window=window
         ).transpose(0, 2, 1, 3)
@@ -1270,15 +1589,14 @@ def causal_self_attention(q, k, v, attention: str = "auto",
 # block's projections). ``_flash_pallas_fwd`` names them, and hands the named
 # output on, so that what a block computes from it is recomputed from the kept
 # copy. Without a policy a name is the identity and lowers to nothing.
-# What a layer then holds is the named [B x H, T, d_v] output as the compiler
-# lays it out, and [B x H, 1, T] float32. At a value width of 64 the kernel
-# writes [B x H, 64, T] and the named array is its swap: kept, the swap
-# becomes a copy with its 64-wide rows padded to the 128 lanes, made for
-# every layer before the backward pass begins. In the cell
-# gpt2-xl.step-fsdp4 (a chip's 400 heads of 1,024 x 64) the compiled plan
-# grows by 93 MiB a layer where the output's bytes are 50, and the backward
-# pass copies each once more into the model's [B, T, H, 64], 0.34 ms a layer
-# on the chip (PERF.md section 6, PR 45).
+# What a layer then holds, where the kernels address the model's arrays
+# (``heads_a_lane_tile``: GPT-2's calls), is the output as the kernel wrote
+# it, a dense [B, T, H x d_v], and [B, lane tiles, heads a tile, T] float32.
+# On the other boundary it is the [B x H, T, d_v] swap of what the kernel
+# wrote and [B x H, 1, T] float32; at a value width of 64 the swap, kept,
+# becomes a copy with its 64-wide rows padded to the 128 lanes (until PR 51
+# GPT-2 XL's: 93 MiB a layer of plan where the output's bytes are 50, and a
+# copy more in the backward pass, PERF.md section 6, PRs 45 and 51).
 _REMAT_NAMES = ("flash_out", "flash_lse")
 # and of the selective scan (``ops/ssm.py`` names them in its forward rule):
 # its output [B, T, channels] in the compute dtype and the state each chunk
@@ -1291,10 +1609,10 @@ def remat_policy():
     """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
     may run a kernel of ``ray_tpu/ops``: keep the selective scan's output and
     boundary states, and the flash kernel's output and log-sum-exp (per layer
-    one [B, T, H, d_v] array in the compute dtype and B x H x T float32; at
-    a value width of 64 the kept copy is a lane-padded [B x H, T, 64],
-    nearly twice those bytes: the comment above),
-    recompute everything else. The backward pass of such a block then
+    one [B, T, H, d_v] array in the compute dtype and B x H x T float32;
+    dense where the kernels address the model's arrays, else at a value
+    width of 64 a lane-padded [B x H, T, 64] of nearly twice those bytes:
+    the comment above), recompute everything else. The backward pass of such a block then
     reruns the projections and not the forward kernel. Where the block's
     attention is not the kernel (``xla``, the scan) no such name exists,
     nothing is kept and the program is the one without a policy."""

@@ -3,7 +3,9 @@ naive reference against a mask written out by hand, the Pallas kernel in
 interpret mode and the scan against the reference, the grid's blocks by
 kind, and that a call with neither lowers to the jaxpr held here; since PR
 50 the backward's dQ sum over several blocks of keys, which the kernel makes
-itself: one array a call, in copies of whole rows of tiles."""
+itself: one array a call, in copies of whole rows of tiles; since PR 51 the
+boundary of a one-block call, the model's own [B, T, H x d] arrays or the
+[B x H, T, d] that XLA makes of them."""
 
 import functools
 import hashlib
@@ -397,6 +399,126 @@ def test_causal_self_attention_takes_both_in_a_models_layout(monkeypatch,
             for b in range(2)]).transpose(0, 2, 1, 3)
         got = causal_self_attention(q, k, v, path, window)
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+# (query heads, key-value heads, length, key width, value width, window,
+# block_q, block_k, heads a lane tile): causal self-attention in a model's
+# layout, batch 2. ``heads a lane tile`` is what ``heads_a_lane_tile`` must
+# answer: 2 or 1 where the kernels address the model's [B, T, H x d] arrays
+# (an odd number of 64-wide heads leaves half a tile past the arrays' edge),
+# 0 where the call keeps the [B x H, T, d] boundary and its kernels.
+_BOUNDARY = {
+    "two_heads_64": (2, 2, 128, 64, 64, None, None, None, 2),
+    "twelve_heads_64_tiles_2x4": (12, 12, 256, 64, 64, None, 128, 64, 2),
+    "odd_heads_64": (3, 3, 128, 64, 64, None, 64, 64, 2),
+    "odd_heads_64_window": (5, 5, 256, 64, 64, 100, 128, 128, 2),
+    "two_heads_64_window_of_a_tile": (2, 2, 256, 64, 64, 64, 64, 64, 2),
+    "two_heads_128": (2, 2, 128, 128, 128, None, 64, 32, 1),
+    "three_heads_128_window": (3, 3, 256, 128, 128, 72, 128, 128, 1),
+    "grouped_64": (4, 2, 128, 64, 64, None, 64, 64, 0),
+    "grouped_128_window": (4, 2, 128, 128, 128, 48, 64, 64, 0),
+    "values_twice_the_keys": (2, 2, 128, 64, 128, None, 64, 64, 0),
+    "one_head_64": (1, 1, 128, 64, 64, None, 64, 64, 0),
+    "no_whole_tile_of_queries": (2, 2, 64, 64, 64, None, 32, 32, 0),
+    "width_32": (4, 4, 128, 32, 32, None, 64, 64, 0),
+}
+_BOUNDARY_PARAMS = [
+    pytest.param(case, dtype, id=f"{case}-{dtype.__name__}")
+    for dtype, cases in (
+        (jnp.float32, _BOUNDARY),
+        (jnp.bfloat16, ("twelve_heads_64_tiles_2x4", "odd_heads_64_window",
+                        "two_heads_128", "grouped_64")))
+    for case in cases]
+
+
+@pytest.mark.parametrize("case,dtype", _BOUNDARY_PARAMS)
+def test_a_one_block_call_by_either_boundary_matches_reference(
+        monkeypatch, case, dtype):
+    """``causal_self_attention(..., "flash")`` in interpret mode against
+    the reference in float32, output and the three gradients under a
+    non-uniform cotangent, and which arrays its kernels were handed: the
+    model's own, a (T, 128) block a grid step, where the rule admits the
+    call; else the parent's operands (V^T and K^T from XLA, ``delta`` a
+    row a head)."""
+    heads, kv, length, d, d_v, window, bq, bk, a_tile = _BOUNDARY[case]
+    assert attention.heads_a_lane_tile(length, heads, kv, d, d_v) == a_tile
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        flash_attention, impl="pallas_interpret", block_q=bq, block_k=bk))
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (2, length, heads, d), dtype)
+    k = jax.random.normal(ks[1], (2, length, kv, d), dtype)
+    v = jax.random.normal(ks[2], (2, length, kv, d_v), dtype)
+    w = jax.random.normal(ks[3], (2, length, heads, d_v), jnp.float32)
+    f32 = lambda x: x.astype(jnp.float32)
+    bhsd = lambda t: t.transpose(0, 2, 1, 3)
+    flash = lambda *x: f32(causal_self_attention(*x, "flash", window))
+    ref = lambda *x: bhsd(attention_reference(
+        *map(bhsd, x), causal=True, window=window))
+
+    def out_and_grads(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(w))
+
+    got = out_and_grads(flash, q, k, v)
+    want = out_and_grads(ref, f32(q), f32(k), f32(v))
+    for a, b, like in zip(got, want, (w, q, k, v)):
+        assert a.shape == like.shape
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+        else:
+            assert float(jnp.abs(f32(a) - b).max() / jnp.abs(b).max()) <= 3e-2
+
+    calls = _kernel_eqns(jax.make_jaxpr(
+        lambda *x: out_and_grads(flash, *x))(q, k, v))
+    shapes = {name: [v.aval.shape for v in eqn.invars]
+              for name, eqn in calls.items()}
+    name = lambda base: base if window is None else f"{base}_w{window}"
+    if a_tile:
+        own, tiles = (2, length, heads * d), -(-heads // a_tile)
+        rows = (2, tiles, a_tile, length)
+        assert shapes[name("flash_fwd")] == [own] * 3
+        assert shapes[name("flash_bwd")] == [own] * 5 + [rows]
+        assert calls[name("flash_bwd")].params["grid_mapping"].grid == (
+            2, tiles)
+    else:
+        folded = lambda n, width: (2 * n, length, width)
+        row = (2 * heads, 1, length)
+        assert shapes[name("flash_fwd")] == [
+            folded(heads, d), folded(kv, d), (2 * kv, d_v, length)]
+        assert shapes[name("flash_bwd")] == [
+            folded(heads, d), folded(kv, d), folded(kv, d_v),
+            (2 * kv, d, length), folded(heads, d_v), row, row]
+
+
+def test_the_boundary_taken_is_in_the_ring(monkeypatch):
+    """One ``attention/boundary`` record a traced call of the kernel's
+    path: heads, widths, heads a lane tile and whether the kernels address
+    the model's arrays; GPT-2 XL's 25 heads are admitted, a group is not."""
+    from ray_tpu._private import steptrace
+
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        flash_attention, impl="pallas"))
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()
+        for heads, kv in ((25, 25), (8, 2)):
+            q = jax.ShapeDtypeStruct((4, 1024, heads, 64), jnp.bfloat16)
+            k = jax.ShapeDtypeStruct((4, 1024, kv, 64), jnp.bfloat16)
+            jax.eval_shape(lambda *x: causal_self_attention(*x, "flash"),
+                           q, k, k)
+        records = [r["values"] for r in steptrace.snapshot()
+                   if r["kind"] == "counters"
+                   and r["name"] == "attention/boundary"]
+    finally:
+        steptrace.set_enabled(False)
+        jax.clear_caches()
+    shared = {"tokens": 1024, "d_qk": 64, "d_v": 64, "window": 0}
+    assert records == [
+        shared | {"heads": 25, "kv_heads": 25, "heads_a_lane_tile": 2,
+                  "model_arrays": 1},
+        shared | {"heads": 8, "kv_heads": 2, "heads_a_lane_tile": 0,
+                  "model_arrays": 0}]
 
 
 # sha256 (first 16 digits) of the jaxpr's text, forward and gradient, of a

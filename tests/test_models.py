@@ -345,6 +345,39 @@ def test_auto_attention_dispatch(monkeypatch, backend):
         paths("splash", 128)
 
 
+@pytest.mark.parametrize("n_head,n_embd", [(2, 128), (3, 192), (2, 256)],
+                         ids=["two_heads_64", "odd_heads_64", "heads_128"])
+def test_gpt2_on_the_kernels_own_address_is_the_xla_model(monkeypatch, n_head,
+                                                          n_embd):
+    """GPT-2 with ``attention="flash"`` at a length and widths whose
+    kernels address the model's [B, T, H x d] arrays (interpret mode: two
+    64-wide heads a lane tile, an odd head out, one 128-wide): the loss and
+    every gradient are ``attention="xla"``'s."""
+    import functools
+
+    from ray_tpu.models import gpt2
+    from ray_tpu.ops import attention as A
+
+    d = n_embd // n_head
+    assert A.heads_a_lane_tile(128, n_head, n_head, d, d)
+    monkeypatch.setattr(A, "flash_attention", functools.partial(
+        A.flash_attention, impl="pallas_interpret"))
+
+    def loss_and_grads(attention):
+        cfg = gpt2.GPT2Config(vocab_size=256, n_positions=128, n_embd=n_embd,
+                              n_layer=2, n_head=n_head, attention=attention,
+                              dtype=jnp.float32)
+        model, params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
+        batch = gpt2.synthetic_batch(jax.random.PRNGKey(1), 2, 128, 256)
+        return jax.value_and_grad(
+            lambda p: gpt2.loss_fn(p, model, batch))(params)
+
+    (loss, grads), (want, want_grads) = map(loss_and_grads, ("flash", "xla"))
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, atol=2e-5, rtol=2e-4), grads, want_grads)
+
+
 def test_gpt2_auto_is_the_xla_program_on_cpu():
     """On the CPU backend ``auto`` lowers to the very program ``xla`` does."""
     from ray_tpu.models import gpt2

@@ -105,27 +105,38 @@ def _device_bytes(compiled):
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize(
-    "heads,seq,widths",
-    [(16 * 12, SEQ, (64, 64)), (4 * 12, 4096, (64, 64)),
-     (2 * 32, 8192, (192, 128))],
-    ids=["gpt2_1024", "several_grid_blocks_4096", "latent_8192_192_128"])
-def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, widths,
-                               backward):
-    """(batch 16 x 12 heads, 1024, 64) bf16 — the shape the model calls,
-    one grid step a head, the tile walk unrolled; 4096, past
-    ``_MAX_RESIDENT``: 2 x 2 grid blocks a head, each walked by its kind
-    (one whole, two on the diagonal, one dead: a branch on the grid
-    position, inside it straight-line code as at 1024), dead blocks clamped
-    in the index maps; and the latent-attention cell's call, 2 x 32 heads of
-    8192 with keys 192 and values 128 wide: 4 x 4 grid blocks a head."""
+    "batch,heads,seq,widths",
+    [(None, 16 * 12, SEQ, (64, 64)), (None, 4 * 12, 4096, (64, 64)),
+     (None, 2 * 32, 8192, (192, 128)),
+     (16, 12, SEQ, (64, 64)), (16, 25, SEQ, (64, 64)),
+     (8, 32, 2048, (128, 128))],
+    ids=["gpt2_1024", "several_grid_blocks_4096", "latent_8192_192_128",
+         "model_arrays_12_heads_1024_64", "model_arrays_25_heads_1024_64",
+         "model_arrays_32_heads_2048_128"])
+def test_flash_kernel_compiles(topo, no_compile_cache, batch, heads, seq,
+                               widths, backward):
+    """(batch 16 x 12 heads, 1024, 64) bf16 — one grid step a head, the tile
+    walk unrolled; 4096, past ``_MAX_RESIDENT``: 2 x 2 grid blocks a head,
+    each walked by its kind (one whole, two on the diagonal, one dead: a
+    branch on the grid position, inside it straight-line code as at 1024),
+    dead blocks clamped in the index maps; the latent-attention cell's
+    call, 2 x 32 heads of 8192 with keys 192 and values 128 wide: 4 x 4
+    grid blocks a head. With a ``batch``, the kernels that address a
+    model's own [B, T, H x d] (PR 51): the shape GPT-2's step calls, two
+    64-wide heads a lane tile and the turns made in VMEM; GPT-2 XL's 25
+    heads, whose thirteenth tile lies half past the arrays' edge; and one
+    128-wide head a tile at the longest one-block length."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    q = jax.ShapeDtypeStruct((heads, seq, widths[0]), jnp.bfloat16,
+    shape = lambda width: ((heads, seq, width) if batch is None
+                           else (batch, seq, heads * width))
+    q = jax.ShapeDtypeStruct(shape(widths[0]), jnp.bfloat16,
                              sharding=one_chip)
-    v = jax.ShapeDtypeStruct((heads, seq, widths[1]), jnp.bfloat16,
+    v = jax.ShapeDtypeStruct(shape(widths[1]), jnp.bfloat16,
                              sharding=one_chip)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, impl="pallas")
+        return flash_attention(q, k, v, causal=True, impl="pallas",
+                               heads=None if batch is None else heads)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
@@ -133,6 +144,18 @@ def test_flash_kernel_compiles(topo, no_compile_cache, heads, seq, widths,
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
     text = jax.jit(fn).lower(q, q, v).compile().as_text()
     assert "tpu_custom_call" in text
+    if batch is not None and heads * widths[0] % 128 == 0:
+        # nothing is turned outside the kernels (1,600 lanes are no whole
+        # number of tiles: the compiler lays such an argument out with the
+        # tokens minor, and copies it to the row-major the kernel reads)
+        assert not re.findall(r" (?:copy|transpose)\(", text)
+
+
+_FLASH_CALL = re.compile(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
+                         r'custom_call_target="tpu_custom_call"', re.M)
+_HEAD_SHAPED_COPY = re.compile(
+    r"= bf16\[(?:16,12,1024,64|192,1024,64|192,64,1024|16,1024,12,64"
+    r"|48,64,1024)\]\S* (?:copy|copy-done|slice-done)\(")
 
 
 def _compile_step(cache, key, step, args):
@@ -170,6 +193,17 @@ def test_train_step_fits_one_chip(topo, no_compile_cache, compiled_steps,
     assert planned < HBM_BYTES
     if attention == "auto":
         assert planned < _one_chip(compiled_steps, topo, "xla")[1]
+    if attention != "xla":
+        # the kernels address the model's [16, 1024, 12 x 64] themselves
+        # (PR 51): 12 + 12 calls and none of the head-shaped copies that
+        # stood round them (84 ``copy``, 12 ``copy-done``, 48 ``slice-done``
+        # a step, each result lane-padded to twice its bytes), and the plan
+        # that went with them: 4.80 GiB where it was 6.49
+        assert collections.Counter(_FLASH_CALL.findall(text)) == {
+            "flash_fwd": 12, "flash_bwd": 12}
+        assert not _HEAD_SHAPED_COPY.findall(text)
+        print(f"planned {planned / 2**30:.3f} GiB")
+        assert planned < 5.25 * 2**30
 
 
 @pytest.mark.parametrize("attention", ["xla", "auto", "flash"])
@@ -320,6 +354,7 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
         params, opt_state, {"input_ids": ids, "labels": ids}).compile()
 
     planned = _device_bytes(compiled)
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
     assert 0.5 * 15.75 * 2**30 < planned < 14.25 * 2**30
     out_params, out_opt_state, _ = compiled.output_shardings
     for out, arg in zip(jax.tree.leaves((out_params, out_opt_state)),
@@ -354,11 +389,13 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     for shape in kinds["all-reduce"].values():
         assert not re.search(r"\[\d+,\d+", shape), shape
     assert not re.search(r"f32\[\d+,25,1024,1024\]", text)
-    kernels = re.findall(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
-                         r'custom_call_target="tpu_custom_call"', text, re.M)
-    assert collections.Counter(kernels) == {"flash_fwd": layers,
-                                            "flash_bwd": layers}
-    assert f"bf16[{16 * 25},1024,64]" in text  # a chip's share of the batch
+    assert collections.Counter(_FLASH_CALL.findall(text)) == {
+        "flash_fwd": layers, "flash_bwd": layers}
+    # a chip's share of the batch, in the model's own [B, T, H x d]: the
+    # kernels address it (25 heads: twelve lane tiles and half a one)
+    written = re.findall(r"^\s*%?flash_fwd[\w.\-]* = \((\w+\[[\d,]+\])[^=]*? "
+                         r"(\w+\[[\d,]+\])", text, re.M)
+    assert set(written) == {("bf16[16,1024,1600]", "f32[16,13,2,1024]")}
 
 
 def _row_buffer_census(text, drawn, pairs, d, width, unwritten):
@@ -442,7 +479,12 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     finally:
         steptrace.set_enabled(False)
     assert {e["name"] for e in drawn} == {"attn/grid_blocks",
-                                          "moe/row_buffers"}
+                                          "moe/row_buffers",
+                                          "attention/boundary"}
+    # several blocks of keys a head: the boundary the kernels were measured
+    # with, [B x H, T, d] operands made by XLA
+    assert {e["args"]["model_arrays"] for e in drawn
+            if e["name"] == "attention/boundary"} == {0}
     blocks = [e for e in drawn if e["name"] == "attn/grid_blocks"]
     assert {e["args"]["backward"] for e in blocks} == {0, 1}
     for e in blocks:
@@ -541,6 +583,9 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                  if e["name"] == "attn/grid_blocks"]
     finally:
         steptrace.set_enabled(False)
+    assert {(e["args"]["kv_heads"], e["args"]["model_arrays"])
+            for e in counters if e["name"] == "attention/boundary"} == {
+        (4, 0)}
     by_window = {2048: (0, 8, 7, 49), 0: (28, 8, 0, 28)}
     assert {(e["window"], e["backward"]) for e in drawn} == {
         (w, b) for w in by_window for b in (0, 1)}
@@ -630,7 +675,9 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
     for e in counters:
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "ssm/scan",
-                            "model/layer_kinds"}
+                            "model/layer_kinds", "attention/boundary"}
+    assert {(e["d_qk"], e["d_v"], e["model_arrays"])
+            for e in by_name["attention/boundary"]} == {(64, 128, 0)}
     assert by_name["model/layer_kinds"][-1] == {
         "ssm": 2, "window": 1, "full": 1, "gmu": 1, "cross": 1, "layers": 6,
         "published_layers": 32, "hands_memory": 16, "hands_keys_values": 17}
