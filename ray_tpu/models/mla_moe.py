@@ -144,13 +144,10 @@ class LatentAttention(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """The expert feed-forward part: router over all ``experts`` experts,
-    the share ``expert_shard = (index, of)`` of them held here and their
-    part of the routed result, ``shared`` shared experts on every token;
-    each expert a SwiGLU of ``width``, ``per_token`` a token, weighted as
-    ``ops.moe.topk_routing`` says by ``scale`` and ``normalize``. The sizes
-    are fields and no model's config: ``models/afmoe.py`` runs the same
-    layer under its own names. -> (y, tokens each held expert received)."""
+    """The expert feed-forward part: a router over all ``experts``, the share
+    ``expert_shard = (index, of)`` held here, ``shared`` shared experts on
+    every token (0: none, and no parameter of one); SwiGLUs of ``width``,
+    weighted as ``ops.moe.topk_routing`` says. -> (y, tokens a held expert)."""
     experts: int
     expert_shard: Tuple[int, int]
     width: int
@@ -160,25 +157,28 @@ class RoutedExperts(nn.Module):
     shared: int
     dtype: Any
     kernel_init: Any
+    eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x):
-        B, T, d = x.shape
-        index, of = self.expert_shard
+        (B, T, d), (index, of) = x.shape, self.expert_shard
         held, width, init = self.experts // of, self.width, self.kernel_init
         router = self.param("router", init, (d, self.experts))
         bias = self.param("router_bias", nn.initializers.zeros,
                           (self.experts,))
         wi = self.param("experts_wi", init, (held, d, 2 * width))
         wo = self.param("experts_wo", init, (held, width, d))
+        shared = SwiGLU(width * self.shared, self.dtype, init,
+                        name="shared_experts") if self.shared else None
         flat = x.reshape(B * T, d)
         experts, weights = moe.topk_routing(
-            flat, router, bias, self.per_token, self.scale, self.normalize)
+            flat, router, bias, self.per_token, self.scale, self.normalize,
+            self.eps)
         y, tokens = moe.held_expert_ffn(flat, experts, weights, wi, wo,
                                         index=index, of=of)
-        shared = SwiGLU(width * self.shared, self.dtype, init,
-                        name="shared_experts")(x)
-        return shared + y.reshape(B, T, d), tokens
+        if shared is None:   # the routed part alone
+            return y.reshape(B, T, d), tokens
+        return shared(x) + y.reshape(B, T, d), tokens
 
 
 class Block(nn.Module):

@@ -182,14 +182,14 @@ def moe_ffn_ep(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
 # ----------------------------------------------------------------------
 
 def topk_routing(x, router, bias, k: int, scale: float = 1.0,
-                 normalize: bool = True):
+                 normalize: bool = True, eps: float = 1e-20):
     """Route each token of ``x`` (T, d) to ``k`` of the E experts.
 
     Scores are ``sigmoid(x @ router)`` in float32 (``router`` (d, E)); the k
     experts with the largest ``score + bias`` are chosen (``bias`` (E,) moves
     the selection only and takes no gradient); a chosen expert's weight is
-    its score, over the sum of the k chosen scores where ``normalize``,
-    times ``scale``. -> (experts (T, k) int32, weights (T, k) float32)."""
+    its score, over the sum of the k chosen scores plus ``eps`` where
+    ``normalize``, times ``scale``. -> (experts (T, k) int32, weights f32)."""
     f32 = jnp.float32
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(f32), router.astype(f32),
@@ -206,7 +206,7 @@ def topk_routing(x, router, bias, k: int, scale: float = 1.0,
         [jnp.where(experts[:, j:j + 1] == columns, scores, 0.0).sum(axis=-1)
          for j in range(k)], axis=-1)
     if normalize:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scale
 
 

@@ -724,3 +724,91 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
                20 * 128, 2048}
     assert model["vocab_size"] == 25008 and 25008 not in others
     print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
+
+
+def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``lfm2-8b-a1b.step-8k`` (published
+    layers 0, 2, 3, 4 and 5 at the published widths: four gated
+    short-convolution layers and one grouped-attention layer, a dense
+    feed-forward and four expert layers of 8 of 32 experts with no shared
+    one, a quarter of the vocabulary under a tied head), its step at 4 x
+    8,192 with recomputation, as the benchmark's family builds it: the plan
+    stays under the 14.5 GiB that ISSUE 52 set for this batch (13.60 read)
+    with the state's 6.09 GB as arguments. Every convolution is the Pallas
+    kernel pair over the in-projection's own [4, 8192, 6144] array: two
+    forward calls a layer (the recomputed block makes the output again:
+    nothing of it is kept) and one backward; attention is one flash call
+    forward and one backward, 32 query heads on 8 key-value heads of 64,
+    whose output ``ops.attention.remat_policy`` keeps. Each traced call
+    wrote its record into the runtime's ring. No array is shaped like a [T,
+    T] score matrix, and the vocabulary's 16,384 rows equal no other
+    dimension of the program."""
+    from ray_tpu._private import steptrace
+
+    worker, model, traffic = _cut_cell("lfm2-8b-a1b.step-8k")
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (4, 8192)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = built.step.lower(
+            params, opt_state, {"input_ids": ids, "labels": ids})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {"attn/grid_blocks", "conv/short",
+                            "model/layer_kinds", "attention/boundary",
+                            "moe/row_buffers"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "conv": 4, "full_attention": 1, "dense": 1, "expert": 4, "layers": 5,
+        "published_layers": 24}
+    assert {(e["heads"], e["kv_heads"], e["d_qk"], e["d_v"],
+             e["model_arrays"]) for e in by_name["attention/boundary"]} == {
+        (32, 8, 64, 64, 0)}
+    assert {e["backward"] for e in by_name["conv/short"]} == {0, 1}
+    cells = batch * seq * 2048 * 2
+    for e in by_name["conv/short"]:
+        assert e == {"channels": 2048, "taps": 3, "tokens": batch * seq,
+                     "sequences": batch, "backward": e["backward"],
+                     "bytes_needed": (7 * cells + 2 * 3 * 2048 * 4
+                                      if e["backward"]
+                                      else 4 * cells + 3 * 2048 * 4)}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 507_820_288
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?((?:flash|short_conv)_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "short_conv_fwd": 8,
+                     "short_conv_bwd": 4}
+    assert "bf16[128,8192,64]" in text and "bf16[32,8192,64]" in text
+    assert "bf16[4,8192,6144]" in text
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+        # 16,384 stands only beside the hidden size (the tied table, its
+        # gradient and moments) or as a loss chunk's logits
+        if 16384 in dims:
+            assert dims in {(16384, 2048), (16384, 2048, 1),
+                            (4, 1024, 16384)}, dims
+    others = {model[k] for k in ("hidden_size", "intermediate_size",
+                                 "moe_intermediate_size",
+                                 "num_experts_published")}
+    others |= {seq, batch * seq, batch * seq // 8,
+               batch * seq * model["num_experts_per_tok"], 3 * 2048,
+               2 * 1792, 32 * 64, 8 * 64}
+    assert model["vocab_size"] == 16384 and 16384 not in others
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
