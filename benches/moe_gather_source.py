@@ -1,8 +1,12 @@
 """What a gather of tokens x k rows back to the tokens costs by its source:
 the source's length, the rows of it that are touched, and the column blocks
 it is cut into. ``ops.moe._sum_of_pairs`` chooses its column blocks, and
-``held_expert_ffn`` its rungs, from this table. Run on the chip; prints one
-JSON line a case, ms a call.
+``ops.moe._gathered`` the lengths it cuts its source to
+(``_gather_sources``), from this table. Since PR 53 that is the layer's
+path only where the kernel ``to_tokens`` does not run (under a mesh, at
+shapes ``ops.moe._token_blocks`` refuses; off a TPU the table says nothing);
+``benches/moe_row_buffer.py`` times both. Run on the chip; prints one JSON
+line a case, ms a call.
 
     python benches/moe_gather_source.py
 

@@ -25,18 +25,22 @@ experts it holds, sorts the token-expert pairs that fall on them and runs
 grouped SwiGLU matmuls over the sorted rows (``jax.lax.ragged_dot``, which
 the TPU compiler lowers to its own grouped-matmul kernel whose grid follows
 the rows present), over as much of the row buffer as holds them, chunk by
-chunk (``row_buffer_rungs``, PR 32). Every pair of a held expert is computed,
-whatever the routing; what absent experts would add is left out and nothing
-stands in for them or for their exchange.
+chunk (``row_buffer_rungs``, PR 32), and takes the rows back to their tokens
+with a Pallas kernel that reads the rows that hold a pair (``to_tokens``, PR
+53: on a TPU under no mesh; a gather of tokens x k rows elsewhere). Every
+pair of a held expert is computed, whatever the routing; what absent experts
+would add is left out and nothing stands in for them or for their exchange.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import functools
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private import steptrace
 from ray_tpu.parallel.mesh_utils import traced_mesh_axes
@@ -272,9 +276,10 @@ def _walk(plan, fresh, of_chunk):
     nothing of the rest: a grouped matmul, which reads its groups' rows a
     tile of 512 at a time (a chunk at the cells' sizes is 8 such tiles, so
     a tile that holds a pair lies inside a walked chunk); another walk over
-    the same plan, a chunk at a time; a gather whose places past the count
-    are clamped and whose values there a select sets to zero
-    (``_sum_of_pairs``, ``d_weights``)."""
+    the same plan, a chunk at a time; the kernel ``to_tokens``, which sets to
+    zero what a chunk of its own holds outside an expert's range; a gather
+    whose places past the count are clamped and whose values there a select
+    sets to zero (``_sum_of_pairs``, ``d_weights``)."""
     pairs = plan["order"].shape[0]
     rungs = row_buffer_rungs(pairs)
     chunk, length = rungs[0], len(rungs) * rungs[0]
@@ -311,6 +316,10 @@ def _chunk(rows, start, like):
     return jax.lax.dynamic_slice_in_dim(rows, start, like.shape[0])
 
 
+# The way back to the tokens where no kernel runs (off a TPU, under a mesh, at
+# shapes ``_token_blocks`` refuses): ``_gathered``, a gather of tokens x k
+# rows and a sum over a token's pairs, and the kernel's reference.
+#
 # A gather of rows whose source is at most this large ran at the speed of its
 # writes on a v5e, a larger one at a fifth of it, whatever part of the source
 # its indices touch (benches/moe_gather_source.py, PR 32: 131,072 rows of
@@ -360,7 +369,7 @@ def _gather_sources(rows) -> Tuple[int, ...]:
         rows.shape[0],)
 
 
-def _to_tokens(rows, plan, weights=None):
+def _gathered(rows, plan, weights=None):
     """``_sum_of_pairs`` over the first of ``_gather_sources(rows)`` that
     holds the pairs present: a gather's cost follows its source's length,
     not the rows it touches, and the length has to be static."""
@@ -373,6 +382,189 @@ def _to_tokens(rows, plan, weights=None):
     return jax.lax.switch(
         sum(plan["present"] > n for n in sources[:-1]) + 0 * plan["present"],
         [from_the_first(n) for n in sources], rows, plan, weights)
+
+
+# What a block of tokens may keep in VMEM of the compiler's own 16 MiB: its
+# float32 sum, two blocks of the result and two chunks of rows (9 MiB at
+# blocks of 512 tokens of 2,048 bfloat16), beside the tables' blocks and a
+# column block's product.
+_TOKEN_BLOCK_BYTES = 10 * 2**20
+
+
+def _token_blocks(rows, plan) -> Optional[Tuple[int, int]]:
+    """(tokens a block, rows a chunk) of the kernel ``to_tokens`` over
+    ``rows`` (R, d), or None where it does not run: rows that are not whole
+    lane tiles of bfloat16 or float32, a buffer that is not whole chunks of
+    128 rows, tokens that no block of 128 or more divides."""
+    (tokens, _), (length, d) = plan["mine"].shape, rows.shape
+    size, chunk = rows.dtype.itemsize, 128
+    if (d % 128 or length % chunk
+            or rows.dtype not in (jnp.bfloat16, jnp.float32)):
+        return None
+    return next(((block, chunk) for block in (512, 256, 128)
+                 if tokens % block == 0
+                 and (block * (4 + 2 * size) + 2 * chunk * size) * d
+                 <= _TOKEN_BLOCK_BYTES), None)
+
+
+def _to_tokens_kernel(starts, counts, place_ref, *refs, held: int,
+                      weighted: bool):
+    """One block of tokens of ``_placed``. ``starts`` / ``counts`` (blocks x
+    held, in SMEM): where in ``rows_ref`` (HBM) each held expert's rows of
+    this block's tokens begin, and how many they are; ``place_ref`` (block,
+    held): the row of (token, expert), -1 where the token has none.
+
+    An expert's range is read in chunks that start at a whole tile of rows
+    at or before it, two in flight (the next expert's first among them).
+    What a chunk holds outside the range (another expert's or another
+    block's rows, what nobody wrote) is set to zero before the MXU places
+    the rows at their tokens: ``S @ rows`` with ``S`` the 0/1 matrix of
+    ``place``, which moves bits (a token has at most one row an expert) but
+    would make NaN of 0 x NaN."""
+    refs = list(refs)
+    weight_ref = refs.pop(0) if weighted else None
+    rows_ref, out_ref, sum_ref, chunk_ref, sems = refs
+    (block, d), (_, chunk, _) = sum_ref.shape, chunk_ref.shape
+    tile = 32 // rows_ref.dtype.itemsize        # rows of a tile in HBM
+    last = rows_ref.shape[0] - chunk
+    exact = (jax.lax.Precision.HIGHEST if rows_ref.dtype == jnp.float32
+             else None)
+    cols = min(d, 512)
+    at = pl.program_id(0) * held
+
+    def span(e):
+        start, count = starts[at + e], counts[at + e]
+        first = start // tile * tile
+        chunks = jnp.where(count > 0, pl.cdiv(start + count - first, chunk), 0)
+        return start, start + count, first, chunks
+
+    def base(first, c):
+        # the buffer's last chunk at the latest: its rows before this
+        # chunk's own are the one before's, and count as outside
+        return pl.multiple_of(jnp.minimum(first + c * chunk, last), tile)
+
+    def copy(first, c, slot):
+        return pltpu.make_async_copy(
+            rows_ref.at[pl.ds(base(first, c), chunk)], chunk_ref.at[slot],
+            sems.at[slot])
+
+    def an_expert(e, done, span, following, begun):
+        start, end, first, chunks = span
+
+        @pl.when((chunks > 0) & jnp.logical_not(begun))
+        def _():
+            copy(first, 0, done % 2).start()
+
+        place = place_ref[:, e:e + 1]
+        weight = weight_ref[:, e:e + 1] if weighted else None
+
+        def a_chunk(c, carry):
+            slot = (done + c) % 2
+            copy(first, c, slot).wait()
+
+            @pl.when(c + 1 < chunks)
+            def _():
+                copy(first, c + 1, 1 - slot).start()
+
+            if following is not None:
+                @pl.when((c + 1 == chunks) & (following[3] > 0))
+                def _():
+                    copy(following[2], 0, 1 - slot).start()
+
+            here = base(first, c)
+            row = here + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+            inside = (row >= jnp.maximum(start, first + c * chunk)) & (
+                row < end)
+            places = (place - here == jax.lax.broadcasted_iota(
+                jnp.int32, (block, chunk), 1)).astype(rows_ref.dtype)
+            for c0 in range(0, d, cols):
+                rows = chunk_ref[slot, :, c0:c0 + cols]
+                placed = jnp.dot(
+                    places, jnp.where(inside, rows, jnp.zeros_like(rows)),
+                    preferred_element_type=jnp.float32, precision=exact)
+                sum_ref[:, c0:c0 + cols] += (
+                    placed * weight if weighted else placed)
+            return carry
+
+        jax.lax.fori_loop(0, chunks, a_chunk, 0)
+        return done + chunks
+
+    sum_ref[...] = jnp.zeros_like(sum_ref)
+    spans = [span(e) for e in range(held)]
+    done = 0
+    for e in range(held):
+        done = an_expert(
+            e, done, spans[e], spans[e + 1] if e + 1 < held else None,
+            spans[e - 1][3] > 0 if e else False)
+    out_ref[...] = sum_ref[...].astype(out_ref.dtype)
+
+
+def _block_ranges(plan, block: int):
+    """(starts, counts), each (T / block, held): where in the sorted rows
+    each held expert's pairs on a block of tokens begin (its group's start
+    plus its pairs on earlier blocks), and how many they are."""
+    (tokens, k), held = plan["mine"].shape, plan["tokens"].shape[0]
+    counts = (plan["local"].reshape(tokens // block, block * k, 1)
+              == jnp.arange(held)).sum(axis=1, dtype=jnp.int32)
+    return (jnp.cumsum(plan["tokens"]) - plan["tokens"]
+            + jnp.cumsum(counts, axis=0) - counts), counts
+
+
+def _placed(rows, plan, weights, block: int, chunk: int,
+            interpret: bool = False):
+    """``_sum_of_pairs`` rounded to ``rows``' type, by the kernel
+    ``to_tokens``: its cost follows the rows that hold a pair, not tokens x
+    k. The sort is stable over the pairs in the tokens' order and a token
+    has at most one pair an expert (``topk_routing``'s k experts differ), so
+    the rows that held expert ``e`` owes a block of tokens are one range of
+    ``rows``, at most a block long: the group's start plus ``e``'s pairs on
+    earlier blocks. The kernel reads each range in chunks of ``chunk`` rows
+    and sums in float32, a token's pairs in the order of their experts
+    (``_sum_of_pairs``: in the order of the k choices)."""
+    (tokens, k), held = plan["mine"].shape, plan["tokens"].shape[0]
+    local, experts = plan["local"], jnp.arange(held)
+    starts, counts = _block_ranges(plan, block)
+
+    def by_expert(of_pairs):
+        """(T, k) -> (T, held), by comparison as ``topk_routing``'s weights"""
+        return sum(jnp.where(local[:, j:j + 1] == experts,
+                             of_pairs[:, j:j + 1], 0) for j in range(k))
+
+    tables = [by_expert(plan["inverse"].reshape(tokens, k) + 1) - 1]
+    if weights is not None:
+        tables.append(by_expert(weights.astype(jnp.float32)))
+    d = rows.shape[1]
+    return pl.pallas_call(
+        functools.partial(_to_tokens_kernel, held=held,
+                          weighted=weights is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(tokens // block,),
+            in_specs=[pl.BlockSpec((block, held), lambda b, *_: (b, 0))
+                      for _ in tables] + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block, d), lambda b, *_: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                            pltpu.VMEM((2, chunk, d), rows.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), rows.dtype),
+        interpret=interpret, name="to_tokens",
+    )(starts.reshape(-1), counts.reshape(-1), *tables, rows)
+
+
+def _to_tokens(rows, plan, weights, fresh, backward: bool):
+    """The way from the sorted ``rows`` back to the tokens: the kernel
+    (``_placed``) where ``_fresh_buffer`` chose ``_unwritten`` and
+    ``_token_blocks`` finds its blocks, else the gathers (``_gathered``).
+    One ``counters`` record ``moe/to_tokens`` a traced pass says which, and
+    the ``slots`` (tokens x k) a gather's result holds."""
+    blocks = _token_blocks(rows, plan) if fresh is _unwritten else None
+    block, chunk = blocks or (0, 0)
+    steptrace.record_counters("moe/to_tokens", {
+        "kernel": int(blocks is not None), "slots": plan["mine"].size,
+        "tokens": plan["mine"].shape[0], "block": block, "chunk": chunk,
+        "held": plan["tokens"].shape[0], "backward": int(backward)})
+    if blocks is None:
+        return _gathered(rows, plan, weights)
+    return _placed(rows, plan, weights, block, chunk)
 
 
 def _swiglu(hidden):
@@ -391,7 +583,8 @@ def _rows_forward(x, weights, wi, wo, plan):
         _swiglu(_chunk(hidden, start, pair)),))
     out = jax.lax.ragged_dot(act, wo.astype(x.dtype), sizes)
     _count_row_buffers(fresh, (rows, act), backward=False)
-    return _to_tokens(out, plan, weights).astype(x.dtype)
+    return _to_tokens(out, plan, weights, fresh,
+                      backward=False).astype(x.dtype)
 
 
 @jax.jit
@@ -425,7 +618,8 @@ def _rows_backward(x, weights, wi, wo, plan, g):
     d_rows = jax.lax.ragged_dot(d_hidden, wi_x.swapaxes(1, 2), sizes)
     d_weights = jnp.where(plan["mine"], _take_rows(d_scale, jnp.minimum(
         plan["inverse"], d_scale.shape[0] - 1)).reshape(weights.shape), 0.0)
-    return (_to_tokens(d_rows, plan).astype(x.dtype),
+    return (_to_tokens(d_rows, plan, None, fresh,
+                       backward=True).astype(x.dtype),
             d_weights.astype(weights.dtype),
             for_the_matrices(rows, wi_x, d_hidden).astype(wi.dtype),
             for_the_matrices(act_scaled, wo_x, g_rows).astype(wo.dtype))
@@ -436,7 +630,8 @@ def _held_rows(x, weights, wi, wo, plan):
     """``held_expert_ffn``'s row work: gather, grouped SwiGLU, back to the
     tokens. The grouped matmuls run over whole buffers and follow the rows
     present by themselves; what is not a matmul walks the buffer only as far
-    as the pairs present (``_walk``), or reads that far (``_to_tokens``).
+    as the pairs present (``_walk``), or reads the rows that hold a pair
+    (``_to_tokens``).
     Past the last walked chunk a row buffer holds what nobody wrote, of the
     walked ones (``rows``, ``act``; backward ``rows``, ``g_rows``,
     ``d_hidden``, ``act_scaled``, ``d_scale``) as of the grouped matmuls'
@@ -459,9 +654,11 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     """The held experts' part of a routed SwiGLU layer.
 
     ``x`` (T, d); ``experts`` / ``weights`` (T, k) from ``topk_routing``
-    over all E experts; ``wi`` (held, d, 2 x width) holds gate and up side
-    by side and ``wo`` (held, width, d) the way down, of the experts
-    ``index * held ... (index + 1) * held - 1``: shard ``index`` of ``of``.
+    over all E experts (a token's k experts differ: the kernel back to the
+    tokens counts on at most one row a token and expert); ``wi`` (held, d,
+    2 x width) holds gate and up side by side and ``wo`` (held, width, d)
+    the way down, of the experts ``index * held ... (index + 1) * held -
+    1``: shard ``index`` of ``of``.
     -> (y (T, d), tokens (held,) int32): ``y = sum over the token's pairs
     on held experts of weight x expert(x)``, and how many tokens each held
     expert received.
@@ -474,35 +671,50 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     work follows the pairs present, ``tokens.sum()``: the matmuls' kernel by
     itself, the gathers into the buffer and the passes over the hidden rows
     in loops that stop after the chunk that holds the last pair
-    (``_walk``), the gathers back to the tokens by reading a source cut to
-    that length (``_to_tokens``), forward and again backward. On a TPU the
-    rest of every buffer is never written (PR 46: ``_walk`` starts from
-    ``_unwritten`` memory where it filled 2.25 GiB a layer a step with
-    zeros) and never read: no result and no gradient depends on it.
+    (``_walk``), the way back to the tokens by reading, a block of tokens
+    at a time, each held expert's range of rows (the kernel ``to_tokens``
+    on a TPU under no mesh; elsewhere gathers of tokens x k rows from a
+    source cut to the pairs present: ``_to_tokens``), forward and again
+    backward. On a TPU the rest of every buffer is never written (PR 46:
+    ``_walk`` starts from ``_unwritten`` memory where it filled 2.25 GiB a
+    layer a step with zeros) and never read: no result and no gradient
+    depends on it.
 
-    What that buys (``benches/moe_row_buffer.py`` on a v5e, PRs 32 and 46:
-    the layer alone, forward plus backward, T = 16,384, k = 8, d = 2,048,
-    experts 768 wide, 16 held; ms; PR 31's function, every pass over the
-    whole buffer, beside PR 32's, whose loops started from zeros, and this
-    one, whose loops start from unwritten memory, and the rows it walks)::
+    What that buys (``benches/moe_row_buffer.py`` on a v5e, re-read by PR
+    53: the layer alone, forward plus backward, T = 16,384, k = 8, d =
+    2,048, experts 768 wide, 16 held; ms; PR 31's function, every pass over
+    the whole buffer, beside PR 46's, whose loops start from unwritten
+    memory and whose way back to the tokens is the gathers', and this one,
+    whose way back is the kernel ``to_tokens``, and the rows it walks)::
 
-        pairs present    PR 31's    PR 32's    this function
-                8,200       27.3       12.2      9.6   (12,288)
-               16,384       29.0       13.9     11.2   (16,384)
-               25,000       31.1       16.5     14.0   (28,672)
-               45,000       35.5       21.5     19.0   (45,056)
-               65,536       40.0       28.4     26.0   (65,536)
-               90,000       45.5       34.8     32.6   (90,112)
-              131,072       54.4       46.7     44.7  (131,072)
+        pairs present    PR 31's    PR 46's    this function
+                8,200       28.3       14.1     10.9   (12,288)
+               16,384       30.2       16.8     13.3   (16,384)
+               25,000       32.3       20.5     17.1   (28,672)
+               45,000       36.6       27.5     23.9   (45,056)
+               65,536       41.2       38.4     32.4   (65,536)
+              131,072       55.6       66.2     57.6  (131,072)
 
-    The bench differentiates a loss that is linear in the result, so only
-    the backward pass's five buffers are in it (1.56 GiB of zeros, 1.7 ms a
-    GiB); a training step fills the forward's two as well.
+    The bench hands back the layer's result beside the gradients, so both
+    passes are in it (until PR 53 its loss was linear in a result nobody
+    read, and the forward pass was dead: 9.6 where this table has 14.1).
 
-    The gathers back to the tokens step at 49,152 and 98,304 pairs (about
-    1.5 ms each way: ``_gather_sources``); the rest is the rows'."""
-    held = wi.shape[0]
+    The way back to the tokens alone, a pass (the same bench): 1.2-1.3 ms by
+    the kernel from 8,200 to 45,000 pairs (512 ranges of rows, one chunk of
+    128 each, whatever the load) and 2.5-2.7 at all 131,072, where the
+    gathers took 2.6 up to 49,152 pairs, 4.1 up to 98,304 and 5.5 beyond
+    (``_gather_sources``); the rest is the rows'."""
     assert 0 <= index < of, (index, of)
+    plan = _plan(experts, wi.shape[0], index)
+    return _held_rows(x, weights, wi, wo, plan), plan["tokens"]
+
+
+def _plan(experts, held: int, index: int):
+    """What ``_held_rows`` needs of a routing ``experts`` (T, k) on a chip
+    that holds experts ``index * held ...``: the sort of the pairs by held
+    expert, its inverse, which pairs are ``mine``, their ``local`` expert
+    (``held`` for the others), each held expert's ``tokens`` and how many
+    pairs are ``present``."""
     local = experts - index * held
     mine = (local >= 0) & (local < held)
     key = jnp.where(mine, local, held).reshape(-1)          # (T * k,)
@@ -516,10 +728,11 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     # buffers the loops fill (``_walk``) are left as found past the last
     # walked chunk too. Rows past the count belong to no pair that is
     # ``mine``: they are set to zero where they would reach a token
-    # (``_sum_of_pairs``, forward and backward; ``d_weights``) and are
-    # read nowhere else (a group's matmul reads its own rows only).
-    # Left in, they gave a toy configuration NaN and the full one a finite
-    # loss that fell a tenth as fast (PERF.md section 6, PR 31).
-    plan = {"order": order, "inverse": inverse, "mine": mine,
-            "tokens": tokens, "present": tokens.sum()}
-    return _held_rows(x, weights, wi, wo, plan), tokens
+    # (``_to_tokens_kernel`` and ``_sum_of_pairs``, forward and backward;
+    # ``d_weights``) and are read nowhere else (a group's matmul reads its
+    # own rows only). Left in, they gave a toy configuration NaN and the
+    # full one a finite loss that fell a tenth as fast (PERF.md section 6,
+    # PR 31).
+    return {"order": order, "inverse": inverse, "mine": mine,
+            "local": key.reshape(mine.shape), "tokens": tokens,
+            "present": tokens.sum()}

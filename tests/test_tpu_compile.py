@@ -424,6 +424,31 @@ def _row_buffer_census(text, drawn, pairs, d, width, unwritten):
         r"custom-call\(\)", entry)) == unwritten
 
 
+def _to_tokens_census(text, drawn, tokens, k, d, held, calls):
+    """The expert layers' way back to the tokens in a compiled step ``text``
+    (PR 53): ``calls`` calls of the kernel ``to_tokens``, one a pass of a
+    layer that the executable keeps; no branch over cut gather sources; no
+    ``gather`` and no ``reduce`` that forms or reads a [tokens x k, rows'
+    width] or [tokens, k, rows' width] array (whole rows, or the halves and
+    quarters of their columns that the gathers were cut into); and each
+    traced pass wrote one ``moe/to_tokens`` record that says ``kernel``."""
+    assert len(re.findall(
+        r'^\s*%?to_tokens[\w.\-]* = .*custom_call_target="tpu_custom_call"',
+        text, re.M)) == calls
+    assert "conditional(" not in text
+    widths = "|".join(str(d // parts) for parts in (1, 2, 4))
+    slots = re.compile(rf"\[(?:{tokens * k}|{tokens},{k}),(?:{widths})\]")
+    assert not [line for line in text.splitlines()
+                if re.search(r" (?:gather|reduce)\(", line)
+                and slots.search(line)]
+    records = {tuple(sorted(e["args"].items())) for e in drawn
+               if e["name"] == "moe/to_tokens"}
+    assert records == {tuple(sorted({
+        "kernel": 1, "slots": tokens * k, "tokens": tokens, "block": 512,
+        "chunk": 128, "held": held, "backward": backward}.items()))
+        for backward in (0, 1)}
+
+
 def _cut_cell(name="joyai-llm-flash.step-8k"):
     from perfbench import run, worker
 
@@ -480,6 +505,7 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
         steptrace.set_enabled(False)
     assert {e["name"] for e in drawn} == {"attn/grid_blocks",
                                           "moe/row_buffers",
+                                          "moe/to_tokens",
                                           "attention/boundary"}
     # several blocks of keys a head: the boundary the kernels were measured
     # with, [B x H, T, d] operands made by XLA
@@ -510,12 +536,15 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     # backward pass (the recomputed forward's are dead: the backward pass
     # recomputes its own rows). What is no matmul walks the buffers only as
     # far as the pairs present, in loops that stand once in the executable
-    # (two forward, two backward), and the gathers back to the tokens are cut
-    # to the pairs present under a branch (one forward, one backward).
+    # (two forward, two backward), and the rows go back to the tokens
+    # through the kernel ``to_tokens`` (one call forward, one backward),
+    # which reads the rows that hold a pair.
     pairs = tokens * model["num_experts_per_tok"]
     rungs = moe.row_buffer_rungs(pairs)
     assert len(rungs) > 8 and rungs[-1] == pairs
-    assert text.count("conditional(") == 2 * 5
+    _to_tokens_census(text, drawn, tokens, model["num_experts_per_tok"],
+                      model["hidden_size"], model["n_routed_experts"],
+                      calls=2 * 5)
     walked = [line.split(" while(")[0] for line in text.splitlines()
               if " while(" in line and f"[{pairs}," in line.split(" while(")[0]]
     assert len(walked) == 4 * 5
@@ -616,10 +645,12 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     # the four expert layers: two loops forward, two in the recomputed
     # forward (a block's last norm reads the layer's result, so here it is
     # not dead) and two backward, each over row buffers tokens x k long that
-    # nobody filled (2 + 2 + 5 a layer), the gathers back under a branch in
-    # each of the three passes
+    # nobody filled (2 + 2 + 5 a layer), the way back to the tokens one
+    # call of the kernel ``to_tokens`` in each of the three passes
     pairs = seq * model["num_experts_per_tok"]
-    assert text.count("conditional(") == 3 * 4
+    _to_tokens_census(text, counters, seq, model["num_experts_per_tok"],
+                      model["hidden_size"], model["num_experts"],
+                      calls=3 * 4)
     assert len([line for line in text.splitlines() if " while(" in line
                 and f"[{pairs}," in line.split(" while(")[0]]) == 6 * 4
     _row_buffer_census(text, counters, pairs, model["hidden_size"],
@@ -768,7 +799,7 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "conv/short",
                             "model/layer_kinds", "attention/boundary",
-                            "moe/row_buffers"}
+                            "moe/row_buffers", "moe/to_tokens"}
     assert by_name["model/layer_kinds"][-1] == {
         "conv": 4, "full_attention": 1, "dense": 1, "expert": 4, "layers": 5,
         "published_layers": 24}
@@ -796,6 +827,12 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
                      "short_conv_bwd": 4}
     assert "bf16[128,8192,64]" in text and "bf16[32,8192,64]" in text
     assert "bf16[4,8192,6144]" in text
+    # the four expert layers' rows go back to the tokens through the kernel
+    # ``to_tokens``, forward and backward (no recomputed forward of the
+    # layer is live): 8 calls where 8 branches of gathers stood
+    _to_tokens_census(text, counters, batch * seq,
+                      model["num_experts_per_tok"], model["hidden_size"],
+                      model["num_experts"], calls=2 * 4)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
