@@ -1483,6 +1483,8 @@ class GcsServer:
         processes, _ = await self._scrape_processes(
             "steptrace_node", "steptrace_snapshot",
             cfg.steptrace_scrape_timeout_s, tag_drivers=True)
+        processes.append(steptrace.process_snapshot(
+            {"node_id": "gcs", "role": "gcs"}))
         agg = self._steptrace_agg
         if agg is None:
             agg = self._steptrace_agg = steptrace.SkewAggregator()
@@ -1501,6 +1503,12 @@ class GcsServer:
         merged["processes"] = len(processes)
         merged["errors"] = [proc for proc in processes
                             if proc.get("error")]
+        # each ring reached, by process: a reader that needs a process's
+        # WHOLE record (set-up's account) refuses one that dropped any
+        merged["rings"] = [
+            {"node_id": proc.get("node_id"), "pid": proc.get("pid"),
+             "dropped": proc.get("dropped", 0)}
+            for proc in processes if not proc.get("error")]
         return merged
 
     # ------------------------------------------------------------------

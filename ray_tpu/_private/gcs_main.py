@@ -3,6 +3,10 @@ gcs_server_main.cc)."""
 
 from __future__ import annotations
 
+import time
+
+_T_FIRST_LINE = time.time()  # the package itself is imported by now
+
 import argparse
 import asyncio
 import logging
@@ -13,12 +17,20 @@ async def amain(args):
     from ray_tpu._private.rpcio import enable_eager_tasks
 
     enable_eager_tasks(asyncio.get_running_loop())
+    from ray_tpu._private import steptrace
     from ray_tpu._private.gcs import GcsServer
 
-    server = GcsServer(host=args.host, port=args.port,
-                       persist_path=args.persist_path,
-                       cluster_id=args.cluster_id)
-    port = await server.start()
+    # this process's own part of the driver's ``init/gcs``: the interpreter
+    # and every import, then the server's start, which loads the native
+    # library and BUILDS it (``make -C src``, seconds) where the tree has
+    # none or an older one than its sources
+    steptrace.record_phase(
+        "gcs/boot", steptrace.process_began(_T_FIRST_LINE), time.time())
+    with steptrace.span("gcs/server"):
+        server = GcsServer(host=args.host, port=args.port,
+                           persist_path=args.persist_path,
+                           cluster_id=args.cluster_id)
+        port = await server.start()
     if args.port_file:
         tmp = args.port_file + ".tmp"
         with open(tmp, "w") as f:

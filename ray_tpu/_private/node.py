@@ -33,6 +33,8 @@ import time
 import uuid
 from typing import Callable, Dict, Optional
 
+from ray_tpu._private import steptrace
+
 logger = logging.getLogger(__name__)
 
 DEFAULT_SESSION_ROOT = "/dev/shm/ray_tpu" if os.path.isdir("/dev/shm") else None
@@ -233,15 +235,17 @@ class NodeProcesses:
         self.gcs_persist_path = os.path.join(self.session_dir, "gcs_store.log")
         if head:
             port_file = os.path.join(self.session_dir, f"gcs_port_{suffix}")
-            self.gcs_proc = _spawn(
-                [sys.executable, "-m", "ray_tpu._private.gcs_main",
-                 "--host", gcs_host, "--port", "0", "--port-file", port_file,
-                 "--persist-path", self.gcs_persist_path,
-                 "--cluster-id", os.path.basename(self.session_dir)],
-                os.path.join(self.logs, "gcs.out"),
-                env=package_env(),
-            )
-            self.gcs_port = int(_wait_port_file(port_file)[0])
+            with steptrace.span("init/gcs"):
+                self.gcs_proc = _spawn(
+                    [sys.executable, "-m", "ray_tpu._private.gcs_main",
+                     "--host", gcs_host, "--port", "0",
+                     "--port-file", port_file,
+                     "--persist-path", self.gcs_persist_path,
+                     "--cluster-id", os.path.basename(self.session_dir)],
+                    os.path.join(self.logs, "gcs.out"),
+                    env=package_env(),
+                )
+                self.gcs_port = int(_wait_port_file(port_file)[0])
         else:
             assert gcs_port is not None
             self.gcs_port = gcs_port
@@ -256,11 +260,12 @@ class NodeProcesses:
             cmd += ["--resources", json.dumps(resources)]
         if labels is not None:
             cmd += ["--labels", json.dumps(labels)]
-        self.raylet_proc = _spawn(
-            cmd, os.path.join(self.logs, f"raylet_{suffix}.out"),
-            env=package_env(),
-        )
-        lines = _wait_port_file(raylet_port_file)
+        with steptrace.span("init/raylet"):
+            self.raylet_proc = _spawn(
+                cmd, os.path.join(self.logs, f"raylet_{suffix}.out"),
+                env=package_env(),
+            )
+            lines = _wait_port_file(raylet_port_file)
         self.raylet_port = int(lines[0])
         self.node_id = lines[1] if len(lines) > 1 else None
 

@@ -220,9 +220,10 @@ def record_chunk(group: str, seq: int, chunk: int, op: str, rank: int,
 
 def record_phase(name: str, start: float, end: float,
                  step: Optional[int] = None, rank: Optional[int] = None,
-                 n: Optional[int] = None):
+                 n: Optional[int] = None, thread: Optional[str] = None):
     """``n`` is the span's count (rows or bytes), taken at the same
-    boundary as its times."""
+    boundary as its times; ``thread`` the name of the thread the span ran
+    on where that is not the process's main thread."""
     global _events, _idx
     if not _enabled:
         return
@@ -232,7 +233,7 @@ def record_phase(name: str, start: float, end: float,
     _events += 1
     ring[_idx % _ring_size] = (
         "phase", _idx, _step if step is None else step, name,
-        _rank if rank is None else rank, start, end, n)
+        _rank if rank is None else rank, start, end, n, thread)
     _idx += 1
 
 
@@ -270,6 +271,22 @@ def record_compile(name: str, start: float, end: float, first: bool,
     ring[_idx % _ring_size] = ("compile", _idx, name, bool(first), _rank,
                                start, end, _step, part, cache, retrieval_s)
     _idx += 1
+
+
+def process_began(first_line: float) -> float:
+    """Epoch seconds at which the kernel started this process, to a clock
+    tick (/proc): the interpreter's start and the package's import run
+    before a ``*_main`` module's first line and are most of a process's
+    boot. Where /proc cannot say, or says what cannot be, ``first_line``
+    (that module's own ``time.time()``)."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        began = time.time() - (time.clock_gettime(time.CLOCK_BOOTTIME)
+                               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return first_line
+    return began if first_line - 120.0 < began <= first_line else first_line
 
 
 def record_restart(cause: str, start: float, end: float, generation: int):
@@ -333,6 +350,7 @@ def clear_train_context():
 
 
 _annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+_MAIN_THREAD = threading.main_thread()
 
 
 def _annotation_class():
@@ -379,8 +397,11 @@ class span:
             end = time.time()
             if self._ann is not None:
                 self._ann.__exit__(*exc)
+            thread = threading.current_thread()
             record_phase(self.name, self._t0, end, step=self._step,
-                         n=self.n)
+                         n=self.n,
+                         thread=None if thread is _MAIN_THREAD
+                         else thread.name)
         return False
 
 
@@ -502,6 +523,8 @@ def snapshot() -> List[dict]:
             out.append({"kind": "phase", "idx": rec[1], "step": rec[2],
                         "phase": rec[3], "rank": rec[4], "start": rec[5],
                         "end": rec[6], "n": rec[7]})
+            if len(rec) > 8 and rec[8]:
+                out[-1]["thread"] = rec[8]
         elif kind == "step":
             out.append({"kind": "step", "idx": rec[1], "step": rec[2],
                         "rank": rec[3], "start": rec[4], "end": rec[5]})
@@ -665,13 +688,23 @@ def merge_processes(processes: Sequence[dict]) -> Dict[str, Any]:
     return merge_records(flat)
 
 
+_DRIVER_ROW = -1  # Chrome-trace pid of the row ``driver``
+
+
 def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
     """Render a merged view (``merge_processes`` output) as Chrome-trace
     JSON events — loadable in Perfetto / chrome://tracing. One process
     row per rank; step/phase/collective/compile slices on named
     threads; collective slices carry the merged skew attribution in
     ``args``; ``counters`` records are counter events ("ph": "C") named
-    as the record, their values the series."""
+    as the record, their values the series. A phase lies on the lane
+    ``phases``, or ``phases:<thread>`` where it ran off its process's main
+    thread (``save/commit`` across the loop's slices). The spans of a
+    driver (a ``node_id`` that begins ``driver:``: ``init``, ``gang/*``,
+    ``ckpt/persist``) and its ``restart`` records share the row
+    ``driver``, the GCS's own start the row ``gcs``; a ``worker/boot`` lies
+    in the row of the rank its process then took, or in a row
+    ``worker <pid>`` where it took none."""
     trace: List[dict] = []
     seen_ranks = set()
 
@@ -682,6 +715,18 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
         trace.append({"name": "process_name", "ph": "M", "pid": rank,
                       "args": {"name": f"rank {rank}"}})
 
+    rows: Dict[str, int] = {}  # processes that are no rank: name -> pid < 0
+
+    def named_row(name, pid=None):
+        if name not in rows:
+            if pid is None:
+                pid = min(rows.values(), default=_DRIVER_ROW) - 1
+            rows[name] = pid
+            seen_ranks.add(pid)
+            trace.append({"name": "process_name", "ph": "M", "pid": pid,
+                          "args": {"name": name}})
+        return rows[name]
+
     for rec in merged.get("steps", ()):
         proc_meta(rec["rank"])
         trace.append({
@@ -691,13 +736,30 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
             "pid": rec["rank"], "tid": "step",
             "args": {"step": rec["step"]},
         })
+    # a worker's boot is recorded before its train session gives it a
+    # rank: it is drawn in the row of the rank that process then took
+    session_rank = {(rec.get("node_id"), rec.get("pid")): rec["rank"]
+                    for rec in merged.get("phases", ())
+                    if rec["phase"] == "gang/session"}
     for rec in merged.get("phases", ()):
-        proc_meta(rec["rank"])
+        process = (rec.get("node_id"), rec.get("pid"))
+        if str(process[0]).startswith("driver:"):
+            row = named_row("driver", _DRIVER_ROW)
+        elif process[0] == "gcs":  # its own start: ``gcs/boot``, ``gcs/server``
+            row = named_row("gcs")
+        elif rec["phase"].startswith("worker/"):
+            row = session_rank.get(process)
+            if row is None:  # started ahead of need, never a gang's
+                row = named_row(f"worker {process[1]}")
+        else:
+            row = rec["rank"]
+        proc_meta(row)
+        thread = rec.get("thread")
         trace.append({
             "name": rec["phase"], "cat": "phase", "ph": "X",
             "ts": rec["start"] * 1e6,
             "dur": max((rec["end"] - rec["start"]) * 1e6, 1.0),
-            "pid": rec["rank"], "tid": "phases",
+            "pid": row, "tid": f"phases:{thread}" if thread else "phases",
             "args": ({"step": rec["step"]} if rec.get("n") is None
                      else {"step": rec["step"], "n": rec["n"]}),
         })
@@ -749,17 +811,13 @@ def chrome_trace(merged: Dict[str, Any]) -> List[dict]:
             "ts": rec["start"] * 1e6, "pid": rec["rank"],
             "args": dict(rec["values"]),
         })
-    restarts = merged.get("restarts", ())
-    if restarts:
-        trace.append({"name": "process_name", "ph": "M", "pid": -1,
-                      "args": {"name": "driver (recovery)"}})
-    for rec in restarts:
+    for rec in merged.get("restarts", ()):
         trace.append({
             "name": f"restart[{rec['cause']}] -> gen {rec['generation']}",
             "cat": "restart", "ph": "X",
             "ts": rec["start"] * 1e6,
             "dur": max((rec["end"] - rec["start"]) * 1e6, 1.0),
-            "pid": -1, "tid": "recovery",
+            "pid": named_row("driver", _DRIVER_ROW), "tid": "recovery",
             "args": {"cause": rec["cause"],
                      "generation": rec["generation"],
                      "recovery_s": rec["end"] - rec["start"]},

@@ -1837,7 +1837,12 @@ class CoreWorker:
         ranks by (group, seq) into arrival-skew attribution."""
         from ray_tpu._private import steptrace
 
-        return self._annotate_profile(steptrace.process_snapshot())
+        out = self._annotate_profile(steptrace.process_snapshot())
+        if self.is_driver:
+            # a driver is no worker of its raylet's node: its spans (the
+            # cluster's start, the gang's) get a row of their own
+            out["node_id"] = f"driver:{self.client_id}"
+        return out
 
     # -- request observatory (reqtrace.py) -----------------------------
     async def rpc_reqtrace_snapshot(self, conn: Connection, p):

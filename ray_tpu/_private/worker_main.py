@@ -4,6 +4,10 @@ the task executor, and serve until told to exit."""
 
 from __future__ import annotations
 
+import time
+
+_T_FIRST_LINE = time.time()  # the package itself is imported by now
+
 import atexit
 import logging
 import os
@@ -113,6 +117,13 @@ def main():
         TaskExecutor(cw)
         global_worker.core_worker = cw
         global_worker.mode = "worker"
+        # registered with the raylet and ready for work. The raylet starts
+        # workers ahead of need, so this may lie before the gang that gets
+        # the process was asked for: the record says when it was.
+        from ray_tpu._private import steptrace
+
+        steptrace.record_phase(
+            "worker/boot", steptrace.process_began(_T_FIRST_LINE), time.time())
 
         # Exit when our raylet goes away (the raylet owns worker
         # lifetimes). Runs ON the io loop: only stdio can flush here —

@@ -10,8 +10,10 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
+from ray_tpu._private import steptrace
 from ray_tpu._private.common import SchedulingStrategy
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 from ray_tpu._private.ids import ActorID
@@ -62,6 +64,7 @@ def init(
     ray parity: ray.init (python/ray/_private/worker.py:1108). With no
     address, starts a head node (GCS + raylet) owned by this process.
     """
+    entered = time.time()
     with _init_lock:
         if global_worker.connected:
             if ignore_reinit_error:
@@ -117,19 +120,20 @@ def init(
             if not alive:
                 raise ConnectionError(f"no alive nodes in cluster at {address}")
             raylet_host, raylet_port = alive[0]["host"], alive[0]["port"]
-        cw = CoreWorker(
-            raylet_host=raylet_host,
-            raylet_port=int(raylet_port),
-            gcs_host=gcs_host,
-            gcs_port=int(gcs_port),
-            is_driver=True,
-            namespace=namespace,
-        )
-        global_worker.core_worker = cw
-        global_worker.mode = "driver"
-        # with no subscribers, raylets skip tailing too
-        if log_to_driver:
-            _subscribe_worker_logs(cw)
+        with steptrace.span("init/connect"):
+            cw = CoreWorker(
+                raylet_host=raylet_host,
+                raylet_port=int(raylet_port),
+                gcs_host=gcs_host,
+                gcs_port=int(gcs_port),
+                is_driver=True,
+                namespace=namespace,
+            )
+            global_worker.core_worker = cw
+            global_worker.mode = "driver"
+            # with no subscribers, raylets skip tailing too
+            if log_to_driver:
+                _subscribe_worker_logs(cw)
         # local usage snapshot (reference: usage_lib's session report;
         # this build never phones home — see usage_lib docstring)
         if global_worker.node is not None:
@@ -142,6 +146,9 @@ def init(
                     )
             except Exception:
                 pass
+        # the start-up path's own account (the step observatory's ring):
+        # ``init`` holds ``init/gcs``, ``init/raylet``, ``init/connect``
+        steptrace.record_phase("init", entered, time.time())
         return RayContext(address, cw.node_id)
 
 

@@ -19,6 +19,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ray_tpu._private import steptrace
+
 
 @dataclass
 class BackendConfig:
@@ -119,22 +121,25 @@ def _chip_holders(node: str) -> List[int]:
 
 def _wait_for_chips() -> float:
     """Wait until every chip node of this host can be opened, at most
-    CHIPS_FREE_DEADLINE_S; log and return the seconds waited. The job
+    CHIPS_FREE_DEADLINE_S; log and return the seconds waited (the span
+    ``gang/chip_wait`` holds the same, its count the probes made). The job
     before on this host may have returned with its worker still exiting
     (an older runtime, a killed driver or raylet), and jax's open of a
     held chip fails once, from the user's loop, with no retry."""
     start = time.monotonic()
-    while (node := _busy_chip_node()) is not None:
-        waited = time.monotonic() - start
-        if waited >= CHIPS_FREE_DEADLINE_S:
-            holders = _chip_holders(node)
-            raise RuntimeError(
-                f"{node} is still held after {waited:.1f} s"
-                + (f" by pid {', '.join(map(str, holders))}" if holders
-                   else " (no live holder in /proc: a process still exiting,"
-                        " or another user's)")
-                + ": another job on this host has the chips")
-        time.sleep(_CHIPS_POLL_S)
+    with steptrace.span("gang/chip_wait", 1) as probes:
+        while (node := _busy_chip_node()) is not None:
+            waited = time.monotonic() - start
+            if waited >= CHIPS_FREE_DEADLINE_S:
+                holders = _chip_holders(node)
+                raise RuntimeError(
+                    f"{node} is still held after {waited:.1f} s"
+                    + (f" by pid {', '.join(map(str, holders))}" if holders
+                       else " (no live holder in /proc: a process still exiting,"
+                            " or another user's)")
+                    + ": another job on this host has the chips")
+            time.sleep(_CHIPS_POLL_S)
+            probes.n += 1
     waited = time.monotonic() - start
     print(f"ray_tpu: waited {waited:.1f} s for this host's chips", flush=True)
     return waited

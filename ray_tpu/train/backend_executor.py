@@ -195,30 +195,38 @@ class BackendExecutor:
         self._runtime_env = runtime_env
         bundles = self.scaling.as_placement_group_bundles()
         strategy = self.scaling.placement_strategy
-        self.pg = placement_group(bundles, strategy=strategy)
-        if not self.pg.wait(120):
+        # the gang's start records itself (``gang/*`` here, ``worker/boot``
+        # and ``gang/*`` in each worker's ring): a restarted gang records
+        # the same spans, inside its ``restart`` record
+        with steptrace.span("gang/placement", len(bundles)):
+            self.pg = placement_group(bundles, strategy=strategy)
+            placed = self.pg.wait(120)
+        if not placed:
             raise TrainingFailedError(
                 f"placement group infeasible: {bundles} ({strategy})"
             )
-        self.worker_group = WorkerGroup(
-            self.scaling.num_workers,
-            self.scaling.worker_resources(),
-            placement_group=self.pg,
-            runtime_env=runtime_env,
-            generation=generation,
-        )
-        # rank wiring (ray parity: backend_executor.py:273)
-        refs = []
-        for rank, w in enumerate(self.worker_group.workers):
-            refs.append(
-                w.setup_session.remote(
-                    rank, self.scaling.num_workers, 0, rank,
-                    self.run_config.name or "experiment", self.trial_id,
-                    self.trial_dir, checkpoint,
-                )
+        with steptrace.span("gang/workers", self.scaling.num_workers):
+            self.worker_group = WorkerGroup(
+                self.scaling.num_workers,
+                self.scaling.worker_resources(),
+                placement_group=self.pg,
+                runtime_env=runtime_env,
+                generation=generation,
             )
-        ray_tpu.get(refs, timeout=GLOBAL_CONFIG.train_worker_start_timeout_s)
-        self.backend.on_start(self.worker_group, self.backend_config)
+            # rank wiring (ray parity: backend_executor.py:273)
+            refs = []
+            for rank, w in enumerate(self.worker_group.workers):
+                refs.append(
+                    w.setup_session.remote(
+                        rank, self.scaling.num_workers, 0, rank,
+                        self.run_config.name or "experiment", self.trial_id,
+                        self.trial_dir, checkpoint,
+                    )
+                )
+            ray_tpu.get(refs,
+                        timeout=GLOBAL_CONFIG.train_worker_start_timeout_s)
+        with steptrace.span("gang/backend"):
+            self.backend.on_start(self.worker_group, self.backend_config)
 
     # ------------------------------------------------------------------
     def run(self, train_fn: Callable, config: Optional[dict] = None,
@@ -284,12 +292,13 @@ class BackendExecutor:
         recovery histogram measures from."""
         wg = self.worker_group
         try:
-            self.backend.on_training_start(wg, self.backend_config)
-            ray_tpu.get(
-                [w.start_training.remote(train_fn, dict(config or {}))
-                 for w in wg.workers],
-                timeout=GLOBAL_CONFIG.train_worker_start_timeout_s,
-            )
+            with steptrace.span("gang/launch"):
+                self.backend.on_training_start(wg, self.backend_config)
+                ray_tpu.get(
+                    [w.start_training.remote(train_fn, dict(config or {}))
+                     for w in wg.workers],
+                    timeout=GLOBAL_CONFIG.train_worker_start_timeout_s,
+                )
         except Exception as e:
             # a rank that dies during gang setup is a gang failure, not a
             # user-code error: the recovery loop should re-place it

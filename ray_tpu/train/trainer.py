@@ -13,6 +13,7 @@ from __future__ import annotations
 import os
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu._private import steptrace
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import RunConfig, ScalingConfig
 from ray_tpu.air.result import Result
@@ -108,7 +109,8 @@ class DataParallelTrainer(BaseTrainer):
             )
             cfg = dict(self.train_loop_config)
             if self.datasets:
-                cfg["__datasets__"] = self._shard_datasets()
+                with steptrace.span("gang/datasets", len(self.datasets)):
+                    cfg["__datasets__"] = self._shard_datasets()
             result = executor.run(
                 self.train_loop_per_worker, cfg, result_callback=result_callback
             )

@@ -9,10 +9,12 @@ thread and draining a result queue.
 from __future__ import annotations
 
 import threading
+import time
 import traceback
 from typing import Any, Callable, List, Optional
 
 import ray_tpu
+from ray_tpu._private import steptrace
 from ray_tpu.air.checkpoint import Checkpoint, finish_commit
 from ray_tpu.train import session as session_mod
 
@@ -29,12 +31,13 @@ class TrainWorker:
     def setup_session(self, rank: int, world_size: int, local_rank: int,
                       node_rank: int, experiment_name: str, trial_id: str,
                       trial_dir: str, checkpoint: Optional[Checkpoint]):
-        ctx = session_mod.TrainContext(
-            rank=rank, world_size=world_size, local_rank=local_rank,
-            node_rank=node_rank, experiment_name=experiment_name,
-            trial_id=trial_id, trial_dir=trial_dir,
-        )
-        self._session = session_mod.init_session(ctx, checkpoint)
+        with steptrace.span("gang/session"):
+            ctx = session_mod.TrainContext(
+                rank=rank, world_size=world_size, local_rank=local_rank,
+                node_rank=node_rank, experiment_name=experiment_name,
+                trial_id=trial_id, trial_dir=trial_dir,
+            )
+            self._session = session_mod.init_session(ctx, checkpoint)
         return True
 
     def execute(self, fn: Callable, *args, **kwargs):
@@ -66,6 +69,7 @@ class TrainWorker:
 
     def start_training(self, train_fn: Callable, config: dict):
         assert self._session is not None, "setup_session must run first"
+        received = time.time()
         sess = self._session
         shards = config.pop("__datasets__", None)
         if shards:
@@ -79,6 +83,9 @@ class TrainWorker:
                 import inspect
 
                 sig = inspect.signature(train_fn)
+                # ``gang/loop``: this call received -> the user's loop
+                # about to run on its thread
+                steptrace.record_phase("gang/loop", received, time.time())
                 try:
                     if len(sig.parameters) >= 1:
                         train_fn(config)
