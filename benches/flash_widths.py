@@ -7,11 +7,22 @@ profiler trace of three more calls, the device time of one ``flash_fwd``
 and one ``flash_bwd`` alone (``flash_*_kernel_ms``), what the wall time of
 forward plus backward holds besides them (``round_kernels_ms``: on the
 ``xla_copies`` boundary the V^T, K^T, O^T and dQ^T transposes, ``delta`` and
-dQ's rounding, until PR 50 the sum of dQ's float32 partials too; on either
-the bench's own sum of the output and its cotangent) and the bytes of dQ that the backward call writes
-(``dq_written_bytes``: its first output, as the traced call declares it; since
-PR 50 one float32 [B x H, d, T] sum where a head has several blocks of keys,
-where there was one such array a block of keys).
+dQ's rounding, until PR 50 the sum of dQ's float32 partials too; on
+``model_results``, since PR 55 the boundary past one block of keys at one
+width of whole lane tiles, what is left of them: q's, K^T's and V^T's; on
+any the bench's own product of the output with its cotangent) and the bytes
+of dQ that the backward call writes (``dq_written_bytes``: its first output,
+as the traced call declares it; since PR 50 one float32 [B x H, d, T] sum
+where a head has several blocks of keys, where there was one such array a
+block of keys; on ``model_results`` the kernel's own running sums, which
+nothing reads after it).
+
+Every ARGUMENT is in a model's layout, [B, T, H x d] dense, the output's
+cotangent among them (the loss is the sum of the output times a seeded [B,
+T, H x d_v] array, so that dO is an array of the program as a model's is,
+not a constant that XLA folds into a copy), and the gradients come back in
+it: what stands round the kernels is what a model pays (PR 51: a bench whose
+arguments are lane-padded pays a relayout the model does not).
 
     python benches/flash_widths.py --widths 192x128 --lengths 1024,8192
     python benches/flash_widths.py --widths 128x128 --lengths 16384 \
@@ -55,6 +66,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.ops import attention
     from ray_tpu.ops.attention import (attention_reference,
                                        causal_self_attention,
                                        grid_block_kinds, heads_a_lane_tile)
@@ -160,17 +172,23 @@ def main():
             # operands as a model's projection writes them, [B, T, H x d]
             # dense, and shaped [B, T, H, d] inside the timed function: an
             # argument [B, T, H, 64] would itself be padded to the lanes
-            keys = jax.random.split(jax.random.PRNGKey(seq), 3)
-            q, k, v = (
+            keys = jax.random.split(jax.random.PRNGKey(seq), 4)
+            q, k, v, w = (
                 jax.random.normal(key, (batch, seq, n * d), jnp.bfloat16)
-                for key, n, d in zip(keys, (args.heads, kv_heads, kv_heads),
-                                     (d_qk, d_qk, d_v)))
+                for key, n, d in zip(
+                    keys, (args.heads, kv_heads, kv_heads, args.heads),
+                    (d_qk, d_qk, d_v, d_v)))
             shaped = lambda x, n: x.reshape(batch, seq, n, -1)
+            # a tree without the rule (a parent's, under this file) has none
+            results = getattr(attention, "results_in_model_arrays",
+                              lambda *_: False)
             line = {"d_qk": d_qk, "d_v": d_v, "seq": seq, "batch": batch,
                     "heads": args.heads, "kv_heads": kv_heads,
                     "window": args.window,
-                    "boundary": ("model_arrays" if heads_a_lane_tile(
-                        seq, args.heads, kv_heads, d_qk, d_v)
+                    "boundary": (
+                        "model_arrays" if heads_a_lane_tile(
+                            seq, args.heads, kv_heads, d_qk, d_v)
+                        else "model_results" if results(seq, d_qk, d_v)
                         else "xla_copies"),
                     "device": jax.devices()[0].device_kind,
                     "grid_blocks": grid_block_kinds(seq, seq, True,
@@ -181,21 +199,22 @@ def main():
                 if path == "xla" and seq > 4096:
                     continue
 
-                def loss(q, k, v, path=path):
-                    return causal_self_attention(
+                def loss(q, k, v, w, path=path):
+                    out = causal_self_attention(
                         shaped(q, args.heads), shaped(k, kv_heads),
-                        shaped(v, kv_heads), path,
-                        args.window).astype(jnp.float32).sum()
+                        shaped(v, kv_heads), path, args.window)
+                    return (out.reshape(w.shape).astype(jnp.float32)
+                            * w).sum()
 
                 fns = {"_fwd_ms": jax.jit(loss),
                        "_fwd_bwd_ms": jax.jit(jax.grad(loss, argnums=(0, 1, 2)))}
                 try:
                     for name, fn in fns.items():
-                        line[path + name] = round(timed(fn, q, k, v), 3)
+                        line[path + name] = round(timed(fn, q, k, v, w), 3)
                     if path == "flash":
-                        kernels = kernel_ms(fns["_fwd_bwd_ms"], q, k, v)
+                        kernels = kernel_ms(fns["_fwd_bwd_ms"], q, k, v, w)
                         line.update(kernels, dq_written_bytes=dq_written_bytes(
-                            fns["_fwd_bwd_ms"], q, k, v))
+                            fns["_fwd_bwd_ms"], q, k, v, w))
                         if kernels:
                             line["round_kernels_ms"] = round(
                                 line["flash_fwd_bwd_ms"]

@@ -10,7 +10,13 @@ training.
   and carry no positional term. ``num_attention_heads`` query heads read
   ``num_key_value_heads`` key-value heads, query head j the head ``j //
   group``: ``ops.attention.causal_self_attention`` takes both, and its kernel
-  repeats no key or value in memory. Each head's queries and keys pass an
+  repeats no key or value in memory; at this family's published head width
+  of 128 and past 2,048 tokens the kernel writes its output into, and reads
+  that output's cotangent from, the [B, T, H x 128] array that the gate and
+  ``o_proj`` read, and writes dK and dV as [B, T, H_kv x 128] (XLA folds q,
+  k and v for it, which the projections write in that layout anyway, and
+  turns dQ back, which the rotary's backward wants with the tokens minor).
+  Each head's queries and keys pass an
   RMSNorm over ``head_dim`` (one scale vector each, shared by the heads);
   the attention output is multiplied by ``sigmoid(x W_g)`` before ``W_o``.
 - A block has four norms: ``h = h + N2(Attn(N1(h)))``, ``h = h + N4(F(N3(h)))``.

@@ -519,9 +519,14 @@ def _fold_tile(s, carry, masked, values_t):
     return m_new, l, acc + _dot(vt, p.astype(vt.dtype), _NN)
 
 
-def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
+def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
-                offset: int, static: bool, kinds, window: Optional[int]):
+                offset: int, static: bool, kinds, window: Optional[int],
+                rows_out: bool = False):
+    """``o_ref`` is this block of queries' O^T (d_v, resident queries), or
+    with ``rows_out`` its O (resident queries, d_v): a head's lanes of a
+    model's own [B, T, H x d_v] array (``results_in_model_arrays``), for
+    which a row of tiles' float32 accumulator is turned here, in VMEM."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -556,7 +561,10 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, ot_ref, lse_ref, m_scr, l_scr, acc_scr,
             @pl.when(ki == nk - 1)
             def _finalize():
                 l_safe = jnp.where(l == 0.0, 1.0, l)
-                ot_ref[:, cols] = (acc / l_safe).astype(ot_ref.dtype)
+                if rows_out:
+                    o_ref[cols, :] = (acc / l_safe).T.astype(o_ref.dtype)
+                else:
+                    o_ref[:, cols] = (acc / l_safe).astype(o_ref.dtype)
                 # queries with no live key get lse=+inf => p == 0 in the
                 # backward
                 lse_ref[:, cols] = jnp.where(
@@ -573,7 +581,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *sums,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
-                nq: int, group: int):
+                nq: int, group: int, o_rows: bool = False):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three. The last grid axis walks the ``nq`` blocks of queries of each of
@@ -598,7 +606,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
     way back is waited for where its buffer is next filled, a live step
     later, or at the last step of this block of keys (``pending`` says
     whether one is under way): no two steps of one block of keys touch the
-    same block of queries, so nothing reads a sum before it has landed."""
+    same block of queries, so nothing reads a sum before it has landed.
+
+    ``delta_ref`` is the queries' row of ``delta``, or with ``o_rows``
+    (``results_in_model_arrays``) this block of queries' O itself, a head's
+    lanes of the model's [B, T, H x d_v] as ``do_ref`` is of its cotangent,
+    from which a row of tiles makes its ``delta`` here; ``dk_ref`` and
+    ``dv_ref`` are then a key-value head's lanes of such arrays too, which
+    changes nothing in here."""
     ki, step_q = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
     qi = step_q if group == 1 else step_q % nq
@@ -650,12 +665,25 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
         if sums:
             pending[0] = 0
 
+    def delta_of(cols, do):
+        """A query's sum of dO x O over its head's lanes, as the row that
+        the transposed scores need: ``delta_ref``'s, where XLA made it; with
+        ``o_rows`` that operand is this block of queries' O, laid out as dO
+        is, and the row is made here, by one float32 turn (XLA reaches it
+        from the model's arrays only by way of a float32 copy of the whole
+        product: 0.25 GiB written, copied and read a layer at 16,384 tokens
+        of 32 heads)."""
+        if not o_rows:
+            return delta_ref[:, cols]
+        return (do.astype(jnp.float32) * delta_ref[cols, :].astype(
+            jnp.float32)).T.sum(axis=0, keepdims=True)
+
     def walk(rel0):
         def row(j, *bounds):
             cols = _tile(j, block_q, n_q)
             rel = rel0 + j * block_q
             q, do = _scaled(q_ref[cols, :], sm_scale, fold), do_ref[cols, :]
-            lse, delta = lse_ref[:, cols], delta_ref[:, cols]  # (1, block_q)
+            lse, delta = lse_ref[:, cols], delta_of(cols, do)  # (1, block_q)
 
             def step(c, dqt, masked):
                 rows = _tile(c, block_k, n_k)
@@ -985,11 +1013,15 @@ def _kernel_name(base: str, window: Optional[int]) -> str:
 
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
-                  interpret: bool, window: Optional[int] = None):
+                  interpret: bool, window: Optional[int] = None,
+                  heads: Optional[int] = None):
     """q: (B, S, D) with batch*heads folded into B; k: (B_kv, S, D) and v:
     (B_kv, S, Dv) with B a multiple of B_kv: query head ``i`` reads
     key-value head ``i // (B // B_kv)``, through the index maps.
-    -> (out (B, S, Dv), lse) with lse (B, 1, S) float32."""
+    -> (out (B, S, Dv), lse) with lse (B, 1, S) float32. Given ``heads``
+    (``results_in_model_arrays``), of which B is a multiple, out is a
+    model's own (B / heads, S, heads x Dv): the kernel writes head ``i %
+    heads``'s lanes of it, a block of queries at a time."""
     b, q_len, d = q.shape
     k_len, d_v = k.shape[1], v.shape[2]
     group = b // k.shape[0]
@@ -1022,7 +1054,19 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         block_k=block_k, offset=offset, static=nq == nk == 1, window=window,
         kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, False,
                              window, (b, k.shape[0])))
-    out_t, lse = pl.pallas_call(
+    if heads is None:
+        # O^T, which XLA turns
+        out_spec = pl.BlockSpec((None, d_v, res_q),
+                                lambda bi, qi, ki: (bi, 0, qi))
+        out_shape = jax.ShapeDtypeStruct((b, d_v, q_len), q.dtype)
+    else:
+        kernel = functools.partial(kernel, rows_out=True)
+        out_spec = pl.BlockSpec(
+            (None, res_q, d_v), lambda bi, qi, ki: (bi // heads, qi,
+                                                    bi % heads))
+        out_shape = jax.ShapeDtypeStruct((b // heads, q_len, heads * d_v),
+                                         q.dtype)
+    out, lse = pl.pallas_call(
         kernel,
         grid=(b, nq, nk),
         in_specs=[
@@ -1033,11 +1077,11 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                          lambda bi, qi, ki: (kv_head(bi), 0, kmap(qi, ki))),
         ],
         out_specs=[
-            pl.BlockSpec((None, d_v, res_q), lambda bi, qi, ki: (bi, 0, qi)),
+            out_spec,
             pl.BlockSpec((None, 1, res_q), lambda bi, qi, ki: (bi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, d_v, q_len), q.dtype),
+            out_shape,
             jax.ShapeDtypeStruct((b, 1, q_len), jnp.float32),
         ],
         scratch_shapes=[
@@ -1049,13 +1093,21 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         interpret=interpret,
         name=_kernel_name("flash_fwd", window),
     )(q, k, jnp.swapaxes(v, 1, 2))
-    return jnp.swapaxes(out_t, 1, 2), lse
+    return (jnp.swapaxes(out, 1, 2) if heads is None else out), lse
 
 
 def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              sm_scale: float, block_q: Optional[int],
                              block_k: Optional[int], interpret: bool,
-                             window: Optional[int] = None):
+                             window: Optional[int] = None,
+                             heads: Optional[int] = None):
+    """-> (dq, dk, dv) of ``_flash_pallas``'s call, shaped as q, k and v
+    [B x H, T, d] from ``do`` shaped as its output; given ``heads``, ``do``
+    is the cotangent of the model's own [B, T, heads x d_v] output, read a
+    head's lanes at a time, ``delta`` is that output itself, from which the
+    kernel makes the rows, and dk, dv are written as model's arrays
+    likewise, [B, T, key-value heads x width]; dq is [B x H, T, d] either
+    way."""
     b, q_len, d = q.shape
     b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
     group = b // b_kv
@@ -1084,9 +1136,29 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
     kspec = pl.BlockSpec((None, res_k, d), lambda bi, ki, step: (bi, ki, 0))
     vspec = pl.BlockSpec((None, res_k, d_v), lambda bi, ki, step: (bi, ki, 0))
-    dospec = pl.BlockSpec(
-        (None, res_q, d_v),
-        lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
+    if heads is None:
+        # dO as q, dK and dV as k and v: a head's block of [B x H, T, width]
+        dospec = pl.BlockSpec(
+            (None, res_q, d_v),
+            lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
+        dkspec, dvspec = kspec, vspec
+        dk_dims, dv_dims = k.shape, v.shape
+    else:
+        # a head's lanes of a model's [B, T, heads x width] array, by
+        # (batch row, block, head of the row)
+        assert causal and nk > 1 and not offset and res_q == res_k, (
+            q.shape, k.shape, causal)
+        kv_heads = heads // group
+        row = lambda bi: bi // kv_heads
+        of_kv = lambda bi, ki, step: (row(bi), ki, bi % kv_heads)
+        dospec = pl.BlockSpec(
+            (None, res_q, d_v),
+            lambda bi, ki, step: (row(bi), qmap(ki, block(step)),
+                                  head(bi, step) % heads))
+        dkspec = pl.BlockSpec((None, res_k, d), of_kv)
+        dvspec = pl.BlockSpec((None, res_k, d_v), of_kv)
+        dk_dims, dv_dims = ((b // heads, k_len, kv_heads * width)
+                            for width in (d, d_v))
     rowspec = pl.BlockSpec(
         (None, 1, res_q),
         lambda bi, ki, step: (head(bi, step), 0, qmap(ki, block(step))))
@@ -1118,21 +1190,18 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, offset=offset, static=nq == nk == 1,
-            window=window, nq=nq, group=group,
+            window=window, nq=nq, group=group, o_rows=heads is not None,
             kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True,
                                  window, (b, b_kv))),
         grid=(b_kv, nk, group * nq),
         in_specs=[
             qspec, kspec, vspec,
             pl.BlockSpec((None, d, res_k), lambda bi, ki, step: (bi, 0, ki)),
-            dospec, rowspec, rowspec,
+            dospec, rowspec, rowspec if heads is None else dospec,
         ],
-        out_specs=[dqspec, kspec, vspec],
-        out_shape=[
-            dq_shape,
-            jax.ShapeDtypeStruct((b_kv, k_len, d), k.dtype),
-            jax.ShapeDtypeStruct((b_kv, k_len, d_v), v.dtype),
-        ],
+        out_specs=[dqspec, dkspec, dvspec],
+        out_shape=[dq_shape, jax.ShapeDtypeStruct(dk_dims, k.dtype),
+                   jax.ShapeDtypeStruct(dv_dims, v.dtype)],
         scratch_shapes=[
             pltpu.VMEM((res_k, d), jnp.float32),
             pltpu.VMEM((res_k, d_v), jnp.float32),
@@ -1153,18 +1222,38 @@ def heads_a_lane_tile(seq_len: int, heads: int, kv_heads: int, d: int,
     (``_flash_pallas_lanes``), and 0 where XLA turns them into the kernels'
     own [B x H, T, d] (``_flash_pallas``). Read from the call's shapes alone:
     a head is one block of keys (every length up to ``_MAX_RESIDENT``: the
-    kernels past it sum over blocks and walk them by kind, on the boundary
-    they were measured with), in whole tiles of queries; keys and values
-    share a width that divides the lanes, 64 (two heads a tile, an odd head
-    out in half a tile past the arrays' edge) or 128 (192 / 128 and 64 /
-    128 would need two addresses a head); every query head has its own keys
-    and values (a group's would lie in another tile's half); and the heads
-    fill a tile."""
+    kernels past it sum over blocks and walk them by kind, a head a grid
+    row; what of THEIR boundary is the model's arrays,
+    ``results_in_model_arrays`` says), in whole tiles of queries; keys and
+    values share a width that divides the lanes, 64 (two heads a tile, an
+    odd head out in half a tile past the arrays' edge) or 128 (192 / 128
+    and 64 / 128 would need two addresses a head); every query head has its
+    own keys and values (a group's would lie in another tile's half); and
+    the heads fill a tile."""
     one_block = seq_len <= _MAX_RESIDENT and seq_len % 128 == 0
     if (one_block and heads == kv_heads and d == d_v and d in (64, 128)
             and heads * d >= 128):
         return 128 // d
     return 0
+
+
+def results_in_model_arrays(seq_len: int, d: int, d_v: int) -> bool:
+    """Whether the kernels that walk several blocks of keys a head
+    (``_flash_pallas``) write O, dK and dV into, and read O and its
+    cotangent from, a model's own [B, T, H x width] arrays, a head's lanes
+    a block, where otherwise XLA turns [B x H, d_v, T] and [B x H, T, d]
+    arrays round them (at 16,384 tokens and 32 heads of 128 the kernel's
+    output was laid out three times and its cotangent twice, 15 ms of a
+    474 ms step: PERF.md section 6, PR 55). Read from the call's shapes
+    alone, as ``heads_a_lane_tile`` is, which takes the lengths up to
+    ``_MAX_RESIDENT``: past it, keys and values of one width that is whole
+    lane tiles, so that a head's block is whole tiles of either array (192
+    / 128 and 64 / 128 keep XLA's copies, as a width of 64 does: half a
+    tile a head); any grouping, a window or none. The operands q, k and V^T
+    stay the [B x H, T, d] that XLA makes of the projections' results,
+    which it writes in that layout anyway, and dQ leaves as the kernel's
+    float32 [B x H, d, T] sum (``_flash_pallas_bwd`` says why)."""
+    return seq_len > _MAX_RESIDENT and d == d_v and d % 128 == 0
 
 
 def _lanes_params(interpret: bool, dtype):
@@ -1259,9 +1348,32 @@ def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
     kernels (forward saves the logsumexp; one backward kernel recomputes P
     per tile from q,k,lse — O(seq) memory, no attention matrix ever
     materialized). q, k, v are the kernels' own [B x H, T, d] or, given
-    ``heads``, a model's [B, T, heads x d] (``heads_a_lane_tile``)."""
+    ``heads``, a model's [B, T, heads x d] (``heads_a_lane_tile``, or past
+    one block of keys ``results_in_model_arrays``, where k and v may hold
+    fewer heads)."""
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                           interpret, window, heads)[0]
+
+
+def _folded(x, heads: int):
+    """A model's [B, T, heads x d] as the kernels' [B x heads, T, d]."""
+    b, seq, lanes = x.shape
+    return x.reshape(b, seq, heads, lanes // heads).transpose(
+        0, 2, 1, 3).reshape(b * heads, seq, lanes // heads)
+
+
+def _unfolded(x, heads: int):
+    """The kernels' [B x heads, T, d] as a model's [B, T, heads x d]."""
+    folded, seq, d = x.shape
+    return x.reshape(folded // heads, heads, seq, d).transpose(
+        0, 2, 1, 3).reshape(folded // heads, seq, heads * d)
+
+
+def _folded_operands(q, k, v, heads: int):
+    """q, k, v [B, T, H x d] (k and v of as many heads as their widths
+    say) as ``_flash_pallas`` takes them."""
+    kv_heads = k.shape[2] // (q.shape[2] // heads)
+    return _folded(q, heads), _folded(k, kv_heads), _folded(v, kv_heads)
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
@@ -1272,6 +1384,11 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                              block_q=block_q, block_k=block_k,
                              interpret=interpret, window=window)
     assert causal, "a model's own arrays are causal self-attention's"
+    if q.shape[1] > _MAX_RESIDENT:   # ``results_in_model_arrays``
+        return _flash_pallas(*_folded_operands(q, k, v, heads),
+                             causal=True, sm_scale=sm_scale, block_q=block_q,
+                             block_k=block_k, interpret=interpret,
+                             window=window, heads=heads)
     return _flash_pallas_lanes(q, k, v, heads=heads, sm_scale=sm_scale,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret, window=window)
@@ -1297,6 +1414,18 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
             q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             window=window)
+    if q.shape[1] > _MAX_RESIDENT:   # ``results_in_model_arrays``
+        # O goes in as dO does, and the kernel makes ``delta`` of them.
+        # dQ stays its float32 [B x H, d, T] sum, which XLA rounds and
+        # turns: what reads dQ next (the rotary's and the heads' norm's
+        # backward) wants the tokens minor, and a dQ rounded by the kernel
+        # into the model's [B, T, H x d] cost the step 14 ms more in XLA's
+        # float32 copies than it saved (PERF.md section 6, PR 55)
+        dq, dk, dv = _flash_pallas_bwd_kernel(
+            *_folded_operands(q, k, v, heads), g, lse, out, causal=True,
+            sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+            interpret=interpret, window=window, heads=heads)
+        return _unfolded(dq, heads), dk, dv
     return _flash_pallas_lanes_bwd(
         q, k, v, g, out, lse, heads=heads, sm_scale=sm_scale, block_q=block_q,
         block_k=block_k, interpret=interpret, window=window)
@@ -1339,7 +1468,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     head's width side by side along the last axis, and so is the output:
     causal self-attention of a shape ``heads_a_lane_tile`` admits, whose
     kernels address those arrays themselves (two 64-wide heads a lane
-    tile); the other paths are handed the heads as an axis.
+    tile), or past one block of keys a head of a shape
+    ``results_in_model_arrays`` admits (k and v of fewer heads, as their
+    last axis says), whose kernels write the output, dK and dV into such
+    arrays and read the output's cotangent from one; the other paths are
+    handed the heads as an axis.
 
     ``block_q`` queries meet ``block_k`` keys at a time; left out, the
     kernel chooses both from the sequence lengths (``_block_sizes``) and
@@ -1359,8 +1492,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
         sm_scale = (q.shape[-1] // (heads or 1)) ** -0.5
     if impl in ("reference", "scan"):
         if heads is not None:   # these take the heads as an axis
-            q, k, v = (t.reshape(*t.shape[:2], heads, -1).transpose(
-                0, 2, 1, 3) for t in (q, k, v))
+            d = q.shape[2] // heads
+            q, k, v = (t.reshape(*t.shape[:2], n, -1).transpose(0, 2, 1, 3)
+                       for t, n in ((q, heads), (k, k.shape[2] // d),
+                                    (v, k.shape[2] // d)))
         if impl == "reference":
             out = attention_reference(q, k, v, causal=causal,
                                       sm_scale=sm_scale, window=window)
@@ -1376,10 +1511,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
         assert (q.shape[-3] % k.shape[-3] == 0
                 and k.shape[:-1] == v.shape[:-1]), (q.shape, k.shape, v.shape)
     else:
-        d = q.shape[2] // heads
-        assert q.shape == k.shape == v.shape and heads_a_lane_tile(
-            q.shape[1], heads, heads, d, d), (q.shape, k.shape, v.shape,
-                                              heads)
+        d, seq = q.shape[2] // heads, q.shape[1]
+        if seq > _MAX_RESIDENT:
+            kv_heads = k.shape[2] // d
+            admitted = (heads % kv_heads == 0 and results_in_model_arrays(
+                seq, d, v.shape[2] // kv_heads))
+        else:
+            admitted = q.shape == k.shape == v.shape and heads_a_lane_tile(
+                seq, heads, heads, d, d)
+        assert admitted and k.shape[1] == v.shape[1] == seq, (
+            q.shape, k.shape, v.shape, heads)
 
     def kernel(q, k, v):
         if heads is not None:
@@ -1556,18 +1697,29 @@ def causal_self_attention(q, k, v, attention: str = "auto",
     that write the [B, H, T, T] scores to HBM (it takes one width and no
     window here, so for values of another width or under a window the same
     program is written out: ``attention_reference``); "auto", whichever
-    ``auto_attention`` finds for ``q`` and ``v``."""
+    ``auto_attention`` finds for ``q`` and ``v``.
+
+    The kernel's path has three boundaries, chosen here from the call's
+    shapes and written into the runtime's ring, one ``attention/boundary``
+    record a traced call: the kernels address the model's own [B, T, H x
+    d] arrays throughout (``heads_a_lane_tile``; ``model_arrays`` 1); past
+    one block of keys the output, its cotangent, dK and dV alone cross in
+    such arrays (``results_in_model_arrays``; ``model_results`` 1) while
+    XLA folds q, k and v into [B x H, T, d] and turns dQ back; or XLA turns
+    everything (both 0)."""
     if attention == "auto":
         attention = auto_attention(q, v)
     bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
         (b, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
         a_tile = heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
+        results = results_in_model_arrays(seq, d, d_v)
         steptrace.record_counters("attention/boundary", {
             "tokens": seq, "heads": heads, "kv_heads": kv_heads,
             "d_qk": d, "d_v": d_v, "window": window or 0,
-            "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0)})
-        if a_tile:
+            "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0),
+            "model_results": int(results)})
+        if a_tile or results:
             lanes = lambda t: t.reshape(b, seq, -1)
             return flash_attention(
                 lanes(q), lanes(k), lanes(v), causal=True, window=window,
@@ -1592,7 +1744,12 @@ def causal_self_attention(q, k, v, attention: str = "auto",
 # What a layer then holds, where the kernels address the model's arrays
 # (``heads_a_lane_tile``: GPT-2's calls), is the output as the kernel wrote
 # it, a dense [B, T, H x d_v], and [B, lane tiles, heads a tile, T] float32.
-# On the other boundary it is the [B x H, T, d_v] swap of what the kernel
+# Where their results alone cross in the model's arrays
+# (``results_in_model_arrays``: past one block of keys at one width of whole
+# lane tiles) it is again the dense [B, T, H x d_v] that the kernel wrote,
+# which the backward kernel reads a second time for ``delta``, and [B x H,
+# 1, T] float32.
+# On the last boundary it is the [B x H, T, d_v] swap of what the kernel
 # wrote and [B x H, 1, T] float32; at a value width of 64 the swap, kept,
 # becomes a copy with its 64-wide rows padded to the 128 lanes (until PR 51
 # GPT-2 XL's: 93 MiB a layer of plan where the output's bytes are 50, and a
@@ -1610,7 +1767,7 @@ def remat_policy():
     may run a kernel of ``ray_tpu/ops``: keep the selective scan's output and
     boundary states, and the flash kernel's output and log-sum-exp (per layer
     one [B, T, H, d_v] array in the compute dtype and B x H x T float32;
-    dense where the kernels address the model's arrays, else at a value
+    dense where the kernels write the model's arrays, else at a value
     width of 64 a lane-padded [B x H, T, 64] of nearly twice those bytes:
     the comment above), recompute everything else. The backward pass of such a block then
     reruns the projections and not the forward kernel. Where the block's

@@ -19,9 +19,9 @@ from ray_tpu.parallel.mesh_utils import replicated
 
 class _StepByLayout:
     """A train step ``(params, opt_state, batch) -> (params, opt_state,
-    loss)`` that takes its layout from its arguments. Called or lowered
-    with a state that lies on one device (or abstract and unplaced) it is
-    ``jitted()``, the plain jit. With a state placed over several devices
+    loss)`` that takes its layout from its arguments. Called, traced or
+    lowered with a state that lies on one device (or abstract and unplaced)
+    it is ``jitted()``, the plain jit. With a state placed over several devices
     (``place_train_state``) it is ``jitted((param shardings, optimizer
     state shardings))``: the state comes back in the shardings it went in,
     so the second step finds the program of the first, and the gradients
@@ -51,8 +51,11 @@ class _StepByLayout:
         # the loss, then the leaves of a loss function's auxiliary output
         return (params, opt_state, *jax.tree.leaves(out))
 
+    def trace(self, params, opt_state, batch):
+        return self._for(params, opt_state).trace(params, opt_state, batch)
+
     def lower(self, params, opt_state, batch):
-        return self._for(params, opt_state).lower(params, opt_state, batch)
+        return self.trace(params, opt_state, batch).lower()
 
 
 def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):

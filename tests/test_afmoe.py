@@ -5,6 +5,7 @@ weights, no cluster; its configuration file held to the published widths;
 the benchmark family's step as the worker calls it."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -129,22 +130,41 @@ def _as_reference(config):
             "expert_shard": {"index": index, "of": of}}
 
 
-@pytest.mark.parametrize("attention", ["xla", "scan"])
-def test_the_model_is_the_reference(attention, monkeypatch):
+@pytest.mark.parametrize("attention", ["xla", "scan", "kernel_results"])
+def test_the_model_is_the_reference(attention, monkeypatch, request):
     """Logits, loss and every parameter's gradient, float32 on both sides:
     five layers (a dense one, a full one among the expert layers), two
     key-value heads for four query heads, a window of 8 in 32 positions,
     half the experts held. ``scan`` is the path that stands for the kernel
-    where there is no chip."""
-    if attention == "scan":
-        from ray_tpu.ops import attention as ops_attention
+    where there is no chip; ``kernel_results`` is the kernel itself in
+    interpret mode at the published head width of 128 with four blocks of
+    keys a head, the boundary the cell's calls take since PR 55: its
+    output, dQ, dK and dV written into the model's [B, T, H x 128] arrays
+    and the gate's cotangent read from one."""
+    from ray_tpu.ops import attention as ops_attention
 
+    more = {}
+    if attention == "scan":
         monkeypatch.setattr(
             afmoe, "causal_self_attention",
             lambda q, k, v, path, window: ops_attention.flash_attention(
                 *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
                 window=window, impl="scan", block_k=8).transpose(0, 2, 1, 3))
-    config, model, params, batch = _small(expert_shard=(1, 2))
+    elif attention == "kernel_results":
+        more = {"head_dim": 128}
+        monkeypatch.setattr(ops_attention, "_MAX_RESIDENT", 8)
+        monkeypatch.setattr(
+            ops_attention, "flash_attention", functools.partial(
+                ops_attention.flash_attention, impl="pallas_interpret",
+                block_q=8, block_k=8))
+        assert ops_attention.results_in_model_arrays(32, 128, 128)
+        monkeypatch.setattr(
+            afmoe, "causal_self_attention",
+            lambda q, k, v, path, window: ops_attention.causal_self_attention(
+                q, k, v, "flash", window))
+        jax.clear_caches()  # flash_attention is jitted: the rule is read
+        request.addfinalizer(jax.clear_caches)
+    config, model, params, batch = _small(expert_shard=(1, 2), **more)
     m = _as_reference(config)
     with jax.default_matmul_precision("highest"):
         hidden, _ = model.apply({"params": params}, batch["input_ids"])

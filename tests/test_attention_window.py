@@ -5,7 +5,9 @@ kind, and that a call with neither lowers to the jaxpr held here; since PR
 50 the backward's dQ sum over several blocks of keys, which the kernel makes
 itself: one array a call, in copies of whole rows of tiles; since PR 51 the
 boundary of a one-block call, the model's own [B, T, H x d] arrays or the
-[B x H, T, d] that XLA makes of them."""
+[B x H, T, d] that XLA makes of them; since PR 55 the boundary of a call over
+several blocks of keys at one width of whole lane tiles, whose kernels write
+O, dK and dV into, and read O and its cotangent from, the model's arrays."""
 
 import functools
 import hashlib
@@ -490,10 +492,118 @@ def test_a_one_block_call_by_either_boundary_matches_reference(
             (2 * kv, d, length), folded(heads, d_v), row, row]
 
 
+# (query heads, key-value heads, length, key width, value width, window,
+# resident queries and keys, whether the results cross in the model's
+# arrays): causal self-attention in a model's layout over SEVERAL blocks of
+# keys a head (``_MAX_RESIDENT`` patched down to ``resident``; tiles of 16),
+# batch 2. Admitted (PR 55): keys and values of one width of whole lane
+# tiles, any grouping, a window or none; the others keep [B x H, T, d].
+_RESULTS = {
+    "group_8_full": (8, 1, 128, 128, 128, None, 32, True),
+    "group_1_full": (2, 2, 128, 128, 128, None, 32, True),
+    "group_8_window_of_a_block": (8, 1, 128, 128, 128, 32, 32, True),
+    "group_1_window_of_a_block": (2, 2, 128, 128, 128, 32, 32, True),
+    "group_2_window_of_two_blocks": (4, 2, 128, 128, 128, 64, 32, True),
+    "group_8_window_of_part_blocks": (8, 1, 128, 128, 128, 40, 32, True),
+    "group_1_window_of_part_blocks": (2, 2, 128, 128, 128, 24, 32, True),
+    "group_2_two_blocks_a_head": (4, 2, 128, 128, 128, None, 64, True),
+    "width_of_two_tiles": (2, 1, 64, 256, 256, None, 32, True),
+    "keys_192_values_128": (2, 2, 128, 192, 128, None, 32, False),
+    "keys_64_values_128_window": (4, 2, 128, 64, 128, 32, 32, False),
+    "width_64": (4, 4, 128, 64, 64, None, 32, False),
+}
+_RESULTS_PARAMS = [
+    pytest.param(case, dtype, id=f"{case}-{dtype.__name__}")
+    for dtype, cases in (
+        (jnp.float32, _RESULTS),
+        (jnp.bfloat16, ("group_8_full", "group_8_window_of_a_block",
+                        "group_1_window_of_part_blocks", "width_64")))
+    for case in cases]
+
+
+@pytest.mark.parametrize("case,dtype", _RESULTS_PARAMS)
+def test_a_call_over_several_blocks_by_either_boundary_matches_reference(
+        monkeypatch, case, dtype):
+    """``causal_self_attention(..., "flash")`` in interpret mode against
+    the reference in float32, output and the three gradients under a
+    non-uniform cotangent, and which arrays its kernels handed over: where
+    ``results_in_model_arrays`` admits the call, O, dK and dV are the
+    model's own [B, T, H x width], dO and O go in as such arrays (the
+    kernel makes ``delta``: nothing outside it reduces), dQ is the float32
+    [B x H, d, T] sum it was and the first three operands the [B x H, T,
+    d], [B x H_kv, T, d] and V^T that they were; else every operand and
+    result is the parent's."""
+    heads, kv, length, d, d_v, window, resident, results = _RESULTS[case]
+    monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+    assert attention.results_in_model_arrays(length, d, d_v) == results
+    assert not attention.heads_a_lane_tile(length, heads, kv, d, d_v)
+    monkeypatch.setattr(attention, "flash_attention", functools.partial(
+        flash_attention, impl="pallas_interpret", block_q=16, block_k=16))
+    jax.clear_caches()  # flash_attention is jitted: the rule is read
+    ks = jax.random.split(jax.random.PRNGKey(13), 4)
+    q = jax.random.normal(ks[0], (2, length, heads, d), dtype)
+    k = jax.random.normal(ks[1], (2, length, kv, d), dtype)
+    v = jax.random.normal(ks[2], (2, length, kv, d_v), dtype)
+    w = jax.random.normal(ks[3], (2, length, heads, d_v), jnp.float32)
+    f32 = lambda x: x.astype(jnp.float32)
+    bhsd = lambda t: t.transpose(0, 2, 1, 3)
+    flash = lambda *x: f32(causal_self_attention(*x, "flash", window))
+    ref = lambda *x: bhsd(attention_reference(
+        *map(bhsd, x), causal=True, window=window))
+
+    def out_and_grads(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out, *vjp(w))
+
+    try:
+        got = jax.jit(functools.partial(out_and_grads, flash))(q, k, v)
+        jaxpr = jax.make_jaxpr(lambda *x: out_and_grads(flash, *x))(q, k, v)
+    finally:
+        jax.clear_caches()
+    want = out_and_grads(ref, f32(q), f32(k), f32(v))
+    for a, b, like in zip(got, want, (w, q, k, v)):
+        assert a.shape == like.shape
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+        else:
+            assert float(jnp.abs(f32(a) - b).max() / jnp.abs(b).max()) <= 3e-2
+
+    name = lambda base: base if window is None else f"{base}_w{window}"
+    calls = _kernel_eqns(jaxpr)
+    operands = {n: [x.aval.shape for x in e.invars] for n, e in calls.items()}
+    handed = {n: [(x.aval.shape, x.aval.dtype) for x in e.outvars]
+              for n, e in calls.items()}
+    folded = lambda n, width: (2 * n, length, width)
+    own = lambda n, width: (2, length, n * width)
+    row = (2 * heads, 1, length)
+    first_three = [folded(heads, d), folded(kv, d), (2 * kv, d_v, length)]
+    assert operands[name("flash_fwd")] == first_three
+    assert operands[name("flash_bwd")][:4] == [
+        folded(heads, d), folded(kv, d), folded(kv, d_v), (2 * kv, d, length)]
+    sums = ((2 * heads, d, length), jnp.float32)
+    if results:
+        assert operands[name("flash_bwd")][4:] == [
+            own(heads, d_v), row, own(heads, d_v)]
+        assert handed[name("flash_fwd")] == [(own(heads, d_v), dtype),
+                                             (row, jnp.float32)]
+        assert handed[name("flash_bwd")] == [
+            sums, (own(kv, d), dtype), (own(kv, d_v), dtype)]
+        # nothing outside the kernels reduces: ``delta`` is the kernel's
+        assert not _reductions(jaxpr)
+    else:
+        assert operands[name("flash_bwd")][4:] == [
+            folded(heads, d_v), row, row]
+        assert handed[name("flash_fwd")] == [((2 * heads, d_v, length), dtype),
+                                             (row, jnp.float32)]
+        assert handed[name("flash_bwd")] == [
+            sums, (folded(kv, d), dtype), (folded(kv, d_v), dtype)]
+
+
 def test_the_boundary_taken_is_in_the_ring(monkeypatch):
     """One ``attention/boundary`` record a traced call of the kernel's
-    path: heads, widths, heads a lane tile and whether the kernels address
-    the model's arrays; GPT-2 XL's 25 heads are admitted, a group is not."""
+    path: heads, widths, heads a lane tile, whether the kernels address
+    the model's arrays and whether their results alone cross in them;
+    GPT-2 XL's 25 heads are admitted, a group is not."""
     from ray_tpu._private import steptrace
 
     monkeypatch.setattr(attention, "flash_attention", functools.partial(
@@ -502,9 +612,10 @@ def test_the_boundary_taken_is_in_the_ring(monkeypatch):
     steptrace.reset()
     try:
         jax.clear_caches()
-        for heads, kv in ((25, 25), (8, 2)):
-            q = jax.ShapeDtypeStruct((4, 1024, heads, 64), jnp.bfloat16)
-            k = jax.ShapeDtypeStruct((4, 1024, kv, 64), jnp.bfloat16)
+        for heads, kv, seq, d in ((25, 25, 1024, 64), (8, 2, 1024, 64),
+                                  (8, 1, 4096, 128), (8, 8, 4096, 64)):
+            q = jax.ShapeDtypeStruct((4, seq, heads, d), jnp.bfloat16)
+            k = jax.ShapeDtypeStruct((4, seq, kv, d), jnp.bfloat16)
             jax.eval_shape(lambda *x: causal_self_attention(*x, "flash"),
                            q, k, k)
         records = [r["values"] for r in steptrace.snapshot()
@@ -513,12 +624,20 @@ def test_the_boundary_taken_is_in_the_ring(monkeypatch):
     finally:
         steptrace.set_enabled(False)
         jax.clear_caches()
-    shared = {"tokens": 1024, "d_qk": 64, "d_v": 64, "window": 0}
+    shared = {"tokens": 1024, "d_qk": 64, "d_v": 64, "window": 0,
+              "model_results": 0}
     assert records == [
         shared | {"heads": 25, "kv_heads": 25, "heads_a_lane_tile": 2,
                   "model_arrays": 1},
         shared | {"heads": 8, "kv_heads": 2, "heads_a_lane_tile": 0,
-                  "model_arrays": 0}]
+                  "model_arrays": 0},
+        # several blocks of keys a head: the results alone, at one width of
+        # whole lane tiles, whatever the group (PR 55); not at 64
+        shared | {"tokens": 4096, "heads": 8, "kv_heads": 1, "d_qk": 128,
+                  "d_v": 128, "heads_a_lane_tile": 0, "model_arrays": 0,
+                  "model_results": 1},
+        shared | {"tokens": 4096, "heads": 8, "kv_heads": 8,
+                  "heads_a_lane_tile": 0, "model_arrays": 0}]
 
 
 # sha256 (first 16 digits) of the jaxpr's text, forward and gradient, of a

@@ -13,6 +13,7 @@ must be the xdist worker that was handed this file.
 """
 
 import collections
+import hashlib
 import json
 import os
 import re
@@ -97,6 +98,36 @@ def _train_step_args(attention, state_sharding, batch_sharding, batch=BATCH):
     return step, (params, opt_state, {"input_ids": ids, "labels": ids})
 
 
+# sha256 (first 16 digits) of the jaxpr that each accepted cell's step lowers
+# from, but for the window cell's, whose attention calls PR 55 moved: the
+# program as jax hands it to the lowering, every kernel's body in it, with no
+# file and no line (the Mosaic payloads in the lowered text carry the line of
+# every frame, so that text moves with any edit above a kernel). They are
+# the parent's of PR 55 (commit 9501e07), read there under this file: a PR
+# that means to leave a cell's step alone finds here, before any chip call,
+# whether it did; one that means to move it re-anchors the cell's digest (it
+# is printed) and says so.
+_HELD_PROGRAMS = {
+    "gpt2-124m.step": "d12d86d6c3b58f16",
+    "gpt2-xl.step-fsdp4": "dd746428dd36d2db",
+    "joyai-llm-flash.step-8k": "be022cbee2cb17d6",
+    "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
+    "lfm2-8b-a1b.step-8k": "26754d67a7295565",
+}
+
+
+def _lower_held(cell, step, *args):
+    """``step.lower(*args)``, with the jaxpr it lowers from held to
+    ``_HELD_PROGRAMS[cell]`` (addresses of function objects, which a
+    ``custom_vjp`` or a policy prints, struck out)."""
+    traced = step.trace(*args)
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(traced.jaxpr))
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    print(f"program {cell}: {digest}")
+    assert digest == _HELD_PROGRAMS[cell], (cell, digest)
+    return traced.lower()
+
+
 def _device_bytes(compiled):
     m = compiled.memory_analysis()
     return (m.temp_size_in_bytes + m.argument_size_in_bytes
@@ -151,6 +182,51 @@ def test_flash_kernel_compiles(topo, no_compile_cache, batch, heads, seq,
         assert not re.findall(r" (?:copy|transpose)\(", text)
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("window", [2048, None], ids=["w2048", "full"])
+def test_flash_kernel_with_results_in_the_models_arrays_compiles(
+        topo, no_compile_cache, window, backward):
+    """The window cell's calls (PR 55): one sequence of 16,384 tokens, 32
+    query heads on 4 key-value heads of 128, eight blocks of keys a head,
+    under the 2,048-key window and without. q, k and v go in as a model's
+    [1, T, heads x 128] and XLA folds them; O leaves the forward kernel as
+    the model's ``bf16[1,16384,4096]``, dK and dV the backward kernel as
+    ``bf16[1,16384,512]``, with no copy of any of them and none of the
+    cotangent, and ``delta`` is the kernel's (no reduction outside it); of
+    dQ the float32 [32, 128, 16384] sum is turned by XLA as before. O's
+    accumulator is turned in VMEM and so is the dO x O product (what Mosaic
+    makes of a (128, 512) and a (256, 128) float32 turn, and of the 12 MiB
+    the backward holds, is this test's to find)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, kv, w = (jax.ShapeDtypeStruct((1, 16384, n * 128), jnp.bfloat16,
+                                     sharding=one_chip) for n in (32, 4, 32))
+
+    def loss(q, k, v, w):
+        out = flash_attention(q, k, v, causal=True, impl="pallas",
+                              window=window, heads=32)
+        return (out.astype(jnp.float32) * w).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else loss
+    text = jax.jit(fn).lower(q, kv, kv, w).compile().as_text()
+    name = "flash_fwd" if window is None else f"flash_fwd_w{window}"
+    assert f"%{name}" in text and "tpu_custom_call" in text
+    copied = collections.Counter(re.findall(
+        r"= (\w+\[[\d,]+\])\S* (?:copy|transpose)\(", text))
+    # q's fold (the argument's own: a projection writes that layout), K^T
+    # and V^T of four heads, and in the backward dQ's way back: nothing of
+    # O, dO, dK or dV
+    q_or_dq = {"bf16[1,16384,4096]", "bf16[1,16384,32,128]",
+               "bf16[32,128,16384]", "bf16[32,16384,128]"}
+    assert set(copied) <= q_or_dq | {"bf16[4,128,16384]",
+                                     "bf16[4,16384,128]"}, copied
+    assert sum(copied[shape] for shape in q_or_dq) <= (4 if backward else 2)
+    if backward:
+        assert not re.search(r" reduce\(", text)
+        handed, = re.findall(r"%flash_bwd\S* = (.*?) custom-call\(", text)
+        assert re.findall(r"(\w+\[[\d,]+\])", handed) == [
+            "f32[32,128,16384]", "bf16[1,16384,512]", "bf16[1,16384,512]"]
+
+
 _FLASH_CALL = re.compile(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
                          r'custom_call_target="tpu_custom_call"', re.M)
 _HEAD_SHAPED_COPY = re.compile(
@@ -193,6 +269,9 @@ def test_train_step_fits_one_chip(topo, no_compile_cache, compiled_steps,
     assert planned < HBM_BYTES
     if attention == "auto":
         assert planned < _one_chip(compiled_steps, topo, "xla")[1]
+        one = SingleDeviceSharding(topo.devices[0])
+        step, args = _train_step_args(attention, one, one)
+        _lower_held("gpt2-124m.step", step, *args)
     if attention != "xla":
         # the kernels address the model's [16, 1024, 12 x 64] themselves
         # (PR 51): 12 + 12 calls and none of the head-shaped copies that
@@ -350,7 +429,8 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
         params, opt_state, mesh_utils.shard_params_fsdp(params, mesh))
     ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"]), jnp.int32,
                                sharding=mesh_utils.data_sharding(mesh))
-    compiled = gpt2.build_train_step(model, tx, donate=True).lower(
+    compiled = _lower_held(
+        "gpt2-xl.step-fsdp4", gpt2.build_train_step(model, tx, donate=True),
         params, opt_state, {"input_ids": ids, "labels": ids}).compile()
 
     planned = _device_bytes(compiled)
@@ -497,8 +577,9 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     steptrace.set_enabled(True)
     steptrace.reset()
     try:
-        lowered = built.step.lower(
-            params, opt_state, {"input_ids": ids, "labels": ids})
+        lowered = _lower_held(
+            "joyai-llm-flash.step-8k", built.step, params, opt_state,
+            {"input_ids": ids, "labels": ids})
         drawn = [e for e in steptrace.chrome_trace(steptrace.merge_records(
             steptrace.snapshot())) if e["ph"] == "C"]
     finally:
@@ -612,9 +693,15 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                  if e["name"] == "attn/grid_blocks"]
     finally:
         steptrace.set_enabled(False)
-    assert {(e["args"]["kv_heads"], e["args"]["model_arrays"])
-            for e in counters if e["name"] == "attention/boundary"} == {
-        (4, 0)}
+    # all five layers' calls, window or none: the kernels' results and the
+    # output's cotangent cross in the model's arrays (PR 55), q, k and V^T
+    # stay XLA's [B x H, T, d]
+    boundary = [e["args"] for e in counters
+                if e["name"] == "attention/boundary"]
+    assert {(e["kv_heads"], e["model_arrays"], e["model_results"])
+            for e in boundary} == {(4, 0, 1)}
+    assert collections.Counter(e["window"] for e in boundary) == {
+        2048: 4, 0: 1}    # 5 of the 5 layers
     by_window = {2048: (0, 8, 7, 49), 0: (28, 8, 0, 28)}
     assert {(e["window"], e["backward"]) for e in drawn} == {
         (w, b) for w in by_window for b in (0, 1)}
@@ -639,6 +726,30 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                      "flash_bwd_w2048": 4}
     assert "bf16[32,16384,128]" in text and "bf16[4,16384,128]" in text
     _dq_census(text, 32, 128, seq)
+    # round the ten calls XLA laid the kernel's output out three times and
+    # its cotangent twice (PR 53's kept trace: 16 copies a step of
+    # ``bf16[1,16384,32,128]``, 15 of ``bf16[16384,4096]`` and 5 of the 10
+    # of ``bf16[32,128,16384]``, 14.6 ms of a 474 ms step); since PR 55 the
+    # kernels write O, dK and dV into, and read O and dO from, the model's
+    # [1, 16384, heads x 128] themselves. What is left of the third shape is
+    # dQ's rounding, one a layer: the float32 sum leaves the kernel as it
+    # did (the other copies of a head's shape, printed, are the backward of
+    # the heads' norm and of the rotary halves, not the kernels' boundary)
+    copies = collections.Counter(re.findall(
+        r"= (\w+\[[\d,]+\])\S* copy\(", text))
+    print("copies by result:", dict(copies))
+    assert copies["bf16[1,16384,32,128]"] <= 1, copies   # a norm's own
+    assert not copies["bf16[16384,4096]"], copies
+    assert copies["bf16[32,128,16384]"] <= 5, copies
+    for call, results in (("flash_fwd", ["bf16[1,16384,4096]"]),
+                          ("flash_bwd", ["bf16[1,16384,512]"] * 2)):
+        lines = [line for line in text.splitlines()
+                 if re.match(rf"\s*%?{call}[\w.\-]* = ", line)]
+        assert len(lines) == 5
+        for line in lines:
+            handed = re.findall(r"bf16\[[\d,]+\]",
+                                line.split(" custom-call(")[0])
+            assert handed == results, line[:400]
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
@@ -696,8 +807,9 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
     steptrace.set_enabled(True)
     steptrace.reset()
     try:
-        lowered = built.step.lower(
-            params, opt_state, {"input_ids": ids, "labels": ids})
+        lowered = _lower_held(
+            "phi-4-mini-flash.step-one-seq", built.step, params, opt_state,
+            {"input_ids": ids, "labels": ids})
         counters = [e for e in steptrace.chrome_trace(
             steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
     finally:
@@ -788,8 +900,9 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
     steptrace.set_enabled(True)
     steptrace.reset()
     try:
-        lowered = built.step.lower(
-            params, opt_state, {"input_ids": ids, "labels": ids})
+        lowered = _lower_held(
+            "lfm2-8b-a1b.step-8k", built.step, params, opt_state,
+            {"input_ids": ids, "labels": ids})
         counters = [e for e in steptrace.chrome_trace(
             steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
     finally:
