@@ -11,10 +11,13 @@
 - phi4flash: blocks of five kinds that hand state down the stack:
   state-space layers (``ops.ssm.selective_scan``), window, full and cross
   differential attention, a gated memory unit (training)
+- qwen3_next: gated delta-rule linear-attention layers
+  (``ops.delta.gated_delta_rule``) three to one over gated attention,
+  softmax-routed experts beside a gated shared expert (training)
 """
 
 from ray_tpu.models import (afmoe, gpt2, llama, mla_moe, moe_lm, phi4flash,
-                            vision)
+                            qwen3_next, vision)
 
 __all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "phi4flash",
-           "vision"]
+           "qwen3_next", "vision"]

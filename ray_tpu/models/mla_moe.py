@@ -146,8 +146,11 @@ class LatentAttention(nn.Module):
 class RoutedExperts(nn.Module):
     """The expert feed-forward part: a router over all ``experts``, the share
     ``expert_shard = (index, of)`` held here, ``shared`` shared experts on
-    every token (0: none, and no parameter of one); SwiGLUs of ``width``,
-    weighted as ``ops.moe.topk_routing`` says. -> (y, tokens a held expert)."""
+    every token (0: none, and no parameter of one), multiplied by
+    ``sigmoid(x w_g)`` where ``shared_gate`` (a parameter ``shared_gate``
+    [d, 1]; off, there is none); SwiGLUs of ``width``, weighted as
+    ``ops.moe.topk_routing`` says of ``score``. -> (y, tokens a held
+    expert)."""
     experts: int
     expert_shard: Tuple[int, int]
     width: int
@@ -158,6 +161,8 @@ class RoutedExperts(nn.Module):
     dtype: Any
     kernel_init: Any
     eps: float = 1e-20
+    score: str = "sigmoid"
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -173,12 +178,18 @@ class RoutedExperts(nn.Module):
         flat = x.reshape(B * T, d)
         experts, weights = moe.topk_routing(
             flat, router, bias, self.per_token, self.scale, self.normalize,
-            self.eps)
+            self.eps, self.score)
         y, tokens = moe.held_expert_ffn(flat, experts, weights, wi, wo,
                                         index=index, of=of)
         if shared is None:   # the routed part alone
             return y.reshape(B, T, d), tokens
-        return shared(x) + y.reshape(B, T, d), tokens
+        if not self.shared_gate:
+            return shared(x) + y.reshape(B, T, d), tokens
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, self.param("shared_gate", init, (d, 1)).astype(self.dtype),
+            preferred_element_type=jnp.float32))
+        return ((gate * shared(x)).astype(self.dtype) + y.reshape(B, T, d),
+                tokens)
 
 
 class Block(nn.Module):

@@ -1,6 +1,7 @@
 """ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, the
-selective scan of state-space layers, the gated short convolution, expert
-layers and the vocabulary's loss."""
+selective scan of state-space layers, the gated delta rule of
+linear-attention layers, the short convolutions, expert layers and the
+vocabulary's loss."""
 
 from ray_tpu.ops.attention import (
     attention_reference,
@@ -8,19 +9,23 @@ from ray_tpu.ops.attention import (
     finalize_flash,
     online_block_update,
 )
-from ray_tpu.ops.conv import gated_short_conv
+from ray_tpu.ops.conv import causal_conv, gated_short_conv
+from ray_tpu.ops.delta import gated_delta_rule
 from ray_tpu.ops.ring_attention import ring_attention, ring_self_attention
 from ray_tpu.ops.ssm import selective_scan
-from ray_tpu.ops import conv, moe, ssm, xent
+from ray_tpu.ops import conv, delta, moe, ssm, xent
 
 __all__ = [
     "conv",
+    "delta",
     "moe",
     "ssm",
     "xent",
     "attention_reference",
+    "causal_conv",
     "finalize_flash",
     "flash_attention",
+    "gated_delta_rule",
     "gated_short_conv",
     "online_block_update",
     "ring_attention",

@@ -1656,8 +1656,8 @@ def unmapped_mesh_axes(x) -> tuple:
 _FLASH_MIN_SEQ = 512
 # (key width, value width) of a head the kernel was measured at. (192, 128):
 # latent attention's per-head form, PERF.md section 6, PR 31. (64, 128): a
-# map of a differential layer on its pair's values, PR 48 (this file's end)
-_FLASH_HEAD_DIMS = ((64, 64), (64, 128), (128, 128), (192, 128))
+# differential layer's map, PR 48; (256, 256): PR 56 (both: this file's end)
+_FLASH_HEAD_DIMS = ((64, 64), (64, 128), (128, 128), (192, 128), (256, 256))
 
 
 def auto_attention(q, v=None) -> str:
@@ -1760,12 +1760,17 @@ _REMAT_NAMES = ("flash_out", "flash_lse")
 # starts from, [B, T / chunk, states, channels] float32. A block without a
 # scan has no such name, and its program is the one it was.
 SCAN_REMAT_NAMES = ("ssm_scan_out", "ssm_scan_bounds")
+# and of the gated delta rule (``ops/delta.py``): its output [B, T, heads,
+# d_v] in the compute dtype and the state each group of chunks starts from,
+# [B, heads, T / stride, d_k, d_v] float32, no more bytes than the output
+DELTA_REMAT_NAMES = ("delta_rule_out", "delta_rule_bounds")
 
 
 def remat_policy():
     """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
-    may run a kernel of ``ray_tpu/ops``: keep the selective scan's output and
-    boundary states, and the flash kernel's output and log-sum-exp (per layer
+    may run a kernel of ``ray_tpu/ops``: keep the selective scan's and the
+    gated delta rule's output and boundary states, and the flash kernel's
+    output and log-sum-exp (per layer
     one [B, T, H, d_v] array in the compute dtype and B x H x T float32;
     dense where the kernels write the model's arrays, else at a value
     width of 64 a lane-padded [B x H, T, 64] of nearly twice those bytes:
@@ -1774,7 +1779,7 @@ def remat_policy():
     attention is not the kernel (``xla``, the scan) no such name exists,
     nothing is kept and the program is the one without a policy."""
     return jax.checkpoint_policies.save_only_these_names(
-        *_REMAT_NAMES, *SCAN_REMAT_NAMES)
+        *_REMAT_NAMES, *SCAN_REMAT_NAMES, *DELTA_REMAT_NAMES)
 
 
 # Keys 64 and values 128 wide (PR 48; ``benches/flash_widths.py --widths
@@ -1802,3 +1807,20 @@ def remat_policy():
 # there: within 0.0029 to 0.0055 of the largest entry, with and without the
 # window (bfloat16 operands). XLA's scores at these lengths are [20, 16384,
 # 16384] a map and were not tried.
+#
+# Keys and values 256 wide (PR 56; ``benches/flash_widths.py --widths 256x256
+# --lengths 8192 --tokens 32768 --heads 16 --kv-heads 2 --check 1``, my chip
+# run: four sequences of 8,192 tokens, 16 query heads on 2 key-value heads,
+# the widest head and, with (32 on 4 of 128), the widest group so far): the
+# tiles of the narrower widths fit VMEM at twice the width, so none changed;
+# a head is 4 x 4 grid blocks (6 whole, 4 diagonal, 6 dead, none looped) on
+# the ``model_results`` boundary (one width of whole lane tiles past one
+# block of keys). ``flash_fwd`` 16.29 ms and ``flash_bwd`` 30.26 alone, 49.80
+# forward plus backward by the wall clock (3.25 of it outside the kernels);
+# the needed pairs are 69.5% of the MXU's peak forward and 92.1% backward
+# (``attn_kernel_roofline_pct``'s count on the cell's traced step, 8.03 and
+# 15.15 ms at two sequences): a 256-wide head fills the MXU's depth where a
+# 64-wide one fills a quarter. Output and the three gradients against
+# ``attention_reference`` in float32 there: within 0.0030 (out), 0.0044
+# (dq), 0.0042 (dk), 0.0032 (dv) of the largest entry. XLA's scores at this
+# shape are [16, 8192, 8192] float32 a sequence, 4.3 GB, and were not tried.

@@ -393,3 +393,45 @@ def gated_short_conv(bcx, taps, *, impl: Optional[str] = None) -> jax.Array:
                              out_specs=rows, axis_names=set(axes),
                              check_vma=False)
     return conv(bcx, taps)
+
+
+# ---------------------------------------------------------------------------
+# the plain causal depthwise convolution, with its activation
+# ---------------------------------------------------------------------------
+
+def _causal_conv(x, taps, activation):
+    length, last = x.shape[1], taps.shape[0] - 1
+    padded = jnp.pad(x, ((0, 0), (last, 0), (0, 0)))
+    y = sum(taps[k].astype(_F32) * padded[:, k:k + length].astype(_F32)
+            for k in range(last + 1))
+    return (y if activation is None else activation(y)).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _causal_conv_diff(x, taps, activation):
+    return _causal_conv(x, taps, activation)
+
+
+def _causal_conv_fwd(x, taps, activation):
+    return _causal_conv(x, taps, activation), (x, taps)
+
+
+def _causal_conv_bwd(activation, res, dy):
+    # nothing the size of y is kept: the sum over the taps is made again
+    return jax.vjp(functools.partial(_causal_conv, activation=activation),
+                   *res)[1](dy)
+
+
+_causal_conv_diff.defvjp(_causal_conv_fwd, _causal_conv_bwd)
+
+
+def causal_conv(x, taps, activation=None):
+    """``y_t = activation(sum_k taps[k] * x_{t - K + 1 + k})`` per channel:
+    the depthwise causal convolution of ``x`` [B, T, channels] with ``taps``
+    [K, channels], zeros before a sequence's first position, no bias, then
+    ``activation`` (None: none; a function of the float32 sum, such as
+    ``jax.nn.silu``). The sum over the taps is float32, ``y`` has ``x``'s
+    dtype. Plain XLA on every backend: the padded slices, the products, the
+    sum and the activation fuse into one pass over ``x``; its residuals are
+    its two arguments (the backward pass makes the sum again)."""
+    return _causal_conv_diff(x, taps, activation)

@@ -19,9 +19,9 @@ overflow tokens are dropped by the dispatch mask (their combine weight is
 zero, so the residual path carries them — standard Switch behavior).
 
 Beside them, the layer that drops nothing (PR 31): ``topk_routing`` (sigmoid
-scores, selection by score + bias, k experts a token, weights normalised
-over the k) and ``held_expert_ffn``, which is told which slice of the
-experts it holds, sorts the token-expert pairs that fall on them and runs
+or softmax scores, selection by score + bias, k experts a token, weights
+normalised over the k) and ``held_expert_ffn``, which is told which slice of
+the experts it holds, sorts the token-expert pairs that fall on them and runs
 grouped SwiGLU matmuls over the sorted rows (``jax.lax.ragged_dot``, which
 the TPU compiler lowers to its own grouped-matmul kernel whose grid follows
 the rows present), over as much of the row buffer as holds them, chunk by
@@ -186,20 +186,26 @@ def moe_ffn_ep(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
 # ----------------------------------------------------------------------
 
 def topk_routing(x, router, bias, k: int, scale: float = 1.0,
-                 normalize: bool = True, eps: float = 1e-20):
+                 normalize: bool = True, eps: float = 1e-20,
+                 score: str = "sigmoid"):
     """Route each token of ``x`` (T, d) to ``k`` of the E experts.
 
-    Scores are ``sigmoid(x @ router)`` in float32 (``router`` (d, E)); the k
-    experts with the largest ``score + bias`` are chosen (``bias`` (E,) moves
-    the selection only and takes no gradient); a chosen expert's weight is
-    its score, over the sum of the k chosen scores plus ``eps`` where
-    ``normalize``, times ``scale``. -> (experts (T, k) int32, weights f32)."""
+    Scores are ``sigmoid(x @ router)``, or with ``score="softmax"`` the
+    softmax of ``x @ router`` over all E, in float32 (``router`` (d, E));
+    the k experts with the largest ``score + bias`` are chosen (``bias``
+    (E,) moves the selection only and takes no gradient; None: the scores
+    alone choose); a chosen expert's weight is its score, over the sum of
+    the k chosen scores plus ``eps`` where ``normalize``, times ``scale``.
+    -> (experts (T, k) int32, weights f32)."""
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(
+    squash = {"sigmoid": jax.nn.sigmoid,
+              "softmax": functools.partial(jax.nn.softmax, axis=-1)}[score]
+    scores = squash(jnp.dot(
         x.astype(f32), router.astype(f32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(bias.astype(f32)), k)
+        scores if bias is None
+        else scores + jax.lax.stop_gradient(bias.astype(f32)), k)
     # each chosen expert's own score, picked by comparison, one choice at a
     # time (no array of tokens x k x experts is formed): a gather of T * k
     # scalars, and the scatter-add that is its transpose, take a v5e four to

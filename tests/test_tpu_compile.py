@@ -113,6 +113,7 @@ _HELD_PROGRAMS = {
     "joyai-llm-flash.step-8k": "be022cbee2cb17d6",
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
     "lfm2-8b-a1b.step-8k": "26754d67a7295565",
+    "qwen3-next-80b-a3b.step-8k": "780e3f8a1591dd61",
 }
 
 
@@ -961,4 +962,99 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
                batch * seq * model["num_experts_per_tok"], 3 * 2048,
                2 * 1792, 32 * 64, 8 * 64}
     assert model["vocab_size"] == 16384 and 16384 not in others
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
+
+
+def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``qwen3-next-80b-a3b.step-8k``
+    (published layers 0 to 3 at the published widths: three gated
+    delta-rule layers and one gated-attention layer, each with 32 of 512
+    softmax-routed experts beside a gated shared expert, an eighth of the
+    vocabulary under an untied head), its step at 2 x 8,192 with
+    recomputation, as the benchmark's family builds it: the plan stays under
+    the 14.5 GiB that ISSUE 56 set for choosing the batch (13.32 read; at 4
+    x 8,192 it is 17.74, which is why the cell runs the accepted ``step-8k``
+    traffic) with the state's 7.0 GiB as arguments. Every linear layer's
+    rule is the Pallas kernel pair over [2, 8192, 2048] keys and [2, 8192,
+    4096] values, one forward and one backward call a layer (the recomputed
+    block keeps the forward's output and boundary states by
+    ``ops.attention.remat_policy``); attention is one flash call forward and
+    one backward at 16 query heads on 2 key-value heads of 256. Each traced
+    call wrote its record into the runtime's ring. No array is shaped like
+    a [T, T] score matrix (the convolution's [2, T, 8192 channels] apart),
+    and the vocabulary's 18,992 rows equal no other
+    dimension of the program."""
+    from ray_tpu._private import steptrace
+
+    worker, model, traffic = _cut_cell("qwen3-next-80b-a3b.step-8k")
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (2, 8192)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = _lower_held(
+            "qwen3-next-80b-a3b.step-8k", built.step, params, opt_state,
+            {"input_ids": ids, "labels": ids})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {"attn/grid_blocks", "delta/rule",
+                            "model/layer_kinds", "attention/boundary",
+                            "moe/row_buffers", "moe/to_tokens"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "linear_attention": 3, "full_attention": 1, "expert": 4, "layers": 4,
+        "published_layers": 48}
+    assert {(e["heads"], e["kv_heads"], e["d_qk"], e["d_v"],
+             e["model_results"]) for e in by_name["attention/boundary"]} == {
+        (16, 2, 256, 256, 1)}
+    assert {e["backward"] for e in by_name["delta/rule"]} == {0, 1}
+    tokens = batch * seq
+    for e in by_name["delta/rule"]:
+        assert e == {"heads": 32, "key_heads": 16, "d_k": 128, "d_v": 128,
+                     "tokens": tokens, "sequences": batch, "chunk": 64,
+                     "boundary_bytes": tokens * 4096 * 2,   # the output's
+                     "bytes_needed": tokens * (41_472 if e["backward"]
+                                               else 24_832),
+                     "backward": e["backward"]}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 625_667_136 + 4 * 512
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?((?:flash|gated_delta)_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "gated_delta_fwd": 3,
+                     "gated_delta_bwd": 3}
+    assert "bf16[32,8192,256]" in text and "bf16[4,8192,256]" in text
+    assert "f32[2,32,32,128,128]" in text     # a boundary every 256 positions
+    _dq_census(text, 32, 256, seq)
+    # the four expert layers' rows go back to the tokens through the kernel
+    # ``to_tokens``, forward and backward
+    _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
+                      model["hidden_size"], model["num_experts"], calls=2 * 4)
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    # the convolution's 8,192 channels (q, k and v: 2 x 2048 + 4096) happen
+    # to equal the length: [batch, T, channels] is no score matrix, which
+    # would carry 16 heads or 2 x 16 before its [T, T]
+    channels = (batch, seq, 2 * 2048 + 4096)
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert dims == channels or not any(
+            a == b == seq for a, b in zip(dims, dims[1:])), dims
+        # 18,992 stands only beside the hidden size (the embedding, the
+        # head, their gradients and moments) or as a loss chunk's logits
+        if 18992 in dims:
+            assert dims in {(18992, 2048), (18992, 2048, 1),
+                            (2, 1024, 18992)}, dims
     print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
