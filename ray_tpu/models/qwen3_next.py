@@ -13,7 +13,10 @@ training.
 
   - ``linear_attention``: ``[q | k | v | z] = u W_qkvz`` and ``[b | a] = u
     W_ba``; ``[q | k | v]`` pass a depthwise causal convolution of
-    ``linear_conv_kernel_dim`` taps and a SiLU (``ops.conv.causal_conv``);
+    ``linear_conv_kernel_dim`` taps and a SiLU (``ops.conv.causal_conv``:
+    on a TPU the Pallas kernels ``causal_conv_fwd`` / ``causal_conv_bwd``,
+    one pass over ``[q | k | v]`` each way, forward again where a block is
+    recomputed; XLA's padded slices elsewhere);
     q and k are L2-normalised over each head's width, q scaled by
     width^-0.5; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
     dt_bias)`` in float32; ``o = ops.delta.gated_delta_rule(q, k, v, g,

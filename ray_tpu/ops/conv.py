@@ -1,6 +1,6 @@
 """The gated short convolution (a causal depthwise convolution of a few taps
 between two gates), forward and backward: a Pallas TPU kernel pair and the
-same arithmetic in ``jax.numpy``.
+same arithmetic in ``jax.numpy``; below it, ``causal_conv``'s pair (PR 57).
 
     [B | C | x] = bcx                       three chunks of the last axis
     z_t = B_t * x_t
@@ -398,6 +398,32 @@ def gated_short_conv(bcx, taps, *, impl: Optional[str] = None) -> jax.Array:
 # ---------------------------------------------------------------------------
 # the plain causal depthwise convolution, with its activation
 # ---------------------------------------------------------------------------
+#
+#     s_t = sum_k taps[k] * x_{t - K + 1 + k}   K taps, zeros before position 0
+#     y_t = activation(s_t)
+#
+# One chunk, no gates, an activation whose derivative the backward needs: a
+# second kernel pair (``causal_conv_fwd`` / ``causal_conv_bwd``) over the
+# helpers above, and the XLA form as its ``jnp`` twin. The forward reads ``x``
+# and writes ``y``; the backward reads ``x`` and ``dy``, makes ``s`` again and
+# writes ``dx`` and the taps' float32 partial sums a sequence. The grid is
+# (sequences, slabs of channels, blocks of positions): a grid step holds one
+# ``_slab`` of lanes, so a loop step is a few vector registers an array with
+# no loop over slabs (read on the chip at [2, 8192, 8192] bfloat16, PR 57: a
+# block of 256 channels forward 1.008 ms and backward 1.662; of 512 in two
+# slabs 1.038 / 1.710; of 2,048 in eight 1.044 / 1.767). The rows before a block
+# come as a ``_HALO``-row block of ``x``, as the gated kernels' do; inside a
+# block each loop step hands its last rows on to the next. The backward needs
+# ``ds = dy * activation'(s)`` of the rows AFTER a block, which no operand
+# holds: it walks a sequence's blocks, and a block's loop steps, from the last
+# to the first, and hands the first ``_HALO`` rows of each step's ``ds`` on to
+# the step before it (across blocks in a VMEM scratch), zeros at a sequence's
+# last block. The sigmoid is the exact one (XLA's ``logistic``: ``y`` equals
+# the XLA form's to the bit on the chip); it is a fifth of the forward's
+# time and a quarter of the backward's (PERF.md section 6, PR 57).
+
+_CAUSAL_TAPS = (2, 3, 4)
+
 
 def _causal_conv(x, taps, activation):
     length, last = x.shape[1], taps.shape[0] - 1
@@ -407,31 +433,239 @@ def _causal_conv(x, taps, activation):
     return (y if activation is None else activation(y)).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _causal_conv_diff(x, taps, activation):
-    return _causal_conv(x, taps, activation)
+def _behind_by_tap(x, before, taps: int):
+    """[x_{t-K+1}, ..., x_{t-1}, x_t]: what each tap weighs."""
+    return [_shifted_behind(x, before, taps - 1 - k)
+            for k in range(taps - 1)] + [x]
 
 
-def _causal_conv_fwd(x, taps, activation):
-    return _causal_conv(x, taps, activation), (x, taps)
+def _weighed(w, by_tap):
+    """sum_k w[k] * by_tap[k], in the taps' order."""
+    return functools.reduce(lambda total, t: total + t,
+                            (wk * xk for wk, xk in zip(w, by_tap)))
 
 
-def _causal_conv_bwd(activation, res, dy):
+def _rows_before_block(hx_ref, first):
+    """The _HALO rows before a block, in ``x``'s dtype: the block before's,
+    zeros in a sequence's ``first`` block."""
+    before = hx_ref[...]
+    return jnp.where(first, jnp.zeros_like(before), before)
+
+
+def _causal_fwd_kernel(x_ref, hx_ref, w_ref, y_ref, *, taps, silu):
+    w = [w_ref[k:k + 1] for k in range(taps)]
+
+    def chunk(j, before):
+        # ``before``: the last rows of the step before, handed on
+        rows = pl.ds(pl.multiple_of(j * _ROWS, _ROWS), _ROWS)
+        x = x_ref[rows].astype(_F32)
+        total = _weighed(w, _behind_by_tap(x, before, taps))
+        if silu:
+            total = total * jax.nn.sigmoid(total)
+        y_ref[rows] = total.astype(y_ref.dtype)
+        return x[_ROWS - _HALO:]
+
+    first = pl.program_id(2) == 0
+    lax.fori_loop(0, y_ref.shape[0] // _ROWS, chunk,
+                  _rows_before_block(hx_ref, first).astype(_F32))
+
+
+def _causal_bwd_kernel(x_ref, dy_ref, hx_ref, w_ref, dx_ref, dw_ref,
+                       ahead_ref, *, taps, silu):
+    """Grid step ``i`` of a sequence holds its block ``blocks - 1 - i``:
+    ``ahead_ref`` [_HALO, channels] is ``ds`` of the first rows of the block
+    after it, left there by the grid step before."""
+    block, channels = dy_ref.shape
+    i = pl.program_id(2)
+    first, final = i == pl.num_programs(2) - 1, i == 0
+    steps = block // _ROWS
+
+    @pl.when(final)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, _F32)
+        ahead_ref[...] = jnp.zeros(ahead_ref.shape, _F32)
+
+    w = [w_ref[k:k + 1] for k in range(taps)]
+    edge = _rows_before_block(hx_ref, first)
+
+    def chunk(r, carried):
+        # from the block's last step to its first; each step reads the rows
+        # of the step before it and hands them on as that step's x
+        x, after, sums = carried
+        j = steps - 1 - r
+        start = pl.multiple_of(j * _ROWS, _ROWS)
+        rows = pl.ds(start, _ROWS)
+        behind = x_ref[pl.ds(pl.multiple_of(
+            jnp.maximum(start - _ROWS, 0), _ROWS), _ROWS)]
+        before = jnp.where(j == 0, edge, behind[_ROWS - _HALO:])
+        x = x.astype(_F32)
+        by_tap = _behind_by_tap(x, before.astype(_F32), taps)
+        ds = dy_ref[rows].astype(_F32)
+        if silu:
+            total = _weighed(w, by_tap)
+            gate = jax.nn.sigmoid(total)
+            ds = ds * (gate * (1.0 + total * (1.0 - gate)))
+        dx = _weighed(w, [_shifted_ahead(ds, after, taps - 1 - k)
+                          for k in range(taps - 1)] + [ds])
+        dx_ref[rows] = dx.astype(dx_ref.dtype)
+        # eight sublanes of partial sums a tap: whole registers added
+        fold = lambda t: sum(t[q:q + 8] for q in range(0, _ROWS, 8))
+        return behind, ds[:_HALO], tuple(
+            acc + fold(ds * xk) for acc, xk in zip(sums, by_tap))
+
+    zero = jnp.zeros((8, channels), _F32)
+    _, after, sums = lax.fori_loop(
+        0, steps, chunk,
+        (x_ref[pl.ds(block - _ROWS, _ROWS)], ahead_ref[...], (zero,) * taps))
+    ahead_ref[...] = after
+    for k, acc in enumerate(sums):
+        dw_ref[k:k + 1] += acc.sum(axis=0, keepdims=True)
+
+
+def causal_needed_bytes(tokens: int, channels: int, taps: int, itemsize: int,
+                        backward: bool) -> int:
+    """What a pass over ``tokens`` positions has to move: ``x`` in and ``y``
+    out, or ``x`` and ``dy`` in and ``dx`` out with the taps' float32
+    gradient; the taps themselves either way."""
+    cells = tokens * channels * itemsize
+    return (3 if backward else 2) * cells + (2 if backward else 1) * (
+        taps * channels * 4)
+
+
+def _causal_record(x, taps, silu: bool, backward: bool):
+    """One ``counters`` record a traced pass of the kernels (none a step,
+    none where the XLA form runs)."""
+    batch, length, channels = x.shape
+    steptrace.record_counters("conv/causal", {
+        "channels": channels, "taps": taps.shape[0],
+        "tokens": batch * length, "sequences": batch,
+        "activation": int(silu),      # 0: none, 1: a SiLU
+        "bytes_needed": causal_needed_bytes(
+            batch * length, channels, taps.shape[0], x.dtype.itemsize,
+            backward),
+        "backward": int(backward)})
+
+
+def _causal_specs(x, taps, backward: bool):
+    """The grid and the operands' blocks: a block of positions and channels,
+    the ``_HALO`` rows before it, the taps. The backward's grid walks a
+    sequence from its last block to its first."""
+    batch, length, channels = x.shape
+    wide = _slab(channels)
+    block = block_rows(length, wide, x.dtype.itemsize)
+    assert block, x.shape     # causal_fits: whole lane tiles, whole steps
+    per, blocks = block // _HALO, length // block
+    at = (lambda i: blocks - 1 - i) if backward else (lambda i: i)
+    rows = pl.BlockSpec((None, block, wide), lambda b, c, i: (b, at(i), c))
+    before = pl.BlockSpec(
+        (None, _HALO, wide),
+        lambda b, c, i: (b, jnp.maximum(at(i) * per - 1, 0), c))
+    weights = pl.BlockSpec((taps.shape[0], wide), lambda b, c, i: (0, c))
+    return (batch, channels // wide, blocks), rows, before, weights, wide
+
+
+def _causal_pallas_fwd(x, taps, silu, interpret):
+    grid, rows, before, weights, _ = _causal_specs(x, taps, False)
+    _causal_record(x, taps, silu, False)
+    return pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, taps=taps.shape[0], silu=silu),
+        grid=grid, in_specs=[rows, before, weights], out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_params(interpret,
+                                ("parallel", "parallel", "parallel")),
+        interpret=interpret, name="causal_conv_fwd",
+    )(x, x, taps)
+
+
+def _causal_pallas_bwd(x, taps, dy, silu, interpret):
+    grid, rows, before, weights, wide = _causal_specs(x, taps, True)
+    batch, _, channels = x.shape
+    _causal_record(x, taps, silu, True)
+    dx, dtaps = pl.pallas_call(
+        functools.partial(_causal_bwd_kernel, taps=taps.shape[0], silu=silu),
+        grid=grid, in_specs=[rows, rows, before, weights],
+        out_specs=[
+            rows,
+            # a sequence's sum, added to at each of its blocks
+            pl.BlockSpec((None, taps.shape[0], wide),
+                         lambda b, c, i: (b, 0, c)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((batch, taps.shape[0], channels), _F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((_HALO, wide), _F32)],
+        compiler_params=_params(interpret,
+                                ("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="causal_conv_bwd",
+    )(x, dy, x, taps)
+    return dx, dtaps.sum(0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _causal_conv_diff(x, taps, activation, impl):
+    if impl == "jnp":
+        return _causal_conv(x, taps, activation)
+    return _causal_pallas_fwd(x, taps, activation is not None,
+                              impl == "pallas_interpret")
+
+
+def _causal_conv_fwd(x, taps, activation, impl):
+    return _causal_conv_diff(x, taps, activation, impl), (x, taps)
+
+
+def _causal_conv_bwd(activation, impl, res, dy):
     # nothing the size of y is kept: the sum over the taps is made again
-    return jax.vjp(functools.partial(_causal_conv, activation=activation),
-                   *res)[1](dy)
+    if impl == "jnp":
+        return jax.vjp(functools.partial(_causal_conv, activation=activation),
+                       *res)[1](dy)
+    x, taps = res
+    return _causal_pallas_bwd(x, taps, dy, activation is not None,
+                              impl == "pallas_interpret")
 
 
 _causal_conv_diff.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
-def causal_conv(x, taps, activation=None):
+def causal_fits(x, taps, activation) -> bool:
+    """Whether the causal kernels take the call: 2 to 4 taps, channels a
+    multiple of 128, a length ``_ROWS`` divides, no activation or
+    ``jax.nn.silu`` itself."""
+    _, length, channels = x.shape
+    return bool(taps.shape[0] in _CAUSAL_TAPS and channels % _LANES == 0
+                and length % _ROWS == 0
+                and (activation is None or activation is jax.nn.silu))
+
+
+def causal_auto_impl(x, taps, activation) -> str:
+    """What ``impl=None`` runs: the rule of ``auto_impl``."""
+    if (jax.default_backend() == "tpu" and causal_fits(x, taps, activation)
+            and not unmapped_mesh_axes(x)):
+        return "pallas"
+    return "jnp"
+
+
+def causal_conv(x, taps, activation=None, *, impl: Optional[str] = None):
     """``y_t = activation(sum_k taps[k] * x_{t - K + 1 + k})`` per channel:
     the depthwise causal convolution of ``x`` [B, T, channels] with ``taps``
     [K, channels], zeros before a sequence's first position, no bias, then
     ``activation`` (None: none; a function of the float32 sum, such as
     ``jax.nn.silu``). The sum over the taps is float32, ``y`` has ``x``'s
-    dtype. Plain XLA on every backend: the padded slices, the products, the
-    sum and the activation fuse into one pass over ``x``; its residuals are
-    its two arguments (the backward pass makes the sum again)."""
-    return _causal_conv_diff(x, taps, activation)
+    dtype. Its residuals are its two arguments (the backward pass makes the
+    sum again). ``impl``: "pallas" | "pallas_interpret" (the kernels
+    ``causal_conv_fwd`` / ``causal_conv_bwd``: one pass over the operands
+    each way, where ``causal_fits``) | "jnp" (plain XLA on every backend: the
+    padded slices, the products, the sum and the activation, and their
+    transposes); None: ``causal_auto_impl``."""
+    impl = impl or causal_auto_impl(x, taps, activation)
+    if impl == "jnp":
+        return _causal_conv_diff(x, taps, activation, impl)
+    assert causal_fits(x, taps, activation), (x.shape, taps.shape, activation)
+    conv = lambda x, taps: _causal_conv_diff(x, taps, activation, impl)
+    mesh, axes = _batch_axes(x)
+    if axes:
+        rows, whole = PartitionSpec(axes), PartitionSpec()
+        conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, whole),
+                             out_specs=rows, axis_names=set(axes),
+                             check_vma=False)
+    return conv(x, taps.astype(_F32))

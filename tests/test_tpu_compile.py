@@ -113,7 +113,7 @@ _HELD_PROGRAMS = {
     "joyai-llm-flash.step-8k": "be022cbee2cb17d6",
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
     "lfm2-8b-a1b.step-8k": "26754d67a7295565",
-    "qwen3-next-80b-a3b.step-8k": "780e3f8a1591dd61",
+    "qwen3-next-80b-a3b.step-8k": "d03aab20694bde68",   # PR 57
 }
 
 
@@ -226,6 +226,40 @@ def test_flash_kernel_with_results_in_the_models_arrays_compiles(
         handed, = re.findall(r"%flash_bwd\S* = (.*?) custom-call\(", text)
         assert re.findall(r"(\w+\[[\d,]+\])", handed) == [
             "f32[32,128,16384]", "bf16[1,16384,512]", "bf16[1,16384,512]"]
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_causal_conv_kernels_compile(topo, no_compile_cache, backward):
+    """The linear mixers' call in ``qwen3-next-80b-a3b.step-8k`` (PR 57): two
+    sequences of 8,192 positions of 8,192 channels in bfloat16, four taps and
+    a SiLU. One ``causal_conv_fwd`` and, in the gradient, one
+    ``causal_conv_bwd`` (whose own forward is not run: the backward makes the
+    sum again), blocks of 2,048 positions of 256 channels; nothing of the
+    XLA form's passes is left beside them: no pad, no slice, no reduction
+    over the positions."""
+    from ray_tpu.ops import conv
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((2, 8192, 8192), jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, 8192), jnp.float32, sharding=one_chip)
+    assert conv.block_rows(8192, conv._slab(8192), 2) == 2048
+
+    def fwd(x, taps):
+        return conv.causal_conv(x, taps, jax.nn.silu, impl="pallas")
+
+    def grads(x, taps, dy):
+        return jax.vjp(fwd, x, taps)[1](dy)
+
+    args = (x, taps, x) if backward else (x, taps)
+    text = jax.jit(grads if backward else fwd).lower(*args).compile().as_text()
+    calls = collections.Counter(re.findall(
+        r"(causal_conv_(?:fwd|bwd))[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text))
+    assert calls == ({"causal_conv_bwd": 1} if backward
+                     else {"causal_conv_fwd": 1})
+    assert not re.findall(r" (?:pad|slice|copy|transpose)\(", text)
+    # the taps' gradient: two sequences' float32 partial sums added
+    assert len(re.findall(r" reduce\(", text)) <= (1 if backward else 0)
 
 
 _FLASH_CALL = re.compile(r"^\s*%?(flash_fwd|flash_bwd)[\w.\-]* = .*"
@@ -979,7 +1013,10 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     rule is the Pallas kernel pair over [2, 8192, 2048] keys and [2, 8192,
     4096] values, one forward and one backward call a layer (the recomputed
     block keeps the forward's output and boundary states by
-    ``ops.attention.remat_policy``); attention is one flash call forward and
+    ``ops.attention.remat_policy``); every linear layer's four-tap
+    convolution is the pair ``causal_conv_fwd`` / ``causal_conv_bwd`` over
+    [2, 8192, 8192] (PR 57: forward, forward again in the recomputed block,
+    backward; the plan did not move); attention is one flash call forward and
     one backward at 16 query heads on 2 key-value heads of 256. Each traced
     call wrote its record into the runtime's ring. No array is shaped like
     a [T, T] score matrix (the convolution's [2, T, 8192 channels] apart),
@@ -1008,7 +1045,7 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     by_name = collections.defaultdict(list)
     for e in counters:
         by_name[e["name"]].append(e["args"])
-    assert set(by_name) == {"attn/grid_blocks", "delta/rule",
+    assert set(by_name) == {"attn/grid_blocks", "delta/rule", "conv/causal",
                             "model/layer_kinds", "attention/boundary",
                             "moe/row_buffers", "moe/to_tokens"}
     assert by_name["model/layer_kinds"][-1] == {
@@ -1026,6 +1063,15 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
                      "bytes_needed": tokens * (41_472 if e["backward"]
                                                else 24_832),
                      "backward": e["backward"]}
+    assert {e["backward"] for e in by_name["conv/causal"]} == {0, 1}
+    cells = tokens * 8192 * 2
+    for e in by_name["conv/causal"]:
+        assert e == {"channels": 8192, "taps": 4, "tokens": tokens,
+                     "sequences": batch, "activation": 1,
+                     "backward": e["backward"],
+                     "bytes_needed": (3 * cells + 2 * 4 * 8192 * 4
+                                      if e["backward"]
+                                      else 2 * cells + 4 * 8192 * 4)}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -1033,10 +1079,13 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     assert 3 * 4 * n_params < planned < 14.5 * 2**30
     text = compiled.as_text()
     calls = collections.Counter(re.findall(
-        r"^\s*%?((?:flash|gated_delta)_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
-        r'custom_call_target="tpu_custom_call"', text, re.M))
+        r"^\s*%?((?:flash|gated_delta|causal_conv)_(?:fwd|bwd)(?:_w\d+)?)"
+        r'[\w.\-]* = .*custom_call_target="tpu_custom_call"', text, re.M))
+    # every linear layer's convolution is the kernel pair (PR 57): forward,
+    # forward again in the recomputed block, backward
     assert calls == {"flash_fwd": 1, "flash_bwd": 1, "gated_delta_fwd": 3,
-                     "gated_delta_bwd": 3}
+                     "gated_delta_bwd": 3, "causal_conv_fwd": 6,
+                     "causal_conv_bwd": 3}
     assert "bf16[32,8192,256]" in text and "bf16[4,8192,256]" in text
     assert "f32[2,32,32,128,128]" in text     # a boundary every 256 positions
     _dq_census(text, 32, 256, seq)
