@@ -14,10 +14,14 @@
 - qwen3_next: gated delta-rule linear-attention layers
   (``ops.delta.gated_delta_rule``) three to one over gated attention,
   softmax-routed experts beside a gated shared expert (training)
+- nemotron_h: one mixer a block by a pattern string: Mamba-2 state-space
+  mixers (``ops.ssm.ssd_scan``), grouped attention without a positional
+  term, sigmoid-routed un-gated relu^2 experts beside a shared expert
+  (training)
 """
 
-from ray_tpu.models import (afmoe, gpt2, llama, mla_moe, moe_lm, phi4flash,
-                            qwen3_next, vision)
+from ray_tpu.models import (afmoe, gpt2, llama, mla_moe, moe_lm, nemotron_h,
+                            phi4flash, qwen3_next, vision)
 
-__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "phi4flash",
-           "qwen3_next", "vision"]
+__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "nemotron_h",
+           "phi4flash", "qwen3_next", "vision"]

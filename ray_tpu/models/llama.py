@@ -182,6 +182,23 @@ class SwiGLU(nn.Module):
         return dense(x.shape[-1], name="down_proj")(nn.silu(g) * u)
 
 
+class ReLU2(nn.Module):
+    """``down(relu(up(x))^2)`` of inner width ``intermediate``, no gate and
+    no biases: the shared expert beside ``models/mla_moe.RoutedExperts``'s
+    un-gated experts (``activation="relu2"``)."""
+    intermediate: int
+    dtype: Any = jnp.bfloat16
+    kernel_init: Any = nn.linear.default_kernel_init
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  kernel_init=self.kernel_init)
+        u = dense(self.intermediate, name="up_proj")(x)
+        return dense(x.shape[-1], name="down_proj")(
+            jnp.square(nn.relu(u)))
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
 

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
-from ray_tpu.models.llama import (RMSNorm, SwiGLU, apply_rope,
+from ray_tpu.models.llama import (ReLU2, RMSNorm, SwiGLU, apply_rope,
                                   rope_frequencies)
 from ray_tpu.ops import moe, xent
 from ray_tpu.ops.attention import causal_self_attention, remat_policy
@@ -148,9 +148,10 @@ class RoutedExperts(nn.Module):
     ``expert_shard = (index, of)`` held here, ``shared`` shared experts on
     every token (0: none, and no parameter of one), multiplied by
     ``sigmoid(x w_g)`` where ``shared_gate`` (a parameter ``shared_gate``
-    [d, 1]; off, there is none); SwiGLUs of ``width``, weighted as
-    ``ops.moe.topk_routing`` says of ``score``. -> (y, tokens a held
-    expert)."""
+    [d, 1]; off, there is none); SwiGLUs of ``width``, or with
+    ``activation="relu2"`` un-gated ``relu(x W_i)^2 W_o`` of ``width`` (the
+    shared expert of the same form), weighted as ``ops.moe.topk_routing``
+    says of ``score``. -> (y, tokens a held expert)."""
     experts: int
     expert_shard: Tuple[int, int]
     width: int
@@ -163,24 +164,29 @@ class RoutedExperts(nn.Module):
     eps: float = 1e-20
     score: str = "sigmoid"
     shared_gate: bool = False
+    activation: str = "swiglu"
 
     @nn.compact
     def __call__(self, x):
         (B, T, d), (index, of) = x.shape, self.expert_shard
+        gated = self.activation == "swiglu"
         held, width, init = self.experts // of, self.width, self.kernel_init
         router = self.param("router", init, (d, self.experts))
         bias = self.param("router_bias", nn.initializers.zeros,
                           (self.experts,))
-        wi = self.param("experts_wi", init, (held, d, 2 * width))
+        wi = self.param("experts_wi", init,
+                        (held, d, 2 * width if gated else width))
         wo = self.param("experts_wo", init, (held, width, d))
-        shared = SwiGLU(width * self.shared, self.dtype, init,
-                        name="shared_experts") if self.shared else None
+        shared = (SwiGLU if gated else ReLU2)(
+            width * self.shared, self.dtype, init,
+            name="shared_experts") if self.shared else None
         flat = x.reshape(B * T, d)
         experts, weights = moe.topk_routing(
             flat, router, bias, self.per_token, self.scale, self.normalize,
             self.eps, self.score)
         y, tokens = moe.held_expert_ffn(flat, experts, weights, wi, wo,
-                                        index=index, of=of)
+                                        index=index, of=of,
+                                        activation=self.activation)
         if shared is None:   # the routed part alone
             return y.reshape(B, T, d), tokens
         if not self.shared_gate:

@@ -1,5 +1,5 @@
 """ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, the
-selective scan of state-space layers, the gated delta rule of
+selective and the scalar-decay scan of state-space layers, the gated delta rule of
 linear-attention layers, the short convolutions, expert layers and the
 vocabulary's loss."""
 
@@ -12,7 +12,7 @@ from ray_tpu.ops.attention import (
 from ray_tpu.ops.conv import causal_conv, gated_short_conv
 from ray_tpu.ops.delta import gated_delta_rule
 from ray_tpu.ops.ring_attention import ring_attention, ring_self_attention
-from ray_tpu.ops.ssm import selective_scan
+from ray_tpu.ops.ssm import selective_scan, ssd_scan
 from ray_tpu.ops import conv, delta, moe, ssm, xent
 
 __all__ = [
@@ -31,4 +31,5 @@ __all__ = [
     "ring_attention",
     "ring_self_attention",
     "selective_scan",
+    "ssd_scan",
 ]

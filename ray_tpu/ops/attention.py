@@ -1764,12 +1764,17 @@ SCAN_REMAT_NAMES = ("ssm_scan_out", "ssm_scan_bounds")
 # d_v] in the compute dtype and the state each group of chunks starts from,
 # [B, heads, T / stride, d_k, d_v] float32, no more bytes than the output
 DELTA_REMAT_NAMES = ("delta_rule_out", "delta_rule_bounds")
+# and of the scalar-decay state-space scan (``ops/ssm.py:ssd_scan``): its
+# output [B, T, heads, head_dim] in the compute dtype and the state each
+# stride starts from, [B, T / stride, heads, head_dim, states] float32, no
+# more bytes than the output
+SSD_REMAT_NAMES = ("ssd_out", "ssd_bounds")
 
 
 def remat_policy():
     """The policy for ``jax.checkpoint`` / ``nn.remat`` round a block that
-    may run a kernel of ``ray_tpu/ops``: keep the selective scan's and the
-    gated delta rule's output and boundary states, and the flash kernel's
+    may run a kernel of ``ray_tpu/ops``: keep the selective scan's, the
+    scalar-decay scan's and the gated delta rule's output and boundary states, and the flash kernel's
     output and log-sum-exp (per layer
     one [B, T, H, d_v] array in the compute dtype and B x H x T float32;
     dense where the kernels write the model's arrays, else at a value
@@ -1779,7 +1784,8 @@ def remat_policy():
     attention is not the kernel (``xla``, the scan) no such name exists,
     nothing is kept and the program is the one without a policy."""
     return jax.checkpoint_policies.save_only_these_names(
-        *_REMAT_NAMES, *SCAN_REMAT_NAMES, *DELTA_REMAT_NAMES)
+        *_REMAT_NAMES, *SCAN_REMAT_NAMES, *DELTA_REMAT_NAMES,
+        *SSD_REMAT_NAMES)
 
 
 # Keys 64 and values 128 wide (PR 48; ``benches/flash_widths.py --widths

@@ -400,7 +400,7 @@ def gated_short_conv(bcx, taps, *, impl: Optional[str] = None) -> jax.Array:
 # ---------------------------------------------------------------------------
 #
 #     s_t = sum_k taps[k] * x_{t - K + 1 + k}   K taps, zeros before position 0
-#     y_t = activation(s_t)
+#     y_t = activation(s_t + bias)              bias: a number a channel, or none
 #
 # One chunk, no gates, an activation whose derivative the backward needs: a
 # second kernel pair (``causal_conv_fwd`` / ``causal_conv_bwd``) over the
@@ -425,11 +425,13 @@ def gated_short_conv(bcx, taps, *, impl: Optional[str] = None) -> jax.Array:
 _CAUSAL_TAPS = (2, 3, 4)
 
 
-def _causal_conv(x, taps, activation):
+def _causal_conv(x, taps, activation, bias=None):
     length, last = x.shape[1], taps.shape[0] - 1
     padded = jnp.pad(x, ((0, 0), (last, 0), (0, 0)))
     y = sum(taps[k].astype(_F32) * padded[:, k:k + length].astype(_F32)
             for k in range(last + 1))
+    if bias is not None:
+        y = y + bias.astype(_F32)
     return (y if activation is None else activation(y)).astype(x.dtype)
 
 
@@ -452,7 +454,9 @@ def _rows_before_block(hx_ref, first):
     return jnp.where(first, jnp.zeros_like(before), before)
 
 
-def _causal_fwd_kernel(x_ref, hx_ref, w_ref, y_ref, *, taps, silu):
+def _causal_fwd_kernel(x_ref, hx_ref, w_ref, y_ref, *, taps, silu,
+                       bias=False):
+    # with a ``bias`` it is the row after the taps' of ``w_ref``
     w = [w_ref[k:k + 1] for k in range(taps)]
 
     def chunk(j, before):
@@ -460,6 +464,8 @@ def _causal_fwd_kernel(x_ref, hx_ref, w_ref, y_ref, *, taps, silu):
         rows = pl.ds(pl.multiple_of(j * _ROWS, _ROWS), _ROWS)
         x = x_ref[rows].astype(_F32)
         total = _weighed(w, _behind_by_tap(x, before, taps))
+        if bias:
+            total = total + w_ref[taps:taps + 1]
         if silu:
             total = total * jax.nn.sigmoid(total)
         y_ref[rows] = total.astype(y_ref.dtype)
@@ -471,10 +477,12 @@ def _causal_fwd_kernel(x_ref, hx_ref, w_ref, y_ref, *, taps, silu):
 
 
 def _causal_bwd_kernel(x_ref, dy_ref, hx_ref, w_ref, dx_ref, dw_ref,
-                       ahead_ref, *, taps, silu):
+                       ahead_ref, *, taps, silu, bias=False):
     """Grid step ``i`` of a sequence holds its block ``blocks - 1 - i``:
     ``ahead_ref`` [_HALO, channels] is ``ds`` of the first rows of the block
-    after it, left there by the grid step before."""
+    after it, left there by the grid step before. With a ``bias`` (the row
+    after the taps' of ``w_ref``) its gradient, the sum of ``ds``, is the
+    row after the taps' of ``dw_ref``."""
     block, channels = dy_ref.shape
     i = pl.program_id(2)
     first, final = i == pl.num_programs(2) - 1, i == 0
@@ -503,6 +511,8 @@ def _causal_bwd_kernel(x_ref, dy_ref, hx_ref, w_ref, dx_ref, dw_ref,
         ds = dy_ref[rows].astype(_F32)
         if silu:
             total = _weighed(w, by_tap)
+            if bias:
+                total = total + w_ref[taps:taps + 1]
             gate = jax.nn.sigmoid(total)
             ds = ds * (gate * (1.0 + total * (1.0 - gate)))
         dx = _weighed(w, [_shifted_ahead(ds, after, taps - 1 - k)
@@ -510,39 +520,42 @@ def _causal_bwd_kernel(x_ref, dy_ref, hx_ref, w_ref, dx_ref, dw_ref,
         dx_ref[rows] = dx.astype(dx_ref.dtype)
         # eight sublanes of partial sums a tap: whole registers added
         fold = lambda t: sum(t[q:q + 8] for q in range(0, _ROWS, 8))
+        weighed_by = by_tap + [jnp.ones_like(ds)] if bias else by_tap
         return behind, ds[:_HALO], tuple(
-            acc + fold(ds * xk) for acc, xk in zip(sums, by_tap))
+            acc + fold(ds * xk) for acc, xk in zip(sums, weighed_by))
 
     zero = jnp.zeros((8, channels), _F32)
     _, after, sums = lax.fori_loop(
         0, steps, chunk,
-        (x_ref[pl.ds(block - _ROWS, _ROWS)], ahead_ref[...], (zero,) * taps))
+        (x_ref[pl.ds(block - _ROWS, _ROWS)], ahead_ref[...],
+         (zero,) * (taps + int(bias))))
     ahead_ref[...] = after
     for k, acc in enumerate(sums):
         dw_ref[k:k + 1] += acc.sum(axis=0, keepdims=True)
 
 
 def causal_needed_bytes(tokens: int, channels: int, taps: int, itemsize: int,
-                        backward: bool) -> int:
+                        backward: bool, bias: bool = False) -> int:
     """What a pass over ``tokens`` positions has to move: ``x`` in and ``y``
     out, or ``x`` and ``dy`` in and ``dx`` out with the taps' float32
-    gradient; the taps themselves either way."""
+    gradient; the taps themselves either way, a ``bias`` as one tap more."""
     cells = tokens * channels * itemsize
     return (3 if backward else 2) * cells + (2 if backward else 1) * (
-        taps * channels * 4)
+        (taps + int(bias)) * channels * 4)
 
 
-def _causal_record(x, taps, silu: bool, backward: bool):
+def _causal_record(x, taps, silu: bool, backward: bool, bias: bool = False):
     """One ``counters`` record a traced pass of the kernels (none a step,
-    none where the XLA form runs)."""
+    none where the XLA form runs). ``taps``: without the bias's row."""
     batch, length, channels = x.shape
     steptrace.record_counters("conv/causal", {
-        "channels": channels, "taps": taps.shape[0],
+        "channels": channels, "taps": taps,
         "tokens": batch * length, "sequences": batch,
         "activation": int(silu),      # 0: none, 1: a SiLU
+        "bias": int(bias),
         "bytes_needed": causal_needed_bytes(
-            batch * length, channels, taps.shape[0], x.dtype.itemsize,
-            backward),
+            batch * length, channels, taps, x.dtype.itemsize, backward,
+            bias),
         "backward": int(backward)})
 
 
@@ -564,11 +577,14 @@ def _causal_specs(x, taps, backward: bool):
     return (batch, channels // wide, blocks), rows, before, weights, wide
 
 
-def _causal_pallas_fwd(x, taps, silu, interpret):
+def _causal_pallas_fwd(x, taps, silu, interpret, bias=False):
+    """``taps``: with a ``bias``, [K + 1, channels], the bias its last row."""
     grid, rows, before, weights, _ = _causal_specs(x, taps, False)
-    _causal_record(x, taps, silu, False)
+    taps_n = taps.shape[0] - int(bias)
+    _causal_record(x, taps_n, silu, False, bias)
     return pl.pallas_call(
-        functools.partial(_causal_fwd_kernel, taps=taps.shape[0], silu=silu),
+        functools.partial(_causal_fwd_kernel, taps=taps_n, silu=silu,
+                          bias=bias),
         grid=grid, in_specs=[rows, before, weights], out_specs=rows,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         compiler_params=_params(interpret,
@@ -577,12 +593,14 @@ def _causal_pallas_fwd(x, taps, silu, interpret):
     )(x, x, taps)
 
 
-def _causal_pallas_bwd(x, taps, dy, silu, interpret):
+def _causal_pallas_bwd(x, taps, dy, silu, interpret, bias=False):
     grid, rows, before, weights, wide = _causal_specs(x, taps, True)
     batch, _, channels = x.shape
-    _causal_record(x, taps, silu, True)
+    taps_n = taps.shape[0] - int(bias)
+    _causal_record(x, taps_n, silu, True, bias)
     dx, dtaps = pl.pallas_call(
-        functools.partial(_causal_bwd_kernel, taps=taps.shape[0], silu=silu),
+        functools.partial(_causal_bwd_kernel, taps=taps_n, silu=silu,
+                          bias=bias),
         grid=grid, in_specs=[rows, rows, before, weights],
         out_specs=[
             rows,
@@ -627,6 +645,36 @@ def _causal_conv_bwd(activation, impl, res, dy):
 _causal_conv_diff.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal_conv_bias_diff(x, taps, bias, activation, impl):
+    """``_causal_conv_diff`` with a bias: the kernels take it as one row
+    more of the taps' array and hand its gradient back the same way."""
+    if impl == "jnp":
+        return _causal_conv(x, taps, activation, bias)
+    return _causal_pallas_fwd(
+        x, jnp.concatenate([taps, bias[None]]), activation is not None,
+        impl == "pallas_interpret", bias=True)
+
+
+def _causal_conv_bias_fwd(x, taps, bias, activation, impl):
+    return (_causal_conv_bias_diff(x, taps, bias, activation, impl),
+            (x, taps, bias))
+
+
+def _causal_conv_bias_bwd(activation, impl, res, dy):
+    if impl == "jnp":
+        return jax.vjp(lambda x, taps, bias: _causal_conv(
+            x, taps, activation, bias), *res)[1](dy)
+    x, taps, bias = res
+    dx, dweights = _causal_pallas_bwd(
+        x, jnp.concatenate([taps, bias[None]]), dy, activation is not None,
+        impl == "pallas_interpret", bias=True)
+    return dx, dweights[:-1], dweights[-1]
+
+
+_causal_conv_bias_diff.defvjp(_causal_conv_bias_fwd, _causal_conv_bias_bwd)
+
+
 def causal_fits(x, taps, activation) -> bool:
     """Whether the causal kernels take the call: 2 to 4 taps, channels a
     multiple of 128, a length ``_ROWS`` divides, no activation or
@@ -645,27 +693,34 @@ def causal_auto_impl(x, taps, activation) -> str:
     return "jnp"
 
 
-def causal_conv(x, taps, activation=None, *, impl: Optional[str] = None):
-    """``y_t = activation(sum_k taps[k] * x_{t - K + 1 + k})`` per channel:
-    the depthwise causal convolution of ``x`` [B, T, channels] with ``taps``
-    [K, channels], zeros before a sequence's first position, no bias, then
-    ``activation`` (None: none; a function of the float32 sum, such as
-    ``jax.nn.silu``). The sum over the taps is float32, ``y`` has ``x``'s
-    dtype. Its residuals are its two arguments (the backward pass makes the
+def causal_conv(x, taps, activation=None, bias=None, *,
+                impl: Optional[str] = None):
+    """``y_t = activation(sum_k taps[k] * x_{t - K + 1 + k} + bias)`` per
+    channel: the depthwise causal convolution of ``x`` [B, T, channels] with
+    ``taps`` [K, channels], zeros before a sequence's first position, plus
+    ``bias`` [channels] (None: none, and the program is the one without the
+    argument), then ``activation`` (None: none; a function of the float32
+    sum, such as ``jax.nn.silu``). The sum over the taps and the bias's
+    addition are float32, ``y`` has ``x``'s dtype. Its residuals are its two arguments (the backward pass makes the
     sum again). ``impl``: "pallas" | "pallas_interpret" (the kernels
     ``causal_conv_fwd`` / ``causal_conv_bwd``: one pass over the operands
     each way, where ``causal_fits``) | "jnp" (plain XLA on every backend: the
     padded slices, the products, the sum and the activation, and their
     transposes); None: ``causal_auto_impl``."""
     impl = impl or causal_auto_impl(x, taps, activation)
+    if bias is None:
+        diff, weights = _causal_conv_diff, (taps,)
+    else:
+        diff, weights = _causal_conv_bias_diff, (taps, bias)
     if impl == "jnp":
-        return _causal_conv_diff(x, taps, activation, impl)
+        return diff(x, *weights, activation, impl)
     assert causal_fits(x, taps, activation), (x.shape, taps.shape, activation)
-    conv = lambda x, taps: _causal_conv_diff(x, taps, activation, impl)
+    conv = lambda x, *weights: diff(x, *weights, activation, impl)
     mesh, axes = _batch_axes(x)
     if axes:
         rows, whole = PartitionSpec(axes), PartitionSpec()
-        conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, whole),
+        conv = jax.shard_map(conv, mesh=mesh,
+                             in_specs=(rows,) + (whole,) * len(weights),
                              out_specs=rows, axis_names=set(axes),
                              check_vma=False)
-    return conv(x, taps.astype(_F32))
+    return conv(x, *(w.astype(_F32) for w in weights))

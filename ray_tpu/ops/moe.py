@@ -578,23 +578,36 @@ def _swiglu(hidden):
     return jax.nn.silu(gate) * up
 
 
-@jax.jit
-def _rows_forward(x, weights, wi, wo, plan):
+def _relu2(hidden):
+    """Un-gated: ``relu(h)^2``, whose derivative is ``2 relu(h)``."""
+    return jnp.square(jax.nn.relu(hidden))
+
+
+# what an expert does between its two matrices, by ``activation``: "swiglu"
+# (``wi`` [held, d, 2 x width], gate and up side by side) or "relu2" (``wi``
+# [held, d, width])
+_ACTIVATIONS = {"swiglu": _swiglu, "relu2": _relu2}
+
+
+@functools.partial(jax.jit, static_argnames="activation")
+def _rows_forward(x, weights, wi, wo, plan, activation="swiglu"):
+    act_fn = _ACTIVATIONS[activation]
     k, sizes = plan["mine"].shape[1], plan["tokens"]
     fresh = _fresh_buffer(x)
     rows, = _walk(plan, fresh, lambda start, pair: (
         _take_rows(x, pair // k),))
     hidden = jax.lax.ragged_dot(rows, wi.astype(x.dtype), sizes)
     act, = _walk(plan, fresh, lambda start, pair: (
-        _swiglu(_chunk(hidden, start, pair)),))
+        act_fn(_chunk(hidden, start, pair)),))
     out = jax.lax.ragged_dot(act, wo.astype(x.dtype), sizes)
     _count_row_buffers(fresh, (rows, act), backward=False)
     return _to_tokens(out, plan, weights, fresh,
                       backward=False).astype(x.dtype)
 
 
-@jax.jit
-def _rows_backward(x, weights, wi, wo, plan, g):
+@functools.partial(jax.jit, static_argnames="activation")
+def _rows_backward(x, weights, wi, wo, plan, g, activation="swiglu"):
+    act_fn = _ACTIVATIONS[activation]
     f32, k, sizes = jnp.float32, plan["mine"].shape[1], plan["tokens"]
     wi_x, wo_x = wi.astype(x.dtype), wo.astype(x.dtype)
     fresh = _fresh_buffer(x)
@@ -607,7 +620,7 @@ def _rows_backward(x, weights, wi, wo, plan, g):
 
     def of_chunk(start, pair):
         scale = _take_rows(weights.reshape(-1), pair)[:, None]
-        act, pull = jax.vjp(_swiglu, _chunk(hidden, start, pair))
+        act, pull = jax.vjp(act_fn, _chunk(hidden, start, pair))
         g_chunk = _chunk(g_act, start, pair).astype(f32)
         d_hidden, = pull((scale * g_chunk).astype(act.dtype))
         return (d_hidden, (scale * act.astype(f32)).astype(act.dtype),
@@ -631,10 +644,10 @@ def _rows_backward(x, weights, wi, wo, plan, g):
             for_the_matrices(act_scaled, wo_x, g_rows).astype(wo.dtype))
 
 
-@jax.custom_vjp
-def _held_rows(x, weights, wi, wo, plan):
-    """``held_expert_ffn``'s row work: gather, grouped SwiGLU, back to the
-    tokens. The grouped matmuls run over whole buffers and follow the rows
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_rows(x, weights, wi, wo, plan, activation="swiglu"):
+    """``held_expert_ffn``'s row work: gather, the grouped experts (a SwiGLU
+    or an un-gated ``relu^2`` by ``activation``), back to the tokens. The grouped matmuls run over whole buffers and follow the rows
     present by themselves; what is not a matmul walks the buffer only as far
     as the pairs present (``_walk``), or reads the rows that hold a pair
     (``_to_tokens``).
@@ -649,15 +662,20 @@ def _held_rows(x, weights, wi, wo, plan):
     is a gather. Both passes are jitted so that a model's layers of one
     shape trace their loops and branches once: traced a layer at a time
     they cost the cell 4 to 8 s of every start."""
-    return _rows_forward(x, weights, wi, wo, plan)
+    return _rows_forward(x, weights, wi, wo, plan, activation)
 
 
-_held_rows.defvjp(lambda *a: (_rows_forward(*a), a),
-                  lambda res, g: _rows_backward(*res, g) + (None,))
+_held_rows.defvjp(
+    lambda *a: (_rows_forward(*a[:5], activation=a[5]), a[:5]),
+    lambda activation, res, g: _rows_backward(
+        *res, g, activation=activation) + (None,))
 
 
-def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
-    """The held experts' part of a routed SwiGLU layer.
+def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int,
+                    activation: str = "swiglu"):
+    """The held experts' part of a routed layer of SwiGLU experts, or with
+    ``activation="relu2"`` of un-gated ``relu(x W_i)^2 W_o`` experts (``wi``
+    then [held, d, width]); everything below holds for both.
 
     ``x`` (T, d); ``experts`` / ``weights`` (T, k) from ``topk_routing``
     over all E experts (a token's k experts differ: the kernel back to the
@@ -710,9 +728,10 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int):
     128 each, whatever the load) and 2.5-2.7 at all 131,072, where the
     gathers took 2.6 up to 49,152 pairs, 4.1 up to 98,304 and 5.5 beyond
     (``_gather_sources``); the rest is the rows'."""
-    assert 0 <= index < of, (index, of)
+    assert 0 <= index < of and activation in _ACTIVATIONS, (
+        index, of, activation)
     plan = _plan(experts, wi.shape[0], index)
-    return _held_rows(x, weights, wi, wo, plan), plan["tokens"]
+    return _held_rows(x, weights, wi, wo, plan, activation), plan["tokens"]
 
 
 def _plan(experts, held: int, index: int):

@@ -465,3 +465,564 @@ def selective_scan(x, delta, A, B, C, D, *, chunk: Optional[int] = None,
             out_specs=rows, axis_names=set(axes), check_vma=False)
     y = scan(x, delta, A.astype(_F32), B, C, D.astype(_F32))
     return y[:, :length] if pad else y
+
+
+# ===========================================================================
+# Mamba-2's recurrence (SSD: Dao and Gu, arXiv:2405.21060): a scalar decay a
+# head and position. Appended below the selective scan, whose kernels' Mosaic
+# payloads carry the lines above.
+#
+#     S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) B_t^T        [head_dim, states]
+#     y_t = S_t C_t + D x_t
+#
+# a head (``A``, ``D`` one scalar each; ``B_t``, ``C_t`` shared by the heads
+# of a group), zero state at a sequence's start. With one scalar decay a
+# chunk of C positions IS matmuls: ``a = cumsum(dt A)`` inside the chunk,
+#
+#     Y  = (L o (C B^T)) (dt x) + exp(a) C S^T + D x,   L_ij = exp(a_i - a_j)
+#                                                       for i >= j, else 0
+#     S <- exp(a_C) S + (exp(a_C - a) dt x)^T B
+#
+# ``ops/delta.py``'s scheme without its triangular solve. A decay only ever
+# appears as ``exp`` of a difference that is <= 0. ``a``, ``L``, the state,
+# ``dt x`` and every accumulation are float32; the matmuls take their
+# operands in the inputs' dtype. ``C B^T`` is made once a group and chunk
+# and used by the group's heads.
+#
+# Both paths keep one boundary state every ``ssd_stride_of`` positions (so
+# that the boundaries weigh no more than the output: 256 positions for
+# bfloat16 at 128 states); the backward pass walks the strides from the
+# last, makes a stride's inner states again from its boundary and walks its
+# chunks in reverse with the state's gradient as the carry. One
+# ``custom_vjp`` holds both; the forward rule's outputs are named
+# ``ssd_out`` / ``ssd_bounds`` for ``ops.attention.remat_policy``.
+#
+# The kernels (``ssd_fwd`` / ``ssd_bwd``: the benchmark's readers find them
+# by these names) address the model's own arrays, x and y as [B, T, heads x
+# 64] and B, C as [B, T, groups x states]: two 64-wide heads are one lane
+# tile, worked on together (their two ``L`` differ, so the products that
+# take ``L`` are made a head each over the pair's 128 lanes and chosen by
+# lane; the products with the state contract or produce both heads' rows at
+# once). The grid is (batch, groups, blocks of up to 1,024 positions), the
+# blocks innermost and in order; a group's states, [heads a group x 64,
+# states] float32, are carried from block to block in VMEM scratch.
+# ===========================================================================
+
+from ray_tpu.ops.attention import SSD_REMAT_NAMES  # noqa: E402
+# the chunk scheme's helpers are ``ops/delta.py``'s: the matmul forms, the
+# turns between a chunk's numbers along the lanes and down the sublanes, the
+# [T / stride, stride / chunk, B, chunk, ...] and [B, H, T / block, block /
+# chunk, chunk] views
+from ray_tpu.ops.delta import (  # noqa: E402
+    _NN, _NT, _TN, _as_col as _ssd_col, _as_row as _ssd_row,
+    _dot as _ssd_dot, _folded as _ssd_folded, _gates as _ssd_gates,
+    _grouped as _ssd_grouped, _iota as _ssd_iota, _ungated as _ssd_ungated,
+    _ungrouped as _ssd_ungrouped)
+
+SSD_CHUNK = 128       # positions a chunk, as published
+_SSD_BLOCK = 1024     # positions a grid step at most
+_SSD_HEAD = 64        # the head width the kernels take: two a lane tile
+
+
+def ssd_stride_of(chunk: int, states: int, itemsize: int) -> int:
+    """Positions between two kept boundary states: whole chunks, and enough
+    of them that a head's boundary ([head_dim, states] float32) weighs no
+    more than its output over them ([stride, head_dim] of ``itemsize``)."""
+    return chunk * -(-(states * 4) // (itemsize * chunk))
+
+
+def ssd_bytes_needed(x, b, backward: bool) -> int:
+    """What a call has to move whatever its form: forward x, B, C, dt in and
+    y out; backward those and y's cotangent in, the four gradients out."""
+    batch, length, heads, head_dim = x.shape
+    groups, states = b.shape[2:]
+    tokens, size = batch * length, x.dtype.itemsize
+    xs, bc, dts = (tokens * heads * head_dim * size,
+                   2 * tokens * groups * states * size, tokens * heads * 4)
+    return (3 * xs + 2 * bc + 2 * dts) if backward else 2 * xs + bc + dts
+
+
+def _ssd_record(x, b, chunk, stride, backward: bool):
+    """One ``counters`` record a traced pass (none a step)."""
+    batch, length, heads, head_dim = x.shape
+    groups, states = b.shape[2:]
+    steptrace.record_counters("ssd/scan", {
+        "heads": heads, "groups": groups, "head_dim": head_dim,
+        "states": states, "tokens": batch * length, "sequences": batch,
+        "chunk": chunk, "stride": stride,
+        "boundary_bytes": (batch * (length // stride) * heads * head_dim
+                           * states * 4),
+        "bytes_needed": ssd_bytes_needed(x, b, backward),
+        "backward": int(backward)})
+
+
+# --- the chunked twin: any backend -----------------------------------------
+
+def _ssd_chunk(state, inputs):
+    """One chunk from the state [B, H, P, N] it starts in: x [B, C, H, P],
+    dt, la [B, C, H] (``la`` = dt A), b, c [B, C, H, N] (one a head
+    already), all float32 -> (end state, y [B, C, H, P] without D x)."""
+    x, dt, la, b, c = inputs
+    chunk = x.shape[1]
+    a = jnp.cumsum(la, axis=1)                                  # [B, C, H]
+    by_head = jnp.moveaxis(a, 1, 2)                             # [B, H, C]
+    row, col = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    diff = by_head[..., :, None] - by_head[..., None, :]
+    decay = jnp.where(row >= col, jnp.exp(jnp.where(row >= col, diff, 0.0)),
+                      0.0)                                      # [B, H, C, C]
+    scores = jnp.einsum("bihn,bjhn->bhij", c, b)
+    v = dt[..., None] * x
+    y = (jnp.einsum("bhij,bjhp->bihp", scores * decay, v)
+         + jnp.einsum("bihn,bhpn->bihp", c * jnp.exp(a)[..., None], state))
+    last = a[:, -1:, :]                                         # [B, 1, H]
+    state = (jnp.exp(last)[:, 0, :, None, None] * state
+             + jnp.einsum("bjhp,bjhn->bhpn",
+                          v * jnp.exp(last - a)[..., None], b))
+    return state, y
+
+
+def _ssd_stride(state, inputs, skip, rep):
+    """The chunks of one stride: (state, (x, dt, la, b, c) [chunks, B, C,
+    ...] with b, c a group each) -> (end state, y with the skip term)."""
+    x, dt, la, b, c = inputs
+    wide = lambda t: jnp.repeat(t, rep, axis=3) if rep > 1 else t
+    state, y = lax.scan(_ssd_chunk, state, (x, dt, la, wide(b), wide(c)))
+    return state, y + skip[:, None] * x
+
+
+def _ssd_twin_operands(x, dt, la, b, c, chunk, stride):
+    return tuple(_ssd_grouped(t.astype(_F32), stride, chunk)
+                 for t in (x, dt, la, b, c))
+
+
+def _ssd_twin_fwd(x, dt, la, b, c, skip, chunk, stride):
+    """-> (y [B, T, H, P] float32, bounds [B, T / stride, H, P, N] float32:
+    the state each stride starts from)."""
+    batch, _, heads, head_dim = x.shape
+    groups, states = b.shape[2:]
+    one_stride = functools.partial(_ssd_stride, skip=skip,
+                                   rep=heads // groups)
+
+    def one(state, inputs):
+        end, y = one_stride(state, inputs)
+        return end, (y, state)
+
+    zero = jnp.zeros((batch, heads, head_dim, states), _F32)
+    _, (y, bounds) = lax.scan(
+        one, zero, _ssd_twin_operands(x, dt, la, b, c, chunk, stride))
+    return _ssd_ungrouped(y), jnp.moveaxis(bounds, 0, 1)
+
+
+def _ssd_twin_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride):
+    """The gradients of ``_ssd_twin_fwd``'s y, a stride at a time from the
+    last: each stride's states are made again from its boundary (``jax.vjp``
+    of the stride), the gradient of the state handed to the stride before."""
+    rep = x.shape[2] // b.shape[2]
+
+    def one(carry, inputs):
+        dstate, dskip = carry
+        *operands, start, dy_s = inputs
+        _, pull = jax.vjp(
+            lambda state, ops, skip: _ssd_stride(state, ops, skip, rep),
+            start, tuple(operands), skip)
+        dstate, grads, dskip_s = pull((dstate, dy_s))
+        return (dstate, dskip + dskip_s), grads
+
+    (_, dskip), grads = lax.scan(
+        one, (jnp.zeros(bounds.shape[:1] + bounds.shape[2:], _F32),
+              jnp.zeros_like(skip)),
+        (*_ssd_twin_operands(x, dt, la, b, c, chunk, stride),
+         jnp.moveaxis(bounds, 1, 0),
+         _ssd_grouped(dy.astype(_F32), stride, chunk)),
+        reverse=True)
+    return (*map(_ssd_ungrouped, grads), dskip)
+
+
+# --- the kernels -------------------------------------------------------------
+
+def _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, scores, t, ci, rows, chunk,
+              states):
+    """What both kernels make of head pair ``t`` of the group over chunk
+    ``ci`` of the block (positions ``rows``) before a state enters: a
+    head's numbers are ``[u]`` of a list of two, what both heads share lies
+    over the pair's 128 lanes, head 0 in the first 64. ``scores`` is the
+    group's ``C B^T`` [C, C], or None where only the state's walk is
+    wanted."""
+    width = 2 * _SSD_HEAD
+    lanes = slice(t * width, (t + 1) * width)
+    row, col = _ssd_iota(chunk)
+    seen = row >= col
+    first = lax.broadcasted_iota(jnp.int32, (chunk, width), 1) < _SSD_HEAD
+    a_row = [a_ref[2 * t + u, pl.ds(ci, 1), :] for u in (0, 1)]    # [1, C]
+    a_col = [_ssd_col(r, chunk) for r in a_row]                    # [C, 1]
+    dt_col = [_ssd_col(dt_ref[2 * t + u, pl.ds(ci, 1), :], chunk)
+              for u in (0, 1)]
+    last = [jnp.sum(jnp.where(col[:1] == chunk - 1, r, 0.0), axis=1,
+                    keepdims=True) for r in a_row]                 # [1, 1]
+    decay = [jnp.where(seen, jnp.exp(jnp.where(seen, c - r, 0.0)), 0.0)
+             for c, r in zip(a_col, a_row)]                        # [C, C]
+    by_lane = lambda pair: jnp.where(first, pair[0], pair[1])      # [C, 128]
+    x = x_ref[rows, lanes]
+    x32 = x.astype(_F32)
+    v32 = by_lane(dt_col) * x32
+    to_end = by_lane([jnp.exp(l - c) for l, c in zip(last, a_col)])
+    # a head's 64 rows of the pair's state [128, N]
+    upper = lax.broadcasted_iota(jnp.int32, (width, states), 0) < _SSD_HEAD
+    grown = [jnp.broadcast_to(jnp.exp(l), (1, states)) for l in last]
+    return dict(
+        lanes=lanes, first=first, seen=seen, by_lane=by_lane, dt=x.dtype,
+        x32=x32, v32=v32, v=v32.astype(x.dtype), decay=decay,
+        weights=scores if scores is None else [scores * d for d in decay],
+        dt_lane=by_lane(dt_col),
+        grow=by_lane([jnp.exp(c) for c in a_col]), to_end=to_end,
+        ve=(v32 * to_end).astype(x.dtype), last=last,
+        last_rows=jnp.where(upper, grown[0], grown[1]),
+        skip=skip_ref[:, lanes])
+
+
+def _ssd_block_loops(steps: int, per_stride: int, body, reverse: bool):
+    """``body(s, r)`` over the block's strides ``s`` (a loop) and a stride's
+    chunks ``r`` (written out), from the last where ``reverse``."""
+
+    def one(n, carry):
+        s = steps - 1 - n if reverse else n
+        body(s, None)
+        for r in (reversed(range(per_stride)) if reverse
+                  else range(per_stride)):
+            body(s, r)
+        return carry
+
+    lax.fori_loop(0, steps, one, 0)
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, y_ref,
+                    bound_ref, s_scr, *, chunk: int, per_stride: int,
+                    steps: int, pairs: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_scr[...] = jnp.zeros(s_scr.shape, _F32)
+
+    def body(s, r):
+        if r is None:           # a stride starts: keep the state it starts in
+            bound_ref[s] = s_scr[...]
+            return
+        ci = s * per_stride + r
+        rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        b, c = b_ref[rows, :], c_ref[rows, :]
+        scores = _ssd_dot(c, b, _NT)                               # [C, C]
+        for t in range(pairs):
+            p = _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, scores, t, ci,
+                          rows, chunk, b.shape[1])
+            dt = p["dt"]
+            state = s_scr[t]
+            within = p["by_lane"]([_ssd_dot(w.astype(dt), p["v"])
+                                   for w in p["weights"]])
+            y = (within + p["grow"] * _ssd_dot(c, state.astype(dt), _NT)
+                 + p["skip"] * p["x32"])
+            y_ref[rows, p["lanes"]] = y.astype(y_ref.dtype)
+            s_scr[t] = p["last_rows"] * state + _ssd_dot(p["ve"], b, _TN)
+
+    _ssd_block_loops(steps, per_stride, body, reverse=False)
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, dy_ref,
+                    bound_ref, dx_ref, db_ref, dc_ref, da_ref, ddt_ref,
+                    dskip_ref, ds_scr, starts_scr, *, chunk: int,
+                    per_stride: int, steps: int, pairs: int):
+    @pl.when(pl.program_id(2) == 0)     # the sequence's last block
+    def _():
+        ds_scr[...] = jnp.zeros(ds_scr.shape, _F32)
+
+    rowsum = lambda t: jnp.sum(t, axis=1, keepdims=True)
+
+    def halves_rows(m):
+        upper = lax.broadcasted_iota(jnp.int32, m.shape, 0) < _SSD_HEAD
+        return jnp.where(upper, m, 0.0), jnp.where(upper, 0.0, m)
+
+    def body(s, r):
+        if r is None:
+            # the stride's states again, from its boundary: starts[r] is the
+            # state chunk r starts from
+            starts_scr[0] = bound_ref[s]
+            for q in range(per_stride - 1):
+                ci = s * per_stride + q
+                rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+                b = b_ref[rows, :]
+                for t in range(pairs):
+                    p = _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, None, t,
+                                  ci, rows, chunk, b.shape[1])
+                    starts_scr[q + 1, t] = (
+                        p["last_rows"] * starts_scr[q, t]
+                        + _ssd_dot(p["ve"], b, _TN))
+            return
+        ci = s * per_stride + r
+        rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+        b, c = b_ref[rows, :], c_ref[rows, :]
+        scores = _ssd_dot(c, b, _NT)
+        dscores = jnp.zeros((chunk, chunk), _F32)
+        db = jnp.zeros(b.shape, _F32)
+        dc = jnp.zeros(c.shape, _F32)
+        lane = lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        for t in range(pairs):
+            p = _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, scores, t, ci,
+                          rows, chunk, b.shape[1])
+            dt, first, by_lane = p["dt"], p["first"], p["by_lane"]
+            cast = lambda m: m.astype(dt)
+            halves = lambda m: (jnp.where(first, m, 0.0),
+                                jnp.where(first, 0.0, m))
+            start, dstate = starts_scr[r, t], ds_scr[t]
+            start_dt, dstate_dt = cast(start), cast(dstate)
+            dy = dy_ref[rows, p["lanes"]]
+            dy32 = dy.astype(_F32)
+            # dv = M^T dy + e o (B dS'^T)
+            b_ds = _ssd_dot(b, dstate_dt, _NT)                   # [C, 128]
+            dv = (by_lane([_ssd_dot(cast(w), dy, _TN)
+                           for w in p["weights"]]) + p["to_end"] * b_ds)
+            # dM = mask(dy v^T) a head: the other head's lanes set to zero
+            dweights = [jnp.where(p["seen"], _ssd_dot(cast(h), p["v"], _NT),
+                                  0.0) for h in halves(dy32)]
+            for dw, d in zip(dweights, p["decay"]):
+                dscores = dscores + dw * d
+            through = [dw * w for dw, w in zip(dweights, p["weights"])]
+            # the state's part of y, and what the decays to the end carry
+            inter = p["grow"] * _ssd_dot(c, start_dt, _NT)
+            dy_grown = cast(dy32 * p["grow"])
+            dc = dc + _ssd_dot(dy_grown, start_dt)
+            db = db + _ssd_dot(p["ve"], dstate_dt)
+            carried = halves(p["v32"] * p["to_end"] * b_ds)
+            grown = halves(dy32 * inter)
+            direct = halves(dv * p["x32"])
+            skipped = halves(dy32 * p["x32"])
+            kept = halves_rows(start * dstate)
+            for u in (0, 1):
+                head = 2 * t + u
+                carried_u = rowsum(carried[u])                   # [C, 1]
+                dlast = (jnp.sum(carried_u, axis=0, keepdims=True)
+                         + jnp.exp(p["last"][u]) * jnp.sum(
+                             rowsum(kept[u]), axis=0, keepdims=True))
+                da_col = rowsum(through[u]) + rowsum(grown[u]) - carried_u
+                da_ref[head, pl.ds(ci, 1), :] = (
+                    _ssd_row(da_col, chunk)
+                    - jnp.sum(through[u], axis=0, keepdims=True)
+                    + jnp.where(lane == chunk - 1, dlast, 0.0))
+                ddt_ref[head, pl.ds(ci, 1), :] = _ssd_row(
+                    rowsum(direct[u]), chunk)
+                dskip_ref[head, pl.ds(ci, 1), :] = _ssd_row(
+                    rowsum(skipped[u]), chunk)
+            dx_ref[rows, p["lanes"]] = (
+                p["dt_lane"] * dv + p["skip"] * dy32).astype(dx_ref.dtype)
+            ds_scr[t] = p["last_rows"] * dstate + _ssd_dot(dy_grown, c, _TN)
+        dscores_dt = dscores.astype(b.dtype)
+        dc_ref[rows, :] = (dc + _ssd_dot(dscores_dt, b)).astype(dc_ref.dtype)
+        db_ref[rows, :] = (db + _ssd_dot(dscores_dt, c, _TN)).astype(
+            db_ref.dtype)
+
+    _ssd_block_loops(steps, per_stride, body, reverse=True)
+
+
+def _ssd_geometry(x, b, chunk, stride):
+    """-> (batch, groups, blocks, strides a block, chunks a stride, head
+    pairs a group, states)."""
+    batch, length, heads, head_dim = x.shape
+    groups, states = b.shape[2:]
+    assert (length % stride == 0 and stride % chunk == 0
+            and head_dim == _SSD_HEAD and heads % (2 * groups) == 0
+            and states % _LANES == 0 and chunk % _LANES == 0), (
+                x.shape, b.shape, chunk, stride)
+    strides = length // stride
+    steps = next(n for n in range(max(1, _SSD_BLOCK // stride), 0, -1)
+                 if strides % n == 0)
+    return (batch, groups, strides // steps, steps, stride // chunk,
+            heads // groups // 2, states)
+
+
+def _ssd_params(interpret: bool):
+    if interpret:
+        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 * 2**20)
+
+
+def _ssd_specs(x, b, chunk, stride, block_of):
+    """The block specs both kernels share: (x or y, B or C, a gate, the
+    skip's lanes, the boundary states), the block a grid step works on by
+    ``block_of``."""
+    _, _, _, steps, per_stride, pairs, states = _ssd_geometry(
+        x, b, chunk, stride)
+    block, width = steps * stride, 2 * pairs * _SSD_HEAD
+    return (
+        pl.BlockSpec((None, block, width),
+                     lambda n, g, i: (n, block_of(i), g)),
+        pl.BlockSpec((None, block, states),
+                     lambda n, g, i: (n, block_of(i), g)),
+        pl.BlockSpec((None, 2 * pairs, None, block // chunk, chunk),
+                     lambda n, g, i: (n, g, block_of(i), 0, 0)),
+        pl.BlockSpec((1, width), lambda n, g, i: (0, g)),
+        pl.BlockSpec((None, steps, pairs, 2 * _SSD_HEAD, states),
+                     lambda n, g, i: (n, block_of(i), g, 0, 0)))
+
+
+def _ssd_kernel_operands(x, dt, la, b, c, skip, chunk, block):
+    """The arrays as the kernels take them: the model's own x, B, C; the
+    log-decay summed from each chunk's start and dt, a chunk's numbers along
+    the lanes; D spread over its head's lanes."""
+    gates = lambda t: _ssd_gates(t.astype(_F32), chunk, block)
+    return (_ssd_folded(x), _ssd_folded(b), _ssd_folded(c),
+            jnp.cumsum(gates(la), axis=-1), gates(dt),
+            jnp.repeat(skip.astype(_F32), x.shape[3])[None, :])
+
+
+def _ssd_pallas_fwd(x, dt, la, b, c, skip, chunk, stride, interpret):
+    batch, groups, blocks, steps, per_stride, pairs, states = _ssd_geometry(
+        x, b, chunk, stride)
+    heads, head_dim = x.shape[2:]
+    xs, bc, gate, lanes, bound = _ssd_specs(x, b, chunk, stride, lambda i: i)
+    y, bounds = pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, chunk=chunk,
+                          per_stride=per_stride, steps=steps, pairs=pairs),
+        grid=(batch, groups, blocks),
+        in_specs=[xs, bc, bc, gate, gate, lanes],
+        out_specs=[xs, bound],
+        out_shape=[
+            jax.ShapeDtypeStruct(_ssd_folded(x).shape, x.dtype),
+            jax.ShapeDtypeStruct((batch, blocks * steps, heads // 2,
+                                  2 * head_dim, states), _F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((pairs, 2 * head_dim, states), _F32)],
+        compiler_params=_ssd_params(interpret),
+        interpret=interpret,
+        name="ssd_fwd",
+    )(*_ssd_kernel_operands(x, dt, la, b, c, skip, chunk, steps * stride))
+    return y.reshape(x.shape), bounds.reshape(
+        batch, blocks * steps, heads, head_dim, states)
+
+
+def _ssd_pallas_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride,
+                    interpret):
+    batch, groups, blocks, steps, per_stride, pairs, states = _ssd_geometry(
+        x, b, chunk, stride)
+    heads, head_dim = x.shape[2:]
+    block = steps * stride
+    xs, bc, gate, lanes, bound = _ssd_specs(x, b, chunk, stride,
+                                            lambda i: blocks - 1 - i)
+    gates = jax.ShapeDtypeStruct(
+        (batch, heads, blocks, block // chunk, chunk), _F32)
+    operands = _ssd_kernel_operands(x, dt, la, b, c, skip, chunk, block)
+    dx, db, dc, da, ddt, dskip = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, chunk=chunk,
+                          per_stride=per_stride, steps=steps, pairs=pairs),
+        grid=(batch, groups, blocks),
+        in_specs=[xs, bc, bc, gate, gate, lanes, xs, bound],
+        out_specs=[xs, bc, bc, gate, gate, gate],
+        out_shape=[
+            jax.ShapeDtypeStruct(operands[0].shape, x.dtype),
+            jax.ShapeDtypeStruct(operands[1].shape, b.dtype),
+            jax.ShapeDtypeStruct(operands[2].shape, c.dtype),
+            gates, gates, gates,
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((pairs, 2 * head_dim, states), _F32),
+            pltpu.VMEM((per_stride, pairs, 2 * head_dim, states), _F32),
+        ],
+        compiler_params=_ssd_params(interpret),
+        interpret=interpret,
+        name="ssd_bwd",
+    )(*operands, _ssd_folded(dy), bounds.reshape(
+        batch, blocks * steps, heads // 2, 2 * head_dim, states))
+    # la_t enters every a from t to its chunk's end
+    dla = jnp.flip(jnp.cumsum(jnp.flip(da, -1), axis=-1), -1)
+    return (dx.reshape(x.shape), _ssd_ungated(ddt), _ssd_ungated(dla),
+            db.reshape(b.shape), dc.reshape(c.shape),
+            dskip.sum((0, 2, 3, 4)))
+
+
+# --- one differentiable function over both --------------------------------------
+
+def _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl):
+    _ssd_record(x, b, chunk, stride, False)
+    if impl == "scan":
+        y, bounds = _ssd_twin_fwd(x, dt, la, b, c, skip, chunk, stride)
+        return y.astype(x.dtype), bounds
+    return _ssd_pallas_fwd(x, dt, la, b, c, skip, chunk, stride,
+                           impl == "pallas_interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _ssd_diff(x, dt, la, b, c, skip, chunk, stride, impl):
+    return _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl)[0]
+
+
+def _ssd_diff_fwd(x, dt, la, b, c, skip, chunk, stride, impl):
+    y, bounds = map(ad_checkpoint.checkpoint_name,
+                    _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl),
+                    SSD_REMAT_NAMES)
+    return y, (x, dt, la, b, c, skip, bounds)
+
+
+def _ssd_diff_bwd(chunk, stride, impl, res, dy):
+    x, dt, la, b, c, skip, bounds = res
+    _ssd_record(x, b, chunk, stride, True)
+    if impl == "scan":
+        grads = _ssd_twin_bwd(x, dt, la, b, c, skip, bounds, dy, chunk,
+                              stride)
+    else:
+        grads = _ssd_pallas_bwd(x, dt, la, b, c, skip, bounds, dy, chunk,
+                                stride, impl == "pallas_interpret")
+    return tuple(g.astype(r.dtype) for g, r in zip(grads, res))
+
+
+_ssd_diff.defvjp(_ssd_diff_fwd, _ssd_diff_bwd)
+
+
+def ssd_auto_impl(x, b) -> str:
+    """What ``ssd_scan(impl=None)`` runs: the kernels on a TPU where the
+    layout fits them (heads 64 wide, an even number of them a group, the
+    states whole lane tiles) and the mesh ``x`` is traced under has no axis
+    of more than one device but the batch's (the kernel then runs per batch
+    shard, as the flash kernel does); the chunked twin elsewhere."""
+    heads, head_dim = x.shape[2:]
+    groups, states = b.shape[2:]
+    fits = (head_dim == _SSD_HEAD and heads % (2 * groups) == 0
+            and states % _LANES == 0)
+    if jax.default_backend() == "tpu" and fits and not unmapped_mesh_axes(x):
+        return "pallas"
+    return "scan"
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "impl"))
+def ssd_scan(x, dt, A, B, C, D, *, chunk: Optional[int] = None,
+             impl: Optional[str] = None) -> jax.Array:
+    """``y`` [B, T, heads, head_dim] of the scalar-decay recurrence above,
+    each of the B sequences from a zero state: ``x`` [B, T, heads,
+    head_dim], ``dt`` [B, T, heads] (positive, after its softplus), ``A``
+    [heads] (negative), ``B``, ``C`` [B, T, groups, states] (group ``h //
+    (heads / groups)`` serves head ``h``), ``D`` [heads]. ``y`` has ``x``'s
+    dtype; the state, the decays and every accumulation are float32.
+    ``chunk`` positions are one set of matmuls (``SSD_CHUNK`` if left out;
+    the kernels want a multiple of 128); a boundary state is kept every
+    ``ssd_stride_of(chunk, states, itemsize)`` positions, and a length that
+    is no multiple of that is padded with positions that leave the state as
+    it is (``dt`` 0) and whose output is dropped. ``impl``: "pallas" |
+    "pallas_interpret" | "scan"; None: ``ssd_auto_impl``."""
+    length = x.shape[1]
+    impl = impl or ssd_auto_impl(x, B)
+    chunk = chunk or SSD_CHUNK
+    stride = ssd_stride_of(chunk, B.shape[3], x.dtype.itemsize)
+    pad = -length % stride
+    if pad:
+        widths = lambda t: ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)
+        x, dt, B, C = (jnp.pad(t, widths(t)) for t in (x, dt, B, C))
+    dt = dt.astype(_F32)
+
+    def scan(x, dt, la, b, c, skip):
+        return _ssd_diff(x, dt, la, b, c, skip, chunk, stride, impl)
+
+    mesh, axes = _batch_axes(x) if impl != "scan" else (None, ())
+    if axes:
+        rows, whole = PartitionSpec(axes), PartitionSpec()
+        scan = jax.shard_map(
+            scan, mesh=mesh, in_specs=(rows,) * 5 + (whole,),
+            out_specs=rows, axis_names=set(axes), check_vma=False)
+    y = scan(x, dt, dt * A.astype(_F32), B, C, D.astype(_F32))
+    return y[:, :length] if pad else y

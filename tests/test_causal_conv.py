@@ -27,24 +27,28 @@ def _silu_slope(s):
     return gate * (1.0 + s * (1.0 - gate))
 
 
-def loop_reference(x, taps, dy, silu):
-    """y, dx, dtaps one position at a time, each sequence on its own, in
-    float64."""
+def loop_reference(x, taps, dy, silu, bias=None):
+    """y, dx, dtaps (and, with a ``bias``, dbias) one position at a time,
+    each sequence on its own, in float64."""
     x, taps, dy = (np.asarray(t, np.float64) for t in (x, taps, dy))
     batch, length, _ = x.shape
     k = taps.shape[0]
     y, dx, dtaps = np.zeros_like(x), np.zeros_like(x), np.zeros_like(taps)
+    dbias = np.zeros_like(taps[0])
     for n in range(batch):
         for t in range(length):
             held = [(j, t - (k - 1) + j) for j in range(k)
                     if t - (k - 1) + j >= 0]
             s = sum(taps[j] * x[n, src] for j, src in held)
+            if bias is not None:
+                s = s + np.asarray(bias, np.float64)
             y[n, t] = _silu(s) if silu else s
             ds = dy[n, t] * (_silu_slope(s) if silu else 1.0)
+            dbias += ds
             for j, src in held:
                 dx[n, src] += taps[j] * ds
                 dtaps[j] += ds * x[n, src]
-    return y, dx, dtaps
+    return (y, dx, dtaps) if bias is None else (y, dx, dtaps, dbias)
 
 
 def _operands(batch, length, channels, k, dtype=jnp.float32, seed=0):
@@ -210,7 +214,7 @@ def test_kernels_are_named_and_recorded():
     cells = 2 * 8192 * 8192 * 2
     for r in records:
         assert r == {"channels": 8192, "taps": 4, "tokens": 2 * 8192,
-                     "sequences": 2, "activation": 1,
+                     "sequences": 2, "activation": 1, "bias": 0,
                      "backward": r["backward"],
                      "bytes_needed": (3 * cells + 2 * 4 * 8192 * 4
                                       if r["backward"]
@@ -307,3 +311,114 @@ def test_under_a_batch_axis_the_call_is_a_shard_map_over_rows():
     assert got[0].sharding.spec[0] == got[1].sharding.spec[0] == "data"
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# with a bias (PR 58): a number a channel added to the float32 sum before the
+# activation; the kernels take it as one row more of the taps' array
+# ---------------------------------------------------------------------------
+
+def _with_bias(impl, x, taps, bias, dy, activation):
+    y, pull = jax.vjp(
+        lambda a, w, b: causal_conv(a, w, activation, b, impl=impl),
+        x, taps, bias)
+    return (y, *pull(dy))
+
+
+def _bias(channels, seed):
+    return jax.random.normal(jax.random.PRNGKey(100 + seed), (channels,))
+
+
+@pytest.mark.parametrize("activation", [None, jax.nn.silu],
+                         ids=["none", "silu"])
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("case", _CASES[2:4], ids=_IDS)
+def test_with_a_bias_the_kernels_match_the_loop(case, k, activation, blocks):
+    """``y``, ``dx``, ``dtaps`` and ``dbias`` (the sum of ``ds``) over
+    several blocks a sequence, several steps a block and two slabs."""
+    batch, length, channels, block_bytes = case
+    blocks(block_bytes)
+    x, taps, dy = _operands(batch, length, channels, k, seed=length + k)
+    bias = _bias(channels, k)
+    want = loop_reference(x, taps, dy, activation is not None, bias)
+    for impl in ("pallas_interpret", "jnp"):
+        got = _with_bias(impl, x, taps, bias, dy, activation)
+        for name, a, b in zip(("y", "dx", "dtaps", "dbias"), got, want):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float64), b, rtol=2e-5,
+                atol=2e-5 * np.abs(b).max(), err_msg=f"{impl} {name}")
+
+
+def test_with_a_bias_bfloat16_operands_round_once(blocks):
+    blocks(2**13)
+    x, taps, dy = _operands(2, 64, 256, 4, jnp.bfloat16, seed=5)
+    bias = _bias(256, 1)
+    y, dx, dtaps, dbias = _with_bias("pallas_interpret", x, taps, bias, dy,
+                                     jax.nn.silu)
+    assert y.dtype == dx.dtype == jnp.bfloat16
+    assert dtaps.dtype == dbias.dtype == jnp.float32
+    want = loop_reference(x, taps, dy, True, bias)
+    for name, a, b in zip(("y", "dx"), (y, dx), want):
+        np.testing.assert_allclose(np.asarray(a, np.float64), b,
+                                   atol=2 ** -8 * np.abs(b).max(),
+                                   rtol=2 ** -8, err_msg=name)
+    for a, b in ((dtaps, want[2]), (dbias, want[3])):
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-5 * np.abs(b).max())
+
+
+def test_with_a_bias_the_record_says_so_and_one_kernel_pair_runs():
+    from ray_tpu._private import steptrace
+
+    x = jax.ShapeDtypeStruct((2, 8192, 6144), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((4, 6144), jnp.float32)
+    bias = jax.ShapeDtypeStruct((6144,), jnp.float32)
+    grad = jax.grad(lambda a, w, b: causal_conv(
+        a, w, jax.nn.silu, b, impl="pallas").astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()
+        jaxpr = jax.make_jaxpr(grad)(x, taps, bias)
+        records = [r["values"] for r in steptrace.snapshot()
+                   if r["kind"] == "counters" and r["name"] == "conv/causal"]
+    finally:
+        steptrace.set_enabled(False)
+        jax.clear_caches()
+    assert kernel_calls(jaxpr) == {"causal_conv_fwd": 1, "causal_conv_bwd": 1}
+    cells = 2 * 8192 * 6144 * 2
+    assert {r["backward"] for r in records} == {0, 1}
+    for r in records:
+        assert r["bias"] == 1 and r["taps"] == 4
+        assert r["bytes_needed"] == conv.causal_needed_bytes(
+            2 * 8192, 6144, 4, 2, bool(r["backward"]), bias=True) == (
+            3 * cells + 2 * 5 * 6144 * 4 if r["backward"]
+            else 2 * cells + 5 * 6144 * 4)
+
+
+# sha256 (16 hex digits) of the jaxpr of the call WITHOUT a bias and of its
+# gradient, function addresses struck out, as the parent of PR 58 traces
+# them: the argument left out is the program it was
+_HELD_WITHOUT_A_BIAS = {"pallas": "21dcb4bee2f31676", "jnp": "cdfccc35a0b2f849"}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_without_a_bias_the_call_traces_to_the_held_jaxpr(impl):
+    import hashlib
+    import re
+
+    x = jax.ShapeDtypeStruct((2, 8192, 8192), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((4, 8192), jnp.float32)
+    fn = lambda a, w: causal_conv(a, w, jax.nn.silu, impl=impl)
+    both = lambda a, w, dy: (fn(a, w), *jax.vjp(fn, a, w)[1](dy))
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(both)(x, taps, x)))
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest == _HELD_WITHOUT_A_BIAS[impl], digest
+    # bias=None spelled out is the same call
+    spelled = lambda a, w, dy: (
+        causal_conv(a, w, jax.nn.silu, None, impl=impl),
+        *jax.vjp(lambda a, w: causal_conv(a, w, jax.nn.silu, None,
+                                          impl=impl), a, w)[1](dy))
+    assert re.sub(r" at 0x[0-9a-f]+", "", str(
+        jax.make_jaxpr(spelled)(x, taps, x))) == text

@@ -114,6 +114,7 @@ _HELD_PROGRAMS = {
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
     "lfm2-8b-a1b.step-8k": "26754d67a7295565",
     "qwen3-next-80b-a3b.step-8k": "d03aab20694bde68",   # PR 57
+    "nemotron-3-nano-30b-a3b.step-8k": "04b7b0bf030790b4",   # PR 58
 }
 
 
@@ -539,7 +540,7 @@ def _row_buffer_census(text, drawn, pairs, d, width, unwritten):
         r"custom-call\(\)", entry)) == unwritten
 
 
-def _to_tokens_census(text, drawn, tokens, k, d, held, calls):
+def _to_tokens_census(text, drawn, tokens, k, d, held, calls, block=512):
     """The expert layers' way back to the tokens in a compiled step ``text``
     (PR 53): ``calls`` calls of the kernel ``to_tokens``, one a pass of a
     layer that the executable keeps; no branch over cut gather sources; no
@@ -559,7 +560,7 @@ def _to_tokens_census(text, drawn, tokens, k, d, held, calls):
     records = {tuple(sorted(e["args"].items())) for e in drawn
                if e["name"] == "moe/to_tokens"}
     assert records == {tuple(sorted({
-        "kernel": 1, "slots": tokens * k, "tokens": tokens, "block": 512,
+        "kernel": 1, "slots": tokens * k, "tokens": tokens, "block": block,
         "chunk": 128, "held": held, "backward": backward}.items()))
         for backward in (0, 1)}
 
@@ -1067,7 +1068,7 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     cells = tokens * 8192 * 2
     for e in by_name["conv/causal"]:
         assert e == {"channels": 8192, "taps": 4, "tokens": tokens,
-                     "sequences": batch, "activation": 1,
+                     "sequences": batch, "activation": 1, "bias": 0,
                      "backward": e["backward"],
                      "bytes_needed": (3 * cells + 2 * 4 * 8192 * 4
                                       if e["backward"]
@@ -1106,4 +1107,98 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
         if 18992 in dims:
             assert dims in {(18992, 2048), (18992, 2048, 1),
                             (2, 1024, 18992)}, dims
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
+
+
+def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``nemotron-3-nano-30b-a3b.step-8k``
+    (published blocks 0 to 8, ``MEMEM*EME``, at the published widths: four
+    Mamba-2 mixers, four blocks of 8 of 128 sigmoid-routed un-gated relu^2
+    experts beside a shared expert, one attention block at 32 query heads on
+    2 key-value heads of 128, an eighth of the vocabulary under an untied
+    head), its step at 2 x 8,192 with recomputation, as the benchmark's
+    family builds it: the plan stays under the 14.5 GiB that ISSUE 58 set for
+    choosing the batch, with the state's 8.0 GB as arguments. Every Mamba
+    block's scan is the Pallas pair ``ssd_fwd`` / ``ssd_bwd`` over the
+    model's own [2, 8192, 4096] and [2, 8192, 1024] arrays, one forward and
+    one backward call a block (the recomputed block keeps the forward's
+    output and boundary states by ``ops.attention.remat_policy``); its
+    convolutions (x, B and C each on its own, with the bias) are the pair
+    ``causal_conv_fwd`` / ``causal_conv_bwd``: forward, forward again in the
+    recomputed block, backward; attention is one flash call each way. Each
+    traced call wrote its record into the runtime's ring. No array is shaped
+    like a [T, T] score matrix, none like a state a position ([.., T, heads,
+    64, 128])."""
+    from ray_tpu._private import steptrace
+
+    cell = "nemotron-3-nano-30b-a3b.step-8k"
+    worker, model, traffic = _cut_cell(cell)
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (2, 8192)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = _lower_held(cell, built.step, params, opt_state,
+                              {"input_ids": ids, "labels": ids})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {"attn/grid_blocks", "ssd/scan", "conv/causal",
+                            "model/layer_kinds", "attention/boundary",
+                            "moe/row_buffers", "moe/to_tokens"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "mamba": 4, "attention": 1, "expert": 4, "layers": 9,
+        "published_layers": 52}
+    assert {(e["heads"], e["kv_heads"], e["d_qk"], e["d_v"],
+             e["model_results"]) for e in by_name["attention/boundary"]} == {
+        (32, 2, 128, 128, 1)}
+    tokens = batch * seq
+    assert {e["backward"] for e in by_name["ssd/scan"]} == {0, 1}
+    for e in by_name["ssd/scan"]:
+        assert e == {"heads": 64, "groups": 8, "head_dim": 64, "states": 128,
+                     "tokens": tokens, "sequences": batch, "chunk": 128,
+                     "stride": 256,
+                     "boundary_bytes": tokens * 4096 * 2,   # the output's
+                     "bytes_needed": tokens * (33_280 if e["backward"]
+                                               else 20_736),
+                     "backward": e["backward"]}
+    assert {(e["channels"], e["taps"], e["bias"], e["activation"])
+            for e in by_name["conv/causal"]} == {(4096, 4, 1, 1),
+                                                 (1024, 4, 1, 1)}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 666_963_456          # ISSUE 58's count
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?((?:flash|ssd|causal_conv)_(?:fwd|bwd)(?:_w\d+)?)"
+        r'[\w.\-]* = .*custom_call_target="tpu_custom_call"', text, re.M))
+    # three convolutions a Mamba block: forward, forward again in the
+    # recomputed block, backward; the scan's forward is NOT run again
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "ssd_fwd": 4,
+                     "ssd_bwd": 4, "causal_conv_fwd": 24,
+                     "causal_conv_bwd": 12}
+    assert "f32[2,32,32,128,128]" in text     # a boundary every 256 positions
+    _dq_census(text, 64, 128, seq)
+    _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
+                      model["hidden_size"], model["n_routed_experts"],
+                      calls=2 * 4, block=256)
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+        # no state a position: [.., 64, 128] stands only in the kept
+        # boundaries, 32 a sequence
+        if dims[-2:] == (64, 128) or dims[-2:] == (128, 128):
+            assert seq not in dims and tokens not in dims, dims
     print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
