@@ -4,6 +4,9 @@ at a layer's size; and the plain causal convolution's kernels beside them.
 
     python benches/delta_rule.py --shape 4x8192 --out chiprun_out/pr56
     python benches/delta_rule.py --shape 2x8192 --rule 0 --out chiprun_out/pr57
+    python benches/delta_rule.py --shape 2x8192 --conv 0 --out chiprun_out/pr59
+    python benches/delta_rule.py --shape 2x8192 --conv 0 --heads 16    # rep 1
+    python benches/delta_rule.py --shape 2x8192 --conv 0 --heads 64    # rep 4
 
 At ``batch x length`` of ``--key-heads`` / ``--heads`` heads ``--d-k`` x
 ``--d-v`` wide: in float32 and in bfloat16 the kernels' ``o`` and five
@@ -15,7 +18,12 @@ in bfloat16 the wall time of forward and of forward plus backward by the
 kernels and by the twin, and from a trace of three calls the device time of
 one ``gated_delta_fwd`` and one ``gated_delta_bwd`` alone with what each
 needs (``perfbench/metrics/delta_rule_roofline_pct.needed``) and its share
-of that floor (``--rule 0`` skips all of that). ``--conv 1``:
+of that floor, and from the two kernels' own jaxprs what a grid step is: the
+grid, the ``dot_general``s a step, the positions x value heads a step and so
+the ``dot_general``s a chunk and head (``gated_delta_{fwd,bwd}_text``: the
+program's text, no measurement; ``--heads`` on ``--key-heads`` gives the
+value heads a key head, 1, 2 or 4 in the examples above) (``--rule 0`` skips
+all of that). ``--conv 1``:
 ``ops.conv.causal_conv`` with four taps and a SiLU at ``--conv-channels``
 channels. With ``--check 1`` the kernels (``causal_conv_fwd`` /
 ``causal_conv_bwd``) and XLA's form, in float32 and bfloat16, against a
@@ -30,6 +38,7 @@ time of every operation, each kernel's own against
 
 import argparse
 import json
+import math
 import os
 import re
 import shutil
@@ -52,6 +61,8 @@ def main():
     parser.add_argument("--check", type=int, default=1)
     parser.add_argument("--rule", type=int, default=1,
                         help="0: the convolution alone")
+    parser.add_argument("--twin", type=int, default=1,
+                        help="0: do not time the chunked lax.scan")
     parser.add_argument("--conv", type=int, default=1)
     parser.add_argument("--conv-channels", type=int, default=8192)
     parser.add_argument("--impl", default="pallas",
@@ -123,6 +134,40 @@ def main():
         jax.block_until_ready(out)
         return round((time.perf_counter() - start) / args.reps * 1e3, 3)
 
+    def kernel_text(fn, *xs):
+        """{"fwd" | "bwd": what one grid step of the kernel is, read from its
+        own jaxpr}: the grid, its steps, the ``dot_general``s a step (a
+        ``pl.when`` body counted as written), the positions x value heads a
+        step works on and so the ``dot_general``s a chunk and head."""
+        found = {}
+
+        def dots(jaxpr):
+            return sum((eqn.primitive.name == "dot_general") + sum(
+                map(dots, jax.core.jaxprs_in_params(eqn.params)))
+                for eqn in jaxpr.eqns)
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                name = eqn.params.get("name", "")
+                if eqn.primitive.name == "pallas_call" and \
+                        name.startswith("gated_delta_"):
+                    grid = tuple(eqn.params["grid_mapping"].grid)
+                    steps = math.prod(grid)
+                    cells = batch * length * args.heads // steps
+                    made = dots(eqn.params["jaxpr"])
+                    found[name.rsplit("_", 1)[1]] = {
+                        "grid": grid, "grid_steps": steps,
+                        "dot_generals_a_step": made,
+                        "positions_x_heads_a_step": cells,
+                        "dot_generals_a_chunk_and_head": round(
+                            made * (args.chunk or 64) / cells, 2)}
+                else:
+                    for sub in jax.core.jaxprs_in_params(eqn.params):
+                        walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*xs).jaxpr)
+        return found
+
     def device_ops(fn, *xs):
         """[(HLO text, ns)] of chip 0 over three traced calls."""
         trace_dir = tempfile.mkdtemp()
@@ -166,12 +211,14 @@ def main():
         line = {"batch": batch, "length": length, "heads": args.heads,
                 "key_heads": args.key_heads, "d_k": args.d_k, "d_v": args.d_v,
                 "dtype": "bfloat16", "device": device}
-        for impl in (args.impl, "scan"):
+        for impl in (args.impl, "scan")[:1 + bool(args.twin)]:
             fwd, both = jax.jit(by_impl(impl)), out_and_grads(by_impl(impl))
             line[f"{impl}_fwd_ms"] = timed(fwd, *xs[:5])
             line[f"{impl}_fwd_bwd_ms"] = timed(both, *xs)
             if impl == "scan":
                 continue
+            for kind, text in kernel_text(both, *xs).items():
+                line[f"gated_delta_{kind}_text"] = text
             found = {}
             for name, ns in device_ops(both, *xs):
                 kernel = KERNEL.match(name)

@@ -69,6 +69,19 @@ _CASES = {
     "decays_near_zero": (*_SHAPE, 12.0),
     "two_key_heads_narrow_keys": (1, 64, 2, 2, 32, 128, 16, 1.0),
     "length_off_the_stride_is_padded": (2, 40, 1, 2, 128, 128, 16, 1.0),
+    # a grid step is the whole groups of both key heads: every value head's
+    # state and its gradient carried over three grid steps, one, two and
+    # four value heads a key head (at chunk 16 the four share a lane tile's
+    # pack)
+    "one_value_head_a_key_head_three_groups": (1, 96, 2, 2, 32, 128, 16, 1.0),
+    "two_value_heads_a_key_head_three_groups": (2, 96, 2, 4, 32, 128, 16,
+                                                1.0),
+    "four_value_heads_a_key_head_three_groups": (1, 96, 2, 8, 32, 128, 16,
+                                                 1.0),
+    "four_value_heads_off_the_stride": (1, 72, 2, 8, 32, 128, 16, 0.1),
+    # chunk 64 as the model runs it: two value heads a lane tile, two packs
+    # a step, two groups
+    "four_value_heads_in_two_packs": (1, 256, 2, 8, 128, 128, 64, 1.0),
 }
 _NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
@@ -111,14 +124,27 @@ def test_sequences_are_independent():
     np.testing.assert_allclose(both[1:], alone, atol=1e-6)
 
 
+# (batch, length, key heads, heads, d_k, d_v, chunk) in bfloat16, where a
+# boundary is kept every 256 positions: one, two and four value heads a key
+# head over two groups and over a length off the stride
+_BF16_CASES = {
+    "two_on_one_padded": _SHAPE,
+    "two_on_two_two_groups": (1, 512, 2, 2, 128, 128, 64),
+    "four_on_two_two_groups": (1, 512, 2, 4, 128, 128, 64),
+    "eight_on_two_off_the_stride": (1, 320, 2, 8, 128, 128, 64),
+}
+
+
 @pytest.mark.parametrize("impl", ["scan", "pallas_interpret"])
-def test_bfloat16_operands_keep_a_float32_state(impl):
+@pytest.mark.parametrize("case", _BF16_CASES)
+def test_bfloat16_operands_keep_a_float32_state(impl, case):
     """q, k, v in bfloat16 (the model's operands), g and beta float32: the
     result is near the float32 loop's on the same rounded operands; the
     gradients come back in the operands' dtypes."""
-    *ops, w = _operands(*_SHAPE[:-1], jnp.bfloat16, seed=3, decay=0.1)
+    *shape, chunk = _BF16_CASES[case]
+    *ops, w = _operands(*shape, jnp.bfloat16, seed=3, decay=0.1)
     f32 = lambda t: t.astype(jnp.float32)
-    out, *grads = _out_and_grads(impl, 32)(*ops, w.astype(jnp.bfloat16))
+    out, *grads = _out_and_grads(impl, chunk)(*ops, w.astype(jnp.bfloat16))
     want, *want_grads = _out_and_grads(None, None)(*map(f32, ops), w)
     assert out.shape == want.shape and out.dtype == jnp.bfloat16
     rel = lambda a, b: float(jnp.linalg.norm(f32(a) - b)
@@ -127,6 +153,38 @@ def test_bfloat16_operands_keep_a_float32_state(impl):
     for name, got, wanted, like in zip(_NAMES[1:], grads, want_grads, ops):
         assert got.dtype == like.dtype, name
         assert rel(got, wanted) < 3e-2, name
+
+
+@pytest.mark.parametrize("chunk, pack, lefts", [(64, 2, 2), (64, 1, 1),
+                                                (16, 4, 2), (32, 2, 1)])
+def test_packed_split_products_are_the_split_product(chunk, pack, lefts):
+    """``_packed_products`` of [C, pack x C] float32s (``pack`` [C, C]
+    blocks side by side, several left factors against one right factor in
+    two stacked passes) is ``_split_dot`` block by block: the same three
+    terms summed in the same order beside exact zeros, so equal to float32
+    rounding; and ``_split_dot`` holds both factors to 16 bits of mantissa
+    (a single bfloat16 pass is a hundred times further off)."""
+    ks = jax.random.split(jax.random.PRNGKey(chunk + pack), lefts + 1)
+    draw = lambda key: jax.random.normal(key, (chunk, pack * chunk))
+    right, *left = map(draw, ks)
+    packing = delta._Packing(chunk, pack)
+    got = delta._packed_products(left, right, packing, exact=False)
+    exact = delta._packed_products(left, right, packing, exact=True)
+    block = lambda t, u: t[:, u * chunk:(u + 1) * chunk]
+    for l, g, e in zip(left, got, exact):
+        assert g.shape == l.shape and g.dtype == jnp.float32
+        for u in range(pack):
+            a, b = block(l, u), block(right, u)
+            want = delta._split_dot(a, b)
+            scale = float(jnp.abs(want).max())
+            np.testing.assert_allclose(block(g, u), want, atol=2e-6 * scale)
+            full = jnp.dot(a, b, precision="highest")
+            np.testing.assert_allclose(block(e, u), full, atol=2e-6 * scale)
+            off = lambda t: float(jnp.linalg.norm(t - full)
+                                  / jnp.linalg.norm(full))
+            one_pass = jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                               preferred_element_type=jnp.float32)
+            assert 100 * off(block(g, u)) < off(one_pass)
 
 
 def test_boundaries_weigh_no_more_than_the_output():
@@ -138,10 +196,31 @@ def test_boundaries_weigh_no_more_than_the_output():
         assert stride % chunk == 0 and d_k * 4 <= stride * size
 
 
+def _kernel_grids(jaxpr):
+    """{a ``pallas_call``'s name: its grid} over ``jaxpr`` and what it
+    calls."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
 def test_kernels_are_named_and_recorded():
     """The two ``pallas_call``s carry the names the benchmark's readers find
-    them by, and each traced pass writes one ``delta/rule`` record: what it
-    walks, what its boundary states weigh and what it has to move."""
+    them by, one forward and one backward a rule call, over a grid of
+    (batch, pairs of key heads, groups): a step is two key heads' whole
+    groups. Each traced pass writes one ``delta/rule`` record: what it
+    walks, what its boundary states weigh, what it has to move and a grid
+    step's width."""
     from ray_tpu._private import steptrace
 
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -161,6 +240,8 @@ def test_kernels_are_named_and_recorded():
         steptrace.set_enabled(False)
         jax.clear_caches()
     assert kernel_calls(jaxpr) == {"gated_delta_fwd": 1, "gated_delta_bwd": 1}
+    assert _kernel_grids(jaxpr) == {"gated_delta_fwd": (4, 8, 32),
+                                    "gated_delta_bwd": (4, 8, 32)}
     assert {r["backward"] for r in records} == {0, 1}
     tokens = 4 * 8192
     qk_bytes, v_bytes, gates = (tokens * 16 * 128 * 2, tokens * 32 * 128 * 2,
@@ -174,7 +255,10 @@ def test_kernels_are_named_and_recorded():
             "bytes_needed": (4 * qk_bytes + 3 * v_bytes + 4 * gates
                              if r["backward"]
                              else 2 * qk_bytes + 2 * v_bytes + 2 * gates),
-            "backward": r["backward"]}
+            "backward": r["backward"],
+            # two key heads x two value heads each x four chunks a group
+            # of 256
+            "problems_a_step": 16, "grid_steps": 4 * 8 * 32}
 
 
 def test_recomputation_keeps_the_rule():

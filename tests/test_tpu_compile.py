@@ -113,7 +113,7 @@ _HELD_PROGRAMS = {
     "joyai-llm-flash.step-8k": "be022cbee2cb17d6",
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
     "lfm2-8b-a1b.step-8k": "26754d67a7295565",
-    "qwen3-next-80b-a3b.step-8k": "d03aab20694bde68",   # PR 57
+    "qwen3-next-80b-a3b.step-8k": "324cebd6c77525bf",   # PR 59
     "nemotron-3-nano-30b-a3b.step-8k": "04b7b0bf030790b4",   # PR 58
 }
 
@@ -1063,7 +1063,11 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
                      "boundary_bytes": tokens * 4096 * 2,   # the output's
                      "bytes_needed": tokens * (41_472 if e["backward"]
                                                else 24_832),
-                     "backward": e["backward"]}
+                     "backward": e["backward"],
+                     # a grid step is two key heads' groups: two value
+                     # heads each x four chunks (PR 59)
+                     "problems_a_step": 16,
+                     "grid_steps": batch * 8 * seq // 256}
     assert {e["backward"] for e in by_name["conv/causal"]} == {0, 1}
     cells = tokens * 8192 * 2
     for e in by_name["conv/causal"]:
