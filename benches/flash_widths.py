@@ -38,6 +38,170 @@ kernel's output and three gradients (bfloat16 operands) against
 highest, one query head at a time, and prints the largest differences
 over the largest reference entry: what interpret mode cannot show of the
 pipeline's writes.
+
+Readings (v5e, one chip; the tables that stood as comments in
+``ops/attention.py`` until PR 60, each with the PR whose chip run made it;
+the constants they justify are ``ops/flash_kernels.py``'s ``_MAX_RESIDENT``,
+``_FWD_TILES``, ``_BWD_TILES`` and ``_COPY_BYTES`` and ``ops/attention.py``'s
+``_FLASH_MIN_SEQ`` and ``_FLASH_HEAD_DIMS``).
+
+Who reaches which walk, and what a loop costs (my chip runs, PRs 25 and
+38; PERF.md section 6). One block a head is every call up to 2048 tokens,
+GPT-2's 1024 in four benchmark cells among them. Several blocks are any
+longer sequence: the cell ``joyai-llm-flash.step-8k`` sends 8192 (4 x 4
+blocks a head: 6 whole, 4 diagonal, 6 dead) and ``attention="auto"`` takes
+3072, 4096 and 8192 (3.3x to 47x faster than XLA's attention there,
+forward plus backward). A loop whose trip count the compiler does not
+know is neither unrolled nor scheduled across: with the loop alone,
+forward plus backward at 1024 take 17% longer (2.53 against 2.16 ms a
+layer; ``fori_loop(..., unroll=True)`` on static bounds reads as the
+Python loop does, 2.165). At 8192, 64 heads, keys 192 and values 128 wide,
+a call's kernel time by walk (forward, backward; needed at the MXU's peak
+6.98 and 18.14 ms): every block in loops with traced bounds 15.48 and
+28.72 ms; by kind with a whole block's rows one loop 13.22 and 23.62; two
+or four rows a loop step 23.42 and 23.32; a row's loop over its tiles, 1 /
+2 / 4 tiles a step 26.76 / 25.11 / 24.32; every tile of a whole block
+written out 13.20 and 44.73 (64 tile bodies of five matmuls on 256 lanes:
+at keys 128 wide the same code reads 16.38 against 17.40 for the rows'
+loop, and compiles in 10.8 s against 6.1).
+
+``_COPY_BYTES``. The most bytes of a block of queries' float32 dQ^T sum that one DMA moves,
+in a backward step over several blocks of keys (``_bwd_kernel``: a copy is
+whole rows of tiles, at least one). Read on the chip (PERF.md section 6,
+PR 50; ``flash_bwd`` alone at the three cells' shapes, ms, by rows of 256
+queries a copy: 1 / 2 / 4 / all 8; the parent's, which wrote partials,
+last): keys 192 wide at 8,192 tokens (a row 192 KiB) 23.49 / 23.48 / 23.71
+/ 24.48, parent 23.62; 128 wide at 16,384 under a window of 2,048 (a row
+128 KiB) 9.65 / 9.23 / 9.10 / 9.27, parent 8.92, and with no window 33.91
+/ 33.59 / 33.51 / 34.20, parent 34.15; 64 wide (a row 64 KiB) 19.51 /
+19.32 / 19.26 / 19.23, parent 19.28. A copy costs its start and its wait
+(about 30 ns each: 32 of them a live step are 1 us), and a large one
+stands in the way of the pipeline's own.
+
+``_FLASH_MIN_SEQ``, ``_FLASH_HEAD_DIMS``. Where "auto" takes the Pallas
+kernel: where it was measured faster than XLA's attention on a v5e, forward plus backward at 16,384 tokens a call
+(PERF.md section 6, PR 25). Head dimension 64: every multiple of 128 tried
+from 512 to 2048 (512, 640, 768, 896, 1024, 1152, 1280, 1536, 2048: 2.4x
+to 4.9x; at 256 and 384 XLA wins), where one grid step holds a whole
+head, and 3072, 4096 and 8192 (3.3x, 5.2x, 47x), where it holds 1536 or
+2048 queries and keys. Past ``_MAX_RESIDENT`` a length that 1024 does not
+divide can leave the kernel 128-wide grid blocks (2176 = 17 x 128: 20.9 ms
+against XLA's 17.3), so those stay with XLA. Head dimension 128: 512, 768,
+1024, 2048, 4096 (2.4x to 4.3x). The multiples of 128 between those
+lengths are interpolated, lengths past 8192 extrapolated (XLA's [T, T]
+scores take 718 ms a layer at 8192 and no longer fit at 16,384).
+Keys 192 wide and values 128 (PR 31; 32 heads, 16,384 tokens a call,
+forward plus backward, kernel against the "xla" path written out for two
+widths): 512: 8.10 against 9.57 ms; 1024: 9.51 / 16.75; 2048: 13.53 /
+30.57; 4096: 28.29 / 59.31 (1.2x to 2.3x); 8192: 50.66 ms, where XLA's
+scores (8.6 GB) were not tried. At 128 / 128 and the same 32 heads the
+kernel read 6.95, 7.86, 10.66, 25.57 and 45.59 ms.
+Since PR 38 (grid blocks walked by kind; ``benches/flash_widths.py``, my
+chip run, same 16,384 tokens and 32 heads), ``flash_fwd`` and ``flash_bwd``
+alone in a trace, then the wall time of forward plus backward with the
+transposes round them and, until PR 50, XLA's sum of dQ's partials (past
+2048 tokens; what PR 50 reads at the cells' shapes stands below):
+  192 / 128   2048: 3.51 and 6.11 ms, 13.53 (one block a head: as before)
+              4096: 6.91 and 12.14, 24.45 (was 28.27)
+              8192: 13.22 and 23.62, 43.29 (was 50.62; the kernels alone
+                    15.48 and 28.72)
+  128 / 128   2048: 2.65 and 4.22, 10.65; 4096: 5.23 and 8.80, 18.75 (was
+              25.57); 8192: 10.12 and 17.40, 32.99 (was 45.59)
+  64 / 64     1024: 1.27 and 2.60, 5.85; 4096: 4.19 and 8.04, 14.71
+The wider key costs 34% to 40% more kernel time at 2048 to 8192 (less
+than its 3/2 in QK^T, dK and dQ; a 192-wide operand is laid out as 256
+lanes and fills the MXU's depth one and a half times). By block at 192 /
+128 (the 8192 reading less four diagonal blocks a head at the 2048
+reading's price): backward 23.9 us a diagonal block of 36 tiles and 45.6
+a whole block of 64 (0.66 and 0.71 us a tile against 0.55 at the MXU's
+peak); forward 13.7 us a diagonal block of 10 tiles and 25.3 a whole
+block of 16 (1.37 and 1.58 us a tile against 0.85).
+Since PR 44 (grouped key-value heads, a window; same bench with
+``--kv-heads 4`` and ``--window``, my chip run, 128 / 128, one sequence of
+16,384 tokens, 32 query heads, 8 x 8 grid blocks a head), ``flash_fwd`` and
+``flash_bwd`` alone, then the wall time of forward plus backward:
+  no window, 32 key-value heads (28 whole, 8 diagonal, 28 dead a head):
+              19.47 and 34.46 ms, 60.80
+  no window, 4 key-value heads: 19.18 and 34.15, 58.20 (the keys and values
+              of a group are fetched once a group in the backward and leave
+              as 4 heads' dK and dV: grouping costs the kernels nothing)
+  window 2048, 4 key-value heads (8 diagonal, 7 trailing, 49 dead):
+              6.15 and 8.92, 17.70; the dQ partials 2 x 32 x 128 x 16,384
+              float32 (0.5 GiB) against 8 (2.0 GiB) without a window
+              (until PR 50: below)
+By kind of block, from the 2048 and 8192 readings above: a diagonal block
+10.35 us forward and 16.5 backward, a whole block 19.45 and 34.3 (the full
+call priced so: 20.08 and 34.96 ms, read 19.18 and 34.15); a trailing
+block with the seven dead grid steps of its row 15.6 us forward (the dead
+steps fetch nothing and still owe the scratch's start and the outputs'
+write) and 21.0 backward. A window that is no whole number of blocks (4096
+tokens under 1024 keys: "looped") reads 4.27 and 7.87 ms against 4.42 and
+6.63 under 2048.
+Since PR 50 (the backward sums dQ^T over a head's blocks of keys itself,
+``_bwd_kernel``; same bench, my chip runs, the parent beside the change in
+one call, at the three cells' shapes): ``flash_bwd`` alone, what the wall
+time of forward plus backward holds besides the two kernels
+(``round_kernels_ms``: the V^T, K^T, O^T and dQ^T swaps, ``delta``, dQ's
+rounding; before, XLA's sum of the partials too), that wall time, and the
+bytes of dQ the call writes for XLA; ``flash_fwd`` unmoved throughout:
+  192 / 128 at 8,192, 64 heads:   23.62 -> 23.48 ms, 6.51 -> 4.83, 43.35 ->
+              41.52; 1,610.6 -> 402.7 MB
+  128 / 128 at 16,384, 32 on 4:   34.15 -> 33.51, 4.88 -> 2.22, 58.20 ->
+              54.91; 2,147.5 -> 268.4 MB
+    under a window of 2,048:      8.92 -> 9.10, 2.64 -> 2.22, 17.71 -> 17.48;
+              536.9 -> 268.4 MB (no dead step wrote zeros here before, so
+              the kernel pays for its copies and gains nothing back)
+  64 / 128 at 16,384, 20 on 10:   19.28 -> 19.28, 1.91 -> 1.12, 32.84 ->
+              32.05; 671.1 -> 83.9 MB
+    under a window of 512:        3.73 -> 3.77, 1.19 -> 1.07, 8.02 -> 7.94
+  64 / 64 at 1,024 (one block of keys a head: the kernel's body is the
+              parent's): 0.975 -> 0.975, 2.09 -> 2.09
+Output and the three gradients against ``attention_reference`` in float32
+on the chip: the same five digits as the parent at every shape (dQ within
+0.00285 to 0.00392 of the largest entry).
+
+Keys 64 and values 128 wide (PR 48; ``benches/flash_widths.py --widths
+64x128 --lengths 16384 --heads 20 --kv-heads 10 --check 1``, my chip run:
+one sequence of 16,384 tokens, 20 query heads on 10 key-value heads, a map
+of a differential attention layer), ``flash_fwd`` and ``flash_bwd`` alone,
+then the wall time of forward plus backward; beside it (64, 64) at the same
+heads, of which such a layer would need four calls where it needs two of
+these:
+  no window:   (64, 128) 11.65 and 19.28 ms, 32.84; (64, 64) 9.58 and
+               19.28, 30.65: the wider value costs the forward 22% and the
+               backward nothing that these readings show. Why not is not
+               known: two of its five matmuls (dP, dV) carry the values'
+               width. A guess that fits, untested: the 64-wide keys'
+               passes set its time. (64, 256) and (128, 128) at the same
+               heads would tell; neither was run
+  window 512:  (64, 128) 3.10 and 3.73 ms, 8.01; (64, 64) 1.99 and 3.72,
+               6.80. 512 keys are a quarter of a 2,048-wide grid block, so
+               all 64 blocks a head are "looped" (``grid_block_kinds``):
+               every diagonal block walks its tiles in loops with traced
+               bounds, and the needed pairs (8.26M a head) are 10% of the
+               peak forward and 20% backward. Left as it is: 14 ms of a
+               785 ms step in the one cell that has such a window.
+Output and the three gradients against ``attention_reference`` in float32
+there: within 0.0029 to 0.0055 of the largest entry, with and without the
+window (bfloat16 operands). XLA's scores at these lengths are [20, 16384,
+16384] a map and were not tried.
+
+Keys and values 256 wide (PR 56; ``benches/flash_widths.py --widths 256x256
+--lengths 8192 --tokens 32768 --heads 16 --kv-heads 2 --check 1``, my chip
+run: four sequences of 8,192 tokens, 16 query heads on 2 key-value heads,
+the widest head and, with (32 on 4 of 128), the widest group so far): the
+tiles of the narrower widths fit VMEM at twice the width, so none changed;
+a head is 4 x 4 grid blocks (6 whole, 4 diagonal, 6 dead, none looped) on
+the ``model_results`` boundary (one width of whole lane tiles past one
+block of keys). ``flash_fwd`` 16.29 ms and ``flash_bwd`` 30.26 alone, 49.80
+forward plus backward by the wall clock (3.25 of it outside the kernels);
+the needed pairs are 69.5% of the MXU's peak forward and 92.1% backward
+(``attn_kernel_roofline_pct``'s count on the cell's traced step, 8.03 and
+15.15 ms at two sequences): a 256-wide head fills the MXU's depth where a
+64-wide one fills a quarter. Output and the three gradients against
+``attention_reference`` in float32 there: within 0.0030 (out), 0.0044
+(dq), 0.0042 (dk), 0.0032 (dv) of the largest entry. XLA's scores at this
+shape are [16, 8192, 8192] float32 a sequence, 4.3 GB, and were not tried.
 """
 
 import argparse
@@ -69,7 +233,8 @@ def main():
     from ray_tpu.ops import attention
     from ray_tpu.ops.attention import (attention_reference,
                                        causal_self_attention,
-                                       grid_block_kinds, heads_a_lane_tile)
+                                       heads_a_lane_tile)
+    from ray_tpu.ops.flash_kernels import grid_block_kinds
 
     kv_heads = args.kv_heads or args.heads
 

@@ -49,7 +49,8 @@ from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_frequencies
 from ray_tpu.models.mla_moe import RoutedExperts, held_expert_load
 from ray_tpu.ops import xent
-from ray_tpu.ops.attention import causal_self_attention, remat_policy
+from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated
 

@@ -5,7 +5,7 @@ air_benchmarks/ + driver BASELINE config "GPT-2-124M data-parallel").
 TPU-native: params in f32, compute in bf16 so matmuls hit the MXU; batch
 sharded over the data/fsdp mesh axes; gradients reduced by the XLA partitioner
 from the sharding annotations; optional remat recomputes a block in backward,
-all of it but the flash kernel where it ran (``ops.attention.remat_policy``).
+all of it but the flash kernel where it ran (``ops.remat.remat_policy``).
 """
 
 from __future__ import annotations
@@ -19,7 +19,8 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ray_tpu.ops.attention import causal_self_attention, remat_policy
+from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.ops.xent import (chunked_xent, fused_xent,
                               token_log_likelihood)
 from ray_tpu.parallel import train_step

@@ -38,7 +38,7 @@ statistics are float32. The selection bias is a parameter that takes a zero
 gradient (its balance update is a training recipe), and there is no
 auxiliary loss. Under ``remat`` a block is recomputed in the backward pass
 from its input; the flash kernel's output is kept
-(``ops.attention.remat_policy``), the convolution's is made again.
+(``ops.remat.remat_policy``), the convolution's is made again.
 """
 
 from __future__ import annotations
@@ -56,8 +56,9 @@ from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_frequencies
 from ray_tpu.models.mla_moe import RoutedExperts
 from ray_tpu.ops import xent
-from ray_tpu.ops.attention import causal_self_attention, remat_policy
+from ray_tpu.ops.attention import causal_self_attention
 from ray_tpu.ops.conv import gated_short_conv
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated
 

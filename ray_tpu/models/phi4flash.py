@@ -44,7 +44,7 @@ Parameters are float32, compute is ``dtype``; ``delta``, ``A``, the scan's
 state, every softmax statistic, ``lam`` and the norms' statistics are
 float32. Under ``remat`` a block is recomputed in the backward pass from its
 inputs, which are the hidden state and whatever was handed down to it; the
-kernels' outputs are kept (``ops.attention.remat_policy``), so neither the
+kernels' outputs are kept (``ops.remat.remat_policy``), so neither the
 flash kernels nor the forward scan run again.
 """
 
@@ -62,7 +62,8 @@ from ray_tpu._private import steptrace
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import xent
-from ray_tpu.ops.attention import causal_self_attention, remat_policy
+from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.ops.ssm import selective_scan
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated

@@ -48,7 +48,7 @@ Parameters are float32, compute is ``dtype``; the router's scores, every
 softmax statistic, the norms' statistics, the rule's state and decays and
 the convolution's sum over its taps are float32. Under ``remat`` a block is
 recomputed in the backward pass from its input; the flash kernel's and the
-rule's outputs are kept (``ops.attention.remat_policy``).
+rule's outputs are kept (``ops.remat.remat_policy``).
 """
 
 from __future__ import annotations
@@ -66,9 +66,10 @@ from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import rope_frequencies
 from ray_tpu.models.mla_moe import RoutedExperts
 from ray_tpu.ops import xent
-from ray_tpu.ops.attention import causal_self_attention, remat_policy
+from ray_tpu.ops.attention import causal_self_attention
 from ray_tpu.ops.conv import causal_conv
 from ray_tpu.ops.delta import gated_delta_rule
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated
 

@@ -1,7 +1,17 @@
 """ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, the
-selective and the scalar-decay scan of state-space layers, the gated delta rule of
-linear-attention layers, the short convolutions, expert layers and the
-vocabulary's loss."""
+selective and the scalar-decay scan of state-space layers, the gated delta
+rule of linear-attention layers, the short convolutions, expert layers and
+the vocabulary's loss.
+
+Who knows whom, arrows one way: ``models/*`` -> ``remat`` (what a recomputed
+block keeps) and the op modules ``attention`` (its kernels:
+``flash_kernels``), ``ssm``, ``conv``, ``delta``, ``moe`` -> ``chunks`` (the
+chunk scheme ``delta`` and ``ssm.ssd_scan`` share) and ``mosaic`` (where a
+Pallas kernel may run, how it is handed to a mesh, the compiler's
+parameters) -> ``parallel/mesh_utils``. A new kernel module names its own
+``REMAT_NAMES`` and adds one line to ``remat``; it imports nothing from
+another op's file.
+"""
 
 from ray_tpu.ops.attention import (
     attention_reference,

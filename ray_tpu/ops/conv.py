@@ -45,10 +45,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec
 
 from ray_tpu._private import steptrace
-from ray_tpu.ops.attention import _batch_axes, unmapped_mesh_axes
+from ray_tpu.ops.mosaic import (compiler_params, per_batch_shard,
+                                takes_kernels)
 
 TAPS = 3             # what the kernels are written for: z_{t-2}, z_{t-1}, z_t
 _HALO = 16           # rows of the neighbouring block: one bfloat16 tile
@@ -267,10 +267,7 @@ def _record(bcx, backward: bool):
 
 
 def _params(interpret: bool, semantics):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=48 * 2**20)
+    return compiler_params(interpret, semantics, 48 * 2**20)
 
 
 def _specs(bcx):
@@ -366,14 +363,10 @@ def fits(bcx, taps) -> bool:
 
 
 def auto_impl(bcx, taps) -> str:
-    """What ``impl=None`` runs: the kernels on a TPU where the layout
-    ``fits`` them and the mesh ``bcx`` is traced under has no axis of more
-    than one device but the batch's (the rule of ``ops.ssm.auto_impl``);
+    """What ``impl=None`` runs: the kernels where the layout ``fits`` them
+    and ``bcx`` is traced where a kernel may run (``mosaic.takes_kernels``);
     the ``jnp`` form elsewhere."""
-    if (jax.default_backend() == "tpu" and fits(bcx, taps)
-            and not unmapped_mesh_axes(bcx)):
-        return "pallas"
-    return "jnp"
+    return "pallas" if fits(bcx, taps) and takes_kernels(bcx) else "jnp"
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -386,12 +379,8 @@ def gated_short_conv(bcx, taps, *, impl: Optional[str] = None) -> jax.Array:
     impl = impl or auto_impl(bcx, taps)
     taps = taps.astype(_F32)
     conv = lambda bcx, taps: _conv_diff(bcx, taps, impl)
-    mesh, axes = _batch_axes(bcx) if impl != "jnp" else (None, ())
-    if axes:
-        rows, whole = PartitionSpec(axes), PartitionSpec()
-        conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, whole),
-                             out_specs=rows, axis_names=set(axes),
-                             check_vma=False)
+    if impl != "jnp":
+        conv = per_batch_shard(conv, bcx, (True, False), "gated_short_conv")
     return conv(bcx, taps)
 
 
@@ -686,11 +675,11 @@ def causal_fits(x, taps, activation) -> bool:
 
 
 def causal_auto_impl(x, taps, activation) -> str:
-    """What ``impl=None`` runs: the rule of ``auto_impl``."""
-    if (jax.default_backend() == "tpu" and causal_fits(x, taps, activation)
-            and not unmapped_mesh_axes(x)):
-        return "pallas"
-    return "jnp"
+    """What ``impl=None`` runs: the kernels where ``causal_fits`` and ``x``
+    is traced where a kernel may run (``mosaic.takes_kernels``); the ``jnp``
+    form elsewhere."""
+    fits = causal_fits(x, taps, activation)
+    return "pallas" if fits and takes_kernels(x) else "jnp"
 
 
 def causal_conv(x, taps, activation=None, bias=None, *,
@@ -715,12 +704,7 @@ def causal_conv(x, taps, activation=None, bias=None, *,
     if impl == "jnp":
         return diff(x, *weights, activation, impl)
     assert causal_fits(x, taps, activation), (x.shape, taps.shape, activation)
-    conv = lambda x, *weights: diff(x, *weights, activation, impl)
-    mesh, axes = _batch_axes(x)
-    if axes:
-        rows, whole = PartitionSpec(axes), PartitionSpec()
-        conv = jax.shard_map(conv, mesh=mesh,
-                             in_specs=(rows,) + (whole,) * len(weights),
-                             out_specs=rows, axis_names=set(axes),
-                             check_vma=False)
+    conv = per_batch_shard(
+        lambda x, *weights: diff(x, *weights, activation, impl), x,
+        (True,) + (False,) * len(weights), "causal_conv")
     return conv(x, *(w.astype(_F32) for w in weights))

@@ -30,8 +30,8 @@ d_k 128: a boundary a chunk of 64 would be 1 GiB a layer at 32,768 tokens of
 inner states again from its boundary, and walks its chunks in reverse with
 the state's gradient as the carry. One ``custom_vjp`` holds both paths: the
 forward rule's outputs are named ``delta_rule_out`` / ``delta_rule_bounds``
-(``ops.attention.remat_policy`` keeps them, so a recomputed block does not
-run the forward rule again).
+(``REMAT_NAMES``: ``ops.remat.remat_policy`` keeps them, so a recomputed
+block does not run the forward rule again).
 
 The kernels (``gated_delta_fwd`` / ``gated_delta_bwd``: the benchmark's
 readers find them by these names) address the model's own arrays, [B, T,
@@ -90,20 +90,18 @@ import jax.numpy as jnp
 from jax import ad_checkpoint, lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec
 
 from ray_tpu._private import steptrace
-from ray_tpu.ops.attention import (DELTA_REMAT_NAMES, _batch_axes,
-                                   unmapped_mesh_axes)
+from ray_tpu.ops.chunks import (NT, TN, dot, folded, gates, grouped, iota,
+                                ungated, ungrouped)
+from ray_tpu.ops.mosaic import (compiler_params, per_batch_shard,
+                                takes_kernels)
 
 CHUNK = 64           # positions a chunk: one triangular solve each
 _SOLVE_BLOCK = 16    # the diagonal blocks the solve's first series inverts
 _LANES = 128
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
-_NN = (((1,), (0,)), ((), ()))  # a @ b
-_NT = (((1,), (1,)), ((), ()))  # a @ b.T
-_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def stride_of(chunk: int, d_k: int, itemsize: int) -> int:
@@ -120,9 +118,9 @@ def bytes_needed(q, v, backward: bool) -> int:
     batch, length, key_heads, d_k = q.shape
     heads, d_v = v.shape[2:]
     tokens, size = batch * length, q.dtype.itemsize
-    qk, vo, gates = (2 * tokens * key_heads * d_k * size,
-                     tokens * heads * d_v * size, 2 * tokens * heads * 4)
-    return (2 * qk + 3 * vo + 2 * gates) if backward else qk + 2 * vo + gates
+    qk, vo, gb = (2 * tokens * key_heads * d_k * size,
+                  tokens * heads * d_v * size, 2 * tokens * heads * 4)
+    return (2 * qk + 3 * vo + 2 * gb) if backward else qk + 2 * vo + gb
 
 
 def _record(q, v, chunk, stride, backward: bool, kernels: bool):
@@ -182,18 +180,6 @@ def _scan_chunk(state, inputs):
     return state, jnp.moveaxis(o, 2, 1)
 
 
-def _grouped(t, stride, chunk):
-    """[B, T, ...] -> [T / stride, stride / chunk, B, chunk, ...]."""
-    b, length = t.shape[:2]
-    t = t.reshape(b, length // stride, stride // chunk, chunk, *t.shape[2:])
-    return jnp.moveaxis(t, 0, 2)
-
-
-def _ungrouped(t):
-    t = jnp.moveaxis(t, 2, 0)
-    return t.reshape(t.shape[0], -1, *t.shape[4:])
-
-
 def _scan_group(state, inputs, rep):
     q, k, v, g, beta = inputs
     wide = lambda t: jnp.repeat(t, rep, axis=3) if rep > 1 else t
@@ -201,7 +187,7 @@ def _scan_group(state, inputs, rep):
 
 
 def _scan_operands(q, k, v, g, beta, chunk, stride):
-    return tuple(_grouped(t.astype(_F32), stride, chunk)
+    return tuple(grouped(t.astype(_F32), stride, chunk)
                  for t in (q, k, v, g, beta))
 
 
@@ -219,7 +205,7 @@ def _scan_fwd(q, k, v, g, beta, chunk, stride):
     zero = jnp.zeros((batch, heads, d_k, d_v), _F32)
     _, (o, bounds) = lax.scan(
         one, zero, _scan_operands(q, k, v, g, beta, chunk, stride))
-    return _ungrouped(o), jnp.moveaxis(bounds, 0, 2)
+    return ungrouped(o), jnp.moveaxis(bounds, 0, 2)
 
 
 def _scan_bwd(q, k, v, g, beta, bounds, do, chunk, stride):
@@ -238,36 +224,14 @@ def _scan_bwd(q, k, v, g, beta, bounds, do, chunk, stride):
     _, grads = lax.scan(
         one, jnp.zeros(bounds.shape[:2] + bounds.shape[3:], _F32),
         (*_scan_operands(q, k, v, g, beta, chunk, stride),
-         jnp.moveaxis(bounds, 2, 0), _grouped(do.astype(_F32), stride, chunk)),
+         jnp.moveaxis(bounds, 2, 0), grouped(do.astype(_F32), stride, chunk)),
         reverse=True)
-    return tuple(map(_ungrouped, grads))
+    return tuple(map(ungrouped, grads))
 
 
 # ---------------------------------------------------------------------------
 # the kernels
 # ---------------------------------------------------------------------------
-
-def _dot(a, b, dims=_NN, precision=None):
-    return lax.dot_general(a, b, dims, precision=precision,
-                           preferred_element_type=_F32)
-
-
-def _iota(chunk: int):
-    return (lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0),
-            lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-
-
-def _as_col(row_vec, chunk: int):
-    """[1, C] -> [C, 1]: the numbers a position each, down the sublanes."""
-    row, col = _iota(chunk)
-    return jnp.sum(jnp.where(row == col, row_vec, 0.0), axis=1, keepdims=True)
-
-
-def _as_row(col_vec, chunk: int):
-    """[C, 1] -> [1, C]."""
-    row, col = _iota(chunk)
-    return jnp.sum(jnp.where(row == col, col_vec, 0.0), axis=0, keepdims=True)
-
 
 def _halves(t):
     """A float32 as two bfloat16s: its 8 leading bits of mantissa and the 8
@@ -282,7 +246,7 @@ def _split_dot(a, b):
     precision takes six. What ``_packed_products`` makes in one and a half
     passes of the MXU; this plain form is its meaning."""
     (a_hi, a_lo), (b_hi, b_lo) = _halves(a), _halves(b)
-    return _dot(a_hi, b_hi) + (_dot(a_hi, b_lo) + _dot(a_lo, b_hi))
+    return dot(a_hi, b_hi) + (dot(a_hi, b_lo) + dot(a_lo, b_hi))
 
 
 def _beside(parts):
@@ -305,10 +269,10 @@ class _Packing:
 
     def __init__(self, chunk: int, pack: int):
         self.chunk, self.pack = chunk, pack
-        iota = lambda shape, axis: lax.broadcasted_iota(jnp.int32, shape,
-                                                        axis)
+        along = lambda shape, axis: lax.broadcasted_iota(jnp.int32, shape,
+                                                         axis)
         packed, square = (chunk, pack * chunk), (pack * chunk, pack * chunk)
-        row, lane = iota(packed, 0), iota(packed, 1)
+        row, lane = along(packed, 0), along(packed, 1)
         block = lax.div(lane, chunk)
         col = lane - block * chunk               # inside its block
         self.in_block = [block == u for u in range(pack)]
@@ -318,11 +282,11 @@ class _Packing:
         self.same = (lax.div(row, _SOLVE_BLOCK)
                      == lax.div(col, _SOLVE_BLOCK))
         self.last_col = col[:1] == chunk - 1                # [1, pack x C]
-        self.on_blocks = (lax.div(iota(square, 0), chunk)
-                          == lax.div(iota(square, 1), chunk))
-        unit_row, unit_col = _iota(chunk)
+        self.on_blocks = (lax.div(along(square, 0), chunk)
+                          == lax.div(along(square, 1), chunk))
+        unit_row, unit_col = iota(chunk)
         self.unit = unit_row == unit_col                    # [C, C]
-        self.final = iota((1, chunk), 1) == chunk - 1
+        self.final = along((1, chunk), 1) == chunk - 1
 
     def as_col(self, row_vec):
         """[1, C] -> [C, 1]: the numbers a position each, down the
@@ -376,14 +340,14 @@ def _packed_products(lefts, right, packing: _Packing, exact: bool):
     half."""
     chunk = packing.chunk
     if exact:
-        return _rows_of(_dot(_stacked(lefts), packing.block_diagonal(right),
-                             precision=_HIGHEST), chunk)
+        return _rows_of(dot(_stacked(lefts), packing.block_diagonal(right),
+                            precision=_HIGHEST), chunk)
     halves = _halves(right)
     r_hi, r_lo = map(packing.block_diagonal, halves)
     split = [halves if l is right else _halves(l) for l in lefts]
     his, los = [h for h, _ in split], [l for _, l in split]
-    top = _rows_of(_dot(_stacked(his + los), r_hi), chunk)
-    low = _rows_of(_dot(_stacked(his), r_lo), chunk)
+    top = _rows_of(dot(_stacked(his + los), r_hi), chunk)
+    low = _rows_of(dot(_stacked(his), r_lo), chunk)
     n = len(lefts)
     return [top[i] + (low[i] + top[n + i]) for i in range(n)]
 
@@ -435,12 +399,12 @@ def _blocks_nt(lefts, rights):
     left factors beside one another against the right factors on a block
     diagonal, one product whose result is born packed."""
     if len(lefts) == 1:
-        return _dot(lefts[0], rights[0], _NT)
+        return dot(lefts[0], rights[0], NT)
     zero = jnp.zeros_like(rights[0])
     diagonal = _stacked([_beside([r if v == u else zero
                                   for v in range(len(rights))])
                          for u, r in enumerate(rights)])
-    return _dot(_beside(lefts), diagonal, _NT)
+    return dot(_beside(lefts), diagonal, NT)
 
 
 def _step_parts(q_ref, k_ref, v_ref, g_ref, b_ref, heads, rep: int,
@@ -466,7 +430,7 @@ def _step_parts(q_ref, k_ref, v_ref, g_ref, b_ref, heads, rep: int,
         rows, lanes = slice(r * chunk, (r + 1) * chunk), slice(h * d_k,
                                                                (h + 1) * d_k)
         q, k = q_ref[rows, lanes], k_ref[rows, lanes]
-        scores = _dot(_stacked([k, q]), _stacked([k] * pack), _NT)
+        scores = dot(_stacked([k, q]), _stacked([k] * pack), NT)
         return (q, k, q.astype(_F32), k.astype(_F32), scores[:chunk],
                 scores[chunk:])
 
@@ -513,9 +477,9 @@ def _step_parts(q_ref, k_ref, v_ref, g_ref, b_ref, heads, rep: int,
         p["solved"] = packing.block_diagonal(t.astype(dt))
         p["attend"] = packing.block_diagonal(p["attend32"].astype(dt))
     for p in every:
-        p["ws"] = _rows_of(_dot(p["solved"], _stacked(
+        p["ws"] = _rows_of(dot(p["solved"], _stacked(
             [kb.astype(dt) for kb in p["kb32s"]])), chunk)
-        p["us"] = _rows_of(_dot(p["solved"], _stacked(
+        p["us"] = _rows_of(dot(p["solved"], _stacked(
             [vb.astype(dt) for vb in p["vb32s"]])), chunk)
     return parts
 
@@ -548,19 +512,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, bound_ref, s_scr,
             rows, dt = slice(r * chunk, (r + 1) * chunk), q_ref.dtype
             mine = _heads_of(packs)
             # W S and (exp(gamma) q) S: one pass over the state
-            over = {j: _dot(_stacked([p["ws"][u].astype(dt),
-                                      p["qg32s"][u].astype(dt)]),
-                            states[j].astype(dt)) for p, u, j in mine}
+            over = {j: dot(_stacked([p["ws"][u].astype(dt),
+                                     p["qg32s"][u].astype(dt)]),
+                           states[j].astype(dt)) for p, u, j in mine}
             new_vs = {j: (p["us"][u] - over[j][:chunk]).astype(dt)
                       for p, u, j in mine}
             within = {j: rows for p in packs for j, rows in zip(
-                p["js"], _rows_of(_dot(p["attend"], _stacked(
+                p["js"], _rows_of(dot(p["attend"], _stacked(
                     [new_vs[j] for j in p["js"]])), chunk))}
             for p, u, j in mine:
                 o = over[j][chunk:] + within[j]
                 o_ref[rows, j * d_v:(j + 1) * d_v] = o.astype(o_ref.dtype)
-            states.update({j: p["last_rows"][u] * states[j] + _dot(
-                p["kd32s"][u].astype(dt), new_vs[j], _TN)
+            states.update({j: p["last_rows"][u] * states[j] + dot(
+                p["kd32s"][u].astype(dt), new_vs[j], TN)
                 for p, u, j in mine})
         for j, state in states.items():
             s_scr[j] = state
@@ -593,9 +557,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
         # attend^T do, down the rows a head, and (exp(gamma) q)^T do
         into_new_v = {(r, j): rows for r, packs in enumerate(parts)
                       for p in packs for j, rows in zip(p["js"], _rows_of(
-                          _dot(p["attend"], _stacked(
-                              [dos[r, j] for j in p["js"]]), _TN), chunk))}
-        into_state = {(r, j): _dot(cast(p["qg32s"][u]), dos[r, j], _TN)
+                          dot(p["attend"], _stacked(
+                              [dos[r, j] for j in p["js"]]), TN), chunk))}
+        into_state = {(r, j): dot(cast(p["qg32s"][u]), dos[r, j], TN)
                       for r, packs in enumerate(parts)
                       for p, u, j in _heads_of(packs)}
 
@@ -606,11 +570,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
         for r, packs in enumerate(parts):
             mine = _heads_of(packs)
             starts.update({(r, j): states[j] for j in heads})
-            new_vs.update({(r, j): p["us"][u] - _dot(
+            new_vs.update({(r, j): p["us"][u] - dot(
                 cast(p["ws"][u]), cast(states[j])) for p, u, j in mine})
             if r + 1 < chunks:
-                states = {j: p["last_rows"][u] * states[j] + _dot(
-                    cast(p["kd32s"][u]), cast(new_vs[r, j]), _TN)
+                states = {j: p["last_rows"][u] * states[j] + dot(
+                    cast(p["kd32s"][u]), cast(new_vs[r, j]), TN)
                     for p, u, j in mine}
 
         # the reverse walk, the state's gradient as the carry: two
@@ -620,10 +584,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
         for r in reversed(range(chunks)):
             mine = _heads_of(parts[r])
             dstates.update({(r, j): carried[j] for j in heads})
-            d_new_vs.update({(r, j): cast(into_new_v[r, j] + _dot(
+            d_new_vs.update({(r, j): cast(into_new_v[r, j] + dot(
                 cast(p["kd32s"][u]), cast(carried[j]))) for p, u, j in mine})
             carried = {j: (into_state[r, j] + p["last_rows"][u] * carried[j]
-                           - _dot(cast(p["ws"][u]), d_new_vs[r, j], _TN))
+                           - dot(cast(p["ws"][u]), d_new_vs[r, j], TN))
                        for p, u, j in mine}
         for j in heads:
             ds_scr[j] = carried[j]
@@ -637,11 +601,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
             p["new_v"] = [cast(new_vs[r, j]) for j in js]
             p["d_new_v"] = [d_new_vs[r, j] for j in js]
             # do S^T and d(V') S^T: one pass over the state
-            by_start = [_dot(_stacked([dos[r, j], dnv]), cast(starts[r, j]),
-                             _NT) for j, dnv in zip(js, p["d_new_v"])]
+            by_start = [dot(_stacked([dos[r, j], dnv]), cast(starts[r, j]),
+                            NT) for j, dnv in zip(js, p["d_new_v"])]
             p["dqgs"] = [b[:chunk] for b in by_start]
             p["dws"] = [cast(-b[chunk:]) for b in by_start]
-            p["dkds"] = [_dot(nv, cast(dstates[r, j]), _NT)
+            p["dkds"] = [dot(nv, cast(dstates[r, j]), NT)
                          for j, nv in zip(js, p["new_v"])]
             p["d_attend"] = jnp.where(packing.seen, _blocks_nt(
                 [dos[r, j] for j in js], p["new_v"]), 0.0)
@@ -649,16 +613,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
             p["dsolved"] = packing.block_diagonal(cast(
                 _blocks_nt(p["d_new_v"], [cast(vb) for vb in p["vb32s"]])
                 + _blocks_nt(p["dws"], [cast(kb) for kb in p["kb32s"]])))
-            p["dvbs"] = _rows_of(_dot(p["solved"], _stacked(p["d_new_v"]),
-                                      _TN), chunk)
-            p["dkbs"] = _rows_of(_dot(p["solved"], _stacked(p["dws"]), _TN),
+            p["dvbs"] = _rows_of(dot(p["solved"], _stacked(p["d_new_v"]),
+                                     TN), chunk)
+            p["dkbs"] = _rows_of(dot(p["solved"], _stacked(p["dws"]), TN),
                                  chunk)
         # d(I + L)^-1 = -T^T dT T^T, on the strictly lower part
         for _, p in every:
-            p["inner"] = cast(_dot(p["solved"], p["dsolved"], _TN))
+            p["inner"] = cast(dot(p["solved"], p["dsolved"], TN))
         for _, p in every:
             p["dlower"] = jnp.where(packing.strict, -packing.by_block(
-                _rows_of(_dot(p["inner"], p["solved"], _NT), chunk)), 0.0)
+                _rows_of(dot(p["inner"], p["solved"], NT), chunk)), 0.0)
         for r, p in every:
             rows, js = slice(r * chunk, (r + 1) * chunk), p["js"]
             dqgs, dkds, dvbs, dkbs, d_attend, dlower = (p[n] for n in (
@@ -702,11 +666,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, bound_ref,
             # them in the product
             k_rows, q_rows = _stacked([p["k"]] * pack), p["q"]
             at = r, p["key"]
-            dqs[at] = dqs[at] + _dot(dqk, k_rows) + sum(
+            dqs[at] = dqs[at] + dot(dqk, k_rows) + sum(
                 g * d for g, d in zip(p["grows"], dqgs))
-            dks[at] = (dks[at] + _dot(dkk, k_rows)
-                       + sum(_rows_of(_dot(dqk, q_rows, _TN)
-                                      + _dot(dkk, p["k"], _TN), chunk))
+            dks[at] = (dks[at] + dot(dkk, k_rows)
+                       + sum(_rows_of(dot(dqk, q_rows, TN)
+                                      + dot(dkk, p["k"], TN), chunk))
                        + sum((b * g) * d for b, g, d in zip(
                            p["betas"], p["grows"], dkbs))
                        + sum(e * d for e, d in zip(p["to_ends"], dkds)))
@@ -764,36 +728,14 @@ def _step_width(rep: int, key_heads: int, chunk: int, chunks: int, d_k: int,
     return pack, keys, width
 
 
-def _folded(t):
-    """[B, T, H, d] -> [B, T, H x d]: the model's own array."""
-    return t.reshape(*t.shape[:2], -1)
-
-
-def _gates(t, chunk, stride):
-    """[B, T, H] float32 -> [B, H, T / stride, stride / chunk, chunk]: a
-    chunk's numbers along the lanes."""
-    batch, length, heads = t.shape
-    return jnp.transpose(
-        t.reshape(batch, length // stride, stride // chunk, chunk, heads),
-        (0, 4, 1, 2, 3))
-
-
-def _ungated(t):
-    batch, heads = t.shape[:2]
-    return jnp.transpose(t, (0, 2, 3, 4, 1)).reshape(batch, -1, heads)
-
-
 def _log_decay(g, chunk, stride):
     """``gamma``: g summed from each chunk's start, in the kernels' form."""
-    return jnp.cumsum(_gates(g.astype(_F32), chunk, stride), axis=-1)
+    return jnp.cumsum(gates(g.astype(_F32), chunk, stride), axis=-1)
 
 
 def _params(interpret: bool):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
+    return compiler_params(interpret, ("parallel", "parallel", "arbitrary"),
+                           _VMEM_LIMIT)
 
 
 def _step(q, v, chunk, stride):
@@ -861,15 +803,15 @@ def _pallas_fwd(q, k, v, g, beta, chunk, stride, interpret):
         in_specs=[qk, qk, vo, gate, gate],
         out_specs=[vo, bound],
         out_shape=[
-            jax.ShapeDtypeStruct(_folded(v).shape, v.dtype),
+            jax.ShapeDtypeStruct(folded(v).shape, v.dtype),
             jax.ShapeDtypeStruct((batch, heads, groups, d_k, d_v), _F32),
         ],
         scratch_shapes=[pltpu.VMEM((keys * rep, d_k, d_v), _F32)],
         compiler_params=_params(interpret),
         interpret=interpret,
         name="gated_delta_fwd",
-    )(_folded(q), _folded(k), _folded(v), _log_decay(g, chunk, stride),
-      _gates(beta.astype(_F32), chunk, stride))
+    )(folded(q), folded(k), folded(v), _log_decay(g, chunk, stride),
+      gates(beta.astype(_F32), chunk, stride))
     return o.reshape(v.shape), bounds
 
 
@@ -880,28 +822,28 @@ def _pallas_bwd(q, k, v, g, beta, bounds, do, chunk, stride, interpret):
     heads, keys = key_heads * rep, _step(q, v, chunk, stride)[1]
     qk, vo, gate, bound = _specs(q, v, chunk, stride,
                                  lambda i: groups - 1 - i)
-    gates = jax.ShapeDtypeStruct((batch, heads, groups, chunks, chunk), _F32)
+    dgate = jax.ShapeDtypeStruct((batch, heads, groups, chunks, chunk), _F32)
     dq, dk, dv, dgamma, dbeta = pl.pallas_call(
         _kernel(_bwd_kernel, q, v, chunk, stride),
         grid=(batch, key_heads // keys, groups),
         in_specs=[qk, qk, vo, gate, gate, vo, bound],
         out_specs=[qk, qk, vo, gate, gate],
         out_shape=[
-            jax.ShapeDtypeStruct(_folded(q).shape, q.dtype),
-            jax.ShapeDtypeStruct(_folded(k).shape, k.dtype),
-            jax.ShapeDtypeStruct(_folded(v).shape, v.dtype),
-            gates, gates,
+            jax.ShapeDtypeStruct(folded(q).shape, q.dtype),
+            jax.ShapeDtypeStruct(folded(k).shape, k.dtype),
+            jax.ShapeDtypeStruct(folded(v).shape, v.dtype),
+            dgate, dgate,
         ],
         scratch_shapes=[pltpu.VMEM((keys * rep, d_k, d_v), _F32)],
         compiler_params=_params(interpret),
         interpret=interpret,
         name="gated_delta_bwd",
-    )(_folded(q), _folded(k), _folded(v), _log_decay(g, chunk, stride),
-      _gates(beta.astype(_F32), chunk, stride), _folded(do), bounds)
+    )(folded(q), folded(k), folded(v), _log_decay(g, chunk, stride),
+      gates(beta.astype(_F32), chunk, stride), folded(do), bounds)
     # g_t enters every gamma from t to its chunk's end
     dg = jnp.flip(jnp.cumsum(jnp.flip(dgamma, -1), axis=-1), -1)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            _ungated(dg), _ungated(dbeta))
+            ungated(dg), ungated(dbeta))
 
 
 # ---------------------------------------------------------------------------
@@ -922,10 +864,17 @@ def _rule_diff(q, k, v, g, beta, chunk, stride, impl):
     return _forward(q, k, v, g, beta, chunk, stride, impl)[0]
 
 
+# What recomputation keeps of the rule (``ops.remat.remat_policy``): its
+# output [B, T, heads, d_v] in the compute dtype and the state each group of
+# chunks starts from, [B, heads, T / stride, d_k, d_v] float32, no more bytes
+# than the output
+REMAT_NAMES = ("delta_rule_out", "delta_rule_bounds")
+
+
 def _rule_diff_fwd(q, k, v, g, beta, chunk, stride, impl):
     o, bounds = map(ad_checkpoint.checkpoint_name,
                     _forward(q, k, v, g, beta, chunk, stride, impl),
-                    DELTA_REMAT_NAMES)
+                    REMAT_NAMES)
     return o, (q, k, v, g, beta, bounds)
 
 
@@ -944,15 +893,12 @@ _rule_diff.defvjp(_rule_diff_fwd, _rule_diff_bwd)
 
 
 def auto_impl(q, v) -> str:
-    """What ``impl=None`` runs: the kernels on a TPU where the layout fits
-    them (a head's key and value widths whole lane tiles) and the mesh ``q``
-    is traced under has no axis of more than one device but the batch's
-    (the kernel then runs per batch shard, as the flash kernel does); the
-    chunked ``lax.scan`` elsewhere."""
+    """What ``impl=None`` runs: the kernels where the layout fits them (a
+    head's key and value widths whole lane tiles) and ``q`` is traced where
+    a kernel may run (``mosaic.takes_kernels``); the chunked ``lax.scan``
+    elsewhere."""
     fits = q.shape[3] % _LANES == 0 and v.shape[3] % _LANES == 0
-    if jax.default_backend() == "tpu" and fits and not unmapped_mesh_axes(q):
-        return "pallas"
-    return "scan"
+    return "pallas" if fits and takes_kernels(q) else "scan"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "impl"))
@@ -984,11 +930,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
     def rule(q, k, v, g, beta):
         return _rule_diff(q, k, v, g, beta, chunk, stride, impl)
 
-    mesh, axes = _batch_axes(q) if impl != "scan" else (None, ())
-    if axes:
-        rows = PartitionSpec(axes)
-        rule = jax.shard_map(rule, mesh=mesh, in_specs=(rows,) * 5,
-                             out_specs=rows, axis_names=set(axes),
-                             check_vma=False)
+    if impl != "scan":
+        rule = per_batch_shard(rule, q, (True,) * 5, "gated_delta_rule")
     o = rule(q, k, v, g, beta)
     return o[:, :length] if pad else o

@@ -43,7 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private import steptrace
-from ray_tpu.parallel.mesh_utils import traced_mesh_axes
+from ray_tpu.ops.mosaic import takes_unmapped_kernel
 
 
 def switch_gating(logits: jnp.ndarray, capacity: int
@@ -256,15 +256,12 @@ def _unwritten(shape, dtype):
 def _fresh_buffer(x):
     """What ``_walk`` starts its buffers from in the layer of input ``x``:
     ``_unwritten`` on a TPU under no mesh axis of more than one device
-    (the partitioner refuses a Mosaic call, and this one has no batch to
-    be mapped over), else ``jnp.zeros``, which the CPU's grouped matmul,
-    reading what it likes, needs. Asked when a pass of the layer is
-    traced, of the backend and ``x``'s type alone, as
-    ``ops.attention.auto_attention`` asks for its kernel."""
-    _, batch_axes, other_axes = traced_mesh_axes(x)
-    if jax.default_backend() == "tpu" and not (batch_axes or other_axes):
-        return _unwritten
-    return jnp.zeros
+    (``mosaic.takes_unmapped_kernel``: stricter than the other kernels'
+    rule, this call has no batch to be mapped over), else ``jnp.zeros``,
+    which the CPU's grouped matmul, reading what it likes, needs. Asked
+    when a pass of the layer is traced, of the backend and ``x``'s type
+    alone."""
+    return _unwritten if takes_unmapped_kernel(x) else jnp.zeros
 
 
 def _walk(plan, fresh, of_chunk):

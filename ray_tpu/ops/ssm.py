@@ -13,8 +13,9 @@ here walk the sequence in chunks of ``chunk`` positions and keep one
 boundary state a chunk (the state a chunk starts from); the backward pass
 recomputes a chunk's states from its boundary and walks the chunk in
 reverse. One ``custom_vjp`` holds both: the forward rule's outputs are
-named ``ssm_scan_out`` / ``ssm_scan_bounds`` (``ops.attention.remat_policy``
-keeps them, so a recomputed block does not run the forward scan again).
+named ``ssm_scan_out`` / ``ssm_scan_bounds`` (``SCAN_REMAT_NAMES``:
+``ops.remat.remat_policy`` keeps them, so a recomputed block does not run the
+forward scan again).
 
 The kernels (``ssm_scan_fwd`` / ``ssm_scan_bwd``: the benchmark's readers
 find them by these names). Channels lie on lanes, the states on sublanes.
@@ -43,11 +44,12 @@ import jax.numpy as jnp
 from jax import ad_checkpoint, lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec
 
 from ray_tpu._private import steptrace
-from ray_tpu.ops.attention import (SCAN_REMAT_NAMES, _batch_axes,
-                                   unmapped_mesh_axes)
+from ray_tpu.ops.chunks import (NT, TN, as_col, as_row, dot, folded, gates,
+                                grouped, iota, ungated, ungrouped)
+from ray_tpu.ops.mosaic import (compiler_params, per_batch_shard,
+                                takes_kernels)
 
 CHUNK = 128          # positions a chunk: one boundary state each
 _CHANNEL_BLOCK = 512  # lanes a grid step: 8 vector registers of state
@@ -285,11 +287,8 @@ def _record(x, a, chunk, backward: bool):
 
 
 def _params(interpret: bool):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=48 * 2**20)
+    return compiler_params(interpret, ("parallel", "arbitrary", "arbitrary"),
+                           48 * 2**20)
 
 
 def _pallas_fwd(x, delta, a, b, c, skip, chunk, interpret):
@@ -400,6 +399,13 @@ def _scan_diff(x, delta, a, b, c, skip, chunk, impl):
     return _forward(x, delta, a, b, c, skip, chunk, impl)[0]
 
 
+# What recomputation keeps of the scan (``ops.remat.remat_policy``): its
+# output [B, T, channels] in the compute dtype and the state each chunk
+# starts from, [B, T / chunk, states, channels] float32. A block without a
+# scan has no such name, and its program is the one it was.
+SCAN_REMAT_NAMES = ("ssm_scan_out", "ssm_scan_bounds")
+
+
 def _scan_diff_fwd(x, delta, a, b, c, skip, chunk, impl):
     y, bounds = map(ad_checkpoint.checkpoint_name,
                     _forward(x, delta, a, b, c, skip, chunk, impl),
@@ -421,15 +427,12 @@ _scan_diff.defvjp(_scan_diff_fwd, _scan_diff_bwd)
 
 
 def auto_impl(x, a) -> str:
-    """What ``impl=None`` runs: the kernels on a TPU where the layout fits
-    them (channels a multiple of 128, states of 8) and the mesh ``x`` is
-    traced under has no axis of more than one device but the batch's
-    (``data`` / ``fsdp``: the kernel then runs per batch shard, as the
-    flash kernel does); the chunked ``lax.scan`` elsewhere."""
+    """What ``impl=None`` runs: the kernels where the layout fits them
+    (channels a multiple of 128, states of 8) and ``x`` is traced where a
+    kernel may run (``mosaic.takes_kernels``); the chunked ``lax.scan``
+    elsewhere."""
     fits = x.shape[2] % _LANES == 0 and a.shape[1] % 8 == 0
-    if jax.default_backend() == "tpu" and fits and not unmapped_mesh_axes(x):
-        return "pallas"
-    return "scan"
+    return "pallas" if fits and takes_kernels(x) else "scan"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "impl"))
@@ -457,20 +460,16 @@ def selective_scan(x, delta, A, B, C, D, *, chunk: Optional[int] = None,
     def scan(x, delta, a, b, c, skip):
         return _scan_diff(x, delta, a, b, c, skip, chunk, impl)
 
-    mesh, axes = _batch_axes(x) if impl != "scan" else (None, ())
-    if axes:
-        rows, whole = PartitionSpec(axes), PartitionSpec()
-        scan = jax.shard_map(
-            scan, mesh=mesh, in_specs=(rows, rows, whole, rows, rows, whole),
-            out_specs=rows, axis_names=set(axes), check_vma=False)
+    if impl != "scan":
+        scan = per_batch_shard(
+            scan, x, (True, True, False, True, True, False), "selective_scan")
     y = scan(x, delta, A.astype(_F32), B, C, D.astype(_F32))
     return y[:, :length] if pad else y
 
 
 # ===========================================================================
 # Mamba-2's recurrence (SSD: Dao and Gu, arXiv:2405.21060): a scalar decay a
-# head and position. Appended below the selective scan, whose kernels' Mosaic
-# payloads carry the lines above.
+# head and position.
 #
 #     S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) B_t^T        [head_dim, states]
 #     y_t = S_t C_t + D x_t
@@ -483,11 +482,11 @@ def selective_scan(x, delta, A, B, C, D, *, chunk: Optional[int] = None,
 #                                                       for i >= j, else 0
 #     S <- exp(a_C) S + (exp(a_C - a) dt x)^T B
 #
-# ``ops/delta.py``'s scheme without its triangular solve. A decay only ever
-# appears as ``exp`` of a difference that is <= 0. ``a``, ``L``, the state,
-# ``dt x`` and every accumulation are float32; the matmuls take their
-# operands in the inputs' dtype. ``C B^T`` is made once a group and chunk
-# and used by the group's heads.
+# ``ops/delta.py``'s scheme without its triangular solve (what the two share
+# is ``ops/chunks.py``). A decay only ever appears as ``exp`` of a difference
+# that is <= 0. ``a``, ``L``, the state, ``dt x`` and every accumulation are
+# float32; the matmuls take their operands in the inputs' dtype. ``C B^T`` is
+# made once a group and chunk and used by the group's heads.
 #
 # Both paths keep one boundary state every ``ssd_stride_of`` positions (so
 # that the boundaries weigh no more than the output: 256 positions for
@@ -495,7 +494,8 @@ def selective_scan(x, delta, A, B, C, D, *, chunk: Optional[int] = None,
 # last, makes a stride's inner states again from its boundary and walks its
 # chunks in reverse with the state's gradient as the carry. One
 # ``custom_vjp`` holds both; the forward rule's outputs are named
-# ``ssd_out`` / ``ssd_bounds`` for ``ops.attention.remat_policy``.
+# ``ssd_out`` / ``ssd_bounds`` (``SSD_REMAT_NAMES``) for
+# ``ops.remat.remat_policy``.
 #
 # The kernels (``ssd_fwd`` / ``ssd_bwd``: the benchmark's readers find them
 # by these names) address the model's own arrays, x and y as [B, T, heads x
@@ -507,17 +507,6 @@ def selective_scan(x, delta, A, B, C, D, *, chunk: Optional[int] = None,
 # blocks innermost and in order; a group's states, [heads a group x 64,
 # states] float32, are carried from block to block in VMEM scratch.
 # ===========================================================================
-
-from ray_tpu.ops.attention import SSD_REMAT_NAMES  # noqa: E402
-# the chunk scheme's helpers are ``ops/delta.py``'s: the matmul forms, the
-# turns between a chunk's numbers along the lanes and down the sublanes, the
-# [T / stride, stride / chunk, B, chunk, ...] and [B, H, T / block, block /
-# chunk, chunk] views
-from ray_tpu.ops.delta import (  # noqa: E402
-    _NN, _NT, _TN, _as_col as _ssd_col, _as_row as _ssd_row,
-    _dot as _ssd_dot, _folded as _ssd_folded, _gates as _ssd_gates,
-    _grouped as _ssd_grouped, _iota as _ssd_iota, _ungated as _ssd_ungated,
-    _ungrouped as _ssd_ungrouped)
 
 SSD_CHUNK = 128       # positions a chunk, as published
 _SSD_BLOCK = 1024     # positions a grid step at most
@@ -591,7 +580,7 @@ def _ssd_stride(state, inputs, skip, rep):
 
 
 def _ssd_twin_operands(x, dt, la, b, c, chunk, stride):
-    return tuple(_ssd_grouped(t.astype(_F32), stride, chunk)
+    return tuple(grouped(t.astype(_F32), stride, chunk)
                  for t in (x, dt, la, b, c))
 
 
@@ -610,7 +599,7 @@ def _ssd_twin_fwd(x, dt, la, b, c, skip, chunk, stride):
     zero = jnp.zeros((batch, heads, head_dim, states), _F32)
     _, (y, bounds) = lax.scan(
         one, zero, _ssd_twin_operands(x, dt, la, b, c, chunk, stride))
-    return _ssd_ungrouped(y), jnp.moveaxis(bounds, 0, 1)
+    return ungrouped(y), jnp.moveaxis(bounds, 0, 1)
 
 
 def _ssd_twin_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride):
@@ -633,9 +622,9 @@ def _ssd_twin_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride):
               jnp.zeros_like(skip)),
         (*_ssd_twin_operands(x, dt, la, b, c, chunk, stride),
          jnp.moveaxis(bounds, 1, 0),
-         _ssd_grouped(dy.astype(_F32), stride, chunk)),
+         grouped(dy.astype(_F32), stride, chunk)),
         reverse=True)
-    return (*map(_ssd_ungrouped, grads), dskip)
+    return (*map(ungrouped, grads), dskip)
 
 
 # --- the kernels -------------------------------------------------------------
@@ -650,12 +639,12 @@ def _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, scores, t, ci, rows, chunk,
     wanted."""
     width = 2 * _SSD_HEAD
     lanes = slice(t * width, (t + 1) * width)
-    row, col = _ssd_iota(chunk)
+    row, col = iota(chunk)
     seen = row >= col
     first = lax.broadcasted_iota(jnp.int32, (chunk, width), 1) < _SSD_HEAD
     a_row = [a_ref[2 * t + u, pl.ds(ci, 1), :] for u in (0, 1)]    # [1, C]
-    a_col = [_ssd_col(r, chunk) for r in a_row]                    # [C, 1]
-    dt_col = [_ssd_col(dt_ref[2 * t + u, pl.ds(ci, 1), :], chunk)
+    a_col = [as_col(r, chunk) for r in a_row]                    # [C, 1]
+    dt_col = [as_col(dt_ref[2 * t + u, pl.ds(ci, 1), :], chunk)
               for u in (0, 1)]
     last = [jnp.sum(jnp.where(col[:1] == chunk - 1, r, 0.0), axis=1,
                     keepdims=True) for r in a_row]                 # [1, 1]
@@ -709,18 +698,18 @@ def _ssd_fwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, y_ref,
         ci = s * per_stride + r
         rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
         b, c = b_ref[rows, :], c_ref[rows, :]
-        scores = _ssd_dot(c, b, _NT)                               # [C, C]
+        scores = dot(c, b, NT)                               # [C, C]
         for t in range(pairs):
             p = _ssd_pair(a_ref, dt_ref, x_ref, skip_ref, scores, t, ci,
                           rows, chunk, b.shape[1])
             dt = p["dt"]
             state = s_scr[t]
-            within = p["by_lane"]([_ssd_dot(w.astype(dt), p["v"])
+            within = p["by_lane"]([dot(w.astype(dt), p["v"])
                                    for w in p["weights"]])
-            y = (within + p["grow"] * _ssd_dot(c, state.astype(dt), _NT)
+            y = (within + p["grow"] * dot(c, state.astype(dt), NT)
                  + p["skip"] * p["x32"])
             y_ref[rows, p["lanes"]] = y.astype(y_ref.dtype)
-            s_scr[t] = p["last_rows"] * state + _ssd_dot(p["ve"], b, _TN)
+            s_scr[t] = p["last_rows"] * state + dot(p["ve"], b, TN)
 
     _ssd_block_loops(steps, per_stride, body, reverse=False)
 
@@ -753,12 +742,12 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, dy_ref,
                                   ci, rows, chunk, b.shape[1])
                     starts_scr[q + 1, t] = (
                         p["last_rows"] * starts_scr[q, t]
-                        + _ssd_dot(p["ve"], b, _TN))
+                        + dot(p["ve"], b, TN))
             return
         ci = s * per_stride + r
         rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
         b, c = b_ref[rows, :], c_ref[rows, :]
-        scores = _ssd_dot(c, b, _NT)
+        scores = dot(c, b, NT)
         dscores = jnp.zeros((chunk, chunk), _F32)
         db = jnp.zeros(b.shape, _F32)
         dc = jnp.zeros(c.shape, _F32)
@@ -775,20 +764,20 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, dy_ref,
             dy = dy_ref[rows, p["lanes"]]
             dy32 = dy.astype(_F32)
             # dv = M^T dy + e o (B dS'^T)
-            b_ds = _ssd_dot(b, dstate_dt, _NT)                   # [C, 128]
-            dv = (by_lane([_ssd_dot(cast(w), dy, _TN)
+            b_ds = dot(b, dstate_dt, NT)                   # [C, 128]
+            dv = (by_lane([dot(cast(w), dy, TN)
                            for w in p["weights"]]) + p["to_end"] * b_ds)
             # dM = mask(dy v^T) a head: the other head's lanes set to zero
-            dweights = [jnp.where(p["seen"], _ssd_dot(cast(h), p["v"], _NT),
+            dweights = [jnp.where(p["seen"], dot(cast(h), p["v"], NT),
                                   0.0) for h in halves(dy32)]
             for dw, d in zip(dweights, p["decay"]):
                 dscores = dscores + dw * d
             through = [dw * w for dw, w in zip(dweights, p["weights"])]
             # the state's part of y, and what the decays to the end carry
-            inter = p["grow"] * _ssd_dot(c, start_dt, _NT)
+            inter = p["grow"] * dot(c, start_dt, NT)
             dy_grown = cast(dy32 * p["grow"])
-            dc = dc + _ssd_dot(dy_grown, start_dt)
-            db = db + _ssd_dot(p["ve"], dstate_dt)
+            dc = dc + dot(dy_grown, start_dt)
+            db = db + dot(p["ve"], dstate_dt)
             carried = halves(p["v32"] * p["to_end"] * b_ds)
             grown = halves(dy32 * inter)
             direct = halves(dv * p["x32"])
@@ -802,19 +791,19 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, skip_ref, dy_ref,
                              rowsum(kept[u]), axis=0, keepdims=True))
                 da_col = rowsum(through[u]) + rowsum(grown[u]) - carried_u
                 da_ref[head, pl.ds(ci, 1), :] = (
-                    _ssd_row(da_col, chunk)
+                    as_row(da_col, chunk)
                     - jnp.sum(through[u], axis=0, keepdims=True)
                     + jnp.where(lane == chunk - 1, dlast, 0.0))
-                ddt_ref[head, pl.ds(ci, 1), :] = _ssd_row(
+                ddt_ref[head, pl.ds(ci, 1), :] = as_row(
                     rowsum(direct[u]), chunk)
-                dskip_ref[head, pl.ds(ci, 1), :] = _ssd_row(
+                dskip_ref[head, pl.ds(ci, 1), :] = as_row(
                     rowsum(skipped[u]), chunk)
             dx_ref[rows, p["lanes"]] = (
                 p["dt_lane"] * dv + p["skip"] * dy32).astype(dx_ref.dtype)
-            ds_scr[t] = p["last_rows"] * dstate + _ssd_dot(dy_grown, c, _TN)
+            ds_scr[t] = p["last_rows"] * dstate + dot(dy_grown, c, TN)
         dscores_dt = dscores.astype(b.dtype)
-        dc_ref[rows, :] = (dc + _ssd_dot(dscores_dt, b)).astype(dc_ref.dtype)
-        db_ref[rows, :] = (db + _ssd_dot(dscores_dt, c, _TN)).astype(
+        dc_ref[rows, :] = (dc + dot(dscores_dt, b)).astype(dc_ref.dtype)
+        db_ref[rows, :] = (db + dot(dscores_dt, c, TN)).astype(
             db_ref.dtype)
 
     _ssd_block_loops(steps, per_stride, body, reverse=True)
@@ -837,11 +826,8 @@ def _ssd_geometry(x, b, chunk, stride):
 
 
 def _ssd_params(interpret: bool):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=64 * 2**20)
+    return compiler_params(interpret, ("parallel", "parallel", "arbitrary"),
+                           64 * 2**20)
 
 
 def _ssd_specs(x, b, chunk, stride, block_of):
@@ -867,9 +853,9 @@ def _ssd_kernel_operands(x, dt, la, b, c, skip, chunk, block):
     """The arrays as the kernels take them: the model's own x, B, C; the
     log-decay summed from each chunk's start and dt, a chunk's numbers along
     the lanes; D spread over its head's lanes."""
-    gates = lambda t: _ssd_gates(t.astype(_F32), chunk, block)
-    return (_ssd_folded(x), _ssd_folded(b), _ssd_folded(c),
-            jnp.cumsum(gates(la), axis=-1), gates(dt),
+    by_chunk = lambda t: gates(t.astype(_F32), chunk, block)
+    return (folded(x), folded(b), folded(c),
+            jnp.cumsum(by_chunk(la), axis=-1), by_chunk(dt),
             jnp.repeat(skip.astype(_F32), x.shape[3])[None, :])
 
 
@@ -885,7 +871,7 @@ def _ssd_pallas_fwd(x, dt, la, b, c, skip, chunk, stride, interpret):
         in_specs=[xs, bc, bc, gate, gate, lanes],
         out_specs=[xs, bound],
         out_shape=[
-            jax.ShapeDtypeStruct(_ssd_folded(x).shape, x.dtype),
+            jax.ShapeDtypeStruct(folded(x).shape, x.dtype),
             jax.ShapeDtypeStruct((batch, blocks * steps, heads // 2,
                                   2 * head_dim, states), _F32),
         ],
@@ -906,7 +892,7 @@ def _ssd_pallas_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride,
     block = steps * stride
     xs, bc, gate, lanes, bound = _ssd_specs(x, b, chunk, stride,
                                             lambda i: blocks - 1 - i)
-    gates = jax.ShapeDtypeStruct(
+    dgate = jax.ShapeDtypeStruct(
         (batch, heads, blocks, block // chunk, chunk), _F32)
     operands = _ssd_kernel_operands(x, dt, la, b, c, skip, chunk, block)
     dx, db, dc, da, ddt, dskip = pl.pallas_call(
@@ -919,7 +905,7 @@ def _ssd_pallas_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride,
             jax.ShapeDtypeStruct(operands[0].shape, x.dtype),
             jax.ShapeDtypeStruct(operands[1].shape, b.dtype),
             jax.ShapeDtypeStruct(operands[2].shape, c.dtype),
-            gates, gates, gates,
+            dgate, dgate, dgate,
         ],
         scratch_shapes=[
             pltpu.VMEM((pairs, 2 * head_dim, states), _F32),
@@ -928,11 +914,11 @@ def _ssd_pallas_bwd(x, dt, la, b, c, skip, bounds, dy, chunk, stride,
         compiler_params=_ssd_params(interpret),
         interpret=interpret,
         name="ssd_bwd",
-    )(*operands, _ssd_folded(dy), bounds.reshape(
+    )(*operands, folded(dy), bounds.reshape(
         batch, blocks * steps, heads // 2, 2 * head_dim, states))
     # la_t enters every a from t to its chunk's end
     dla = jnp.flip(jnp.cumsum(jnp.flip(da, -1), axis=-1), -1)
-    return (dx.reshape(x.shape), _ssd_ungated(ddt), _ssd_ungated(dla),
+    return (dx.reshape(x.shape), ungated(ddt), ungated(dla),
             db.reshape(b.shape), dc.reshape(c.shape),
             dskip.sum((0, 2, 3, 4)))
 
@@ -951,6 +937,13 @@ def _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _ssd_diff(x, dt, la, b, c, skip, chunk, stride, impl):
     return _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl)[0]
+
+
+# What recomputation keeps of the scalar-decay scan
+# (``ops.remat.remat_policy``): its output [B, T, heads, head_dim] in the
+# compute dtype and the state each stride starts from, [B, T / stride, heads,
+# head_dim, states] float32, no more bytes than the output
+SSD_REMAT_NAMES = ("ssd_out", "ssd_bounds")
 
 
 def _ssd_diff_fwd(x, dt, la, b, c, skip, chunk, stride, impl):
@@ -976,18 +969,15 @@ _ssd_diff.defvjp(_ssd_diff_fwd, _ssd_diff_bwd)
 
 
 def ssd_auto_impl(x, b) -> str:
-    """What ``ssd_scan(impl=None)`` runs: the kernels on a TPU where the
-    layout fits them (heads 64 wide, an even number of them a group, the
-    states whole lane tiles) and the mesh ``x`` is traced under has no axis
-    of more than one device but the batch's (the kernel then runs per batch
-    shard, as the flash kernel does); the chunked twin elsewhere."""
+    """What ``ssd_scan(impl=None)`` runs: the kernels where the layout fits
+    them (heads 64 wide, an even number of them a group, the states whole
+    lane tiles) and ``x`` is traced where a kernel may run
+    (``mosaic.takes_kernels``); the chunked twin elsewhere."""
     heads, head_dim = x.shape[2:]
     groups, states = b.shape[2:]
     fits = (head_dim == _SSD_HEAD and heads % (2 * groups) == 0
             and states % _LANES == 0)
-    if jax.default_backend() == "tpu" and fits and not unmapped_mesh_axes(x):
-        return "pallas"
-    return "scan"
+    return "pallas" if fits and takes_kernels(x) else "scan"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "impl"))
@@ -1018,11 +1008,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: Optional[int] = None,
     def scan(x, dt, la, b, c, skip):
         return _ssd_diff(x, dt, la, b, c, skip, chunk, stride, impl)
 
-    mesh, axes = _batch_axes(x) if impl != "scan" else (None, ())
-    if axes:
-        rows, whole = PartitionSpec(axes), PartitionSpec()
-        scan = jax.shard_map(
-            scan, mesh=mesh, in_specs=(rows,) * 5 + (whole,),
-            out_specs=rows, axis_names=set(axes), check_vma=False)
+    if impl != "scan":
+        scan = per_batch_shard(scan, x, (True,) * 5 + (False,), "ssd_scan")
     y = scan(x, dt, dt * A.astype(_F32), B, C, D.astype(_F32))
     return y[:, :length] if pad else y

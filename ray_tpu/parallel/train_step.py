@@ -58,6 +58,12 @@ class _StepByLayout:
         return self.trace(params, opt_state, batch).lower()
 
 
+# The name ``_StepByLayout``'s step is jitted under, for whoever looks for its
+# compilations in the step-telemetry ring (``steptrace`` records of kind
+# ``compile``).
+STEP_NAME = "step"
+
+
 def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
     """Jitted ``(params, opt_state, batch) -> (params, opt_state, loss)``
     over ``loss_fn(params, batch)``; with ``has_aux`` the loss function
@@ -91,15 +97,6 @@ def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
             out_shardings=(*shardings, None) if shardings else None)
 
     return _StepByLayout(jitted)
-
-
-# The name ``_StepByLayout``'s step is jitted under, for whoever looks for its
-# compilations in the step-telemetry ring (``steptrace`` records of kind
-# ``compile``). It stands below the builder because the kernels' Mosaic
-# payload carries the line of every frame above a ``pallas_call``, the lines
-# of ``step`` and ``_StepByLayout.lower`` among them: a line added above them
-# moves every cell's compiled program and cache key.
-STEP_NAME = "step"
 
 
 def state_shardings(params, opt_state, param_shardings):

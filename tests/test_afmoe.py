@@ -141,7 +141,7 @@ def test_the_model_is_the_reference(attention, monkeypatch, request):
     keys a head, the boundary the cell's calls take since PR 55: its
     output, dQ, dK and dV written into the model's [B, T, H x 128] arrays
     and the gate's cotangent read from one."""
-    from ray_tpu.ops import attention as ops_attention
+    from ray_tpu.ops import attention as ops_attention, flash_kernels
 
     more = {}
     if attention == "scan":
@@ -152,7 +152,7 @@ def test_the_model_is_the_reference(attention, monkeypatch, request):
                 window=window, impl="scan", block_k=8).transpose(0, 2, 1, 3))
     elif attention == "kernel_results":
         more = {"head_dim": 128}
-        monkeypatch.setattr(ops_attention, "_MAX_RESIDENT", 8)
+        monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", 8)
         monkeypatch.setattr(
             ops_attention, "flash_attention", functools.partial(
                 ops_attention.flash_attention, impl="pallas_interpret",
@@ -237,7 +237,7 @@ def test_recomputation_changes_no_value_and_keeps_the_kernels_output(
     """With ``remat`` the gradient is the same to the bit; on a TPU (where
     ``auto`` is the kernel at head width 128) a step's jaxpr holds one
     forward and one backward call a layer, windowed in the window layers,
-    and no forward call again: ``ops.attention.remat_policy``."""
+    and no forward call again: ``ops.remat.remat_policy``."""
     config, model, params, batch = _small()
     grad = lambda c: jax.jit(jax.grad(lambda p: afmoe.loss_fn(
         p, afmoe.Afmoe(c), batch)[0]))

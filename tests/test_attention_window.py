@@ -18,9 +18,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, flash_kernels
 from ray_tpu.ops.attention import (attention_reference, causal_self_attention,
-                                   flash_attention, grid_block_kinds)
+                                   flash_attention)
+from ray_tpu.ops.flash_kernels import grid_block_kinds
 from tests.conftest import kernel_calls, kernel_whiles
 
 
@@ -119,7 +120,7 @@ def test_window_and_grouped_heads_match_reference(monkeypatch, case, impl,
     key-value head are sums over its group of query heads."""
     heads, kv, length, d, d_v, bq, bk, resident, window = _CASES[case]
     if resident:
-        monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+        monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", resident)
         jax.clear_caches()  # flash_attention is jitted: the rule is read
     q, k, v, w = _operands(heads, kv, length, d, d_v, dtype)
     f32 = lambda x: x.astype(jnp.float32)
@@ -176,8 +177,8 @@ def test_the_dq_sum_moves_in_pieces(monkeypatch, case):
     blocks, loops) and down (diagonal ones), give dQ, dK and dV as the
     reference does."""
     heads, kv, d, window, rows = _PIECES[case]
-    monkeypatch.setattr(attention, "_MAX_RESIDENT", 64)
-    monkeypatch.setattr(attention, "_COPY_BYTES", 4 * d * 16 * rows)
+    monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", 64)
+    monkeypatch.setattr(flash_kernels, "_COPY_BYTES", 4 * d * 16 * rows)
     jax.clear_caches()
     q, k, v, w = _operands(heads, kv, 256, d, 128)
     flash = lambda q, k, v: flash_attention(
@@ -356,7 +357,7 @@ def test_the_backward_call_hands_back_one_dq(case):
             jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
     b, t, d = q.shape
     name = "flash_bwd" if window is None else f"flash_bwd_w{window}"
-    several = k.shape[1] > attention._MAX_RESIDENT
+    several = k.shape[1] > flash_kernels._MAX_RESIDENT
     dq, dk, dv = _kernel_outputs(jaxpr)[name]
     assert dq == ((b, d, t), jnp.float32 if several else jnp.bfloat16)
     assert dk == (k.shape, jnp.bfloat16) and dv == (v.shape, jnp.bfloat16)
@@ -534,7 +535,7 @@ def test_a_call_over_several_blocks_by_either_boundary_matches_reference(
     d], [B x H_kv, T, d] and V^T that they were; else every operand and
     result is the parent's."""
     heads, kv, length, d, d_v, window, resident, results = _RESULTS[case]
-    monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+    monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", resident)
     assert attention.results_in_model_arrays(length, d, d_v) == results
     assert not attention.heads_a_lane_tile(length, heads, kv, d, d_v)
     monkeypatch.setattr(attention, "flash_attention", functools.partial(

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import delta
-from ray_tpu.ops.attention import remat_policy
+from ray_tpu.ops.remat import remat_policy
 from ray_tpu.ops.delta import gated_delta_rule
 from tests.conftest import kernel_calls
 
@@ -262,7 +262,7 @@ def test_kernels_are_named_and_recorded():
 
 
 def test_recomputation_keeps_the_rule():
-    """Under ``ops.attention.remat_policy`` a recomputed function's
+    """Under ``ops.remat.remat_policy`` a recomputed function's
     backward pass holds the backward kernel and no second forward one: the
     output and the boundary states are kept by their names. Without the
     policy the forward kernel runs again."""

@@ -17,7 +17,7 @@ import pytest
 from perfbench import compare, worker
 from ray_tpu._private import steptrace
 from ray_tpu.models import gpt2, mla_moe
-from ray_tpu.ops import attention, moe, xent
+from ray_tpu.ops import attention, flash_kernels, moe, xent
 from tests.conftest import kernel_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -518,9 +518,9 @@ def test_the_xla_path_takes_values_of_another_width():
 def test_equal_widths_ask_the_compiler_for_nothing_new():
     """Up to 128 lanes the kernels' compiler parameters are what they were:
     no VMEM limit of their own."""
-    assert attention._compiler_params(False, 64).vmem_limit_bytes is None
-    assert attention._compiler_params(False, 128).vmem_limit_bytes is None
-    assert attention._compiler_params(False, 192).vmem_limit_bytes == 2**25
+    assert flash_kernels._compiler_params(False, 64).vmem_limit_bytes is None
+    assert flash_kernels._compiler_params(False, 128).vmem_limit_bytes is None
+    assert flash_kernels._compiler_params(False, 192).vmem_limit_bytes == 2**25
 
 
 # ----------------------------------------------------------------------
@@ -643,7 +643,7 @@ def _gradient(config, batch):
 def test_a_recomputed_block_keeps_what_only_the_kernel_makes(
         monkeypatch, policy, forward):
     """Under ``remat`` a block's backward pass reruns its projections and
-    not the forward kernel: ``ops.attention.remat_policy`` keeps the
+    not the forward kernel: ``ops.remat.remat_policy`` keeps the
     kernel's output and log-sum-exp by name. The gradient's jaxpr holds
     the forward kernel once a block (three layers and the prediction
     module) and twice without the policy."""

@@ -166,11 +166,11 @@ def test_flash_pallas_matches_reference(monkeypatch, case, causal, dtype):
     kernel's matmuls take the inputs' dtype, so the tolerance follows it:
     float32 is held entry by entry (a wrong small entry, such as a masked
     row's, shows), bfloat16 against the largest reference entry."""
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import flash_kernels
 
     bh, q_len, k_len, d, d_v, block_q, block_k, resident = _PALLAS_CASES[case]
     if resident:
-        monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+        monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", resident)
         jax.clear_caches()  # flash_attention is jitted: the rule is read
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
     q = jax.random.normal(ks[0], (bh, q_len, d), dtype)
@@ -224,10 +224,10 @@ def test_flash_walk_by_kind_agrees_with_the_loop_walk(monkeypatch, case):
     blocks walked in loops with traced bounds (the fallback, forced on the
     same inputs) give the same forward and the same three gradients in
     float32."""
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import flash_kernels
 
     bh, q_len, k_len, d, d_v, block_q, block_k, resident = _PALLAS_CASES[case]
-    monkeypatch.setattr(attention, "_MAX_RESIDENT", resident)
+    monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", resident)
     ks = jax.random.split(jax.random.PRNGKey(11), 4)
     q = jax.random.normal(ks[0], (bh, q_len, d))
     k = jax.random.normal(ks[1], (bh, k_len, d))
@@ -244,7 +244,8 @@ def test_flash_walk_by_kind_agrees_with_the_loop_walk(monkeypatch, case):
     try:
         for walk in ("by_kind", "looped"):
             if walk == "looped":
-                monkeypatch.setattr(attention, "_grid_kinds", _all_looped)
+                monkeypatch.setattr(flash_kernels, "_grid_kinds",
+                                    _all_looped)
             jax.clear_caches()  # flash_attention is jitted
             jaxpr = jax.make_jaxpr(out_and_grads)(q, k, v)
             assert bool(kernel_whiles(jaxpr)) == (walk == "looped")
@@ -261,7 +262,7 @@ def test_flash_grid_block_kinds():
     in a loop, and the traced kernels hold no loop with a traced bound;
     lengths that differ keep the loops."""
     from ray_tpu._private import steptrace
-    from ray_tpu.ops.attention import grid_block_kinds
+    from ray_tpu.ops.flash_kernels import grid_block_kinds
 
     kinds = lambda *n: dict(zip(("whole", "diagonal", "dead", "looped"), n))
     for backward in (False, True):
@@ -309,7 +310,8 @@ def test_flash_grid_block_kinds():
 def test_flash_block_rule():
     """Tiles are multiples of 128 or the whole length; a grid step holds
     the whole sequence up to ``_MAX_RESIDENT``."""
-    from ray_tpu.ops.attention import _BWD_TILES, _FWD_TILES, _block_sizes
+    from ray_tpu.ops.flash_kernels import (_BWD_TILES, _FWD_TILES,
+                                           _block_sizes)
 
     fwd = lambda *a: _block_sizes(*a, _FWD_TILES)
     assert fwd(1024, 1024, None, None) == (512, 512, 1024, 1024)
@@ -397,7 +399,7 @@ def _gpt2_gradient(seq=128, **kw):
 def test_the_kernels_names_keep_nothing_without_a_policy(
         monkeypatch, what, forward):
     """The forward rule names the kernel's output and log-sum-exp for
-    ``ops.attention.remat_policy``. GPT-2 recomputes its blocks under that
+    ``ops.remat.remat_policy``. GPT-2 recomputes its blocks under that
     policy: the gradient runs the forward kernel once a block, recomputed
     or not, and the backward kernel once. Without a policy a name is the
     identity: ``jax.checkpoint`` round the kernel alone runs its forward

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import conv, gated_short_conv
-from ray_tpu.ops.attention import remat_policy
+from ray_tpu.ops.remat import remat_policy
 from tests.conftest import kernel_calls
 
 
@@ -184,7 +184,7 @@ def test_kernels_are_named_and_recorded():
 
 
 def test_recomputation_runs_the_forward_kernel_again():
-    """Under ``ops.attention.remat_policy`` a recomputed layer makes the
+    """Under ``ops.remat.remat_policy`` a recomputed layer makes the
     convolution's output again (nothing of it is named for the policy): two
     forward kernels and one backward in the gradient."""
     bcx, taps, _ = _operands(1, 32, 128)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops import ssd_scan, ssm
-from ray_tpu.ops.attention import remat_policy
+from ray_tpu.ops.remat import remat_policy
 from tests.conftest import kernel_calls
 
 F32 = jnp.float32
@@ -198,7 +198,7 @@ def test_kernels_are_named_and_recorded():
 
 
 def test_recomputation_keeps_the_scan():
-    """Under ``ops.attention.remat_policy`` a recomputed function's
+    """Under ``ops.remat.remat_policy`` a recomputed function's
     backward pass does not run the forward kernel again: the output and the
     boundary states are named and kept."""
     xs = _operands(1, 256, 4, 1, seed=7)[:6]

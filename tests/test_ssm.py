@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import selective_scan, ssm
-from ray_tpu.ops.attention import remat_policy
+from ray_tpu.ops.remat import remat_policy
 from tests.conftest import kernel_calls
 
 
@@ -126,7 +126,7 @@ def test_kernels_are_named_and_recorded():
 
 
 def test_recomputation_keeps_the_scan():
-    """Under ``ops.attention.remat_policy`` a recomputed function's
+    """Under ``ops.remat.remat_policy`` a recomputed function's
     backward pass holds the backward kernel and no second forward one: the
     output and the boundary states are kept by their names. Without the
     policy the forward kernel runs again."""

@@ -99,14 +99,13 @@ def _train_step_args(attention, state_sharding, batch_sharding, batch=BATCH):
 
 
 # sha256 (first 16 digits) of the jaxpr that each accepted cell's step lowers
-# from, but for the window cell's, whose attention calls PR 55 moved: the
-# program as jax hands it to the lowering, every kernel's body in it, with no
-# file and no line (the Mosaic payloads in the lowered text carry the line of
-# every frame, so that text moves with any edit above a kernel). They are
-# the parent's of PR 55 (commit 9501e07), read there under this file: a PR
-# that means to leave a cell's step alone finds here, before any chip call,
-# whether it did; one that means to move it re-anchors the cell's digest (it
-# is printed) and says so.
+# from: the program as jax hands it to the lowering, every kernel's body in
+# it, with no file and no line (the Mosaic payloads in the lowered text carry
+# the line of every frame, so that text moves with any edit above a kernel).
+# They are the parent's of PR 55 (commit 9501e07) but where a later PR is
+# named, read there under this file: a PR that means to leave a cell's step
+# alone finds here, before any chip call, whether it did; one that means to
+# move it re-anchors the cell's digest (it is printed) and says so.
 _HELD_PROGRAMS = {
     "gpt2-124m.step": "d12d86d6c3b58f16",
     "gpt2-xl.step-fsdp4": "dd746428dd36d2db",
@@ -115,6 +114,7 @@ _HELD_PROGRAMS = {
     "lfm2-8b-a1b.step-8k": "26754d67a7295565",
     "qwen3-next-80b-a3b.step-8k": "324cebd6c77525bf",   # PR 59
     "nemotron-3-nano-30b-a3b.step-8k": "04b7b0bf030790b4",   # PR 58
+    "trinity-mini.step-16k": "577d858deda03511",   # PR 59
 }
 
 
@@ -436,7 +436,7 @@ def test_gpt2_xl_fsdp4_step_is_zero3(topo, no_compile_cache, on_tpu):
     vectors that are replicated at rest: no weight matrix is all-reduced);
     nothing is resharded by all-to-all; attention is the Pallas kernel per
     batch shard, 96 calls with the recomputation (a recomputed block keeps
-    the kernel's output and log-sum-exp, ``ops.attention.remat_policy``,
+    the kernel's output and log-sum-exp, ``ops.remat.remat_policy``,
     and runs no forward call again: the kept copies are 4.4 GiB of the
     plan, which the upper limit holds 1.5 GiB under the chip); and every
     asynchronous collective is an all-gather, which
@@ -593,7 +593,7 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     7.6 GiB as arguments; attention is the Pallas kernel at keys 192 and
     values 128 wide (12 calls and not 18: six blocks, forward and backward,
     the recomputed blocks keeping the forward's output and log-sum-exp by
-    ``ops.attention.remat_policy``: 0.76 GiB, with which the plan fell); the
+    ``ops.remat.remat_policy``: 0.76 GiB, with which the plan fell); the
     routed experts are the compiler's grouped-matmul kernel over a row
     buffer of which loops walk what holds the pairs present; and no array
     is shaped like a [tokens, experts, capacity] dispatch or a [T, T] score
@@ -699,7 +699,7 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     the Pallas kernel over 32 query heads and 4 key-value heads, whose keys
     and values go in as they are, [4, 16384, 128]: four windowed calls
     (``flash_*_w2048``) and one without a window, forward and backward, and
-    no forward call again (``ops.attention.remat_policy``). Each traced call
+    no forward call again (``ops.remat.remat_policy``). Each traced call
     wrote its grid's blocks by kind into the runtime's ring: 8 x 8 a head,
     under the window 8 diagonal, 7 trailing, 49 dead, without it 28 whole,
     8 diagonal, 28 dead, none walked in a loop with traced bounds. Each
@@ -721,7 +721,8 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     steptrace.set_enabled(True)
     steptrace.reset()
     try:
-        lowered = built.step.lower(
+        lowered = _lower_held(
+            "trinity-mini.step-16k", built.step,
             params, opt_state, {"input_ids": ids, "labels": ids})
         counters = [e for e in steptrace.chrome_trace(
             steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
@@ -821,7 +822,7 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
     the 14.5 GiB that ISSUE 48 set for this length (11.55 read) with the
     state's 8.37 GB as arguments. The two state-space layers' scans are the
     Pallas kernels, once forward and once backward each
-    (``ops.attention.remat_policy`` keeps the output and the boundary
+    (``ops.remat.remat_policy`` keeps the output and the boundary
     states); each of the three differential layers is two flash calls
     forward and two backward, 20 query heads on 10 key-value heads with keys
     64 and values 128 wide, the window layer's named after its 512 keys.
@@ -919,7 +920,7 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
     forward calls a layer (the recomputed block makes the output again:
     nothing of it is kept) and one backward; attention is one flash call
     forward and one backward, 32 query heads on 8 key-value heads of 64,
-    whose output ``ops.attention.remat_policy`` keeps. Each traced call
+    whose output ``ops.remat.remat_policy`` keeps. Each traced call
     wrote its record into the runtime's ring. No array is shaped like a [T,
     T] score matrix, and the vocabulary's 16,384 rows equal no other
     dimension of the program."""
@@ -1014,7 +1015,7 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     rule is the Pallas kernel pair over [2, 8192, 2048] keys and [2, 8192,
     4096] values, one forward and one backward call a layer (the recomputed
     block keeps the forward's output and boundary states by
-    ``ops.attention.remat_policy``); every linear layer's four-tap
+    ``ops.remat.remat_policy``); every linear layer's four-tap
     convolution is the pair ``causal_conv_fwd`` / ``causal_conv_bwd`` over
     [2, 8192, 8192] (PR 57: forward, forward again in the recomputed block,
     backward; the plan did not move); attention is one flash call forward and
@@ -1127,7 +1128,7 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
     block's scan is the Pallas pair ``ssd_fwd`` / ``ssd_bwd`` over the
     model's own [2, 8192, 4096] and [2, 8192, 1024] arrays, one forward and
     one backward call a block (the recomputed block keeps the forward's
-    output and boundary states by ``ops.attention.remat_policy``); its
+    output and boundary states by ``ops.remat.remat_policy``); its
     convolutions (x, B and C each on its own, with the bias) are the pair
     ``causal_conv_fwd`` / ``causal_conv_bwd``: forward, forward again in the
     recomputed block, backward; attention is one flash call each way. Each
