@@ -32,7 +32,8 @@ def kind_of(name: str, short: str, tokens: int, k: int, d: int) -> str:
     op, pairs = short.split(" ")[0], tokens * k
     widths = "|".join(str(d // parts) for parts in (1, 2, 4))
     result = name.split("(")[0]
-    if op.startswith("ragged-dot"):
+    # XLA's own kernel, or since PR 61 ``ops/grouped_matmul.py``'s
+    if op.startswith(("ragged-dot", "grouped_matmul")):
         return "grouped matmuls"
     if op.startswith("to_tokens"):
         return "to_tokens kernel"

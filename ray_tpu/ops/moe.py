@@ -22,12 +22,15 @@ Beside them, the layer that drops nothing (PR 31): ``topk_routing`` (sigmoid
 or softmax scores, selection by score + bias, k experts a token, weights
 normalised over the k) and ``held_expert_ffn``, which is told which slice of
 the experts it holds, sorts the token-expert pairs that fall on them and runs
-grouped SwiGLU matmuls over the sorted rows (``jax.lax.ragged_dot``, which
-the TPU compiler lowers to its own grouped-matmul kernel whose grid follows
-the rows present), over as much of the row buffer as holds them, chunk by
-chunk (``row_buffer_rungs``, PR 32), and takes the rows back to their tokens
-with a Pallas kernel that reads the rows that hold a pair (``to_tokens``, PR
-53: on a TPU under no mesh; a gather of tokens x k rows elsewhere). Every
+grouped SwiGLU matmuls over the sorted rows (on a TPU under no mesh the
+Pallas kernels of ``ops/grouped_matmul.py``, whose grids walk the tiles that
+hold a group's rows, PR 61; elsewhere ``jax.lax.ragged_dot``, which the TPU
+compiler lowers to a grouped-matmul kernel of its own, in tiles of 512 x 128
+x 128), what is no matmul over as much of the row buffer as holds the pairs,
+chunk by chunk (``row_buffer_rungs``, PR 32), and takes the rows back to their
+tokens with a Pallas kernel that reads the rows that hold a pair
+(``to_tokens``, PR 53: on a TPU under no mesh; a gather of tokens x k rows
+elsewhere). Every
 pair of a held expert is computed, whatever the routing; what absent experts
 would add is left out and nothing stands in for them or for their exchange.
 """
@@ -43,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private import steptrace
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.mosaic import takes_unmapped_kernel
 
 
@@ -277,8 +281,10 @@ def _walk(plan, fresh, of_chunk):
     whole, rows past the count inside it from the padded ``order``: real,
     finite values. So a reader may take the walked chunks as they are and
     nothing of the rest: a grouped matmul, which reads its groups' rows a
-    tile of 512 at a time (a chunk at the cells' sizes is 8 such tiles, so
-    a tile that holds a pair lies inside a walked chunk); another walk over
+    tile at a time (512 rows ``ragged_dot``'s, 256 the kernels' of
+    ``ops/grouped_matmul.py``: a chunk at the cells' sizes is whole tiles,
+    so a tile that holds a pair lies inside a walked chunk; the kernels
+    mask by rows what a tile holds outside a group besides); another walk over
     the same plan, a chunk at a time; the kernel ``to_tokens``, which sets to
     zero what a chunk of its own holds outside an expert's range; a gather
     whose places past the count are clamped and whose values there a select
@@ -570,6 +576,54 @@ def _to_tokens(rows, plan, weights, fresh, backward: bool):
     return _placed(rows, plan, weights, block, chunk)
 
 
+# Who multiplies a held expert's rows by its matrices: the Pallas kernels of
+# ``ops/grouped_matmul.py`` where ``_fresh_buffer`` chose ``_unwritten`` (a TPU
+# under no mesh) and the shapes find their tiles in the VMEM the device's kind
+# is known to have (``mosaic.vmem_bytes``), else ``jax.lax.ragged_dot``
+# and its cotangent (the compiler's ``ragged-dot-none`` on a TPU). Either
+# leaves a result's rows past the groups as nobody's. One ``counters`` record
+# ``moe/grouped_matmul`` a traced matmul says which: ``form`` 0 (rows x w),
+# 1 (rows x w^T) or 2 (rows^T x d_out, a group's matrix), the rows' buffer,
+# the contraction ``k`` and the result's ``n`` columns, the rows a ``tile``
+# and the blocks of K and N (0: no kernel).
+def _count_grouped_matmul(form: int, rows, k: int, n: int, sizes, tiles,
+                          backward: bool):
+    tile, block_k, block_n = tiles or (0, 0, 0)
+    steptrace.record_counters("moe/grouped_matmul", {
+        "kernel": int(tiles is not None), "form": form,
+        "rows": rows.shape[0], "k": k, "n": n, "held": sizes.shape[0],
+        "tile": tile, "block_k": block_k, "block_n": block_n,
+        "backward": int(backward)})
+
+
+def _by_group(rows, w, sizes, fresh, backward: bool, transposed: bool = False):
+    """``rows [R, K] x w [held, K, N] -> [R, N]`` by group; ``transposed``:
+    against ``w [held, N, K]``, read in place by the kernel."""
+    tiles = (grouped_matmul.tiles_by_group(rows, w, transposed)
+             if fresh is _unwritten else None)
+    _count_grouped_matmul(
+        int(transposed), rows, rows.shape[1], w.shape[1 if transposed else 2],
+        sizes, tiles and (tiles[0], rows.shape[1], tiles[1]), backward)
+    if tiles is None:
+        return jax.lax.ragged_dot(
+            rows, w.swapaxes(1, 2) if transposed else w, sizes)
+    return grouped_matmul.by_group(rows, w, sizes, transposed=transposed,
+                                   tiles=tiles)
+
+
+def _per_group(rows, d_out, w, sizes, fresh):
+    """``rows^T [K, R] x d_out [R, N] -> [held, K, N]`` by group, of the
+    rows' type: the cotangent of ``_by_group(rows, w, ...)``'s ``w``."""
+    tiles = (grouped_matmul.tiles_per_group(rows, d_out)
+             if fresh is _unwritten else None)
+    _count_grouped_matmul(2, rows, rows.shape[1], d_out.shape[1], sizes,
+                          tiles, backward=True)
+    if tiles is None:
+        return jax.vjp(lambda m: jax.lax.ragged_dot(rows, m, sizes),
+                       w)[1](d_out)[0]
+    return grouped_matmul.per_group(rows, d_out, sizes, tiles=tiles)
+
+
 def _swiglu(hidden):
     gate, up = jnp.split(hidden, 2, axis=-1)
     return jax.nn.silu(gate) * up
@@ -593,10 +647,10 @@ def _rows_forward(x, weights, wi, wo, plan, activation="swiglu"):
     fresh = _fresh_buffer(x)
     rows, = _walk(plan, fresh, lambda start, pair: (
         _take_rows(x, pair // k),))
-    hidden = jax.lax.ragged_dot(rows, wi.astype(x.dtype), sizes)
+    hidden = _by_group(rows, wi.astype(x.dtype), sizes, fresh, backward=False)
     act, = _walk(plan, fresh, lambda start, pair: (
         act_fn(_chunk(hidden, start, pair)),))
-    out = jax.lax.ragged_dot(act, wo.astype(x.dtype), sizes)
+    out = _by_group(act, wo.astype(x.dtype), sizes, fresh, backward=False)
     _count_row_buffers(fresh, (rows, act), backward=False)
     return _to_tokens(out, plan, weights, fresh,
                       backward=False).astype(x.dtype)
@@ -610,10 +664,11 @@ def _rows_backward(x, weights, wi, wo, plan, g, activation="swiglu"):
     fresh = _fresh_buffer(x)
     rows, g_rows = _walk(plan, fresh, lambda start, pair: (
         _take_rows(x, pair // k), _take_rows(g, pair // k)))
-    hidden = jax.lax.ragged_dot(rows, wi_x, sizes)
+    hidden = _by_group(rows, wi_x, sizes, fresh, backward=True)
     # with y = sum of weight x (act @ wo): d_act = weight x (g @ wo^T), and
     # d_weight = g . (act @ wo) = (g @ wo^T) . act: no second matmul again
-    g_act = jax.lax.ragged_dot(g_rows, wo_x.swapaxes(1, 2), sizes)
+    g_act = _by_group(g_rows, wo_x, sizes, fresh, backward=True,
+                      transposed=True)
 
     def of_chunk(start, pair):
         scale = _take_rows(weights.reshape(-1), pair)[:, None]
@@ -627,25 +682,25 @@ def _rows_backward(x, weights, wi, wo, plan, g, activation="swiglu"):
     _count_row_buffers(fresh, (rows, g_rows, d_hidden, act_scaled, d_scale),
                        backward=True)
 
-    def for_the_matrices(rows, matrices, d_out):
-        return jax.vjp(lambda m: jax.lax.ragged_dot(rows, m, sizes),
-                       matrices)[1](d_out)[0]
-
-    d_rows = jax.lax.ragged_dot(d_hidden, wi_x.swapaxes(1, 2), sizes)
+    d_rows = _by_group(d_hidden, wi_x, sizes, fresh, backward=True,
+                       transposed=True)
     d_weights = jnp.where(plan["mine"], _take_rows(d_scale, jnp.minimum(
         plan["inverse"], d_scale.shape[0] - 1)).reshape(weights.shape), 0.0)
     return (_to_tokens(d_rows, plan, None, fresh,
                        backward=True).astype(x.dtype),
             d_weights.astype(weights.dtype),
-            for_the_matrices(rows, wi_x, d_hidden).astype(wi.dtype),
-            for_the_matrices(act_scaled, wo_x, g_rows).astype(wo.dtype))
+            _per_group(rows, d_hidden, wi_x, sizes, fresh).astype(wi.dtype),
+            _per_group(act_scaled, g_rows, wo_x, sizes, fresh
+                       ).astype(wo.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _held_rows(x, weights, wi, wo, plan, activation="swiglu"):
     """``held_expert_ffn``'s row work: gather, the grouped experts (a SwiGLU
-    or an un-gated ``relu^2`` by ``activation``), back to the tokens. The grouped matmuls run over whole buffers and follow the rows
-    present by themselves; what is not a matmul walks the buffer only as far
+    or an un-gated ``relu^2`` by ``activation``), back to the tokens. The
+    grouped matmuls (``_by_group``, ``_per_group``) run over whole buffers
+    and follow the rows present by themselves; what is not a matmul walks
+    the buffer only as far
     as the pairs present (``_walk``), or reads the rows that hold a pair
     (``_to_tokens``).
     Past the last walked chunk a row buffer holds what nobody wrote, of the
@@ -686,7 +741,8 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int,
 
     The T * k pairs are sorted by held expert (pairs of absent experts last,
     under a key of their own), each sorted row gathers its token, grouped
-    matmuls (``jax.lax.ragged_dot``) run over the rows present, and the rows
+    matmuls (the kernels of ``ops/grouped_matmul.py`` on a TPU under no
+    mesh, else ``jax.lax.ragged_dot``) run over the rows present, and the rows
     go back to their tokens. The row buffer is T * k long, the most that
     can fall on held experts, so no pair is dropped at any routing, and the
     work follows the pairs present, ``tokens.sum()``: the matmuls' kernel by
@@ -719,6 +775,16 @@ def held_expert_ffn(x, experts, weights, wi, wo, *, index: int, of: int,
     The bench hands back the layer's result beside the gradients, so both
     passes are in it (until PR 53 its loss was linear in a result nobody
     read, and the forward pass was dead: 9.6 where this table has 14.1).
+
+    The grouped matmuls are the kernels of ``ops/grouped_matmul.py`` where
+    they run (``_by_group``, ``_per_group``; PR 61): XLA's own kernel for
+    ``ragged_dot`` works in tiles of 512 x 128 x 128 and took 2.7-4.0 ms a
+    call at the nemotron cell's widths (2,688 and 1,856, 768 rows an expert:
+    8-11% of the MXU's peak; 0.8-1.0 ms at 3,072 and 2,048, 10-14 ms at
+    4,096 rows an expert: the widths, not the rows), the kernels 0.47-0.51
+    (61-67%); 45-72% against 85-89% at the lfm2 cell's 4,096 rows an expert
+    (``benches/grouped_matmul.py`` holds the table). The nemotron cell's
+    traced step spent 89.3 ms in its 28 grouped matmuls and spends 13.4.
 
     The way back to the tokens alone, a pass (the same bench): 1.2-1.3 ms by
     the kernel from 8,200 to 45,000 pairs (512 ranges of rows, one chunk of

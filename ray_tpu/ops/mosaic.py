@@ -1,8 +1,8 @@
 """What every Pallas kernel of ``ray_tpu/ops`` asks of its surroundings:
 whether a Mosaic call may run where an operand is traced, how it is handed
-to a mesh, and the compiler's parameters. The one place of ``ops/`` that
-reads the backend for a kernel, wraps one in ``shard_map`` or writes
-``pltpu.CompilerParams``; a kernel module keeps what is its own (which
+to a mesh, the compiler's parameters and the VMEM a core has. The one place
+of ``ops/`` that reads the backend or the device for a kernel, wraps one in
+``shard_map`` or writes ``pltpu.CompilerParams``; a kernel module keeps what is its own (which
 shapes fit, which operands are split by rows, its numbers).
 """
 
@@ -79,6 +79,24 @@ def per_batch_shard(fn, x, split: Sequence[bool], who: str):
     return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(rows if s else whole for s in split),
         out_specs=rows, axis_names=set(axes), check_vma=False)
+
+
+# VMEM of a core, by the device's kind, for a kernel that asks for more than
+# the compiler's own 16 MiB. A kind stands here once such a kernel was read on
+# it (``benches/grouped_matmul.py``: the v5e); on any other the kernel's tiles
+# find no room and its caller keeps its twin, where a limit the core does not
+# have would fail the step's compilation.
+_VMEM_BYTES = {"TPU v5 lite": 128 * 2**20}
+
+
+def device_kind() -> str:
+    return jax.devices()[0].device_kind
+
+
+def vmem_bytes() -> int:
+    """VMEM of one core of the backend's devices; 0 for a kind that
+    ``_VMEM_BYTES`` does not know."""
+    return _VMEM_BYTES.get(device_kind(), 0)
 
 
 def compiler_params(interpret: bool, semantics: Sequence[str],

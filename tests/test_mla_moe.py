@@ -17,7 +17,7 @@ import pytest
 from perfbench import compare, worker
 from ray_tpu._private import steptrace
 from ray_tpu.models import gpt2, mla_moe
-from ray_tpu.ops import attention, flash_kernels, moe, xent
+from ray_tpu.ops import attention, flash_kernels, moe, mosaic, xent
 from tests.conftest import kernel_calls
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -377,6 +377,111 @@ def test_nobody_reads_what_the_walk_did_not_write(case, monkeypatch):
     for a, b in zip(got, want):
         assert np.isfinite(np.asarray(a)).all()
         assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize(
+    "case", [0, 1, 300, "uneven", 128 * 4],
+    ids=["none", "one", "some", "uneven", "all"])
+def test_the_layer_under_the_grouped_matmul_kernels_is_each_pair_by_itself(
+        case, monkeypatch):
+    """``held_expert_ffn`` where a TPU under no mesh takes the kernels of
+    ``ops/grouped_matmul.py`` (the backend's name and the device's kind
+    stood in for, the kernels interpreted, every walked buffer started from NaN): the result, the
+    counts and the four gradients are those of each pair computed by
+    itself; each of the seven grouped matmuls wrote one
+    ``moe/grouped_matmul`` record that says ``kernel``; ``ragged_dot`` is
+    not traced."""
+    from ray_tpu.ops import grouped_matmul
+    T, k, d, width, held, of = 128, 4, 192, 128, 4, 2
+    present, experts = _routing_of(case, T, k, held, of)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    x, target = (jax.random.normal(key, (T, d)) for key in keys[:2])
+    weights = jax.random.uniform(keys[2], (T, k), minval=0.1)
+    wi = jax.random.normal(keys[3], (held, d, 2 * width)) * 0.1
+    wo = jax.random.normal(keys[4], (held, width, d)) * 0.1
+
+    def through(ffn):
+        def loss(x, weights, wi, wo):
+            y, tokens = ffn(x, experts, weights, wi, wo, index=0, of=of)
+            return (y * target).sum(), (y, tokens)
+        (_, aux), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True)(x, weights, wi, wo)
+        return aux + grads
+
+    want = through(_each_pair_by_itself)
+    assert int(want[1].sum()) == present
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mosaic, "device_kind", lambda: "TPU v5 lite")
+    monkeypatch.setattr(moe, "_unwritten",
+                        lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    monkeypatch.setattr(
+        jax.lax, "ragged_dot", lambda *a, **kw: pytest.fail("ragged_dot"))
+    for name in ("by_group", "per_group"):
+        monkeypatch.setattr(grouped_matmul, name, functools.partial(
+            getattr(grouped_matmul, name), interpret=True))
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()             # the layer's passes are jitted
+        got = through(moe.held_expert_ffn)
+        drawn = [e["args"] for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot()))
+            if e["ph"] == "C" and e["name"] == "moe/grouped_matmul"]
+    finally:
+        steptrace.set_enabled(False)
+    monkeypatch.undo()
+    jax.clear_caches()
+    rows = T * k
+    tile = grouped_matmul._ROWS_A_TILE
+    record = {"kernel": 1, "rows": rows, "held": held, "tile": tile}
+    assert drawn == [dict(record, form=form, k=k_, n=n, block_k=k_, block_n=n,
+                          backward=backward)
+                     for form, k_, n, backward in (
+                         (0, d, 2 * width, 0), (0, width, d, 0),
+                         (0, d, 2 * width, 1), (1, d, width, 1),
+                         (1, 2 * width, d, 1), (2, d, 2 * width, 1),
+                         (2, width, d, 1))]
+    assert np.array_equal(got[1], want[1])
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_on_a_kind_of_tpu_nobody_read_ragged_dot_stays(monkeypatch):
+    """A TPU whose kind ``mosaic.vmem_bytes`` does not know (the kernels'
+    blocks ask for more VMEM than the compiler's own limit): the shapes a
+    v5e finds tiles for find none, all seven grouped matmuls are
+    ``ragged_dot``'s and their records say ``kernel`` 0."""
+    from ray_tpu.ops import grouped_matmul
+    T, k, d, width, held = 128, 4, 192, 128, 4
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(keys[0], (T, d))
+    experts = jax.random.randint(keys[1], (T, k), 0, held)
+    weights = jnp.full((T, k), 0.25)
+    wi = jax.random.normal(keys[2], (held, d, 2 * width)) * 0.1
+    wo = jax.random.normal(keys[3], (held, width, d)) * 0.1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mosaic, "device_kind", lambda: "TPU v4")
+    monkeypatch.setattr(moe, "_unwritten", jnp.zeros)
+    for name in ("by_group", "per_group"):
+        monkeypatch.setattr(grouped_matmul, name,
+                            lambda *a, **kw: pytest.fail("a kernel"))
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        jax.clear_caches()             # the layer's passes are jitted
+        jax.grad(lambda x, wi, wo: moe.held_expert_ffn(
+            x, experts, weights, wi, wo, index=0, of=1)[0].sum(),
+            argnums=(0, 1, 2))(x, wi, wo)
+        drawn = [e["args"] for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot()))
+            if e["ph"] == "C" and e["name"] == "moe/grouped_matmul"]
+    finally:
+        steptrace.set_enabled(False)
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert [(r["kernel"], r["form"], r["tile"]) for r in drawn] == [
+        (0, form, 0) for form in (0, 0, 0, 1, 1, 2, 2)]
 
 
 def test_a_traced_pass_counts_its_row_buffers_once():

@@ -13,6 +13,7 @@ must be the xdist worker that was handed this file.
 """
 
 import collections
+import functools
 import hashlib
 import json
 import os
@@ -66,9 +67,13 @@ def no_compile_cache():
 @pytest.fixture
 def on_tpu(monkeypatch):
     """Steer code that asks ``jax.default_backend()`` (the flash
-    dispatcher, the model's ``attention="auto"``) onto its TPU branch: the
-    process itself sees the CPU."""
+    dispatcher, the model's ``attention="auto"``) onto its TPU branch, and
+    code that asks the device's kind (``mosaic.vmem_bytes``) onto the
+    described chip's: the process itself sees the CPU."""
+    from ray_tpu.ops import mosaic
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mosaic, "device_kind", lambda: "TPU v5 lite")
     jax.clear_caches()
     yield
     jax.clear_caches()
@@ -109,12 +114,12 @@ def _train_step_args(attention, state_sharding, batch_sharding, batch=BATCH):
 _HELD_PROGRAMS = {
     "gpt2-124m.step": "d12d86d6c3b58f16",
     "gpt2-xl.step-fsdp4": "dd746428dd36d2db",
-    "joyai-llm-flash.step-8k": "be022cbee2cb17d6",
+    "joyai-llm-flash.step-8k": "3df743f71e94ff9d",   # PR 61
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
-    "lfm2-8b-a1b.step-8k": "26754d67a7295565",
-    "qwen3-next-80b-a3b.step-8k": "324cebd6c77525bf",   # PR 59
-    "nemotron-3-nano-30b-a3b.step-8k": "04b7b0bf030790b4",   # PR 58
-    "trinity-mini.step-16k": "577d858deda03511",   # PR 59
+    "lfm2-8b-a1b.step-8k": "73a6b74898269edd",   # PR 61
+    "qwen3-next-80b-a3b.step-8k": "9d394435fa05898f",   # PR 61
+    "nemotron-3-nano-30b-a3b.step-8k": "f4a4c6300c4b51cf",   # PR 61
+    "trinity-mini.step-16k": "2f1a9030f3b267be",   # PR 61
 }
 
 
@@ -565,6 +570,49 @@ def _to_tokens_census(text, drawn, tokens, k, d, held, calls, block=512):
         for backward in (0, 1)}
 
 
+def _grouped_matmul_census(text, drawn, pairs, d, up, width, held, layers,
+                           recomputed: int = 0):
+    """The held experts' grouped matmuls in a compiled step ``text`` (PR 61):
+    none is the compiler's ``ragged-dot-none``; each expert layer calls the
+    kernels of ``ops/grouped_matmul.py`` seven times (hidden and out
+    forward; hidden again, the two operands' gradients and the two
+    matrices' backward; ``recomputed``: the forward's two again where the
+    recomputed forward is live), five over buffers ``pairs`` long and two
+    whose result has the held experts' leading dimension; and each traced
+    matmul wrote one ``moe/grouped_matmul``
+    record that says ``kernel``, with the tiles ``tiles_by_group`` /
+    ``tiles_per_group`` choose for its shapes."""
+    from ray_tpu.ops import grouped_matmul
+
+    assert "ragged-dot" not in text
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(grouped_matmul_\w+?)[.\d]* = (\w+\[\d+),.*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {
+        ("grouped_matmul_rows", f"bf16[{pairs}"): (3 + recomputed) * layers,
+        ("grouped_matmul_rows_t", f"bf16[{pairs}"): 2 * layers,
+        ("grouped_matmul_matrices", f"bf16[{held}"): 2 * layers}, calls
+    bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    want = set()
+    for form, k, n, backward in (
+            (0, d, up, 0), (0, width, d, 0), (0, d, up, 1), (1, d, width, 1),
+            (1, up, d, 1), (2, d, up, 1), (2, width, d, 1)):
+        if form == 2:
+            tile, block_k, block_n = grouped_matmul.tiles_per_group(
+                bf16((pairs, k)), bf16((pairs, n)))
+        else:
+            tile, block_n = grouped_matmul.tiles_by_group(
+                bf16((pairs, k)),
+                bf16((held, n, k) if form else (held, k, n)), bool(form))
+            block_k = k
+        want.add(tuple(sorted({
+            "kernel": 1, "form": form, "rows": pairs, "k": k, "n": n,
+            "held": held, "tile": tile, "block_k": block_k,
+            "block_n": block_n, "backward": backward}.items())))
+    assert {tuple(sorted(e["args"].items())) for e in drawn
+            if e["name"] == "moe/grouped_matmul"} == want
+
+
 def _cut_cell(name="joyai-llm-flash.step-8k"):
     from perfbench import run, worker
 
@@ -594,7 +642,8 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     values 128 wide (12 calls and not 18: six blocks, forward and backward,
     the recomputed blocks keeping the forward's output and log-sum-exp by
     ``ops.remat.remat_policy``: 0.76 GiB, with which the plan fell); the
-    routed experts are the compiler's grouped-matmul kernel over a row
+    routed experts' grouped matmuls are the kernels of
+    ``ops/grouped_matmul.py`` (PR 61; ``_grouped_matmul_census``) over a row
     buffer of which loops walk what holds the pairs present; and no array
     is shaped like a [tokens, experts, capacity] dispatch or a [T, T] score
     matrix, whole or a head's. Each traced kernel call wrote its grid's
@@ -623,6 +672,7 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     assert {e["name"] for e in drawn} == {"attn/grid_blocks",
                                           "moe/row_buffers",
                                           "moe/to_tokens",
+                                          "moe/grouped_matmul",
                                           "attention/boundary"}
     # several blocks of keys a head: the boundary the kernels were measured
     # with, [B x H, T, d] operands made by XLA
@@ -665,11 +715,10 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
     walked = [line.split(" while(")[0] for line in text.splitlines()
               if " while(" in line and f"[{pairs}," in line.split(" while(")[0]]
     assert len(walked) == 4 * 5
-    grouped = collections.Counter(
-        int(n) for n in re.findall(
-            r"%ragged-dot-none[\w.]* = \w+\[(\d+),", text))
-    held = model["n_routed_experts"]
-    assert grouped == {pairs: 5 * 5, held: 5 * 2}
+    _grouped_matmul_census(
+        text, drawn, pairs, model["hidden_size"],
+        2 * model["moe_intermediate_size"], model["moe_intermediate_size"],
+        model["n_routed_experts"], layers=5)
     # and start from buffers nobody filled: seven an expert layer
     _row_buffer_census(text, drawn, pairs, model["hidden_size"],
                        model["moe_intermediate_size"], unwritten=7 * 5)
@@ -803,6 +852,10 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                 and f"[{pairs}," in line.split(" while(")[0]]) == 6 * 4
     _row_buffer_census(text, counters, pairs, model["hidden_size"],
                        model["moe_intermediate_size"], unwritten=9 * 4)
+    _grouped_matmul_census(
+        text, counters, pairs, model["hidden_size"],
+        2 * model["moe_intermediate_size"], model["moe_intermediate_size"],
+        model["num_experts"], layers=4, recomputed=2)
     # the vocabulary's slice equals no other dimension of the program: what
     # ``loss_head_ms`` reads as vocabulary-wide is the head and the embedding
     others = {model[k] for k in ("hidden_size", "intermediate_size",
@@ -949,7 +1002,8 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "conv/short",
                             "model/layer_kinds", "attention/boundary",
-                            "moe/row_buffers", "moe/to_tokens"}
+                            "moe/row_buffers", "moe/to_tokens",
+                            "moe/grouped_matmul"}
     assert by_name["model/layer_kinds"][-1] == {
         "conv": 4, "full_attention": 1, "dense": 1, "expert": 4, "layers": 5,
         "published_layers": 24}
@@ -983,6 +1037,10 @@ def test_short_convolution_expert_step_fits_one_chip_at_four_8k_sequences(
     _to_tokens_census(text, counters, batch * seq,
                       model["num_experts_per_tok"], model["hidden_size"],
                       model["num_experts"], calls=2 * 4)
+    _grouped_matmul_census(
+        text, counters, batch * seq * model["num_experts_per_tok"],
+        model["hidden_size"], 2 * model["moe_intermediate_size"],
+        model["moe_intermediate_size"], model["num_experts"], layers=4)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
@@ -1049,7 +1107,8 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "delta/rule", "conv/causal",
                             "model/layer_kinds", "attention/boundary",
-                            "moe/row_buffers", "moe/to_tokens"}
+                            "moe/row_buffers", "moe/to_tokens",
+                            "moe/grouped_matmul"}
     assert by_name["model/layer_kinds"][-1] == {
         "linear_attention": 3, "full_attention": 1, "expert": 4, "layers": 4,
         "published_layers": 48}
@@ -1099,6 +1158,10 @@ def test_delta_rule_expert_step_fits_one_chip_at_two_8k_sequences(
     # ``to_tokens``, forward and backward
     _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
                       model["hidden_size"], model["num_experts"], calls=2 * 4)
+    _grouped_matmul_census(
+        text, counters, tokens * model["num_experts_per_tok"],
+        model["hidden_size"], 2 * model["moe_intermediate_size"],
+        model["moe_intermediate_size"], model["num_experts"], layers=4)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     # the convolution's 8,192 channels (q, k and v: 2 x 2048 + 4096) happen
     # to equal the length: [batch, T, channels] is no score matrix, which
@@ -1160,7 +1223,8 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "ssd/scan", "conv/causal",
                             "model/layer_kinds", "attention/boundary",
-                            "moe/row_buffers", "moe/to_tokens"}
+                            "moe/row_buffers", "moe/to_tokens",
+                            "moe/grouped_matmul"}
     assert by_name["model/layer_kinds"][-1] == {
         "mamba": 4, "attention": 1, "expert": 4, "layers": 9,
         "published_layers": 52}
@@ -1199,6 +1263,11 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
     _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
                       model["hidden_size"], model["n_routed_experts"],
                       calls=2 * 4, block=256)
+    # un-gated experts: the way up is one width wide
+    _grouped_matmul_census(
+        text, counters, tokens * model["num_experts_per_tok"],
+        model["hidden_size"], model["moe_intermediate_size"],
+        model["moe_intermediate_size"], model["n_routed_experts"], layers=4)
     shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
     for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
         assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
