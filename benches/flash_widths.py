@@ -202,6 +202,23 @@ the needed pairs are 69.5% of the MXU's peak forward and 92.1% backward
 ``attention_reference`` in float32 there: within 0.0030 (out), 0.0044
 (dq), 0.0042 (dk), 0.0032 (dv) of the largest entry. XLA's scores at this
 shape are [16, 8192, 8192] float32 a sequence, 4.3 GB, and were not tried.
+
+A window of half a resident block (PR 62; ``benches/flash_widths.py --widths
+128x128 --lengths 8192 --tokens 16384 --heads 32 --kv-heads 4 --window 1024
+--check 1 [--residents 2048]``, my chip run: two sequences of 8,192 tokens,
+32 query heads on 4 key-value heads, Mellum2's window layer), ``flash_fwd``
+and ``flash_bwd`` alone, then the wall time of forward plus backward:
+  residents 2,048 (the tree before PR 62: 1,024 keys are half a block, all
+              16 blocks a head "looped"): 5.51 and 9.77 ms, 17.08
+  residents 1,024 (the window one whole block: 8 diagonal, 7 trailing, 49
+              dead a head): 4.48 and 6.83, 13.09; at four sequences 8.99
+              and 13.69 against 11.00 and 19.56
+  no window (6 whole, 4 diagonal, 6 dead): 9.95 and 17.14, 28.90
+Output and the three gradients against ``attention_reference`` in float32:
+the same five digits either way (within 0.0029 to 0.0038 of the largest
+entry). ``_block_sizes`` takes the window's length for its residents since
+(``_WINDOW_RESIDENT_FROM``); ``--residents N`` holds a step to N whatever
+the window, so both readings can be made again.
 """
 
 import argparse
@@ -225,18 +242,30 @@ def main():
     parser.add_argument("--kv-heads", type=int, default=None)
     parser.add_argument("--window", type=int, default=None)
     parser.add_argument("--check", type=int, default=0)
+    parser.add_argument("--residents", type=int, default=None,
+                        help="the most queries, and keys, a grid step holds "
+                             "(default: the kernels' own rule)")
     args = parser.parse_args()
 
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import attention, flash_kernels
     from ray_tpu.ops.attention import (attention_reference,
                                        causal_self_attention,
                                        heads_a_lane_tile)
     from ray_tpu.ops.flash_kernels import grid_block_kinds
 
     kv_heads = args.kv_heads or args.heads
+    if jax.default_backend() != "tpu":
+        sys.exit("benches/flash_widths.py reads device times on a TPU; this "
+                 f"is {jax.default_backend()!r}")
+    if args.residents:
+        # whatever the window: the rule that gives a window from
+        # ``_WINDOW_RESIDENT_FROM`` keys up its own length (since PR 62)
+        # takes none under the most a step holds
+        flash_kernels._MAX_RESIDENT = args.residents
+        flash_kernels._WINDOW_RESIDENT_FROM = args.residents
 
     def timed(fn, *xs):
         jax.block_until_ready(fn(*xs))
@@ -349,7 +378,7 @@ def main():
                               lambda *_: False)
             line = {"d_qk": d_qk, "d_v": d_v, "seq": seq, "batch": batch,
                     "heads": args.heads, "kv_heads": kv_heads,
-                    "window": args.window,
+                    "window": args.window, "residents": args.residents,
                     "boundary": (
                         "model_arrays" if heads_a_lane_tile(
                             seq, args.heads, kv_heads, d_qk, d_v)

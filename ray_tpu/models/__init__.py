@@ -18,10 +18,14 @@
   mixers (``ops.ssm.ssd_scan``), grouped attention without a positional
   term, sigmoid-routed un-gated relu^2 experts beside a shared expert
   (training)
+- mellum: window layers three to one over full layers, rotary positions in
+  both from two tables (``llama.rope_table``: plain and YaRN-scaled), every
+  feed-forward softmax-routed experts with no shared expert and no dense
+  layer (training)
 """
 
-from ray_tpu.models import (afmoe, gpt2, llama, mla_moe, moe_lm, nemotron_h,
-                            phi4flash, qwen3_next, vision)
+from ray_tpu.models import (afmoe, gpt2, llama, mellum, mla_moe, moe_lm,
+                            nemotron_h, phi4flash, qwen3_next, vision)
 
-__all__ = ["afmoe", "gpt2", "llama", "mla_moe", "moe_lm", "nemotron_h",
-           "phi4flash", "qwen3_next", "vision"]
+__all__ = ["afmoe", "gpt2", "llama", "mellum", "mla_moe", "moe_lm",
+           "nemotron_h", "phi4flash", "qwen3_next", "vision"]

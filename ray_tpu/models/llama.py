@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu._private import steptrace
 from ray_tpu.ops.xent import fused_xent
 from ray_tpu.parallel import train_step
 
@@ -99,6 +101,60 @@ def rope_frequencies(head_dim: int, positions, theta: float):
                            / head_dim))
     ang = positions[..., None].astype(jnp.float32) * inv
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_correction_range(head_dim: int, theta: float, original: int,
+                          beta_fast: float, beta_slow: float):
+    """(low, high): the rotary dimensions between which YaRN blends. A
+    dimension that turns ``r`` times over the ``original`` positions is
+    ``c(r) = head_dim ln(original / (2 pi r)) / (2 ln theta)``; below
+    ``low = floor(c(beta_fast))`` the published frequency stands, from
+    ``high = ceil(c(beta_slow))`` on it is divided by the factor (both held
+    to the head's dimensions), as ``transformers``'
+    ``_compute_yarn_parameters`` states it."""
+    turns = lambda r: (head_dim * math.log(original / (2 * math.pi * r))
+                       / (2 * math.log(theta)))
+    return (max(math.floor(turns(beta_fast)), 0),
+            min(math.ceil(turns(beta_slow)), head_dim - 1))
+
+
+def rope_table(head_dim: int, positions, parameters):
+    """(..., T) int positions -> cos/sin (..., T, head_dim//2) by one entry
+    of a config's ``rope_parameters`` (a mapping, or its pairs): ``rope_type``
+    ``default`` is ``rope_frequencies`` at ``rope_theta``, to the bit;
+    ``yarn`` blends each frequency between itself and itself over ``factor``
+    along the ramp ``clip((i - low) / (high - low), 0, 1)`` of
+    ``yarn_correction_range`` and multiplies cos and sin by
+    ``attention_factor`` (left out: ``0.1 ln(factor) + 1``), so that a
+    layer's scores carry its square. Static: the same table at every
+    sequence length. One ``counters`` record ``rope/table`` a traced call
+    says what was built."""
+    p = dict(parameters)
+    kind, theta = p.get("rope_type", "default"), float(p["rope_theta"])
+    said = {"kind": {"default": "plain"}.get(kind, kind), "theta": theta,
+            "factor": 1.0, "original": 0, "low": 0, "high": 0,
+            "attention_factor": 1.0, "dims": head_dim}
+    if kind == "default":
+        steptrace.record_counters("rope/table", said)
+        return rope_frequencies(head_dim, positions, theta)
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: expected default or yarn")
+    factor = float(p["factor"])
+    original = int(p["original_max_position_embeddings"])
+    low, high = yarn_correction_range(
+        head_dim, theta, original, float(p.get("beta_fast", 32)),
+        float(p.get("beta_slow", 1)))
+    scale = float(p.get("attention_factor") or 0.1 * math.log(factor) + 1.0)
+    steptrace.record_counters("rope/table", {
+        **said, "factor": factor, "original": original, "low": low,
+        "high": high, "attention_factor": scale})
+    plain = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    inv = (1.0 - ramp) * plain + ramp * plain / factor
+    ang = positions[..., None].astype(jnp.float32) * inv
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 def apply_rope(x, cos, sin):

@@ -76,6 +76,12 @@ NEG_INF = -1e30
 # The most queries, and keys, one grid step holds (a whole head at GPT-2's
 # 1024); a longer call is several grid blocks a head, walked by kind (PR 38).
 _MAX_RESIDENT = 2048
+# The narrowest window whose own length a call's grid steps hold instead
+# (``_block_sizes``), read at 8,192 tokens, 32 query heads on 4 of 128, under
+# 1,024 keys (PR 62: 4.48 and 6.83 ms forward and backward a call of two
+# sequences against 5.51 and 9.77 with every block looped). A window of 512
+# is one cell's (``phi-4-mini-flash``), still looped and not read here.
+_WINDOW_RESIDENT_FROM = 1024
 # (block_q, block_k) targets: the fastest measured for each kernel alone at
 # (192, 1024, 64) bf16 causal (PR 25); the backward's five matmuls pay more
 # for the dead half of a diagonal tile than for smaller tiles' loop steps.
@@ -728,18 +734,30 @@ def _largest_block(n: int, target: int, align: int) -> int:
 
 
 def _block_sizes(q_len: int, k_len: int, block_q: Optional[int],
-                 block_k: Optional[int], targets):
+                 block_k: Optional[int], targets,
+                 window: Optional[int] = None):
     """(block_q, block_k, resident queries, resident keys) for a call.
     Queries and keys both lie along lanes somewhere, so a tile size the
     caller does not give is a multiple of 128 up to the kernel's target,
-    or the whole length."""
+    or the whole length. A grid step holds up to ``_MAX_RESIDENT`` queries
+    and keys; where a head is several grid blocks anyway, under a
+    ``window`` from ``_WINDOW_RESIDENT_FROM`` keys up that whole tiles fill
+    and that divides both lengths, it holds the window's own length: the
+    window is then one whole block, and a block's place says its kind
+    (``_grid_kinds``: diagonal, trailing, dead) where half a block's window
+    leaves every block ``looped``."""
     block_q = min(block_q or _largest_block(q_len, targets[0], 128), q_len)
     block_k = min(block_k or _largest_block(k_len, targets[1], 128), k_len)
     assert q_len % block_q == 0, (q_len, block_q)
     assert k_len % block_k == 0, (k_len, block_k)
-    return (block_q, block_k,
-            _largest_block(q_len, max(_MAX_RESIDENT, block_q), block_q),
-            _largest_block(k_len, max(_MAX_RESIDENT, block_k), block_k))
+    residents = lambda most: (
+        _largest_block(q_len, max(most, block_q), block_q),
+        _largest_block(k_len, max(most, block_k), block_k))
+    if (window is not None and min(q_len, k_len) > _MAX_RESIDENT
+            and _WINDOW_RESIDENT_FROM <= window < _MAX_RESIDENT
+            and residents(window) == (window, window)):
+        return block_q, block_k, window, window
+    return (block_q, block_k, *residents(_MAX_RESIDENT))
 
 
 def _compiler_params(interpret: bool, width: int, keys_add: bool = False):
@@ -798,9 +816,10 @@ def grid_block_kinds(q_len: int, k_len: int, causal: bool,
     others with constant ones. The forward's grid unless ``backward``: the
     two kernels' tiles differ, and at some lengths what a grid step holds
     with them."""
-    _, _, res_q, res_k = _block_sizes(
-        q_len, k_len, block_q, block_k, _BWD_TILES if backward else _FWD_TILES)
     window = _window_of(window, k_len)
+    _, _, res_q, res_k = _block_sizes(
+        q_len, k_len, block_q, block_k,
+        _BWD_TILES if backward else _FWD_TILES, window)
     kinds = _grid_kinds(q_len // res_q, k_len // res_k, res_q, res_k,
                         k_len - q_len, causal, window)
     if window is None:
@@ -858,7 +877,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     k_len, d_v = k.shape[1], v.shape[2]
     group = b // k.shape[0]
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
-                                                  block_k, _FWD_TILES)
+                                                  block_k, _FWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
     if window is not None and offset:
@@ -944,7 +963,7 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
     group = b // b_kv
     block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
-                                                  block_k, _BWD_TILES)
+                                                  block_k, _BWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
     if causal and nk > 1 and window is not None:

@@ -99,8 +99,16 @@ _CASES = {
                                              8),
     "dq_sum_group_8_keys_128_window_looped": (8, 1, 128, 128, 128, 16, 32,
                                               32, 24),
+    # a window of half a grid block that takes residents of its own length
+    # (PR 62: ``_WINDOW_RESIDENT_FROM``, here half of ``resident``): 8 x 8
+    # grid blocks a head, walked by kind, at Mellum's group of 8
+    "window_half_a_block_takes_its_own_residents": (8, 2, 128, 8, 8, 8, 8,
+                                                    32, 16),
+    "dq_sum_group_8_keys_128_window_half_a_block": (8, 1, 128, 128, 128, 16,
+                                                    16, 32, 16),
 }
 _BF16 = ("grouped_blocks_4x4", "window_of_one_block",
+         "window_half_a_block_takes_its_own_residents",
          "window_of_two_blocks_grouped", "window_wide_keys_tiles_2x1",
          "values_twice_the_keys_blocks_4x4", "window_a_quarter_of_a_block")
 _PARAMS = [
@@ -121,7 +129,14 @@ def test_window_and_grouped_heads_match_reference(monkeypatch, case, impl,
     heads, kv, length, d, d_v, bq, bk, resident, window = _CASES[case]
     if resident:
         monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", resident)
+        monkeypatch.setattr(flash_kernels, "_WINDOW_RESIDENT_FROM",
+                            resident // 2)
         jax.clear_caches()  # flash_attention is jitted: the rule is read
+        if "half_a_block" in case:
+            assert grid_block_kinds(length, length, True, bq, bk,
+                                    window=window) == {
+                "whole": 0, "diagonal": 8, "trailing": 7, "dead": 49,
+                "looped": 0}
     q, k, v, w = _operands(heads, kv, length, d, d_v, dtype)
     f32 = lambda x: x.astype(jnp.float32)
 
@@ -219,8 +234,19 @@ def test_grid_blocks_by_kind_under_a_window():
     # one block a head is one kind whatever the window
     assert grid_block_kinds(2048, 2048, True, window=512) == kinds(0, 1, 0, 0)
     # a window that is no whole number of blocks keeps the loops
-    assert grid_block_kinds(4096, 4096, True, window=1024) == kinds(
+    assert grid_block_kinds(4096, 4096, True, window=1536) == kinds(
         0, 0, 0, 0, 4)
+    for backward in (False, True):
+        # from 1,024 keys up a window of whole tiles that divides the
+        # lengths takes residents of its own length (PR 62): Mellum's
+        # window layer at 8,192 is 8 x 8 blocks a head, none looped
+        assert grid_block_kinds(8192, 8192, True, backward=backward,
+                                window=1024) == kinds(0, 8, 7, 49)
+        assert grid_block_kinds(4096, 4096, True, backward=backward,
+                                window=1024) == kinds(0, 4, 3, 9)
+        # phi-4-mini-flash's 512 keys stay a quarter of a block
+        assert grid_block_kinds(16384, 16384, True, backward=backward,
+                                window=512) == kinds(0, 0, 0, 0, 64)
     pairs = 2048 * 16384 - 2048 * 2047 // 2
     assert pairs == 31_458_304 and 16384 * 16385 // 2 == 134_225_920
     # Phi-4-mini-flash's window layer at 16,384 (PR 48): 512 keys are a

@@ -323,6 +323,17 @@ def test_flash_block_rule():
     assert fwd(640, 1280, None, None) == (128, 256, 640, 1280)
     assert fwd(8192, 8192, None, None) == (512, 512, 2048, 2048)
     assert fwd(64, 64, 16, 16) == (16, 16, 64, 64)
+    # under a window of 1,024 to under 2,048 keys that whole tiles fill and
+    # that divides the lengths, a head of several blocks holds the window's
+    # length a step (PR 62); any other window changes nothing
+    under = lambda n, window: _block_sizes(n, n, None, None, _FWD_TILES,
+                                           window)
+    assert under(8192, 1024) == (512, 512, 1024, 1024)
+    assert _block_sizes(8192, 8192, None, None, _BWD_TILES, 1024) == (
+        256, 256, 1024, 1024)
+    assert under(2048, 1024) == (512, 512, 2048, 2048)
+    for window in (512, 1280, 1536, 2048, 4096):
+        assert under(16384, window) == (512, 512, 2048, 2048), window
     with pytest.raises(AssertionError):
         fwd(96, 96, 64, 16)
 

@@ -120,6 +120,7 @@ _HELD_PROGRAMS = {
     "qwen3-next-80b-a3b.step-8k": "9d394435fa05898f",   # PR 61
     "nemotron-3-nano-30b-a3b.step-8k": "f4a4c6300c4b51cf",   # PR 61
     "trinity-mini.step-16k": "2f1a9030f3b267be",   # PR 61
+    "mellum2-12b-a2.5b.step-8k": "0df9243568848ab6",   # PR 62
 }
 
 
@@ -1275,4 +1276,113 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
         # boundaries, 32 a sequence
         if dims[-2:] == (64, 128) or dims[-2:] == (128, 128):
             assert seq not in dims and tokens not in dims, dims
+    print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
+
+
+def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``mellum2-12b-a2.5b.step-8k``
+    (published layers 0 to 3 at the published widths: three window layers of
+    1,024 keys and one YaRN-scaled full layer, 32 query heads on 4 of 128,
+    every feed-forward 16 of 64 softmax-routed experts of 896, a quarter of
+    the vocabulary under an untied head), its step at 2 x 8,192 with
+    recomputation, as the benchmark's family builds it: the plan stays under
+    the 14.5 GiB that ISSUE 62 set for choosing the batch (11.88 read; at 4
+    x 8,192 it is 16.59, over the chip, which is why the cell runs the
+    accepted ``step-8k`` traffic) with the state's 6.65 GiB as arguments.
+    Every layer's attention is the flash kernel pair on the
+    ``model_results`` boundary, once forward and once backward
+    (``ops.remat.remat_policy`` keeps the output and its log-sum-exp), the
+    window layers' named ``flash_*_w1024``; under the window a grid step
+    holds the window's own 1,024 queries and keys (``_block_sizes``, PR 62),
+    so a head is 8 x 8 blocks told apart by their place (8 diagonal, 7
+    trailing, 49 dead, none looped), the full layer's 4 x 4 of 2,048. Two
+    rotary tables are built a traced pass, one plain and one YaRN. The four
+    expert layers' matmuls are the grouped-matmul kernels at 2,304 x 1,792
+    / 896 over 16 groups. No array is shaped like a [T, T] score matrix, and
+    the vocabulary's 24,576 rows equal no other dimension of the program."""
+    from ray_tpu._private import steptrace
+
+    worker, model, traffic = _cut_cell("mellum2-12b-a2.5b.step-8k")
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (2, 8192)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = _lower_held(
+            "mellum2-12b-a2.5b.step-8k", built.step, params, opt_state,
+            {"input_ids": ids, "labels": ids})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {"attn/grid_blocks", "rope/table",
+                            "model/layer_kinds", "attention/boundary",
+                            "moe/row_buffers", "moe/to_tokens",
+                            "moe/grouped_matmul"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "sliding_attention": 3, "full_attention": 1, "expert": 4,
+        "layers": 4, "published_layers": 28}
+    tables = {e["kind"]: e for e in by_name["rope/table"]}
+    assert tables["plain"] == {
+        "kind": "plain", "theta": 500000.0, "factor": 1.0, "original": 0,
+        "low": 0, "high": 0, "attention_factor": 1.0, "dims": 128}
+    assert tables["yarn"] == {
+        "kind": "yarn", "theta": 500000.0, "factor": 16.0, "original": 8192,
+        "low": 18, "high": 35, "attention_factor": 1.2772588722239782,
+        "dims": 128}
+    assert {(e["heads"], e["kv_heads"], e["d_qk"], e["d_v"],
+             e["model_results"]) for e in by_name["attention/boundary"]} == {
+        (32, 4, 128, 128, 1)}
+    by_window = {1024: (0, 8, 7, 49), 0: (6, 4, 0, 6)}
+    assert {(e["window"], e["backward"])
+            for e in by_name["attn/grid_blocks"]} == {
+        (w, b) for w in by_window for b in (0, 1)}
+    for e in by_name["attn/grid_blocks"]:
+        whole, diagonal, trailing, dead = by_window[e["window"]]
+        assert e == {
+            "whole": whole, "diagonal": diagonal, "trailing": trailing,
+            "dead": dead, "looped": 0, "queries": seq, "keys": seq,
+            "backward": e["backward"], "window": e["window"],
+            "heads": batch * 32, "kv_heads": batch * 4, "dq_partials": 0}
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 595_154_432
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    assert planned > 4 * 2**30     # a quarter of the chip's 16 and more
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(flash_(?:fwd|bwd)(?:_w\d+)?)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_fwd_w1024": 3,
+                     "flash_bwd_w1024": 3}
+    assert "bf16[64,8192,128]" in text and "bf16[8,8192,128]" in text
+    _dq_census(text, 64, 128, seq)
+    tokens = batch * seq
+    # blocks of 256 tokens: at 2,304 wide a block of 512 and its chunks'
+    # slots do not fit the kernel's VMEM (``ops.moe._token_blocks``)
+    _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
+                      model["hidden_size"], model["num_experts"], calls=2 * 4,
+                      block=256)
+    _grouped_matmul_census(
+        text, counters, tokens * model["num_experts_per_tok"],
+        model["hidden_size"], 2 * model["moe_intermediate_size"],
+        model["moe_intermediate_size"], model["num_experts"], layers=4)
+    shapes = set(re.findall(r"\b[a-z]\w*\[([\d,]+)\]", text))
+    for dims in (tuple(int(n) for n in s.split(",")) for s in shapes):
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), dims
+        # 24,576 stands only beside the hidden size (the embedding, the
+        # head, their gradients and moments) or as a loss chunk's logits
+        if 24576 in dims:
+            assert dims in {(24576, 2304), (24576, 2304, 1),
+                            (2, 1024, 24576)}, dims
     print(f"planned {planned / 2**30:.3f} GiB", compiled.memory_analysis())
