@@ -13,11 +13,14 @@ training.
   repeats no key or value in memory; at this family's published head width
   of 128 and past 2,048 tokens the kernel writes its output into, and reads
   that output's cotangent from, the [B, T, H x 128] array that the gate and
-  ``o_proj`` read, and writes dK and dV as [B, T, H_kv x 128] (XLA folds q,
-  k and v for it, which the projections write in that layout anyway, and
-  turns dQ back, which the rotary's backward wants with the tokens minor).
+  ``o_proj`` read, and writes dK and dV as [B, T, H_kv x 128].
   Each head's queries and keys pass an
-  RMSNorm over ``head_dim`` (one scale vector each, shared by the heads);
+  RMSNorm over ``head_dim`` (one scale vector each, shared by the heads)
+  before the rotation: the layer calls
+  ``ops.attention.normed_rotary_self_attention``, which at that width and
+  length on a TPU makes both in ``ops/rotary.py``'s kernels, from the
+  projections' own arrays to the flash kernels' operands and from their
+  float32 dQ^T back, and elsewhere in ``jnp``;
   the attention output is multiplied by ``sigmoid(x W_g)`` before ``W_o``.
 - A block has four norms: ``h = h + N2(Attn(N1(h)))``, ``h = h + N4(F(N3(h)))``.
   ``F`` is a dense SwiGLU in the first ``num_dense_layers`` layers and the
@@ -46,10 +49,11 @@ import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
-from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_frequencies
+from ray_tpu.models.llama import (RMSNorm, RMSNormScale, SwiGLU,
+                                  rope_frequencies)
 from ray_tpu.models.mla_moe import RoutedExperts, held_expert_load
 from ray_tpu.ops import xent
-from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.attention import normed_rotary_self_attention
 from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel import train_step
 from ray_tpu.parallel.mesh_utils import on_batch_axes, replicated
@@ -143,16 +147,18 @@ class GatedAttention(nn.Module):
         H, G, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=c.dtype,
                                          kernel_init=_init(c), name=name)
-        norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
+        scale = lambda name: RMSNormScale(D, name=name)()
         q = on_batch_axes(dense(H * D, "q_proj")(x).reshape(B, T, H, D))
         k = on_batch_axes(dense(G * D, "k_proj")(x).reshape(B, T, G, D))
         v = on_batch_axes(dense(G * D, "v_proj")(x).reshape(B, T, G, D))
         gate = dense(H * D, "gate_proj")(x)
-        q, k = norm("q_norm")(q), norm("k_norm")(k)
-        if self.window is not None:
-            cos, sin = rope_frequencies(D, positions, c.rope_theta)
-            q, k = rotate_halves(q, cos, sin), rotate_halves(k, cos, sin)
-        y = causal_self_attention(q, k, v, c.attention, self.window)
+        # each head's q and k normed over its width; turned in the window
+        # layers alone
+        cos, sin = (None, None) if self.window is None else (
+            rope_frequencies(D, positions, c.rope_theta))
+        y = normed_rotary_self_attention(
+            q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
+            eps=c.rms_norm_eps, attention=c.attention, window=self.window)
         y = on_batch_axes(y.reshape(B, T, H * D)) * jax.nn.sigmoid(gate)
         return dense(c.hidden_size, "o_proj")(y)
 
@@ -193,11 +199,11 @@ class Afmoe(nn.Module):
         layers, held]). The head's matrix is the parameter ``lm_head``,
         [V, d]."""
         c = self.config
-        B, T = input_ids.shape
+        _, T = input_ids.shape
         embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
                          embedding_init=_init(c), name="embed")
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
-        positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        positions = jnp.arange(T)[None, :]   # one table for every row
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
         x = on_batch_axes(embed(input_ids) * math.sqrt(c.hidden_size))
         tokens = []

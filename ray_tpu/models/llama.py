@@ -95,6 +95,18 @@ class RMSNorm(nn.Module):
         return (n * scale).astype(self.dtype)
 
 
+class RMSNormScale(nn.Module):
+    """The scale of an ``RMSNorm`` whose arithmetic its caller's function
+    does (a head's norm inside
+    ``ops.attention.normed_rotary_self_attention``): the same parameter
+    under the same name, [width], ones."""
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.width,))
+
+
 def rope_frequencies(head_dim: int, positions, theta: float):
     """(..., T) int positions -> cos/sin of shape (..., T, head_dim//2)."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)

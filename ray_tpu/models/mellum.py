@@ -15,7 +15,10 @@ whatever sizes the config gives), for training.
   the window). Each head's queries and keys pass an RMSNorm over
   ``head_dim`` before the rotation (one scale vector each, shared by the
   heads: the lineage's convention, which the published config has no key
-  for); there is no gate on the output and no bias anywhere.
+  for), both inside ``ops.attention.normed_rotary_self_attention``
+  (``ops/rotary.py``'s kernels at the published width and the cell's
+  length on a TPU, ``jnp`` elsewhere); there is no gate on the output and
+  no bias anywhere.
 - A block has two norms: ``h = h + Attn(N1(h))``, ``h = h + F(N2(h))``.
   ``F`` is, in EVERY layer, the routed-expert layer of ``models/mla_moe.py``
   (``RoutedExperts``: softmax scores over all ``num_experts``,
@@ -47,12 +50,12 @@ from ray_tpu._private import steptrace
 # expert) under an untied ``lm_head`` is this family's too, and is one copy:
 # the loss, the step over it, the parameters' placement, a loop's report
 from ray_tpu.models.afmoe import (  # noqa: F401 (this module's names too)
-    FULL, WINDOW, build_train_step, loss_fn, param_shardings, rotate_halves,
+    FULL, WINDOW, build_train_step, loss_fn, param_shardings,
     shard_train_state, step_metrics)
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
-from ray_tpu.models.llama import RMSNorm, rope_table
+from ray_tpu.models.llama import RMSNorm, RMSNormScale, rope_table
 from ray_tpu.models.mla_moe import RoutedExperts
-from ray_tpu.ops.attention import causal_self_attention
+from ray_tpu.ops.attention import normed_rotary_self_attention
 from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel.mesh_utils import on_batch_axes
 
@@ -155,13 +158,14 @@ class Attention(nn.Module):
         H, G, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=c.dtype,
                                          kernel_init=_init(c), name=name)
-        norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
+        scale = lambda name: RMSNormScale(D, name=name)()
         q = on_batch_axes(dense(H * D, "q_proj")(x).reshape(B, T, H, D))
         k = on_batch_axes(dense(G * D, "k_proj")(x).reshape(B, T, G, D))
         v = on_batch_axes(dense(G * D, "v_proj")(x).reshape(B, T, G, D))
-        q = rotate_halves(norm("q_norm")(q), cos, sin)
-        k = rotate_halves(norm("k_norm")(k), cos, sin)
-        y = causal_self_attention(q, k, v, c.attention, self.window)
+        # each head's q and k normed over its width, then turned
+        y = normed_rotary_self_attention(
+            q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
+            eps=c.rms_norm_eps, attention=c.attention, window=self.window)
         return dense(c.hidden_size, "o_proj")(
             on_batch_axes(y.reshape(B, T, H * D)))
 

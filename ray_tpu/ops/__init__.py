@@ -5,7 +5,8 @@ the vocabulary's loss.
 
 Who knows whom, arrows one way: ``models/*`` -> ``remat`` (what a recomputed
 block keeps) and the op modules ``attention`` (its kernels:
-``flash_kernels``), ``ssm``, ``conv``, ``delta``, ``moe`` -> ``chunks`` (the
+``flash_kernels``, and ``rotary``, the kernels of a normed, rotated layer's
+prologue), ``ssm``, ``conv``, ``delta``, ``moe`` -> ``chunks`` (the
 chunk scheme ``delta`` and ``ssm.ssd_scan`` share) and ``mosaic`` (where a
 Pallas kernel may run, how it is handed to a mesh, the compiler's
 parameters) -> ``parallel/mesh_utils``. A new kernel module names its own

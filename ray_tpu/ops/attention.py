@@ -22,7 +22,9 @@ This file holds what a caller reads: the reference and the scan, the two
 shape rules that choose a call's boundary, the ``custom_vjp`` and the
 entries. The kernels' bodies and their ``pallas_call`` builders are
 ``ops/flash_kernels.py``; where a Mosaic call may run and how it is handed to
-a mesh is ``ops/mosaic.py``.
+a mesh is ``ops/mosaic.py``. ``normed_rotary_self_attention``, at the end, is
+the entry of a layer whose q and k pass a head norm and a rotation first:
+their kernels are ``ops/rotary.py``.
 """
 
 from __future__ import annotations
@@ -35,7 +37,7 @@ import jax.numpy as jnp
 from jax import ad_checkpoint, lax
 
 from ray_tpu._private import steptrace
-from ray_tpu.ops import flash_kernels as kernels
+from ray_tpu.ops import flash_kernels as kernels, rotary
 from ray_tpu.ops.flash_kernels import NEG_INF
 from ray_tpu.ops.mosaic import per_batch_shard, takes_kernels
 
@@ -456,6 +458,22 @@ def auto_attention(q, v=None) -> str:
     return "flash" if measured and takes_kernels(q) else "xla"
 
 
+def _boundary(q, k, v, window: Optional[int]) -> bool:
+    """Whether a "flash" call of q [B, T, H, d], k and v crosses in the
+    model's own arrays, all of it (``heads_a_lane_tile``) or its results
+    (``results_in_model_arrays``); writes the call's ``attention/boundary``
+    record."""
+    (_, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
+    a_tile = heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
+    results = results_in_model_arrays(seq, d, d_v)
+    steptrace.record_counters("attention/boundary", {
+        "tokens": seq, "heads": heads, "kv_heads": kv_heads,
+        "d_qk": d, "d_v": d_v, "window": window or 0,
+        "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0),
+        "model_results": int(results)})
+    return bool(a_tile or results)
+
+
 def causal_self_attention(q, k, v, attention: str = "auto",
                           window: Optional[int] = None):
     """Causal self-attention of ``q`` [B, T, H, d], ``k`` [B, T, H_kv, d]
@@ -482,15 +500,8 @@ def causal_self_attention(q, k, v, attention: str = "auto",
         attention = auto_attention(q, v)
     bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
-        (b, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
-        a_tile = heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
-        results = results_in_model_arrays(seq, d, d_v)
-        steptrace.record_counters("attention/boundary", {
-            "tokens": seq, "heads": heads, "kv_heads": kv_heads,
-            "d_qk": d, "d_v": d_v, "window": window or 0,
-            "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0),
-            "model_results": int(results)})
-        if a_tile or results:
+        (b, seq, heads, d), d_v = q.shape, v.shape[3]
+        if _boundary(q, k, v, window):
             lanes = lambda t: t.reshape(b, seq, -1)
             return flash_attention(
                 lanes(q), lanes(k), lanes(v), causal=True, window=window,
@@ -505,3 +516,159 @@ def causal_self_attention(q, k, v, attention: str = "auto",
                 window=window).transpose(0, 2, 1, 3)
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     raise ValueError(f"attention={attention!r}: expected auto, xla or flash")
+
+
+# ----------------------------------------------------------------------
+# a layer whose q and k pass a head norm and a rotation first: the
+# prologue's kernels (``ops/rotary.py``) and the flash kernels under one
+# ``custom_vjp``, so that XLA has nothing to lay out between two custom
+# calls
+# ----------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
+def _normed_rotary_flash(q, k, v, q_scale, k_scale, cos, sin, heads, eps,
+                         sm_scale, block_q, block_k, interpret, window):
+    """Causal self-attention of ``head_rotary(q)`` on ``head_rotary(k)`` and
+    v, the three as the projections wrote them, [B, T, heads x 128] (k and v
+    of as many heads as their widths say), on the ``model_results`` boundary
+    (``results_in_model_arrays``): -> [B, T, heads x 128]. ``cos``, ``sin``:
+    [T, 64] float32 or None."""
+    return _normed_rotary_flash_fwd(
+        q, k, v, q_scale, k_scale, cos, sin, heads, eps, sm_scale, block_q,
+        block_k, interpret, window)[0]
+
+
+def _normed_rotary_flash_fwd(q, k, v, q_scale, k_scale, cos, sin, heads, eps,
+                             sm_scale, block_q, block_k, interpret, window):
+    kv_heads = k.shape[2] // (q.shape[2] // heads)
+    prologue = functools.partial(rotary.head_rotary_fwd, cos=cos, sin=sin,
+                                 eps=eps, interpret=interpret)
+    # the flash kernels' own operands, straight from the prologue's kernel;
+    # nothing of them is named for ``remat_policy``: a recomputed block
+    # runs the prologue again, 0.4 ms a layer, and the plan keeps its room
+    qf = prologue(q, q_scale, heads=heads)
+    kf = prologue(k, k_scale, heads=kv_heads)
+    out, lse = map(ad_checkpoint.checkpoint_name, kernels._flash_pallas(
+        qf, kf, _folded(v, kv_heads), causal=True, heads=heads,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window), REMAT_NAMES)
+    return out, (q, k, v, q_scale, k_scale, cos, sin, qf, kf, out, lse)
+
+
+def _normed_rotary_flash_bwd(heads, eps, sm_scale, block_q, block_k,
+                             interpret, window, res, g):
+    q, k, v, q_scale, k_scale, cos, sin, qf, kf, out, lse = res
+    kv_heads = k.shape[2] // (q.shape[2] // heads)
+    # dQ^T stays the flash kernel's float32 [B x H, 128, T] sum: the
+    # prologue's backward kernel turns it tile by tile in VMEM; dK and dV
+    # arrive in the model's arrays
+    dq_t, dk, dv = kernels._flash_pallas_bwd_kernel(
+        qf, kf, _folded(v, kv_heads), g, lse, out, causal=True,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window, heads=heads, dq_turned=False)
+    back = functools.partial(rotary.head_rotary_bwd, cos=cos, sin=sin,
+                             eps=eps, interpret=interpret)
+    dq, dq_scale = back(dq_t, q, q_scale, heads=heads, turned=True)
+    dk, dk_scale = back(dk, k, k_scale, heads=kv_heads, turned=False)
+    return (dq, dk, dv, dq_scale.astype(q_scale.dtype),
+            dk_scale.astype(k_scale.dtype),
+            *jax.tree.map(jnp.zeros_like, (cos, sin)))
+
+
+_normed_rotary_flash.defvjp(_normed_rotary_flash_fwd,
+                            _normed_rotary_flash_bwd)
+
+
+def auto_head_rotary(q, v, cos, attention: str = "auto") -> str:
+    """What ``normed_rotary_self_attention(..., impl=None)`` runs for q [B,
+    T, H, d], v [B, T, H_kv, d_v] and the table ``cos`` (None: no rotation)
+    under ``attention``: "pallas", the prologue's kernel pair under one
+    ``custom_vjp`` with the flash kernels, or "jnp". Read from the call
+    alone, as ``auto_attention`` is: the attention comes to "flash" on the
+    ``model_results`` boundary (``results_in_model_arrays``), a Mosaic call
+    may run where ``q`` is traced (``mosaic.takes_kernels``), and
+    ``rotary.fits`` admits the head's width and the table."""
+    if attention == "auto":
+        attention = auto_attention(q, v)
+    return ("pallas" if _prologue_fits(q, v, cos, attention)
+            and takes_kernels(q) else "jnp")
+
+
+def _prologue_fits(q, v, cos, attention: str) -> bool:
+    """Whether the shapes admit the prologue's kernels under ``attention``
+    (``auto_head_rotary`` says what each part asks)."""
+    return (attention == "flash" and rotary.fits(q, cos)
+            and results_in_model_arrays(q.shape[1], q.shape[3], v.shape[3]))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "eps", "window", "interpret", "block_q", "block_k"))
+def _normed_rotary_kernels(q, k, v, q_scale, k_scale, cos, sin, *, heads,
+                           eps, window, interpret, block_q, block_k):
+    """``normed_rotary_self_attention``'s kernels for q [B, T, heads x 128],
+    k and v as the projections wrote them, handed to a mesh a batch shard
+    each. Jitted, as ``flash_attention`` is: layers of one shape share one
+    trace of the kernels' bodies (thousands of equations a flash kernel)
+    and one lowering, where a trace a layer cost the mellum2 cell's step
+    9 s of set-up (PERF.md section 6, PR 63)."""
+    seq = q.shape[1]
+    tables = () if cos is None else rotary.tables(cos, sin)
+
+    def kernel(q, k, v, q_scale, k_scale, *tables):
+        return _normed_rotary_flash(
+            q, k, v, q_scale, k_scale, *(tables or (None, None)), heads, eps,
+            (q.shape[2] // heads) ** -0.5, block_q, block_k, interpret,
+            kernels._window_of(window, seq))
+
+    return per_batch_shard(
+        kernel, q, (True,) * 3 + (False,) * (2 + len(tables)),
+        "normed_rotary_self_attention")(q, k, v, q_scale, k_scale, *tables)
+
+
+def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
+                                 eps: float, attention: str = "auto",
+                                 window: Optional[int] = None,
+                                 impl: Optional[str] = None,
+                                 block_q: Optional[int] = None,
+                                 block_k: Optional[int] = None):
+    """``causal_self_attention`` of a layer whose q [B, T, H, d] and k [B,
+    T, H_kv, d], as the projections wrote them, first pass an RMSNorm over
+    a head's width (``q_scale``, ``k_scale`` [d]; ``eps``) and, given a
+    layer kind's table ``cos``, ``sin`` [B or 1, T, d / 2] (None: normed and
+    not rotated), the rotation of the head's halves:
+    ``rotary.head_rotary``'s arithmetic, float32 with one rounding.
+
+    With ``impl=None`` the call's own shapes and surroundings choose
+    (``auto_head_rotary``): "pallas", ``ops/rotary.py``'s kernel pair under
+    one ``custom_vjp`` with the flash kernels (the forward kernel writes
+    their [B x H, T, d] operands from the projections' results, the backward
+    kernel reads their float32 dQ^T sum and their dK, and XLA lays out
+    nothing between), or "jnp" (off a TPU, under another mesh axis, heads 64
+    wide, a partial rotation, one block of keys a head, XLA's attention):
+    ``rotary.head_rotary`` and ``causal_self_attention``. ``impl`` forces
+    "pallas", "pallas_interpret" (the kernels, the flash pair included, with
+    ``block_q`` / ``block_k`` if given) or "jnp". One ``counters`` record
+    ``attention/head_rotary`` a traced call says which ran."""
+    if attention == "auto":
+        attention = auto_attention(q, v)
+    if impl is None:
+        impl = auto_head_rotary(q, v, cos, attention)
+    (b, seq, heads, d), d_v = q.shape, v.shape[3]
+    steptrace.record_counters("attention/head_rotary", {
+        "tokens": seq, "heads": heads, "kv_heads": k.shape[2], "head_dim": d,
+        "rotated": int(cos is not None), "kernel": int(impl != "jnp")})
+    if impl == "jnp":
+        prologue = functools.partial(rotary.head_rotary, cos=cos, sin=sin,
+                                     eps=eps)
+        return causal_self_attention(prologue(q, q_scale),
+                                     prologue(k, k_scale), v, attention,
+                                     window)
+    assert _prologue_fits(q, v, cos, attention), (q.shape, attention)
+    _boundary(q, k, v, window)
+    lanes = lambda t: t.reshape(b, seq, -1)
+    return _normed_rotary_kernels(
+        lanes(q), lanes(k), lanes(v), q_scale, k_scale, cos, sin,
+        heads=heads, eps=eps, window=window,
+        interpret=impl == "pallas_interpret", block_q=block_q,
+        block_k=block_k).reshape(b, seq, heads, d_v)

@@ -951,14 +951,17 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              sm_scale: float, block_q: Optional[int],
                              block_k: Optional[int], interpret: bool,
                              window: Optional[int] = None,
-                             heads: Optional[int] = None):
+                             heads: Optional[int] = None,
+                             dq_turned: bool = True):
     """-> (dq, dk, dv) of ``_flash_pallas``'s call, shaped as q, k and v
     [B x H, T, d] from ``do`` shaped as its output; given ``heads``, ``do``
     is the cotangent of the model's own [B, T, heads x d_v] output, read a
     head's lanes at a time, ``delta`` is that output itself, from which the
     kernel makes the rows, and dk, dv are written as model's arrays
     likewise, [B, T, key-value heads x width]; dq is [B x H, T, d] either
-    way."""
+    way, or without ``dq_turned`` dQ^T as the kernel leaves it, [B x H, d,
+    T] (float32 where a head has several blocks of keys), for a caller
+    whose own kernel reads it next (``ops/rotary.py``)."""
     b, q_len, d = q.shape
     b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
     group = b // b_kv
@@ -1063,6 +1066,8 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         interpret=interpret,
         name=_kernel_name("flash_bwd", window),
     )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
+    if not dq_turned:
+        return dq_t, dk, dv
     return jnp.swapaxes(dq_t.astype(q.dtype), 1, 2), dk, dv
 
 

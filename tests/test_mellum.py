@@ -132,7 +132,8 @@ def _as_reference(config):
             "expert_shard": {"index": index, "of": of}}
 
 
-@pytest.mark.parametrize("attention", ["xla", "scan", "kernel_results"])
+@pytest.mark.parametrize("attention", ["xla", "scan", "kernel_results",
+                                       "kernel_rotary"])
 def test_the_model_is_the_reference(attention, monkeypatch, request):
     """Logits, loss and every parameter's gradient, float32 on both sides:
     four layers (three window layers and a full one, every one routed), two
@@ -144,17 +145,22 @@ def test_the_model_is_the_reference(attention, monkeypatch, request):
     of keys a head in the window layers and two in the full one, the
     boundary the cell's calls take, the window half of what a grid step may
     hold and so, by ``flash_kernels._block_sizes``'s rule, a whole block of
-    its own (diagonal, trailing and dead blocks told apart)."""
+    its own (diagonal, trailing and dead blocks told apart), with the heads'
+    norm and the rotation XLA's; ``kernel_rotary`` is that boundary with
+    the prologue's kernel pair under the one ``custom_vjp``
+    (``ops.attention.normed_rotary_self_attention``, interpreted): the flash
+    kernels' operands written by ``head_rotary_fwd``, their float32 dQ^T
+    and their dK read by ``head_rotary_bwd``, both tables."""
     from ray_tpu.ops import attention as ops_attention, flash_kernels
 
     more = {}
     if attention == "scan":
         monkeypatch.setattr(
-            mellum, "causal_self_attention",
+            ops_attention, "causal_self_attention",
             lambda q, k, v, path, window: ops_attention.flash_attention(
                 *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
                 window=window, impl="scan", block_k=8).transpose(0, 2, 1, 3))
-    elif attention == "kernel_results":
+    else:
         more = {"head_dim": 128}
         monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", 16)
         monkeypatch.setattr(flash_kernels, "_WINDOW_RESIDENT_FROM", 8)
@@ -166,10 +172,21 @@ def test_the_model_is_the_reference(attention, monkeypatch, request):
                 ops_attention.flash_attention, impl="pallas_interpret",
                 block_q=8, block_k=8))
         assert ops_attention.results_in_model_arrays(32, 128, 128)
-        monkeypatch.setattr(
-            mellum, "causal_self_attention",
-            lambda q, k, v, path, window: ops_attention.causal_self_attention(
-                q, k, v, "flash", window))
+        if attention == "kernel_results":
+            real = ops_attention.causal_self_attention
+            monkeypatch.setattr(
+                ops_attention, "causal_self_attention",
+                lambda q, k, v, path, window: real(q, k, v, "flash", window))
+        else:
+            # the layers' calls ("flash"; the initialiser's are "xla")
+            monkeypatch.setattr(
+                mellum, "normed_rotary_self_attention",
+                lambda *a, attention, **kw:
+                ops_attention.normed_rotary_self_attention(
+                    *a, attention=attention, **kw, **(dict(
+                        impl="pallas_interpret", block_q=8, block_k=8)
+                        if attention == "flash" else {})))
+            more["attention"] = "flash"
         jax.clear_caches()  # flash_attention is jitted: the rule is read
         request.addfinalizer(jax.clear_caches)
     config, model, params, batch = _small(expert_shard=(1, 2), **more)
@@ -346,7 +363,14 @@ def test_recomputation_changes_no_value_and_keeps_the_kernels_output(
     """With ``remat`` the gradient is the same to the bit; on a TPU (where
     ``auto`` is the kernel at head width 128) a step's jaxpr holds one
     forward and one backward call a layer, windowed in the three window
-    layers, and no forward call again: ``ops.remat.remat_policy``."""
+    layers, and no forward call again: ``ops.remat.remat_policy``. Where a head is
+    several blocks of keys (the ``model_results`` boundary, the cell's) the
+    heads' norm and the rotation are ``ops/rotary.py``'s kernels, of which
+    nothing is kept: ``head_rotary_fwd`` for q and for k a layer, run and
+    recomputed, ``head_rotary_bwd`` once for each, the flash calls as
+    before."""
+    from ray_tpu.ops import flash_kernels
+
     config, model, params, batch = _small()
     grad = lambda c: jax.jit(jax.grad(lambda p: mellum.loss_fn(
         p, mellum.Mellum(c), batch)[0]))
@@ -361,14 +385,22 @@ def test_recomputation_changes_no_value_and_keeps_the_kernels_output(
     _, wide_params = mellum.init_params(wide, jax.random.PRNGKey(0))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()
-    try:
-        ids = jnp.zeros((1, 512), jnp.int32)
-        jaxpr = jax.make_jaxpr(jax.grad(lambda p: mellum.loss_fn(
+    def step_calls(seq):
+        ids = jnp.zeros((1, seq), jnp.int32)
+        return kernel_calls(jax.make_jaxpr(jax.grad(lambda p: mellum.loss_fn(
             p, mellum.Mellum(wide), {"input_ids": ids, "labels": ids})[0]))(
-                wide_params)
+                wide_params))
+
+    try:
+        calls = step_calls(512)
+        monkeypatch.setattr(flash_kernels, "_MAX_RESIDENT", 256)
+        jax.clear_caches()
+        blocks = step_calls(1024)     # four blocks of keys a head
     finally:
         jax.clear_caches()
-    calls = kernel_calls(jaxpr)
+    assert (blocks.pop("head_rotary_fwd"), blocks.pop("head_rotary_bwd")) == (
+        4 * wide.num_hidden_layers, 2 * wide.num_hidden_layers)
+    assert blocks == calls
     calls.pop("unwritten")
     assert calls == {
         "flash_fwd_w128": 3, "flash_bwd_w128": 3, "flash_fwd": 1,

@@ -119,8 +119,8 @@ _HELD_PROGRAMS = {
     "lfm2-8b-a1b.step-8k": "73a6b74898269edd",   # PR 61
     "qwen3-next-80b-a3b.step-8k": "9d394435fa05898f",   # PR 61
     "nemotron-3-nano-30b-a3b.step-8k": "f4a4c6300c4b51cf",   # PR 61
-    "trinity-mini.step-16k": "2f1a9030f3b267be",   # PR 61
-    "mellum2-12b-a2.5b.step-8k": "0df9243568848ab6",   # PR 62
+    "trinity-mini.step-16k": "dcb2f4871e1e02db",   # PR 63
+    "mellum2-12b-a2.5b.step-8k": "d57b050e2373b2da",   # PR 63
 }
 
 
@@ -632,6 +632,69 @@ def _dq_census(text, heads, d, seq):
     assert not re.search(rf"f32\[\d+,{heads},{d},{seq}\]", text)
 
 
+def _q_sized_census(text, elements):
+    """{(kind, result): instructions} of the compiled step, outside fused
+    computations, whose result (or one of a tuple's) is a float32 or
+    bfloat16 array of ``elements`` entries, a layer's q: a fusion by its
+    name's kind (``pad_maximum_fusion``), any other by its operation
+    (``copy``, ``custom-call``). At the parent of PR 63 the mellum2 cell's
+    step held 76 such (12 ``copy f32[2,8192,32,128]``, 8 ``fusion`` and 8
+    ``broadcast`` of that result, 8 ``pad_maximum_fusion``, 4 ``copy
+    f32[2,32,8192,128]``, 4 ``convert_convert_fusion f32[64,128,8192]``, the
+    projections' results widened to float32) and the window cell's 102:
+    the heads' norm and the rotation as XLA made them (PERF.md section 6)."""
+    found, fused = collections.Counter(), False
+    for line in text.splitlines():
+        if line and not line[0].isspace():    # a computation's first line
+            fused = line.endswith("{") and bool(
+                re.match(r"%?(fused_computation|region)", line))
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if fused or not m or m.group(3) in (
+                "parameter", "bitcast", "get-tuple-element", "tuple",
+                "constant"):
+            continue
+        name, result, op = m.groups()
+        for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", result):
+            if np.prod([int(n) for n in dims.split(",")]) == elements:
+                kind = re.sub(r"[.\d]+$", "", name) if op == "fusion" else op
+                found[kind, f"{dtype}[{dims}]"] += 1
+    return found
+
+
+def _head_rotary_census(text, counters, q_elements, layers, rotated):
+    """Since PR 63 the heads' norm and the rotation between the q / k
+    projections and the flash kernels are ``ops/rotary.py``'s kernel pair:
+    in the compiled step ``head_rotary_fwd`` for q and for k a layer, run
+    and recomputed (nothing of it is named for ``remat_policy``), and
+    ``head_rotary_bwd`` once for each; every traced layer said so
+    (``attention/head_rotary``: ``kernel`` 1, ``rotated`` in ``rotated`` of
+    them); and between a projection and a flash call XLA makes nothing of
+    q's size: no ``copy``, ``convert`` or ``transpose`` of that size in
+    either type, and nothing float32 of it but the flash backward's own
+    dQ^T sum, which the backward kernel reads as it is."""
+    said = [e["args"] for e in counters
+            if e["name"] == "attention/head_rotary"]
+    assert [e["kernel"] for e in said] == [1] * len(said), said
+    assert {(e["heads"], e["kv_heads"], e["head_dim"])
+            for e in said} == {(32, 4, 128)}
+    assert len(said) % layers == 0 and (
+        sum(e["rotated"] for e in said) * layers == rotated * len(said))
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(head_rotary_(?:fwd|bwd))[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"head_rotary_fwd": 4 * layers,
+                     "head_rotary_bwd": 2 * layers}
+    census = _q_sized_census(text, q_elements)
+    print("results of q's size:", dict(census))
+    assert {kind for kind, result in census
+            if result.startswith("f32")} == {"custom-call"}, census
+    assert sum(n for (_, result), n in census.items()
+               if result.startswith("f32")) == layers     # dQ^T sums
+    assert not {kind for kind, _ in census} & {"copy", "convert",
+                                               "transpose"}, census
+
+
 def test_latent_attention_expert_step_fits_one_chip_at_8k(
         topo, no_compile_cache, on_tpu):
     """The cut configuration of the cell ``joyai-llm-flash.step-8k`` (layer
@@ -813,6 +876,7 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
                      "flash_bwd_w2048": 4}
     assert "bf16[32,16384,128]" in text and "bf16[4,16384,128]" in text
     _dq_census(text, 32, 128, seq)
+    _head_rotary_census(text, counters, seq * 32 * 128, layers=5, rotated=4)
     # round the ten calls XLA laid the kernel's output out three times and
     # its cotangent twice (PR 53's kept trace: 16 copies a step of
     # ``bf16[1,16384,32,128]``, 15 of ``bf16[16384,4096]`` and 5 of the 10
@@ -1326,8 +1390,8 @@ def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
         by_name[e["name"]].append(e["args"])
     assert set(by_name) == {"attn/grid_blocks", "rope/table",
                             "model/layer_kinds", "attention/boundary",
-                            "moe/row_buffers", "moe/to_tokens",
-                            "moe/grouped_matmul"}
+                            "attention/head_rotary", "moe/row_buffers",
+                            "moe/to_tokens", "moe/grouped_matmul"}
     assert by_name["model/layer_kinds"][-1] == {
         "sliding_attention": 3, "full_attention": 1, "expert": 4,
         "layers": 4, "published_layers": 28}
@@ -1367,6 +1431,8 @@ def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
                      "flash_bwd_w1024": 3}
     assert "bf16[64,8192,128]" in text and "bf16[8,8192,128]" in text
     _dq_census(text, 64, 128, seq)
+    _head_rotary_census(text, counters, batch * seq * 32 * 128, layers=4,
+                        rotated=4)
     tokens = batch * seq
     # blocks of 256 tokens: at 2,304 wide a block of 512 and its chunks'
     # slots do not fit the kernel's VMEM (``ops.moe._token_blocks``)
