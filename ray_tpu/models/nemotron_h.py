@@ -25,7 +25,8 @@ config gives), for training.
     x_t``, head ``h`` reading group ``h // (heads / groups)`` (on a TPU the
     kernels ``ssd_fwd`` / ``ssd_bwd``). Then ``y = GroupRMSNorm(y *
     silu(z))``, the norm over each of ``n_groups`` groups of channels with
-    one weight a channel, and ``y W_out``.
+    one weight a channel (``ops.norm.gated_group_rms_norm``; on a TPU the
+    kernels ``group_norm_fwd`` / ``group_norm_bwd``), and ``y W_out``.
   - ``*`` (``Attention``): ``num_attention_heads`` query heads on
     ``num_key_value_heads`` key-value heads of ``head_dim``, causal softmax
     at ``head_dim^-0.5``, NO positional term (the state-space blocks carry
@@ -68,6 +69,7 @@ from ray_tpu.models.mla_moe import RoutedExperts
 from ray_tpu.ops import xent
 from ray_tpu.ops.attention import causal_self_attention
 from ray_tpu.ops.conv import causal_conv
+from ray_tpu.ops.norm import gated_group_rms_norm
 from ray_tpu.ops.remat import remat_policy
 from ray_tpu.ops.ssm import ssd_scan
 from ray_tpu.parallel import train_step
@@ -204,20 +206,18 @@ def dt_bias_init(low: float, high: float, floor: float):
 class GroupRMSNorm(nn.Module):
     """``x / rms(x) * w`` with the mean square over each of ``groups`` equal
     groups of the last axis, one weight a channel (initialised 1), of ``y *
-    silu(z)``; float32 until its one rounding."""
+    silu(z)``; float32 until its one rounding
+    (``ops.norm.gated_group_rms_norm``: a kernel pair where its shapes and
+    surroundings admit one)."""
     groups: int
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, y, z):
-        width = y.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (width,))
-        gated = (y.astype(_F32) * jax.nn.silu(z.astype(_F32))).reshape(
-            *y.shape[:-1], self.groups, width // self.groups)
-        normed = gated * jax.lax.rsqrt(
-            jnp.mean(gated * gated, axis=-1, keepdims=True) + self.eps)
-        return (normed.reshape(y.shape) * scale).astype(self.dtype)
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
+        return gated_group_rms_norm(y, z, scale, groups=self.groups,
+                                    eps=self.eps).astype(self.dtype)
 
 
 class Mamba2Mixer(nn.Module):

@@ -1,12 +1,12 @@
 """ray_tpu.ops — TPU kernels (Pallas), sequence-parallel attention, the
 selective and the scalar-decay scan of state-space layers, the gated delta
-rule of linear-attention layers, the short convolutions, expert layers and
-the vocabulary's loss.
+rule of linear-attention layers, the short convolutions, the gated grouped
+norm, expert layers and the vocabulary's loss.
 
 Who knows whom, arrows one way: ``models/*`` -> ``remat`` (what a recomputed
 block keeps) and the op modules ``attention`` (its kernels:
 ``flash_kernels``, and ``rotary``, the kernels of a normed, rotated layer's
-prologue), ``ssm``, ``conv``, ``delta``, ``moe`` -> ``chunks`` (the
+prologue), ``ssm``, ``conv``, ``norm``, ``delta``, ``moe`` -> ``chunks`` (the
 chunk scheme ``delta`` and ``ssm.ssd_scan`` share) and ``mosaic`` (where a
 Pallas kernel may run, how it is handed to a mesh, the compiler's
 parameters) -> ``parallel/mesh_utils``. A new kernel module names its own
@@ -22,14 +22,16 @@ from ray_tpu.ops.attention import (
 )
 from ray_tpu.ops.conv import causal_conv, gated_short_conv
 from ray_tpu.ops.delta import gated_delta_rule
+from ray_tpu.ops.norm import gated_group_rms_norm
 from ray_tpu.ops.ring_attention import ring_attention, ring_self_attention
 from ray_tpu.ops.ssm import selective_scan, ssd_scan
-from ray_tpu.ops import conv, delta, moe, ssm, xent
+from ray_tpu.ops import conv, delta, moe, norm, ssm, xent
 
 __all__ = [
     "conv",
     "delta",
     "moe",
+    "norm",
     "ssm",
     "xent",
     "attention_reference",
@@ -37,6 +39,7 @@ __all__ = [
     "finalize_flash",
     "flash_attention",
     "gated_delta_rule",
+    "gated_group_rms_norm",
     "gated_short_conv",
     "online_block_update",
     "ring_attention",

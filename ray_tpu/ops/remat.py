@@ -1,7 +1,9 @@
 """What a recomputed block keeps of the kernels of ``ray_tpu/ops``: each
 kernel module names its forward rule's two outputs beside that rule; here
 they meet in one policy. A new kernel adds its names to its own file and one
-line here.
+line here. Kernels that name nothing are made again in a recomputed block:
+``rotary``'s prologue pair, ``conv``'s convolutions and ``norm``'s gated
+grouped norm, each one pass over its operands.
 """
 
 from __future__ import annotations

@@ -940,16 +940,21 @@ def _ssd_diff(x, dt, la, b, c, skip, chunk, stride, impl):
 
 
 # What recomputation keeps of the scalar-decay scan
-# (``ops.remat.remat_policy``): its output [B, T, heads, head_dim] in the
+# (``ops.remat.remat_policy``): its output [B, T, heads x head_dim] in the
 # compute dtype and the state each stride starts from, [B, T / stride, heads,
 # head_dim, states] float32, no more bytes than the output
 SSD_REMAT_NAMES = ("ssd_out", "ssd_bounds")
 
 
 def _ssd_diff_fwd(x, dt, la, b, c, skip, chunk, stride, impl):
-    y, bounds = map(ad_checkpoint.checkpoint_name,
-                    _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl),
-                    SSD_REMAT_NAMES)
+    y, bounds = _ssd_forward(x, dt, la, b, c, skip, chunk, stride, impl)
+    # kept as the kernels wrote it, [B, T, heads x head_dim]: a kept [B, T,
+    # 64, 64] array crosses into the recomputed block with the tokens minor
+    # (64 lanes would half fill a tile), a relayout each way (PERF.md
+    # section 6, PR 64)
+    y = ad_checkpoint.checkpoint_name(
+        y.reshape(*y.shape[:2], -1), SSD_REMAT_NAMES[0]).reshape(y.shape)
+    bounds = ad_checkpoint.checkpoint_name(bounds, SSD_REMAT_NAMES[1])
     return y, (x, dt, la, b, c, skip, bounds)
 
 

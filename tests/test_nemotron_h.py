@@ -309,29 +309,39 @@ def test_the_shares_add_up_to_the_uncut_layer(of):
                 rtol=2e-4, atol=2e-5)
 
 
-def test_the_grouped_gated_norm():
+@pytest.mark.parametrize("shape,impl", [((4, 32), None),
+                                        ((2, 32, 512), "pallas_interpret")],
+                         ids=["twin", "kernels"])
+def test_the_grouped_gated_norm(monkeypatch, shape, impl):
     """``y * silu(z)`` first, then ``/ rms`` over each group of channels,
-    one weight a channel: a group's scale does not move another's."""
-    y = 3.0 * jax.random.normal(jax.random.PRNGKey(0), (4, 32))
-    z = jax.random.normal(jax.random.PRNGKey(1), (4, 32))
+    one weight a channel: a group's scale does not move another's. Through
+    ``ops.norm``'s twin, and through its kernel pair (interpreted) at a
+    shape they take."""
+    if impl:
+        monkeypatch.setattr(nemotron_h, "gated_group_rms_norm",
+                            functools.partial(
+                                nemotron_h.gated_group_rms_norm, impl=impl))
+    width, run = shape[-1], shape[-1] // 4
+    y = 3.0 * jax.random.normal(jax.random.PRNGKey(0), shape)
+    z = jax.random.normal(jax.random.PRNGKey(1), shape)
     norm = nemotron_h.GroupRMSNorm(4, 1e-5, jnp.float32)
     params = norm.init(jax.random.PRNGKey(2), y, z)["params"]
-    np.testing.assert_array_equal(params["scale"], np.ones(32))
-    scale = 1.0 + 0.1 * np.arange(32, dtype=np.float32)
+    np.testing.assert_array_equal(params["scale"], np.ones(width))
+    scale = 1.0 + 0.1 * np.arange(width, dtype=np.float32) * 32 / width
     got = norm.apply({"params": {"scale": scale}}, y, z)
     gated = np.asarray(y, np.float64) * np.asarray(jax.nn.silu(z), np.float64)
-    groups = gated.reshape(4, 4, 8)
+    groups = gated.reshape(*shape[:-1], 4, run)
     want = (groups / np.sqrt((groups ** 2).mean(-1, keepdims=True) + 1e-5)
-            ).reshape(4, 32) * scale
+            ).reshape(shape) * scale
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-    louder = y.at[:, :8].multiply(100.0)
+    louder = y.at[..., :run].multiply(100.0)
     np.testing.assert_allclose(
-        norm.apply({"params": {"scale": scale}}, louder, z)[:, 8:],
-        got[:, 8:], rtol=1e-5, atol=1e-6)
+        norm.apply({"params": {"scale": scale}}, louder, z)[..., run:],
+        got[..., run:], rtol=1e-5, atol=1e-6)
     # ungated-then-normed would differ: the gate is inside the statistics
-    other = (np.asarray(y) / np.sqrt((np.asarray(y).reshape(4, 4, 8) ** 2
-                                      ).mean(-1, keepdims=True) + 1e-5
-                                     ).repeat(8, -1).reshape(4, 32)
+    other = (np.asarray(y) / np.sqrt(
+        (np.asarray(y).reshape(*shape[:-1], 4, run) ** 2
+         ).mean(-1, keepdims=True) + 1e-5).repeat(run, -1).reshape(shape)
              ) * np.asarray(jax.nn.silu(z)) * scale
     assert np.abs(other - want).max() > 0.1
 

@@ -118,7 +118,7 @@ _HELD_PROGRAMS = {
     "phi-4-mini-flash.step-one-seq": "d1a800cb91c9c326",
     "lfm2-8b-a1b.step-8k": "73a6b74898269edd",   # PR 61
     "qwen3-next-80b-a3b.step-8k": "9d394435fa05898f",   # PR 61
-    "nemotron-3-nano-30b-a3b.step-8k": "f4a4c6300c4b51cf",   # PR 61
+    "nemotron-3-nano-30b-a3b.step-8k": "a33f055570590fef",   # PR 64
     "trinity-mini.step-16k": "dcb2f4871e1e02db",   # PR 63
     "mellum2-12b-a2.5b.step-8k": "d57b050e2373b2da",   # PR 63
 }
@@ -266,6 +266,45 @@ def test_causal_conv_kernels_compile(topo, no_compile_cache, backward):
                      else {"causal_conv_fwd": 1})
     assert not re.findall(r" (?:pad|slice|copy|transpose)\(", text)
     # the taps' gradient: two sequences' float32 partial sums added
+    assert len(re.findall(r" reduce\(", text)) <= (1 if backward else 0)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd_bwd"])
+def test_group_norm_kernels_compile(topo, no_compile_cache, backward):
+    """A Mamba-2 block's gated, grouped norm in
+    ``nemotron-3-nano-30b-a3b.step-8k`` (PR 64): two sequences of 8,192
+    tokens of 4,096 channels in 8 groups, bfloat16. One ``group_norm_fwd``
+    and, in the gradient, one ``group_norm_bwd`` (whose own forward is not
+    run: the backward makes the statistic again), blocks of 256 and of 128
+    tokens with all 4,096 lanes; beside them nothing of the XLA form's
+    passes: no copy into the groups' view, no broadcast out of it, nothing
+    float32 the size of the operands."""
+    from ray_tpu.ops import norm
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    assert norm.fits(x, 8)
+    assert norm.block_tokens(8192, 4096, 2, backward) == (
+        128 if backward else 256)
+
+    def fwd(y, z, scale):
+        return norm.gated_group_rms_norm(y, z, scale, groups=8, eps=1e-5,
+                                         impl="pallas")
+
+    def grads(y, z, scale, do):
+        return jax.vjp(fwd, y, z, scale)[1](do)
+
+    args = (x, x, scale, x) if backward else (x, x, scale)
+    text = jax.jit(grads if backward else fwd).lower(*args).compile().as_text()
+    calls = collections.Counter(re.findall(
+        r"(group_norm_(?:fwd|bwd))[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text))
+    assert calls == ({"group_norm_bwd": 1} if backward
+                     else {"group_norm_fwd": 1})
+    assert not re.findall(r" (?:copy|transpose|broadcast|convert)\(", text)
+    assert not re.search(r"f32\[2,8192,", text)
+    # the scale's gradient: 128 grid steps' float32 partial sums added
     assert len(re.findall(r" reduce\(", text)) <= (1 if backward else 0)
 
 
@@ -693,6 +732,42 @@ def _head_rotary_census(text, counters, q_elements, layers, rotated):
                if result.startswith("f32")) == layers     # dQ^T sums
     assert not {kind for kind, _ in census} & {"copy", "convert",
                                                "transpose"}, census
+
+
+def _group_norm_census(text, counters, blocks, batch, seq, width, groups):
+    """Since PR 64 a Mamba-2 block's gated, grouped norm is ``ops/norm.py``'s
+    kernel pair, forward, forward again in the recomputed block (nothing
+    of it is named for ``remat_policy``) and backward: every traced pass
+    said so (``norm/gated_group``: ``kernel`` 1, three records a block);
+    and of the XLA form's passes nothing is left (ISSUE 64's
+    table, 88 instructions at the parent): no result in the groups' float32
+    view ([.., groups, W / groups], its tiles' [T / 8, groups, 8, W /
+    groups]) nor the scan's output widened a head at a time, no ``copy``,
+    ``reshape`` or ``broadcast`` that writes a float32 [B, T, W], and no
+    ``copy`` of a [B, T, W] array in the compute type: the kept scan output
+    goes into the recomputed block as the kernels wrote it, and the
+    reshapes round the norm lower to nothing."""
+    tokens = batch * seq
+    said = [e["args"] for e in counters if e["name"] == "norm/gated_group"]
+    assert said == [
+        {"tokens": tokens, "width": width, "groups": groups,
+         "bytes_needed": tokens * width * 2 * (5 if e["backward"] else 3),
+         "backward": e["backward"], "kernel": 1} for e in said], said
+    assert [e["backward"] for e in said].count(1) == blocks
+    assert len(said) == 3 * blocks, said
+    census = _q_sized_census(text, tokens * width)
+    print("results of the norm's size:", dict(census))
+    run = width // groups
+    for kind, result in census:
+        assert result not in (
+            f"f32[{batch},{seq},{groups},{run}]",
+            f"f32[{tokens // 8},{groups},8,{run}]",
+            f"f32[{batch},{seq},64,64]"), (kind, result)
+        if result.endswith(f"[{batch},{seq},{width}]"):
+            assert kind != "copy" and (
+                result.startswith("bf16")
+                or kind not in ("reshape", "broadcast")), (kind, result)
+    assert not re.search(rf"= f32\[{batch},{seq},{groups}\]", text)
 
 
 def test_latent_attention_expert_step_fits_one_chip_at_8k(
@@ -1259,7 +1334,10 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
     output and boundary states by ``ops.remat.remat_policy``); its
     convolutions (x, B and C each on its own, with the bias) are the pair
     ``causal_conv_fwd`` / ``causal_conv_bwd``: forward, forward again in the
-    recomputed block, backward; attention is one flash call each way. Each
+    recomputed block, backward; the gated, grouped norm between the scan and
+    the out-projection is the pair ``group_norm_fwd`` / ``group_norm_bwd``
+    in the same three passes (``_group_norm_census``, PR 64); attention is
+    one flash call each way. Each
     traced call wrote its record into the runtime's ring. No array is shaped
     like a [T, T] score matrix, none like a state a position ([.., T, heads,
     64, 128])."""
@@ -1289,7 +1367,7 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
     assert set(by_name) == {"attn/grid_blocks", "ssd/scan", "conv/causal",
                             "model/layer_kinds", "attention/boundary",
                             "moe/row_buffers", "moe/to_tokens",
-                            "moe/grouped_matmul"}
+                            "moe/grouped_matmul", "norm/gated_group"}
     assert by_name["model/layer_kinds"][-1] == {
         "mamba": 4, "attention": 1, "expert": 4, "layers": 9,
         "published_layers": 52}
@@ -1316,15 +1394,18 @@ def test_mamba2_relu2_expert_step_fits_one_chip_at_two_8k_sequences(
     assert 3 * 4 * n_params < planned < 14.5 * 2**30
     text = compiled.as_text()
     calls = collections.Counter(re.findall(
-        r"^\s*%?((?:flash|ssd|causal_conv)_(?:fwd|bwd)(?:_w\d+)?)"
+        r"^\s*%?((?:flash|ssd|causal_conv|group_norm)_(?:fwd|bwd)(?:_w\d+)?)"
         r'[\w.\-]* = .*custom_call_target="tpu_custom_call"', text, re.M))
-    # three convolutions a Mamba block: forward, forward again in the
-    # recomputed block, backward; the scan's forward is NOT run again
+    # three convolutions and one norm a Mamba block: forward, forward again
+    # in the recomputed block, backward; the scan's forward is NOT run again
     assert calls == {"flash_fwd": 1, "flash_bwd": 1, "ssd_fwd": 4,
                      "ssd_bwd": 4, "causal_conv_fwd": 24,
-                     "causal_conv_bwd": 12}
+                     "causal_conv_bwd": 12, "group_norm_fwd": 8,
+                     "group_norm_bwd": 4}
     assert "f32[2,32,32,128,128]" in text     # a boundary every 256 positions
     _dq_census(text, 64, 128, seq)
+    _group_norm_census(text, counters, blocks=4, batch=batch, seq=seq,
+                       width=4096, groups=8)
     _to_tokens_census(text, counters, tokens, model["num_experts_per_tok"],
                       model["hidden_size"], model["n_routed_experts"],
                       calls=2 * 4, block=256)
