@@ -241,6 +241,10 @@ def main():
     parser.add_argument("--reps", type=int, default=10)
     parser.add_argument("--kv-heads", type=int, default=None)
     parser.add_argument("--window", type=int, default=None)
+    parser.add_argument("--blocks", type=int, default=None,
+                        help="the block-diffusion mask over two streams of "
+                             "half the length each, in blocks of this many "
+                             "tokens, in place of the causal mask (PR 65)")
     parser.add_argument("--check", type=int, default=0)
     parser.add_argument("--residents", type=int, default=None,
                         help="the most queries, and keys, a grid step holds "
@@ -329,7 +333,8 @@ def main():
             return (out, *vjp(w))
 
         kernel = jax.jit(lambda q, k, v, w: out_and_grads(
-            lambda *x: causal_self_attention(*x, "flash", args.window),
+            lambda *x: causal_self_attention(*x, "flash", args.window,
+                                             args.blocks),
             q, k, v, w))
         bhsd = lambda t: t.transpose(0, 2, 1, 3)
 
@@ -339,7 +344,8 @@ def main():
                 return out_and_grads(
                     lambda *x: bhsd(attention_reference(
                         *(bhsd(t) for t in x), causal=True,
-                        window=args.window)), f32(q), f32(k), f32(v), w)
+                        window=args.window, blocks=args.blocks)),
+                    f32(q), f32(k), f32(v), w)
 
         got = kernel(q, k, v, w)
         group = args.heads // kv_heads
@@ -389,6 +395,15 @@ def main():
                                                     window=args.window),
                     "grid_blocks_bwd": grid_block_kinds(
                         seq, seq, True, backward=True, window=args.window)}
+            if args.blocks:
+                line.update(
+                    blocks=args.blocks, boundary=(
+                        "model_results" if results(seq, d_qk, d_v)
+                        else "xla_copies"),
+                    grid_blocks=flash_kernels.by_block_kinds(
+                        seq, args.blocks),
+                    grid_blocks_bwd=flash_kernels.by_block_kinds(
+                        seq, args.blocks, backward=True))
             for path in ("flash", "xla"):
                 if path == "xla" and seq > 4096:
                     continue
@@ -396,7 +411,8 @@ def main():
                 def loss(q, k, v, w, path=path):
                     out = causal_self_attention(
                         shaped(q, args.heads), shaped(k, kv_heads),
-                        shaped(v, kv_heads), path, args.window)
+                        shaped(v, kv_heads), path, args.window,
+                        args.blocks)
                     return (out.reshape(w.shape).astype(jnp.float32)
                             * w).sum()
 

@@ -22,10 +22,14 @@
   both from two tables (``llama.rope_table``: plain and YaRN-scaled), every
   feed-forward softmax-routed experts with no shared expert and no dense
   layer (training)
+- sdar: block diffusion over two streams, a noisy and a clean copy of a
+  sequence under one mask by block (``ops.attention.seen_by_block``), a
+  weighted loss over the masked positions, the noise drawn inside the step;
+  every feed-forward softmax-routed experts (training)
 """
 
 from ray_tpu.models import (afmoe, gpt2, llama, mellum, mla_moe, moe_lm,
-                            nemotron_h, phi4flash, qwen3_next, vision)
+                            nemotron_h, phi4flash, qwen3_next, sdar, vision)
 
 __all__ = ["afmoe", "gpt2", "llama", "mellum", "mla_moe", "moe_lm",
-           "nemotron_h", "phi4flash", "qwen3_next", "vision"]
+           "nemotron_h", "phi4flash", "qwen3_next", "sdar", "vision"]

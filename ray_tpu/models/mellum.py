@@ -147,9 +147,11 @@ def _init(c: MellumConfig):
 
 class Attention(nn.Module):
     """One attention layer; ``window`` None is a full layer. ``cos``, ``sin``
-    are its kind's table."""
+    are its kind's table. ``blocks`` (``models/sdar.py``'s layers): the T
+    positions are two streams under the block-diffusion mask."""
     config: MellumConfig
     window: Any = None
+    blocks: Any = None
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -165,7 +167,8 @@ class Attention(nn.Module):
         # each head's q and k normed over its width, then turned
         y = normed_rotary_self_attention(
             q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
-            eps=c.rms_norm_eps, attention=c.attention, window=self.window)
+            eps=c.rms_norm_eps, attention=c.attention, window=self.window,
+            blocks=self.blocks)
         return dense(c.hidden_size, "o_proj")(
             on_batch_axes(y.reshape(B, T, H * D)))
 
@@ -174,12 +177,14 @@ class Block(nn.Module):
     """-> (x, tokens per held expert)."""
     config: MellumConfig
     window: Any = None
+    blocks: Any = None
 
     @nn.compact
     def __call__(self, x, cos, sin):
         c = self.config
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
-        x = on_batch_axes(x + Attention(c, self.window, name="attn")(
+        x = on_batch_axes(x + Attention(c, self.window, self.blocks,
+                                        name="attn")(
             norm("input_norm")(x), cos, sin))
         y, tokens = RoutedExperts(
             experts=c.num_experts, expert_shard=c.expert_shard,

@@ -59,17 +59,39 @@ def _seen(qi, ki, window: Optional[int]):
     return (qi >= ki) if window is None else (qi >= ki) & (qi - ki < window)
 
 
+def seen_by_block(qi, ki, blocks: int, half: int):
+    """Whether, under the block-diffusion mask over two streams of ``half``
+    positions, a noisy copy [0, half) and a clean copy [half, 2 half), both
+    cut into blocks of ``blocks`` tokens, the query at ``qi`` sees the key
+    at ``ki``: a noisy query the noisy keys of its own block and the clean
+    keys of the blocks before it; a clean query the clean keys of its own
+    block and of those before it, and no noisy key."""
+    q_block, k_block = (qi % half) // blocks, (ki % half) // blocks
+    return jnp.where(
+        qi < half,
+        jnp.where(ki < half, k_block == q_block, k_block < q_block),
+        (ki >= half) & (k_block <= q_block))
+
+
 def attention_reference(q, k, v, *, causal: bool = False,
                         sm_scale: Optional[float] = None,
-                        window: Optional[int] = None) -> jax.Array:
+                        window: Optional[int] = None,
+                        blocks: Optional[int] = None) -> jax.Array:
     """Naive softmax(QK^T)V. Shapes: (..., h, s, d); ``k`` and ``v`` may
     have fewer heads, each read by a group of query heads. Under ``window``
-    a query sees its own position and the ``window - 1`` before it."""
+    a query sees its own position and the ``window - 1`` before it; given
+    ``blocks``, the mask is ``seen_by_block``'s over two streams of s / 2."""
     sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     assert causal or window is None, "a window is a causal mask's"
     k, v = _per_query_head(q, k), _per_query_head(q, v)
     s = jnp.einsum("...qd,...kd->...qk", q, k) * sm_scale
-    if causal:
+    if blocks:
+        q_len, k_len = s.shape[-2], s.shape[-1]
+        assert q_len == k_len and window is None, (q_len, k_len, window)
+        qi = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
+        ki = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
+        s = jnp.where(seen_by_block(qi, ki, blocks, q_len // 2), s, NEG_INF)
+    elif causal:
         q_len, k_len = s.shape[-2], s.shape[-1]
         qi = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
         ki = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
@@ -210,18 +232,19 @@ def results_in_model_arrays(seq_len: int, d: int, d_v: int) -> bool:
     return seq_len > kernels._MAX_RESIDENT and d == d_v and d % 128 == 0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_pallas_diff(q, k, v, causal, sm_scale, block_q, block_k,
-                       interpret, window=None, heads=None):
+                       interpret, window=None, heads=None, blocks=None):
     """Differentiable Pallas flash attention: both directions are Pallas
     kernels (forward saves the logsumexp; one backward kernel recomputes P
     per tile from q,k,lse — O(seq) memory, no attention matrix ever
     materialized). q, k, v are the kernels' own [B x H, T, d] or, given
     ``heads``, a model's [B, T, heads x d] (``heads_a_lane_tile``, or past
     one block of keys ``results_in_model_arrays``, where k and v may hold
-    fewer heads)."""
+    fewer heads). ``blocks``: the block-diffusion mask's block length."""
     return _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret, window, heads)[0]
+                          interpret, window, heads, blocks)[0]
 
 
 def _folded(x, heads: int):
@@ -246,16 +269,19 @@ def _folded_operands(q, k, v, heads: int):
 
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                   window, heads):
+                   window, heads, blocks=None):
     """(out, lse) by the boundary the operands are in."""
     tiles = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
                  interpret=interpret, window=window)
     if heads is None:
-        return kernels._flash_pallas(q, k, v, causal=causal, **tiles)
+        return kernels._flash_pallas(q, k, v, causal=causal, blocks=blocks,
+                                     **tiles)
     assert causal, "a model's own arrays are causal self-attention's"
     if q.shape[1] > kernels._MAX_RESIDENT:   # ``results_in_model_arrays``
         return kernels._flash_pallas(*_folded_operands(q, k, v, heads),
-                                     causal=True, heads=heads, **tiles)
+                                     causal=True, heads=heads, blocks=blocks,
+                                     **tiles)
+    # never under ``blocks``: ``_boundary`` keeps the lanes' kernels off
     return kernels._flash_pallas_lanes(q, k, v, heads=heads, **tiles)
 
 
@@ -282,15 +308,15 @@ REMAT_NAMES = ("flash_out", "flash_lse")
 
 
 def _flash_pallas_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                      window, heads):
+                      window, heads, blocks):
     out, lse = map(ad_checkpoint.checkpoint_name, _flash_forward(
         q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
-        heads), REMAT_NAMES)
+        heads, blocks), REMAT_NAMES)
     return out, (q, k, v, out, lse)
 
 
 def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
-                      heads, res, g):
+                      heads, blocks, res, g):
     q, k, v, out, lse = res
     # delta_i = rowsum(dO_i * O_i); tiny elementwise reduce — XLA fuses it.
     # A row (b, 1, q_len), like lse.
@@ -300,7 +326,7 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         return kernels._flash_pallas_bwd_kernel(
             q, k, v, g, lse, delta, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window)
+            window=window, blocks=blocks)
     if q.shape[1] > kernels._MAX_RESIDENT:   # ``results_in_model_arrays``
         # O goes in as dO does, and the kernel makes ``delta`` of them.
         # dQ stays its float32 [B x H, d, T] sum, which XLA rounds and
@@ -311,7 +337,7 @@ def _flash_pallas_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         dq, dk, dv = kernels._flash_pallas_bwd_kernel(
             *_folded_operands(q, k, v, heads), g, lse, out, causal=True,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            interpret=interpret, window=window, heads=heads)
+            interpret=interpret, window=window, heads=heads, blocks=blocks)
         return _unfolded(dq, heads), dk, dv
     return kernels._flash_pallas_lanes_bwd(
         q, k, v, g, out, lse, heads=heads, sm_scale=sm_scale, block_q=block_q,
@@ -324,7 +350,7 @@ _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "sm_scale", "block_q", "block_k", "impl",
-                     "window", "heads"),
+                     "window", "heads", "blocks"),
 )
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
@@ -332,7 +358,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_k: Optional[int] = None,
                     impl: Optional[str] = None,
                     window: Optional[int] = None,
-                    heads: Optional[int] = None) -> jax.Array:
+                    heads: Optional[int] = None,
+                    blocks: Optional[int] = None) -> jax.Array:
     """Flash attention over (..., seq, head_dim) inputs.
 
     Accepts (b, h, s, d) or (b, s, d); ``q`` and ``k`` share a width and
@@ -345,6 +372,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     a query head, and the backward kernel sums a key-value head's dK and dV
     over its group. Under ``window``, which needs ``causal``, query ``i``
     sees keys ``i - window + 1 .. i``; one that holds every key is none.
+    Given ``blocks`` (with ``causal``, no window), the sequence is two
+    streams under the block-diffusion mask (``seen_by_block``): the kernels
+    where ``flash_kernels.by_block_fits`` admits the lengths, or the
+    reference's dense mask (the scan has no such mask).
     With ``impl=None`` the platform
     decides: the Pallas kernel on "tpu" — where a kernel that fails to
     lower raises, it never falls back — and the scan formulation on any
@@ -377,6 +408,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
         impl = "pallas" if jax.default_backend() == "tpu" else "scan"
     if sm_scale is None:
         sm_scale = (q.shape[-1] // (heads or 1)) ** -0.5
+    assert not blocks or (causal and window is None
+                          and impl != "scan"), (blocks, causal, window, impl)
     if impl in ("reference", "scan"):
         if heads is not None:   # these take the heads as an axis
             d = q.shape[2] // heads
@@ -385,7 +418,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                                     (v, k.shape[2] // d)))
         if impl == "reference":
             out = attention_reference(q, k, v, causal=causal,
-                                      sm_scale=sm_scale, window=window)
+                                      sm_scale=sm_scale, window=window,
+                                      blocks=blocks)
         else:
             out = _flash_scan(q, k, v, causal=causal, sm_scale=sm_scale,
                               block_k=block_k or 128, window=window)
@@ -412,16 +446,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     def kernel(q, k, v):
         if heads is not None:
             return _flash_pallas_diff(q, k, v, causal, sm_scale, block_q,
-                                      block_k, interpret, window, heads)
+                                      block_k, interpret, window, heads,
+                                      blocks)
         if q.ndim == 4:
             b, h, s, _ = q.shape
             fold = lambda x: x.reshape(b * x.shape[1], *x.shape[-2:])
             out = _flash_pallas_diff(fold(q), fold(k), fold(v), causal,
                                      sm_scale, block_q, block_k, interpret,
-                                     window)
+                                     window, None, blocks)
             return out.reshape(b, h, s, v.shape[-1])
         return _flash_pallas_diff(q, k, v, causal, sm_scale, block_q,
-                                  block_k, interpret, window)
+                                  block_k, interpret, window, None, blocks)
 
     return per_batch_shard(kernel, q, (True,) * 3, "flash_attention")(q, k, v)
 
@@ -458,24 +493,38 @@ def auto_attention(q, v=None) -> str:
     return "flash" if measured and takes_kernels(q) else "xla"
 
 
-def _boundary(q, k, v, window: Optional[int]) -> bool:
+def _boundary(q, k, v, window: Optional[int], blocks: Optional[int] = None,
+              kernel: bool = True) -> bool:
     """Whether a "flash" call of q [B, T, H, d], k and v crosses in the
     model's own arrays, all of it (``heads_a_lane_tile``) or its results
     (``results_in_model_arrays``); writes the call's ``attention/boundary``
-    record."""
-    (_, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
-    a_tile = heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
-    results = results_in_model_arrays(seq, d, d_v)
-    steptrace.record_counters("attention/boundary", {
-        "tokens": seq, "heads": heads, "kv_heads": kv_heads,
-        "d_qk": d, "d_v": d_v, "window": window or 0,
-        "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0),
-        "model_results": int(results)})
+    record. Under the block-diffusion mask (``blocks``; never the lanes'
+    kernels, whose grid step would hold both streams) the record also says
+    the mask's block length, whether the kernels ran (``kernel``; 0: the
+    dense mask in ``jnp``) and the forward grid's live and skipped blocks a
+    call, over all heads."""
+    (b, seq, heads, d), kv_heads, d_v = q.shape, k.shape[2], v.shape[3]
+    a_tile = 0 if blocks else heads_a_lane_tile(seq, heads, kv_heads, d, d_v)
+    results = kernel and results_in_model_arrays(seq, d, d_v)
+    said = {"tokens": seq, "heads": heads, "kv_heads": kv_heads,
+            "d_qk": d, "d_v": d_v, "window": window or 0,
+            "heads_a_lane_tile": a_tile, "model_arrays": int(a_tile > 0),
+            "model_results": int(results)}
+    if blocks:
+        live = skipped = 0
+        if kernel:
+            counts = kernels.by_block_kinds(seq, blocks)
+            skipped = b * heads * counts.pop("dead")
+            live = b * heads * sum(counts.values())
+        said |= {"blocks": blocks, "kernel": int(kernel),
+                 "live_blocks": live, "skipped_blocks": skipped}
+    steptrace.record_counters("attention/boundary", said)
     return bool(a_tile or results)
 
 
 def causal_self_attention(q, k, v, attention: str = "auto",
-                          window: Optional[int] = None):
+                          window: Optional[int] = None,
+                          blocks: Optional[int] = None):
     """Causal self-attention of ``q`` [B, T, H, d], ``k`` [B, T, H_kv, d]
     and ``v`` [B, T, H_kv, d_v] in a model's own layout (one length; H_kv
     divides H, query head ``j`` reading key-value head ``j // (H / H_kv)``;
@@ -495,25 +544,36 @@ def causal_self_attention(q, k, v, attention: str = "auto",
     one block of keys the output, its cotangent, dK and dV alone cross in
     such arrays (``results_in_model_arrays``; ``model_results`` 1) while
     XLA folds q, k and v into [B x H, T, d] and turns dQ back; or XLA turns
-    everything (both 0)."""
+    everything (both 0).
+
+    Given ``blocks`` the T positions are two streams of T / 2, a noisy and
+    a clean copy of one sequence, and the mask is block diffusion's
+    (``seen_by_block``) in place of the causal one: the kernels under
+    "flash" (under "auto" where ``flash_kernels.by_block_fits`` admits the
+    length too), else the dense mask in ``jnp`` (``attention_reference``);
+    the record says which ran (``kernel``)."""
     if attention == "auto":
         attention = auto_attention(q, v)
+        if blocks and not kernels.by_block_fits(q.shape[1], blocks):
+            attention = "xla"
     bhsd = lambda t: t.transpose(0, 2, 1, 3)
     if attention == "flash":
         (b, seq, heads, d), d_v = q.shape, v.shape[3]
-        if _boundary(q, k, v, window):
+        if _boundary(q, k, v, window, blocks):
             lanes = lambda t: t.reshape(b, seq, -1)
             return flash_attention(
                 lanes(q), lanes(k), lanes(v), causal=True, window=window,
-                heads=heads).reshape(b, seq, heads, d_v)
+                heads=heads, blocks=blocks).reshape(b, seq, heads, d_v)
         return flash_attention(
-            bhsd(q), bhsd(k), bhsd(v), causal=True, window=window
-        ).transpose(0, 2, 1, 3)
+            bhsd(q), bhsd(k), bhsd(v), causal=True, window=window,
+            blocks=blocks).transpose(0, 2, 1, 3)
     if attention == "xla":
-        if v.shape[-1] != q.shape[-1] or window is not None:
+        if blocks:
+            _boundary(q, k, v, window, blocks, kernel=False)
+        if v.shape[-1] != q.shape[-1] or window is not None or blocks:
             return attention_reference(
                 bhsd(q), bhsd(k), bhsd(v), causal=True,
-                window=window).transpose(0, 2, 1, 3)
+                window=window, blocks=blocks).transpose(0, 2, 1, 3)
         return jax.nn.dot_product_attention(q, k, v, is_causal=True)
     raise ValueError(f"attention={attention!r}: expected auto, xla or flash")
 
@@ -526,21 +586,25 @@ def causal_self_attention(q, k, v, attention: str = "auto",
 # ----------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
 def _normed_rotary_flash(q, k, v, q_scale, k_scale, cos, sin, heads, eps,
-                         sm_scale, block_q, block_k, interpret, window):
+                         sm_scale, block_q, block_k, interpret, window,
+                         blocks=None):
     """Causal self-attention of ``head_rotary(q)`` on ``head_rotary(k)`` and
     v, the three as the projections wrote them, [B, T, heads x 128] (k and v
     of as many heads as their widths say), on the ``model_results`` boundary
     (``results_in_model_arrays``): -> [B, T, heads x 128]. ``cos``, ``sin``:
-    [T, 64] float32 or None."""
+    [T, 64] float32 or None. ``blocks``: the block-diffusion mask's block
+    length, the T positions then two streams (``cos``, ``sin`` say which
+    positions: a stream's, twice)."""
     return _normed_rotary_flash_fwd(
         q, k, v, q_scale, k_scale, cos, sin, heads, eps, sm_scale, block_q,
-        block_k, interpret, window)[0]
+        block_k, interpret, window, blocks)[0]
 
 
 def _normed_rotary_flash_fwd(q, k, v, q_scale, k_scale, cos, sin, heads, eps,
-                             sm_scale, block_q, block_k, interpret, window):
+                             sm_scale, block_q, block_k, interpret, window,
+                             blocks):
     kv_heads = k.shape[2] // (q.shape[2] // heads)
     prologue = functools.partial(rotary.head_rotary_fwd, cos=cos, sin=sin,
                                  eps=eps, interpret=interpret)
@@ -552,12 +616,12 @@ def _normed_rotary_flash_fwd(q, k, v, q_scale, k_scale, cos, sin, heads, eps,
     out, lse = map(ad_checkpoint.checkpoint_name, kernels._flash_pallas(
         qf, kf, _folded(v, kv_heads), causal=True, heads=heads,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, window=window), REMAT_NAMES)
+        interpret=interpret, window=window, blocks=blocks), REMAT_NAMES)
     return out, (q, k, v, q_scale, k_scale, cos, sin, qf, kf, out, lse)
 
 
 def _normed_rotary_flash_bwd(heads, eps, sm_scale, block_q, block_k,
-                             interpret, window, res, g):
+                             interpret, window, blocks, res, g):
     q, k, v, q_scale, k_scale, cos, sin, qf, kf, out, lse = res
     kv_heads = k.shape[2] // (q.shape[2] // heads)
     # dQ^T stays the flash kernel's float32 [B x H, 128, T] sum: the
@@ -566,7 +630,8 @@ def _normed_rotary_flash_bwd(heads, eps, sm_scale, block_q, block_k,
     dq_t, dk, dv = kernels._flash_pallas_bwd_kernel(
         qf, kf, _folded(v, kv_heads), g, lse, out, causal=True,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, window=window, heads=heads, dq_turned=False)
+        interpret=interpret, window=window, heads=heads, dq_turned=False,
+        blocks=blocks)
     back = functools.partial(rotary.head_rotary_bwd, cos=cos, sin=sin,
                              eps=eps, interpret=interpret)
     dq, dq_scale = back(dq_t, q, q_scale, heads=heads, turned=True)
@@ -603,9 +668,10 @@ def _prologue_fits(q, v, cos, attention: str) -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "eps", "window", "interpret", "block_q", "block_k"))
+    "heads", "eps", "window", "interpret", "block_q", "block_k", "blocks"))
 def _normed_rotary_kernels(q, k, v, q_scale, k_scale, cos, sin, *, heads,
-                           eps, window, interpret, block_q, block_k):
+                           eps, window, interpret, block_q, block_k,
+                           blocks=None):
     """``normed_rotary_self_attention``'s kernels for q [B, T, heads x 128],
     k and v as the projections wrote them, handed to a mesh a batch shard
     each. Jitted, as ``flash_attention`` is: layers of one shape share one
@@ -619,7 +685,7 @@ def _normed_rotary_kernels(q, k, v, q_scale, k_scale, cos, sin, *, heads,
         return _normed_rotary_flash(
             q, k, v, q_scale, k_scale, *(tables or (None, None)), heads, eps,
             (q.shape[2] // heads) ** -0.5, block_q, block_k, interpret,
-            kernels._window_of(window, seq))
+            kernels._window_of(window, seq), blocks)
 
     return per_batch_shard(
         kernel, q, (True,) * 3 + (False,) * (2 + len(tables)),
@@ -631,7 +697,8 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
                                  window: Optional[int] = None,
                                  impl: Optional[str] = None,
                                  block_q: Optional[int] = None,
-                                 block_k: Optional[int] = None):
+                                 block_k: Optional[int] = None,
+                                 blocks: Optional[int] = None):
     """``causal_self_attention`` of a layer whose q [B, T, H, d] and k [B,
     T, H_kv, d], as the projections wrote them, first pass an RMSNorm over
     a head's width (``q_scale``, ``k_scale`` [d]; ``eps``) and, given a
@@ -649,9 +716,13 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     ``rotary.head_rotary`` and ``causal_self_attention``. ``impl`` forces
     "pallas", "pallas_interpret" (the kernels, the flash pair included, with
     ``block_q`` / ``block_k`` if given) or "jnp". One ``counters`` record
-    ``attention/head_rotary`` a traced call says which ran."""
+    ``attention/head_rotary`` a traced call says which ran. ``blocks`` is
+    ``causal_self_attention``'s: the T positions are two streams under the
+    block-diffusion mask, and the table holds a stream's positions twice."""
     if attention == "auto":
         attention = auto_attention(q, v)
+        if blocks and not kernels.by_block_fits(q.shape[1], blocks):
+            attention = "xla"
     if impl is None:
         impl = auto_head_rotary(q, v, cos, attention)
     (b, seq, heads, d), d_v = q.shape, v.shape[3]
@@ -661,14 +732,16 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     if impl == "jnp":
         prologue = functools.partial(rotary.head_rotary, cos=cos, sin=sin,
                                      eps=eps)
-        return causal_self_attention(prologue(q, q_scale),
-                                     prologue(k, k_scale), v, attention,
-                                     window)
+        # ``blocks`` only where there is one: a test's stand-in for the
+        # twin's call takes the five arguments it always did
+        return causal_self_attention(
+            prologue(q, q_scale), prologue(k, k_scale), v, attention, window,
+            **({"blocks": blocks} if blocks else {}))
     assert _prologue_fits(q, v, cos, attention), (q.shape, attention)
-    _boundary(q, k, v, window)
+    _boundary(q, k, v, window, blocks)
     lanes = lambda t: t.reshape(b, seq, -1)
     return _normed_rotary_kernels(
         lanes(q), lanes(k), lanes(v), q_scale, k_scale, cos, sin,
         heads=heads, eps=eps, window=window,
         interpret=impl == "pallas_interpret", block_q=block_q,
-        block_k=block_k).reshape(b, seq, heads, d_v)
+        block_k=block_k, blocks=blocks).reshape(b, seq, heads, d_v)

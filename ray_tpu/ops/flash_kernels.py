@@ -49,7 +49,10 @@ by the live steps alone, ``_bwd_kernel``), and the index maps clamp dead
 blocks to the last live one, which the pipeline does not fetch again. Only
 a masked call that is no self-attention in square blocks (lengths that
 differ, ``res_q != res_k``) keeps loops with bounds computed from the grid
-position.
+position. A call under the block-diffusion mask (``blocks``: two streams of
+one sequence, ``_BY_BLOCK``) is walked the same way, its grid blocks' kinds
+told from their place among the two streams' residents
+(``_by_block_place``, ``_walk_by_place``) and its edges at a block's start.
 """
 
 from __future__ import annotations
@@ -155,14 +158,27 @@ _EDGES = {(False, False): False, (True, False): True,
 
 
 def _scores(k, q, c, *, sm_scale: float, fold: bool, masked,
-            block_k: int, rel, window: Optional[int] = None):
+            block_k: int, rel, window: Optional[int] = None, edge=None):
     """s^T (block_k, block_q) of key tile ``c``, keys along sublanes: scaled
     here unless q came scaled, and masked (``_EDGES``) where the diagonal
-    or the window's far edge crosses."""
+    or the window's far edge crosses; given ``edge`` (a block-diffusion
+    call's: ``_BY_BLOCK``), where that edge does."""
     s = _dot(k, q, _NT)
     if not fold:
         s = s * sm_scale
-    if masked:
+    if masked and edge is not None:
+        # a key's position and the start of a query's own block of
+        # ``blocks`` tokens, both less the q tile's first position, which
+        # is itself the start of a block
+        blocks, near, far = edge    # the sides in blocks: ``_SIDES``
+        key = lax.broadcasted_iota(jnp.int32, s.shape, 0) + (c * block_k - rel)
+        own = lax.broadcasted_iota(
+            jnp.int32, (1, s.shape[1]), 1) // blocks * blocks
+        seen = key < own + far * blocks
+        if near is not None:
+            seen = seen & (key >= own + near * blocks)
+        s = jnp.where(seen, s, NEG_INF)
+    elif masked:
         # query position less key position, within the tile and then overall
         ahead = (lax.broadcasted_iota(jnp.int32, s.shape, 1)
                  - lax.broadcasted_iota(jnp.int32, s.shape, 0))
@@ -205,10 +221,12 @@ def _walk(step, carry, n_full, n_live, n_start=None, n_clear=None):
 
 def _rows(rel0, n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
           alike_loop: bool, window: Optional[int] = None,
-          longest_first: bool = False):
+          longest_first: bool = False, edge=None):
     """(bounds, order) of a grid block's ``n_q`` rows of tiles: row ``j``'s
     bounds from ``_live_tiles``, under a window with those of
-    ``_window_tiles`` after them, and the order in which ``_walk_rows``
+    ``_window_tiles`` after them (under an ``edge`` with a near side, a
+    block-diffusion call's "own" blocks, the tiles that hold the q tile's
+    own positions, every one masked), and the order in which ``_walk_rows``
     walks the rows: None for one loop over ``j``, which with ``alike_loop``
     rows are whose bounds are constants, the same for all and leave no tile
     masked (a whole grid block's, a dead one's); else their indices, with
@@ -217,6 +235,9 @@ def _rows(rel0, n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
     block's)."""
     def bounds_of(j):
         rel = rel0 + j * block_q
+        if edge is not None and edge[1] is not None:
+            first = rel // block_k
+            return (first, (rel + block_q - 1) // block_k + 1, first, first)
         live = _live_tiles(rel, block_q, block_k, n_k, causal)
         if window is None:
             return live
@@ -238,14 +259,14 @@ def _rows(rel0, n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
 
 def _walk_rows(row, rel0, n_q: int, n_k: int, block_q: int, block_k: int,
                causal: bool, alike_loop: bool, window: Optional[int] = None,
-               longest_first: bool = False):
+               longest_first: bool = False, edge=None):
     """``row(j, n_full, n_live)`` for each of a grid block's ``n_q`` rows of
     tiles, under a window ``row(j, n_full, n_live, n_start, n_clear)``, in
     the order ``_rows`` gives: rows alike as one loop over ``j``, a row a
     step, so that the row's tiles stay straight-line code and the kernel's
     size stays a row's."""
     bounds, order = _rows(rel0, n_q, n_k, block_q, block_k, causal,
-                          alike_loop, window, longest_first)
+                          alike_loop, window, longest_first, edge)
     if order is None:
         lax.fori_loop(0, len(bounds), lambda j, _: row(j, *bounds[0]), None)
         return
@@ -342,6 +363,59 @@ def _walk_by_kind(walk, rel0, res_q: int, res: int, kinds,
             pl.when(here)(functools.partial(walk, rel))
 
 
+# The block-diffusion mask over two streams of one sequence, a noisy copy
+# [0, L) and a clean copy [L, 2L), both cut into blocks of ``blocks`` tokens:
+# a noisy query sees the noisy keys of its own block and the clean keys of
+# the blocks before it; a clean query sees the clean keys of its own block
+# and of those before it, and no noisy key. A grid step's residents lie in
+# one stream (``_by_block_grid``: L is a whole number of them, ``n`` a
+# stream), so a grid block's place says its kind as under a causal mask:
+# "whole", "dead", or one of three that an edge crosses, each with the
+# (near, far) side of what a query sees, counted from the start of its own
+# block: "own" (noisy on noisy: the q tile's own positions' tiles alone),
+# "strict" (noisy on the clean copy of its stream's block) and "inclusive"
+# (clean on clean).
+_BY_BLOCK = ("whole", "own", "strict", "inclusive", "dead")
+_SIDES = {"own": (0, 1), "strict": (None, 0), "inclusive": (None, 1)}
+
+
+def _by_block_place(qi, ki, n: int) -> dict:
+    """{kind: whether grid block (qi, ki) of a block-diffusion call is of
+    it}, for the grid's own (traced) indices."""
+    q_noisy, k_noisy, q_clean, k_clean = qi < n, ki < n, qi >= n, ki >= n
+    qc, kc = jnp.where(q_clean, qi - n, qi), jnp.where(k_clean, ki - n, ki)
+    same = qc == kc
+    place = {"whole": k_clean & (kc < qc), "own": q_noisy & k_noisy & same,
+             "strict": q_noisy & k_clean & same,
+             "inclusive": q_clean & k_clean & same}
+    live = functools.reduce(lambda a, b: a | b, place.values())
+    return place | {"dead": jnp.logical_not(live)}
+
+
+def by_block_counts(n: int) -> dict:
+    """{kind: grid blocks of it a head} of a block-diffusion call whose
+    streams are ``n`` grid blocks each: of the (2n)^2, n (n - 1) whole (a
+    triangle a stream of queries), n of each kind an edge crosses, the rest
+    dead (clean queries on noisy keys, noisy on noisy off the diagonal,
+    everything past a diagonal)."""
+    live = {"whole": n * (n - 1), "own": n, "strict": n, "inclusive": n}
+    return live | {"dead": 4 * n * n - sum(live.values())}
+
+
+def _walk_by_place(walk, place: dict, res: int, blocks: int, kinds,
+                   live_only: bool = False):
+    """``walk(rel0, edge)`` for this grid block of a block-diffusion call,
+    a branch a kind present (``kinds``), each with constant tile bounds:
+    ``_walk_by_kind``'s whole and dead blocks, and a block on a diagonal
+    with its ``edge`` (blocks, near, far) for ``_scores`` and ``_rows``.
+    With ``live_only`` (the backward's) a dead block is not walked."""
+    straight = {"whole": (res - 1, None), "dead": (-res, None)} | {
+        kind: (0, (blocks, *sides)) for kind, sides in _SIDES.items()}
+    for kind in kinds:
+        if not (live_only and kind == "dead"):
+            pl.when(place[kind])(functools.partial(walk, *straight[kind]))
+
+
 def _fold_tile(s, carry, masked, values_t):
     """One key tile's scores ``s`` (block_k, block_q) folded into a q
     tile's online softmax ``carry`` (running max and sum as rows (1,
@@ -363,11 +437,12 @@ def _fold_tile(s, carry, masked, values_t):
 def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
-                rows_out: bool = False):
+                rows_out: bool = False, blocks: Optional[int] = None):
     """``o_ref`` is this block of queries' O^T (d_v, resident queries), or
     with ``rows_out`` its O (resident queries, d_v): a head's lanes of a
     model's own [B, T, H x d_v] array (``results_in_model_arrays``), for
-    which a row of tiles' float32 accumulator is turned here, in VMEM."""
+    which a row of tiles' float32 accumulator is turned here, in VMEM.
+    ``blocks``: the block-diffusion mask's block length (``_BY_BLOCK``)."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -383,7 +458,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def walk(rel0):
+    def walk(rel0, edge=None):
         def row(j, *bounds):
             cols = _tile(j, block_q, n_q)
             rel = rel0 + j * block_q
@@ -391,7 +466,8 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
             def step(c, carry, masked):
                 rows = _tile(c, block_k, n_k)
-                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
+                           edge=edge)
                 return _fold_tile(s, carry, masked, lambda: vt_ref[:, rows])
 
             m, l, acc = _walk(
@@ -413,16 +489,21 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                     jnp.where(m > NEG_INF / 2, m, 0.0) + jnp.log(l_safe))
 
         _walk_rows(row, rel0, n_q, n_k, block_q, block_k, causal, not static,
-                   window)
+                   window, edge=edge)
 
-    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window)
+    if blocks:
+        _walk_by_place(walk, _by_block_place(qi, ki, nk // 2), res_k, blocks,
+                       kinds)
+    else:
+        _walk_by_kind(walk, rel0, res_q, res_k, kinds, window)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 dqt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *sums,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
-                nq: int, group: int, o_rows: bool = False):
+                nq: int, group: int, o_rows: bool = False,
+                blocks: Optional[int] = None):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three. The last grid axis walks the ``nq`` blocks of queries of each of
@@ -468,11 +549,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
     if sums:
         nk = pl.num_programs(1)
         sum_scr, had_scr, sems, pending = sums
-        first_k = (0 if window is None else
-                   _first_live_k(qi, res_q, res_k, offset, nk, window))
-        last_k = (_last_live_k(qi, res_q, res_k, offset, nk) if causal
-                  else nk - 1)
-        live, adds = (ki >= first_k) & (ki <= last_k), ki != first_k
+        if blocks:
+            # the first block of keys a block of queries sees is its own
+            # (noisy) or the clean stream's first
+            kind_here = _by_block_place(qi, ki, nq // 2)
+            adds = ki != jnp.minimum(qi, nq // 2)
+        else:
+            first_k = (0 if window is None else
+                       _first_live_k(qi, res_q, res_k, offset, nk, window))
+            last_k = (_last_live_k(qi, res_q, res_k, offset, nk) if causal
+                      else nk - 1)
+            live, adds = (ki >= first_k) & (ki <= last_k), ki != first_k
         head = pl.program_id(0) * group + step_q // nq
         copies, _, wide = sum_scr.shape
         rows_a_copy = wide // block_q
@@ -519,7 +606,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
         return (do.astype(jnp.float32) * delta_ref[cols, :].astype(
             jnp.float32)).T.sum(axis=0, keepdims=True)
 
-    def walk(rel0):
+    def walk(rel0, edge=None):
         def row(j, *bounds):
             cols = _tile(j, block_q, n_q)
             rel = rel0 + j * block_q
@@ -528,7 +615,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
 
             def step(c, dqt, masked):
                 rows = _tile(c, block_k, n_k)
-                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel)
+                s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
+                           edge=edge)
                 p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
                 dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
                 dp = _dot(v_ref[rows, :], do, _NT)
@@ -559,7 +647,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
             where(nth == rows_a_copy - 1, lambda: write(c).start())
 
         the_rows = (rel0, n_q, n_k, block_q, block_k, causal, not static,
-                    window, bool(sums))
+                    window, bool(sums), edge)
         order = _rows(*the_rows)[1] or range(n_q)
         if sums:
             @pl.when(adds)
@@ -571,7 +659,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
         if sums:
             pending[0] = 1
 
-    _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, live)
+    if blocks:
+        _walk_by_place(walk, kind_here, res_k, blocks, kinds,
+                       live_only=True)
+    else:
+        _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, live)
 
     @pl.when(step_q == n_steps - 1)
     def _finalize():
@@ -855,29 +947,99 @@ def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
     return tuple(kind for kind in _KINDS if counts[kind])
 
 
-def _kernel_name(base: str, window: Optional[int]) -> str:
-    """``flash_fwd`` / ``flash_bwd``, and of a windowed call
-    ``flash_fwd_w<window>``: the benchmark's readers find the kernels, and
-    a call's window, by these names."""
+def _kernel_name(base: str, window: Optional[int],
+                 blocks: Optional[int] = None) -> str:
+    """``flash_fwd`` / ``flash_bwd``, of a windowed call
+    ``flash_fwd_w<window>`` and of a block-diffusion call
+    ``flash_fwd_bd<blocks>``: the benchmark's readers find the kernels, and
+    a call's mask, by these names."""
+    if blocks:
+        return f"{base}_bd{blocks}"
     return base if window is None else f"{base}_w{window}"
+
+
+def by_block_fits(length: int, blocks: int, block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> bool:
+    """Whether the kernels take a block-diffusion call over ``length``
+    positions (two streams of half of it) in blocks of ``blocks``: each
+    stream a whole number of blocks and of the residents both kernels
+    choose for it, which queries and keys share, in tiles that are whole
+    blocks (an edge then starts at a q tile's first position) and, where
+    the kernels choose them, whole lane tiles."""
+    half = length // 2
+    if length % 2 or half % blocks:
+        return False
+    for targets in (_FWD_TILES, _BWD_TILES):
+        try:
+            bq, bk, res_q, res_k = _block_sizes(half, half, block_q, block_k,
+                                                targets)
+        except AssertionError:
+            return False
+        if res_q != res_k or bq % blocks or bk % blocks:
+            return False
+        # a tile the kernels choose is whole lane tiles, never an odd
+        # length whole
+        if (block_q is None and bq % 128) or (block_k is None and bk % 128):
+            return False
+    return True
+
+
+def by_block_kinds(length: int, blocks: int, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None, *,
+                   backward: bool = False) -> dict:
+    """{kind: grid blocks of it a head} of a block-diffusion call over
+    ``length`` positions, as ``grid_block_kinds`` says it of the other
+    masks: ``by_block_counts`` of the residents the kernel chooses."""
+    res = _block_sizes(length // 2, length // 2, block_q, block_k,
+                       _BWD_TILES if backward else _FWD_TILES)[2]
+    return by_block_counts(length // 2 // res)
+
+
+def _by_block_grid(length: int, blocks: int, block_q, block_k,
+                   backward: bool, heads):
+    """(block_q, block_k, residents, blocks of residents a stream, the
+    kinds of grid block the call holds) of a block-diffusion call, whose
+    residents are chosen for ONE stream, so that no grid step holds both;
+    writes the call's ``attn/grid_blocks`` record, as ``_kinds_present``
+    does under the other masks."""
+    assert by_block_fits(length, blocks, block_q, block_k), (length, blocks)
+    block_q, block_k, res, _ = _block_sizes(
+        length // 2, length // 2, block_q, block_k,
+        _BWD_TILES if backward else _FWD_TILES)
+    n = length // 2 // res
+    counts = by_block_counts(n)
+    steptrace.record_counters("attn/grid_blocks", {
+        **counts, "queries": length, "keys": length,
+        "backward": int(backward), "window": 0, "blocks": blocks,
+        "heads": heads[0], "kv_heads": heads[1], "dq_partials": 0})
+    return (block_q, block_k, res, n,
+            tuple(kind for kind in _BY_BLOCK if counts[kind]))
 
 
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
                   interpret: bool, window: Optional[int] = None,
-                  heads: Optional[int] = None):
+                  heads: Optional[int] = None,
+                  blocks: Optional[int] = None):
     """q: (B, S, D) with batch*heads folded into B; k: (B_kv, S, D) and v:
     (B_kv, S, Dv) with B a multiple of B_kv: query head ``i`` reads
     key-value head ``i // (B // B_kv)``, through the index maps.
     -> (out (B, S, Dv), lse) with lse (B, 1, S) float32. Given ``heads``
     (``results_in_model_arrays``), of which B is a multiple, out is a
     model's own (B / heads, S, heads x Dv): the kernel writes head ``i %
-    heads``'s lanes of it, a block of queries at a time."""
+    heads``'s lanes of it, a block of queries at a time. Given ``blocks``,
+    S is two streams under the block-diffusion mask (``_BY_BLOCK``)."""
     b, q_len, d = q.shape
     k_len, d_v = k.shape[1], v.shape[2]
     group = b // k.shape[0]
-    block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
-                                                  block_k, _FWD_TILES, window)
+    if blocks:
+        assert causal and window is None and q_len == k_len, (q.shape, k.shape)
+        block_q, block_k, res_q, n, kinds = _by_block_grid(
+            q_len, blocks, block_q, block_k, False, (b, k.shape[0]))
+        res_k = res_q
+    else:
+        block_q, block_k, res_q, res_k = _block_sizes(
+            q_len, k_len, block_q, block_k, _FWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
     if window is not None and offset:
@@ -888,7 +1050,12 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
             f"flash_attention: a window over lengths that differ ({q_len} "
             f"queries, {k_len} keys) is the scan's or the reference's")
 
-    if causal and nk > 1 and window is not None:
+    if blocks:
+        # a dead block fetches what the next live one will: a noisy query's
+        # own block, the clean stream's first, and past a diagonal the last
+        kmap = lambda qi, ki: jnp.where(
+            ki < n, jnp.where(qi < n, qi, n), jnp.minimum(ki, n + qi % n))
+    elif causal and nk > 1 and window is not None:
         # blocks behind the window fetch the first live one, as blocks
         # past the diagonal the last
         kmap = lambda qi, ki: jnp.clip(
@@ -903,8 +1070,10 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, offset=offset, static=nq == nk == 1, window=window,
-        kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, False,
-                             window, (b, k.shape[0])))
+        blocks=blocks,
+        kinds=kinds if blocks else _kinds_present(
+            nq, nk, res_q, res_k, offset, causal, False, window,
+            (b, k.shape[0])))
     if heads is None:
         # O^T, which XLA turns
         out_spec = pl.BlockSpec((None, d_v, res_q),
@@ -942,7 +1111,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         ],
         compiler_params=_compiler_params(interpret, max(d, d_v)),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window),
+        name=_kernel_name("flash_fwd", window, blocks),
     )(q, k, jnp.swapaxes(v, 1, 2))
     return (jnp.swapaxes(out, 1, 2) if heads is None else out), lse
 
@@ -952,7 +1121,8 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              block_k: Optional[int], interpret: bool,
                              window: Optional[int] = None,
                              heads: Optional[int] = None,
-                             dq_turned: bool = True):
+                             dq_turned: bool = True,
+                             blocks: Optional[int] = None):
     """-> (dq, dk, dv) of ``_flash_pallas``'s call, shaped as q, k and v
     [B x H, T, d] from ``do`` shaped as its output; given ``heads``, ``do``
     is the cotangent of the model's own [B, T, heads x d_v] output, read a
@@ -965,11 +1135,21 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     b, q_len, d = q.shape
     b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
     group = b // b_kv
-    block_q, block_k, res_q, res_k = _block_sizes(q_len, k_len, block_q,
-                                                  block_k, _BWD_TILES, window)
+    if blocks:
+        block_q, block_k, res_q, n, kinds = _by_block_grid(
+            q_len, blocks, block_q, block_k, True, (b, b_kv))
+        res_k = res_q
+    else:
+        block_q, block_k, res_q, res_k = _block_sizes(
+            q_len, k_len, block_q, block_k, _BWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
-    if causal and nk > 1 and window is not None:
+    if blocks:
+        # a noisy block of keys is seen by its own block of queries alone;
+        # a clean one by its stream's blocks from its own on, in each stream
+        qmap = lambda ki, qi: jnp.where(
+            ki < n, ki, jnp.maximum(qi, ki - n + jnp.where(qi < n, 0, n)))
+    elif causal and nk > 1 and window is not None:
         qmap = lambda ki, qi: jnp.clip(
             qi, _first_live_q(ki, res_q, res_k, offset, nq),
             _last_live_q(ki, res_q, res_k, offset, nq, window))
@@ -1045,8 +1225,10 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, offset=offset, static=nq == nk == 1,
             window=window, nq=nq, group=group, o_rows=heads is not None,
-            kinds=_kinds_present(nq, nk, res_q, res_k, offset, causal, True,
-                                 window, (b, b_kv))),
+            blocks=blocks,
+            kinds=kinds if blocks else _kinds_present(
+                nq, nk, res_q, res_k, offset, causal, True, window,
+                (b, b_kv))),
         grid=(b_kv, nk, group * nq),
         in_specs=[
             qspec, kspec, vspec,
@@ -1064,7 +1246,7 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
         compiler_params=_compiler_params(interpret, max(d, d_v),
                                          keys_add=nk > 1),
         interpret=interpret,
-        name=_kernel_name("flash_bwd", window),
+        name=_kernel_name("flash_bwd", window, blocks),
     )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
     if not dq_turned:
         return dq_t, dk, dv

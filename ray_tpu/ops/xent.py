@@ -48,7 +48,8 @@ def fused_xent(logits, labels, mask=None):
     return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1)
 
 
-def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8):
+def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8,
+                 denom=None):
     """LM loss of ``hidden`` [B, T, d] through the head ``embedding``
     [V, d] (the embedding itself where the head is tied to it), computed in
     sequence chunks, with its own derivative rule.
@@ -68,7 +69,13 @@ def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8):
 
     A function with a ``custom_vjp`` has no forward-mode derivative:
     ``jax.jvp`` / ``jacfwd`` over this loss raise (``loss_chunks=0`` keeps
-    them). Labels and mask get no gradient."""
+    them). Labels and mask get no gradient.
+
+    ``mask`` may be any per-position weights; the loss is their weighted
+    sum over ``denom``, left out the weights' own sum (a mean). A given
+    ``denom`` is an objective's own (a diffusion loss weights the masked
+    positions by their inverse rate and divides by the positions there
+    are, not by the weights)."""
     B, T, C = hidden.shape
     assert T % n_chunks == 0, (T, n_chunks)
     t = T // n_chunks
@@ -82,7 +89,8 @@ def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8):
         weights, denom = None, jnp.float32(B * T)
     else:
         weights = chunks(mask.astype(jnp.float32))
-        denom = jnp.maximum(weights.sum(), 1.0)
+        denom = (jnp.maximum(weights.sum(), 1.0) if denom is None
+                 else jnp.float32(denom))
     return _chunked_xent(hid, embedding, chunks(labels), weights, denom)
 
 

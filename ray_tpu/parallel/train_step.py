@@ -64,10 +64,14 @@ class _StepByLayout:
 STEP_NAME = "step"
 
 
-def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
+def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False,
+                     with_count: bool = False):
     """Jitted ``(params, opt_state, batch) -> (params, opt_state, loss)``
     over ``loss_fn(params, batch)``; with ``has_aux`` the loss function
-    returns ``(loss, aux)`` and the leaves of ``aux`` follow the loss.
+    returns ``(loss, aux)`` and the leaves of ``aux`` follow the loss. With
+    ``with_count`` it is ``loss_fn(params, batch, count)``, ``count`` the
+    optimizer's own count of the steps taken (``step_count``): the one
+    clock a step has, for a loss that draws noise anew every step.
 
     Sharding is inferred from the placed arguments (``place_train_state``
     and a batch on ``mesh_utils.data_sharding`` first): with the batch
@@ -80,8 +84,9 @@ def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
 
     def jitted(shardings=None):
         def step(params, opt_state, batch):
+            seen = (batch, step_count(opt_state)) if with_count else (batch,)
             out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
-                params, batch)
+                params, *seen)
             if shardings:
                 # a gradient leaves the backward pass laid out as its
                 # parameter is at rest: the sum over the split batch
@@ -97,6 +102,12 @@ def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False):
             out_shardings=(*shardings, None) if shardings else None)
 
     return _StepByLayout(jitted)
+
+
+def step_count(opt_state):
+    """The steps an optax state has taken: its first ``count`` leaf (Adam's;
+    a schedule's runs beside it), int32."""
+    return optax.tree_utils.tree_get_all_with_path(opt_state, "count")[0][1]
 
 
 def state_shardings(params, opt_state, param_shardings):
