@@ -219,6 +219,38 @@ the same five digits either way (within 0.0029 to 0.0038 of the largest
 entry). ``_block_sizes`` takes the window's length for its residents since
 (``_WINDOW_RESIDENT_FROM``); ``--residents N`` holds a step to N whatever
 the window, so both readings can be made again.
+
+A windowed call's grid holds a row's live blocks only (PR 66; my chip runs,
+the parent's tree beside the change in one call, ``chiprun_out/pr66/kernels/``:
+16 grid steps a head, 1 of them dead, where there were 64 and 49; the lines'
+``grid_blocks`` say ``steps`` and ``dead_steps``), ``flash_fwd`` and
+``flash_bwd`` alone, then the wall time of forward plus backward, parent ->
+change:
+  ``--widths 128x128 --lengths 8192 --tokens 16384 --heads 32 --kv-heads 4
+  --window 1024 --check 1`` (Mellum2's window layer: 64 heads, residents of
+              1,024): 4.478 -> 3.476 and 6.836 -> 5.769 ms, 13.10 -> 11.02
+  ``--widths 128x128 --lengths 16384 --tokens 16384 --heads 32 --kv-heads 4
+  --window 2048 --check 1`` (Trinity-Mini's: 32 heads, residents of 2,048):
+              6.180 -> 5.444 and 9.407 -> 8.659 ms, 17.92 -> 16.47
+The 48 grid steps a head that went were 3,072 a call in the first shape and
+1,536 in the second: a dead step cost 0.33 us forward and 0.35 backward at
+residents of 1,024, 0.48 and 0.49 at 2,048 (it started, branched, and in the
+forward walked its rows' scratch: twice the rows at twice the residents).
+Output and the three gradients against ``attention_reference`` in float32:
+the parent's five digits at both shapes (0.00293-0.00376 and 0.00294-0.0042
+of the largest entry), and with no group, where a block of queries' dQ^T
+sum is written on the last step of one block of keys and fetched on the
+first of the next (``--heads 4 --kv-heads 4`` at ``--lengths 8192 --tokens
+8192 --window 1024`` and ``2048``, at ``--lengths 16384 --window 4096``,
+three steps a row, and at ``--widths 64x128``, XLA's copies round the
+kernels): dq within 0.0028-0.0047.
+One reading that changes no path a cell runs, for whoever judges a lower
+``_WINDOW_RESIDENT_FROM``: the hybrid cell's window (``--widths 64x128
+--lengths 16384 --heads 20 --kv-heads 10 --window 512 --check 1``) as it
+runs, every block ``looped`` at residents of 2,048: 3.110 and 3.772 ms,
+8.67; with ``--residents 512`` under the new grid (32 x 32 blocks a head,
+64 steps launched, 1 dead): 1.527 and 2.856 ms, 6.16, the same five digits
+against the reference: 2.5 ms a layer's call, forward plus backward.
 """
 
 import argparse

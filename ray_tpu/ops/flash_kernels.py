@@ -46,7 +46,18 @@ code (``_walk_rows``), a dead block not at all (in the forward it still
 owes the scratch's start and the outputs' write; in the backward nothing:
 dQ^T is summed over a head's blocks of keys by the kernel itself, in HBM,
 by the live steps alone, ``_bwd_kernel``), and the index maps clamp dead
-blocks to the last live one, which the pipeline does not fetch again. Only
+blocks to the last live one, which the pipeline does not fetch again. Under
+a window over several blocks of keys the grid does not hold a head's dead
+blocks at all (``_live_span``, PR 66: a dead step cost 0.33-0.49 us, and
+49 of a head's 64 were dead under a window of one block of eight): a row of
+the forward's grid is the ``span = window / residents + 1`` blocks of keys
+up to its diagonal's, the last step of every row live; a block of keys'
+steps in the backward's are the ``span`` blocks of queries from its own on,
+for each head of its group; the ``span (span - 1) / 2`` steps that pass the
+head's edge (the first rows', the last blocks of keys') are the only dead
+ones left, and do nothing. The index maps are ``qi - (span - 1) + step``
+and ``ki + step``, clamped: the operands are the same arrays in the same
+order, which the benchmark's readers count on. Only
 a masked call that is no self-attention in square blocks (lengths that
 differ, ``res_q != res_k``) keeps loops with bounds computed from the grid
 position. A call under the block-diffusion mask (``blocks``: two streams of
@@ -287,6 +298,30 @@ def _kinds_told_apart(nq: int, nk: int, res_q: int, res_k: int, offset: int,
             and (window is None or window % res_k == 0))
 
 
+def _live_span(nq: int, nk: int, res_q: int, res_k: int, offset: int,
+               causal: bool, window: Optional[int]) -> Optional[int]:
+    """How many blocks of keys a block of queries' window can leave anything
+    of, the diagonal's the last of them (and of queries a block of keys',
+    its own the first), where the call's grid holds those alone: under a
+    window over several blocks of keys whose kinds are told apart. None
+    where the grid holds every block of a head: any other call."""
+    if window is None or nk == 1 or not causal or not _kinds_told_apart(
+            nq, nk, res_q, res_k, offset, causal, window):
+        return None
+    return window // res_k + 1
+
+
+def _grid_steps(nq: int, nk: int, span: Optional[int], dead: int) -> dict:
+    """{"steps": the grid steps a head that a call launches, "dead_steps":
+    those of them the mask leaves nothing of}: every block of the head and
+    its ``dead`` ones, or under ``span`` (``_live_span``) a row's ``span``,
+    of which the first ``span - 1`` rows' (the backward's last columns')
+    pass the head's edge by 1 + ... + (span - 1)."""
+    if span is None:
+        return {"steps": nq * nk, "dead_steps": dead}
+    return {"steps": nq * span, "dead_steps": span * (span - 1) // 2}
+
+
 def _grid_kinds(nq: int, nk: int, res_q: int, res_k: int, offset: int,
                 causal: bool, window: Optional[int] = None) -> dict:
     """{kind: grid blocks of it a head}, every kind of ``_KINDS`` in their
@@ -437,22 +472,34 @@ def _fold_tile(s, carry, masked, values_t):
 def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
-                rows_out: bool = False, blocks: Optional[int] = None):
+                rows_out: bool = False, blocks: Optional[int] = None,
+                span: Optional[int] = None):
     """``o_ref`` is this block of queries' O^T (d_v, resident queries), or
     with ``rows_out`` its O (resident queries, d_v): a head's lanes of a
     model's own [B, T, H x d_v] array (``results_in_model_arrays``), for
     which a row of tiles' float32 accumulator is turned here, in VMEM.
-    ``blocks``: the block-diffusion mask's block length (``_BY_BLOCK``)."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    ``blocks``: the block-diffusion mask's block length (``_BY_BLOCK``).
+    The grid's last axis is a row's blocks of keys, all ``nk`` of them, or
+    with ``span`` (``_live_span``) the last ``span`` up to the diagonal's,
+    which is then every row's last step; a step before a head's first
+    block of keys does nothing."""
+    qi, step_k = pl.program_id(1), pl.program_id(2)
+    n_steps = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
     n_q, n_k = res_q // block_q, res_k // block_k
-    rel0 = offset if static else qi * res_q + offset - ki * res_k
+    if span is None:
+        ki, live = step_k, None
+        rel0 = offset if static else qi * res_q + offset - ki * res_k
+    else:
+        # block of keys qi - (span - 1) + step_k; before the head's first,
+        # the place of a dead block
+        live = qi + step_k >= span - 1
+        rel0 = jnp.where(live, (span - 1 - step_k) * res_k, -res_k)
     fold = _scale_folds(q_ref.dtype, sm_scale)
     scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
                                block_k=block_k, window=window)
 
-    @pl.when(ki == 0)
+    @pl.when(step_k == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -475,7 +522,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *bounds)
             m_scr[:, cols], l_scr[:, cols], acc_scr[:, cols] = m, l, acc
 
-            @pl.when(ki == nk - 1)
+            @pl.when(step_k == n_steps - 1)
             def _finalize():
                 l_safe = jnp.where(l == 0.0, 1.0, l)
                 if rows_out:
@@ -492,10 +539,10 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                    window, edge=edge)
 
     if blocks:
-        _walk_by_place(walk, _by_block_place(qi, ki, nk // 2), res_k, blocks,
-                       kinds)
+        _walk_by_place(walk, _by_block_place(qi, ki, n_steps // 2), res_k,
+                       blocks, kinds)
     else:
-        _walk_by_kind(walk, rel0, res_q, res_k, kinds, window)
+        _walk_by_kind(walk, rel0, res_q, res_k, kinds, window, live)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
@@ -503,17 +550,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
                 nq: int, group: int, o_rows: bool = False,
-                blocks: Optional[int] = None):
+                blocks: Optional[int] = None, span: Optional[int] = None):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three. The last grid axis walks the ``nq`` blocks of queries of each of
     the ``group`` query heads that read this key-value head, one head after
-    another, so dK and dV gather the whole group in the scratch.
+    another, so dK and dV gather the whole group in the scratch; with
+    ``span`` (``_live_span``) a head's steps are the ``span`` blocks of
+    queries from this block of keys' own on, the ones whose window holds any
+    of its keys, and a step past a head's last block of queries does
+    nothing.
 
     One block of keys a head: ``dqt_ref`` is this block of queries' place
     in VMEM and a row of tiles writes its dQ^T there. Several: a block of
-    queries comes back once for every block of keys, never on consecutive
-    steps, so its float32 sum lives in HBM (``dqt_ref`` is the whole array)
+    queries comes back once for every block of keys that sees it (under
+    ``span`` and no group on consecutive steps: the last of one block of
+    keys, after which ``_finalize`` waits for its way back, and the first
+    of the next), so its float32 sum lives in HBM (``dqt_ref`` is the whole
+    array)
     and ``sums`` are two (d, resident queries) buffers cut into the pieces
     that one copy moves (rows of tiles up to ``_COPY_BYTES``), two rows of
     DMA semaphores and a flag. A live step starts the fetch of what the
@@ -538,14 +592,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
     changes nothing in here."""
     ki, step_q = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
-    qi = step_q if group == 1 else step_q % nq
+    steps = nq if span is None else span    # a query head's
+    at = step_q if group == 1 else step_q % steps
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
     n_q, n_k = res_q // block_q, res_k // block_k
-    rel0 = offset if static else qi * res_q + offset - ki * res_k
+    if span is None:
+        qi, live = at, None
+        rel0 = offset if static else qi * res_q + offset - ki * res_k
+    else:
+        # past the head's last block of queries, the place of a dead block
+        qi = ki + at
+        live = qi < nq
+        rel0 = jnp.where(live, at * res_q, -res_k)
     fold = _scale_folds(q_ref.dtype, sm_scale)
     scores = functools.partial(_scores, sm_scale=sm_scale, fold=fold,
                                block_k=block_k, window=window)
-    live = None
     if sums:
         nk = pl.num_programs(1)
         sum_scr, had_scr, sems, pending = sums
@@ -557,10 +618,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
         else:
             first_k = (0 if window is None else
                        _first_live_k(qi, res_q, res_k, offset, nk, window))
-            last_k = (_last_live_k(qi, res_q, res_k, offset, nk) if causal
-                      else nk - 1)
-            live, adds = (ki >= first_k) & (ki <= last_k), ki != first_k
-        head = pl.program_id(0) * group + step_q // nq
+            if span is None:
+                last_k = (_last_live_k(qi, res_q, res_k, offset, nk)
+                          if causal else nk - 1)
+                live = (ki >= first_k) & (ki <= last_k)
+            adds = ki != first_k
+        head = pl.program_id(0) * group + step_q // steps
         copies, _, wide = sum_scr.shape
         rows_a_copy = wide // block_q
 
@@ -905,15 +968,19 @@ def grid_block_kinds(q_len: int, k_len: int, causal: bool,
     ``window`` "trailing" with them: the grid blocks a head of a call of
     these lengths has, by the rule the kernels branch on (``_grid_kinds``).
     ``looped`` blocks walk their tiles in loops with traced bounds, the
-    others with constant ones. The forward's grid unless ``backward``: the
-    two kernels' tiles differ, and at some lengths what a grid step holds
-    with them."""
+    others with constant ones. With them "steps" and "dead_steps": the
+    grid steps a head that the call launches and how many of them the mask
+    leaves nothing of (``_grid_steps``). The forward's grid unless
+    ``backward``: the two kernels' tiles differ, and at some lengths what a
+    grid step holds with them."""
     window = _window_of(window, k_len)
     _, _, res_q, res_k = _block_sizes(
         q_len, k_len, block_q, block_k,
         _BWD_TILES if backward else _FWD_TILES, window)
-    kinds = _grid_kinds(q_len // res_q, k_len // res_k, res_q, res_k,
-                        k_len - q_len, causal, window)
+    grid = (q_len // res_q, k_len // res_k, res_q, res_k, k_len - q_len,
+            causal, window)
+    kinds = _grid_kinds(*grid)
+    kinds.update(_grid_steps(*grid[:2], _live_span(*grid), kinds["dead"]))
     if window is None:
         del kinds["trailing"]
     return kinds
@@ -933,17 +1000,20 @@ def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
     traced call (none a step), so a timeline says which walk a model's
     calls took (``looped`` 0: every block in straight-line code), under
     which ``window`` (0: none), with how many heads of queries and of keys
-    and values, the batch folded into both (``heads``, ``kv_heads``), and
-    how many dQ arrays a backward call leaves for XLA to sum
-    (``dq_partials``: 0 for every shape since PR 50, the kernel sums them
-    itself; until then one a block of keys that a block of queries
-    sees)."""
-    counts = _grid_kinds(nq, nk, res_q, res_k, offset, causal, window)
+    and values, the batch folded into both (``heads``, ``kv_heads``), how
+    many dQ arrays a backward call leaves for XLA to sum (``dq_partials``:
+    0 for every shape since PR 50, the kernel sums them itself; until then
+    one a block of keys that a block of queries sees), and what the call's
+    grid launches of the head's blocks (``steps``, ``dead_steps``:
+    ``_grid_steps`` under the call's ``_live_span``)."""
+    grid = (nq, nk, res_q, res_k, offset, causal, window)
+    counts = _grid_kinds(*grid)
     steptrace.record_counters("attn/grid_blocks", {
         **counts, "queries": nq * res_q, "keys": nk * res_k,
         "backward": int(backward), "window": window or 0,
         "heads": heads[0], "kv_heads": heads[1],
-        "dq_partials": 0})
+        "dq_partials": 0,
+        **_grid_steps(nq, nk, _live_span(*grid), counts["dead"])})
     return tuple(kind for kind in _KINDS if counts[kind])
 
 
@@ -1011,7 +1081,8 @@ def _by_block_grid(length: int, blocks: int, block_q, block_k,
     steptrace.record_counters("attn/grid_blocks", {
         **counts, "queries": length, "keys": length,
         "backward": int(backward), "window": 0, "blocks": blocks,
-        "heads": heads[0], "kv_heads": heads[1], "dq_partials": 0})
+        "heads": heads[0], "kv_heads": heads[1], "dq_partials": 0,
+        **_grid_steps(2 * n, 2 * n, None, counts["dead"])})
     return (block_q, block_k, res, n,
             tuple(kind for kind in _BY_BLOCK if counts[kind]))
 
@@ -1042,6 +1113,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
             q_len, k_len, block_q, block_k, _FWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
+    span = _live_span(nq, nk, res_q, res_k, offset, causal, window)
     if window is not None and offset:
         # no model sends one, and the blocks' kinds, the index maps' clamps
         # and the backward's first and last live blocks (``_first_live_k``,
@@ -1055,6 +1127,10 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
         # own block, the clean stream's first, and past a diagonal the last
         kmap = lambda qi, ki: jnp.where(
             ki < n, jnp.where(qi < n, qi, n), jnp.minimum(ki, n + qi % n))
+    elif span:
+        # a row's last ``span`` blocks of keys up to its diagonal's; a step
+        # before the head's first block fetches that block, the next live
+        kmap = lambda qi, step: jnp.maximum(qi - (span - 1) + step, 0)
     elif causal and nk > 1 and window is not None:
         # blocks behind the window fetch the first live one, as blocks
         # past the diagonal the last
@@ -1070,7 +1146,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, offset=offset, static=nq == nk == 1, window=window,
-        blocks=blocks,
+        blocks=blocks, span=span,
         kinds=kinds if blocks else _kinds_present(
             nq, nk, res_q, res_k, offset, causal, False, window,
             (b, k.shape[0])))
@@ -1088,7 +1164,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                                          q.dtype)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, nq, nk),
+        grid=(b, nq, span or nk),
         in_specs=[
             pl.BlockSpec((None, res_q, d), lambda bi, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((None, res_k, d),
@@ -1144,11 +1220,16 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             q_len, k_len, block_q, block_k, _BWD_TILES, window)
     nq, nk = q_len // res_q, k_len // res_k
     offset = k_len - q_len
+    span = _live_span(nq, nk, res_q, res_k, offset, causal, window)
     if blocks:
         # a noisy block of keys is seen by its own block of queries alone;
         # a clean one by its stream's blocks from its own on, in each stream
         qmap = lambda ki, qi: jnp.where(
             ki < n, ki, jnp.maximum(qi, ki - n + jnp.where(qi < n, 0, n)))
+    elif span:
+        # a block of keys' own block of queries and the ``span - 1`` after
+        # it; a step past the head's last block fetches that block again
+        qmap = lambda ki, step: jnp.minimum(ki + step, nq - 1)
     elif causal and nk > 1 and window is not None:
         qmap = lambda ki, qi: jnp.clip(
             qi, _first_live_q(ki, res_q, res_k, offset, nq),
@@ -1159,12 +1240,14 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     else:
         qmap = lambda ki, qi: qi
     # the grid: key-value heads, their blocks of keys, and for each the
-    # blocks of queries of every query head of the group
+    # blocks of queries of every query head of the group: all ``nq`` of a
+    # head, or the ``span`` that see the block of keys
+    steps = span or nq
     if group == 1:
         head, block = (lambda bi, step: bi), (lambda step: step)
     else:
-        head = lambda bi, step: bi * group + step // nq
-        block = lambda step: step % nq
+        head = lambda bi, step: bi * group + step // steps
+        block = lambda step: step % steps
     qspec = pl.BlockSpec(
         (None, res_q, d),
         lambda bi, ki, step: (head(bi, step), qmap(ki, block(step)), 0))
@@ -1225,11 +1308,11 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, offset=offset, static=nq == nk == 1,
             window=window, nq=nq, group=group, o_rows=heads is not None,
-            blocks=blocks,
+            blocks=blocks, span=span,
             kinds=kinds if blocks else _kinds_present(
                 nq, nk, res_q, res_k, offset, causal, True, window,
                 (b, b_kv))),
-        grid=(b_kv, nk, group * nq),
+        grid=(b_kv, nk, group * steps),
         in_specs=[
             qspec, kspec, vspec,
             pl.BlockSpec((None, d, res_k), lambda bi, ki, step: (bi, 0, ki)),

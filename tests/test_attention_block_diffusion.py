@@ -291,7 +291,8 @@ def test_the_call_is_named_and_recorded(monkeypatch):
         assert g == {"whole": 2, "own": 2, "strict": 2, "inclusive": 2,
                      "dead": 8, "queries": 8192, "keys": 8192,
                      "backward": g["backward"], "window": 0, "blocks": 4,
-                     "heads": 64, "kv_heads": 8, "dq_partials": 0}
+                     "heads": 64, "kv_heads": 8, "dq_partials": 0,
+                     "steps": 16, "dead_steps": 8}
 
 
 def test_the_prologue_serves_positions_that_repeat(monkeypatch):

@@ -7,7 +7,9 @@ itself: one array a call, in copies of whole rows of tiles; since PR 51 the
 boundary of a one-block call, the model's own [B, T, H x d] arrays or the
 [B x H, T, d] that XLA makes of them; since PR 55 the boundary of a call over
 several blocks of keys at one width of whole lane tiles, whose kernels write
-O, dK and dV into, and read O and its cotangent from, the model's arrays."""
+O, dK and dV into, and read O and its cotangent from, the model's arrays;
+since PR 66 the grid of a windowed call whose blocks' kinds are told apart,
+which holds a row's live blocks alone."""
 
 import functools
 import hashlib
@@ -106,6 +108,27 @@ _CASES = {
                                                     32, 16),
     "dq_sum_group_8_keys_128_window_half_a_block": (8, 1, 128, 128, 128, 16,
                                                     16, 32, 16),
+    # no group and a window of one block over 8 blocks of residents (PR 66):
+    # a block of queries' dQ^T sum is written on the last step of one block
+    # of keys and fetched on the first step of the next
+    "dq_sum_group_1_keys_128_window_of_one_block_of_8": (2, 2, 256, 128, 128,
+                                                         16, 16, 32, 32),
+}
+# (blocks of residents a head, blocks a row's window leaves anything of) of
+# the cases whose windowed calls launch a row's live blocks alone (PR 66:
+# ``flash_kernels._live_span``); every other case keeps the grid of all
+# ``nq x nk`` blocks a head
+_SPANS = {
+    "window_of_one_block": (4, 2),
+    "window_of_two_blocks_grouped": (4, 3),
+    "window_wide_keys_tiles_2x1": (4, 2),
+    "window_one_tile_a_block": (4, 2),
+    "dq_sum_group_1_keys_192_window_of_two_blocks": (4, 3),
+    "dq_sum_group_2_keys_64_window_of_one_block": (4, 2),
+    "dq_sum_group_8_keys_128_window_of_one_block": (4, 2),
+    "window_half_a_block_takes_its_own_residents": (8, 2),
+    "dq_sum_group_8_keys_128_window_half_a_block": (8, 2),
+    "dq_sum_group_1_keys_128_window_of_one_block_of_8": (8, 2),
 }
 _BF16 = ("grouped_blocks_4x4", "window_of_one_block",
          "window_half_a_block_takes_its_own_residents",
@@ -136,7 +159,7 @@ def test_window_and_grouped_heads_match_reference(monkeypatch, case, impl,
             assert grid_block_kinds(length, length, True, bq, bk,
                                     window=window) == {
                 "whole": 0, "diagonal": 8, "trailing": 7, "dead": 49,
-                "looped": 0}
+                "looped": 0, "steps": 16, "dead_steps": 1}
     q, k, v, w = _operands(heads, kv, length, d, d_v, dtype)
     f32 = lambda x: x.astype(jnp.float32)
 
@@ -163,9 +186,20 @@ def test_window_and_grouped_heads_match_reference(monkeypatch, case, impl,
         out, grads = jax.jit(functools.partial(out_and_grads, flash))(q, k, v)
         want, want_grads = jax.jit(functools.partial(out_and_grads, ref))(
             f32(q), f32(k), f32(v))
+        if impl != "scan" and resident:
+            grids = _kernel_grids(jax.make_jaxpr(
+                functools.partial(out_and_grads, flash))(q, k, v))
     finally:
         if resident:
             jax.clear_caches()
+    if impl != "scan" and resident:
+        # the grid a head: every block of it, or under a window whose
+        # blocks' kinds are told apart a row's live ones (PR 66)
+        nq, span = _SPANS.get(case, (length // resident, None))
+        name = lambda base: base if window is None else f"{base}_w{window}"
+        assert grids[name("flash_fwd")] == (heads, nq, span or nq)
+        assert grids[name("flash_bwd")] == (kv, nq,
+                                            heads // kv * (span or nq))
     close(out, want, fwd_tol)
     for got, wanted, like in zip(grads, want_grads, (q, k, v)):
         assert got.shape == like.shape and got.dtype == dtype
@@ -215,22 +249,37 @@ def test_the_dq_sum_moves_in_pieces(monkeypatch, case):
 
 
 def test_grid_blocks_by_kind_under_a_window():
-    kinds = lambda w, d, t, x, l=0: {"whole": w, "diagonal": d,
-                                     "trailing": t, "dead": x, "looped": l}
+    """The kinds are the mask's geometry over a head's ``nq x nk`` blocks;
+    ``steps`` and ``dead_steps`` what the call's grid launches of them
+    (PR 66): every block, or under a window whose blocks' kinds are told
+    apart a row's live ones, the only dead steps left those before a
+    head's first block of keys (after its last block of queries)."""
+    def kinds(w, d, t, x, l=0, steps=None, dead_steps=None):
+        total = w + d + t + x + l
+        return {"whole": w, "diagonal": d, "trailing": t, "dead": x,
+                "looped": l, "steps": total if steps is None else steps,
+                "dead_steps": x if dead_steps is None else dead_steps}
+
     for backward in (False, True):
-        # Trinity-Mini's window layer at 16,384: the window is one block
+        # Trinity-Mini's window layer at 16,384: the window is one block,
+        # a row its trailing and its diagonal block
         assert grid_block_kinds(16384, 16384, True, backward=backward,
-                                window=2048) == kinds(0, 8, 7, 49)
+                                window=2048) == kinds(0, 8, 7, 49, 0, 16, 1)
+        # two blocks of window: three steps a row, 1 + 2 past the edge
         assert grid_block_kinds(8192, 8192, True, backward=backward,
-                                window=4096) == kinds(3, 4, 2, 7)
-    # without a window what it gave, "trailing" not among the keys
+                                window=4096) == kinds(3, 4, 2, 7, 0, 12, 3)
+    # without a window what it gave, "trailing" not among the keys, and
+    # every block a step
     assert grid_block_kinds(16384, 16384, True) == {
-        "whole": 28, "diagonal": 8, "dead": 28, "looped": 0}
+        "whole": 28, "diagonal": 8, "dead": 28, "looped": 0, "steps": 64,
+        "dead_steps": 28}
     assert grid_block_kinds(8192, 8192, True) == {
-        "whole": 6, "diagonal": 4, "dead": 6, "looped": 0}
+        "whole": 6, "diagonal": 4, "dead": 6, "looped": 0, "steps": 16,
+        "dead_steps": 6}
     # a window that holds every key is none
     assert grid_block_kinds(2048, 2048, True, window=2048) == {
-        "whole": 0, "diagonal": 1, "dead": 0, "looped": 0}
+        "whole": 0, "diagonal": 1, "dead": 0, "looped": 0, "steps": 1,
+        "dead_steps": 0}
     # one block a head is one kind whatever the window
     assert grid_block_kinds(2048, 2048, True, window=512) == kinds(0, 1, 0, 0)
     # a window that is no whole number of blocks keeps the loops
@@ -241,9 +290,9 @@ def test_grid_blocks_by_kind_under_a_window():
         # lengths takes residents of its own length (PR 62): Mellum's
         # window layer at 8,192 is 8 x 8 blocks a head, none looped
         assert grid_block_kinds(8192, 8192, True, backward=backward,
-                                window=1024) == kinds(0, 8, 7, 49)
+                                window=1024) == kinds(0, 8, 7, 49, 0, 16, 1)
         assert grid_block_kinds(4096, 4096, True, backward=backward,
-                                window=1024) == kinds(0, 4, 3, 9)
+                                window=1024) == kinds(0, 4, 3, 9, 0, 8, 1)
         # phi-4-mini-flash's 512 keys stay a quarter of a block
         assert grid_block_kinds(16384, 16384, True, backward=backward,
                                 window=512) == kinds(0, 0, 0, 0, 64)
@@ -299,7 +348,12 @@ def test_a_windowed_grouped_call_is_named_and_recorded():
         assert r == {"whole": 0, "diagonal": 8, "trailing": 7, "dead": 49,
                      "looped": 0, "queries": 16384, "keys": 16384,
                      "backward": r["backward"], "window": 2048, "heads": 32,
-                     "kv_heads": 4, "dq_partials": 0}
+                     "kv_heads": 4, "dq_partials": 0, "steps": 16,
+                     "dead_steps": 1}
+    # a row's trailing and diagonal block, a block of keys' own and next
+    # block of queries for each of the group's eight heads (PR 66)
+    assert _kernel_grids(jaxpr) == {"flash_fwd_w2048": (32, 8, 2),
+                                    "flash_bwd_w2048": (4, 8, 8 * 2)}
     # dQ^T leaves the backward call as one float32 sum a query head
     assert _kernel_outputs(jaxpr)["flash_bwd_w2048"][0] == (
         (32, 128, 16384), jnp.float32)
@@ -323,6 +377,12 @@ def _kernel_eqns(jaxpr):
 def _kernel_outputs(jaxpr):
     """{kernel's name: [(shape, dtype) of each output]}."""
     return {name: [(v.aval.shape, v.aval.dtype) for v in eqn.outvars]
+            for name, eqn in _kernel_eqns(jaxpr).items()}
+
+
+def _kernel_grids(jaxpr):
+    """{kernel's name: its grid}."""
+    return {name: eqn.params["grid_mapping"].grid
             for name, eqn in _kernel_eqns(jaxpr).items()}
 
 

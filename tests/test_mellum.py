@@ -166,7 +166,7 @@ def test_the_model_is_the_reference(attention, monkeypatch, request):
         monkeypatch.setattr(flash_kernels, "_WINDOW_RESIDENT_FROM", 8)
         assert flash_kernels.grid_block_kinds(32, 32, True, 8, 8, window=8) \
             == {"whole": 0, "diagonal": 4, "trailing": 3, "dead": 9,
-                "looped": 0}
+                "looped": 0, "steps": 8, "dead_steps": 1}
         monkeypatch.setattr(
             ops_attention, "flash_attention", functools.partial(
                 ops_attention.flash_attention, impl="pallas_interpret",

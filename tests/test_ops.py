@@ -264,7 +264,9 @@ def test_flash_grid_block_kinds():
     from ray_tpu._private import steptrace
     from ray_tpu.ops.flash_kernels import grid_block_kinds
 
-    kinds = lambda *n: dict(zip(("whole", "diagonal", "dead", "looped"), n))
+    # every block of a head is a grid step: no window here (PR 66)
+    kinds = lambda *n: dict(zip(("whole", "diagonal", "dead", "looped"), n),
+                            steps=sum(n), dead_steps=n[2])
     for backward in (False, True):
         assert grid_block_kinds(8192, 8192, True,
                                 backward=backward) == kinds(6, 4, 6, 0)

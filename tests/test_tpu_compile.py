@@ -119,8 +119,8 @@ _HELD_PROGRAMS = {
     "lfm2-8b-a1b.step-8k": "73a6b74898269edd",   # PR 61
     "qwen3-next-80b-a3b.step-8k": "9d394435fa05898f",   # PR 61
     "nemotron-3-nano-30b-a3b.step-8k": "a33f055570590fef",   # PR 64
-    "trinity-mini.step-16k": "dcb2f4871e1e02db",   # PR 63
-    "mellum2-12b-a2.5b.step-8k": "d57b050e2373b2da",   # PR 63
+    "trinity-mini.step-16k": "69c63c6279bc5a07",   # PR 66
+    "mellum2-12b-a2.5b.step-8k": "82ab99b9a5fbbc45",   # PR 66
     "sdar-30b-a3b-chat.step-bd-4k": "e6cb997ca8d2efbf",   # PR 65
 }
 
@@ -672,6 +672,49 @@ def _dq_census(text, heads, d, seq):
     assert not re.search(rf"f32\[\d+,{heads},{d},{seq}\]", text)
 
 
+def _windowed_calls_census(text, heads, kv_heads, seq, window, layers):
+    """The benchmark's readers find a windowed call by its instruction's
+    name and take its shapes from the FIRST THREE operands of its
+    ``custom-call`` (``attn_masked_roofline_pct.needed_flops``): q [B x H,
+    T, 128], k [B x H_kv, T, 128], then V^T (forward) or V (backward), all
+    three-dimensional. The grid that holds a row's live blocks alone (PR
+    66) is index maps' arithmetic over the same operands: no table of
+    scalars stands before them, and the reader counts each call's needed
+    pairs as it did."""
+    from perfbench.metrics.attn_masked_roofline_pct import (attended_pairs,
+                                                            needed_flops)
+
+    lines = [line.strip() for line in text.splitlines() if re.match(
+        rf'\s*%?flash_(?:fwd|bwd)_w{window}[\w.\-]* = .*'
+        r'custom_call_target="tpu_custom_call"', line)]
+    assert len(lines) == 2 * layers
+    pairs = heads * attended_pairs(seq, seq, window)
+    needed = {"fwd": pairs * 2 * (128 + 128),
+              "bwd": pairs * 2 * (3 * 128 + 2 * 128)}
+    first_three = {
+        "fwd": [(heads, seq, 128), (kv_heads, seq, 128), (kv_heads, 128, seq)],
+        "bwd": [(heads, seq, 128), (kv_heads, seq, 128), (kv_heads, seq, 128)]}
+    for line in lines:
+        kind = re.match(r"%?flash_(fwd|bwd)", line).group(1)
+        # a trace's event prints each operand with its shape; the compiled
+        # text names the operands alone and says their shapes, in their
+        # order, as the call's layout constraints
+        names = re.search(r"custom-call\((.*?)\), custom_call_target=",
+                          line).group(1)
+        shapes = re.findall(r"\w+\[[\d,]*\]\{[\d,]*\}", re.search(
+            r"operand_layout_constraints=\{((?:\w+\[[\d,]*\]\{[\d,]*\}"
+            r"(?:, )?)+)\}", line).group(1))
+        assert len(shapes) == len(names.split(", ")), (shapes, names)
+        assert not any(shape.startswith("s32") for shape in shapes), shapes
+        assert [tuple(int(n) for n in re.search(
+            r"\[([\d,]*)\]", shape).group(1).split(","))
+                for shape in shapes[:3]] == first_three[kind], shapes
+        event = line.replace(f"custom-call({names})", "custom-call(" + ", ".join(
+            f"{shape} {name}"
+            for shape, name in zip(shapes, names.split(", "))) + ")")
+        assert needed_flops(event) == needed[kind], event[:400]
+
+
 def _q_sized_census(text, elements):
     """{(kind, result): instructions} of the compiled step, outside fused
     computations, whose result (or one of a tuple's) is a float32 or
@@ -824,7 +867,8 @@ def test_latent_attention_expert_step_fits_one_chip_at_8k(
         assert e["args"] == {
             "whole": 6, "diagonal": 4, "trailing": 0, "dead": 6, "looped": 0,
             "queries": seq, "keys": seq, "backward": e["args"]["backward"],
-            "window": 0, "heads": 64, "kv_heads": 64, "dq_partials": 0}
+            "window": 0, "heads": 64, "kv_heads": 64, "dq_partials": 0,
+            "steps": 16, "dead_steps": 6}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     state_bytes = 3 * 4 * sum(
@@ -891,7 +935,9 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     no forward call again (``ops.remat.remat_policy``). Each traced call
     wrote its grid's blocks by kind into the runtime's ring: 8 x 8 a head,
     under the window 8 diagonal, 7 trailing, 49 dead, without it 28 whole,
-    8 diagonal, 28 dead, none walked in a loop with traced bounds. Each
+    8 diagonal, 28 dead, none walked in a loop with traced bounds; the
+    windowed calls' grids launch a row's trailing and diagonal block alone
+    (PR 66: 16 steps a head, 1 dead; ``_windowed_calls_census``). Each
     backward call hands back one float32 dQ^T sum (``_dq_census``: until PR
     50 two partials a block of queries under the window and eight, 2 GiB,
     in the full layer). No array is shaped like a
@@ -928,16 +974,21 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
             for e in boundary} == {(4, 0, 1)}
     assert collections.Counter(e["window"] for e in boundary) == {
         2048: 4, 0: 1}    # 5 of the 5 layers
-    by_window = {2048: (0, 8, 7, 49), 0: (28, 8, 0, 28)}
+    # the windowed calls' grids hold a row's trailing and diagonal block
+    # alone, 16 steps a head, one of them past the head's edge (PR 66);
+    # the full layer's every block
+    by_window = {2048: (0, 8, 7, 49, 16, 1), 0: (28, 8, 0, 28, 64, 28)}
     assert {(e["window"], e["backward"]) for e in drawn} == {
         (w, b) for w in by_window for b in (0, 1)}
     for e in drawn:
-        whole, diagonal, trailing, dead = by_window[e["window"]]
+        whole, diagonal, trailing, dead, steps, dead_steps = by_window[
+            e["window"]]
         assert e == {
             "whole": whole, "diagonal": diagonal, "trailing": trailing,
             "dead": dead, "looped": 0, "queries": seq, "keys": seq,
             "backward": e["backward"], "window": e["window"], "heads": 32,
-            "kv_heads": 4, "dq_partials": 0}
+            "kv_heads": 4, "dq_partials": 0, "steps": steps,
+            "dead_steps": dead_steps}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -951,6 +1002,7 @@ def test_window_and_full_attention_expert_step_fits_one_chip_at_16k(
     assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_fwd_w2048": 4,
                      "flash_bwd_w2048": 4}
     assert "bf16[32,16384,128]" in text and "bf16[4,16384,128]" in text
+    _windowed_calls_census(text, 32, 4, seq, 2048, layers=4)
     _dq_census(text, 32, 128, seq)
     _head_rotary_census(text, counters, seq * 32 * 128, layers=5, rotated=4)
     # round the ten calls XLA laid the kernel's output out three times and
@@ -1070,7 +1122,8 @@ def test_five_kinds_step_fits_one_chip_at_one_16k_sequence(
             "whole": whole, "diagonal": diagonal, "trailing": trailing,
             "dead": dead, "looped": looped, "queries": seq, "keys": seq,
             "backward": e["backward"], "window": e["window"], "heads": 20,
-            "kv_heads": 10, "dq_partials": 0}
+            "kv_heads": 10, "dq_partials": 0, "steps": 64,
+            "dead_steps": dead}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -1442,7 +1495,10 @@ def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
     window layers' named ``flash_*_w1024``; under the window a grid step
     holds the window's own 1,024 queries and keys (``_block_sizes``, PR 62),
     so a head is 8 x 8 blocks told apart by their place (8 diagonal, 7
-    trailing, 49 dead, none looped), the full layer's 4 x 4 of 2,048. Two
+    trailing, 49 dead, none looped), of which the windowed calls' grids
+    launch a row's trailing and diagonal block alone (PR 66: 16 steps a
+    head, 1 dead; ``_windowed_calls_census``), the full layer's 4 x 4 of
+    2,048. Two
     rotary tables are built a traced pass, one plain and one YaRN. The four
     expert layers' matmuls are the grouped-matmul kernels at 2,304 x 1,792
     / 896 over 16 groups. No array is shaped like a [T, T] score matrix, and
@@ -1488,17 +1544,22 @@ def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
     assert {(e["heads"], e["kv_heads"], e["d_qk"], e["d_v"],
              e["model_results"]) for e in by_name["attention/boundary"]} == {
         (32, 4, 128, 128, 1)}
-    by_window = {1024: (0, 8, 7, 49), 0: (6, 4, 0, 6)}
+    # the windowed calls' grids hold a row's trailing and diagonal block
+    # alone, 16 steps a head, one of them past the head's edge (PR 66);
+    # the full layer's every block
+    by_window = {1024: (0, 8, 7, 49, 16, 1), 0: (6, 4, 0, 6, 16, 6)}
     assert {(e["window"], e["backward"])
             for e in by_name["attn/grid_blocks"]} == {
         (w, b) for w in by_window for b in (0, 1)}
     for e in by_name["attn/grid_blocks"]:
-        whole, diagonal, trailing, dead = by_window[e["window"]]
+        whole, diagonal, trailing, dead, steps, dead_steps = by_window[
+            e["window"]]
         assert e == {
             "whole": whole, "diagonal": diagonal, "trailing": trailing,
             "dead": dead, "looped": 0, "queries": seq, "keys": seq,
             "backward": e["backward"], "window": e["window"],
-            "heads": batch * 32, "kv_heads": batch * 4, "dq_partials": 0}
+            "heads": batch * 32, "kv_heads": batch * 4, "dq_partials": 0,
+            "steps": steps, "dead_steps": dead_steps}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
@@ -1512,6 +1573,7 @@ def test_window_over_full_rotary_expert_step_fits_one_chip_at_two_8k_sequences(
     assert calls == {"flash_fwd": 1, "flash_bwd": 1, "flash_fwd_w1024": 3,
                      "flash_bwd_w1024": 3}
     assert "bf16[64,8192,128]" in text and "bf16[8,8192,128]" in text
+    _windowed_calls_census(text, 64, 8, seq, 1024, layers=3)
     _dq_census(text, 64, 128, seq)
     _head_rotary_census(text, counters, batch * seq * 32 * 128, layers=4,
                         rotated=4)
@@ -1599,7 +1661,8 @@ def test_block_diffusion_expert_step_fits_one_chip_at_two_4k_sequences(
             "whole": 2, "own": 2, "strict": 2, "inclusive": 2, "dead": 8,
             "queries": positions, "keys": positions,
             "backward": e["backward"], "window": 0, "blocks": 4,
-            "heads": batch * 32, "kv_heads": batch * 4, "dq_partials": 0}
+            "heads": batch * 32, "kv_heads": batch * 4, "dq_partials": 0,
+            "steps": 16, "dead_steps": 8}
     compiled = lowered.compile()
     planned = _device_bytes(compiled)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
