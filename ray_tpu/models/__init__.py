@@ -26,10 +26,16 @@
   sequence under one mask by block (``ops.attention.seen_by_block``), a
   weighted loss over the masked positions, the noise drawn inside the step;
   every feed-forward softmax-routed experts (training)
+- keye: every attention layer over the keys a learned indexer picks for each
+  query (``ops/sparse_index.py``: index scores, the exact ``topk``-th
+  largest a row, the indexer's KL loss), rotary positions of three
+  components (``llama.rope_table`` under ``mrope_section``), every
+  feed-forward softmax-routed experts (training; the language model of a
+  vision-language model, without its tower)
 """
 
-from ray_tpu.models import (afmoe, gpt2, llama, mellum, mla_moe, moe_lm,
+from ray_tpu.models import (afmoe, gpt2, keye, llama, mellum, mla_moe, moe_lm,
                             nemotron_h, phi4flash, qwen3_next, sdar, vision)
 
-__all__ = ["afmoe", "gpt2", "llama", "mellum", "mla_moe", "moe_lm",
+__all__ = ["afmoe", "gpt2", "keye", "llama", "mellum", "mla_moe", "moe_lm",
            "nemotron_h", "phi4flash", "qwen3_next", "sdar", "vision"]

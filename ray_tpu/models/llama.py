@@ -24,6 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import flax.linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -140,12 +141,35 @@ def rope_table(head_dim: int, positions, parameters):
     ``attention_factor`` (left out: ``0.1 ln(factor) + 1``), so that a
     layer's scores carry its square. Static: the same table at every
     sequence length. One ``counters`` record ``rope/table`` a traced call
-    says what was built."""
+    says what was built.
+
+    Given ``mrope_section`` (three counts that add up to ``head_dim // 2``;
+    ``rope_type`` ``default``), ``positions`` is (3, ..., T), a token's
+    temporal, height and width components, and frequency pair ``i`` takes
+    its angle from the row its section names: the first ``section[0]``
+    pairs from row 0, the next ``section[1]`` from row 1, the rest from row
+    2 (in order, not interleaved). Three equal rows give the plain table to
+    the bit; the record says the sections."""
     p = dict(parameters)
     kind, theta = p.get("rope_type", "default"), float(p["rope_theta"])
     said = {"kind": {"default": "plain"}.get(kind, kind), "theta": theta,
             "factor": 1.0, "original": 0, "low": 0, "high": 0,
             "attention_factor": 1.0, "dims": head_dim}
+    section = tuple(p.get("mrope_section") or ())
+    if section:
+        if kind != "default" or len(section) != 3 or sum(
+                section) != head_dim // 2 or positions.shape[0] != 3:
+            raise ValueError(
+                f"mrope_section {section}: three counts that add up to "
+                f"{head_dim // 2}, rope_type default, positions (3, ..., T)")
+        steptrace.record_counters("rope/table", {
+            **said, "kind": "sections", "section_t": section[0],
+            "section_h": section[1], "section_w": section[2]})
+        cos, sin = rope_frequencies(head_dim, positions, theta)
+        row = np.repeat(np.arange(3), section)      # a pair's row
+        pick = lambda t: jnp.moveaxis(
+            t[row, ..., np.arange(head_dim // 2)], 0, -1)
+        return pick(cos), pick(sin)
     if kind == "default":
         steptrace.record_counters("rope/table", said)
         return rope_frequencies(head_dim, positions, theta)

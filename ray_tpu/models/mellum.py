@@ -43,6 +43,7 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
@@ -55,6 +56,7 @@ from ray_tpu.models.afmoe import (  # noqa: F401 (this module's names too)
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm, RMSNormScale, rope_table
 from ray_tpu.models.mla_moe import RoutedExperts
+from ray_tpu.ops import sparse_index
 from ray_tpu.ops.attention import normed_rotary_self_attention
 from ray_tpu.ops.remat import remat_policy
 from ray_tpu.parallel.mesh_utils import on_batch_axes
@@ -148,10 +150,15 @@ def _init(c: MellumConfig):
 class Attention(nn.Module):
     """One attention layer; ``window`` None is a full layer. ``cos``, ``sin``
     are its kind's table. ``blocks`` (``models/sdar.py``'s layers): the T
-    positions are two streams under the block-diffusion mask."""
+    positions are two streams under the block-diffusion mask. ``sparse``
+    (``models/keye.py``'s layers; an object with ``heads``, ``width``,
+    ``topk``, ``dtype``): a learned indexer beside q, k and v picks the
+    ``topk`` keys each query sees (``ops/sparse_index.py``), and the call
+    returns (y, the layer's sum over its queries of the indexer's KL)."""
     config: MellumConfig
     window: Any = None
     blocks: Any = None
+    sparse: Any = None
 
     @nn.compact
     def __call__(self, x, cos, sin):
@@ -164,35 +171,79 @@ class Attention(nn.Module):
         q = on_batch_axes(dense(H * D, "q_proj")(x).reshape(B, T, H, D))
         k = on_batch_axes(dense(G * D, "k_proj")(x).reshape(B, T, G, D))
         v = on_batch_axes(dense(G * D, "v_proj")(x).reshape(B, T, G, D))
+        o_proj = lambda y: dense(c.hidden_size, "o_proj")(
+            on_batch_axes(y.reshape(B, T, H * D)))
+        if self.sparse is not None:
+            return self._selected(x, q, k, v, scale, cos, sin, o_proj)
         # each head's q and k normed over its width, then turned
         y = normed_rotary_self_attention(
             q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
             eps=c.rms_norm_eps, attention=c.attention, window=self.window,
             blocks=self.blocks)
-        return dense(c.hidden_size, "o_proj")(
-            on_batch_axes(y.reshape(B, T, H * D)))
+        return o_proj(y)
+
+    def _selected(self, x, q, k, v, scale, cos, sin, o_proj):
+        """The layer under its indexer's selection. The indexer reads ``x``
+        as a constant and nothing of it but the KL is differentiated: the
+        trunk is moved by the language loss alone, the indexer by the KL
+        alone. ``q_idx`` [B, T, J, W] and the one index key a token ``k_idx``
+        [B, T, W] (a LayerNorm over W, float32) go to the index matmul in
+        ``sparse.dtype``; ``w`` [B, T, J] is float32 at precision highest,
+        scaled ``J^-0.5 W^-0.5``; no rotation inside the indexer."""
+        c, sp = self.config, self.sparse
+        B, T, _ = x.shape
+        xd = jax.lax.stop_gradient(x)
+        # the two projections in the index matmul's own type: a float32
+        # index is float32 from ``x`` on
+        dense = lambda n, name: nn.Dense(
+            n, use_bias=False, dtype=sp.dtype, kernel_init=_init(c),
+            precision=sparse_index.precision_of(sp.dtype),
+            name=name)
+        q_idx = dense(sp.heads * sp.width, "index_q")(xd).reshape(
+            B, T, sp.heads, sp.width)
+        k_idx = nn.LayerNorm(epsilon=c.rms_norm_eps, dtype=jnp.float32,
+                             name="index_k_norm")(
+            dense(sp.width, "index_k")(xd)).astype(sp.dtype)
+        w = nn.Dense(sp.heads, use_bias=False, dtype=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST,
+                     kernel_init=_init(c), name="index_w")(
+            xd.astype(jnp.float32)) * (sp.heads * sp.width) ** -0.5
+        chosen = sparse_index.select(q_idx, k_idx, w, sp.topk)
+        # for a side run that asks for it (``mutable=["intermediates"]``)
+        self.sow("intermediates", "selected", chosen.mask)
+        y, (qf, kf, lse) = normed_rotary_self_attention(
+            q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
+            eps=c.rms_norm_eps, attention=c.attention, selected=chosen.mask,
+            topk=sp.topk)
+        kl = sparse_index.index_kl(
+            q_idx, k_idx, w, chosen, qf, kf, lse, topk=sp.topk,
+            sm_scale=c.head_dim ** -0.5)
+        return o_proj(y), kl
 
 
 class Block(nn.Module):
-    """-> (x, tokens per held expert)."""
+    """-> (x, tokens per held expert), and under ``sparse`` the layer's
+    indexer's KL third."""
     config: MellumConfig
     window: Any = None
     blocks: Any = None
+    sparse: Any = None
 
     @nn.compact
     def __call__(self, x, cos, sin):
         c = self.config
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
-        x = on_batch_axes(x + Attention(c, self.window, self.blocks,
-                                        name="attn")(
-            norm("input_norm")(x), cos, sin))
+        y = Attention(c, self.window, self.blocks, self.sparse, name="attn")(
+            norm("input_norm")(x), cos, sin)
+        y, *kl = y if self.sparse is not None else (y,)
+        x = on_batch_axes(x + y)
         y, tokens = RoutedExperts(
             experts=c.num_experts, expert_shard=c.expert_shard,
             width=c.moe_intermediate_size, per_token=c.num_experts_per_tok,
             scale=1.0, normalize=c.norm_topk_prob, shared=0, dtype=c.dtype,
             kernel_init=_init(c), eps=0.0, score="softmax", name="moe")(
                 norm("post_attn_norm")(x))
-        return on_batch_axes(x + y), tokens
+        return (on_batch_axes(x + y), tokens, *kl)
 
 
 class Mellum(nn.Module):

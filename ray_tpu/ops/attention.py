@@ -25,6 +25,15 @@ entries. The kernels' bodies and their ``pallas_call`` builders are
 a mesh is ``ops/mosaic.py``. ``normed_rotary_self_attention``, at the end, is
 the entry of a layer whose q and k pass a head norm and a rotation first:
 their kernels are ``ops/rotary.py``.
+
+Four kinds of mask: causal, a causal window, block diffusion's over two
+streams (``blocks``), each a function of the two positions alone that the
+kernels work out from a tile's place, and since PR 67 a SELECTION
+(``selected``): a mask the step computed (``ops/sparse_index.py``: the keys
+a learned indexer picks for each query), an int8 operand [B, T keys, T
+queries] that every head of a batch row reads a tile at a time.
+``attention_reference`` and ``normed_rotary_self_attention`` take it; it
+excludes ``window`` and ``blocks``.
 """
 
 from __future__ import annotations
@@ -34,10 +43,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import ad_checkpoint, lax
 
 from ray_tpu._private import steptrace
 from ray_tpu.ops import flash_kernels as kernels, rotary
+from ray_tpu.ops.sparse_index import pairs_selected
 from ray_tpu.ops.flash_kernels import NEG_INF
 from ray_tpu.ops.mosaic import per_batch_shard, takes_kernels
 
@@ -76,16 +87,24 @@ def seen_by_block(qi, ki, blocks: int, half: int):
 def attention_reference(q, k, v, *, causal: bool = False,
                         sm_scale: Optional[float] = None,
                         window: Optional[int] = None,
-                        blocks: Optional[int] = None) -> jax.Array:
+                        blocks: Optional[int] = None,
+                        selected=None) -> jax.Array:
     """Naive softmax(QK^T)V. Shapes: (..., h, s, d); ``k`` and ``v`` may
     have fewer heads, each read by a group of query heads. Under ``window``
     a query sees its own position and the ``window - 1`` before it; given
-    ``blocks``, the mask is ``seen_by_block``'s over two streams of s / 2."""
+    ``blocks``, the mask is ``seen_by_block``'s over two streams of s / 2;
+    given ``selected`` (int8 [b, s keys, s queries], a mask the step
+    computed: ``ops/sparse_index.py``; no window, no blocks), a query sees
+    the keys it says, which hold the causal edge."""
     sm_scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     assert causal or window is None, "a window is a causal mask's"
     k, v = _per_query_head(q, k), _per_query_head(q, v)
     s = jnp.einsum("...qd,...kd->...qk", q, k) * sm_scale
-    if blocks:
+    if selected is not None:
+        assert causal and window is None and not blocks, (window, blocks)
+        s = jnp.where(jnp.swapaxes(selected, -1, -2)[:, None] != 0, s,
+                      NEG_INF)
+    elif blocks:
         q_len, k_len = s.shape[-2], s.shape[-1]
         assert q_len == k_len and window is None, (q_len, k_len, window)
         qi = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
@@ -524,7 +543,7 @@ def _boundary(q, k, v, window: Optional[int], blocks: Optional[int] = None,
 
 def causal_self_attention(q, k, v, attention: str = "auto",
                           window: Optional[int] = None,
-                          blocks: Optional[int] = None):
+                          blocks: Optional[int] = None, selected=None):
     """Causal self-attention of ``q`` [B, T, H, d], ``k`` [B, T, H_kv, d]
     and ``v`` [B, T, H_kv, d_v] in a model's own layout (one length; H_kv
     divides H, query head ``j`` reading key-value head ``j // (H / H_kv)``;
@@ -551,7 +570,17 @@ def causal_self_attention(q, k, v, attention: str = "auto",
     (``seen_by_block``) in place of the causal one: the kernels under
     "flash" (under "auto" where ``flash_kernels.by_block_fits`` admits the
     length too), else the dense mask in ``jnp`` (``attention_reference``);
-    the record says which ran (``kernel``)."""
+    the record says which ran (``kernel``).
+
+    Given ``selected`` (``attention_reference``'s: a mask the step
+    computed) this entry has the dense mask in ``jnp`` alone: the kernels
+    under a selection are ``normed_rotary_self_attention``'s."""
+    if selected is not None:
+        assert window is None and not blocks, (window, blocks)
+        return attention_reference(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), causal=True,
+            selected=selected).transpose(0, 2, 1, 3)
     if attention == "auto":
         attention = auto_attention(q, v)
         if blocks and not kernels.by_block_fits(q.shape[1], blocks):
@@ -645,6 +674,65 @@ _normed_rotary_flash.defvjp(_normed_rotary_flash_fwd,
                             _normed_rotary_flash_bwd)
 
 
+# The same under a selection (``ops/sparse_index.py``): the mask is an
+# operand, every head of a batch row reads that row's, and the call hands
+# back what the indexer's loss reads of the main attention beside the output:
+# the flash kernels' own operands and their log-sum-exp over the selected
+# set. A function of its own: the calls above keep their equations.
+
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(8, 9, 10, 11, 12, 13, 14))
+def _normed_rotary_flash_selected(q, k, v, q_scale, k_scale, cos, sin, mask,
+                                  heads, eps, sm_scale, block_q, block_k,
+                                  interpret, topk):
+    """``_normed_rotary_flash`` under ``mask`` (int8 [B, T keys, T
+    queries]) -> (out [B, T, heads x 128], (qf [B x H, T, 128], kf [B x G,
+    T, 128], lse [B x H, 1, T])); the three are constants to whoever reads
+    them: their cotangents are dropped."""
+    return _normed_rotary_flash_selected_fwd(
+        q, k, v, q_scale, k_scale, cos, sin, mask, heads, eps, sm_scale,
+        block_q, block_k, interpret, topk)[0]
+
+
+def _normed_rotary_flash_selected_fwd(q, k, v, q_scale, k_scale, cos, sin,
+                                      mask, heads, eps, sm_scale, block_q,
+                                      block_k, interpret, topk):
+    kv_heads = k.shape[2] // (q.shape[2] // heads)
+    prologue = functools.partial(rotary.head_rotary_fwd, cos=cos, sin=sin,
+                                 eps=eps, interpret=interpret)
+    qf = prologue(q, q_scale, heads=heads)
+    kf = prologue(k, k_scale, heads=kv_heads)
+    out, lse = map(ad_checkpoint.checkpoint_name, kernels._flash_pallas(
+        qf, kf, _folded(v, kv_heads), causal=True, heads=heads,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, selected=mask, topk=topk), REMAT_NAMES)
+    return (out, (qf, kf, lse)), (q, k, v, q_scale, k_scale, cos, sin, mask,
+                                  qf, kf, out, lse)
+
+
+def _normed_rotary_flash_selected_bwd(heads, eps, sm_scale, block_q, block_k,
+                                      interpret, topk, res, g):
+    q, k, v, q_scale, k_scale, cos, sin, mask, qf, kf, out, lse = res
+    kv_heads = k.shape[2] // (q.shape[2] // heads)
+    dq_t, dk, dv = kernels._flash_pallas_bwd_kernel(
+        qf, kf, _folded(v, kv_heads), g[0], lse, out, causal=True,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, heads=heads, dq_turned=False, selected=mask,
+        topk=topk)
+    back = functools.partial(rotary.head_rotary_bwd, cos=cos, sin=sin,
+                             eps=eps, interpret=interpret)
+    dq, dq_scale = back(dq_t, q, q_scale, heads=heads, turned=True)
+    dk, dk_scale = back(dk, k, k_scale, heads=kv_heads, turned=False)
+    return (dq, dk, dv, dq_scale.astype(q_scale.dtype),
+            dk_scale.astype(k_scale.dtype),
+            *jax.tree.map(jnp.zeros_like, (cos, sin)),
+            np.zeros(mask.shape, jax.dtypes.float0))
+
+
+_normed_rotary_flash_selected.defvjp(_normed_rotary_flash_selected_fwd,
+                                     _normed_rotary_flash_selected_bwd)
+
+
 def auto_head_rotary(q, v, cos, attention: str = "auto") -> str:
     """What ``normed_rotary_self_attention(..., impl=None)`` runs for q [B,
     T, H, d], v [B, T, H_kv, d_v] and the table ``cos`` (None: no rotation)
@@ -665,6 +753,20 @@ def _prologue_fits(q, v, cos, attention: str) -> bool:
     (``auto_head_rotary`` says what each part asks)."""
     return (attention == "flash" and rotary.fits(q, cos)
             and results_in_model_arrays(q.shape[1], q.shape[3], v.shape[3]))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "eps", "interpret", "block_q", "block_k", "topk"))
+def _normed_rotary_kernels_selected(q, k, v, q_scale, k_scale, cos, sin, mask,
+                                    *, heads, eps, interpret, block_q,
+                                    block_k, topk):
+    """``_normed_rotary_kernels`` under a selection's ``mask``, outside any
+    mesh (``sparse_index.auto_impl`` keeps the kernels there): -> (out,
+    (qf, kf, lse))."""
+    tables = (None, None) if cos is None else rotary.tables(cos, sin)
+    return _normed_rotary_flash_selected(
+        q, k, v, q_scale, k_scale, *tables, mask, heads, eps,
+        (q.shape[2] // heads) ** -0.5, block_q, block_k, interpret, topk)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -698,7 +800,8 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
                                  impl: Optional[str] = None,
                                  block_q: Optional[int] = None,
                                  block_k: Optional[int] = None,
-                                 blocks: Optional[int] = None):
+                                 blocks: Optional[int] = None,
+                                 selected=None, topk: Optional[int] = None):
     """``causal_self_attention`` of a layer whose q [B, T, H, d] and k [B,
     T, H_kv, d], as the projections wrote them, first pass an RMSNorm over
     a head's width (``q_scale``, ``k_scale`` [d]; ``eps``) and, given a
@@ -718,7 +821,16 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     ``block_q`` / ``block_k`` if given) or "jnp". One ``counters`` record
     ``attention/head_rotary`` a traced call says which ran. ``blocks`` is
     ``causal_self_attention``'s: the T positions are two streams under the
-    block-diffusion mask, and the table holds a stream's positions twice."""
+    block-diffusion mask, and the table holds a stream's positions twice.
+
+    Given ``selected`` (int8 [B, T keys, T queries], a mask the step
+    computed, of ``topk`` keys a query: ``ops/sparse_index.py``; no window,
+    no blocks) a query sees the keys it says, and the call returns (y, (qf
+    [B x H, T, d], kf [B x H_kv, T, d], lse)): beside the output, what the
+    indexer's loss reads of the main attention, the normed and rotated
+    queries and keys and, from the kernels, the log-sum-exp of the scores
+    over the selected set [B x H, 1, T] (None from the twin, whose reader
+    makes its own). One ``attn/selected`` record a traced call."""
     if attention == "auto":
         attention = auto_attention(q, v)
         if blocks and not kernels.by_block_fits(q.shape[1], blocks):
@@ -726,12 +838,25 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     if impl is None:
         impl = auto_head_rotary(q, v, cos, attention)
     (b, seq, heads, d), d_v = q.shape, v.shape[3]
+    if selected is not None:
+        assert window is None and not blocks and topk, (window, blocks, topk)
+        steptrace.record_counters("attn/selected", {
+            "topk": topk, "rows": b * seq, "heads": heads,
+            "pairs_selected": b * pairs_selected(seq, topk),
+            "pairs_causal": b * seq * (seq + 1) // 2,
+            "dead_tiles": 0, "kernel": int(impl != "jnp")})
     steptrace.record_counters("attention/head_rotary", {
         "tokens": seq, "heads": heads, "kv_heads": k.shape[2], "head_dim": d,
         "rotated": int(cos is not None), "kernel": int(impl != "jnp")})
     if impl == "jnp":
         prologue = functools.partial(rotary.head_rotary, cos=cos, sin=sin,
                                      eps=eps)
+        if selected is not None:
+            qr, kr = prologue(q, q_scale), prologue(k, k_scale)
+            heads_first = lambda t: t.transpose(0, 2, 1, 3).reshape(
+                -1, seq, t.shape[3])
+            return (causal_self_attention(qr, kr, v, selected=selected),
+                    (heads_first(qr), heads_first(kr), None))
         # ``blocks`` only where there is one: a test's stand-in for the
         # twin's call takes the five arguments it always did
         return causal_self_attention(
@@ -740,6 +865,12 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     assert _prologue_fits(q, v, cos, attention), (q.shape, attention)
     _boundary(q, k, v, window, blocks)
     lanes = lambda t: t.reshape(b, seq, -1)
+    if selected is not None:
+        y, read = _normed_rotary_kernels_selected(
+            lanes(q), lanes(k), lanes(v), q_scale, k_scale, cos, sin,
+            selected, heads=heads, eps=eps, block_q=block_q, block_k=block_k,
+            interpret=impl == "pallas_interpret", topk=topk)
+        return y.reshape(b, seq, heads, d_v), read
     return _normed_rotary_kernels(
         lanes(q), lanes(k), lanes(v), q_scale, k_scale, cos, sin,
         heads=heads, eps=eps, window=window,

@@ -469,11 +469,27 @@ def _fold_tile(s, carry, masked, values_t):
     return m_new, l, acc + _dot(vt, p.astype(vt.dtype), _NN)
 
 
+def _selected(s, sel_ref, rows, cols):
+    """Scores ``s`` (block_k, block_q) with every pair that a selection's
+    mask leaves out at NEG_INF. The mask holds the causal edge too."""
+    return jnp.where(sel_ref[rows, cols].astype(jnp.int32) != 0, s, NEG_INF)
+
+
+def _with_selection(kernel, at: int):
+    """``kernel`` with the operand at place ``at`` of its refs, a
+    selection's mask, handed over as ``sel_ref``: the kernels' own operands
+    keep their places and their order, which the benchmark's readers count
+    on."""
+    def selected(*refs):
+        return kernel(*refs[:at], *refs[at + 1:], sel_ref=refs[at])
+    return selected
+
+
 def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
                 rows_out: bool = False, blocks: Optional[int] = None,
-                span: Optional[int] = None):
+                span: Optional[int] = None, sel_ref=None):
     """``o_ref`` is this block of queries' O^T (d_v, resident queries), or
     with ``rows_out`` its O (resident queries, d_v): a head's lanes of a
     model's own [B, T, H x d_v] array (``results_in_model_arrays``), for
@@ -482,7 +498,9 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     The grid's last axis is a row's blocks of keys, all ``nk`` of them, or
     with ``span`` (``_live_span``) the last ``span`` up to the diagonal's,
     which is then every row's last step; a step before a head's first
-    block of keys does nothing."""
+    block of keys does nothing. ``sel_ref`` (``_with_selection``): this
+    block's (resident keys, resident queries) of a selection's mask, int8,
+    under which every live tile is masked by what it says."""
     qi, step_k = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -515,6 +533,8 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 rows = _tile(c, block_k, n_k)
                 s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
                            edge=edge)
+                if sel_ref is not None:
+                    s, masked = _selected(s, sel_ref, rows, cols), True
                 return _fold_tile(s, carry, masked, lambda: vt_ref[:, rows])
 
             m, l, acc = _walk(
@@ -550,7 +570,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 offset: int, static: bool, kinds, window: Optional[int],
                 nq: int, group: int, o_rows: bool = False,
-                blocks: Optional[int] = None, span: Optional[int] = None):
+                blocks: Optional[int] = None, span: Optional[int] = None,
+                sel_ref=None):
     """dQ^T of this (resident keys, resident queries) pair, and dK, dV
     accumulated over the queries: s and p are recomputed once for all
     three. The last grid axis walks the ``nq`` blocks of queries of each of
@@ -589,7 +610,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
     lanes of the model's [B, T, H x d_v] as ``do_ref`` is of its cotangent,
     from which a row of tiles makes its ``delta`` here; ``dk_ref`` and
     ``dv_ref`` are then a key-value head's lanes of such arrays too, which
-    changes nothing in here."""
+    changes nothing in here. ``sel_ref``: as ``_fwd_kernel``'s."""
     ki, step_q = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
     steps = nq if span is None else span    # a query head's
@@ -680,6 +701,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 rows = _tile(c, block_k, n_k)
                 s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
                            edge=edge)
+                if sel_ref is not None:
+                    s = _selected(s, sel_ref, rows, cols)
                 p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
                 dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
                 dp = _dot(v_ref[rows, :], do, _NT)
@@ -915,18 +938,21 @@ def _block_sizes(q_len: int, k_len: int, block_q: Optional[int],
     return (block_q, block_k, *residents(_MAX_RESIDENT))
 
 
-def _compiler_params(interpret: bool, width: int, keys_add: bool = False):
+def _compiler_params(interpret: bool, width: int, keys_add: bool = False,
+                     selected: bool = False):
     """``width`` is the widest head dimension of the call: up to 128 lanes
     the residents fit the compiler's own 16 MiB of VMEM; past it (keys of
     192 are laid out as 256 lanes) the backward's residents take 16.5 MiB
     at 2048 queries and keys, so the kernel asks for 32 of the chip's 128.
     With ``keys_add`` (the backward over several blocks of keys) the steps
     along the grid's second axis add to one sum in HBM, one after another:
-    that axis is no core's to split."""
+    that axis is no core's to split. Under a selection (``selected``) a
+    step also holds a (resident keys, resident queries) block of the mask,
+    4 MiB of int8 at 2048 each way and twice that in flight: 48 MiB."""
     return compiler_params(
         interpret,
         ("parallel", "arbitrary" if keys_add else "parallel", "arbitrary"),
-        32 * 2**20 if width > 128 else None)
+        48 * 2**20 if selected else 32 * 2**20 if width > 128 else None)
 
 
 # The blocks of the other operand that the mask leaves a block anything of,
@@ -1018,11 +1044,15 @@ def _kinds_present(nq: int, nk: int, res_q: int, res_k: int, offset: int,
 
 
 def _kernel_name(base: str, window: Optional[int],
-                 blocks: Optional[int] = None) -> str:
+                 blocks: Optional[int] = None,
+                 topk: Optional[int] = None) -> str:
     """``flash_fwd`` / ``flash_bwd``, of a windowed call
-    ``flash_fwd_w<window>`` and of a block-diffusion call
-    ``flash_fwd_bd<blocks>``: the benchmark's readers find the kernels, and
-    a call's mask, by these names."""
+    ``flash_fwd_w<window>``, of a block-diffusion call
+    ``flash_fwd_bd<blocks>`` and of a call under a selection of ``topk``
+    keys a query ``flash_fwd_sel<topk>``: the benchmark's readers find the
+    kernels, and a call's mask, by these names."""
+    if topk:
+        return f"{base}_sel{topk}"
     if blocks:
         return f"{base}_bd{blocks}"
     return base if window is None else f"{base}_w{window}"
@@ -1091,7 +1121,8 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                   block_q: Optional[int], block_k: Optional[int],
                   interpret: bool, window: Optional[int] = None,
                   heads: Optional[int] = None,
-                  blocks: Optional[int] = None):
+                  blocks: Optional[int] = None, selected=None,
+                  topk: Optional[int] = None):
     """q: (B, S, D) with batch*heads folded into B; k: (B_kv, S, D) and v:
     (B_kv, S, Dv) with B a multiple of B_kv: query head ``i`` reads
     key-value head ``i // (B // B_kv)``, through the index maps.
@@ -1099,10 +1130,19 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     (``results_in_model_arrays``), of which B is a multiple, out is a
     model's own (B / heads, S, heads x Dv): the kernel writes head ``i %
     heads``'s lanes of it, a block of queries at a time. Given ``blocks``,
-    S is two streams under the block-diffusion mask (``_BY_BLOCK``)."""
+    S is two streams under the block-diffusion mask (``_BY_BLOCK``). Given
+    ``selected`` (with ``heads`` and ``causal``; a mask the step computed,
+    ``ops/sparse_index.py``: int8 [B / heads, S keys, S queries], 1 where
+    the query sees the key, nothing past the diagonal), every head of a
+    batch row reads that row's mask, a grid block's (resident keys,
+    resident queries) a step; ``topk`` names the kernel."""
     b, q_len, d = q.shape
     k_len, d_v = k.shape[1], v.shape[2]
     group = b // k.shape[0]
+    if selected is not None:
+        assert (causal and heads and window is None and not blocks
+                and selected.shape == (b // heads, k_len, q_len)), (
+                    selected.shape, q.shape, heads)
     if blocks:
         assert causal and window is None and q_len == k_len, (q.shape, k.shape)
         block_q, block_k, res_q, n, kinds = _by_block_grid(
@@ -1162,6 +1202,12 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                                                     bi % heads))
         out_shape = jax.ShapeDtypeStruct((b // heads, q_len, heads * d_v),
                                          q.dtype)
+    masks, mask_specs = (), []
+    if selected is not None:
+        kernel, masks = _with_selection(kernel, 3), (selected,)
+        mask_specs = [pl.BlockSpec(
+            (None, res_k, res_q),
+            lambda bi, qi, ki: (bi // heads, kmap(qi, ki), qi))]
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, nq, span or nk),
@@ -1171,6 +1217,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
                          lambda bi, qi, ki: (kv_head(bi), kmap(qi, ki), 0)),
             pl.BlockSpec((None, d_v, res_k),
                          lambda bi, qi, ki: (kv_head(bi), 0, kmap(qi, ki))),
+            *mask_specs,
         ],
         out_specs=[
             out_spec,
@@ -1185,10 +1232,11 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
             pltpu.VMEM((1, res_q), jnp.float32),
             pltpu.VMEM((d_v, res_q), jnp.float32),
         ],
-        compiler_params=_compiler_params(interpret, max(d, d_v)),
+        compiler_params=_compiler_params(interpret, max(d, d_v),
+                                         selected=bool(masks)),
         interpret=interpret,
-        name=_kernel_name("flash_fwd", window, blocks),
-    )(q, k, jnp.swapaxes(v, 1, 2))
+        name=_kernel_name("flash_fwd", window, blocks, topk),
+    )(q, k, jnp.swapaxes(v, 1, 2), *masks)
     return (jnp.swapaxes(out, 1, 2) if heads is None else out), lse
 
 
@@ -1198,7 +1246,8 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                              window: Optional[int] = None,
                              heads: Optional[int] = None,
                              dq_turned: bool = True,
-                             blocks: Optional[int] = None):
+                             blocks: Optional[int] = None, selected=None,
+                             topk: Optional[int] = None):
     """-> (dq, dk, dv) of ``_flash_pallas``'s call, shaped as q, k and v
     [B x H, T, d] from ``do`` shaped as its output; given ``heads``, ``do``
     is the cotangent of the model's own [B, T, heads x d_v] output, read a
@@ -1207,7 +1256,8 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
     likewise, [B, T, key-value heads x width]; dq is [B x H, T, d] either
     way, or without ``dq_turned`` dQ^T as the kernel leaves it, [B x H, d,
     T] (float32 where a head has several blocks of keys), for a caller
-    whose own kernel reads it next (``ops/rotary.py``)."""
+    whose own kernel reads it next (``ops/rotary.py``). ``selected``,
+    ``topk``: ``_flash_pallas``'s."""
     b, q_len, d = q.shape
     b_kv, k_len, d_v = k.shape[0], k.shape[1], v.shape[2]
     group = b // b_kv
@@ -1303,20 +1353,31 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                 pltpu.VMEM(pieces, jnp.float32),
                 pltpu.SemaphoreType.DMA((2, pieces[0])),
                 pltpu.SMEM((1,), jnp.int32)]
+    kernel = functools.partial(
+        _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, offset=offset, static=nq == nk == 1,
+        window=window, nq=nq, group=group, o_rows=heads is not None,
+        blocks=blocks, span=span,
+        kinds=kinds if blocks else _kinds_present(
+            nq, nk, res_q, res_k, offset, causal, True, window,
+            (b, b_kv)))
+    masks, mask_specs = (), []
+    if selected is not None:
+        assert heads and selected.shape == (b // heads, k_len, q_len), (
+            selected.shape, q.shape, heads)
+        kernel, masks = _with_selection(kernel, 7), (selected,)
+        mask_specs = [pl.BlockSpec(
+            (None, res_k, res_q),
+            lambda bi, ki, step: (bi // (heads // group), ki,
+                                  qmap(ki, block(step))))]
     dq_t, dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, offset=offset, static=nq == nk == 1,
-            window=window, nq=nq, group=group, o_rows=heads is not None,
-            blocks=blocks, span=span,
-            kinds=kinds if blocks else _kinds_present(
-                nq, nk, res_q, res_k, offset, causal, True, window,
-                (b, b_kv))),
+        kernel,
         grid=(b_kv, nk, group * steps),
         in_specs=[
             qspec, kspec, vspec,
             pl.BlockSpec((None, d, res_k), lambda bi, ki, step: (bi, 0, ki)),
             dospec, rowspec, rowspec if heads is None else dospec,
+            *mask_specs,
         ],
         out_specs=[dqspec, dkspec, dvspec],
         out_shape=[dq_shape, jax.ShapeDtypeStruct(dk_dims, k.dtype),
@@ -1327,10 +1388,11 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             *sums,
         ],
         compiler_params=_compiler_params(interpret, max(d, d_v),
-                                         keys_add=nk > 1),
+                                         keys_add=nk > 1,
+                                         selected=bool(masks)),
         interpret=interpret,
-        name=_kernel_name("flash_bwd", window, blocks),
-    )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta)
+        name=_kernel_name("flash_bwd", window, blocks, topk),
+    )(q, k, v, jnp.swapaxes(k, 1, 2), do, lse, delta, *masks)
     if not dq_turned:
         return dq_t, dk, dv
     return jnp.swapaxes(dq_t.astype(q.dtype), 1, 2), dk, dv

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from ray_tpu.ops import attention, delta, ssm
+from ray_tpu.ops import attention, delta, sparse_index, ssm
 
 
 def remat_policy():
@@ -21,11 +21,14 @@ def remat_policy():
     [B, T, H, d_v] array in the compute dtype and B x H x T float32; dense
     where the kernels write the model's arrays, else at a value width of 64
     a lane-padded [B x H, T, 64] of nearly twice those bytes: the comment
-    above ``attention.REMAT_NAMES``), recompute everything else. The
+    above ``attention.REMAT_NAMES``), and the three gradients that the
+    indexer's KL kernel makes beside its loss (``sparse_index.REMAT_NAMES``:
+    float32, shaped as the index queries, keys and weights), recompute
+    everything else. The
     backward pass of such a block then reruns the projections and not the
     forward kernel. Where the block's attention is not the kernel (``xla``,
     the scan) no such name exists, nothing is kept and the program is the
     one without a policy."""
     return jax.checkpoint_policies.save_only_these_names(
         *attention.REMAT_NAMES, *ssm.SCAN_REMAT_NAMES, *delta.REMAT_NAMES,
-        *ssm.SSD_REMAT_NAMES)
+        *ssm.SSD_REMAT_NAMES, *sparse_index.REMAT_NAMES)

@@ -66,7 +66,10 @@ def fits(x, cos) -> bool:
     (a width of 64 is half a tile, two heads a tile: the twin's, as a wider
     head is until a chip has read it), the rotation is over all of the
     head's width or there is none (a partial rotation leaves lanes that only
-    the norm touches: the twin's), and one table serves every row."""
+    the norm touches: the twin's), and one table serves every row. What
+    the table's angles were made of is not asked: one whose frequency pairs
+    read three position rows (``llama.rope_table`` under ``mrope_section``)
+    fits while it is [1, T, 64], one layout for every row of the batch."""
     d = x.shape[-1]
     whole = cos is None or (cos.shape[-1] * 2 == d and cos.ndim == 3
                             and cos.shape[0] == 1)

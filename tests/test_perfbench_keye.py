@@ -1,0 +1,20 @@
+"""``perfbench/tests/test_keye_family.py``'s cases, run with tier-1 as
+``tests/test_perfbench_sdar.py`` runs the ``sdar`` family's: the ``keye``
+family's toy configuration rehearsed through ``run.py`` on the CPU, the
+cell's entries in BENCHMARK.json (by name, not by their place at a list's
+end), and the five readers the family brought on canned event texts and
+hand-made traces. The cases are the module's own functions, imported by
+path, so each counts here under its own name."""
+
+import importlib.util
+import os
+
+_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "perfbench", "tests", "test_keye_family.py")
+_spec = importlib.util.spec_from_file_location(
+    "perfbench_test_keye_family", _PATH)
+_module = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_module)
+
+globals().update({name: case for name, case in vars(_module).items()
+                  if name.startswith("test_")})

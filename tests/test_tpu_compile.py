@@ -122,6 +122,7 @@ _HELD_PROGRAMS = {
     "trinity-mini.step-16k": "69c63c6279bc5a07",   # PR 66
     "mellum2-12b-a2.5b.step-8k": "82ab99b9a5fbbc45",   # PR 66
     "sdar-30b-a3b-chat.step-bd-4k": "e6cb997ca8d2efbf",   # PR 65
+    "keye-vl-2.0-30b-a3b.step-16k-img": "4a44ee31d7602259",   # PR 67
 }
 
 
@@ -1692,6 +1693,108 @@ def test_block_diffusion_expert_step_fits_one_chip_at_two_4k_sequences(
         # no [2L, 2L] (nor [L, L]) a head: no mask, score or probability
         assert not any(a == b and a in (positions, seq)
                        for a, b in zip(dims, dims[1:])), dims
+        if 18992 in dims:
+            assert set(dims) <= {18992, 2048, batch, seq // 8, 1}, dims
+
+
+def test_selected_attention_expert_step_fits_one_chip_at_16384(
+        topo, no_compile_cache, on_tpu):
+    """The cut configuration of the cell ``keye-vl-2.0-30b-a3b.step-16k-img``
+    (published layers 0 to 5 at the published widths: 32 query heads on 4
+    of 128, in front of every layer an indexer of 16 index heads of 64 on
+    one index key that picks 2,048 keys a query, every feed-forward 16 of
+    128 softmax-routed experts of 768, an eighth of the vocabulary under an
+    untied head), its step at one sequence of 16,384 with recomputation, as
+    the benchmark's family builds it over a batch that carries the
+    layout's position ids and loss weights. The plan stays under the 14.5
+    GiB that ISSUE 67 set for keeping six layers (14.18 read: 7.37 of
+    arguments, 6.82 of temporaries). Every layer's attention is the flash
+    kernel pair under the selection, named ``flash_*_sel2048``, once
+    forward and once backward (``ops.remat.remat_policy`` keeps the output
+    and its log-sum-exp); the selection's kernel runs twice a layer (the
+    recomputed block makes the mask again), the KL's once (its forward
+    rule's gradients are kept). PR 63's prologue writes the flash kernels'
+    operands under a table whose pairs read three position rows. The one
+    [T, T] array is the mask, int8; the vocabulary's 18,992 rows stand only
+    beside the hidden size and the loss walk's positions."""
+    from ray_tpu._private import steptrace
+
+    cell = "keye-vl-2.0-30b-a3b.step-16k-img"
+    worker, model, traffic = _cut_cell(cell)
+    built = worker.load_family(ROOT, model).build(model, traffic, None)
+    one = SingleDeviceSharding(topo.devices[0])
+    params, opt_state = _with_sharding(
+        jax.eval_shape(built.make_state, jax.random.PRNGKey(0)), one)
+    batch, seq = traffic["batch"], traffic["seq"]
+    assert (batch, seq) == (1, 16384)
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one)
+    laid = _with_sharding(jax.eval_shape(lambda: built.extra), one)
+    steptrace.set_enabled(True)
+    steptrace.reset()
+    try:
+        lowered = _lower_held(cell, built.step, params, opt_state,
+                              {"input_ids": ids, "labels": ids, **laid})
+        counters = [e for e in steptrace.chrome_trace(
+            steptrace.merge_records(steptrace.snapshot())) if e["ph"] == "C"]
+    finally:
+        steptrace.set_enabled(False)
+    by_name = collections.defaultdict(list)
+    for e in counters:
+        by_name[e["name"]].append(e["args"])
+    assert set(by_name) == {
+        "attn/grid_blocks", "rope/table", "model/layer_kinds",
+        "attention/boundary", "attention/head_rotary", "attn/selected",
+        "index/scores", "index/threshold", "index/loss", "moe/row_buffers",
+        "moe/to_tokens", "moe/grouped_matmul"}
+    assert by_name["model/layer_kinds"][-1] == {
+        "sparse": 6, "expert": 6, "layers": 6, "published_layers": 48,
+        "topk": 2048}
+    assert by_name["rope/table"][-1]["kind"] == "sections"
+    causal = seq * (seq + 1) // 2
+    assert by_name["attn/selected"][-1] == {
+        "topk": 2048, "rows": seq, "heads": 32,
+        "pairs_selected": 31_458_304, "pairs_causal": causal,
+        "dead_tiles": 0, "kernel": 1}
+    assert by_name["index/scores"][-1] == {
+        "heads": 16, "width": 64, "rows": seq, "pairs": causal, "topk": 2048,
+        "kernel": 1, "flops_needed": 2 * causal * 16 * 64,
+        "bytes_needed": seq * (17 * 64 * 2 + 64), "operand_bits": 16}
+    assert by_name["index/threshold"][-1]["passes"] == 32
+    assert by_name["index/loss"][-1]["kernel"] == 1
+    for e in by_name["attn/grid_blocks"]:
+        assert (e["whole"], e["diagonal"], e["dead"]) == (28, 8, 28)
+    compiled = lowered.compile()
+    planned = _device_bytes(compiled)
+    n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+    assert n_params == 659_190_784
+    assert 3 * 4 * n_params < planned < 14.5 * 2**30
+    assert planned > 4 * 2**30     # a quarter of the chip's 16 and more
+    print(f"planned {planned / 2**30:.2f} GiB")
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"^\s*%?(flash_(?:fwd|bwd)(?:_(?:w|bd|sel)\d+)?|index_select_top\d+"
+        r"|index_kl)[\w.\-]* = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M))
+    assert calls == {"flash_fwd_sel2048": 6, "flash_bwd_sel2048": 6,
+                     "index_select_top2048": 12, "index_kl": 6}
+    assert f"bf16[32,{seq},128]" in text and f"bf16[4,{seq},128]" in text
+    assert f"s8[1,{seq},{seq}]" in text
+    _dq_census(text, 32, 128, seq)
+    _head_rotary_census(text, counters, batch * seq * 32 * 128, layers=6,
+                        rotated=6)
+    _to_tokens_census(text, counters, seq, model["num_experts_per_tok"],
+                      model["hidden_size"], model["num_experts"], calls=2 * 6)
+    _grouped_matmul_census(
+        text, counters, seq * model["num_experts_per_tok"],
+        model["hidden_size"], 2 * model["moe_intermediate_size"],
+        model["moe_intermediate_size"], model["num_experts"], layers=6)
+    shapes = set(re.findall(r"\b([a-z]\w*)\[([\d,]+)\]", text))
+    for kind, dims in ((k, tuple(int(n) for n in s.split(",")))
+                       for k, s in shapes):
+        # the one [T, T]: the mask, a byte a pair; no score, no probability
+        if any(a == b == seq for a, b in zip(dims, dims[1:])):
+            assert kind in ("s8", "pred") and dims[-2:] == (seq, seq), (
+                kind, dims)
         if 18992 in dims:
             assert set(dims) <= {18992, 2048, batch, seq // 8, 1}, dims
 
