@@ -23,10 +23,12 @@ trunk reads ids, and what it keeps of images is their positions.
   j] . kI[s])``, no rotation; each query sees its ``topk`` highest-scored
   earlier keys (all of them while it has no more), in sequence order
   whatever the position ids say, all heads the same set: exactly, the mask
-  written once a layer (``sparse_index.select``) and read by the flash
-  kernels' tiles (``ops.attention.normed_rotary_self_attention(...,
-  selected=)``). ``F`` is, in every layer, ``models/mla_moe.py``'s
-  routed-expert layer (softmax scores, ``num_experts_per_tok`` a token,
+  made once a layer as bits, a bit a pair (``sparse_index.select``), read
+  by the flash kernels' tiles
+  (``ops.attention.normed_rotary_self_attention(..., selected=)``) and kept
+  for a recomputed block's backward (``remat_policy``). ``F`` is, in every
+  layer, ``models/mla_moe.py``'s routed-expert layer (softmax scores,
+  ``num_experts_per_tok`` a token,
   normalised, no shared expert, the slice ``expert_shard`` held here).
 - The loss is ``L_lm + L_I``. ``L_lm``: next-token cross-entropy through
   the final norm and the untied head over the targets ``loss_weights``

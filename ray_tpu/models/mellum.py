@@ -209,8 +209,11 @@ class Attention(nn.Module):
                      kernel_init=_init(c), name="index_w")(
             xd.astype(jnp.float32)) * (sp.heads * sp.width) ** -0.5
         chosen = sparse_index.select(q_idx, k_idx, w, sp.topk)
-        # for a side run that asks for it (``mutable=["intermediates"]``)
-        self.sow("intermediates", "selected", chosen.mask)
+        # for a side run that asks for it (``mutable=["intermediates"]``):
+        # the set as a dense int8 [B, T keys, T queries], which no step makes
+        if self.is_mutable_collection("intermediates"):
+            self.sow("intermediates", "selected",
+                     sparse_index.unpack(chosen.mask))
         y, (qf, kf, lse) = normed_rotary_self_attention(
             q, k, v, scale("q_norm"), scale("k_norm"), cos, sin,
             eps=c.rms_norm_eps, attention=c.attention, selected=chosen.mask,

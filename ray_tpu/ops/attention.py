@@ -30,10 +30,13 @@ Four kinds of mask: causal, a causal window, block diffusion's over two
 streams (``blocks``), each a function of the two positions alone that the
 kernels work out from a tile's place, and since PR 67 a SELECTION
 (``selected``): a mask the step computed (``ops/sparse_index.py``: the keys
-a learned indexer picks for each query), an int8 operand [B, T keys, T
-queries] that every head of a batch row reads a tile at a time.
-``attention_reference`` and ``normed_rotary_self_attention`` take it; it
-excludes ``window`` and ``blocks``.
+a learned indexer picks for each query), an operand that every head of a
+batch row reads a tile at a time: a bit a pair, int32 words [B, T / 32, T
+queries] (``sparse_index.pack``), to ``normed_rotary_self_attention``,
+whose kernels expand a tile's words in registers; the dense int8 [B, T
+keys, T queries] that ``sparse_index.unpack`` makes of them to
+``attention_reference`` and ``causal_self_attention``. It excludes
+``window`` and ``blocks``.
 """
 
 from __future__ import annotations
@@ -48,7 +51,7 @@ from jax import ad_checkpoint, lax
 
 from ray_tpu._private import steptrace
 from ray_tpu.ops import flash_kernels as kernels, rotary
-from ray_tpu.ops.sparse_index import pairs_selected
+from ray_tpu.ops.sparse_index import pairs_selected, unpack
 from ray_tpu.ops.flash_kernels import NEG_INF
 from ray_tpu.ops.mosaic import per_batch_shard, takes_kernels
 
@@ -685,9 +688,9 @@ _normed_rotary_flash.defvjp(_normed_rotary_flash_fwd,
 def _normed_rotary_flash_selected(q, k, v, q_scale, k_scale, cos, sin, mask,
                                   heads, eps, sm_scale, block_q, block_k,
                                   interpret, topk):
-    """``_normed_rotary_flash`` under ``mask`` (int8 [B, T keys, T
-    queries]) -> (out [B, T, heads x 128], (qf [B x H, T, 128], kf [B x G,
-    T, 128], lse [B x H, 1, T])); the three are constants to whoever reads
+    """``_normed_rotary_flash`` under ``mask`` (int32 [B, T / 32, T
+    queries], a bit a pair) -> (out [B, T, heads x 128], (qf [B x H, T,
+    128], kf [B x G, T, 128], lse [B x H, 1, T])); the three are constants to whoever reads
     them: their cotangents are dropped."""
     return _normed_rotary_flash_selected_fwd(
         q, k, v, q_scale, k_scale, cos, sin, mask, heads, eps, sm_scale,
@@ -823,9 +826,10 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
     ``causal_self_attention``'s: the T positions are two streams under the
     block-diffusion mask, and the table holds a stream's positions twice.
 
-    Given ``selected`` (int8 [B, T keys, T queries], a mask the step
-    computed, of ``topk`` keys a query: ``ops/sparse_index.py``; no window,
-    no blocks) a query sees the keys it says, and the call returns (y, (qf
+    Given ``selected`` (int32 [B, T / 32, T queries], the bits of a mask
+    the step computed, of ``topk`` keys a query: ``sparse_index.select``'s;
+    no window, no blocks) a query sees the keys it says (the twin through
+    ``sparse_index.unpack``), and the call returns (y, (qf
     [B x H, T, d], kf [B x H_kv, T, d], lse)): beside the output, what the
     indexer's loss reads of the main attention, the normed and rotated
     queries and keys and, from the kernels, the log-sum-exp of the scores
@@ -855,7 +859,8 @@ def normed_rotary_self_attention(q, k, v, q_scale, k_scale, cos, sin, *,
             qr, kr = prologue(q, q_scale), prologue(k, k_scale)
             heads_first = lambda t: t.transpose(0, 2, 1, 3).reshape(
                 -1, seq, t.shape[3])
-            return (causal_self_attention(qr, kr, v, selected=selected),
+            return (causal_self_attention(qr, kr, v,
+                                          selected=unpack(selected)),
                     (heads_first(qr), heads_first(kr), None))
         # ``blocks`` only where there is one: a test's stand-in for the
         # twin's call takes the five arguments it always did

@@ -81,6 +81,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu._private import steptrace
+from ray_tpu.ops import sparse_index
 from ray_tpu.ops.mosaic import compiler_params
 
 NEG_INF = -1e30
@@ -469,10 +470,15 @@ def _fold_tile(s, carry, masked, values_t):
     return m_new, l, acc + _dot(vt, p.astype(vt.dtype), _NN)
 
 
-def _selected(s, sel_ref, rows, cols):
-    """Scores ``s`` (block_k, block_q) with every pair that a selection's
-    mask leaves out at NEG_INF. The mask holds the causal edge too."""
-    return jnp.where(sel_ref[rows, cols].astype(jnp.int32) != 0, s, NEG_INF)
+def _selected(s, sel_ref, c, cols):
+    """Scores ``s`` (block_k, block_q) of key tile ``c`` with every pair
+    that a selection's mask leaves out at NEG_INF. The mask holds the causal
+    edge too. ``sel_ref`` holds the pairs as bits, 32 keys a word
+    (``sparse_index.pack``): the tile's block_k / 32 rows of words are
+    expanded in registers, an ``and`` a register of scores."""
+    words = s.shape[0] // sparse_index.KEYS_A_WORD
+    rows = _tile(c, words, sel_ref.shape[0] // words)
+    return jnp.where(sparse_index.bits(sel_ref[rows, cols]) != 0, s, NEG_INF)
 
 
 def _with_selection(kernel, at: int):
@@ -499,8 +505,9 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     with ``span`` (``_live_span``) the last ``span`` up to the diagonal's,
     which is then every row's last step; a step before a head's first
     block of keys does nothing. ``sel_ref`` (``_with_selection``): this
-    block's (resident keys, resident queries) of a selection's mask, int8,
-    under which every live tile is masked by what it says."""
+    block's (resident keys / 32, resident queries) of a selection's mask,
+    words of 32 keys' bits, under which every live tile is masked by what
+    they say."""
     qi, step_k = pl.program_id(1), pl.program_id(2)
     n_steps = pl.num_programs(2)
     res_q, res_k = q_ref.shape[0], k_ref.shape[0]
@@ -534,7 +541,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
                            edge=edge)
                 if sel_ref is not None:
-                    s, masked = _selected(s, sel_ref, rows, cols), True
+                    s, masked = _selected(s, sel_ref, c, cols), True
                 return _fold_tile(s, carry, masked, lambda: vt_ref[:, rows])
 
             m, l, acc = _walk(
@@ -702,7 +709,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kt_ref, do_ref, lse_ref, delta_ref,
                 s = scores(k_ref[rows, :], q, c, masked=masked, rel=rel,
                            edge=edge)
                 if sel_ref is not None:
-                    s = _selected(s, sel_ref, rows, cols)
+                    s = _selected(s, sel_ref, c, cols)
                 p = jnp.exp(s - lse)  # normalized; lse=+inf queries -> 0
                 dv_scr[rows, :] += _dot(p.astype(do.dtype), do, _NN)
                 dp = _dot(v_ref[rows, :], do, _NT)
@@ -947,12 +954,13 @@ def _compiler_params(interpret: bool, width: int, keys_add: bool = False,
     With ``keys_add`` (the backward over several blocks of keys) the steps
     along the grid's second axis add to one sum in HBM, one after another:
     that axis is no core's to split. Under a selection (``selected``) a
-    step also holds a (resident keys, resident queries) block of the mask,
-    4 MiB of int8 at 2048 each way and twice that in flight: 48 MiB."""
+    step also holds a (resident keys / 32, resident queries) block of the
+    mask's words, 512 KiB at 2048 each way and twice that in flight, and a
+    tile's expanded bits beside its scores: 32 MiB."""
     return compiler_params(
         interpret,
         ("parallel", "arbitrary" if keys_add else "parallel", "arbitrary"),
-        48 * 2**20 if selected else 32 * 2**20 if width > 128 else None)
+        32 * 2**20 if selected or width > 128 else None)
 
 
 # The blocks of the other operand that the mask leaves a block anything of,
@@ -1132,16 +1140,18 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     heads``'s lanes of it, a block of queries at a time. Given ``blocks``,
     S is two streams under the block-diffusion mask (``_BY_BLOCK``). Given
     ``selected`` (with ``heads`` and ``causal``; a mask the step computed,
-    ``ops/sparse_index.py``: int8 [B / heads, S keys, S queries], 1 where
-    the query sees the key, nothing past the diagonal), every head of a
-    batch row reads that row's mask, a grid block's (resident keys,
-    resident queries) a step; ``topk`` names the kernel."""
+    ``ops/sparse_index.py``: int32 [B / heads, S / 32, S queries], a bit a
+    pair, set where the query sees the key, nothing past the diagonal),
+    every head of a batch row reads that row's mask, a grid block's
+    (resident keys / 32, resident queries) words a step; ``topk`` names the
+    kernel."""
     b, q_len, d = q.shape
     k_len, d_v = k.shape[1], v.shape[2]
     group = b // k.shape[0]
     if selected is not None:
         assert (causal and heads and window is None and not blocks
-                and selected.shape == (b // heads, k_len, q_len)), (
+                and selected.shape == (
+                    b // heads, k_len // sparse_index.KEYS_A_WORD, q_len)), (
                     selected.shape, q.shape, heads)
     if blocks:
         assert causal and window is None and q_len == k_len, (q.shape, k.shape)
@@ -1206,7 +1216,7 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
     if selected is not None:
         kernel, masks = _with_selection(kernel, 3), (selected,)
         mask_specs = [pl.BlockSpec(
-            (None, res_k, res_q),
+            (None, res_k // sparse_index.KEYS_A_WORD, res_q),
             lambda bi, qi, ki: (bi // heads, kmap(qi, ki), qi))]
     out, lse = pl.pallas_call(
         kernel,
@@ -1363,11 +1373,12 @@ def _flash_pallas_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
             (b, b_kv)))
     masks, mask_specs = (), []
     if selected is not None:
-        assert heads and selected.shape == (b // heads, k_len, q_len), (
-            selected.shape, q.shape, heads)
+        assert heads and selected.shape == (
+            b // heads, k_len // sparse_index.KEYS_A_WORD, q_len), (
+                selected.shape, q.shape, heads)
         kernel, masks = _with_selection(kernel, 7), (selected,)
         mask_specs = [pl.BlockSpec(
-            (None, res_k, res_q),
+            (None, res_k // sparse_index.KEYS_A_WORD, res_q),
             lambda bi, ki, step: (bi // (heads // group), ki,
                                   qmap(ki, block(step))))]
     dq_t, dk, dv = pl.pallas_call(

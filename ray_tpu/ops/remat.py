@@ -21,14 +21,16 @@ def remat_policy():
     [B, T, H, d_v] array in the compute dtype and B x H x T float32; dense
     where the kernels write the model's arrays, else at a value width of 64
     a lane-padded [B x H, T, 64] of nearly twice those bytes: the comment
-    above ``attention.REMAT_NAMES``), and the three gradients that the
-    indexer's KL kernel makes beside its loss (``sparse_index.REMAT_NAMES``:
-    float32, shaped as the index queries, keys and weights), recompute
-    everything else. The
-    backward pass of such a block then reruns the projections and not the
-    forward kernel. Where the block's attention is not the kernel (``xla``,
-    the scan) no such name exists, nothing is kept and the program is the
-    one without a policy."""
+    above ``attention.REMAT_NAMES``), and of a learned selection
+    (``sparse_index.REMAT_NAMES``) the three gradients that the indexer's KL
+    kernel makes beside its loss (float32, shaped as the index queries, keys
+    and weights) and the selection's mask as ``select`` packed it (a bit a
+    pair: int32 [B, T / 32, T], 32 MiB a sequence of 16,384, where a byte a
+    pair was 256), recompute everything else. The backward pass of such a
+    block then reruns the projections and not the forward kernel, and not
+    the selection either: the flash backward reads the kept mask. Where the
+    block's attention is not the kernel (``xla``, the scan) no such name
+    exists, nothing is kept and the program is the one without a policy."""
     return jax.checkpoint_policies.save_only_these_names(
         *attention.REMAT_NAMES, *ssm.SCAN_REMAT_NAMES, *delta.REMAT_NAMES,
         *ssm.SSD_REMAT_NAMES, *sparse_index.REMAT_NAMES)

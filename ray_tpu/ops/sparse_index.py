@@ -10,16 +10,25 @@ T, J] float32:
     I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s])
 
 - ``select``: ``tau[t]``, the ``topk``-th largest of ``I[t, :t + 1]`` (``-inf``
-  while the query has fewer than ``topk`` keys), and the set as a MASK,
-  int8 [B, T keys, T queries], 1 where ``s <= t`` and ``I[t, s] >= tau[t]``:
-  keys major, queries along the lanes, which is how the flash kernels hold a
-  tile of scores (``ops/flash_kernels.py:_selected``). Exact: a bisection
-  over the float32 scores' own bits, 32 counts a row, no approximation; keys
-  that tie with the ``topk``-th are all kept, as ``I >= lax.top_k(...)[-1]``
-  keeps them. The mask is written ONCE a layer and every head's tiles read
-  it, forward and backward: membership is decided by one piece of
-  arithmetic, and no score is made a second time in another tiling to be
-  held against ``tau``.
+  while the query has fewer than ``topk`` keys), and the set as a MASK of
+  BITS, a bit a pair, set where ``s <= t`` and ``I[t, s] >= tau[t]``: int32
+  words [B, T / 32, T queries], keys packed along the key axis, queries
+  along the lanes, which is how the flash kernels hold a tile of scores
+  (``ops/flash_kernels.py:_selected``). A chunk of 256 keys is 8 rows of
+  words, the sublanes of one register: bit ``j`` of row ``8 c + i`` is key
+  ``256 c + 8 j + i``, so that a reader has the 8 keys of an (8, 128)
+  register of scores by ONE ``and`` of the chunk's own register with a
+  constant, no shift and no move along the sublanes (``pack``, ``bits``;
+  ``unpack`` gives the dense int8 [B, T keys, T queries] back to the twins,
+  the dense reference and a side run that wants to look). Exact: a
+  bisection over the float32 scores' own bits, 32 counts a row, no
+  approximation; keys that tie with the ``topk``-th are all kept, as ``I >=
+  lax.top_k(...)[-1]`` keeps them. The mask is made ONCE a layer and every
+  head's tiles read it, forward and backward, a recomputed block's too (32
+  MiB a sequence of 16,384, which ``remat_policy`` keeps: ``REMAT_NAMES``):
+  membership is decided by one piece of arithmetic, and no score is made a
+  second time, in another tiling or another pass, to be held against
+  ``tau``.
 - ``index_kl``: ``sum_t KL(p[t, S_t] || softmax(I[t, S_t]))`` with ``p`` the
   mean over the query heads of the main attention's softmax over the
   selected set, taken as a constant: the gradient reaches ``q_idx``,
@@ -34,13 +43,16 @@ The kernels. ``select``: a grid step holds 128 queries along the lanes and
 makes their scores against every key up to their own, 512 keys at a time,
 16 small matmuls a tile, into a [T, 128] scratch of the scores' bits as
 sortable integers; 32 passes over the scratch find each query's threshold
-bit by bit; a last pass writes the mask's block and the set's log-sum-exp.
+bit by bit; a last pass packs the mask's block, 512 keys into 16 rows of
+words, and makes the set's log-sum-exp.
 ``index_kl``: a grid step holds a tile of 512 keys by 512 queries: every
 query head's scores against its keys again (what the flash forward made and
 could not keep), ``exp(s - lse)`` summed over the heads, the index scores
 again, the tile's part of the KL and of ``dI``, and from ``dI`` the tile's
 part of the three gradients; its forward rule keeps the gradients
-(``REMAT_NAMES``), so a recomputed block does not run it again.
+(``REMAT_NAMES``), so a recomputed block does not run it again. Neither
+does it run ``select`` again: the flash backward finds the mask among the
+kept values, and ``tau`` and the set's log-sum-exp feed the KL alone.
 """
 
 from __future__ import annotations
@@ -50,6 +62,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import ad_checkpoint, lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -68,16 +81,26 @@ _KL_TILE = 512
 _TWIN_ROWS = 256
 # the key of a pair past the diagonal: under every float's, -inf's too
 _NO_PAIR = jnp.iinfo(jnp.int32).min
+# The mask's words: 32 keys each, a chunk of keys the 8 rows of words that
+# one register holds along its sublanes (this file's docstring)
+KEYS_A_WORD, _CHUNK_ROWS = 32, 8
+_CHUNK = KEYS_A_WORD * _CHUNK_ROWS
+_BIT = tuple(np.uint32(1 << j).astype(np.int32)
+             for j in range(KEYS_A_WORD))
 # What recomputation keeps (``ops.remat.remat_policy``): the gradients that
-# ``index_kl``'s forward rule made beside the loss, so that a recomputed
-# block does not run the kernel a second time for them
-REMAT_NAMES = ("index_kl_dq", "index_kl_dk", "index_kl_dw")
+# ``index_kl``'s forward rule made beside the loss and the mask that
+# ``select`` packed, so that a recomputed block runs neither kernel a second
+# time
+_KL_NAMES = ("index_kl_dq", "index_kl_dk", "index_kl_dw")
+_MASK_NAME = "index_mask"
+REMAT_NAMES = (*_KL_NAMES, _MASK_NAME)
 
 
 class Selection(NamedTuple):
-    """What ``select`` found. ``mask``: int8 [B, T keys, T queries];
-    ``tau``: float32 [B, T], a query's threshold; ``lse``: float32 [B, T],
-    the log-sum-exp of a query's selected index scores."""
+    """What ``select`` found. ``mask``: int32 [B, T / 32 (whole chunks of
+    256 keys), T queries], a bit a pair (``pack``); ``tau``: float32 [B, T],
+    a query's threshold; ``lse``: float32 [B, T], the log-sum-exp of a
+    query's selected index scores."""
     mask: jax.Array
     tau: jax.Array
     lse: jax.Array
@@ -115,6 +138,50 @@ def precision_of(dtype):
     precision by the type it hands over."""
     return lax.Precision.HIGHEST if dtype == _F32 else None
 
+
+# ----------------------------------------------------------------------
+# the mask's bits
+# ----------------------------------------------------------------------
+
+def pack(seen):
+    """Membership ``seen`` [..., keys, queries] (bool; ``keys`` whole
+    chunks of 256) as words, int32 [..., keys / 32, queries]: bit ``j`` of
+    row ``8 c + i`` is key ``256 c + 8 j + i``. Traced in the kernel and in
+    the twin alike."""
+    *lead, keys, queries = seen.shape
+    assert keys % _CHUNK == 0, seen.shape
+    by_bit = seen.astype(jnp.int32).reshape(
+        *lead, keys // _CHUNK, KEYS_A_WORD, _CHUNK_ROWS, queries)
+    place = lax.broadcasted_iota(jnp.int32, by_bit.shape, len(lead) + 1)
+    # distinct bits: the sum is their union, the sign bit's wrap included
+    return (by_bit << place).sum(axis=-3).reshape(
+        *lead, keys // KEYS_A_WORD, queries)
+
+
+def bits(words):
+    """``pack``'s words [rows, queries] (whole chunks of 8 rows) as int32
+    [32 x rows, queries], nonzero where the pair's bit is set: a chunk's
+    rows ``and`` one constant for each 8 keys, so a register of the result
+    is one operation on a register that is already there. For a kernel's
+    tile; ``unpack`` is the arrays'."""
+    rows = words.shape[0]
+    assert rows % _CHUNK_ROWS == 0, words.shape
+    return jnp.concatenate([
+        words[at:at + _CHUNK_ROWS] & bit
+        for at in range(0, rows, _CHUNK_ROWS) for bit in _BIT], axis=0)
+
+
+def unpack(words):
+    """``pack``'s words [B, rows, T queries] as the dense mask, int8 [B, T
+    keys, T queries], 1 where the query sees the key: for the twins, the
+    dense reference and whoever wants to look at a set. 8 times the bytes:
+    nothing on a step's path at a real length reads it."""
+    b, rows, seq = words.shape
+    by_bit = lax.shift_right_logical(
+        words.reshape(b, rows // _CHUNK_ROWS, 1, _CHUNK_ROWS, seq),
+        jnp.arange(KEYS_A_WORD, dtype=jnp.int32).reshape(1, 1, -1, 1, 1)) & 1
+    return by_bit.reshape(b, rows * KEYS_A_WORD, seq)[:, :seq].astype(
+        jnp.int8)
 
 
 # ----------------------------------------------------------------------
@@ -164,13 +231,18 @@ def _select_twin(q_idx, k_idx, w, topk: int, rows: Optional[int]):
             tau = jnp.full(scores.shape[:2], -jnp.inf)
         mask = seen & (scores >= tau[..., None])
         lse = jax.nn.logsumexp(jnp.where(mask, scores, -jnp.inf), axis=-1)
-        return mask.astype(jnp.int8), tau, lse
+        # keys major, whole chunks of them: no key past the last is seen
+        words = pack(jnp.pad(mask.swapaxes(1, 2),
+                             ((0, 0), (0, -seq % _CHUNK), (0, 0))))
+        return words, tau, lse
 
-    mask, tau, lse = lax.map(one, (
+    words, tau, lse = lax.map(one, (
         _by_rows(q_idx, rows), _by_rows(w, rows),
         jnp.arange(0, seq, rows)))
     joined = lambda x: x.swapaxes(0, 1).reshape(b, seq, *x.shape[3:])
-    return joined(mask).swapaxes(1, 2), joined(tau), joined(lse)
+    # [blocks, B, words' rows, queries a block] -> queries along the last
+    return (words.transpose(1, 2, 0, 3).reshape(b, -1, seq), joined(tau),
+            joined(lse))
 
 
 def _heads_of(folded, b: int):
@@ -180,8 +252,9 @@ def _heads_of(folded, b: int):
 
 def _kl_twin(q_idx, k_idx, w, mask, qf, kf, sm_scale: float,
              rows: Optional[int]):
-    """The KL in plain ``jnp``, rows a block at a time against every key;
-    differentiable in ``q_idx``, ``k_idx`` and ``w`` by JAX's own rules."""
+    """The KL in plain ``jnp`` under the dense ``mask`` (``unpack``'s), rows
+    a block at a time against every key; differentiable in ``q_idx``,
+    ``k_idx`` and ``w`` by JAX's own rules."""
     b, seq = q_idx.shape[:2]
     rows = _row_blocks(seq, rows)
     qh, kh = _heads_of(qf, b), _heads_of(kf, b)
@@ -245,9 +318,10 @@ def _select_kernel(k_ref, qt_ref, wt_ref, mask_ref, tau_ref, lse_ref,
     """One block of queries (along the lanes) against every key up to the
     block's last: ``k_ref`` (T, W) the index keys, ``qt_ref`` (J, W,
     queries) the index queries turned, ``wt_ref`` (J, queries); ->
-    ``mask_ref`` (T, queries) int8, ``tau_ref`` and ``lse_ref`` (1,
-    queries). ``keys_scr`` (T, queries) int32 holds the scores' bits as
-    sortable integers, ``_NO_PAIR`` past the diagonal."""
+    ``mask_ref`` (T / 32, queries) int32, the set's words (``pack``),
+    ``tau_ref`` and ``lse_ref`` (1, queries). ``keys_scr`` (T, queries)
+    int32 holds the scores' bits as sortable integers, ``_NO_PAIR`` past the
+    diagonal."""
     qi = pl.program_id(1)
     n_q = qt_ref.shape[2]
     seq = k_ref.shape[0]
@@ -256,8 +330,8 @@ def _select_kernel(k_ref, qt_ref, wt_ref, mask_ref, tau_ref, lse_ref,
     n_live = (first + n_q - 1) // block_k + 1
     at = first + lax.broadcasted_iota(jnp.int32, (1, n_q), 1)
 
-    def rows_of(c):
-        return pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+    def rows_of(c, size=block_k):
+        return pl.ds(pl.multiple_of(c * size, size), size)
 
     def fill(c, _):
         rows = rows_of(c)
@@ -302,7 +376,7 @@ def _select_kernel(k_ref, qt_ref, wt_ref, mask_ref, tau_ref, lse_ref,
 
     def write(c, l):
         keys, seen = kept(c)
-        mask_ref[rows_of(c), :] = seen.astype(jnp.int8)
+        mask_ref[rows_of(c, block_k // KEYS_A_WORD), :] = pack(seen)
         return l + jnp.where(seen, jnp.exp(_unsortable(keys) - m), 0.0).sum(
             axis=0, keepdims=True)
 
@@ -310,7 +384,8 @@ def _select_kernel(k_ref, qt_ref, wt_ref, mask_ref, tau_ref, lse_ref,
     lse_ref[...] = m + jnp.log(l)
 
     def blank(c, _):
-        mask_ref[rows_of(c), :] = jnp.zeros((block_k, n_q), jnp.int8)
+        mask_ref[rows_of(c, block_k // KEYS_A_WORD), :] = jnp.zeros(
+            (block_k // KEYS_A_WORD, n_q), jnp.int32)
         return 0
 
     lax.fori_loop(n_live, seq // block_k, blank, 0)
@@ -322,7 +397,8 @@ def _select_pallas(q_idx, k_idx, w, topk: int, interpret: bool,
     b, seq, heads, width = q_idx.shape
     block_q = block_q or _SELECT_QUERIES
     block_k = min(block_k or _SELECT_KEYS, seq)
-    assert seq % block_q == 0 and seq % block_k == 0, (seq, block_q, block_k)
+    assert (seq % block_q == 0 and seq % block_k == 0
+            and block_k % _CHUNK == 0), (seq, block_q, block_k)
     qt = q_idx.transpose(0, 2, 3, 1)                      # [B, J, W, T]
     wt = w.astype(_F32).transpose(0, 2, 1)                # [B, J, T]
     row = pl.BlockSpec((None, 1, block_q), lambda bi, qi: (bi, 0, qi))
@@ -336,9 +412,11 @@ def _select_pallas(q_idx, k_idx, w, topk: int, interpret: bool,
             pl.BlockSpec((None, heads, block_q), lambda bi, qi: (bi, 0, qi)),
         ],
         out_specs=[
-            pl.BlockSpec((None, seq, block_q), lambda bi, qi: (bi, 0, qi)),
+            pl.BlockSpec((None, seq // KEYS_A_WORD, block_q),
+                         lambda bi, qi: (bi, 0, qi)),
             row, row],
-        out_shape=[jax.ShapeDtypeStruct((b, seq, seq), jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct((b, seq // KEYS_A_WORD, seq),
+                                        jnp.int32),
                    jax.ShapeDtypeStruct((b, 1, seq), _F32),
                    jax.ShapeDtypeStruct((b, 1, seq), _F32)],
         scratch_shapes=[pltpu.VMEM((seq, block_q), jnp.int32)],
@@ -355,7 +433,7 @@ def _kl_kernel(qf_ref, kf_ref, lse_ref, mask_ref, k_ref, kt_ref, q_ref,
                sm_scale: float, group: int):
     """One tile of (keys, queries) of one batch row. Main attention:
     ``qf_ref`` (H, queries, D), ``kf_ref`` (G, keys, D), ``lse_ref`` (H, 1,
-    queries). The selection: ``mask_ref`` (keys, queries) int8. The
+    queries). The selection's words: ``mask_ref`` (keys / 32, queries). The
     indexer: ``k_ref`` (keys, W), ``kt_ref`` (W, keys), ``q_ref`` (J,
     queries, W), ``qt_ref`` (J, W, queries), ``wt_ref`` (J, queries),
     ``lsei_ref`` (1, queries). -> summed over a row of the grid's tiles of
@@ -376,7 +454,7 @@ def _kl_kernel(qf_ref, kf_ref, lse_ref, mask_ref, k_ref, kt_ref, q_ref,
 
     @pl.when(ki <= qi)
     def _live():
-        seen = mask_ref[...].astype(jnp.int32) != 0
+        seen = bits(mask_ref[...]) != 0
 
         def a_head(h, total):
             s = _dot(kf_ref[h // group], qf_ref[h], _NT) * sm_scale
@@ -412,7 +490,7 @@ def _kl_pallas(q_idx, k_idx, w, selection: Selection, qf, kf, lse,
     b, seq, index_heads, width = q_idx.shape
     heads, kv_heads, d = qf.shape[0] // b, kf.shape[0] // b, qf.shape[-1]
     tile = min(tile or _KL_TILE, seq)
-    assert seq % tile == 0, (seq, tile)
+    assert seq % tile == 0 and tile % _CHUNK == 0, (seq, tile)
     n = seq // tile
     live_k = lambda qi, ki: jnp.minimum(ki, qi)
     q_heads = q_idx.transpose(0, 2, 1, 3)                 # [B, J, T, W]
@@ -432,7 +510,7 @@ def _kl_pallas(q_idx, k_idx, w, selection: Selection, qf, kf, lse,
                          lambda bi, qi, ki: (bi, 0, live_k(qi, ki), 0)),
             pl.BlockSpec((None, heads, 1, tile),
                          lambda bi, qi, ki: (bi, 0, 0, qi)),
-            pl.BlockSpec((None, tile, tile),
+            pl.BlockSpec((None, tile // KEYS_A_WORD, tile),
                          lambda bi, qi, ki: (bi, live_k(qi, ki), qi)),
             pl.BlockSpec((None, tile, width),
                          lambda bi, qi, ki: (bi, live_k(qi, ki), 0)),
@@ -472,23 +550,22 @@ def _kl_diff(q_idx, k_idx, w, selection, qf, kf, lse, sm_scale, interpret,
 
 def _kl_fwd(q_idx, k_idx, w, selection, qf, kf, lse, sm_scale, interpret,
             tile):
+    """The residuals are the three gradients, float32, and an EMPTY array
+    in each operand's type for the backward rule to round to: nothing of
+    the operands themselves, so what a recomputed block would have to make
+    again to hand them over (the selection, the indexer's projections) is
+    made for the forward pass alone."""
     kl, *grads = _kl_pallas(q_idx, k_idx, w, selection, qf, kf, lse,
                             sm_scale, interpret, tile)
-    grads = tuple(map(ad_checkpoint.checkpoint_name, grads, REMAT_NAMES))
-    return kl, (grads, (q_idx, k_idx, w), selection, qf, kf, lse)
-
-
-def _zero(x):
-    if jnp.issubdtype(x.dtype, jnp.floating):
-        return jnp.zeros_like(x)
-    return jnp.zeros(x.shape, jax.dtypes.float0)
+    grads = tuple(map(ad_checkpoint.checkpoint_name, grads, _KL_NAMES))
+    return kl, (grads, tuple(jnp.zeros((0,), x.dtype)
+                             for x in (q_idx, k_idx, w)))
 
 
 def _kl_bwd(sm_scale, interpret, tile, res, g):
-    grads, operands, *rest = res
-    return (*(
-        (g * grad).astype(x.dtype) for grad, x in zip(grads, operands)),
-        *jax.tree.map(_zero, tuple(rest)))
+    # the selection and the main attention's operands are constants here
+    return (*((g * grad).astype(as_.dtype) for grad, as_ in zip(*res)),
+            None, None, None, None)
 
 
 _kl_diff.defvjp(_kl_fwd, _kl_bwd)
@@ -514,8 +591,10 @@ def select(q_idx, k_idx, w, topk: int, *, impl: Optional[str] = None,
     constants. ``impl``: None (``auto_impl``), "pallas",
     "pallas_interpret" or "jnp" (``rows`` a block). The matmuls run in the
     operands' type, float32 accumulated: the caller chooses the precision
-    by the type it hands over. Records ``index/scores`` and
-    ``index/threshold``, one each a traced call."""
+    by the type it hands over. The mask is bits on every path (``pack``) and
+    is named for recomputation (``REMAT_NAMES``). Records ``index/scores``,
+    ``index/threshold`` and ``index/kept`` (the mask as it is kept: a bit a
+    pair, its bytes), one each a traced call."""
     q_idx, k_idx, w = map(lax.stop_gradient, (q_idx, k_idx, w))
     impl = impl or auto_impl(q_idx, topk)
     b, seq, heads, width = q_idx.shape
@@ -530,9 +609,16 @@ def select(q_idx, k_idx, w, topk: int, *, impl: Optional[str] = None,
         "passes": 32 if impl != "jnp" else 1})
     if impl == "jnp":
         with jax.named_scope("index_select_twin"):
-            return Selection(*_select_twin(q_idx, k_idx, w, topk, rows))
-    return Selection(*_select_pallas(
-        q_idx, k_idx, w, topk, impl == "pallas_interpret", block_q, block_k))
+            mask, tau, lse = _select_twin(q_idx, k_idx, w, topk, rows)
+    else:
+        mask, tau, lse = _select_pallas(
+            q_idx, k_idx, w, topk, impl == "pallas_interpret", block_q,
+            block_k)
+    steptrace.record_counters("index/kept", {
+        "bits_a_pair": 1, "bytes": mask.size * mask.dtype.itemsize,
+        "kernel": said["kernel"]})
+    return Selection(ad_checkpoint.checkpoint_name(mask, _MASK_NAME), tau,
+                     lse)
 
 
 def index_kl(q_idx, k_idx, w, selection: Selection, qf, kf, lse=None, *,
@@ -555,7 +641,7 @@ def index_kl(q_idx, k_idx, w, selection: Selection, qf, kf, lse=None, *,
         "main_heads": qf.shape[0] // q_idx.shape[0]})
     if impl == "jnp":
         with jax.named_scope("index_kl_twin"):
-            return _kl_twin(q_idx, k_idx, w, selection.mask, qf, kf,
+            return _kl_twin(q_idx, k_idx, w, unpack(selection.mask), qf, kf,
                             sm_scale, rows)
     assert lse is not None, "the kernel reads the flash kernel's log-sum-exp"
     return _kl_diff(q_idx, k_idx, w, selection, qf, kf, lax.stop_gradient(lse),

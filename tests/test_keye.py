@@ -186,6 +186,10 @@ def test_a_traced_pass_says_what_it_built():
     assert by_name["index/scores"]["flops_needed"] == 2 * 48 * 49 * 4 * 8
     assert by_name["index/scores"]["operand_bits"] == 32
     assert by_name["index/threshold"]["passes"] == 1
+    # the mask as it is kept: a bit a pair, 48 keys in one chunk's 8 rows of
+    # words a query
+    assert by_name["index/kept"] == {
+        "bits_a_pair": 1, "bytes": 2 * 8 * 48 * 4, "kernel": 0}
     assert by_name["index/loss"]["main_heads"] == 4
     assert by_name["attention/head_rotary"]["rotated"] == 1
 

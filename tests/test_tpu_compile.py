@@ -122,7 +122,7 @@ _HELD_PROGRAMS = {
     "trinity-mini.step-16k": "69c63c6279bc5a07",   # PR 66
     "mellum2-12b-a2.5b.step-8k": "82ab99b9a5fbbc45",   # PR 66
     "sdar-30b-a3b-chat.step-bd-4k": "e6cb997ca8d2efbf",   # PR 65
-    "keye-vl-2.0-30b-a3b.step-16k-img": "4a44ee31d7602259",   # PR 67
+    "keye-vl-2.0-30b-a3b.step-16k-img": "1c2c2a4256a8fa75",   # PR 68
 }
 
 
@@ -1707,16 +1707,20 @@ def test_selected_attention_expert_step_fits_one_chip_at_16384(
     untied head), its step at one sequence of 16,384 with recomputation, as
     the benchmark's family builds it over a batch that carries the
     layout's position ids and loss weights. The plan stays under the 14.5
-    GiB that ISSUE 67 set for keeping six layers (14.18 read: 7.37 of
-    arguments, 6.82 of temporaries). Every layer's attention is the flash
+    GiB that ISSUE 67 set for keeping six layers (14.37 read at PR 68, 14.18
+    at PR 67: 7.37 of arguments, the rest temporaries, the six layers' kept
+    words 0.19 of them). Every layer's attention is the flash
     kernel pair under the selection, named ``flash_*_sel2048``, once
     forward and once backward (``ops.remat.remat_policy`` keeps the output
-    and its log-sum-exp); the selection's kernel runs twice a layer (the
-    recomputed block makes the mask again), the KL's once (its forward
-    rule's gradients are kept). PR 63's prologue writes the flash kernels'
-    operands under a table whose pairs read three position rows. The one
-    [T, T] array is the mask, int8; the vocabulary's 18,992 rows stand only
-    beside the hidden size and the loss walk's positions."""
+    and its log-sum-exp); the selection's kernel runs ONCE a layer too
+    (since PR 68 the mask is bits, int32 [1, T / 32, T], 32 MiB a layer,
+    which the policy keeps: the recomputed block's flash backward reads the
+    kept words), and so does the KL's (its forward rule's gradients are
+    kept). PR 63's prologue writes the flash kernels' operands under a table
+    whose pairs read three position rows. No [T, T] array is left, a byte or
+    a score a pair: what crosses the kernels' boundary of the selection is
+    its words; the vocabulary's 18,992 rows stand only beside the hidden size
+    and the loss walk's positions."""
     from ray_tpu._private import steptrace
 
     cell = "keye-vl-2.0-30b-a3b.step-16k-img"
@@ -1744,7 +1748,8 @@ def test_selected_attention_expert_step_fits_one_chip_at_16384(
     assert set(by_name) == {
         "attn/grid_blocks", "rope/table", "model/layer_kinds",
         "attention/boundary", "attention/head_rotary", "attn/selected",
-        "index/scores", "index/threshold", "index/loss", "moe/row_buffers",
+        "index/scores", "index/threshold", "index/kept", "index/loss",
+        "moe/row_buffers",
         "moe/to_tokens", "moe/grouped_matmul"}
     assert by_name["model/layer_kinds"][-1] == {
         "sparse": 6, "expert": 6, "layers": 6, "published_layers": 48,
@@ -1760,6 +1765,8 @@ def test_selected_attention_expert_step_fits_one_chip_at_16384(
         "kernel": 1, "flops_needed": 2 * causal * 16 * 64,
         "bytes_needed": seq * (17 * 64 * 2 + 64), "operand_bits": 16}
     assert by_name["index/threshold"][-1]["passes"] == 32
+    assert by_name["index/kept"][-1] == {
+        "bits_a_pair": 1, "bytes": seq * seq // 8, "kernel": 1}
     assert by_name["index/loss"][-1]["kernel"] == 1
     for e in by_name["attn/grid_blocks"]:
         assert (e["whole"], e["diagonal"], e["dead"]) == (28, 8, 28)
@@ -1776,9 +1783,9 @@ def test_selected_attention_expert_step_fits_one_chip_at_16384(
         r"|index_kl)[\w.\-]* = .*"
         r'custom_call_target="tpu_custom_call"', text, re.M))
     assert calls == {"flash_fwd_sel2048": 6, "flash_bwd_sel2048": 6,
-                     "index_select_top2048": 12, "index_kl": 6}
+                     "index_select_top2048": 6, "index_kl": 6}
     assert f"bf16[32,{seq},128]" in text and f"bf16[4,{seq},128]" in text
-    assert f"s8[1,{seq},{seq}]" in text
+    assert f"s32[1,{seq // 32},{seq}]" in text
     _dq_census(text, 32, 128, seq)
     _head_rotary_census(text, counters, batch * seq * 32 * 128, layers=6,
                         rotated=6)
@@ -1791,10 +1798,9 @@ def test_selected_attention_expert_step_fits_one_chip_at_16384(
     shapes = set(re.findall(r"\b([a-z]\w*)\[([\d,]+)\]", text))
     for kind, dims in ((k, tuple(int(n) for n in s.split(",")))
                        for k, s in shapes):
-        # the one [T, T]: the mask, a byte a pair; no score, no probability
-        if any(a == b == seq for a, b in zip(dims, dims[1:])):
-            assert kind in ("s8", "pred") and dims[-2:] == (seq, seq), (
-                kind, dims)
+        # no [T, T]: no mask a byte a pair, no score, no probability
+        assert not any(a == b == seq for a, b in zip(dims, dims[1:])), (
+            kind, dims)
         if 18992 in dims:
             assert set(dims) <= {18992, 2048, batch, seq // 8, 1}, dims
 
