@@ -64,7 +64,8 @@ __all__ = [
     "set_enabled", "is_enabled", "record_calls", "record_collective",
     "record_phase", "record_compile", "step_mark", "span",
     "set_train_context", "clear_train_context", "reset", "snapshot",
-    "process_snapshot", "install_compile_listener",
+    "process_snapshot", "install_compile_listener", "device_scope",
+    "DEVICE_SCOPES",
     "merge_collectives", "merge_processes", "chrome_trace",
     "SkewAggregator", "SEQ_MOD",
 ]
@@ -403,6 +404,31 @@ class span:
                          thread=None if thread is _MAIN_THREAD
                          else thread.name)
         return False
+
+
+# The classes of the device's work. The one vocabulary there is: a reader of
+# a profiler trace (``perfbench/opscopes.py``) takes an operation's class
+# from the LAST ``rt.<kind>`` segment of its name stack, so a norm inside a
+# mixer is the norm's, and a forward, its recomputed copy and its transpose
+# keep the segment (``transpose(jvp(M))/.../rt.mixer/...``). Every other
+# segment is free and is nobody's class: a module's name, and the two bare
+# ``jax.named_scope("index_select_twin")`` / ``("index_kl_twin")`` of
+# ``ops/sparse_index.py``, which name a twin inside ``rt.mixer``.
+DEVICE_SCOPES = ("mixer", "experts", "mlp", "norm", "vocab", "optimizer")
+
+
+def device_scope(kind: str):
+    """``jax.named_scope("rt." + kind)`` for a ``kind`` of
+    ``DEVICE_SCOPES``: the operations traced inside it carry the segment in
+    their name stack, in the jaxpr, in the compiled program's metadata and
+    in a profiler trace's. Metadata and nothing else: no ring record, no
+    flag, nothing at run time. Only code that traces a jax program calls
+    it, so jax is imported here and not by the module."""
+    if kind not in DEVICE_SCOPES:
+        raise ValueError(f"no device scope {kind!r}: one of {DEVICE_SCOPES}")
+    import jax
+
+    return jax.named_scope("rt." + kind)
 
 
 # ---------------------------------------------------------------------------

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import (RMSNorm, RMSNormScale, SwiGLU,
                                   rope_frequencies)
@@ -173,21 +174,23 @@ class Block(nn.Module):
     def __call__(self, x, positions):
         c = self.config
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
-        attended = GatedAttention(c, self.window, name="attn")(
-            norm("input_norm")(x), positions)
-        x = on_batch_axes(x + norm("post_attn_norm")(attended))
-        h = norm("pre_mlp_norm")(x)
-        if self.dense:
-            y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
-                               name="mlp")(h), jnp.zeros((0,), jnp.int32)
-        else:
-            y, tokens = RoutedExperts(
-                experts=c.num_experts, expert_shard=c.expert_shard,
-                width=c.moe_intermediate_size,
-                per_token=c.num_experts_per_tok, scale=c.route_scale,
-                normalize=c.route_norm, shared=c.num_shared_experts,
-                dtype=c.dtype, kernel_init=_init(c), name="moe")(h)
-        return on_batch_axes(x + norm("post_mlp_norm")(y)), tokens
+        with device_scope("mixer"):
+            attended = GatedAttention(c, self.window, name="attn")(
+                norm("input_norm")(x), positions)
+            x = on_batch_axes(x + norm("post_attn_norm")(attended))
+        with device_scope("mlp" if self.dense else "experts"):
+            h = norm("pre_mlp_norm")(x)
+            if self.dense:
+                y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
+                                   name="mlp")(h), jnp.zeros((0,), jnp.int32)
+            else:
+                y, tokens = RoutedExperts(
+                    experts=c.num_experts, expert_shard=c.expert_shard,
+                    width=c.moe_intermediate_size,
+                    per_token=c.num_experts_per_tok, scale=c.route_scale,
+                    normalize=c.route_norm, shared=c.num_shared_experts,
+                    dtype=c.dtype, kernel_init=_init(c), name="moe")(h)
+            return on_batch_axes(x + norm("post_mlp_norm")(y)), tokens
 
 
 class Afmoe(nn.Module):
@@ -205,7 +208,8 @@ class Afmoe(nn.Module):
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
         positions = jnp.arange(T)[None, :]   # one table for every row
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x = on_batch_axes(embed(input_ids) * math.sqrt(c.hidden_size))
+        with device_scope("vocab"):
+            x = on_batch_axes(embed(input_ids) * math.sqrt(c.hidden_size))
         tokens = []
         for i, kind in enumerate(c.layer_types):
             dense = i < c.num_dense_layers
@@ -225,12 +229,13 @@ def loss_fn(params, model, batch):
     c = model.config
     hidden, tokens = model.apply({"params": params}, batch["input_ids"])
     head, labels, mask = params["lm_head"], batch["labels"], batch.get("mask")
-    if c.loss_chunks:
-        loss = xent.chunked_xent(hidden, head, labels, mask,
-                                 n_chunks=c.loss_chunks)
-    else:
-        loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels,
-                               mask)
+    with device_scope("vocab"):
+        if c.loss_chunks:
+            loss = xent.chunked_xent(hidden, head, labels, mask,
+                                     n_chunks=c.loss_chunks)
+        else:
+            loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype),
+                                   labels, mask)
     return loss, {"tokens_per_expert": tokens}
 
 

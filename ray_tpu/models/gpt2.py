@@ -19,6 +19,7 @@ import flax.linen as nn
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.ops.attention import causal_self_attention
 from ray_tpu.ops.remat import remat_policy
 from ray_tpu.ops.xent import (chunked_xent, fused_xent,
@@ -117,12 +118,16 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic=True):
         c = self.config
-        x = on_batch_axes(x + CausalSelfAttention(c, name="attn")(
-            nn.LayerNorm(dtype=c.dtype, name="ln_1")(x), deterministic
-        ))
-        x = on_batch_axes(x + MLP(c, name="mlp")(
-            nn.LayerNorm(dtype=c.dtype, name="ln_2")(x), deterministic
-        ))
+        # a class holds its residual sum; the innermost segment decides
+        with device_scope("mixer"):
+            with device_scope("norm"):
+                h = nn.LayerNorm(dtype=c.dtype, name="ln_1")(x)
+            x = on_batch_axes(x + CausalSelfAttention(c, name="attn")(
+                h, deterministic))
+        with device_scope("mlp"):
+            with device_scope("norm"):
+                h = nn.LayerNorm(dtype=c.dtype, name="ln_2")(x)
+            x = on_batch_axes(x + MLP(c, name="mlp")(h, deterministic))
         return x
 
 
@@ -143,20 +148,24 @@ class GPT2(nn.Module):
         # nn.Embed's own lookup, on a table gathered whole first: looked up
         # in its shards at rest (split by features) the rows would come
         # back split by features and cross to the batch axes by all-to-all
-        table = on_batch_axes(wte.embedding.astype(c.dtype), batch_dim=None)
-        x = on_batch_axes(jnp.take(table, input_ids, axis=0) + wpe(pos))
+        with device_scope("vocab"):
+            table = on_batch_axes(wte.embedding.astype(c.dtype),
+                                  batch_dim=None)
+            x = on_batch_axes(jnp.take(table, input_ids, axis=0) + wpe(pos))
         block = Block
         if c.remat:
             block = nn.remat(Block, static_argnums=(2,), policy=remat_policy())
         for i in range(c.n_layer):
             x = block(c, name=f"h_{i}")(x, deterministic)
-        x = on_batch_axes(nn.LayerNorm(dtype=c.dtype, name="ln_f")(x))
+        with device_scope("norm"):
+            x = on_batch_axes(nn.LayerNorm(dtype=c.dtype, name="ln_f")(x))
         if return_hidden:
             # chunked-loss path: hand back the final hidden states so the
             # loss can run the tied vocab matmul chunk by chunk
             return x
         # weight-tied LM head; bf16 matmul (MXU) — loss upcasts per-element
-        logits = on_batch_axes(wte.attend(x))
+        with device_scope("vocab"):
+            logits = on_batch_axes(wte.attend(x))
         return logits
 
 
@@ -226,8 +235,9 @@ def build_train_step_sp(model, tx, mesh: Mesh, *, sp_axis: str = "sp",
         loss, grads = jax.value_and_grad(loss_fn)(params, model, batch)
         grads = jax.lax.pmean(grads, axes)
         loss = jax.lax.pmean(loss, axes)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with device_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     bspec = PartitionSpec(batch_axis, sp_axis)
@@ -434,8 +444,9 @@ def build_train_step_pp(config: GPT2Config, tx, mesh: Mesh, *,
 
     def step(params, opt_state, batch):
         loss, grads = grad_fn(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with device_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.afmoe import (  # noqa: F401 (this module's names too)
     param_shardings, shard_train_state)
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
@@ -185,11 +186,13 @@ class Keye(nn.Module):
             "published_layers": KeyeConfig.num_hidden_layers,
             "topk": c.topk})
         # one table, [B, T, head_dim / 2], its pairs from three rows
-        cos, sin = rope_table(c.head_dim, position_ids, {
-            "rope_type": "default", "rope_theta": c.rope_theta,
-            "mrope_section": c.mrope_section})
+        with device_scope("mixer"):
+            cos, sin = rope_table(c.head_dim, position_ids, {
+                "rope_type": "default", "rope_theta": c.rope_theta,
+                "mrope_section": c.mrope_section})
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens, kl = on_batch_axes(embed(input_ids)), [], []
+        with device_scope("vocab"):
+            x, tokens, kl = on_batch_axes(embed(input_ids)), [], []
         for i in range(c.num_hidden_layers):
             x, n, layer_kl = block(c, sparse=c.indexer, name=f"layers_{i}")(
                 x, cos, sin)
@@ -210,16 +213,18 @@ def loss_fn(params, model, batch):
                                      batch["position_ids"])
     head, labels = params["lm_head"], batch["labels"]
     weights = batch.get("loss_weights")
-    if weights is None:
-        weights = jnp.ones(labels.shape, jnp.float32)
-    if c.loss_chunks:
-        lm = xent.chunked_xent(hidden, head, labels, weights,
-                               n_chunks=c.loss_chunks)
-    else:
-        ll = xent.token_log_likelihood(
-            hidden @ head.T.astype(hidden.dtype), labels)
-        lm = -(ll * weights).sum() / weights.sum()
-    index = kl.sum() / (c.num_hidden_layers * ids.size)
+    with device_scope("vocab"):
+        if weights is None:
+            weights = jnp.ones(labels.shape, jnp.float32)
+        if c.loss_chunks:
+            lm = xent.chunked_xent(hidden, head, labels, weights,
+                                   n_chunks=c.loss_chunks)
+        else:
+            ll = xent.token_log_likelihood(
+                hidden @ head.T.astype(hidden.dtype), labels)
+            lm = -(ll * weights).sum() / weights.sum()
+    with device_scope("mixer"):   # the indexers' loss
+        index = kl.sum() / (c.num_hidden_layers * ids.size)
     return lm + index, {"lm_loss": lm, "index_loss": index,
                         "tokens_per_expert": tokens}
 

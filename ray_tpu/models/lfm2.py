@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.afmoe import rotate_halves, step_metrics  # noqa: F401
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm, SwiGLU, rope_frequencies
@@ -192,25 +193,27 @@ class Block(nn.Module):
     def __call__(self, x, positions):
         c = self.config
         norm = lambda name: RMSNorm(c.norm_eps, c.dtype, name=name)
-        u = norm("operator_norm")(x)
-        if self.kind == CONV:
-            mixed = ShortConv(c, name="conv")(u)
-        else:
-            mixed = Attention(c, name="attn")(u, positions)
-        x = on_batch_axes(x + mixed)
-        h = norm("ffn_norm")(x)
-        if self.dense:
-            y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
-                               name="mlp")(h), jnp.zeros((0,), jnp.int32)
-        else:
-            y, tokens = RoutedExperts(
-                experts=c.num_experts, expert_shard=c.expert_shard,
-                width=c.moe_intermediate_size,
-                per_token=c.num_experts_per_tok,
-                scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
-                shared=0, dtype=c.dtype, kernel_init=_init(c),
-                eps=c.route_eps, name="moe")(h)
-        return on_batch_axes(x + y), tokens
+        with device_scope("mixer"):
+            u = norm("operator_norm")(x)
+            if self.kind == CONV:
+                mixed = ShortConv(c, name="conv")(u)
+            else:
+                mixed = Attention(c, name="attn")(u, positions)
+            x = on_batch_axes(x + mixed)
+        with device_scope("mlp" if self.dense else "experts"):
+            h = norm("ffn_norm")(x)
+            if self.dense:
+                y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
+                                   name="mlp")(h), jnp.zeros((0,), jnp.int32)
+            else:
+                y, tokens = RoutedExperts(
+                    experts=c.num_experts, expert_shard=c.expert_shard,
+                    width=c.moe_intermediate_size,
+                    per_token=c.num_experts_per_tok,
+                    scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
+                    shared=0, dtype=c.dtype, kernel_init=_init(c),
+                    eps=c.route_eps, name="moe")(h)
+            return on_batch_axes(x + y), tokens
 
 
 class Lfm2(nn.Module):
@@ -233,7 +236,8 @@ class Lfm2(nn.Module):
                          embedding_init=_init(c), name="embed")
         positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(input_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(input_ids)), []
         for i, kind, dense in c.layers:
             x, n = block(c, kind, dense, name=f"layers_{i}")(x, positions)
             if not dense:
@@ -251,12 +255,13 @@ def loss_fn(params, model, batch):
     hidden, tokens = model.apply({"params": params}, batch["input_ids"])
     head, labels, mask = (params["embed"]["embedding"], batch["labels"],
                           batch.get("mask"))
-    if c.loss_chunks:
-        loss = xent.chunked_xent(hidden, head, labels, mask,
-                                 n_chunks=c.loss_chunks)
-    else:
-        loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels,
-                               mask)
+    with device_scope("vocab"):
+        if c.loss_chunks:
+            loss = xent.chunked_xent(hidden, head, labels, mask,
+                                     n_chunks=c.loss_chunks)
+        else:
+            loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype),
+                                   labels, mask)
     return loss, {"tokens_per_expert": tokens}
 
 

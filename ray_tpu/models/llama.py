@@ -29,6 +29,7 @@ import flax.linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.ops.xent import fused_xent
 from ray_tpu.parallel import train_step
 
@@ -86,6 +87,7 @@ class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
+    @device_scope("norm")
     def __call__(self, x):
         # normalize in f32 (rsqrt of a bf16 mean-square loses mantissa),
         # scale in compute dtype
@@ -297,14 +299,16 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, cache_index=None):
         c = self.config
-        h, new_cache = LlamaAttention(c, name="attn")(
-            RMSNorm(c.rms_eps, c.dtype, name="input_norm")(x),
-            positions, kv_cache, cache_index,
-        )
-        x = x + h
-        x = x + SwiGLU(c.intermediate, c.dtype, name="mlp")(
-            RMSNorm(c.rms_eps, c.dtype, name="post_attn_norm")(x)
-        )
+        with device_scope("mixer"):
+            h, new_cache = LlamaAttention(c, name="attn")(
+                RMSNorm(c.rms_eps, c.dtype, name="input_norm")(x),
+                positions, kv_cache, cache_index,
+            )
+            x = x + h
+        with device_scope("mlp"):
+            x = x + SwiGLU(c.intermediate, c.dtype, name="mlp")(
+                RMSNorm(c.rms_eps, c.dtype, name="post_attn_norm")(x)
+            )
         return x, new_cache
 
 
@@ -320,8 +324,9 @@ class Llama(nn.Module):
         B, T = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
-                     name="embed")(input_ids)
+        with device_scope("vocab"):
+            x = nn.Embed(c.vocab_size, c.n_embd, dtype=c.dtype,
+                         name="embed")(input_ids)
         block = LlamaBlock
         if c.remat and kv_caches is None:
             block = nn.remat(LlamaBlock, static_argnums=())
@@ -331,8 +336,9 @@ class Llama(nn.Module):
             x, nc = block(c, name=f"h_{i}")(x, positions, cache, cache_index)
             new_caches.append(nc)
         x = RMSNorm(c.rms_eps, c.dtype, name="norm")(x)
-        logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
-                          name="lm_head")(x)
+        with device_scope("vocab"):
+            logits = nn.Dense(c.vocab_size, use_bias=False, dtype=c.dtype,
+                              name="lm_head")(x)
         if kv_caches is None:
             return logits, None
         return logits, new_caches

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 # What ``afmoe`` says of any model that hands back (hidden, tokens a held
 # expert) under an untied ``lm_head`` is this family's too, and is one copy:
 # the loss, the step over it, the parameters' placement, a loop's report
@@ -236,17 +237,20 @@ class Block(nn.Module):
     def __call__(self, x, cos, sin):
         c = self.config
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
-        y = Attention(c, self.window, self.blocks, self.sparse, name="attn")(
-            norm("input_norm")(x), cos, sin)
-        y, *kl = y if self.sparse is not None else (y,)
-        x = on_batch_axes(x + y)
-        y, tokens = RoutedExperts(
-            experts=c.num_experts, expert_shard=c.expert_shard,
-            width=c.moe_intermediate_size, per_token=c.num_experts_per_tok,
-            scale=1.0, normalize=c.norm_topk_prob, shared=0, dtype=c.dtype,
-            kernel_init=_init(c), eps=0.0, score="softmax", name="moe")(
-                norm("post_attn_norm")(x))
-        return (on_batch_axes(x + y), tokens, *kl)
+        with device_scope("mixer"):
+            y = Attention(c, self.window, self.blocks, self.sparse,
+                          name="attn")(norm("input_norm")(x), cos, sin)
+            y, *kl = y if self.sparse is not None else (y,)
+            x = on_batch_axes(x + y)
+        with device_scope("experts"):
+            y, tokens = RoutedExperts(
+                experts=c.num_experts, expert_shard=c.expert_shard,
+                width=c.moe_intermediate_size,
+                per_token=c.num_experts_per_tok,
+                scale=1.0, normalize=c.norm_topk_prob, shared=0,
+                dtype=c.dtype, kernel_init=_init(c), eps=0.0,
+                score="softmax", name="moe")(norm("post_attn_norm")(x))
+            return (on_batch_axes(x + y), tokens, *kl)
 
 
 class Mellum(nn.Module):
@@ -268,10 +272,12 @@ class Mellum(nn.Module):
             "published_layers": MellumConfig.num_hidden_layers})
         # one table a kind of layer, [1, T, head_dim / 2], for every row
         positions, ropes = jnp.arange(T)[None, :], dict(c.rope_parameters)
-        tables = {kind: rope_table(c.head_dim, positions, ropes[kind])
-                  for kind in sorted(set(c.layer_types))}
+        with device_scope("mixer"):
+            tables = {kind: rope_table(c.head_dim, positions, ropes[kind])
+                      for kind in sorted(set(c.layer_types))}
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(input_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(input_ids)), []
         for i, kind in enumerate(c.layer_types):
             window = c.sliding_window if kind == WINDOW else None
             x, n = block(c, window, name=f"layers_{i}")(x, *tables[kind])

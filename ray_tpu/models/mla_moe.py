@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import (ReLU2, RMSNorm, SwiGLU, apply_rope,
                                   rope_frequencies)
@@ -208,21 +209,23 @@ class Block(nn.Module):
     def __call__(self, x, positions):
         c = self.config
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
-        x = on_batch_axes(x + LatentAttention(c, name="attn")(
-            norm("input_norm")(x), positions))
-        h = norm("post_attn_norm")(x)
-        if self.dense:
-            y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
-                               name="mlp")(h), jnp.zeros((0,), jnp.int32)
-        else:
-            y, tokens = RoutedExperts(
-                experts=c.n_routed_experts, expert_shard=c.expert_shard,
-                width=c.moe_intermediate_size,
-                per_token=c.num_experts_per_tok,
-                scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
-                shared=c.n_shared_experts, dtype=c.dtype,
-                kernel_init=_init(c), name="moe")(h)
-        return on_batch_axes(x + y), tokens
+        with device_scope("mixer"):
+            x = on_batch_axes(x + LatentAttention(c, name="attn")(
+                norm("input_norm")(x), positions))
+        with device_scope("mlp" if self.dense else "experts"):
+            h = norm("post_attn_norm")(x)
+            if self.dense:
+                y, tokens = SwiGLU(c.intermediate_size, c.dtype, _init(c),
+                                   name="mlp")(h), jnp.zeros((0,), jnp.int32)
+            else:
+                y, tokens = RoutedExperts(
+                    experts=c.n_routed_experts, expert_shard=c.expert_shard,
+                    width=c.moe_intermediate_size,
+                    per_token=c.num_experts_per_tok,
+                    scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
+                    shared=c.n_shared_experts, dtype=c.dtype,
+                    kernel_init=_init(c), name="moe")(h)
+            return on_batch_axes(x + y), tokens
 
 
 class MLAMoE(nn.Module):
@@ -243,7 +246,8 @@ class MLAMoE(nn.Module):
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
         positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(input_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(input_ids)), []
         for i in range(c.num_hidden_layers):
             dense = i < c.first_k_dense_replace
             x, n = block(c, dense, name=f"layers_{i}")(x, positions)
@@ -251,11 +255,15 @@ class MLAMoE(nn.Module):
                 tokens.append(n)
         hidden, predicted = norm("norm")(x), None
         if next_ids is not None and c.num_nextn_predict_layers:
-            joined = jnp.concatenate(
-                [norm("mtp_enorm")(embed(next_ids)),
-                 norm("mtp_hnorm")(hidden)], axis=-1)
-            z = nn.Dense(c.hidden_size, use_bias=False, dtype=c.dtype,
-                         kernel_init=_init(c), name="mtp_eh_proj")(joined)
+            with device_scope("vocab"):
+                ahead = embed(next_ids)
+            # the prediction module's way in: a dense projection
+            with device_scope("mlp"):
+                joined = jnp.concatenate(
+                    [norm("mtp_enorm")(ahead), norm("mtp_hnorm")(hidden)],
+                    axis=-1)
+                z = nn.Dense(c.hidden_size, use_bias=False, dtype=c.dtype,
+                             kernel_init=_init(c), name="mtp_eh_proj")(joined)
             z, n = block(c, False, name="mtp_block")(on_batch_axes(z),
                                                      positions)
             tokens.append(n)
@@ -292,7 +300,9 @@ def loss_fn(params, model, batch):
         if c.loss_chunks:
             return xent.chunked_xent(h, head, targets, weights,
                                      n_chunks=c.loss_chunks)
-        return xent.fused_xent(h @ head.T.astype(h.dtype), targets, weights)
+        with device_scope("vocab"):
+            logits = h @ head.T.astype(h.dtype)
+        return xent.fused_xent(logits, targets, weights)
 
     main = term(hidden, labels, mask)
     mtp = jnp.float32(0.0)

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.afmoe import step_metrics  # noqa: F401
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm
@@ -291,23 +292,26 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        u = RMSNorm(c.layer_norm_epsilon, c.dtype, name="norm")(x)
-        tokens = jnp.zeros((0,), jnp.int32)
-        if self.kind == MAMBA:
-            y = Mamba2Mixer(c, name="mixer")(u)
-        elif self.kind == ATTENTION:
-            y = Attention(c, name="mixer")(u)
-        else:
-            y, tokens = RoutedExperts(
-                experts=c.n_routed_experts, expert_shard=c.expert_shard,
-                width=c.moe_intermediate_size,
-                per_token=c.num_experts_per_tok,
-                scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
-                shared=(c.moe_shared_expert_intermediate_size
-                        // c.moe_intermediate_size),
-                dtype=c.dtype, kernel_init=_init(c), eps=1e-20,
-                score="sigmoid", activation="relu2", name="mixer")(u)
-        return on_batch_axes(x + y), tokens
+        # one mixer a block: an expert block's module is named ``mixer``
+        # too, and its class is ``experts``
+        with device_scope("experts" if self.kind == EXPERT else "mixer"):
+            u = RMSNorm(c.layer_norm_epsilon, c.dtype, name="norm")(x)
+            tokens = jnp.zeros((0,), jnp.int32)
+            if self.kind == MAMBA:
+                y = Mamba2Mixer(c, name="mixer")(u)
+            elif self.kind == ATTENTION:
+                y = Attention(c, name="mixer")(u)
+            else:
+                y, tokens = RoutedExperts(
+                    experts=c.n_routed_experts, expert_shard=c.expert_shard,
+                    width=c.moe_intermediate_size,
+                    per_token=c.num_experts_per_tok,
+                    scale=c.routed_scaling_factor, normalize=c.norm_topk_prob,
+                    shared=(c.moe_shared_expert_intermediate_size
+                            // c.moe_intermediate_size),
+                    dtype=c.dtype, kernel_init=_init(c), eps=1e-20,
+                    score="sigmoid", activation="relu2", name="mixer")(u)
+            return on_batch_axes(x + y), tokens
 
 
 class NemotronH(nn.Module):
@@ -327,7 +331,8 @@ class NemotronH(nn.Module):
                          embedding_init=_init(c), name="embed")
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(input_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(input_ids)), []
         for i, kind in c.layers:
             x, n = block(c, kind, name=f"layers_{i}")(x)
             if kind == EXPERT:
@@ -344,12 +349,13 @@ def loss_fn(params, model, batch):
     c = model.config
     hidden, tokens = model.apply({"params": params}, batch["input_ids"])
     head, labels, mask = params["lm_head"], batch["labels"], batch.get("mask")
-    if c.loss_chunks:
-        loss = xent.chunked_xent(hidden, head, labels, mask,
-                                 n_chunks=c.loss_chunks)
-    else:
-        loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels,
-                               mask)
+    with device_scope("vocab"):
+        if c.loss_chunks:
+            loss = xent.chunked_xent(hidden, head, labels, mask,
+                                     n_chunks=c.loss_chunks)
+        else:
+            loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype),
+                                   labels, mask)
     return loss, {"tokens_per_expert": tokens}
 
 

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import RMSNorm
 from ray_tpu.ops import xent
@@ -278,19 +279,24 @@ class Block(nn.Module):
         c = self.config
         norm = lambda name: nn.LayerNorm(epsilon=c.layer_norm_eps,
                                          dtype=c.dtype, name=name)
-        u, handed = norm("ln1")(h), None
-        if self.kind == SSM:
-            mixed, handed = Mamba(c, name="mixer")(u)
-        elif self.kind == GMU:
-            mixed = GatedMemory(c, name="mixer")(u, side)
-        else:
-            window = c.sliding_window if self.kind == WINDOW else None
-            mixed, kv = DiffAttention(c, self.index, window, name="mixer")(
-                u, side if self.kind == CROSS else None)
-            handed = kv if self.kind == FULL else None
-        h = on_batch_axes(h + mixed)
-        return on_batch_axes(h + GatedMLP(c, name="mlp")(norm("ln2")(h))), \
-            handed
+        with device_scope("mixer"):
+            with device_scope("norm"):
+                u, handed = norm("ln1")(h), None
+            if self.kind == SSM:
+                mixed, handed = Mamba(c, name="mixer")(u)
+            elif self.kind == GMU:
+                mixed = GatedMemory(c, name="mixer")(u, side)
+            else:
+                window = c.sliding_window if self.kind == WINDOW else None
+                mixed, kv = DiffAttention(c, self.index, window,
+                                          name="mixer")(
+                    u, side if self.kind == CROSS else None)
+                handed = kv if self.kind == FULL else None
+            h = on_batch_axes(h + mixed)
+        with device_scope("mlp"):
+            with device_scope("norm"):
+                u = norm("ln2")(h)
+            return on_batch_axes(h + GatedMLP(c, name="mlp")(u)), handed
 
 
 class Phi4Flash(nn.Module):
@@ -309,7 +315,8 @@ class Phi4Flash(nn.Module):
         embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=c.dtype,
                          embedding_init=_init(c), name="embed")
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        h = on_batch_axes(embed(input_ids))
+        with device_scope("vocab"):
+            h = on_batch_axes(embed(input_ids))
         memory = keys_values = None
         for i, kind in c.kinds:
             side = {GMU: memory, CROSS: keys_values}.get(kind)
@@ -318,8 +325,9 @@ class Phi4Flash(nn.Module):
                 memory = handed
             elif kind == FULL:
                 keys_values = handed
-        return nn.LayerNorm(epsilon=c.layer_norm_eps, dtype=c.dtype,
-                            name="norm")(h)
+        with device_scope("norm"):
+            return nn.LayerNorm(epsilon=c.layer_norm_eps, dtype=c.dtype,
+                                name="norm")(h)
 
 
 def loss_fn(params, model, batch):
@@ -329,10 +337,12 @@ def loss_fn(params, model, batch):
     hidden = model.apply({"params": params}, batch["input_ids"])
     head, labels, mask = (params["embed"]["embedding"], batch["labels"],
                           batch.get("mask"))
-    if c.loss_chunks:
-        return xent.chunked_xent(hidden, head, labels, mask,
-                                 n_chunks=c.loss_chunks)
-    return xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels, mask)
+    with device_scope("vocab"):
+        if c.loss_chunks:
+            return xent.chunked_xent(hidden, head, labels, mask,
+                                     n_chunks=c.loss_chunks)
+        return xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels,
+                               mask)
 
 
 def init_params(config: Phi4FlashConfig, rng):

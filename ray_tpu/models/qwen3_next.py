@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.afmoe import rotate_halves, step_metrics  # noqa: F401
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
 from ray_tpu.models.llama import rope_frequencies
@@ -178,6 +179,7 @@ class ZeroCentredRMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
+    @device_scope("norm")
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],))
         return (_rms(x, self.eps) * (1.0 + scale)).astype(self.dtype)
@@ -282,21 +284,24 @@ class Block(nn.Module):
         c = self.config
         norm = lambda name: ZeroCentredRMSNorm(c.rms_norm_eps, c.dtype,
                                                name=name)
-        u = norm("input_norm")(x)
-        if self.kind == LINEAR:
-            mixed = LinearAttention(c, name="linear_attn")(u)
-        else:
-            mixed = GatedAttention(c, name="attn")(u, positions)
-        x = on_batch_axes(x + mixed)
-        y, tokens = RoutedExperts(
-            experts=c.num_experts, expert_shard=c.expert_shard,
-            width=c.moe_intermediate_size, per_token=c.num_experts_per_tok,
-            scale=1.0, normalize=c.norm_topk_prob,
-            shared=(c.shared_expert_intermediate_size
-                    // c.moe_intermediate_size),
-            dtype=c.dtype, kernel_init=_init(c), eps=0.0, score="softmax",
-            shared_gate=True, name="moe")(norm("post_attn_norm")(x))
-        return on_batch_axes(x + y), tokens
+        with device_scope("mixer"):
+            u = norm("input_norm")(x)
+            if self.kind == LINEAR:
+                mixed = LinearAttention(c, name="linear_attn")(u)
+            else:
+                mixed = GatedAttention(c, name="attn")(u, positions)
+            x = on_batch_axes(x + mixed)
+        with device_scope("experts"):
+            y, tokens = RoutedExperts(
+                experts=c.num_experts, expert_shard=c.expert_shard,
+                width=c.moe_intermediate_size,
+                per_token=c.num_experts_per_tok,
+                scale=1.0, normalize=c.norm_topk_prob,
+                shared=(c.shared_expert_intermediate_size
+                        // c.moe_intermediate_size),
+                dtype=c.dtype, kernel_init=_init(c), eps=0.0, score="softmax",
+                shared_gate=True, name="moe")(norm("post_attn_norm")(x))
+            return on_batch_axes(x + y), tokens
 
 
 class Qwen3Next(nn.Module):
@@ -318,7 +323,8 @@ class Qwen3Next(nn.Module):
         self.param("lm_head", _init(c), (c.vocab_size, c.hidden_size))
         positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(input_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(input_ids)), []
         for i, kind in c.layers:
             x, n = block(c, kind, name=f"layers_{i}")(x, positions)
             tokens.append(n)
@@ -333,12 +339,13 @@ def loss_fn(params, model, batch):
     c = model.config
     hidden, tokens = model.apply({"params": params}, batch["input_ids"])
     head, labels, mask = params["lm_head"], batch["labels"], batch.get("mask")
-    if c.loss_chunks:
-        loss = xent.chunked_xent(hidden, head, labels, mask,
-                                 n_chunks=c.loss_chunks)
-    else:
-        loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype), labels,
-                               mask)
+    with device_scope("vocab"):
+        if c.loss_chunks:
+            loss = xent.chunked_xent(hidden, head, labels, mask,
+                                     n_chunks=c.loss_chunks)
+        else:
+            loss = xent.fused_xent(hidden @ head.T.astype(hidden.dtype),
+                                   labels, mask)
     return loss, {"tokens_per_expert": tokens}
 
 

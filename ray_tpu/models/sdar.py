@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import steptrace
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.models.afmoe import (  # noqa: F401 (this module's names too)
     param_shardings, shard_train_state)
 from ray_tpu.models.gpt2 import make_optimizer  # the one AdamW recipe
@@ -146,17 +147,20 @@ class Sdar(nn.Module):
             "block_length": c.block_length, "streams": 2})
         # one table, [1, 2 L, head_dim / 2]: a stream's positions, twice
         positions = jnp.tile(jnp.arange(T // 2), 2)[None, :]
-        cos, sin = rope_table(c.head_dim, positions, {
-            "rope_type": "default", "rope_theta": c.rope_theta})
+        with device_scope("mixer"):
+            cos, sin = rope_table(c.head_dim, positions, {
+                "rope_type": "default", "rope_theta": c.rope_theta})
         block = nn.remat(Block, policy=remat_policy()) if c.remat else Block
-        x, tokens = on_batch_axes(embed(both_ids)), []
+        with device_scope("vocab"):
+            x, tokens = on_batch_axes(embed(both_ids)), []
         for i in range(c.num_hidden_layers):
             x, n = block(c, blocks=c.block_length, name=f"layers_{i}")(
                 x, cos, sin)
             tokens.append(n)
-        noisy = on_batch_axes(x[:, :T // 2])
-        return (RMSNorm(c.rms_norm_eps, c.dtype, name="norm")(noisy),
-                jnp.stack(tokens))
+        with device_scope("norm"):
+            noisy = on_batch_axes(x[:, :T // 2])
+            return (RMSNorm(c.rms_norm_eps, c.dtype, name="norm")(noisy),
+                    jnp.stack(tokens))
 
 
 def noise(config: SdarConfig, input_ids, count):
@@ -185,19 +189,22 @@ def loss_fn(params, model, batch, count):
     position there is."""
     c = model.config
     clean = batch["input_ids"]
-    noisy, masked, p = noise(c, clean, count)
-    hidden, tokens = model.apply(
-        {"params": params}, jnp.concatenate([noisy, clean], axis=1))
-    head, weights = params["lm_head"], masked / p
-    if c.loss_chunks:
-        loss = xent.chunked_xent(hidden, head, clean, weights,
-                                 n_chunks=c.loss_chunks, denom=clean.size)
-    else:
-        ll = xent.token_log_likelihood(
-            hidden @ head.T.astype(hidden.dtype), clean)
-        loss = -(ll * weights).sum() / clean.size
-    return loss, {"masked_share": masked.mean(dtype=jnp.float32),
-                  "tokens_per_expert": tokens}
+    # the draw makes the ids the embedding looks up: the vocabulary's
+    with device_scope("vocab"):
+        noisy, masked, p = noise(c, clean, count)
+        both = jnp.concatenate([noisy, clean], axis=1)
+    hidden, tokens = model.apply({"params": params}, both)
+    with device_scope("vocab"):
+        head, weights = params["lm_head"], masked / p
+        if c.loss_chunks:
+            loss = xent.chunked_xent(hidden, head, clean, weights,
+                                     n_chunks=c.loss_chunks, denom=clean.size)
+        else:
+            ll = xent.token_log_likelihood(
+                hidden @ head.T.astype(hidden.dtype), clean)
+            loss = -(ll * weights).sum() / clean.size
+        return loss, {"masked_share": masked.mean(dtype=jnp.float32),
+                      "tokens_per_expert": tokens}
 
 
 def init_params(config: SdarConfig, rng):

@@ -14,6 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private.steptrace import device_scope
 from ray_tpu.parallel.mesh_utils import on_batch_axes
 
 
@@ -42,10 +43,11 @@ def token_log_likelihood(logits, labels):
 
 def fused_xent(logits, labels, mask=None):
     """Masked-mean fused cross-entropy (see token_log_likelihood)."""
-    ll = token_log_likelihood(logits, labels)
-    if mask is None:
-        return -ll.mean()
-    return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1)
+    with device_scope("vocab"):
+        ll = token_log_likelihood(logits, labels)
+        if mask is None:
+            return -ll.mean()
+        return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1)
 
 
 def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8,
@@ -83,15 +85,16 @@ def chunked_xent(hidden, embedding, labels, mask=None, n_chunks=8,
     def chunks(x):
         return x.reshape(B, n_chunks, t, *x.shape[2:]).swapaxes(0, 1)
 
-    hid = on_batch_axes(chunks(hidden), batch_dim=1)
-    if mask is None:
-        # unmasked: the denominator is statically B*T, no ones to scan
-        weights, denom = None, jnp.float32(B * T)
-    else:
-        weights = chunks(mask.astype(jnp.float32))
-        denom = (jnp.maximum(weights.sum(), 1.0) if denom is None
-                 else jnp.float32(denom))
-    return _chunked_xent(hid, embedding, chunks(labels), weights, denom)
+    with device_scope("vocab"):
+        hid = on_batch_axes(chunks(hidden), batch_dim=1)
+        if mask is None:
+            # unmasked: the denominator is statically B*T, no ones to scan
+            weights, denom = None, jnp.float32(B * T)
+        else:
+            weights = chunks(mask.astype(jnp.float32))
+            denom = (jnp.maximum(weights.sum(), 1.0) if denom is None
+                     else jnp.float32(denom))
+        return _chunked_xent(hid, embedding, chunks(labels), weights, denom)
 
 
 def _scan_xent_chunks(hid, embedding, lab, weights, denom, with_grads):

@@ -87,13 +87,15 @@ def build_train_step(loss_fn, tx, donate: bool = True, has_aux: bool = False,
             seen = (batch, step_count(opt_state)) if with_count else (batch,)
             out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
                 params, *seen)
-            if shardings:
-                # a gradient leaves the backward pass laid out as its
-                # parameter is at rest: the sum over the split batch
-                # becomes a reduce-scatter, not an all-reduce
-                grads = jax.lax.with_sharding_constraint(grads, shardings[0])
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with steptrace.device_scope("optimizer"):
+                if shardings:
+                    # a gradient leaves the backward pass laid out as its
+                    # parameter is at rest: the sum over the split batch
+                    # becomes a reduce-scatter, not an all-reduce
+                    grads = jax.lax.with_sharding_constraint(grads,
+                                                             shardings[0])
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, out
 
         step.__name__ = STEP_NAME

@@ -28,9 +28,12 @@ def test_the_benchmark_file_gained_the_cell_and_nothing_else_moved():
     what PR 44 added is there, unchanged, and after everything older."""
     import json
 
+    from tests.perfbench_cases import without_later_metrics
+
     with open(os.path.join(os.path.dirname(_PATH), "..", "..",
                            "BENCHMARK.json")) as f:
-        bench = json.load(f)
+        # PR 69's seven list every step cell
+        bench = without_later_metrics(json.load(f))
     cell = "trinity-mini.step-16k"
     cells = [w["name"] for w in bench["workloads"]]
     assert cells.index(cell) == 5 and bench["workloads"][5]["chips"] == 1

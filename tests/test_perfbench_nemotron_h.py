@@ -30,6 +30,9 @@ def test_the_benchmark_file_gained_the_cell(monkeypatch):
     ``tests/test_perfbench_clusterspans.py`` does for its module's."""
     import json
 
+    from tests.perfbench_cases import strike_later_metrics
+
+    strike_later_metrics(monkeypatch)   # PR 69's seven list every step cell
     load = json.load
 
     def up_to_the_cell(f):
